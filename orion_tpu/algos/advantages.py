@@ -8,6 +8,8 @@ time axis — compiler-friendly, no Python loop over T.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -42,12 +44,18 @@ def per_token_rewards(scores: jnp.ndarray, kl: jnp.ndarray,
     return rewards * mask
 
 
+@functools.partial(jax.jit, static_argnames=("gamma", "lam"))
 def gae(rewards: jnp.ndarray, values: jnp.ndarray, mask: jnp.ndarray,
         gamma: float, lam: float) -> tuple:
     """Generalized advantage estimation over [B, T] tensors.
 
     V beyond the last real token is treated as 0 (sequences terminate).
     Returns (advantages, returns) both [B, T] f32, masked.
+
+    Jitted: PPO calls this eagerly once per iteration, and an eager
+    ``lax.scan`` over a fresh ``step`` closure is a new program every
+    call — one recompile per training iteration (found by the
+    RecompileSentinel in chip_smoke.py's steady window).
     """
     rewards = rewards.astype(jnp.float32) * mask
     values = values.astype(jnp.float32) * mask

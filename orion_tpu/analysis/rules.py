@@ -189,17 +189,17 @@ def _walk_traced(ctx: ModuleContext, jit: JitIndex):
 
 
 # ---------------------------------------------------------------------------
-# rule: compat-import — jax-version landmines the shims exist for
+# rule: compat-import — one import site for shard_map / axis_size
 # ---------------------------------------------------------------------------
 
 _SHIM_HINT = ("use orion_tpu.utils.platform.shard_map / axis_size — "
-              "jax 0.4.37 has no jax.shard_map or lax.axis_size, and the "
-              "shim degrades partial-manual mode safely")
+              "the one place that maps this repo's keyword spelling "
+              "onto jax.shard_map / lax.axis_size")
 
 
 @rule("compat-import",
       "direct jax.shard_map / lax.axis_size use that bypasses the "
-      "utils/platform.py compat shims (ImportError on jax 0.4.37)")
+      "utils/platform.py forwards (one import site per jax API)")
 def _check_compat_import(ctx: ModuleContext):
     if ctx.path.replace(os.sep, "/").endswith("utils/platform.py"):
         return  # the shim itself

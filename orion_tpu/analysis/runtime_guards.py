@@ -25,11 +25,14 @@ import threading
 import warnings
 from typing import Dict, Optional
 
-_COMPILE_RE = re.compile(r"^Compiling ([^\s]+) with global shapes")
+# jax logs "Compiling jit(<name>) with global shapes ..."; counts are
+# keyed by the bare function name, so the jit(...) wrapper is stripped.
+_COMPILE_RE = re.compile(
+    r"^Compiling (?:jit\()?([^\s()]+)\)? with global shapes")
 
 # jax_log_compiles emits through child loggers of "jax"
-# (jax._src.interpreters.pxla on 0.4.37); attaching to the parent
-# survives the module moving between versions.
+# (jax._src.interpreters.pxla); attaching to the parent survives the
+# module moving.
 _JAX_LOGGER = "jax"
 
 # Shared install state: refcounted so two live sentinels don't fight —

@@ -333,10 +333,11 @@ class RolloutConfig:
     # registered at runtime via engine.configure_tenant().
     max_queued_requests: int = 0
     # Waves between a slot's done-flag snapshot and its harvest.
-    # 1 lets the flag fetch ride out the next segment's execution —
-    # worth a full tunnel RTT per wave on a remote TPU link, but pure
-    # waste (one extra masked segment per request) on a local backend
-    # where the fetch is ~free.  -1 = auto: 1 on TPU, 0 elsewhere.
+    # 1 lets the flag fetch ride out the next segment's execution at
+    # the price of one extra masked segment per request; 0 fetches
+    # immediately.  -1 = auto: 1 on TPU, 0 elsewhere.  The fetch cost
+    # on the local chip is not measured yet (ROADMAP D4), so auto is
+    # a carried-over setting, not a tuned one.
     harvest_lag: int = -1
 
     def effective_min_new(self, eos_id) -> int:
@@ -760,9 +761,9 @@ class TrainConfig:
     data: DataConfig = field(default_factory=DataConfig)
     # Policy init: HF checkpoint path (None => random init), or a
     # ModelConfig preset name ("llama3_8b"|"llama3_1b"|"pythia_1b") that
-    # overrides `model` wholesale.
+    # replaces `model` before any `model.*` key applies (load_config).
     hf_path: Optional[str] = None
-    model_preset: Optional[str] = None
+    model_preset: Optional[str] = None  # orion: ignore[config-drift] consumed by load_config itself: the preset must land before the model.* overrides
     # Reward source: "math" (rule verifier), "length" (debug),
     # "model:<hf-or-ckpt-path>" (reward model scoring).
     reward: str = "math"
@@ -903,8 +904,13 @@ def load_config(cls, yaml_path: Optional[str] = None,
     """Build a config from an optional yaml file plus ``key=value`` CLI args.
 
     Nested keys use dots: ``model.hidden_size=1024 optimizer.learning_rate=3e-6``.
+    ``model_preset=<name>`` replaces ``model`` with that ModelConfig
+    preset FIRST, wherever it appears; ``model.*`` keys given beside it
+    apply on top of the preset (``model_preset=pythia_1b
+    model.remat=true``).
     """
     cfg = cls()
+    overrides = {}
     if yaml_path:
         import yaml  # lazy: pyyaml ships with the base image
 
@@ -921,10 +927,15 @@ def load_config(cls, yaml_path: Optional[str] = None,
                     out[kk] = v
             return out
 
-        _apply_overrides(cfg, flatten(data))
+        overrides.update(flatten(data))
     for arg in cli_args or []:
         if "=" not in arg:
             raise ValueError(f"expected key=value, got {arg!r}")
         k, v = arg.split("=", 1)
-        _apply_overrides(cfg, {k: v})
-    return cfg
+        overrides.pop(k, None)  # CLI wins, and keeps CLI order
+        overrides[k] = v
+    preset = overrides.get("model_preset")
+    if preset:
+        _apply_overrides(cfg, {"model_preset": preset})
+        cfg.model = getattr(ModelConfig, cfg.model_preset)()
+    return _apply_overrides(cfg, overrides)

@@ -57,9 +57,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from orion_tpu.config import (GRPOConfig, ModelConfig, OnlineDPOConfig,
-                              PPOConfig, RLOOConfig, RolloutConfig,
-                              load_config)
+from orion_tpu.config import (GRPOConfig, OnlineDPOConfig, PPOConfig,
+                              RLOOConfig, RolloutConfig, load_config)
 from orion_tpu.data import build_prompt_iterator
 from orion_tpu.data.prompts import load_tokenizer
 from orion_tpu.models import (ScalarHeadModel, Transformer)
@@ -69,6 +68,7 @@ from orion_tpu.parallel.mesh import make_mesh
 from orion_tpu.rewards import MathVerifierReward, ModelReward
 from orion_tpu.trainers import (GRPOTrainer, OnlineDPOTrainer, PPOTrainer,
                                 RLOOTrainer)
+from orion_tpu.utils.platform import enable_compile_cache
 
 ALGOS = {
     "ppo": (PPOConfig, PPOTrainer),
@@ -310,6 +310,7 @@ def run_serve(cfg, port: int = 0, tenant_spec: Optional[str] = None,
                                                  parse_tenant_spec)
     from orion_tpu.resilience.preemption import install_handler
 
+    enable_compile_cache()
     tokenizer = load_tokenizer(cfg.data.tokenizer)
     if cfg.data.tokenizer in (None, "byte"):
         cfg.model.vocab_size = max(cfg.model.vocab_size, 260)
@@ -391,25 +392,43 @@ def run_serve(cfg, port: int = 0, tenant_spec: Optional[str] = None,
     return gw.stats
 
 
+def _require_pool_worker_routing(ranks) -> None:
+    """One process per chip: raise unless every same-host pool worker
+    is routed off the TPU this (learner) process holds."""
+    if jax.default_backend() != "tpu" or \
+            os.environ.get("ORION_POOL_WORKER_PLATFORM"):
+        return
+    unrouted = [r for r in ranks
+                if not os.environ.get(f"ORION_POOL_WORKER_ENV_{r}")]
+    if unrouted:
+        raise RuntimeError(
+            f"pool workers {unrouted} would inherit this process's "
+            "environment and contend for the TPU it already holds "
+            "(one process per chip): set ORION_POOL_WORKER_PLATFORM "
+            "(e.g. cpu) or ORION_POOL_WORKER_ENV_<rank> (e.g. "
+            "TPU_VISIBLE_DEVICES=<n>) to route them elsewhere")
+
+
 def spawn_pool_workers(algo: str, argv: list, port: int, n: int) -> list:
     """Spawn ``n`` rollout worker processes re-execing this entrypoint
     with the same CLI args; env vars route them into
     :func:`run_pool_worker`.  Returns the Popen handles (the tier-1
     smoke monkeypatches this with the in-process thread harness).
 
-    Device placement: children inherit the parent's environment, so
-    on a single TPU host they would contend for the chips the learner
-    already holds (libtpu is single-process per chip).  Same-host
-    workers must be pointed elsewhere with
+    Device placement: children inherit the parent's environment, and
+    a chip belongs to one process at a time — the learner (this
+    process) already holds the local TPU, so a child that asks for it
+    hangs.  Same-host workers must be pointed elsewhere with
     ``ORION_POOL_WORKER_PLATFORM`` (exported to the children as their
     ``JAX_PLATFORMS``, e.g. ``cpu``) or per-rank device isolation via
     ``ORION_POOL_WORKER_ENV_<rank>`` (``KEY=V,KEY2=V2``, e.g.
-    ``TPU_VISIBLE_DEVICES``); multi-host pods set neither and give
-    each worker its own host."""
+    ``TPU_VISIBLE_DEVICES``).  With the parent on a TPU and neither
+    variable set, this raises BEFORE spawning anything."""
     import subprocess
 
     from orion_tpu.resilience import fault_point
 
+    _require_pool_worker_routing(range(n))
     worker_platform = os.environ.get("ORION_POOL_WORKER_PLATFORM")
     procs = []
     for rank in range(n):
@@ -509,6 +528,7 @@ def main(argv: Optional[list] = None) -> Any:
         raise SystemExit(2)
     algo = argv.pop(0)
     raw_argv = list(argv)  # worker processes re-exec with these
+    enable_compile_cache()
     yaml_path = None
     if "--config" in argv:
         i = argv.index("--config")
@@ -538,8 +558,6 @@ def main(argv: Optional[list] = None) -> Any:
             rollout = True
     cfg_cls, _ = ALGOS.get(algo, (GRPOConfig, None))
     cfg = load_config(cfg_cls, yaml_path=yaml_path, cli_args=argv)
-    if cfg.model_preset:
-        cfg.model = getattr(ModelConfig, cfg.model_preset)()
 
     if algo == "serve":
         return run_serve(cfg, port=serve_port, tenant_spec=tenant_spec,
@@ -614,6 +632,9 @@ def main(argv: Optional[list] = None) -> Any:
         from orion_tpu.orchestration.async_orchestrator import (
             PoolOrchestrator)
 
+        # before the trainer is built: a doomed spawn must not cost a
+        # model init first
+        _require_pool_worker_routing(range(cfg.resilience.pool_size))
         mesh = make_mesh(cfg.mesh)
         with mesh:
             trainer = build_trainer(algo, cfg, mesh, tokenizer)

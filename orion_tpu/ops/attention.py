@@ -9,6 +9,7 @@ repeated-KV expansion never materializes (see reference_attention_gqa).
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -144,6 +145,43 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if impl == "flash" and q.shape[1] > 1:
         if q_positions is None:
             raise ValueError("flash attention requires q_positions")
-        from orion_tpu.ops.pallas.flash_attention import flash_attention_gqa
-        return flash_attention_gqa(q, k, v, q_positions, scale)
+        return _flash_on_mesh(q, k, v, q_positions, scale)
     return reference_attention_gqa(q, k, v, mask, scale)
+
+
+def _flash_on_mesh(q, k, v, q_positions, scale):
+    """The flash kernel under whatever mesh is ambient.
+
+    jax refuses to lower a Mosaic kernel inside an automatically
+    partitioned program ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map") — so under a
+    multi-device ``with mesh:`` the kernel runs in a shard_map that is
+    manual over EVERY mesh axis: batch over (data, fsdp), heads over
+    tensor (q and kv heads split proportionally, so the local GQA
+    mapping holds), each only where it divides; whatever is not named
+    replicates.  Already inside someone else's shard_map (ring /
+    ulysses / pipeline bodies) the kernel is called as is.
+    """
+    from orion_tpu.ops.pallas.flash_attention import flash_attention_gqa
+    from orion_tpu.parallel.sharding import ambient_mesh
+
+    mesh = ambient_mesh()
+    if (mesh.empty or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return flash_attention_gqa(q, k, v, q_positions, scale)
+    from jax.sharding import PartitionSpec as P
+
+    from orion_tpu.utils.platform import shard_map
+
+    shape = dict(mesh.shape)
+    batch = tuple(a for a in ("data", "fsdp") if shape.get(a, 1) > 1)
+    n_batch = math.prod(shape[a] for a in batch)
+    b = batch if batch and q.shape[0] % n_batch == 0 else None
+    tp = shape.get("tensor", 1)
+    h = ("tensor" if tp > 1 and q.shape[2] % tp == 0
+         and k.shape[2] % tp == 0 else None)
+    qkv = P(b, None, h, None)
+    return shard_map(
+        lambda q_, k_, v_, pos: flash_attention_gqa(q_, k_, v_, pos, scale),
+        mesh=mesh, in_specs=(qkv, qkv, qkv, P(b, None)), out_specs=qkv,
+        check_vma=False)(q, k, v, q_positions)

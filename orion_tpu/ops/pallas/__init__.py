@@ -17,27 +17,29 @@ NEG_INF = -1e30
 def target_platform() -> str:
     """Platform the current trace will execute on.
 
-    An active ``with mesh:`` context wins over the default backend —
-    a CPU fake-device mesh on a TPU box (the SURVEY.md §4 test harness
-    and the driver's dryrun fallback) must compile kernels for CPU, and
-    vice versa a TPU mesh on a box whose default backend is CPU.
-    """
-    try:
-        from jax._src import mesh as mesh_lib
+    An active mesh context wins over the default backend — a CPU
+    fake-device mesh must compile kernels for CPU whatever the default
+    backend is, and vice versa.  Both spellings are seen: the legacy
+    ``with mesh:`` (thread resources) and ``jax.set_mesh`` (the concrete
+    mesh context; ``jax.sharding.get_mesh`` refuses to answer inside a
+    trace, which is exactly where this runs).  Then a
+    ``jax.default_device`` pin, then the default backend.
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m.devices.flat[0].platform
-    except Exception:
-        pass
-    try:
-        # A `with jax.default_device(dev):` pin (the dryrun's hermetic
-        # CPU fallback) also redirects where unsharded traces execute.
-        dev = jax.config.jax_default_device
-        if dev is not None:
-            return dev.platform
-    except Exception:
-        pass
+    ``jax._src.mesh`` is private: if it moves, this raises — it must
+    never fall through to the default backend silently, because this one
+    answer decides interpreted-vs-compiled for every kernel.
+    """
+    from jax._src import mesh as mesh_lib
+
+    m = mesh_lib.thread_resources.env.physical_mesh
+    if m.empty:
+        m = mesh_lib.get_concrete_mesh()
+    if not m.empty:
+        return m.devices.flat[0].platform
+    dev = jax.config.jax_default_device
+    if dev is not None:
+        # a Device, or a platform name (``jax.default_device("cpu")``)
+        return dev if isinstance(dev, str) else dev.platform
     return jax.default_backend()
 
 

@@ -271,8 +271,14 @@ def paged_decode_attention_sharded(q, k_pages, v_pages, block_tables,
         return paged_decode_attention(q_, kp, vp, bt, ln, scale,
                                       k_scales=ks, v_scales=vs)
 
+    # Manual over EVERY mesh axis, not just 'tensor': jax refuses to
+    # lower a Mosaic kernel under a partially-manual shard_map ("Mosaic
+    # kernels cannot be automatically partitioned"), and the repo's
+    # meshes always carry all six axis names.  The specs name only
+    # 'tensor', so the operands replicate over the other axes (q, block
+    # tables and lengths are tiny; the pools are sharded over 'tensor'
+    # alone to begin with).
     mapped = shard_map(
         body, mesh=mesh, in_specs=tuple(specs),
-        out_specs=P(None, "tensor", None),
-        axis_names={"tensor"}, check_vma=False)
+        out_specs=P(None, "tensor", None), check_vma=False)
     return mapped(*args)

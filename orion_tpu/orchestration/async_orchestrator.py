@@ -410,9 +410,15 @@ class AsyncOrchestrator:
                                 self.trainer.cfg, "group_size", 1)),
                             params=params)
                     else:
-                        result = self.engine.generate(
-                            np.asarray(ids), np.asarray(lens), sub,
-                            params=params)
+                        # The mesh context is thread-local: without it
+                        # this thread's trace sees no mesh, and on TPU
+                        # the prefill's flash kernel would land bare in
+                        # a program partitioned over the rollout group
+                        # (jax refuses to lower that).
+                        with self.rollout_mesh:
+                            result = self.engine.generate(
+                                np.asarray(ids), np.asarray(lens), sub,
+                                params=params)
                 # An incarnation abandoned (or shut down) while inside
                 # the dispatch drops its orphaned result here: scoring
                 # would race the replacement worker through the shared

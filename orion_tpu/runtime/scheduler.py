@@ -62,6 +62,10 @@ _lib_lock = threading.Lock()
 # in this process (and, via the .fail sentinel, later processes) fall
 # straight back to PyScheduler until the source changes.
 _load_failed_hash: Optional[str] = None
+# Why the last build attempt fell back (None = it did not): the
+# fallback itself stays quiet, so callers that must not be served by
+# PyScheduler unawares (chip_smoke.py) print this.
+last_build_error: Optional[str] = None
 
 POLICIES = {"fifo": 0, "priority": 1, "deadline": 2}
 NO_DEADLINE = -1
@@ -83,6 +87,7 @@ def _compile() -> Optional[str]:
     per source hash (the ``.fail`` sentinel), so a toolchain-less box
     pays the compile attempt once, not per construction.
     """
+    global last_build_error
     os.makedirs(_BUILD_DIR, exist_ok=True)
     hash_file = _SO + ".sha256"
     want = _src_hash()
@@ -93,6 +98,7 @@ def _compile() -> Optional[str]:
     try:
         with open(_FAIL) as f:
             if f.read().strip() == want:
+                last_build_error = f"memoized failure ({_FAIL})"
                 return None
     except OSError:
         pass
@@ -106,15 +112,18 @@ def _compile() -> Optional[str]:
         except OSError:
             pass
         return _SO
-    except subprocess.TimeoutExpired:
+    except subprocess.TimeoutExpired as e:
+        last_build_error = repr(e)
         # Transient (loaded box): fall back for THIS process (the
         # in-process memo still stops repeat attempts) but never write
         # the cross-process sentinel — a one-off slow CI run must not
         # disable the native scheduler for the checkout forever.
         return None
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
         # Deterministic per source/toolchain (g++ missing, compile
         # error): memoize across processes until the source changes.
+        stderr = getattr(e, "stderr", None) or b""
+        last_build_error = f"{e!r} {stderr[-500:].decode(errors='replace')}"
         try:
             with open(_FAIL, "w") as f:
                 f.write(want)

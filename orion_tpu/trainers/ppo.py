@@ -25,7 +25,8 @@ from orion_tpu.algos import (AdaptiveKLController, FixedKLController, gae,
                              ppo_value_loss)
 from orion_tpu.config import PPOConfig
 from orion_tpu.models.heads import ScalarHeadModel
-from orion_tpu.trainers.base import BaseTrainer, TrainState
+from orion_tpu.trainers.base import (BaseTrainer, TrainState,
+                                     state_out_shardings)
 
 
 class PPOTrainer(BaseTrainer):
@@ -62,8 +63,11 @@ class PPOTrainer(BaseTrainer):
                     "critic_params (or set cfg.share_backbone=True)")
             self.critic_model = critic_model
             self.critic_state = TrainState.create(critic_params, self.tx)
-            self._jit_ppo_epochs = jax.jit(self._ppo_epochs_fn,
-                                           donate_argnums=(0, 1))
+            self._jit_ppo_epochs = jax.jit(
+                self._ppo_epochs_fn, donate_argnums=(0, 1),
+                out_shardings=(state_out_shardings(self.state),
+                               state_out_shardings(self.critic_state),
+                               None))
         self.kl_ctl = (AdaptiveKLController(cfg.kl_coef, cfg.kl_target,
                                             cfg.kl_horizon)
                        if cfg.adaptive_kl else FixedKLController(cfg.kl_coef))

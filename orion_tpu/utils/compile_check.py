@@ -14,10 +14,11 @@ import jax
 import jax.numpy as jnp
 
 
-def _build_8b_shell():
+def _build_8b_shell(model_cfg=None):
     """(shell, state_shapes, minibatch_shapes): an abstract 8B
     shared-backbone PPO trainer — every attribute its jitted update
-    touches, with ShapeDtypeStruct params (no buffers)."""
+    touches, with ShapeDtypeStruct params (no buffers).  ``model_cfg``
+    swaps the model (tests compile the Pythia-1B update this way)."""
     import flax.linen as nn
 
     from orion_tpu.config import ModelConfig, OptimizerConfig, PPOConfig
@@ -26,7 +27,7 @@ def _build_8b_shell():
     from orion_tpu.trainers.ppo import PPOTrainer
 
     cfg = PPOConfig()
-    cfg.model = ModelConfig.llama3_8b()
+    cfg.model = model_cfg or ModelConfig.llama3_8b()
     cfg.model.remat = True
     cfg.model.scan_layers = True
     cfg.share_backbone = True
@@ -80,7 +81,8 @@ def _abstract_state(shell, pshape):
                       step=jax.ShapeDtypeStruct((), jnp.int32))
 
 
-def lower_8b_update(mesh=None, compile: bool = False) -> str:
+def lower_8b_update(mesh=None, compile: bool = False,
+                    model_cfg=None) -> str:
     """Trace + lower (and optionally compile) the full 8B update step.
 
     mesh=None: single-device shapes (bench.py's compile check).  With a
@@ -94,7 +96,7 @@ def lower_8b_update(mesh=None, compile: bool = False) -> str:
     # obs.timed measures even with tracing off; with it, the 8B lower/
     # compile shows up as one span on the run's timeline.
     with obs.timed("compile.8b_update", compile=compile) as sp:
-        shell, pshape, mb = _build_8b_shell()
+        shell, pshape, mb = _build_8b_shell(model_cfg)
         if mesh is not None:
             from orion_tpu.models.sharded import mesh_shardings_for
 

@@ -41,10 +41,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from orion_tpu.utils.platform import ensure_live_backend
-
-ensure_live_backend(timeout=float(os.environ.get("SPEC_PROBE_S", "30")))
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -1,0 +1,270 @@
+"""AOT compiles for a DESCRIBED v5e chip (no chip attached): the
+kernels of the main path at real widths, through the TPU compiler.
+
+This is rehearsal 3 of /opt/skills/guides/on-chip-measurement §2: it
+shows what interpret mode cannot (tiling, VMEM, Mosaic lowering rules)
+at no chip time.  A compile that passes is not a chip run.
+
+Rules this file keeps (the suite runs under ``-p xdist -n 6 --dist
+loadfile``; every worker imports every test file):
+
+- the topology is described INSIDE a module-scoped fixture that skips
+  on failure — never at import, in a ``skipif``, in ``parametrize``, in
+  conftest, ``autouse`` or a child process: only one process may load
+  libtpu, and a module that decides at import which tests exist gives
+  the workers different collections (xdist then runs nothing);
+- all such tests live in THIS one file (a second file could land on a
+  worker that cannot load the library and skip in silence);
+- the persistent compile cache is off around these compiles: an entry
+  written for a described chip cannot be read back without one.
+
+``interpret_mode()`` asks the default backend (CPU here); the tests
+steer it with monkeypatch — the program gets no option for it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Kernel dispatch believes it targets the TPU (compiled Mosaic
+    kernels, flash instead of the einsum reference, the paged kernel
+    instead of its XLA twin)."""
+    import orion_tpu.ops.pallas as pallas
+
+    monkeypatch.setattr(pallas, "target_platform", lambda: "tpu")
+    assert not pallas.interpret_mode()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# (B, Lq, Lk, H, Hkv, D, first q position)
+FLASH_SHAPES = {
+    # the ppo1b update/experience shape (bench.py, chip_smoke.py)
+    "pythia1b": (16, 384, 384, 8, 8, 256, 0),
+    # llama3-8B width (GQA 32/8, D=128)
+    "llama8b": (4, 1024, 1024, 32, 8, 128, 0),
+    # tests/test_tpu_smoke.py regression shapes: a cache length that is
+    # no multiple of 128 ...
+    "odd_cache_144": (2, 16, 144, 8, 4, 64, 128),
+    # ... and the speculative-verify chunk (Lq=5 over Lk=388=4*97)
+    "spec_verify_5x388": (4, 5, 388, 8, 8, 64, 300),
+}
+
+
+def _flash_args(name, sharding):
+    B, Lq, Lk, H, Hkv, D, _ = FLASH_SHAPES[name]
+    return (_sds((B, Lq, H, D), BF16, sharding),
+            _sds((B, Lk, Hkv, D), BF16, sharding),
+            _sds((B, Lk, Hkv, D), BF16, sharding))
+
+
+def _flash(name):
+    from orion_tpu.ops.pallas.flash_attention import flash_attention_gqa
+
+    B, Lq, _, _, _, D, p0 = FLASH_SHAPES[name]
+
+    def fwd(q, k, v):
+        qpos = jnp.broadcast_to(
+            jnp.arange(p0, p0 + Lq, dtype=jnp.int32), (B, Lq))
+        return flash_attention_gqa(q, k, v, qpos, D ** -0.5)
+
+    return fwd
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_SHAPES))
+def test_flash_fwd_compiles_for_v5e(name, one_chip, on_tpu):
+    compiled = jax.jit(_flash(name)).lower(
+        *_flash_args(name, one_chip)).compile()
+    assert _kernel_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("name", ["pythia1b", "llama8b"])
+def test_flash_fwd_bwd_compiles_for_v5e(name, one_chip, on_tpu):
+    fwd = _flash(name)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_flash_args(name, one_chip)).compile()
+    # forward + dq + dkv kernels
+    assert _kernel_calls(compiled) == 3
+
+
+# The serving shape: B=48 slots, page_size 64, 288 pages (+1 scratch).
+PAGED = dict(B=48, pages=289, page_size=64, max_pages=6)
+PAGED_WIDTHS = {"pythia1b": (8, 8, 256), "llama8b": (32, 8, 128)}
+
+
+def _paged_args(width, quantized, shard):
+    """Abstract paged-decode operands; ``shard(kind)`` gives the
+    sharding for 'q' / 'pool' / 'rep'."""
+    H, Hkv, D = PAGED_WIDTHS[width]
+    B, N, ps, mp = (PAGED["B"], PAGED["pages"], PAGED["page_size"],
+                    PAGED["max_pages"])
+    pool_dt = jnp.int8 if quantized else BF16
+    args = [_sds((B, H, D), BF16, shard("q")),
+            _sds((N, Hkv, ps, D), pool_dt, shard("pool")),
+            _sds((N, Hkv, ps, D), pool_dt, shard("pool"))]
+    if quantized:
+        args += [_sds((N, Hkv, 1, ps), jnp.float32, shard("pool")),
+                 _sds((N, Hkv, 1, ps), jnp.float32, shard("pool"))]
+    args += [_sds((B, mp), jnp.int32, shard("rep")),
+             _sds((B,), jnp.int32, shard("rep"))]
+    return args, D ** -0.5
+
+
+def _paged_fn(entry, quantized, scale):
+    def fn(q, kp, vp, *rest):
+        if quantized:
+            ks, vs, bt, ln = rest
+        else:
+            (bt, ln), ks, vs = rest, None, None
+        return entry(q, kp, vp, bt, ln, scale, k_scales=ks, v_scales=vs)
+
+    return fn
+
+
+@pytest.mark.parametrize("quantized", [False, True],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("width", sorted(PAGED_WIDTHS))
+def test_paged_decode_compiles_for_v5e(width, quantized, one_chip,
+                                       on_tpu):
+    from orion_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    args, scale = _paged_args(width, quantized, lambda kind: one_chip)
+    compiled = jax.jit(_paged_fn(paged_decode_attention, quantized,
+                                 scale)).lower(*args).compile()
+    assert _kernel_calls(compiled) == 1
+
+
+@pytest.mark.parametrize("quantized", [False, True],
+                         ids=["bf16", "int8"])
+def test_paged_decode_sharded_compiles_for_2x2(quantized, topo):
+    """The tensor-parallel decode wraps the kernel in a partial-manual
+    ``shard_map`` (manual over 'tensor', auto over 'fsdp'); this native
+    lowering has never met the TPU compiler before.  The kernel must be
+    in the per-device program and the KV pool must NOT be all-gathered
+    (the pool stays kv-head-sharded; q and the output are tiny)."""
+    from orion_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention_sharded)
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2),
+                ("fsdp", "tensor"))
+    spec = {"q": P(None, "tensor", None),
+            "pool": P(None, "tensor", None, None), "rep": P()}
+    args, scale = _paged_args(
+        "pythia1b", quantized,
+        lambda kind: NamedSharding(mesh, spec[kind]))
+    # no monkeypatch: the mesh's devices ARE tpu devices, and
+    # target_platform() reads the mesh context
+    with mesh:
+        compiled = jax.jit(_paged_fn(paged_decode_attention_sharded,
+                                     quantized, scale)).lower(
+                                         *args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    H, Hkv, D = PAGED_WIDTHS["pythia1b"]
+    pool = f"[{PAGED['pages']},{Hkv},{PAGED['page_size']},{D}]"
+    gathers = [ln for ln in text.splitlines() if "all-gather" in ln]
+    assert not any(pool in ln for ln in gathers), gathers
+
+
+def test_pythia1b_ppo_update_compiles_for_2x2(topo):
+    """The whole shared-backbone PPO update at Pythia-1B (16 layers,
+    remat, scanned) over a described fsdp=2 x tensor=2 mesh with the
+    real param shardings: the SPMD partitioner runs, and the flash
+    kernels are in the per-device program.  Before PR 22 this did not
+    lower at all — jax refuses a Mosaic kernel in an automatically
+    partitioned program; ``ops.attention._flash_on_mesh`` wraps it."""
+    from orion_tpu.config import MeshConfig, ModelConfig
+    from orion_tpu.parallel.mesh import make_mesh
+    from orion_tpu.utils.compile_check import lower_8b_update
+
+    mesh = make_mesh(MeshConfig(data=1, fsdp=2, tensor=2),
+                     devices=topo.devices)
+    with mesh:   # target_platform() reads the mesh: its devices are tpu
+        status = lower_8b_update(mesh=mesh, compile=True,
+                                 model_cfg=ModelConfig.pythia_1b())
+    assert status.startswith("ok (1.01B params compiled"), status
+
+
+def test_pythia1b_decode_segment_compiles_for_v5e(one_chip, on_tpu):
+    """The whole decode-segment program of the continuous engine at the
+    Pythia-1B serving shape (16 layers, int8 weights + KV, 32 slots,
+    page_size 64): abstract arguments shaped like the engine's own,
+    compiled for one described chip.  Also says whether it fits HBM."""
+    from orion_tpu.config import ModelConfig, RolloutConfig
+    from orion_tpu.models import Transformer, init_params
+    from orion_tpu.models.transformer import prep_decode_params
+    from orion_tpu.rollout.continuous import ContinuousBatchingEngine
+
+    mc = ModelConfig.pythia_1b()
+    rc = RolloutConfig(max_prompt_len=512, max_new_tokens=128,
+                       page_size=64, max_batch_size=32,
+                       quantize_weights=True, quantize_kv=True,
+                       engine="continuous")
+    model = Transformer(mc)
+    eng = ContinuousBatchingEngine(model, mc, rc, eos_token_id=0,
+                                   pad_token_id=0)
+
+    def abstract(tree):
+        return jax.tree.map(
+            lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+
+    params = jax.eval_shape(lambda: prep_decode_params(
+        init_params(model, jax.random.key(0), mc), mc, True))
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = eng._jit_segment.lower(
+        abstract(params), abstract(eng._pools),
+        _sds(eng._bt.shape, jnp.int32, one_chip),
+        abstract(jax.eval_shape(eng._init_state)),
+        _sds(rng.shape, rng.dtype, one_chip),
+        n_steps=eng.segment_len).compile()
+    # one paged-decode kernel call per layer program (layers unrolled
+    # or scanned — at least one call either way)
+    assert _kernel_calls(compiled) >= 1
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 16e9, f"decode segment needs {total / 1e9:.1f} GB"
