@@ -343,9 +343,10 @@ def check_training(run: dict, iterations: int, shape: Shape,
         "flash_calls_in_update": run["flash_calls_in_update"],
         "warmup_and_compile_s": round(run["warmup_s"], 2),
         "steady_s": round(run["steady_s"], 2),
-        "iter_time_rollout_s": [round(h["time_rollout_s"], 3)
-                                for h in hist],
-        "iter_time_update_s": [round(h["time_update_s"], 3) for h in hist],
+        "iter_host_experience_s": [round(h["host_experience_s"], 3)
+                                   for h in hist],
+        "iter_host_update_dispatch_s": [
+            round(h["host_update_dispatch_s"], 3) for h in hist],
         "compiles_warmup": run["compiles_warmup"],
         "compiles_steady": run["compiles_steady"],
         "peak_hbm_bytes": device_memory("peak_bytes_in_use"),

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from orion_tpu import obs
 from orion_tpu.config import (GRPOConfig, OnlineDPOConfig, PPOConfig,
                               RLOOConfig, RolloutConfig, load_config)
 from orion_tpu.data import build_prompt_iterator
@@ -379,6 +380,9 @@ def run_serve(cfg, port: int = 0, tenant_spec: Optional[str] = None,
           flush=True)
     if on_ready is not None:
         on_ready(gw)
+    # obs.trace=true: the ring (and its flight recorder) for this
+    # serving process; its spans are written out when the serve ends.
+    obs_session = obs.install_from_config(cfg)
     try:
         for rep in replicas[1:]:
             rep.start()
@@ -389,6 +393,8 @@ def run_serve(cfg, port: int = 0, tenant_spec: Optional[str] = None,
         for rep in reversed(replicas[1:]):
             rep.close()
         gw.close()
+        if obs_session is not None:
+            obs_session.uninstall()
     return gw.stats
 
 

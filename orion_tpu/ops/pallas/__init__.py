@@ -46,3 +46,26 @@ def target_platform() -> str:
 def interpret_mode() -> bool:
     """Run kernels interpreted off-TPU (CPU test harness)."""
     return target_platform() != "tpu"
+
+
+def named_pallas_call(name: str, kernel, **kw):
+    """``pl.pallas_call`` under a name that reaches the device trace.
+
+    The profiler's device event is named after the HLO instruction, and
+    this jax names a custom call's instruction after the innermost
+    entry of the name stack (``%attn.42`` when the call sat directly
+    under the model's ``attn`` module), not after the kernel.  So the
+    call runs under ``jax.named_scope(name)`` — the instruction becomes
+    ``%<name>.<n>`` — and carries ``name=`` too (the ``kernel_name``
+    attribute of the ``tpu_custom_call``, which Mosaic's own dumps
+    use).  Names only: numerics, grid and block shapes are untouched.
+    """
+    from jax.experimental import pallas as pl
+
+    call = pl.pallas_call(kernel, name=name, **kw)
+
+    def run(*operands):
+        with jax.named_scope(name):
+            return call(*operands)
+
+    return run

@@ -52,7 +52,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from orion_tpu.ops.pallas import NEG_INF, interpret_mode
+from orion_tpu.ops.pallas import (NEG_INF, interpret_mode,
+                                  named_pallas_call)
 
 
 def _pick_block(n: int, preferred: int) -> int:
@@ -218,7 +219,8 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
     if use_kvpos:
         operands.append(kvpos3)
     operands += [qt, kt, vt]
-    out, lse = pl.pallas_call(
+    out, lse = named_pallas_call(
+        "flash_fwd",
         functools.partial(_fwd_kernel, scale=scale,
                           use_kvpos=use_kvpos),
         grid_spec=grid_spec,
@@ -379,7 +381,8 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
     if use_kvpos:
         operands.append(kvpos3)
     operands += [qt, kt, vt, dout_t, lse, delta]
-    return pl.pallas_call(
+    return named_pallas_call(
+        "flash_bwd_dq",
         functools.partial(_dq_kernel, scale=scale,
                           use_kvpos=use_kvpos),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -451,7 +454,8 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
     if use_kvpos:
         operands.append(kvpos3)
     operands += [qt, kt, vt, dout_t, lse, delta]
-    dk_h, dv_h = pl.pallas_call(
+    dk_h, dv_h = named_pallas_call(
+        "flash_bwd_dkv",
         functools.partial(_dkv_kernel, scale=scale,
                           use_kvpos=use_kvpos),
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -33,7 +33,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from orion_tpu.ops.pallas import NEG_INF as _NEG_INF
-from orion_tpu.ops.pallas import interpret_mode as _interpret
+from orion_tpu.ops.pallas import (interpret_mode as _interpret,
+                                  named_pallas_call)
 
 
 def _decode_kernel(bt_ref, len_ref, q_ref, *refs, scale: float,
@@ -200,7 +201,8 @@ def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
             pltpu.VMEM((1, D), jnp.float32),   # running accumulator
         ],
     )
-    out = pl.pallas_call(
+    out = named_pallas_call(
+        "paged_decode",
         functools.partial(_decode_kernel, scale=scale,
                           page_size=page_size, quantized=quantized),
         grid_spec=grid_spec,

@@ -719,7 +719,11 @@ class AsyncOrchestrator:
                         "iteration": it,
                         "staleness": self._version - 1 - item.version,
                         "time_learner_wait_s": t_wait,
-                        "time_update_s": t_done - upd_start,
+                        # host time from the update's dispatch to the
+                        # end of the iteration (same key as the sync
+                        # loop; here it holds the stats fetch and the
+                        # weight hand-over too — not a device time)
+                        "host_update_dispatch_s": t_done - upd_start,
                         "samples_per_sec": n_samples / max(t_done, 1e-9),
                     })
                     stats.update(self._recovery_stats(degraded))
@@ -1132,7 +1136,11 @@ class PoolOrchestrator:
                         "worker": float(wid),
                         "staleness": self._version - 1 - item.version,
                         "time_learner_wait_s": t_wait,
-                        "time_update_s": t_done - upd_start,
+                        # host time from the update's dispatch to the
+                        # end of the iteration (same key as the sync
+                        # loop; here it holds the stats fetch and the
+                        # weight hand-over too — not a device time)
+                        "host_update_dispatch_s": t_done - upd_start,
                         "samples_per_sec": n_samples / max(t_done, 1e-9),
                     })
                     stats.update(self._recovery_stats(degraded))

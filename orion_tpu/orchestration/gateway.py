@@ -1122,6 +1122,11 @@ class ServingGateway:
         (forwarding engine ops to the owner) and its membership
         duties; the owner additionally adopts dead replicas' work,
         drains the fleet op queue, and runs the engines."""
+        n = len(self._clients)  # orion: ignore[lock-discipline] a span label; len() of a dict is one atomic read
+        with obs.span("gw.step", clients=n):
+            return self._pump()
+
+    def _pump(self) -> int:
         owner = self._is_owner()
         while True:
             try:

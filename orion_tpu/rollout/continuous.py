@@ -122,7 +122,7 @@ from orion_tpu.ops.sampling import (apply_repetition_penalty,
                                     eos_forbid_mask, is_stop_token,
                                     sample_tokens, seen_from_prompts,
                                     transformed_logits)
-from orion_tpu.runtime import Scheduler
+from orion_tpu.runtime import PyScheduler, Scheduler
 
 # slot lifecycle: empty -> prefilling (admitted, prompt KV being
 # written chunk by chunk) -> decoding (first token sampled, segments
@@ -310,6 +310,9 @@ class ContinuousBatchingEngine:
         self._watermark = wm
         self.sched = Scheduler(self.num_pages, ps, self.slots,
                                watermark=wm, policy=cfg.admission_policy)
+        # which twin serves, as the sched.* spans label it
+        self._sched_impl = ("python" if isinstance(self.sched, PyScheduler)
+                            else "native")
 
         # One extra scratch page (index num_pages): inactive/done slots
         # point their whole block table at it, so their masked lockstep
@@ -1891,12 +1894,22 @@ class ContinuousBatchingEngine:
         final chunk — the pre-PR8 one-shot wave."""
         chunk = self._chunk
         inter, final = {}, {}
+        tokens = 0
         for head, e in self._prefilling.items():
             remaining = len(e["ids"]) - e["off"]
             if chunk > 0 and remaining > chunk:
                 inter[head] = e
+                tokens += chunk
             else:
                 final[head] = e
+                tokens += remaining
+        with obs.span("engine.prefill_wave", rows=len(inter) + len(final),
+                      tokens=tokens, final=len(final)):
+            self._dispatch_prefill(inter, final, chunk, rng)
+        self._prefilling = {h: e for h, e in self._prefilling.items()
+                            if h not in final}
+
+    def _dispatch_prefill(self, inter, final, chunk, rng) -> None:
         if inter:
             nb = self._bucket(len(inter), self.slots)
             pps = self.pages_per_seq
@@ -1917,8 +1930,6 @@ class ContinuousBatchingEngine:
                     C=chunk)
         if final:
             self._activate(final, rng)
-        self._prefilling = {h: e for h, e in self._prefilling.items()
-                            if h not in final}
 
     def step(self) -> List[CompletedRequest]:
         """Run ONE wave of the standing service: harvest-lagged flag
@@ -1930,25 +1941,18 @@ class ContinuousBatchingEngine:
             raise ValueError("no sampling stream: call reset_rng() first")
         if self._state is None:
             self._state = self._init_state()
-        # One span per wave (no-op when tracing is off): the serving
-        # timeline's unit of work, nesting the prefill/segment
-        # dispatches and the req.* lifecycle instants.
+        # One span per wave (no-op when nothing records): the serving
+        # timeline's unit of work.  Its children name each layer
+        # boundary of the wave for what it is — host work (sched.admit,
+        # sched.extend), a dispatch (engine.prefill_wave,
+        # engine.segment: the host's enqueue, not the device's time) or
+        # a wait (engine.harvest: the thread blocks on the flag fetch).
         with obs.span("engine.step", pending=len(self._reqinfo)):
             return self._step_wave()
 
-    def _step_wave(self) -> List[CompletedRequest]:
-        self._early_out = []
-
-        # -- deferred aborts: a cancel that landed mid-chunked-prefill
-        #    is applied at this wave boundary (activation flipped the
-        #    request to decoding, where the preemption machinery can
-        #    free its pages safely) ---------------------------------------
-        for rid in list(self._cancels):
-            self._cancels.discard(rid)
-            if rid in self._reqinfo:
-                self.cancel(rid)
-
-        # -- admission (between jitted segments) ------------------------
+    def _admit(self) -> int:
+        """Scheduler admission plus the slot bookkeeping of every
+        admitted request; returns how many were admitted."""
         admitted = self.sched.admit()
         if (not admitted and not self.sched.running
                 and not self._prefilling and self.sched.waiting):
@@ -1983,6 +1987,24 @@ class ContinuousBatchingEngine:
                 e["slots"][j] = (rid, slot)
             else:
                 self._prefilling[head]["slots"][j] = (rid, slot)
+        return len(admitted)
+
+    def _step_wave(self) -> List[CompletedRequest]:
+        self._early_out = []
+
+        # -- deferred aborts: a cancel that landed mid-chunked-prefill
+        #    is applied at this wave boundary (activation flipped the
+        #    request to decoding, where the preemption machinery can
+        #    free its pages safely) ---------------------------------------
+        for rid in list(self._cancels):
+            self._cancels.discard(rid)
+            if rid in self._reqinfo:
+                self.cancel(rid)
+
+        # -- admission (between jitted segments) ------------------------
+        with obs.span("sched.admit", impl=self._sched_impl) as sp:
+            admitted = self._admit()
+            sp.set(admitted=admitted, waiting=int(self.sched.waiting))
 
         # -- host-tier spill: admission may have LRU-evicted cached
         #    pages; their KV is still intact ONLY until the prefill
@@ -2000,7 +2022,10 @@ class ContinuousBatchingEngine:
         spec_wave = self._spec_wave_decision()
 
         # -- on-demand reservation growth (may preempt) -----------------
-        self._extend_running(spec_wave)
+        with obs.span("sched.extend") as sp:
+            before = self.preemptions
+            self._extend_running(spec_wave)
+            sp.set(preempted=self.preemptions - before)
         # Extension evictions spill here, before the segment dispatch
         # below donates the pools.
         self._drain_spills()
@@ -2011,11 +2036,14 @@ class ContinuousBatchingEngine:
 
         # -- decode segment (fixed length: done slots idle in place,
         #    so no reservation-overrun risk) ----------------------------
-        if (self._phase == _DECODE).any():
+        decoding = self._phase == _DECODE
+        if decoding.any():
             self._rng, sub = jax.random.split(self._rng)
             if self._bt_dev is None:
                 self._bt_dev = jnp.asarray(self._bt)
-            with self._ctx():
+            with self._ctx(), obs.span(
+                    "engine.segment", live=int(decoding.sum()),
+                    spec_k=self._spec_k if spec_wave else 0):
                 if spec_wave:
                     self._pools, self._state = self._jit_spec_segment(
                         self._params, self._pools, self._bt_dev,
@@ -2276,9 +2304,16 @@ class ContinuousBatchingEngine:
         fetch the finished slots' completion rows, retire them with
         the scheduler (pages free here), and return the completions.
         Clears the pending snapshot."""
-        out: List[CompletedRequest] = []
         if self._pending_flags is None:
-            return out
+            return []
+        with obs.span("engine.harvest") as sp:
+            out = self._harvest_flags()
+            sp.set(finished=len(out),
+                   tokens=sum(len(c.tokens) for c in out))
+        return out
+
+    def _harvest_flags(self) -> List[CompletedRequest]:
+        out: List[CompletedRequest] = []
         pf = self._pending_flags
         self._pending_flags = None
         fetch = {k: pf[k]

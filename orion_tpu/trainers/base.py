@@ -437,9 +437,12 @@ class BaseTrainer:
         """One place for the device-vs-host reward dispatch (the
         wants_device_result contract) — used by make_experience,
         evaluate, and the async rollout loop."""
+        from orion_tpu import obs
+
         wants_device = getattr(self.reward_fn, "wants_device_result",
                                False)
-        return self.score(result if wants_device else host, meta)
+        with obs.span("reward.score", n=int(host.completion_lens.shape[0])):
+            return self.score(result if wants_device else host, meta)
 
     def score(self, result: GenerationResult, batch: dict) -> np.ndarray:
         """Sequence-level scores [B] as host f32.  ``result`` should be
@@ -531,11 +534,21 @@ class BaseTrainer:
         the generation (plus one scalar fetch for model-based rewards);
         any stats tree staged in ``self._pending_fetch`` (the deferred
         previous-iteration stats) rides the same fetch for free."""
-        ids, lens, meta = self.prepare_prompts(batch)
-        result = self.generate(
-            ids, lens, group_size=getattr(self.cfg, "group_size", 1))
+        from orion_tpu import obs
+
+        # Each span is named for what it is: a *dispatch* is the host's
+        # enqueue of asynchronous device work, the *fetch* is the one
+        # place this thread blocks on the device.
+        with obs.span("rollout.dispatch") as sp:
+            ids, lens, meta = self.prepare_prompts(batch)
+            sp.set(batch=int(ids.shape[0]), prompt_len=int(ids.shape[1]))
+            result = self.generate(
+                ids, lens, group_size=getattr(self.cfg, "group_size", 1))
         pend, self._pending_fetch = self._pending_fetch, None
-        fetched = jax.device_get({"r": result._fields(), "p": pend})
+        with obs.span("rollout.fetch") as sp:
+            fetched = jax.device_get({"r": result._fields(), "p": pend})
+            sp.set(bytes=sum(int(getattr(x, "nbytes", 0))
+                             for x in jax.tree.leaves(fetched)))
         if self._pending_meta is not None:
             # Finalize the previous iteration NOW — before this
             # iteration's build_experience reads kl_ctl.value — so the
@@ -546,7 +559,8 @@ class BaseTrainer:
                                      now=meta_p["t_next"])
         host = GenerationResult(**fetched["r"])
         scores = self._score_result(result, host, meta)
-        return self.build_experience(result, scores, host=host)
+        with obs.span("experience.dispatch"):
+            return self.build_experience(result, scores, host=host)
 
     def _epochs_fn(self, state: TrainState, experience, idx_mat):
         """All epochs×minibatches as ONE program: lax.scan threads the
@@ -748,8 +762,6 @@ class BaseTrainer:
         ``eval_iter``: held-out prompt stream for the cfg.eval_every
         evaluation loop (launch.py builds it from data.eval_split).
         """
-        import time
-
         from orion_tpu import obs
 
         if num_iterations is not None:
@@ -779,8 +791,7 @@ class BaseTrainer:
                 if preemption_requested():
                     if pending is not None:
                         fetched = jax.device_get(pending["dev"])
-                        self._finalize_iteration(pending, fetched,
-                                                 now=time.perf_counter())
+                        self._finalize_iteration(pending, fetched)
                         pending = None
                     if self.ckpt is not None:
                         self.save_checkpoint(prompt_iter,
@@ -788,66 +799,75 @@ class BaseTrainer:
                                              wait=True)
                     break
                 prof.step(it)
-                t0 = time.perf_counter()
-                batch = next(prompt_iter)
-                if pending is not None:
-                    self._pending_fetch = pending["dev"]
-                    # steady-state wall attribution: iteration i ends
-                    # where iteration i+1 begins.  make_experience
-                    # finalizes the pending iteration right after the
-                    # batched fetch (before build_experience reads the
-                    # KL coefficient).
-                    pending["t_next"] = t0
-                    self._pending_meta = pending
-                    pending = None
-                with guard_scope(self.cfg.transfer_guard), \
-                        jax.named_scope("experience"), \
-                        obs.span("experience", it=it):
-                    experience, exp_stats = self.make_experience(batch)
-                t1 = time.perf_counter()
-                with guard_scope(self.cfg.transfer_guard), \
-                        jax.named_scope("update"), \
-                        obs.span("update", it=it):
-                    upd_dev = self.update_epochs(experience, defer=True)
-                with obs.span("weight_sync"):
-                    self.sync_weights()
-                t2 = time.perf_counter()
-                self.global_iter += 1
-                pending = {
-                    "dev": {"exp": exp_stats, "upd": upd_dev},
-                    "n": int(experience["prompt_lens"].shape[0]),
-                    "it": it, "giter": self.global_iter,
-                    "t0": t0, "t1": t1, "t2": t2,
-                }
-                # Held-out eval on schedule (generates with the
-                # freshest weights — sync_weights already ran).  Eval
-                # runs BEFORE a same-step checkpoint so the saved eval
-                # cursor includes this step's eval — otherwise a resume
-                # replays it, and the resumed run's eval-reward series
-                # diverges from an uninterrupted one.
-                do_eval = self._should_eval(eval_iter)
-                do_ckpt = (self.ckpt is not None and
-                           self.global_iter % self.cfg.checkpoint_every
-                           == 0)
-                if (do_eval or do_ckpt) and pending is not None:
-                    # Materialize this iteration's stats first — the
-                    # logged series stays in order around evals
-                    # (ADVICE r4) and a checkpointed KL coefficient
-                    # includes this iteration's measured KL (identical
-                    # to the eager path).  Costs one extra fetch on
-                    # eval/checkpoint iterations only.
-                    fetched = jax.device_get(pending["dev"])
-                    self._finalize_iteration(pending, fetched,
-                                             now=time.perf_counter())
-                    pending = None
-                if do_eval:
-                    self._maybe_evaluate(eval_iter)
-                if do_ckpt:
-                    self.save_checkpoint(prompt_iter, eval_iter=eval_iter)
+                # Every stamp of a metrics row is the start or the end
+                # of a span (obs.timed measures with tracing off), so
+                # all differences are taken on one clock.  The batch
+                # fetch is a sibling BEFORE train.iteration, not its
+                # child: whoever starts or stops a profiler from inside
+                # the iterator then cuts this small span, and the
+                # window holds whole train.iteration spans.
+                with obs.timed("data.next_batch", it=it) as sp_data:
+                    batch = next(prompt_iter)
+                with obs.span("train.iteration", it=it):
+                    if pending is not None:
+                        self._pending_fetch = pending["dev"]
+                        # steady-state wall attribution: iteration i
+                        # ends where iteration i+1 begins.
+                        # make_experience finalizes the pending
+                        # iteration right after the batched fetch
+                        # (before build_experience reads the KL
+                        # coefficient).
+                        pending["t_next"] = sp_data.start
+                        self._pending_meta = pending
+                        pending = None
+                    with guard_scope(self.cfg.transfer_guard), \
+                            jax.named_scope("experience"), \
+                            obs.timed("experience", it=it) as sp_exp:
+                        experience, exp_stats = self.make_experience(batch)
+                    with guard_scope(self.cfg.transfer_guard), \
+                            jax.named_scope("update"), \
+                            obs.span("update", it=it):
+                        upd_dev = self.update_epochs(experience, defer=True)
+                    with obs.timed("weight_sync") as sp_sync:
+                        self.sync_weights()
+                    self.global_iter += 1
+                    pending = {
+                        "dev": {"exp": exp_stats, "upd": upd_dev},
+                        "n": int(experience["prompt_lens"].shape[0]),
+                        "it": it, "giter": self.global_iter,
+                        "t0": sp_data.start, "t1": sp_exp.end,
+                        "t2": sp_sync.end,
+                    }
+                    # Held-out eval on schedule (generates with the
+                    # freshest weights — sync_weights already ran).
+                    # Eval runs BEFORE a same-step checkpoint so the
+                    # saved eval cursor includes this step's eval —
+                    # otherwise a resume replays it, and the resumed
+                    # run's eval-reward series diverges from an
+                    # uninterrupted one.
+                    do_eval = self._should_eval(eval_iter)
+                    do_ckpt = (self.ckpt is not None and
+                               self.global_iter % self.cfg.checkpoint_every
+                               == 0)
+                    if do_eval or do_ckpt:
+                        # Materialize this iteration's stats first —
+                        # the logged series stays in order around evals
+                        # (ADVICE r4) and a checkpointed KL coefficient
+                        # includes this iteration's measured KL
+                        # (identical to the eager path).  Costs one
+                        # extra fetch on eval/checkpoint iterations
+                        # only.
+                        fetched = jax.device_get(pending["dev"])
+                        self._finalize_iteration(pending, fetched)
+                        pending = None
+                    if do_eval:
+                        self._maybe_evaluate(eval_iter)
+                    if do_ckpt:
+                        self.save_checkpoint(prompt_iter,
+                                             eval_iter=eval_iter)
             if pending is not None:  # flush the last iteration's stats
                 fetched = jax.device_get(pending["dev"])
-                self._finalize_iteration(pending, fetched,
-                                         now=time.perf_counter())
+                self._finalize_iteration(pending, fetched)
         except BaseException as e:
             # Forensics before the crash surfaces (no-op unless
             # cfg.obs armed the flight recorder).
@@ -894,33 +914,49 @@ class BaseTrainer:
             self.writer.write(self.global_iter, stats)
 
     def _finalize_iteration(self, pending: dict, fetched: dict,
-                            now: float) -> None:
+                            now: Optional[float] = None) -> None:
         """Materialize a deferred iteration's stats (host side): merge
         experience + update stats, run the KL-controller hook, log.
         ``samples_per_sec`` uses wall-clock up to *now* — in steady
-        state that is the next iteration's fetch completion, i.e. the
-        honest end-to-end rate including the deferred update's device
-        execution."""
+        state that is the next iteration's start, i.e. the honest
+        end-to-end rate including the deferred update's device
+        execution; a flush (the stats were just fetched) passes none
+        and the rate runs up to this call.  ``now`` and the stamps in
+        ``pending`` are starts and ends of obs spans: one clock.
+
+        ``host_experience_s`` / ``host_update_dispatch_s`` are HOST
+        times around asynchronous dispatch, named for that: the first
+        holds the batch fetch and the blocking generation fetch (during
+        which the previous update still runs on the device), the second
+        is the update's and the weight sync's enqueue.  Neither is a
+        phase's device time; that is read from a profiler trace."""
+        from orion_tpu import obs
+
         def scal(v):
             return float(np.mean(v)) if hasattr(v, "ndim") else v
 
-        stats = {k: scal(v) for k, v in fetched["upd"].items()}
-        stats.update({k: scal(v) for k, v in fetched["exp"].items()})
-        self._on_host_stats(stats, pending["n"])
-        stats.update({
-            "iteration": pending["it"],
-            "time_rollout_s": pending["t1"] - pending["t0"],
-            "time_update_s": pending["t2"] - pending["t1"],
-            "samples_per_sec": pending["n"] / max(now - pending["t0"], 1e-9),
-        })
-        self.metrics_history.append(stats)
-        if self.writer is not None:
-            # giter: the global counter at dispatch time — monotone
-            # across resumed runs (a loop-local index would rewrite
-            # steps 1..n of the metrics log after every resume).
-            self.writer.write(pending["giter"], stats)
-        if self.cfg.log_every and pending["it"] % self.cfg.log_every == 0:
-            self.log(stats)
+        with obs.timed("stats.finalize", it=pending["it"]) as sp:
+            if now is None:
+                now = sp.start
+            stats = {k: scal(v) for k, v in fetched["upd"].items()}
+            stats.update({k: scal(v) for k, v in fetched["exp"].items()})
+            self._on_host_stats(stats, pending["n"])
+            stats.update({
+                "iteration": pending["it"],
+                "host_experience_s": pending["t1"] - pending["t0"],
+                "host_update_dispatch_s": pending["t2"] - pending["t1"],
+                "samples_per_sec":
+                    pending["n"] / max(now - pending["t0"], 1e-9),
+            })
+            self.metrics_history.append(stats)
+            if self.writer is not None:
+                # giter: the global counter at dispatch time — monotone
+                # across resumed runs (a loop-local index would rewrite
+                # steps 1..n of the metrics log after every resume).
+                self.writer.write(pending["giter"], stats)
+            if self.cfg.log_every and \
+                    pending["it"] % self.cfg.log_every == 0:
+                self.log(stats)
 
     def log(self, stats: dict) -> None:
         keys = ("iteration", "reward_mean", "loss", "kl", "samples_per_sec")

@@ -77,6 +77,20 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _kernel_names(compiled) -> list:
+    """The compiled program's Pallas kernels by the name the profiler's
+    device events will carry: the HLO instruction's (``%flash_fwd.3 =
+    ... custom-call(...) custom_call_target="tpu_custom_call"`` ->
+    ``flash_fwd``), sorted, one entry per call."""
+    import re
+
+    return sorted(
+        re.sub(r"\.\d+$", "", m.group(1)) for m in re.finditer(
+            r"^\s*(?:ROOT )?%([\w.\-]+) = .*custom-call\(.*"
+            r'custom_call_target="tpu_custom_call"',
+            compiled.as_text(), re.M))
+
+
 # (B, Lq, Lk, H, Hkv, D, first q position)
 FLASH_SHAPES = {
     # the ppo1b update/experience shape (bench.py, chip_smoke.py)
@@ -116,6 +130,7 @@ def test_flash_fwd_compiles_for_v5e(name, one_chip, on_tpu):
     compiled = jax.jit(_flash(name)).lower(
         *_flash_args(name, one_chip)).compile()
     assert _kernel_calls(compiled) == 1
+    assert _kernel_names(compiled) == ["flash_fwd"]
 
 
 @pytest.mark.parametrize("name", ["pythia1b", "llama8b"])
@@ -127,8 +142,10 @@ def test_flash_fwd_bwd_compiles_for_v5e(name, one_chip, on_tpu):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *_flash_args(name, one_chip)).compile()
-    # forward + dq + dkv kernels
+    # forward + dq + dkv kernels, each under its own name
     assert _kernel_calls(compiled) == 3
+    assert _kernel_names(compiled) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                       "flash_fwd"]
 
 
 # The serving shape: B=48 slots, page_size 64, 288 pages (+1 scratch).
@@ -176,6 +193,7 @@ def test_paged_decode_compiles_for_v5e(width, quantized, one_chip,
     compiled = jax.jit(_paged_fn(paged_decode_attention, quantized,
                                  scale)).lower(*args).compile()
     assert _kernel_calls(compiled) == 1
+    assert _kernel_names(compiled) == ["paged_decode"]
 
 
 @pytest.mark.parametrize("quantized", [False, True],
@@ -204,6 +222,7 @@ def test_paged_decode_sharded_compiles_for_2x2(quantized, topo):
                                          *args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
+    assert _kernel_names(compiled) == ["paged_decode"]
     H, Hkv, D = PAGED_WIDTHS["pythia1b"]
     pool = f"[{PAGED['pages']},{Hkv},{PAGED['page_size']},{D}]"
     gathers = [ln for ln in text.splitlines() if "all-gather" in ln]
@@ -264,6 +283,8 @@ def test_pythia1b_decode_segment_compiles_for_v5e(one_chip, on_tpu):
     # one paged-decode kernel call per layer program (layers unrolled
     # or scanned — at least one call either way)
     assert _kernel_calls(compiled) >= 1
+    names = _kernel_names(compiled)
+    assert names and set(names) == {"paged_decode"}, names
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)

@@ -2,8 +2,9 @@
 Perfetto-schema validity, cross-process trace stitching over a real
 pool, flight-recorder dumps (worker death, degrade, injected fault,
 SIGUSR1), histogram percentile math, MetricsWriter lifecycle, the
-continuous engine's request telemetry, and the disabled-tracing
-overhead budget."""
+continuous engine's request telemetry, the disabled-tracing overhead
+budget, and (ISSUE 25) the bridge to the profiler: every span is also a
+``jax.profiler.TraceAnnotation`` while a profiler session records."""
 
 import glob
 import json
@@ -91,6 +92,10 @@ def test_chrome_export_is_valid_trace_event_json(tmp_path):
 
 def test_disabled_span_is_a_shared_noop_but_timed_measures():
     t = Tracer(ring_size=16, enabled=False)
+    # The off path: tracer disabled AND no profiler session recording
+    # (under one, the same call opens a profiler annotation — see
+    # test_disabled_span_reaches_the_profiler_trace).
+    assert not jax.profiler.TraceAnnotation.is_enabled()
     assert t.span("a") is t.span("b")  # allocation-free singleton
     with t.span("a") as sp:
         pass
@@ -100,6 +105,142 @@ def test_disabled_span_is_a_shared_noop_but_timed_measures():
     assert sp.duration >= 0.005  # measured even with tracing off
     assert t.events() == []      # ...but nothing recorded
     assert t.context() == (0, 0)
+
+
+# ---------------------------------------------------------------------------
+# the bridge to the profiler (ISSUE 25): spans on the device trace's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled(tmp_path, body):
+    """Run ``body()`` under a jax.profiler session (Python's own call
+    tracer off, as the benchmark's traced run has it) and return the
+    host events of the xplane: {name: [(thread, start, end, stats)]}."""
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                events.setdefault(e.name, []).append(
+                    (i, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats)))
+    return events
+
+
+def _program_spans(t):
+    with t.span("outer", it=3, tag="abc"):
+        with t.span("inner") as sp:
+            time.sleep(0.002)
+            sp.set(rows=2, bytes=1234)   # known only when the work is done
+        with t.timed("lap") as lap:
+            pass
+    assert lap.duration >= 0.0
+
+
+def test_disabled_span_reaches_the_profiler_trace(tmp_path):
+    """obs.trace off: the ring stays empty, yet a span opened while a
+    profiler session records lies in the /host:CPU plane with its name,
+    nesting and attributes — the program finds out by itself."""
+    t = Tracer(ring_size=16, enabled=False)
+    before = t.span("not-yet")          # no session yet: the singleton
+    events = _profiled(tmp_path, lambda: _program_spans(t))
+    assert before is t.span("again")    # and the singleton again after
+    assert t.events() == []
+    (th_o, o0, o1, o_stats), = events["outer"]
+    (th_i, i0, i1, i_stats), = events["inner"]
+    (th_l, l0, l1, _), = events["lap"]
+    assert th_o == th_i == th_l                     # one thread's line
+    assert o0 <= i0 and i1 <= l0 and l1 <= o1       # nested, in order
+    assert i1 - i0 >= 1e6                           # the 2 ms sleep, in ns
+    assert o_stats == {"it": 3, "tag": "abc"}
+    assert i_stats == {"rows": 2, "bytes": 1234}
+
+
+def test_enabled_span_is_in_the_ring_and_in_the_profiler_trace(tmp_path):
+    t = Tracer(ring_size=16, enabled=True)
+    events = _profiled(tmp_path, lambda: _program_spans(t))
+    ring = {e["name"]: e for e in t.events()}
+    assert set(ring) == {"outer", "inner", "lap"}
+    assert ring["inner"]["parent"] == ring["outer"]["span"]
+    assert ring["inner"]["attrs"] == {"rows": 2, "bytes": 1234}
+    assert set(events) >= {"outer", "inner", "lap"}
+    (_, _, _, stats), = events["inner"]
+    assert stats == {"rows": 2, "bytes": 1234}
+    # the two records agree on the duration (different clocks, one scope)
+    (_, i0, i1, _), = events["inner"]
+    assert abs((i1 - i0) / 1e9 - ring["inner"]["dur"]) < 1e-3
+
+
+def test_no_session_no_ring_event_and_the_off_path_budget():
+    """Tracer off and nothing recording: no event anywhere, and a span
+    costs under the microsecond that obs/trace.py's docstring states
+    (best of five loops; the machine is shared)."""
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    prev = obs.set_tracer(None)     # the disabled default
+    try:
+        t = obs.get_tracer()
+        assert not t.enabled
+        n = 100_000
+        best = float("inf")
+        for _ in range(5):
+            sp = obs.timed("budget-window")
+            with sp:
+                for _ in range(n):
+                    with obs.span("x", a=1):
+                        pass
+            best = min(best, sp.duration / n)
+        assert t.events() == []
+        assert best < 1e-6, best
+    finally:
+        obs.set_tracer(prev)
+
+
+def test_span_start_and_end_share_one_clock():
+    """The trainer loop's stamps are starts and ends of spans."""
+    t = Tracer(ring_size=4, enabled=False)
+    with t.timed("a") as a:
+        time.sleep(0.002)
+    with t.timed("b") as b:
+        pass
+    assert a.end == pytest.approx(a.start + a.duration)
+    assert a.start < a.end <= b.start <= b.end
+
+
+def test_session_end_writes_the_ring_once(tmp_path):
+    """obs.trace on and a directory: the spans kept in memory are
+    written as spans-<pid>.json when the session ends (trainer.close(),
+    run_serve's exit), once."""
+    cfg = _mk(GRPOConfig, log_dir=str(tmp_path / "m"))
+    cfg.obs.trace = True
+    session = obs.install_from_config(cfg)
+    try:
+        with obs.span("experience", it=0):
+            obs.instant("tick")
+    finally:
+        session.uninstall()
+    path = session.spans_path
+    assert path == str(tmp_path / "m" / f"spans-{os.getpid()}.json")
+    with open(path) as f:
+        doc = json.load(f)
+    names = [e["name"] for e in doc["traceEvents"] if e["ph"] != "M"]
+    assert names == ["tick", "experience"]
+    os.remove(path)
+    session.uninstall()              # idempotent: not written again
+    assert not os.path.exists(path)
+    assert not obs.get_tracer().enabled
 
 
 # ---------------------------------------------------------------------------
