@@ -27,7 +27,7 @@ class ModelConfig:
     attention+MLP residual, partial rotary — Pythia-1B).
     """
 
-    arch: str = "llama"  # "llama" | "neox"
+    arch: str = "llama"  # "llama" | "neox" | "deepseek_v3"
     vocab_size: int = 32000
     hidden_size: int = 512
     intermediate_size: int = 1376
@@ -67,14 +67,71 @@ class ModelConfig:
     # Weight of the Switch load-balance auxiliary loss (consumed by the
     # trainer loss paths via BaseTrainer._logprobs_fn's aux output).
     router_aux_coef: float = 0.01
+    # arch="deepseek_v3" (models/transformer.py: latent attention, a
+    # dropless sigmoid-routed expert layer after first_k_dense_replace
+    # dense layers), under the published key names.  The keys above
+    # keep their meaning: intermediate_size is the dense layers' width,
+    # num_experts stays 0 (that is the GShard layer's switch).
+    kv_lora_rank: int = 0          # width of the cached latent
+    qk_nope_head_dim: int = 0      # per-head key width without rotary
+    qk_rope_head_dim: int = 0      # the one rotary key all heads share
+    v_head_dim: int = 0
+    n_routed_experts: int = 0      # the router's width: ALL experts
+    num_experts_per_tok: int = 0
+    n_shared_experts: int = 0      # one SwiGLU of n x moe_intermediate_size
+    moe_intermediate_size: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
+    # The chip's share under expert parallelism: this layer holds
+    # experts_held consecutive experts from expert_offset on (0 held =>
+    # all of them).  The router still scores and selects over all
+    # n_routed_experts; what the absent experts would add is left out.
+    experts_held: int = 0
+    expert_offset: int = 0
 
     def __post_init__(self) -> None:
+        if self.arch == "deepseek_v3":
+            self._check_deepseek_v3()
         if self.head_dim == 0:
             self.head_dim = self.hidden_size // self.num_heads
         if self.arch == "neox":
             # GPT-NeoX has no GQA.  (use_parallel_residual stays as
             # given — NeoX-family checkpoints exist with either value.)
             self.num_kv_heads = self.num_heads
+
+    def _check_deepseek_v3(self) -> None:
+        for key in ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                    "v_head_dim", "n_routed_experts", "num_experts_per_tok",
+                    "moe_intermediate_size"):
+            if getattr(self, key) <= 0:
+                raise ValueError(f"arch='deepseek_v3' needs model.{key} > 0")
+        self.num_kv_heads = self.num_heads
+        if self.head_dim == 0:
+            self.head_dim = self.qk_rope_head_dim   # as published
+        if self.experts_held == 0:
+            self.experts_held = self.n_routed_experts
+        if not (0 <= self.expert_offset and self.expert_offset
+                + self.experts_held <= self.n_routed_experts):
+            raise ValueError(
+                f"experts {self.expert_offset}..+{self.experts_held} are "
+                f"not among the {self.n_routed_experts} routed experts")
+        if not 0 <= self.first_k_dense_replace <= self.num_layers:
+            raise ValueError("first_k_dense_replace outside 0..num_layers")
+        if self.attention_impl in ("ring", "ulysses"):
+            raise ValueError(
+                f"attention_impl={self.attention_impl!r} cannot run "
+                "arch='deepseek_v3': the sequence-parallel attentions "
+                "exchange per-head K/V of one head_dim, and there is no "
+                "exchange of the latent (c, k_rope) yet")
+        if self.num_experts or self.quantize_dense or self.tie_word_embeddings:
+            raise ValueError(
+                "arch='deepseek_v3' has its own expert layer (num_experts "
+                "is the GShard layer's), no int8 Dense twin and an untied "
+                "head")
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.arch == "deepseek_v3"
 
     @staticmethod
     def llama3_8b() -> "ModelConfig":
@@ -104,8 +161,40 @@ class ModelConfig:
         )
 
     @staticmethod
+    def kanana_2_30b_a3b() -> "ModelConfig":
+        """kakaocorp/kanana-2-30b-a3b-instruct-2601 as published
+        (config.json, model_type deepseek_v3): every expert held."""
+        return ModelConfig(
+            arch="deepseek_v3", vocab_size=128256, hidden_size=2048,
+            intermediate_size=6144, num_layers=48, num_heads=32,
+            max_seq_len=32768, rope_theta=1000000.0, rms_norm_eps=1e-6,
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, n_routed_experts=128, num_experts_per_tok=6,
+            n_shared_experts=2, moe_intermediate_size=768,
+            first_k_dense_replace=1, routed_scaling_factor=2.448,
+        )
+
+    @staticmethod
+    def tiny_deepseek_v3() -> "ModelConfig":
+        """``model_preset=tiny_deepseek_v3``: the small sibling of
+        kanana_2_30b_a3b (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("deepseek_v3")
+
+    @staticmethod
     def tiny(arch: str = "llama", **kw: Any) -> "ModelConfig":
         """Small config for tests (runs on CPU in <1s)."""
+        if arch == "deepseek_v3":
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=96, num_layers=3, num_heads=4,
+                max_seq_len=128, rms_norm_eps=1e-6, kv_lora_rank=16,
+                qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+                n_routed_experts=8, num_experts_per_tok=2,
+                n_shared_experts=2, moe_intermediate_size=32,
+                first_k_dense_replace=1, routed_scaling_factor=2.448,
+            )
+            base.update(kw)
+            return ModelConfig(**base)
         base = dict(
             arch=arch, vocab_size=256, hidden_size=64,
             intermediate_size=128, num_layers=2, num_heads=4,

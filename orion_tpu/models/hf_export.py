@@ -36,6 +36,11 @@ def hf_state_dict(params: dict, cfg: ModelConfig) -> Dict[str, np.ndarray]:
         raise ValueError(
             "HF export of MoE models is not supported: the expert-"
             "stacked MLP (ops.moe) has no llama/neox HF layout")
+    if cfg.arch == "deepseek_v3":
+        raise ValueError(
+            "HF export of arch='deepseek_v3' is not written: there is no "
+            "deepseek_v3 checkpoint layout (per-expert gate/up/down "
+            "tensors, kv_a/kv_b projections) on either side yet")
     params = dict(params)
     if "backbone" in params:  # ActorCriticModel / ScalarHeadModel tree
         params = dict(params["backbone"])

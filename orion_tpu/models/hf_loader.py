@@ -46,12 +46,21 @@ def _lin(w: Any, bias: Any = None) -> Dict[str, np.ndarray]:
     return out
 
 
+_NO_DSV3_LOADER = (
+    "there is no deepseek_v3 checkpoint loader yet: the per-expert "
+    "gate/up/down tensors have to be stacked over the experts held "
+    "(gate and up fused), and kv_a_proj_with_mqa / kv_b_proj mapped; "
+    "arch='deepseek_v3' runs from random weights only")
+
+
 def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
                           include_lm_head: bool = True) -> dict:
     if cfg.arch == "llama":
         p = _convert_llama(sd, cfg)
     elif cfg.arch == "neox":
         p = _convert_neox(sd, cfg)
+    elif cfg.arch == "deepseek_v3":
+        raise ValueError(_NO_DSV3_LOADER)
     else:
         raise ValueError(cfg.arch)
     if not include_lm_head:
@@ -203,6 +212,8 @@ def load_hf_scalar_model(path: str, cfg: ModelConfig) -> dict:
 def config_from_hf(hf_cfg: Any) -> ModelConfig:
     """Build a ModelConfig from a transformers config object."""
     mt = getattr(hf_cfg, "model_type", "")
+    if mt == "deepseek_v3":
+        raise ValueError(_NO_DSV3_LOADER)
     if mt == "llama":
         return ModelConfig(
             arch="llama",

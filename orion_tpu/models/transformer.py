@@ -7,6 +7,12 @@ requires (SURVEY.md §2 #14):
   (Llama-3 family).
 - ``arch="neox"``: LayerNorm with bias, parallel attention+MLP residual,
   partial rotary (``rotary_pct``), biased projections (Pythia family).
+- ``arch="deepseek_v3"``: pre-norm RMSNorm, latent attention
+  (:class:`LatentAttention`), a SwiGLU MLP in the first
+  ``first_k_dense_replace`` layers and the dropless expert layer
+  (``ops.moe.SigmoidTopKMoE``) after them; no biases, untied head.
+  Under ``scan_layers`` the leading dense layers stay outside the
+  scanned stack of expert layers.
 
 Design notes (TPU-first):
 - Params are annotated with *logical* axes via flax logical
@@ -29,13 +35,16 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.config import ModelConfig
-from orion_tpu.ops.attention import attention
+from orion_tpu.ops.attention import _NEG_INF, attention
 from orion_tpu.ops.paged_kv import is_paged, write_paged_tokens
 from orion_tpu.ops.rotary import apply_rotary
 
 # Unrolled models: per-layer list of {"k": [B,L,Hkv,D], "v": ...}.
 # scan_layers models: ONE stacked dict {"k": [N,B,L,Hkv,D], "v": ...}
 # scanned over axis 0 (likewise for the paged-cache pytrees).
+# deepseek_v3: a layer caches {"c": [B,L,kv_lora_rank], "k_rope":
+# [B,L,qk_rope_head_dim]}; scan_layers models {"dense": [per layer],
+# "layers": stacked} (the leading dense layers are not in the stack).
 KVCache = Any
 
 _dt = lambda s: jnp.dtype(s)  # noqa: E731
@@ -101,7 +110,7 @@ def _dense(features, axes, use_bias, cfg, name):
 
 
 def _norm(cfg, name):
-    if cfg.arch == "llama":
+    if cfg.arch in ("llama", "deepseek_v3"):
         return nn.RMSNorm(
             epsilon=cfg.rms_norm_eps, dtype=_dt(cfg.dtype),
             param_dtype=_dt(cfg.param_dtype),
@@ -116,6 +125,34 @@ def _norm(cfg, name):
         bias_init=nn.with_logical_partitioning(
             nn.initializers.zeros_init(), ("norm",)),
         name=name)
+
+
+def _cache_writer(positions, B: int, L: int):
+    """``write(cache, new)``: the L new entries of every sequence at
+    slots starting at ``positions[:, 0]``."""
+    starts = positions[:, 0]
+    if L == 1:
+        # Decode: ONE batched scatter with unique indices.  The
+        # vmap(dynamic_update_slice) form lowers to a serial
+        # scatter-WHILE per array on TPU — profiled at 5.2 ms of
+        # a 7.6 ms decode step (32 nested whiles + 1024 per-
+        # element fusions per step) vs ~0 for this scatter.
+        bidx = jnp.arange(B)
+
+        def write(cache, new):
+            return cache.at[bidx, starts].set(
+                new[:, 0], unique_indices=True)
+    else:
+        # Prefill writes an L-token block per sequence; runs
+        # once per generate, where the slice form is fine.
+        def write(cache, new):
+            # vmap strips the batch dim: per-sequence slices
+            # index (start, 0, ...) over new.ndim-1 dims.
+            zeros = (0,) * (new.ndim - 2)
+            return jax.vmap(
+                lambda c, t, i: jax.lax.dynamic_update_slice(
+                    c, t, (i,) + zeros))(cache, new, starts)
+    return write
 
 
 class Attention(nn.Module):
@@ -173,29 +210,7 @@ class Attention(nn.Module):
                 from orion_tpu.ops.paged_kv import gather_paged_kv
                 keys, values = gather_paged_kv(new_cache, _dt(cfg.dtype))
         elif layer_cache is not None:
-            starts = positions[:, 0]
-
-            if L == 1:
-                # Decode: ONE batched scatter with unique indices.  The
-                # vmap(dynamic_update_slice) form lowers to a serial
-                # scatter-WHILE per array on TPU — profiled at 5.2 ms of
-                # a 7.6 ms decode step (32 nested whiles + 1024 per-
-                # element fusions per step) vs ~0 for this scatter.
-                bidx = jnp.arange(B)
-
-                def write(cache, new):
-                    return cache.at[bidx, starts].set(
-                        new[:, 0], unique_indices=True)
-            else:
-                # Prefill writes an L-token block per sequence; runs
-                # once per generate, where the slice form is fine.
-                def write(cache, new):
-                    # vmap strips the batch dim: per-sequence slices
-                    # index (start, 0, ...) over new.ndim-1 dims.
-                    zeros = (0,) * (new.ndim - 2)
-                    return jax.vmap(
-                        lambda c, t, i: jax.lax.dynamic_update_slice(
-                            c, t, (i,) + zeros))(cache, new, starts)
+            write = _cache_writer(positions, B, L)
 
             if "k_scale" in layer_cache:
                 # int8 KV cache (RolloutConfig.quantize_kv): quantize
@@ -261,13 +276,118 @@ class Attention(nn.Module):
         return out, new_cache
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (deepseek_v3, ``q_lora_rank: null``).
+
+    ``q = h W_q`` per head is ``[q_nope ; q_rope]``; ``h W_kva`` is
+    ``[c_raw ; k_rope_raw]``, ``c = RMSNorm(c_raw)``; ``c W_kvb`` per
+    head is ``[k_nope ; v]``.  Rotary on ``q_rope`` of every head and on
+    the one ``k_rope`` all heads share, the rotary features stored as
+    adjacent pairs and brought to the half-split layout first
+    (``rope_interleave``).  Scores ``q . [k_nope ; k_rope] /
+    sqrt(nope + rope)``.
+
+    The cache holds ``c`` and the rotated ``k_rope`` of a token, nothing
+    per head.  Two paths compute the same attention:
+
+    - **expand** (training; prefill and any step longer than one token):
+      keys and values of every head are formed from ``c`` (of the whole
+      cache, when there is one: slot == position as everywhere) and go
+      through ``ops.attention`` (flash on the TPU);
+    - **absorb** (one new token against the cache): with ``W_kvb``
+      split per head into ``W_uk`` and ``W_uv``, ``score = (q_nope
+      W_uk^T) . c + q_rope . k_rope`` and ``o = (P c) W_uv``, so no
+      per-head key or value of the context is ever formed.
+    """
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, R = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        if is_paged(layer_cache) or (layer_cache is not None
+                                     and "c" not in layer_cache):
+            raise ValueError(
+                "latent attention caches {'c', 'k_rope'} (init_cache); "
+                "there is no paged or int8 latent cache yet")
+        scale = 1.0 / (dn + dr) ** 0.5
+
+        q = _dense(H * (dn + dr), ("embed", "heads"), False, cfg,
+                   "q_proj")(x).reshape(B, L, H, dn + dr)
+        kva = _dense(R + dr, ("embed", "latent"), False, cfg,
+                     "kv_a_proj_with_mqa")(x)
+        c = nn.RMSNorm(
+            epsilon=cfg.rms_norm_eps, dtype=_dt(cfg.dtype),
+            param_dtype=_dt(cfg.param_dtype),
+            scale_init=nn.with_logical_partitioning(
+                nn.initializers.ones_init(), ("norm",)),
+            name="kv_a_norm")(kva[..., :R])
+        # a bare kernel, not a Dense: the absorbed path applies it to
+        # the query and the output, split per head
+        w_kvb = self.param(
+            "kv_b_proj", nn.with_logical_partitioning(
+                nn.initializers.normal(stddev=0.02), ("latent", "heads")),
+            (R, H * (dn + dv)), _dt(cfg.param_dtype)).astype(_dt(cfg.dtype))
+
+        def halves(t):   # adjacent pairs -> half-split (rope_interleave)
+            return jnp.concatenate([t[..., 0::2], t[..., 1::2]], axis=-1)
+
+        q_nope, q_rope = q[..., :dn], halves(q[..., dn:])
+        k_rope = halves(kva[..., R:])[:, :, None, :]          # one "head"
+        q_rope, k_rope = apply_rotary(q_rope, k_rope, positions, dr,
+                                      cfg.rope_theta)
+        k_rope = k_rope[:, :, 0, :]
+
+        new_cache = None
+        if layer_cache is not None:
+            write = _cache_writer(positions, B, L)
+            new_cache = {"c": write(layer_cache["c"], c),
+                         "k_rope": write(layer_cache["k_rope"], k_rope)}
+            c, k_rope = new_cache["c"], new_cache["k_rope"]
+        key_slots = jnp.arange(c.shape[1], dtype=positions.dtype)
+        mask = key_slots[None, None, :] <= positions[:, :, None]
+
+        if layer_cache is not None and L == 1:
+            with jax.named_scope("mla.absorb"):
+                w = w_kvb.reshape(R, H, dn + dv)
+                q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0],
+                                   w[..., :dn])
+                scores = (jnp.einsum("bhr,blr->bhl", q_lat, c,
+                                     preferred_element_type=jnp.float32)
+                          + jnp.einsum("bhd,bld->bhl", q_rope[:, 0], k_rope,
+                                       preferred_element_type=jnp.float32)
+                          ) * scale
+                scores = jnp.where(mask, scores, _NEG_INF)
+                probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+                o_lat = jnp.einsum("bhl,blr->bhr", probs, c)
+                out = jnp.einsum("bhr,rhd->bhd", o_lat,
+                                 w[..., dn:])[:, None]
+        else:
+            with jax.named_scope("mla.expand"):
+                kv = jnp.dot(c, w_kvb).reshape(B, c.shape[1], H, dn + dv)
+                k = jnp.concatenate(
+                    [kv[..., :dn],
+                     jnp.broadcast_to(k_rope[:, :, None, :],
+                                      kv.shape[:3] + (dr,))], axis=-1)
+                qf = jnp.concatenate([q_nope, q_rope], axis=-1)
+            out = attention(qf, k, kv[..., dn:], mask, scale=scale,
+                            impl=cfg.attention_impl, q_positions=positions)
+        out = out.reshape(B, L, H * dv)
+        return _dense(cfg.hidden_size, ("heads", "embed"), False, cfg,
+                      "o_proj")(out), new_cache
+
+
 class MLP(nn.Module):
     cfg: ModelConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        if cfg.arch == "llama":
+        if cfg.arch in ("llama", "deepseek_v3"):
             gate = _dense(cfg.intermediate_size, ("embed", "mlp"),
                           cfg.mlp_bias, cfg, "gate_proj")(x)
             up = _dense(cfg.intermediate_size, ("embed", "mlp"),
@@ -315,6 +435,26 @@ class Block(nn.Module):
         return (sp(h + mlp_out) if sp else h + mlp_out), new_cache
 
 
+class LatentBlock(nn.Module):
+    """deepseek_v3 block: ``a = x + Attn(N1(x))``, ``y = a + FFN(N2(a))``,
+    FFN the SwiGLU MLP (``dense``) or the expert layer."""
+
+    cfg: ModelConfig
+    dense: bool = False
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None, token_mask=None):
+        cfg = self.cfg
+        attn_out, new_cache = LatentAttention(cfg, name="attn")(
+            _norm(cfg, "input_norm")(x), positions, layer_cache)
+        h = x + attn_out
+        z = _norm(cfg, "post_attn_norm")(h)
+        if self.dense:
+            return h + MLP(cfg, name="mlp")(z), new_cache
+        from orion_tpu.ops.moe import SigmoidTopKMoE
+        return h + SigmoidTopKMoE(cfg, name="mlp")(z, token_mask), new_cache
+
+
 class Transformer(nn.Module):
     """Backbone + LM head.
 
@@ -328,11 +468,14 @@ class Transformer(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions, cache: Optional[KVCache] = None,
                  return_hidden: bool = False, skip_lm_head: bool = False,
-                 logits_positions: Optional[jnp.ndarray] = None):
+                 logits_positions: Optional[jnp.ndarray] = None,
+                 token_mask: Optional[jnp.ndarray] = None):
         """``logits_positions`` [B, T]: compute the vocab projection only
         at these sequence positions (ops.logprobs.completion_window_
         positions) — logits come back [B, T, V].  ``return_hidden``
-        always returns the FULL [B, L, E] hidden states."""
+        always returns the FULL [B, L, E] hidden states.
+        ``token_mask`` [B, L] bool (deepseek_v3 only): which positions
+        hold a token; the expert layers route the others nowhere."""
         cfg = self.cfg
         embed = nn.Embed(
             num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
@@ -342,9 +485,24 @@ class Transformer(nn.Module):
             name="embed")
         x = embed(input_ids)
 
-        block_cls = Block
+        block_cls = LatentBlock if cfg.latent_attention else Block
         if cfg.remat:
-            block_cls = nn.remat(Block, static_argnums=())
+            block_cls = nn.remat(block_cls, static_argnums=())
+        # deepseek_v3: the leading dense layers, layers_0.. in both
+        # layouts, stand outside the scanned stack of expert layers.
+        n_lead = cfg.first_k_dense_replace if cfg.latent_attention else 0
+        more = () if token_mask is None else (token_mask,)
+        lead_cache = None
+        if n_lead:
+            if cache is not None:
+                lead_cache = (cache["dense"] if cfg.scan_layers
+                              else cache[:n_lead])
+            new_lead = []
+            for i in range(n_lead):
+                x, c_i = block_cls(cfg, dense=True, name=f"layers_{i}")(
+                    x, positions,
+                    lead_cache[i] if lead_cache is not None else None)
+                new_lead.append(c_i)
 
         if cfg.scan_layers:
             # One Block traced once, lax.scan over a stacked param tree
@@ -362,21 +520,26 @@ class Transformer(nn.Module):
                 # scan_layers with no error.
                 variable_axes={"params": 0, "intermediates": 0},
                 split_rngs={"params": True},
-                in_axes=(nn.broadcast, 0),
+                in_axes=(nn.broadcast, 0) + (nn.broadcast,) * len(more),
                 out_axes=0,
-                length=cfg.num_layers,
+                length=cfg.num_layers - n_lead,
                 metadata_params={nn.meta.PARTITION_NAME: "layers"},
             )
             x, new_cache = scan_block(cfg, name="layers")(
-                x, positions, cache)
+                x, positions, cache["layers"] if lead_cache is not None
+                else cache, *more)
             if cache is None:
                 new_cache = None
+            elif n_lead:
+                new_cache = {"dense": new_lead, "layers": new_cache}
         else:
-            new_cache = [] if cache is not None else None
-            for i in range(cfg.num_layers):
+            new_cache = None
+            if cache is not None:
+                new_cache = list(new_lead) if n_lead else []
+            for i in range(n_lead, cfg.num_layers):
                 layer_cache = cache[i] if cache is not None else None
                 x, new_layer_cache = block_cls(cfg, name=f"layers_{i}")(
-                    x, positions, layer_cache)
+                    x, positions, layer_cache, *more)
                 if new_cache is not None:
                     new_cache.append(new_layer_cache)
 
@@ -421,6 +584,25 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     # verify chunk).  Slots carry the slot==position causal rule, so
     # the padded tail is masked for every real query.
     max_len = -(-max_len // 8) * 8
+    if cfg.latent_attention:
+        if quantized:
+            raise ValueError(
+                "there is no int8 latent cache (rollout.quantize_kv) for "
+                "arch='deepseek_v3' yet: ops/quant.py scales per head")
+
+        def latent(pre=()):
+            return {"c": jnp.zeros(pre + (batch, max_len, cfg.kv_lora_rank),
+                                   dtype),
+                    "k_rope": jnp.zeros(
+                        pre + (batch, max_len, cfg.qk_rope_head_dim), dtype)}
+
+        n_lead = cfg.first_k_dense_replace
+        if cfg.scan_layers and n_lead:
+            return {"dense": [latent() for _ in range(n_lead)],
+                    "layers": latent((cfg.num_layers - n_lead,))}
+        if cfg.scan_layers:
+            return latent((cfg.num_layers,))
+        return [latent() for _ in range(cfg.num_layers)]
     shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
 
     def layer(pre=()):
@@ -458,7 +640,8 @@ def maybe_unstack_for_decode(params: Any, cfg: ModelConfig):
     constant-index slices XLA fuses); identity for unrolled models."""
     if not cfg.scan_layers:
         return params
-    return unstack_params_tree(params, cfg.num_layers)
+    n_lead = cfg.first_k_dense_replace if cfg.latent_attention else 0
+    return unstack_params_tree(params, cfg.num_layers - n_lead, n_lead)
 
 
 def prep_decode_params(params: Any, cfg: ModelConfig,
@@ -482,11 +665,12 @@ def prep_decode_params(params: Any, cfg: ModelConfig,
     return params
 
 
-def unstack_params_tree(params: Any, num_layers: int):
+def unstack_params_tree(params: Any, num_layers: int, first: int = 0):
     """jit-safe inverse of the scan_layers stacking: every subtree
-    holding a stacked "layers" entry [L, ...] becomes layers_0..L-1
-    subtrees (recursing through wrappers like ActorCriticModel's
-    "backbone").  XLA lowers the constant-index slices to views/copies
+    holding a stacked "layers" entry [L, ...] becomes
+    layers_<first>..<first+L-1> subtrees (recursing through wrappers
+    like ActorCriticModel's "backbone"; ``first`` > 0 where leading
+    layers already stand unstacked beside the stack).  XLA lowers the constant-index slices to views/copies
     it can fuse — used by the rollout engine to decode with an
     unrolled model twin (the stacked cache carried through nn.scan
     costs ~2x decode time; see RolloutEngine)."""
@@ -496,9 +680,10 @@ def unstack_params_tree(params: Any, num_layers: int):
     for k, v in params.items():
         if k == "layers":
             for i in range(num_layers):
-                out[f"layers_{i}"] = jax.tree.map(lambda x: x[i], v)
+                out[f"layers_{first + i}"] = jax.tree.map(
+                    lambda x: x[i], v)
         elif isinstance(v, dict):
-            out[k] = unstack_params_tree(v, num_layers)
+            out[k] = unstack_params_tree(v, num_layers, first)
         else:
             out[k] = v
     return out

@@ -14,6 +14,9 @@ kernels.  Design:
   footprint is therefore O(block), not O(L) — long-context safe.
 - GQA via BlockSpec index maps (``h // n_rep``) — no materialized
   ``repeat_kv``.
+- The value width ``Dv`` may differ from the query/key width ``D``
+  (latent attention expands keys of 192 and values of 128): v, o, dO
+  and dV blocks are ``Dv`` wide, q, k, dQ and dK blocks ``D`` wide.
 - Masking is positional, matching the model's semantics exactly
   (models/transformer.py Attention): query at absolute position p
   attends to the KV at absolute position j iff ``j <= p``.  KV
@@ -165,7 +168,7 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
     """qt [B,H,Lq,D], kt/vt [B,Hkv,Lk,D], qpos3 [B,Lq,1], kvpos3
     [B,1,Lk].  clamp=True enables the contiguous-path fetch clamps."""
     B, H, Lq, D = qt.shape
-    Hkv, Lk = kt.shape[1], kt.shape[2]
+    Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
     bq = _pick_block(Lq, blk_q)
     bkv = _pick_block(Lk, blk_kv)
@@ -201,10 +204,10 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
             + [pl.BlockSpec((1, 1, bq, D),
                             lambda b, h, i, j, qm, im, km: (b, h, i, 0)),
                pl.BlockSpec((1, 1, bkv, D), kv_map),
-               pl.BlockSpec((1, 1, bkv, D), kv_map)]
+               pl.BlockSpec((1, 1, bkv, Dv), kv_map)]
         ),
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D),
+            pl.BlockSpec((1, 1, bq, Dv),
                          lambda b, h, i, j, qm, im, km: (b, h, i, 0)),
             pl.BlockSpec((1, 1, bq, 1),
                          lambda b, h, i, j, qm, im, km: (b, h, i, 0)),
@@ -212,7 +215,7 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),   # running sumexp
-            pltpu.VMEM((bq, D), jnp.float32),   # running accumulator
+            pltpu.VMEM((bq, Dv), jnp.float32),  # running accumulator
         ],
     )
     operands = [qmax, imin, kvmin, qpos3]
@@ -225,7 +228,7 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
                           use_kvpos=use_kvpos),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+            jax.ShapeDtypeStruct((B, H, Lq, Dv), qt.dtype),
             jax.ShapeDtypeStruct((B, H, Lq, 1), jnp.float32),
         ],
         interpret=interpret_mode(),
@@ -364,8 +367,11 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
         def kvpos_map(b, h, i, j, qm, im, km):
             return (b, 0, j)
 
+    Dv = vt.shape[3]
     q_spec = pl.BlockSpec((1, 1, bq, D),
                           lambda b, h, i, j, qm, im, km: (b, h, i, 0))
+    do_spec = pl.BlockSpec((1, 1, bq, Dv),
+                           lambda b, h, i, j, qm, im, km: (b, h, i, 0))
     row_spec = pl.BlockSpec((1, 1, bq, 1),
                             lambda b, h, i, j, qm, im, km: (b, h, i, 0))
     in_specs = (
@@ -374,8 +380,8 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
         + ([pl.BlockSpec((1, 1, bkv), kvpos_map)] if use_kvpos else [])
         + [q_spec,
            pl.BlockSpec((1, 1, bkv, D), kv_map),
-           pl.BlockSpec((1, 1, bkv, D), kv_map),
-           q_spec, row_spec, row_spec]
+           pl.BlockSpec((1, 1, bkv, Dv), kv_map),
+           do_spec, row_spec, row_spec]
     )
     operands = [qmax, imin, kvmin, qpos3]
     if use_kvpos:
@@ -432,8 +438,11 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
         def qpos_map(b, h, j, i, qm, im, km):
             return (b, i, 0)
 
+    Dv = vt.shape[3]
     kv_out_spec = pl.BlockSpec((1, 1, bkv, D),
                                lambda b, h, j, i, qm, im, km: (b, h, j, 0))
+    v_out_spec = pl.BlockSpec((1, 1, bkv, Dv),
+                              lambda b, h, j, i, qm, im, km: (b, h, j, 0))
     in_specs = (
         [pl.BlockSpec((1, bq, 1), qpos_map)]
         + ([pl.BlockSpec((1, 1, bkv),
@@ -443,10 +452,10 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
            pl.BlockSpec((1, 1, bkv, D),
                         lambda b, h, j, i, qm, im, km, r=n_rep:
                         (b, h // r, j, 0)),
-           pl.BlockSpec((1, 1, bkv, D),
+           pl.BlockSpec((1, 1, bkv, Dv),
                         lambda b, h, j, i, qm, im, km, r=n_rep:
                         (b, h // r, j, 0)),
-           pl.BlockSpec((1, 1, bq, D), q_map),
+           pl.BlockSpec((1, 1, bq, Dv), q_map),
            pl.BlockSpec((1, 1, bq, 1), q_row_map),
            pl.BlockSpec((1, 1, bq, 1), q_row_map)]
     )
@@ -462,15 +471,15 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
             num_scalar_prefetch=3,
             grid=(B, H, nkv, nq),
             in_specs=in_specs,
-            out_specs=[kv_out_spec, kv_out_spec],
+            out_specs=[kv_out_spec, v_out_spec],
             scratch_shapes=[
                 pltpu.VMEM((bkv, D), jnp.float32),
-                pltpu.VMEM((bkv, D), jnp.float32),
+                pltpu.VMEM((bkv, Dv), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Lk, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, Lk, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Lk, Dv), jnp.float32),
         ],
         interpret=interpret_mode(),
     )(*operands)
@@ -491,7 +500,7 @@ def _bwd_impl(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv, out_t,
                            scale, blk_q, blk_kv, clamp)
     if n_rep > 1:
         dk = dk_h.reshape(B, Hkv, n_rep, Lk, D).sum(axis=2)
-        dv = dv_h.reshape(B, Hkv, n_rep, Lk, D).sum(axis=2)
+        dv = dv_h.reshape(B, Hkv, n_rep, Lk, vt.shape[3]).sum(axis=2)
     else:
         dk, dv = dk_h, dv_h
     return dq, dk, dv
@@ -535,11 +544,12 @@ def flash_attention_gqa(q, k, v, q_positions, scale,
     # fall back via _pick_block.
     """Flash attention with positional causal masking.
 
-    q: [B, Lq, H, D]; k/v: [B, Lk, Hkv, D] (Hkv divides H);
+    q: [B, Lq, H, D]; k: [B, Lk, Hkv, D]; v: [B, Lk, Hkv, Dv] (Hkv
+    divides H; Dv may differ from D — the output is Dv wide);
     q_positions: [B, Lq] int32 absolute positions, monotonic per row —
     query at position p attends to KV slots j <= p (identical semantics
     to the reference attention mask built in models/transformer.py).
-    Returns [B, Lq, H, D] in q.dtype.
+    Returns [B, Lq, H, Dv] in q.dtype.
     """
     out, _ = _fwd(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                   v.transpose(0, 2, 1, 3), q_positions[:, :, None],

@@ -58,6 +58,11 @@ def quantize_params_int8(params: Any) -> Any:
     decode step)."""
     if not isinstance(params, dict):
         return params
+    if "kv_b_proj" in params or "experts_gate_up_proj" in params:
+        raise ValueError(
+            "quantize_params_int8 cannot quantise a deepseek_v3 tree: "
+            "there are no int8 expert stacks and no int8 form of the "
+            "absorbed kv_b_proj yet (run bfloat16 rollout weights)")
     out = {}
     for name, sub in params.items():
         if name == "router":

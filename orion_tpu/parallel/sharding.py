@@ -13,6 +13,8 @@ Rules (MaxText/T5X-style):
   kv_heads— kv heads (GQA)                → tensor
   vocab   — embedding/unembedding vocab   → tensor
   layers  — scanned layer stack dimension → (replicated)
+  latent  — latent attention's compressed KV → (replicated)
+  expert  — stacked expert weights         → expert
 """
 
 from __future__ import annotations
@@ -31,6 +33,7 @@ LOGICAL_RULES: dict = {
     "vocab": "tensor",
     "layers": None,
     "norm": None,
+    "latent": None,     # latent attention's 512 / 576: replicated
     "expert": "expert",
     "batch": ("data", "fsdp"),
     "seq": "seq",

@@ -211,6 +211,13 @@ class ContinuousBatchingEngine:
                  eos_token_id: Optional[int] = None, pad_token_id: int = 0,
                  segment_len: Optional[int] = None,
                  mesh: Optional[Mesh] = None):
+        if model_cfg.latent_attention:
+            raise ValueError(
+                f"the continuous engine cannot run arch={model_cfg.arch!r}"
+                ": its page pool, block tables and the Pallas paged-decode "
+                "kernel hold per-head K/V pages of one head_dim; a latent "
+                "paged cache (c, k_rope) and a kernel that attends over it "
+                "are not written yet (use rollout.engine=simple)")
         self.mc = model_cfg
         self.cfg = cfg
         cfg.check_stop_ids(model_cfg.vocab_size, eos_token_id)

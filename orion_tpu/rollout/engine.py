@@ -73,10 +73,24 @@ class RolloutEngine:
         self.eos_token_id = eos_token_id
         self.pad_token_id = pad_token_id
         self._params = None
+        self._cache_bytes: dict = {}
         from orion_tpu.models.transformer import make_decode_twin
 
         self._decode_model, self._decode_cfg = make_decode_twin(
             model, model_cfg)
+        if model_cfg.latent_attention:
+            for on, missing in (
+                    (cfg.paged, "rollout.paged: there is no latent paged "
+                     "cache (ops/paged_kv.py and the Pallas paged-decode "
+                     "kernel hold per-head K/V pages)"),
+                    (cfg.quantize_kv, "rollout.quantize_kv: there is no "
+                     "int8 latent cache (ops/quant.py scales per head)"),
+                    (cfg.quantize_weights, "rollout.quantize_weights: "
+                     "there are no int8 expert stacks or absorbed int8 "
+                     "kv_b_proj (ops/quant.py quantises Dense kernels)")):
+                if on:
+                    raise ValueError(
+                        f"arch={model_cfg.arch!r} cannot run with {missing}")
         if cfg.quantize_weights:
             # int8 decode twin (ops/quant.py): same architecture, Dense
             # layers read int8 kernels.  Params are quantized inside
@@ -117,6 +131,23 @@ class RolloutEngine:
         mode the weight-sync channel device_puts a fresh snapshot here
         (SURVEY.md §2 #11)."""
         self._params = params
+
+    def cache_bytes(self, batch: int, prompt_len: int,
+                    max_new_tokens: Optional[int] = None) -> int:
+        """Bytes of the cache ``_generate`` allocates for such a batch
+        (shapes only, nothing is placed)."""
+        T = int(max_new_tokens or self.cfg.max_new_tokens)
+        if self.cfg.paged:
+            return 0
+        key = (batch, prompt_len + T)
+        if key not in self._cache_bytes:
+            cache = jax.eval_shape(
+                lambda: init_cache(self._decode_cfg, *key,
+                                   dtype=jnp.dtype(self._decode_cfg.dtype),
+                                   quantized=self.cfg.quantize_kv))
+            self._cache_bytes[key] = sum(
+                x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+        return self._cache_bytes[key]
 
     # -- generation -----------------------------------------------------
     def generate(self, prompt_ids: jnp.ndarray, prompt_lens: jnp.ndarray,
@@ -176,6 +207,9 @@ class RolloutEngine:
                                dtype=jnp.dtype(self._decode_cfg.dtype),
                                quantized=cfg.quantize_kv)
         positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
+        # the dropless expert layer routes a prompt's padding nowhere
+        pad_kw = {"token_mask": positions < prompt_lens[:, None]} \
+            if self.model_cfg.n_routed_experts > 0 else {}
         with jax.named_scope("prefill"):
             # Only the last real prompt token's logits are needed (they
             # predict completion[0]) — logits_positions skips the other
@@ -183,7 +217,7 @@ class RolloutEngine:
             # logits buffer (1.6 GB at ppo1b shapes).
             logits, cache = self._decode_model.apply(
                 {"params": params}, prompt_ids, positions, cache,
-                logits_positions=(prompt_lens - 1)[:, None])
+                logits_positions=(prompt_lens - 1)[:, None], **pad_kw)
         last = logits[:, 0]
         V = last.shape[-1]
         # Generation controls (static per compile): repetition penalty

@@ -151,6 +151,30 @@ def state_out_shardings(state: "TrainState"):
         else None, state)
 
 
+def _sown(intermediates, name: str) -> list:
+    """The arrays sown under ``name`` anywhere in an intermediates
+    tree (stacked over layers under scan_layers)."""
+    return [x for path, x in
+            jax.tree_util.tree_flatten_with_path(intermediates)[0]
+            if any(getattr(k, "key", None) == name for k in path)]
+
+
+def moe_load_stats(loads: list, pairs_per_layer: int) -> dict:
+    """Counters of the dropless expert layer for one forward, from the
+    ``moe_load`` arrays its layers sowed ([held] or, scanned, [layers,
+    held] pairs routed to each expert held here): the pairs computed
+    here and all pairs routed (over all layers), and the largest and
+    the mean load of a held expert (over layers and experts): max over
+    mean is the padding a grouped product pays."""
+    load = jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])
+    return {
+        "moe_pairs_here": jnp.sum(load).astype(jnp.float32),
+        "moe_pairs_total": jnp.float32(pairs_per_layer * load.shape[0]),
+        "moe_load_max": jnp.max(load).astype(jnp.float32),
+        "moe_load_mean": jnp.mean(load.astype(jnp.float32)),
+    }
+
+
 class BaseTrainer:
     """Shared machinery; see PPOTrainer/GRPOTrainer/... for algorithms.
 
@@ -319,34 +343,46 @@ class BaseTrainer:
     # jitted helpers
     # ------------------------------------------------------------------
     def _policy_apply(self, params, sequences, positions, **apply_kw):
-        """(apply outputs, aux): policy forward + the MoE router
-        load-balance auxiliary loss (mean over layers; 0.0 for dense
-        models).  Loss paths add ``cfg.model.router_aux_coef * aux`` —
+        """(apply outputs, aux, moe): policy forward + the GShard
+        router's load-balance auxiliary loss (mean over layers; 0.0 for
+        dense models and for the dropless deepseek_v3 layer, which has
+        none).  Loss paths add ``cfg.model.router_aux_coef * aux`` —
         without it a num_experts>0 run has zero load-balancing pressure
-        and experts silently collapse.  ``apply_kw`` passes through to
-        the module (e.g. with_values=True on ActorCriticModel) — the
-        single source of truth for the aux aggregation."""
-        if self.cfg.model.num_experts > 0:
+        and experts silently collapse.  ``moe``: the dropless layer's
+        counters of this forward (:func:`moe_load_stats`; {} for every
+        other model), which loss paths put into their stats.
+        ``apply_kw`` passes through to the module (e.g.
+        with_values=True on ActorCriticModel) — the single source of
+        truth for the aux aggregation."""
+        mc = self.cfg.model
+        moe = {}
+        if mc.num_experts > 0:
             out, inter = self.model.apply(
                 {"params": params}, sequences, positions,
                 mutable=["intermediates"], **apply_kw)
             # Only the router's 'moe_aux_loss' sows feed the loss — any
             # other sown diagnostic (activation stats, attention probes)
             # must NOT silently shift the training objective (ADVICE r2).
-            leaves = [x for path, x in
-                      jax.tree_util.tree_flatten_with_path(inter)[0]
-                      if any(getattr(k, "key", None) == "moe_aux_loss"
-                             for k in path)]
+            leaves = _sown(inter, "moe_aux_loss")
             if not leaves:
                 raise ValueError(
                     "num_experts > 0 but no 'moe_aux_loss' intermediates "
                     "were sown — router aux loss would be silently zero")
             aux = sum(jnp.mean(x) for x in leaves) / len(leaves)
+        elif mc.n_routed_experts > 0:
+            # The dropless layer (deepseek_v3) has no auxiliary loss;
+            # the loads its layers sow become the forward's counters.
+            out, inter = self.model.apply(
+                {"params": params}, sequences, positions,
+                mutable=["intermediates"], **apply_kw)
+            moe = moe_load_stats(_sown(inter, "moe_load"),
+                                 sequences.size * mc.num_experts_per_tok)
+            aux = jnp.zeros((), jnp.float32)
         else:
             out = self.model.apply({"params": params}, sequences,
                                    positions, **apply_kw)
             aux = jnp.zeros((), jnp.float32)
-        return out, aux
+        return out, aux, moe
 
     def _windowed_forward(self, params, sequences, prompt_lens,
                           max_new: int, with_entropy: bool = True,
@@ -356,8 +392,9 @@ class BaseTrainer:
         window_positions) — the [B, L, V] f32 logits at full length are
         the biggest tensor in the pipeline and 2/3 of them were thrown
         away (r3 perf).  Returns (lp [B,T], ent [B,T] | None, extra
-        apply outputs, aux) where ``extra`` carries whatever the module
-        returned beyond logits (e.g. values for ActorCriticModel)."""
+        apply outputs, aux, moe) where ``extra`` carries whatever the
+        module returned beyond logits (e.g. values for
+        ActorCriticModel) and ``aux``, ``moe`` are _policy_apply's."""
         from orion_tpu.ops.logprobs import (completion_window_positions,
                                             windowed_completion_logprobs)
 
@@ -365,21 +402,27 @@ class BaseTrainer:
         positions = jnp.broadcast_to(
             jnp.arange(L, dtype=jnp.int32), sequences.shape)
         widx = completion_window_positions(prompt_lens, max_new, L)
-        out, aux = self._policy_apply(
+        if self.cfg.model.n_routed_experts > 0:
+            # behind prompt + completion window a row is padding, whatever
+            # the completion's length: the dropless expert layer routes
+            # those positions nowhere
+            apply_kw["token_mask"] = positions < (prompt_lens
+                                                  + max_new)[:, None]
+        out, aux, moe = self._policy_apply(
             params, sequences, positions, logits_positions=widx,
             **apply_kw)
         logits_w, extra = out[0], out[1:]
         lp = windowed_completion_logprobs(logits_w, sequences, prompt_lens,
                                           max_new)
         ent = entropy_from_logits(logits_w) if with_entropy else None
-        return lp, ent, extra, aux
+        return lp, ent, extra, aux, moe
 
     def _logprobs_fn(self, params, sequences, prompt_lens, max_new: int):
-        """Completion logprobs + entropy (+ MoE aux loss) under the
-        training graph, over the completion window."""
-        lp, ent, _, aux = self._windowed_forward(
+        """Completion logprobs + entropy (+ MoE aux loss and counters)
+        under the training graph, over the completion window."""
+        lp, ent, _, aux, moe = self._windowed_forward(
             params, sequences, prompt_lens, max_new)
-        return lp, (ent, aux)
+        return lp, (ent, aux, moe)
 
     def loss_fn(self, params, mb: Dict[str, jnp.ndarray]):
         raise NotImplementedError
@@ -432,6 +475,13 @@ class BaseTrainer:
                                    self.state.params)
         return self.engine.generate(ids, lens, rng,
                                     params=self.state.params)
+
+    def _rollout_cache_bytes(self, prompts_shape) -> int:
+        """Bytes of the KV cache the fixed-batch engine allocates for
+        this batch (from shapes; 0 for the continuous engine, whose
+        pool is its own)."""
+        cache_bytes = getattr(self.engine, "cache_bytes", None)
+        return cache_bytes(*prompts_shape) if cache_bytes else 0
 
     def _score_result(self, result, host, meta) -> np.ndarray:
         """One place for the device-vs-host reward dispatch (the
@@ -541,7 +591,8 @@ class BaseTrainer:
         # place this thread blocks on the device.
         with obs.span("rollout.dispatch") as sp:
             ids, lens, meta = self.prepare_prompts(batch)
-            sp.set(batch=int(ids.shape[0]), prompt_len=int(ids.shape[1]))
+            sp.set(batch=int(ids.shape[0]), prompt_len=int(ids.shape[1]),
+                   cache_bytes=self._rollout_cache_bytes(ids.shape))
             result = self.generate(
                 ids, lens, group_size=getattr(self.cfg, "group_size", 1))
         pend, self._pending_fetch = self._pending_fetch, None
@@ -948,6 +999,8 @@ class BaseTrainer:
                 "samples_per_sec":
                     pending["n"] / max(now - pending["t0"], 1e-9),
             })
+            sp.set(**{k: v for k, v in stats.items()
+                      if k.startswith("moe_")})
             self.metrics_history.append(stats)
             if self.writer is not None:
                 # giter: the global counter at dispatch time — monotone

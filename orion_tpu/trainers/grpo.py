@@ -57,7 +57,7 @@ class GRPOTrainer(BaseTrainer):
 
     def loss_fn(self, params, mb: Dict[str, jnp.ndarray]):
         T = mb["mask"].shape[1]
-        lp, (ent, aux) = self._logprobs_fn(
+        lp, (ent, aux, moe) = self._logprobs_fn(
             params, mb["sequences"], mb["prompt_lens"], max_new=T)
         pg_loss, stats = ppo_policy_loss(
             lp, mb["old_logprobs"], mb["advantages"], mb["mask"],
@@ -66,7 +66,7 @@ class GRPOTrainer(BaseTrainer):
         kl_mean = masked_mean(kl, mb["mask"])
         loss = pg_loss + self.cfg.kl_coef * kl_mean \
             + self.cfg.model.router_aux_coef * aux
-        stats = dict(stats)
+        stats = {**stats, **moe}
         stats["kl"] = kl_mean
         stats["entropy"] = masked_mean(ent, mb["mask"])
         return loss, stats

@@ -68,9 +68,9 @@ class OnlineDPOTrainer(BaseTrainer):
 
     def loss_fn(self, params, mb: Dict[str, jnp.ndarray]):
         T = mb["chosen_mask"].shape[1]
-        c_lp, (_, c_aux) = self._logprobs_fn(
+        c_lp, (_, c_aux, c_moe) = self._logprobs_fn(
             params, mb["chosen_sequences"], mb["prompt_lens"], max_new=T)
-        r_lp, (_, r_aux) = self._logprobs_fn(
+        r_lp, (_, r_aux, _) = self._logprobs_fn(
             params, mb["rejected_sequences"], mb["rejected_prompt_lens"],
             max_new=T)
         c_seq = jnp.sum(c_lp * mb["chosen_mask"], axis=1)
@@ -80,4 +80,5 @@ class OnlineDPOTrainer(BaseTrainer):
             self.cfg.beta, self.cfg.label_smoothing,
             pair_weight=mb["pair_weight"])
         loss = loss + self.cfg.model.router_aux_coef * (c_aux + r_aux)
-        return loss, stats
+        # the expert layer's counters: the chosen forward's
+        return loss, {**stats, **c_moe}

@@ -107,12 +107,12 @@ class PPOTrainer(BaseTrainer):
         The vocab projection runs only over the completion window via
         BaseTrainer._windowed_forward (values still read the full
         hidden states)."""
-        lp, ent, extra, aux = self._windowed_forward(
+        lp, ent, extra, aux, moe = self._windowed_forward(
             params, sequences, prompt_lens, max_new,
             with_entropy=with_entropy, with_values=True)
         values = extra[0]
         return (lp, ent,
-                self._gather_completion(values, prompt_lens, mask), aux)
+                self._gather_completion(values, prompt_lens, mask), aux, moe)
 
     # ------------------------------------------------------------------
     def build_experience(self, result, scores, host=None):
@@ -120,7 +120,7 @@ class PPOTrainer(BaseTrainer):
         mask = result.completion_mask
         if self.cfg.share_backbone and not self.cfg.async_mode:
             # One fused trunk pass yields old logprobs AND values.
-            old_lp, _, values, _ = self._jit_lp_values(
+            old_lp, _, values, _, _ = self._jit_lp_values(
                 self.state.params, result.sequences, result.prompt_lens,
                 mask, max_new=T, with_entropy=False)
         else:
@@ -192,7 +192,7 @@ class PPOTrainer(BaseTrainer):
         forward/backward.  Flows through BaseTrainer's scanned epoch
         program (_epochs_fn) unchanged."""
         T = mb["mask"].shape[1]
-        lp, ent, values, aux = self._lp_values_fwd(
+        lp, ent, values, aux, moe = self._lp_values_fwd(
             params, mb["sequences"], mb["prompt_lens"], mb["mask"],
             max_new=T)
         p_loss, p_stats = ppo_policy_loss(
@@ -201,20 +201,20 @@ class PPOTrainer(BaseTrainer):
         v_loss, v_stats = ppo_value_loss(
             values, mb["old_values"], mb["returns"], mb["mask"],
             self.cfg.value_clip)
-        stats = {**p_stats, **v_stats}
+        stats = {**p_stats, **v_stats, **moe}
         stats["entropy"] = masked_mean(ent, mb["mask"])
         return (p_loss + self.cfg.vf_coef * v_loss
                 + self.cfg.model.router_aux_coef * aux), stats
 
     def _policy_loss(self, params, mb):
         T = mb["mask"].shape[1]
-        lp, (ent, aux) = self._logprobs_fn(
+        lp, (ent, aux, moe) = self._logprobs_fn(
             params, mb["sequences"], mb["prompt_lens"], max_new=T)
         loss, stats = ppo_policy_loss(
             lp, mb["old_logprobs"], mb["advantages"], mb["mask"],
             self.cfg.clip_ratio)
         loss = loss + self.cfg.model.router_aux_coef * aux
-        stats = dict(stats)
+        stats = {**stats, **moe}
         stats["entropy"] = masked_mean(ent, mb["mask"])
         return loss, stats
 

@@ -56,7 +56,7 @@ class RLOOTrainer(BaseTrainer):
 
     def loss_fn(self, params, mb: Dict[str, jnp.ndarray]):
         T = mb["mask"].shape[1]
-        lp, (ent, aux) = self._logprobs_fn(
+        lp, (ent, aux, moe) = self._logprobs_fn(
             params, mb["sequences"], mb["prompt_lens"], max_new=T)
         seq_lp = jnp.sum(lp * mb["mask"], axis=1)
         # REINFORCE on whole-sequence logprob with a stop-grad sequence
@@ -73,5 +73,6 @@ class RLOOTrainer(BaseTrainer):
             "entropy": masked_mean(ent, mb["mask"]),
             "seq_logprob_mean": jnp.mean(seq_lp),
             "ratio_mean": jnp.mean(ratio),
+            **moe,
         }
         return loss, stats

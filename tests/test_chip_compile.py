@@ -289,3 +289,57 @@ def test_pythia1b_decode_segment_compiles_for_v5e(one_chip, on_tpu):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 16e9, f"decode segment needs {total / 1e9:.1f} GB"
+
+
+# -- the deepseek_v3 block's kernels at the published widths ----------------
+
+def test_flash_with_a_narrower_value_compiles_for_v5e(one_chip, on_tpu):
+    """Latent attention expands keys of 192 (128 + 64 rotary) and values
+    of 128: forward and both backward kernels at the update's shape of
+    ``ppo-kanana-ep8-sync`` (16 x 1024, 32 heads)."""
+    from orion_tpu.ops.pallas.flash_attention import flash_attention_gqa
+
+    B, L, H, D, Dv = 16, 1024, 32, 192, 128
+
+    def loss(q, k, v):
+        qpos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+        out = flash_attention_gqa(q, k, v, qpos, D ** -0.5)
+        assert out.shape == (B, L, H, Dv)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _sds((B, L, H, D), BF16, one_chip),
+        _sds((B, L, H, D), BF16, one_chip),
+        _sds((B, L, H, Dv), BF16, one_chip)).compile()
+    assert _kernel_names(compiled) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                       "flash_fwd"]
+
+
+def test_grouped_expert_product_compiles_for_v5e(one_chip, on_tpu):
+    """The dropless expert path at the update's shape (16 x 1024 tokens,
+    top-6 of 128, 16 experts held, hidden 2048, expert width 768): the
+    grouped product forward and its two backward kernels, by the names
+    the device trace will carry."""
+    from orion_tpu.ops.moe import experts_grouped
+
+    T, D, I, H, k = 16 * 1024, 2048, 768, 16, 6
+
+    def loss(x, w_gate_up, w_down, local, gates):
+        return jnp.sum(experts_grouped(x, w_gate_up, w_down, local,
+                                       gates).astype(jnp.float32))
+
+    # the suite's "highest" matmul precision is for float32 parity on
+    # the CPU; Mosaic refuses it on the kernels' bfloat16 operands, and
+    # no program sets it
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            _sds((T, D), BF16, one_chip),
+            _sds((H, D, 2 * I), BF16, one_chip),
+            _sds((H, I, D), BF16, one_chip),
+            _sds((T, k), jnp.int32, one_chip),
+            _sds((T, k), jnp.float32, one_chip)).compile()
+    names = _kernel_names(compiled)
+    assert set(names) == {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm"}, names
+    # gate|up and down: two of each backward half (the second forward
+    # product's result is not needed for these gradients)
+    assert names.count("moe_gmm_dlhs") == 2 and names.count("moe_tgmm") == 2
