@@ -6,12 +6,31 @@ kernels.  Design:
 - Public layout [B, L, H, D] (matching the model); internally the
   wrapper transposes to [B, H, L, D] so every block's trailing two dims
   are (seq-block, head-dim) — the shape Mosaic requires to tile onto
-  the MXU.
+  the MXU — or, for v, o and dq, (head-dim, seq-block): see the
+  transposed tiles below.
 - Both loop dimensions are *grid* dimensions: the forward/dq grid is
   (B, H, q-block, kv-block) and the dkv grid is (B, H, kv-block,
   q-block), with online-softmax / gradient accumulators carried in VMEM
   scratch across the innermost dimension (sequential on TPU).  VMEM
   footprint is therefore O(block), not O(L) — long-context safe.
+- A grid step holds a MAJOR block of up to ``_MAJOR`` queries and one
+  of up to ``_MAJOR`` keys, each several [bq, bkv] TILES (``_tiles``),
+  and computes, for every q tile (kv tile in dkv), ONE wide tile over
+  the run of kv (q) tiles that the causal rule leaves live
+  (``_for_each_extent``): the skip keeps the tile's granularity, while
+  what a step costs whatever it computes — pipeline set-up, the update
+  of the running statistics — is paid once per major block, and the
+  wide tile is straight-line code in which the scheduler overlaps the
+  products with the element-wise work.
+- The score tiles are TRANSPOSED, [keys, queries]: ``s^T = k q^T``.
+  Everything per query (positions, running max and sum, ``lse``,
+  ``delta``) is then a lane-dense row vector instead of a [bq, 1]
+  column, the softmax reductions run down sublanes (element-wise
+  across vregs, no cross-lane work), and all five products take their
+  operands as they lie: ``o^T = v^T p^T`` (v enters, o leaves
+  transposed: the wrappers' own layout transposes absorb it),
+  ``dv = p^T dO``, ``dp^T = v dO^T``, ``dk = ds^T q`` and
+  ``dq^T = k^T ds^T`` (k enters dq a second time, transposed).
 - GQA via BlockSpec index maps (``h // n_rep``) — no materialized
   ``repeat_kv``.
 - The value width ``Dv`` may differ from the query/key width ``D``
@@ -24,10 +43,9 @@ kernels.  Design:
   causal path passes ``arange(Lk)`` (slot == position), and the
   ring-attention path passes rotated chunk positions — zigzag chunks
   are piecewise-contiguous, so an offset would not do.
-- Causal skipping: a (q-block, kv-block) pair is skipped when the
-  kv-block's MIN position exceeds the q-block's MAX position
-  (``pl.when``); block-extent scalars (per-q-block max position,
-  per-kv-block min position, per-kv-block first relevant q-block) are
+- Causal skipping: a (q, kv) TILE is skipped when the kv tile's MIN
+  position exceeds the q tile's MAX position; tile-extent scalars (per-q-tile max position,
+  per-kv-tile min position, per-kv-tile first relevant q-tile) are
   scalar-prefetched.  On the standard contiguous path the *index maps*
   additionally clamp the fetched block index so skipped steps re-fetch
   the same block — Pallas elides consecutive identical fetches, so
@@ -42,6 +60,19 @@ kernels.  Design:
   dK/dV kernel emits per-q-head gradients, group-summed outside.  The
   per-chunk entry points (``flash_chunk_*``) take a caller-supplied
   GLOBAL lse, which is what makes the ring-attention backward exact.
+- Precision follows the INPUT dtype and nothing else.  All five
+  products (``q k^T``, ``p v``, ``do v^T``, ``ds k``, ``p^T do`` /
+  ``ds^T q``) take their operands in the dtype q / k / v / dO arrived
+  in and accumulate in float32 (``_dot``): bf16 in -> single-pass bf16
+  MXU products (a product of two bf16 numbers is exact in the float32
+  accumulator), float32 in -> float32 products.  The scale is applied
+  to the float32 scores, never to an operand.  The softmax statistics
+  (running max and sum, ``lse``, ``delta``) and every accumulator
+  (``acc``, ``dq``, ``dk``, ``dv``) are float32; ``p`` and ``ds`` are
+  computed in float32 and rounded to the operand dtype for their
+  product only — the rounding ``ops.attention.reference_attention``
+  makes (``probs.astype(q.dtype)`` before its second einsum), so the
+  kernel and the model's plain path state the same precision.
 
 Interpret mode runs automatically off-TPU (CPU test harness).
 """
@@ -49,6 +80,7 @@ Interpret mode runs automatically off-TPU (CPU test harness).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -60,15 +92,17 @@ from orion_tpu.ops.pallas import (NEG_INF, interpret_mode,
 
 
 def _pick_block(n: int, preferred: int) -> int:
-    # Mosaic requires the second-minor block dim to be a multiple of 8
-    # OR equal to the full array dim.  A dim that fits in one block is
-    # therefore always legal as-is — and any sub-8 divisor is NOT
-    # (found on-chip r5: the speculative verify chunk runs Lq=k+1=5
-    # over an Lk=388 cache; the old divisor scan chose bkv=4 and
-    # Mosaic refused to lower — invisible to CPU interpret mode).
+    # Every tile dim is a lane dim somewhere (the queries of the
+    # transposed tiles, the keys of the v^T block and of dq's score
+    # tile), so Mosaic wants it a multiple of 128 OR equal to the full
+    # array dim.  A dim that fits in one block is therefore always
+    # legal as-is — and no smaller divisor is (found on-chip r5: the
+    # speculative verify chunk runs Lq=k+1=5 over an Lk=388 cache; the
+    # old divisor scan chose bkv=4 and Mosaic refused to lower —
+    # invisible to CPU interpret mode).
     if n <= preferred:
         return n
-    for c in (preferred, 512, 256, 128, 64, 32, 16, 8):
+    for c in (preferred, 512, 256, 128):
         if c <= preferred and n % c == 0:
             return c
     return n  # no legal tile ≤ preferred: one full-dim block
@@ -100,23 +134,136 @@ def _block_extents(q_positions, kv_positions, bq, bkv, nkv=None):
     return qmax, imin, kvmin
 
 
+# Contracting dims of the kernels' two product forms.
+_NT = ((1,), (1,))   # a @ b.T
+_NN = ((1,), (0,))   # a @ b
+
+
+def _dot(a, b, contract):
+    """One MXU product: operands as given, float32 accumulation.
+
+    A product of two bf16 numbers is exact in the float32 accumulator,
+    so for them an ambient ``jax_default_matmul_precision`` asks for
+    nothing — and Mosaic refuses "highest" on bf16 operands ("Bad lhs
+    type"), so it is pinned to DEFAULT.  float32 operands keep the
+    ambient precision, as before."""
+    precision = (None if a.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
-# forward.  Internal layout: q/k/v/o [B, H, L, D]; qpos [B, Lq, 1];
-# kvpos [B, 1, Lk] (lane-major: the kv-position vector broadcasts
-# along lanes in the mask compare; a sublane-major [B, Lk, 1] layout
-# forces a giant Mosaic relayout that blows scoped VMEM); lse [B, H, Lq, 1].  Grid (B, H, nq, nkv), kv innermost.
+# The kernels.  Internal layout: q/k/v/dO [B, H, L, D]; the score tiles
+# are TRANSPOSED, [keys, queries] (keys along sublanes, queries along
+# lanes), so everything per query — qpos [B, 1, Lq], lse and delta
+# [B, H, 1, Lq], the running max and sum — is a lane-dense row vector
+# (a [bq, 1] column costs a whole vreg for every 8 queries in every
+# operation that touches it, and its reductions cross lanes), the
+# softmax reductions run down sublanes, element-wise, and every
+# product takes its operands as they lie: s^T = k q^T, o^T = v^T p^T,
+# dv = p^T dO, dp^T = v dO^T, dk = ds^T q.  kvpos is [B, Lk, 1].
+# Forward and dq: grid (B, H, nq, nkv), kv innermost; dkv: grid
+# (B, H, nkv, nq), q innermost; one grid step holds a MAJOR block of
+# several tiles along each sequence dim (see _tiles).
 # ---------------------------------------------------------------------------
+
+# The most keys or queries one grid step holds.
+_MAJOR = 1024
+
+
+def _tiles(n: int, preferred: int):
+    """(tile, tiles per major block) along one sequence dim: as many
+    tiles as fit ``_MAJOR`` and divide the dim's tile count."""
+    tile = _pick_block(n, preferred)
+    n_sub = max(1, min(_MAJOR, n) // tile)
+    while (n // tile) % n_sub:
+        n_sub -= 1
+    return tile, n_sub
+
+
+class _Plan(NamedTuple):
+    """How one call tiles its two sequence dims, with the scalar-prefetch
+    tables (``_block_extents``, at TILE granularity)."""
+    bq: int          # q tile
+    nq_sub: int      # q tiles per q major block
+    bkv: int         # kv tile
+    n_sub: int       # kv tiles per kv major block
+    qmajor: int
+    major: int
+    nq: int          # q major blocks
+    nkv: int         # kv major blocks
+    tables: tuple    # (qmax, imin, kvmin)
+
+
+def _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3) -> _Plan:
+    bq, nq_sub = _tiles(Lq, blk_q)
+    bkv, n_sub = _tiles(Lk, blk_kv)
+    qmajor, major = bq * nq_sub, bkv * n_sub
+    tables = _block_extents(
+        qpos3[:, 0, :], None if kvpos3 is None else kvpos3[:, :, 0],
+        bq, bkv, nkv=Lk // bkv)
+    return _Plan(bq, nq_sub, bkv, n_sub, qmajor, major, Lq // qmajor,
+                 Lk // major, tables)
+
+
+def _kv_fetch(p: _Plan, clamp: bool):
+    """The kv major block that step (i, j) of a (.., nq, nkv) grid
+    fetches.  Under the clamp, steps beyond the q major block's causal
+    frontier (its last tile's max position: positions are monotone)
+    re-fetch the same block, which Pallas elides.  (Contiguous kv
+    positions only.)"""
+    if not clamp:
+        return lambda qmax, b, i, j: j
+    return lambda qmax, b, i, j: jnp.minimum(
+        j, qmax[b, i * p.nq_sub + p.nq_sub - 1] // p.major)
+
+
+def _for_each_extent(live, body, leading: bool):
+    """Call ``body(c)`` for the one c such that tiles ``[0, c]`` of the
+    major block (``leading``) or ``[c, n)`` (trailing) reach from its
+    edge to the farthest live tile — nothing if no tile is live.
+
+    Under the causal rule the live tiles ARE that run (a prefix of the
+    keys for a q tile, a suffix of the queries for a kv tile), so the
+    body computes one wide tile of exactly the live work: straight-line
+    code, in which the scheduler overlaps the products with the
+    element-wise work, and one update of the running statistics.  With
+    arbitrary positions (ring chunks) a dead tile inside the run is
+    computed and masked: correct, only not skipped."""
+    order = range(len(live)) if leading else reversed(range(len(live)))
+    edge = jnp.int32(-1)
+    for c in order:                     # the last live tile in order wins
+        edge = jnp.where(live[c], c, edge)
+    for c in range(len(live)):
+        pl.when(edge == c)(functools.partial(body, c))
+
+
+def _seen(qpos_ref, kvpos_ref, kv_start, kv_rows, q_cols, shape):
+    """[keys, queries] bool (``shape``): key rows ``kv_rows`` of the kv
+    block, which starts at slot ``kv_start``, against query columns
+    ``q_cols`` of the q block."""
+    if kvpos_ref is not None:
+        kvcol = kvpos_ref[0, kv_rows, :]                         # [w, 1]
+    else:
+        # standard causal path: slot == position, pure iota — no
+        # kvpos operand (whose block would violate the Mosaic
+        # divisibility rule at odd cache lengths).
+        kvcol = kv_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return kvcol <= qpos_ref[0, :, q_cols]
 
 
 def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-                scale: float, use_kvpos: bool):
+                scale: float, use_kvpos: bool, nq_sub: int, n_sub: int):
+    kvpos_ref = None
     if use_kvpos:
-        (kvpos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-         m_sc, l_sc, acc_sc) = rest
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
+        kvpos_ref, *rest = rest
+    q_ref, k_ref, vt_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
+    blk_q, major = q_ref.shape[2] // nq_sub, k_ref.shape[2]
+    blk_kv = major // n_sub
 
     @pl.when(j == 0)
     def _():
@@ -124,34 +271,29 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         l_sc[:, :] = jnp.zeros_like(l_sc)
         acc_sc[:, :] = jnp.zeros_like(acc_sc)
 
-    @pl.when(kvmin_ref[b, j] <= qmax_ref[b, i])
-    def _():
-        blk_q = q_ref.shape[2]
-        blk_kv = k_ref.shape[2]
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale        # [bq, D]
-        qpos = qpos_ref[0, :, 0]
-        if use_kvpos:
-            kvmat = kvpos_ref[0, 0, :][None, :]
-        else:
-            # standard causal path: slot == position, pure iota — no
-            # kvpos operand (whose lane-dim block would violate the
-            # Mosaic divisibility rule at odd cache lengths).
-            kvmat = j * blk_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_kv), 1)
-        k = k_ref[0, 0, :, :].astype(jnp.float32)                # [bkv, D]
-        v = v_ref[0, 0, :, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # [bq, bkv]
-        s = jnp.where(kvmat <= qpos[:, None], s, NEG_INF)
-        m_prev, l_prev = m_sc[:, :], l_sc[:, :]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+    def update(cols, c):
+        width = (c + 1) * blk_kv
+        vt = vt_ref[0, 0, :, :width]                             # [Dv, w]
+        st = _dot(k_ref[0, 0, :width, :], q_ref[0, 0, cols, :],
+                  _NT) * scale                                   # [w, bq]
+        st = jnp.where(_seen(qpos_ref, kvpos_ref, j * major,
+                             slice(0, width), cols, st.shape), st, NEG_INF)
+        m_prev, l_prev = m_sc[:, cols], l_sc[:, cols]            # [1, bq]
+        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+        pt = jnp.exp(st - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        m_sc[:, :] = m_new
-        l_sc[:, :] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[:, :] = acc_sc[:, :] * alpha + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        m_sc[:, cols] = m_new
+        l_sc[:, cols] = l_prev * alpha + jnp.sum(pt, axis=0, keepdims=True)
+        acc_sc[:, cols] = acc_sc[:, cols] * alpha + _dot(
+            vt, pt.astype(vt.dtype), _NN)                        # [Dv, bq]
+
+    for r in range(nq_sub):
+        # kv tile c of this major block holds a key some row of q tile r sees
+        _for_each_extent(
+            [kvmin_ref[b, j * n_sub + c] <= qmax_ref[b, i * nq_sub + r]
+             for c in range(n_sub)],
+            functools.partial(update, slice(r * blk_q, (r + 1) * blk_q)),
+            leading=True)
 
     @pl.when(j == nj - 1)
     def _():
@@ -165,75 +307,66 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
 
 def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
          clamp: bool):
-    """qt [B,H,Lq,D], kt/vt [B,Hkv,Lk,D], qpos3 [B,Lq,1], kvpos3
-    [B,1,Lk].  clamp=True enables the contiguous-path fetch clamps."""
+    """qt [B,H,Lq,D], kt/vt [B,Hkv,Lk,D], qpos3 [B,1,Lq], kvpos3
+    [B,Lk,1] -> out [B,H,Lq,Dv], lse [B,H,1,Lq].  clamp=True enables
+    the contiguous-path fetch clamps."""
     B, H, Lq, D = qt.shape
     Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
-    bq = _pick_block(Lq, blk_q)
-    bkv = _pick_block(Lk, blk_kv)
-    nq, nkv = Lq // bq, Lk // bkv
+    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3)
+    qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
-    qmax, imin, kvmin = _block_extents(
-        qpos3[:, :, 0], kvpos3[:, 0, :] if use_kvpos else None,
-        bq, bkv, nkv=nkv)
+    fetch = _kv_fetch(p, clamp)
 
-    if clamp:
-        def kv_map(b, h, i, j, qmax, imin, kvmin, r=n_rep, bkv=bkv):
-            # Steps beyond the causal frontier re-fetch the same block,
-            # which Pallas elides.  (Contiguous kv positions only.)
-            return (b, h // r, jnp.minimum(j, qmax[b, i] // bkv), 0)
+    def k_map(b, h, i, j, qm, im, km):
+        return (b, h // n_rep, fetch(qm, b, i, j), 0)
 
-        def kvpos_map(b, h, i, j, qmax, imin, kvmin, bkv=bkv):
-            return (b, 0, jnp.minimum(j, qmax[b, i] // bkv))
-    else:
-        def kv_map(b, h, i, j, qmax, imin, kvmin, r=n_rep):
-            return (b, h // r, j, 0)
+    def vt_map(b, h, i, j, qm, im, km):
+        return (b, h // n_rep, 0, fetch(qm, b, i, j))
 
-        def kvpos_map(b, h, i, j, qmax, imin, kvmin):
-            return (b, 0, j)
+    def q_lanes(b, h, i, j, qm, im, km):
+        return (b, h, 0, i)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, H, nq, nkv),
+        grid=(B, H, p.nq, p.nkv),
         in_specs=(
-            [pl.BlockSpec((1, bq, 1),
-                          lambda b, h, i, j, qm, im, km: (b, i, 0))]
-            + ([pl.BlockSpec((1, 1, bkv), kvpos_map)] if use_kvpos
-               else [])
-            + [pl.BlockSpec((1, 1, bq, D),
+            [pl.BlockSpec((1, 1, qmajor),
+                          lambda b, h, i, j, qm, im, km: (b, 0, i))]
+            + ([pl.BlockSpec((1, major, 1),
+                             lambda b, h, i, j, qm, im, km: (b, j, 0))]
+               if use_kvpos else [])
+            + [pl.BlockSpec((1, 1, qmajor, D),
                             lambda b, h, i, j, qm, im, km: (b, h, i, 0)),
-               pl.BlockSpec((1, 1, bkv, D), kv_map),
-               pl.BlockSpec((1, 1, bkv, Dv), kv_map)]
+               pl.BlockSpec((1, 1, major, D), k_map),
+               pl.BlockSpec((1, 1, Dv, major), vt_map)]
         ),
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, Dv),
-                         lambda b, h, i, j, qm, im, km: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bq, 1),
-                         lambda b, h, i, j, qm, im, km: (b, h, i, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, Dv, qmajor), q_lanes),
+                   pl.BlockSpec((1, 1, 1, qmajor), q_lanes)],
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),   # running max
-            pltpu.VMEM((bq, 1), jnp.float32),   # running sumexp
-            pltpu.VMEM((bq, Dv), jnp.float32),  # running accumulator
+            pltpu.VMEM((1, qmajor), jnp.float32),    # running max
+            pltpu.VMEM((1, qmajor), jnp.float32),    # running sumexp
+            pltpu.VMEM((Dv, qmajor), jnp.float32),   # running accumulator
         ],
     )
-    operands = [qmax, imin, kvmin, qpos3]
+    operands = [*p.tables, qpos3]
     if use_kvpos:
         operands.append(kvpos3)
-    operands += [qt, kt, vt]
-    out, lse = named_pallas_call(
+    # v and o cross the kernel's boundary transposed ([.., Dv, L]); the
+    # callers' own [B, L, H, D] <-> [B, H, L, D] transposes absorb it
+    operands += [qt, kt, vt.swapaxes(2, 3)]
+    out_t, lse = named_pallas_call(
         "flash_fwd",
-        functools.partial(_fwd_kernel, scale=scale,
-                          use_kvpos=use_kvpos),
+        functools.partial(_fwd_kernel, scale=scale, use_kvpos=use_kvpos,
+                          nq_sub=p.nq_sub, n_sub=p.n_sub),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lq, Dv), qt.dtype),
-            jax.ShapeDtypeStruct((B, H, Lq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Dv, Lq), qt.dtype),
+            jax.ShapeDtypeStruct((B, H, 1, Lq), jnp.float32),
         ],
         interpret=interpret_mode(),
     )(*operands)
-    return out, lse
+    return out_t.swapaxes(2, 3), lse
 
 
 # ---------------------------------------------------------------------------
@@ -242,46 +375,41 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
 
 
 def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-               scale: float, use_kvpos: bool):
+               scale: float, use_kvpos: bool, nq_sub: int, n_sub: int):
+    kvpos_ref = None
     if use_kvpos:
-        (kvpos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dq_ref, dq_sc) = rest
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-         dq_sc) = rest
+        kvpos_ref, *rest = rest
+    (q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+     dq_sc) = rest
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
+    blk_q, major = q_ref.shape[2] // nq_sub, k_ref.shape[2]
+    blk_kv = major // n_sub
 
     @pl.when(j == 0)
     def _():
         dq_sc[:, :] = jnp.zeros_like(dq_sc)
 
-    @pl.when(kvmin_ref[b, j] <= qmax_ref[b, i])
-    def _():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale
-        do = do_ref[0, 0, :, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, :]
-        delta = delta_ref[0, 0, :, :]
-        blk_q = q_ref.shape[2]
-        blk_kv = k_ref.shape[2]
-        qpos = qpos_ref[0, :, 0]
-        if use_kvpos:
-            kvmat = kvpos_ref[0, 0, :][None, :]
-        else:
-            kvmat = j * blk_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_kv), 1)
-        k = k_ref[0, 0, :, :].astype(jnp.float32)
-        v = v_ref[0, 0, :, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        p = jnp.where(kvmat <= qpos[:, None], jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_sc[:, :] = dq_sc[:, :] + jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
+    def update(cols, c):
+        width = (c + 1) * blk_kv
+        kt = kt_ref[0, 0, :, :width]                             # [D, w]
+        do = do_ref[0, 0, cols, :]                               # [bq, Dv]
+        st = _dot(k_ref[0, 0, :width, :], q_ref[0, 0, cols, :],
+                  _NT) * scale                                   # [w, bq]
+        pt = jnp.where(_seen(qpos_ref, kvpos_ref, j * major,
+                             slice(0, width), cols, st.shape),
+                       jnp.exp(st - lse_ref[0, 0, :, cols]), 0.0)
+        dpt = _dot(v_ref[0, 0, :width, :], do, _NT)              # [w, bq]
+        dst = pt * (dpt - delta_ref[0, 0, :, cols])
+        dq_sc[:, cols] = dq_sc[:, cols] + _dot(
+            kt, dst.astype(kt.dtype), _NN)                       # [D, bq]
+
+    for r in range(nq_sub):
+        _for_each_extent(
+            [kvmin_ref[b, j * n_sub + c] <= qmax_ref[b, i * nq_sub + r]
+             for c in range(n_sub)],
+            functools.partial(update, slice(r * blk_q, (r + 1) * blk_q)),
+            leading=True)
 
     @pl.when(j == nj - 1)
     def _():
@@ -289,197 +417,175 @@ def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
 
 
 def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-                scale: float, use_kvpos: bool):
+                scale: float, use_kvpos: bool, nq_sub: int, n_sub: int):
+    kvpos_ref = None
     if use_kvpos:
-        (kvpos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-         dk_ref, dv_ref, dk_sc, dv_sc) = rest
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-         dv_ref, dk_sc, dv_sc) = rest
+        kvpos_ref, *rest = rest
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+     dv_ref, dk_sc, dv_sc) = rest
     b, j, i = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     ni = pl.num_programs(3)
+    qmajor, major = q_ref.shape[2], k_ref.shape[2]
+    blk_q, blk_kv = qmajor // nq_sub, major // n_sub
 
     @pl.when(i == 0)
     def _():
         dk_sc[:, :] = jnp.zeros_like(dk_sc)
         dv_sc[:, :] = jnp.zeros_like(dv_sc)
 
-    @pl.when(qmax_ref[b, i] >= kvmin_ref[b, j])
-    def _():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale
-        do = do_ref[0, 0, :, :].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, :]
-        delta = delta_ref[0, 0, :, :]
-        blk_q = q_ref.shape[2]
-        blk_kv = k_ref.shape[2]
-        qpos = qpos_ref[0, :, 0]
-        if use_kvpos:
-            kvmat = kvpos_ref[0, 0, :][None, :]
-        else:
-            kvmat = j * blk_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (blk_q, blk_kv), 1)
-        k = k_ref[0, 0, :, :].astype(jnp.float32)
-        v = v_ref[0, 0, :, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, bkv]
-        p = jnp.where(kvmat <= qpos[:, None], jnp.exp(s - lse), 0.0)
-        dv_sc[:, :] = dv_sc[:, :] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bkv, D]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bq, bkv]
-        ds = p * (dp - delta)
-        dk_sc[:, :] = dk_sc[:, :] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [bkv, D]
+    def update(rows, c):
+        cols = slice(c * blk_q, qmajor)     # from q tile c to the block's end
+        q = q_ref[0, 0, cols, :]                                 # [w, D]
+        do = do_ref[0, 0, cols, :]                               # [w, Dv]
+        st = _dot(k_ref[0, 0, rows, :], q, _NT) * scale          # [bkv, w]
+        pt = jnp.where(_seen(qpos_ref, kvpos_ref, j * major + rows.start,
+                             rows, cols, st.shape),
+                       jnp.exp(st - lse_ref[0, 0, :, cols]), 0.0)
+        dv_sc[rows, :] = dv_sc[rows, :] + _dot(pt.astype(do.dtype), do, _NN)
+        dpt = _dot(v_ref[0, 0, rows, :], do, _NT)                # [bkv, w]
+        dst = pt * (dpt - delta_ref[0, 0, :, cols])
+        dk_sc[rows, :] = dk_sc[rows, :] + _dot(dst.astype(q.dtype), q, _NN)
+
+    for r in range(n_sub):
+        # q tile c of this major block holds a query that sees a key of
+        # kv tile r
+        _for_each_extent(
+            [qmax_ref[b, i * nq_sub + c] >= kvmin_ref[b, j * n_sub + r]
+             for c in range(nq_sub)],
+            functools.partial(update, slice(r * blk_kv, (r + 1) * blk_kv)),
+            leading=False)
 
     @pl.when(i == ni - 1)
     def _():
-        dk_ref[0, 0, :, :] = dk_sc[:, :].astype(dk_ref.dtype)  # carries scale
+        dk_ref[0, 0, :, :] = (dk_sc[:, :] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0, :, :] = dv_sc[:, :].astype(dv_ref.dtype)
 
 
 def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
              blk_q, blk_kv, clamp: bool):
     B, H, Lq, D = qt.shape
-    Hkv, Lk = kt.shape[1], kt.shape[2]
+    Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
-    bq = _pick_block(Lq, blk_q)
-    bkv = _pick_block(Lk, blk_kv)
-    nq, nkv = Lq // bq, Lk // bkv
+    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3)
+    qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
-    qmax, imin, kvmin = _block_extents(
-        qpos3[:, :, 0], kvpos3[:, 0, :] if use_kvpos else None,
-        bq, bkv, nkv=nkv)
+    fetch = _kv_fetch(p, clamp)
 
-    if clamp:
-        def kv_map(b, h, i, j, qm, im, km, r=n_rep, bkv=bkv):
-            return (b, h // r, jnp.minimum(j, qm[b, i] // bkv), 0)
+    def kv_map(b, h, i, j, qm, im, km):
+        return (b, h // n_rep, fetch(qm, b, i, j), 0)
 
-        def kvpos_map(b, h, i, j, qm, im, km, bkv=bkv):
-            return (b, 0, jnp.minimum(j, qm[b, i] // bkv))
-    else:
-        def kv_map(b, h, i, j, qm, im, km, r=n_rep):
-            return (b, h // r, j, 0)
+    def kt_map(b, h, i, j, qm, im, km):
+        return (b, h // n_rep, 0, fetch(qm, b, i, j))
 
-        def kvpos_map(b, h, i, j, qm, im, km):
-            return (b, 0, j)
+    def q_rows(b, h, i, j, qm, im, km):
+        return (b, h, i, 0)
 
-    Dv = vt.shape[3]
-    q_spec = pl.BlockSpec((1, 1, bq, D),
-                          lambda b, h, i, j, qm, im, km: (b, h, i, 0))
-    do_spec = pl.BlockSpec((1, 1, bq, Dv),
-                           lambda b, h, i, j, qm, im, km: (b, h, i, 0))
-    row_spec = pl.BlockSpec((1, 1, bq, 1),
-                            lambda b, h, i, j, qm, im, km: (b, h, i, 0))
+    def q_lanes(b, h, i, j, qm, im, km):
+        return (b, h, 0, i)
+
     in_specs = (
-        [pl.BlockSpec((1, bq, 1),
-                      lambda b, h, i, j, qm, im, km: (b, i, 0))]
-        + ([pl.BlockSpec((1, 1, bkv), kvpos_map)] if use_kvpos else [])
-        + [q_spec,
-           pl.BlockSpec((1, 1, bkv, D), kv_map),
-           pl.BlockSpec((1, 1, bkv, Dv), kv_map),
-           do_spec, row_spec, row_spec]
+        [pl.BlockSpec((1, 1, qmajor),
+                      lambda b, h, i, j, qm, im, km: (b, 0, i))]
+        + ([pl.BlockSpec((1, major, 1),
+                         lambda b, h, i, j, qm, im, km: (b, j, 0))]
+           if use_kvpos else [])
+        + [pl.BlockSpec((1, 1, qmajor, D), q_rows),
+           pl.BlockSpec((1, 1, major, D), kv_map),
+           pl.BlockSpec((1, 1, D, major), kt_map),
+           pl.BlockSpec((1, 1, major, Dv), kv_map),
+           pl.BlockSpec((1, 1, qmajor, Dv), q_rows),
+           pl.BlockSpec((1, 1, 1, qmajor), q_lanes),
+           pl.BlockSpec((1, 1, 1, qmajor), q_lanes)]
     )
-    operands = [qmax, imin, kvmin, qpos3]
+    operands = [*p.tables, qpos3]
     if use_kvpos:
         operands.append(kvpos3)
-    operands += [qt, kt, vt, dout_t, lse, delta]
-    return named_pallas_call(
+    # k enters twice: as it lies for s^T = k q^T, transposed for
+    # dq^T = k^T ds^T; dq leaves transposed like the forward's output
+    operands += [qt, kt, kt.swapaxes(2, 3), vt, dout_t, lse, delta]
+    dq_t = named_pallas_call(
         "flash_bwd_dq",
-        functools.partial(_dq_kernel, scale=scale,
-                          use_kvpos=use_kvpos),
+        functools.partial(_dq_kernel, scale=scale, use_kvpos=use_kvpos,
+                          nq_sub=p.nq_sub, n_sub=p.n_sub),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, H, nq, nkv),
+            grid=(B, H, p.nq, p.nkv),
             in_specs=in_specs,
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            out_specs=pl.BlockSpec((1, 1, D, qmajor), q_lanes),
+            scratch_shapes=[pltpu.VMEM((D, qmajor), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, D, Lq), qt.dtype),
         interpret=interpret_mode(),
     )(*operands)
+    return dq_t.swapaxes(2, 3)
 
 
 def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
               blk_q, blk_kv, clamp: bool):
-    """Per-q-head dK/dV [B, H, Lk, D] f32 (caller group-sums GQA)."""
+    """Per-q-head dK/dV [B, H, Lk, D]: float32 where the caller still
+    has to group-sum them (GQA), else in the inputs' dtype."""
     B, H, Lq, D = qt.shape
-    Hkv, Lk = kt.shape[1], kt.shape[2]
+    Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
-    bq = _pick_block(Lq, blk_q)
-    bkv = _pick_block(Lk, blk_kv)
-    nq, nkv = Lq // bq, Lk // bkv
+    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3)
+    qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
-    qmax, imin, kvmin = _block_extents(
-        qpos3[:, :, 0], kvpos3[:, 0, :] if use_kvpos else None,
-        bq, bkv, nkv=nkv)
 
-    if clamp:
-        def q_map(b, h, j, i, qm, im, km):
-            # q-blocks before this kv-block's causal frontier re-fetch
-            # the first relevant block (monotone positions only).
-            return (b, h, jnp.maximum(i, im[b, j]), 0)
+    def first(im, b, j, i):
+        # q major blocks before this kv block's causal frontier (its
+        # first tile's first relevant q tile) re-fetch the first
+        # relevant one: monotone positions only
+        return jnp.maximum(i, im[b, j * p.n_sub] // p.nq_sub) if clamp else i
 
-        def q_row_map(b, h, j, i, qm, im, km):
-            return (b, h, jnp.maximum(i, im[b, j]), 0)
+    def q_rows(b, h, j, i, qm, im, km):
+        return (b, h, first(im, b, j, i), 0)
 
-        def qpos_map(b, h, j, i, qm, im, km):
-            return (b, jnp.maximum(i, im[b, j]), 0)
-    else:
-        def q_map(b, h, j, i, qm, im, km):
-            return (b, h, i, 0)
+    def q_lanes(b, h, j, i, qm, im, km):
+        return (b, h, 0, first(im, b, j, i))
 
-        def q_row_map(b, h, j, i, qm, im, km):
-            return (b, h, i, 0)
+    def kv_in(b, h, j, i, qm, im, km):
+        return (b, h // n_rep, j, 0)
 
-        def qpos_map(b, h, j, i, qm, im, km):
-            return (b, i, 0)
+    def kv_out(b, h, j, i, qm, im, km):
+        return (b, h, j, 0)
 
-    Dv = vt.shape[3]
-    kv_out_spec = pl.BlockSpec((1, 1, bkv, D),
-                               lambda b, h, j, i, qm, im, km: (b, h, j, 0))
-    v_out_spec = pl.BlockSpec((1, 1, bkv, Dv),
-                              lambda b, h, j, i, qm, im, km: (b, h, j, 0))
     in_specs = (
-        [pl.BlockSpec((1, bq, 1), qpos_map)]
-        + ([pl.BlockSpec((1, 1, bkv),
-                         lambda b, h, j, i, qm, im, km: (b, 0, j))]
+        [pl.BlockSpec((1, 1, qmajor),
+                      lambda b, h, j, i, qm, im, km:
+                      (b, 0, first(im, b, j, i)))]
+        + ([pl.BlockSpec((1, major, 1),
+                         lambda b, h, j, i, qm, im, km: (b, j, 0))]
            if use_kvpos else [])
-        + [pl.BlockSpec((1, 1, bq, D), q_map),
-           pl.BlockSpec((1, 1, bkv, D),
-                        lambda b, h, j, i, qm, im, km, r=n_rep:
-                        (b, h // r, j, 0)),
-           pl.BlockSpec((1, 1, bkv, Dv),
-                        lambda b, h, j, i, qm, im, km, r=n_rep:
-                        (b, h // r, j, 0)),
-           pl.BlockSpec((1, 1, bq, Dv), q_map),
-           pl.BlockSpec((1, 1, bq, 1), q_row_map),
-           pl.BlockSpec((1, 1, bq, 1), q_row_map)]
+        + [pl.BlockSpec((1, 1, qmajor, D), q_rows),
+           pl.BlockSpec((1, 1, major, D), kv_in),
+           pl.BlockSpec((1, 1, major, Dv), kv_in),
+           pl.BlockSpec((1, 1, qmajor, Dv), q_rows),
+           pl.BlockSpec((1, 1, 1, qmajor), q_lanes),
+           pl.BlockSpec((1, 1, 1, qmajor), q_lanes)]
     )
-    operands = [qmax, imin, kvmin, qpos3]
+    operands = [*p.tables, qpos3]
     if use_kvpos:
         operands.append(kvpos3)
     operands += [qt, kt, vt, dout_t, lse, delta]
+    grad_dtype = jnp.float32 if n_rep > 1 else kt.dtype
     dk_h, dv_h = named_pallas_call(
         "flash_bwd_dkv",
-        functools.partial(_dkv_kernel, scale=scale,
-                          use_kvpos=use_kvpos),
+        functools.partial(_dkv_kernel, scale=scale, use_kvpos=use_kvpos,
+                          nq_sub=p.nq_sub, n_sub=p.n_sub),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, H, nkv, nq),
+            grid=(B, H, p.nkv, p.nq),
             in_specs=in_specs,
-            out_specs=[kv_out_spec, v_out_spec],
+            out_specs=[pl.BlockSpec((1, 1, major, D), kv_out),
+                       pl.BlockSpec((1, 1, major, Dv), kv_out)],
             scratch_shapes=[
-                pltpu.VMEM((bkv, D), jnp.float32),
-                pltpu.VMEM((bkv, Dv), jnp.float32),
+                pltpu.VMEM((major, D), jnp.float32),
+                pltpu.VMEM((major, Dv), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lk, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, Lk, Dv), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Lk, D), grad_dtype),
+            jax.ShapeDtypeStruct((B, H, Lk, Dv), grad_dtype),
         ],
         interpret=interpret_mode(),
     )(*operands)
@@ -493,7 +599,7 @@ def _bwd_impl(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv, out_t,
     n_rep = H // Hkv
     # delta = rowsum(dO * O) — cheap elementwise, plain XLA.
     delta = jnp.sum(dout_t.astype(jnp.float32) * out_t.astype(jnp.float32),
-                    axis=-1, keepdims=True)                   # [B, H, Lq, 1]
+                    axis=-1)[:, :, None, :]                   # [B, H, 1, Lq]
     dq = _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
                   blk_q, blk_kv, clamp)
     dk_h, dv_h = _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta,
@@ -513,35 +619,38 @@ def _bwd_impl(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv, out_t,
 
 def _check_chunk_alignment(Lq: int, Lk: int, blk_q: int,
                            blk_kv: int) -> None:
-    """Ring chunks feed the explicit-kv-positions kernel variant; on
-    real TPU its blocks must satisfy Mosaic's lane/sublane rules:
-    the kv-position block's lane dim (bkv) must be a multiple of 128
-    or equal the full Lk, and the q block's sublane dim (bq) a
-    multiple of 8 or equal the full Lq.  The standard causal path has
-    no kv-position operand and no such constraint."""
+    """Ring chunks meet real TPU tiling rules with whatever length the
+    mesh leaves them: every tile dim is a lane dim somewhere, so a
+    chunk must tile into multiples of 128 or be one full block (what
+    ``_pick_block`` falls back to).  A full block is only refused where
+    it cannot be meant: longer than the major block."""
     if interpret_mode():
         return
-    bkv = _pick_block(Lk, blk_kv)
-    if bkv % 128 and bkv != Lk:
-        raise ValueError(
-            f"ring-chunk kv length {Lk} tiles into lane blocks of "
-            f"{bkv} on TPU, violating the Mosaic 128-lane rule; use a "
-            "chunk length that is a multiple of 128 (or a power of two "
-            "<= 512)")
-    bq = _pick_block(Lq, blk_q)
-    if bq % 8 and bq != Lq:
-        raise ValueError(
-            f"ring-chunk query length {Lq} tiles into sublane blocks "
-            f"of {bq} on TPU, violating the Mosaic 8-sublane rule; use "
-            "a chunk length that is a multiple of 8")
+    for name, n, blk in (("query", Lq, blk_q), ("kv", Lk, blk_kv)):
+        tile = _pick_block(n, blk)
+        if tile % 128 and n > _MAJOR:
+            raise ValueError(
+                f"ring-chunk {name} length {n} has no tile that is a "
+                "multiple of 128 (the Mosaic lane rule) and is too long "
+                "for one block; use a chunk length that is a multiple "
+                "of 128")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def flash_attention_gqa(q, k, v, q_positions, scale,
-                        blk_q: int = 256, blk_kv: int = 512):
-    # Default blocks from an on-chip sweep at L=2048/D=128 (bf16, v5e):
-    # (256, 512) ≈ 2.9x/2.3x the XLA reference fwd/bwd; small shapes
-    # fall back via _pick_block.
+                        blk_q: int = 512, blk_kv: int = 512):
+    # Default tiles from on-chip sweeps of 2026-09-28 (PR 29; bf16, one
+    # v5e chip; PERF.md section 6) at the shapes the benchmark's cells
+    # run — (16 | 48, 384, 8 heads of 256), (16 | 32, 1024, 32 heads, keys
+    # 192 / values 128), the prefills (48, 256) and (32, 512) — and at
+    # (4, 2048, GQA 32 / 8, 128): (512, 512) is the best or within 5% of
+    # it for all three kernels at every one (a length that 512 does not
+    # divide takes its largest 128-multiple divisor, 384 itself), with
+    # ``_MAJOR`` 1024 (2048 overflows VMEM at 1024-wide tiles and runs
+    # 128 / 256-wide ones slower).  Precision: see the module docstring —
+    # operands in the input dtype, float32 accumulation and softmax
+    # statistics, ``p`` / ``ds`` rounded as ``reference_attention``
+    # rounds ``probs``.
     """Flash attention with positional causal masking.
 
     q: [B, Lq, H, D]; k: [B, Lk, Hkv, D]; v: [B, Lk, Hkv, Dv] (Hkv
@@ -552,7 +661,7 @@ def flash_attention_gqa(q, k, v, q_positions, scale,
     Returns [B, Lq, H, Dv] in q.dtype.
     """
     out, _ = _fwd(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                  v.transpose(0, 2, 1, 3), q_positions[:, :, None],
+                  v.transpose(0, 2, 1, 3), q_positions[:, None, :],
                   None, scale, blk_q, blk_kv, clamp=True)
     return out.transpose(0, 2, 1, 3)
 
@@ -561,7 +670,7 @@ def _vjp_fwd(q, k, v, q_positions, scale, blk_q, blk_kv):
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    qpos3 = q_positions[:, :, None]
+    qpos3 = q_positions[:, None, :]
     out_t, lse = _fwd(qt, kt, vt, qpos3, None, scale, blk_q, blk_kv,
                       clamp=True)
     return out_t.transpose(0, 2, 1, 3), (qt, kt, vt, qpos3, out_t, lse)
@@ -587,7 +696,7 @@ flash_attention_gqa.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 def flash_chunk_fwd(q, k, v, q_positions, kv_positions, scale,
-                    blk_q: int = 256, blk_kv: int = 512):
+                    blk_q: int = 512, blk_kv: int = 512):
     """One ring chunk, flash-blockwise: returns (out [B, Lq, H, D]
     normalized WITHIN the chunk, lse [B, H, Lq] f32).  kv_positions
     [B, Lk] are arbitrary absolute positions (rotated zigzag chunks);
@@ -595,14 +704,14 @@ def flash_chunk_fwd(q, k, v, q_positions, kv_positions, scale,
     caller owns the backward (flash_chunk_grads with the global lse)."""
     _check_chunk_alignment(q.shape[1], k.shape[1], blk_q, blk_kv)
     out_t, lse = _fwd(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                      v.transpose(0, 2, 1, 3), q_positions[:, :, None],
-                      kv_positions[:, None, :], scale, blk_q, blk_kv,
+                      v.transpose(0, 2, 1, 3), q_positions[:, None, :],
+                      kv_positions[:, :, None], scale, blk_q, blk_kv,
                       clamp=False)
-    return out_t.transpose(0, 2, 1, 3), lse[..., 0]
+    return out_t.transpose(0, 2, 1, 3), lse[:, :, 0, :]
 
 
 def flash_chunk_grads(q, k, v, q_positions, kv_positions, out, lse,
-                      dout, scale, blk_q: int = 256, blk_kv: int = 512):
+                      dout, scale, blk_q: int = 512, blk_kv: int = 512):
     """Per-chunk flash backward against the GLOBAL softmax statistics:
     ``lse`` [B, H, Lq] is the all-chunks log-sum-exp and ``out``/
     ``dout`` the FINAL merged output/cotangent — p = exp(s - lse)
@@ -614,8 +723,8 @@ def flash_chunk_grads(q, k, v, q_positions, kv_positions, out, lse,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     dq, dk, dv = _bwd_impl(
-        qt, kt, vt, q_positions[:, :, None], kv_positions[:, None, :],
-        scale, blk_q, blk_kv, out.transpose(0, 2, 1, 3), lse[..., None],
+        qt, kt, vt, q_positions[:, None, :], kv_positions[:, :, None],
+        scale, blk_q, blk_kv, out.transpose(0, 2, 1, 3), lse[:, :, None, :],
         dout.transpose(0, 2, 1, 3), clamp=False)
     return (dq.transpose(0, 2, 1, 3),
             dk.transpose(0, 2, 1, 3).astype(k.dtype),

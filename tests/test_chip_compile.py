@@ -148,6 +148,32 @@ def test_flash_fwd_bwd_compiles_for_v5e(name, one_chip, on_tpu):
                                        "flash_fwd"]
 
 
+def test_flash_ring_chunk_compiles_for_v5e(one_chip, on_tpu):
+    """The ring path's per-chunk entries (explicit kv positions: a
+    lane-major position block in forward and dq, a sublane-major one in
+    the transposed dkv tiles) at a 1024-token chunk of llama-3-8B
+    heads, bf16."""
+    from orion_tpu.ops.pallas.flash_attention import (flash_chunk_fwd,
+                                                      flash_chunk_grads)
+
+    B, L, H, Hkv, D = 2, 1024, 32, 8, 128
+
+    def chunk(q, k, v, dout, qpos, kvpos):
+        out, lse = flash_chunk_fwd(q, k, v, qpos, kvpos, D ** -0.5)
+        return flash_chunk_grads(q, k, v, qpos, kvpos, out, lse, dout,
+                                 D ** -0.5)
+
+    compiled = jax.jit(chunk).lower(
+        _sds((B, L, H, D), BF16, one_chip),
+        _sds((B, L, Hkv, D), BF16, one_chip),
+        _sds((B, L, Hkv, D), BF16, one_chip),
+        _sds((B, L, H, D), BF16, one_chip),
+        _sds((B, L), jnp.int32, one_chip),
+        _sds((B, L), jnp.int32, one_chip)).compile()
+    assert _kernel_names(compiled) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                       "flash_fwd"]
+
+
 # The serving shape: B=48 slots, page_size 64, 288 pages (+1 scratch).
 PAGED = dict(B=48, pages=289, page_size=64, max_pages=6)
 PAGED_WIDTHS = {"pythia1b": (8, 8, 256), "llama8b": (32, 8, 128)}

@@ -7,7 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from orion_tpu.ops.attention import reference_attention, repeat_kv
+from orion_tpu.ops.attention import (reference_attention,
+                                     reference_attention_gqa, repeat_kv)
 from orion_tpu.ops.pallas.flash_attention import flash_attention_gqa
 
 
@@ -224,3 +225,172 @@ def test_flash_on_mesh_matches_reference():
     np.testing.assert_allclose(got, ref, rtol=1e-4)
     for a, b in zip(got_g, ref_g):
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# bf16 inputs: the kernels hand the MXU the operands in the dtype they
+# arrived in, accumulate in float32, and round p / ds for the second
+# product as reference_attention rounds probs (module docstring,
+# "Precision").  The float32 cases above keep their tolerances: float32
+# callers compute what they did.
+# ---------------------------------------------------------------------------
+
+# bf16 keeps 8 significant bits: neighbouring values lie 2^-8 of their
+# size apart.  Tolerances are that step at the reference's largest
+# entry, times a stated factor — derived, not fitted:
+# - forward, 4: flash and the reference each round their output (half a
+#   step each) and their probabilities (each at most 2^-9 * max|v|, and
+#   max|v| <= 3 * max|out| on these inputs);
+# - gradients, 8: two chained rounded products on either side (p, then
+#   ds), and the reference's own bf16 backward rounds its cotangents.
+BF16_STEP = 2.0 ** -8
+FWD_STEPS, GRAD_STEPS = 4, 8
+
+# (H, Hkv, D, Dv): the head shapes of pythia-1b, a llama-3-8B-like GQA
+# and latent attention's expanded keys / values.
+BF16_HEADS = {"d256_h8": (8, 8, 256, 256),
+              "d128_gqa32_8": (32, 8, 128, 128),
+              "d192_dv128": (4, 4, 192, 128)}
+
+
+def _make_bf16(heads, Lq, Lk, B=2, seed=5):
+    H, Hkv, D, Dv = BF16_HEADS[heads]
+    ks = jax.random.split(jax.random.key(seed), 4)
+    bf = jnp.bfloat16
+    return (jax.random.normal(ks[0], (B, Lq, H, D), bf),
+            jax.random.normal(ks[1], (B, Lk, Hkv, D), bf),
+            jax.random.normal(ks[2], (B, Lk, Hkv, Dv), bf),
+            jax.random.normal(ks[3], (B, Lq, H, Dv), bf))
+
+
+def _positions(layout):
+    """(Lq, Lk, q_positions [2, Lq]) — causal, or the chunked-prefill
+    layout of test_forward_ragged_positions (rows continue from 5 and
+    30 over a 64-slot cache)."""
+    if layout == "causal":
+        return 64, 64, jnp.broadcast_to(jnp.arange(64, dtype=jnp.int32),
+                                        (2, 64))
+    starts = jnp.asarray([5, 30], jnp.int32)
+    return 32, 64, starts[:, None] + jnp.arange(32, dtype=jnp.int32)[None]
+
+
+def _assert_within_bf16_steps(got, ref, steps, name=""):
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    tol = steps * BF16_STEP * np.max(np.abs(ref))
+    worst = np.max(np.abs(got - ref))
+    assert worst <= tol, (name, worst, tol)
+
+
+@pytest.mark.parametrize("layout", ["causal", "ragged"])
+@pytest.mark.parametrize("heads", sorted(BF16_HEADS))
+def test_bf16_forward_matches_reference(heads, layout):
+    Lq, Lk, qpos = _positions(layout)
+    q, k, v, _ = _make_bf16(heads, Lq, Lk)
+    scale = q.shape[-1] ** -0.5
+    mask = jnp.arange(Lk)[None, None, :] <= qpos[:, :, None]
+    out = flash_attention_gqa(q, k, v, qpos, scale, 32, 32)
+    assert out.dtype == jnp.bfloat16 and out.shape == q.shape[:3] + (
+        v.shape[-1],)
+    _assert_within_bf16_steps(out, reference_attention_gqa(q, k, v, mask, scale),
+                              FWD_STEPS)
+
+
+@pytest.mark.parametrize("layout", ["causal", "ragged"])
+@pytest.mark.parametrize("heads", sorted(BF16_HEADS))
+def test_bf16_backward_matches_reference(heads, layout):
+    Lq, Lk, qpos = _positions(layout)
+    q, k, v, dout = _make_bf16(heads, Lq, Lk)
+    scale = q.shape[-1] ** -0.5
+    mask = jnp.arange(Lk)[None, None, :] <= qpos[:, :, None]
+    _, vjp_flash = jax.vjp(
+        lambda q, k, v: flash_attention_gqa(q, k, v, qpos, scale, 32, 32),
+        q, k, v)
+    _, vjp_ref = jax.vjp(lambda q, k, v: reference_attention_gqa(q, k, v, mask, scale),
+                         q, k, v)
+    for gf, gr, name in zip(vjp_flash(dout), vjp_ref(dout), "qkv"):
+        assert gf.dtype == jnp.bfloat16 and gf.shape == gr.shape
+        _assert_within_bf16_steps(gf, gr, GRAD_STEPS, name)
+
+
+def test_bf16_chunk_rotated_kv_positions():
+    """The ring path's per-chunk entries in bf16: a zigzag query chunk
+    over a ROTATED kv chunk (piecewise-contiguous, not monotone: one
+    block pair is wholly in the future and skipped).  The chunk holds
+    every key a row may see, so its lse is the global one and
+    flash_chunk_grads must give the reference's gradients."""
+    from orion_tpu.ops.pallas.flash_attention import (flash_chunk_fwd,
+                                                      flash_chunk_grads)
+
+    B, L = 2, 32
+    q, k, v, dout = _make_bf16("d192_dv128", L, L)
+    scale = q.shape[-1] ** -0.5
+    ar = jnp.arange(16, dtype=jnp.int32)
+    qpos = jnp.broadcast_to(jnp.concatenate([ar, 48 + ar]), (B, L))
+    kvpos = jnp.broadcast_to(jnp.concatenate([32 + ar, ar]), (B, L))
+    mask = kvpos[:, None, :] <= qpos[:, :, None]
+    out, lse = flash_chunk_fwd(q, k, v, qpos, kvpos, scale, 16, 16)
+    ref, vjp_ref = jax.vjp(lambda q, k, v: reference_attention_gqa(q, k, v, mask, scale),
+                           q, k, v)
+    assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    _assert_within_bf16_steps(out, ref, FWD_STEPS)
+    grads = flash_chunk_grads(q, k, v, qpos, kvpos, out, lse, dout, scale,
+                              16, 16)
+    for gf, gr, name in zip(grads, vjp_ref(dout), "qkv"):
+        assert gf.dtype == jnp.bfloat16
+        _assert_within_bf16_steps(gf, gr, GRAD_STEPS, name)
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its equations'
+    parameters (the pallas_call's kernel, pl.when's branches, ...)."""
+    from jax.extend import core as jcore
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    yield from _walk_eqns(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_kernel_products_take_the_input_dtype(dtype):
+    """The mechanism's "does it engage" check, read from the jaxprs of
+    the three kernels: every one of the five products (q k^T, p v,
+    do v^T, ds k, p^T do / ds^T q — nine dot_generals over forward, dq
+    and dkv) takes both operands in the INPUT dtype and gives
+    float32, and with bf16 inputs nothing inside a kernel is converted
+    from bf16 up to float32 (that would be a q / k / v / do block on
+    its way to a float32 product: the upcast coming back)."""
+    H, Hkv, D, Dv = BF16_HEADS["d192_dv128"]
+    qpos = jnp.broadcast_to(jnp.arange(64, dtype=jnp.int32), (1, 64))
+    q = jnp.zeros((1, 64, H, D), dtype)
+    k = jnp.zeros((1, 64, Hkv, D), dtype)
+    v = jnp.zeros((1, 64, Hkv, Dv), dtype)
+
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention_gqa(q, k, v, qpos, 0.1, 64, 64),
+            q, k, v)
+        return vjp(out)
+
+    kernels = [e for e in _walk_eqns(jax.make_jaxpr(fwd_bwd)(q, k, v).jaxpr)
+               if e.primitive.name == "pallas_call"]
+    assert len(kernels) == 3  # flash_fwd, flash_bwd_dq, flash_bwd_dkv
+    dots, upcasts = [], []
+    for call in kernels:
+        for e in _walk_eqns(call.params["jaxpr"]):
+            if e.primitive.name == "dot_general":
+                dots.append(e)
+            elif (e.primitive.name == "convert_element_type"
+                  and e.invars[0].aval.dtype == jnp.bfloat16
+                  and e.params["new_dtype"] == jnp.float32):
+                upcasts.append(e)
+    assert len(dots) == 2 + 3 + 4     # one 64 x 64 tile in each kernel
+    for e in dots:
+        assert [x.aval.dtype for x in e.invars] == [dtype, dtype], e
+        assert e.outvars[0].aval.dtype == jnp.float32, e
+    assert not upcasts, upcasts
