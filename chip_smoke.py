@@ -3,10 +3,10 @@
 Drives the two main paths once, through the entry points a user calls,
 at the full Pythia-1B shape (16 layers, random weights from a seed):
 
-  1. trainer: ``orion_tpu.launch.main(["ppo", ...])`` at the bench.py
-     ppo1b shape (shared backbone, remat, scanned layers, bf16 Adam
-     moments, int8 rollout weights + KV, B=48, mb=16, P=256, T=128),
-     one warm-up iteration + 3 steady ones;
+  1. trainer: ``orion_tpu.launch.main(["ppo", ...])`` at the ppo1b
+     shape, the benchmark's ``ppo1b-sync`` job (shared backbone, remat,
+     scanned layers, bf16 Adam moments, int8 rollout weights + KV,
+     B=48, mb=16, P=256, T=128), one warm-up iteration + 3 steady ones;
   2. server: ``orion_tpu.launch.run_serve`` on a thread of the SAME
      process, answered through ``GatewayClient``: two passes (warm-up,
      steady) of 8 ragged streamed requests, two sharing a prefix.

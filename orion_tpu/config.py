@@ -338,30 +338,30 @@ class RolloutConfig:
     # scales, convert fused into the dot — measured 1.76x on the matmul
     # stack) and/or the dense KV cache int8 (per-token-per-head scales)
     # moves the bandwidth floor itself.  Opt-in: off by default so
-    # parity tests see the exact policy; the bench turns both on.  The
-    # training graph is never quantized.
+    # parity tests see the exact policy; the ppo1b-sync job turns both
+    # on.  The training graph is never quantized.
     quantize_weights: bool = False
     quantize_kv: bool = False
-    # Speculative decoding: draft speculative_k tokens per step by
-    # prompt-lookup (match the trailing spec_ngram-gram against
-    # earlier sequence content) and verify all k+1 positions in ONE
-    # chunked forward — decode is HBM-bound, so a step that emits m+1
+    # Speculative decoding, continuous engine only (rollout.engine=
+    # continuous; the fixed-batch RolloutEngine refuses k > 0): each
+    # decoding slot drafts speculative_k tokens per verify wave by
+    # prompt-lookup (the trailing spec_ngram-gram matched against the
+    # slot's earlier content) and all k+1 positions are verified in
+    # ONE chunked forward over the paged pool (k slack positions per
+    # reservation) — decode is HBM-bound, so a wave that emits m+1
     # tokens reads the weights once instead of m+1 times.  0 disables.
     # Exact in both modes: greedy output is token-identical to
     # sequential decode; temperature>0 uses delta-draft speculative
     # sampling whose emitted-token marginal is exactly the tempered
     # sampling distribution (behavior logprobs stay correct for the
-    # async importance ratio).
-    # Simple engine (v1): dense cache only, no repetition penalty /
-    # min_new_tokens.  Continuous engine (v2, PR 10): per-slot
-    # draft/verify over the paged pool with k slack positions per
-    # reservation, composing with repetition_penalty / min_new_tokens
-    # / EOS-stop-in-chunk and with prefix cache + chunked prefill.
+    # async importance ratio).  Composes with repetition_penalty /
+    # min_new_tokens / stop ids inside a chunk, the prefix cache and
+    # chunked prefill.
     speculative_k: int = 0
     spec_ngram: int = 2
-    # Adaptive k (continuous engine): track a per-request acceptance
-    # EMA and skip the verify chunk for waves whose decoding slots all
-    # draft below `spec_breakeven` emitted tokens per verify step (the
+    # Adaptive k: track a per-request acceptance EMA and skip the
+    # verify chunk for waves whose decoding slots all draft below
+    # `spec_breakeven` emitted tokens per verify step (the
     # measured chunk-cost breakeven, ~1.55-1.6x a plain decode step on
     # chip) — cold workloads degrade to plain decode instead of paying
     # the chunk tax, which is what makes speculative_k safe to leave

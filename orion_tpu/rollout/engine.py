@@ -1,22 +1,29 @@
-"""The rollout engine — TPU-native equivalent of the reference's vLLM
-generation engine (SURVEY.md §2 #5, §3c).
+"""The fixed-batch rollout engine — TPU-native equivalent of the
+reference's vLLM generation engine (SURVEY.md §2 #5, §3c).
 
-Design (XLA-first, static shapes):
+One decode path (XLA-first, static shapes):
 - one jitted program per (batch, prompt_len, max_new_tokens) bucket:
   prefill (full-seq forward filling the KV cache) then a
-  ``lax.while_loop`` decode with per-sequence EOS early exit — the loop
-  terminates as soon as every sequence is done, so wall-clock tracks the
-  longest completion, not the static bound;
+  ``lax.while_loop`` of one-token steps with per-sequence EOS early
+  exit — the loop terminates as soon as every sequence is done, so
+  wall-clock tracks the longest completion, not the static bound;
 - per-token logprobs captured in f32 under the *actual* sampling
-  distribution (temperature/top-k/top-p applied);
+  distribution (temperature/top-k/top-p applied), and the raw policy
+  logprobs beside them;
 - ``load_weights`` is the weight hot-reload channel the trainer calls
   between steps (in async mode the weight-sync channel lands here);
 - right-padded prompts with per-sequence lengths; the cache write path
   overwrites the padded tail slot-by-slot during decode (see
-  models.transformer.Attention).
+  models.transformer.Attention);
+- the cache is dense ([B, P+T] per layer; the latent form for
+  ``latent_attention``), int8 under ``quantize_kv``, or paged under
+  ``RolloutConfig.paged`` (block tables + the Pallas paged-decode
+  kernel; slower than dense for a fixed batch, ROADMAP D3(a)).
 
-The paged-KV upgrade (block tables + Pallas paged attention) slots in
-behind the same interface via RolloutConfig.paged.
+Speculative decoding is not here: a lockstep batch advances at its
+slowest row's acceptance, and it lost on the chip (PERF.md section 6,
+PR 30).  ``speculative_k > 0`` is the continuous engine's
+(rollout/continuous.py: per slot, adaptive k) and is refused below.
 """
 
 from __future__ import annotations
@@ -70,6 +77,11 @@ class RolloutEngine:
         self.model_cfg = model_cfg
         self.cfg = cfg
         cfg.check_stop_ids(model_cfg.vocab_size, eos_token_id)
+        if cfg.speculative_k > 0:
+            raise ValueError(
+                "rollout.speculative_k > 0 needs rollout.engine=continuous: "
+                "the fixed-batch engine has one decode path, one token a "
+                "step")
         self.eos_token_id = eos_token_id
         self.pad_token_id = pad_token_id
         self._params = None
@@ -98,31 +110,8 @@ class RolloutEngine:
             self._decode_cfg = dataclasses.replace(
                 self._decode_cfg, quantize_dense=True)
             self._decode_model = type(self._decode_model)(self._decode_cfg)
-        if cfg.speculative_k > 0:
-            if cfg.paged:
-                raise ValueError(
-                    "speculative_k > 0 requires the dense cache "
-                    "(paged=False): the draft chunk writes k+1 "
-                    "positions past the current length, outside a "
-                    "paged reservation")
-            if cfg.repetition_penalty != 1.0 or cfg.min_new_tokens:
-                raise ValueError(
-                    "speculative_k > 0 does not compose with "
-                    "repetition_penalty / min_new_tokens yet")
-            # Verify chunks are k+1 queries wide; at that width the
-            # flash kernel's sub-8-row MXU tiles lose to the XLA
-            # einsum (measured on-chip r5: chunk cost 2.5x -> 1.55x a
-            # plain decode step).  A separate twin pins the reference
-            # path for the CHUNK apply only — prefill (Lq = P) stays
-            # on the main twin so it keeps the flash kernel; both
-            # twins share the same params.
-            self._spec_verify_model = type(self._decode_model)(
-                dataclasses.replace(self._decode_cfg,
-                                    attention_impl="reference"))
         self._generate_jit = jax.jit(
             self._generate, static_argnames=("max_new_tokens",))
-        self._generate_spec_jit = jax.jit(
-            self._generate_spec, static_argnames=("max_new_tokens",))
 
     # -- weight hot-reload channel (trainer → rollout) ------------------
     def load_weights(self, params: Any) -> None:
@@ -161,16 +150,8 @@ class RolloutEngine:
         if params is None:
             raise ValueError("no weights loaded: call load_weights() first")
         T = int(max_new_tokens or self.cfg.max_new_tokens)
-        if self.cfg.speculative_k > 0:
-            out = self._generate_spec_jit(params, prompt_ids, prompt_lens,
-                                          rng, max_new_tokens=T)
-            # diagnostic: verify-forward count (device scalar; fetch
-            # lazily — bench/AB scripts read it, trainers ignore it)
-            self.last_spec_steps = out.pop("spec_steps")
-        else:
-            out = self._generate_jit(params, prompt_ids, prompt_lens, rng,
-                                     max_new_tokens=T)
-        return GenerationResult(**out)
+        return GenerationResult(**self._generate_jit(
+            params, prompt_ids, prompt_lens, rng, max_new_tokens=T))
 
     def _generate(self, params, prompt_ids, prompt_lens, rng,
                   max_new_tokens: int):
@@ -300,219 +281,4 @@ class RolloutEngine:
             policy_logprobs=plogps,
             prompt_lens=prompt_lens,
             total_lens=prompt_lens + comp_len,
-        )
-
-    def _generate_spec(self, params, prompt_ids, prompt_lens, rng,
-                       max_new_tokens: int):
-        """Decode with n-gram (prompt-lookup) speculative drafting:
-        each verify step drafts ``speculative_k`` tokens by matching
-        the trailing ``spec_ngram``-gram against earlier sequence
-        content, runs ONE chunked forward over the k+1 candidate
-        positions, and accepts a prefix — decode reads the full weight
-        set once per verify step instead of once per token, so the
-        speedup is ≈ mean tokens emitted per step on an HBM-bound
-        decode.
-
-        Acceptance is EXACT in both modes:
-          - temperature=0: accept drafts agreeing with argmax of the
-            SAME logits plain greedy would produce — output is
-            bit-identical to sequential greedy regardless of draft
-            quality (a bad draft only costs speed);
-          - temperature>0: delta-draft speculative sampling (the
-            deterministic-draft case of Leviathan et al.): accept
-            draft x with probability p(x) under the tempered/truncated
-            sampling distribution; on rejection resample from p with x
-            excluded.  The emitted token's MARGINAL distribution is
-            exactly p, so ``logprobs`` (= log p(token), the behavior
-            logprob the async importance ratio needs) stays correct —
-            the token stream differs from the sequential path only in
-            which RNG draws produced it, not in distribution.
-
-        Cache consistency (both modes): each chunk writes k+1
-        consecutive positions starting exactly at the first stale
-        position (the previous step's bonus-token slot), so rejected-
-        draft KV is always overwritten before any query position can
-        attend it (queries at position p only attend keys <= p, and
-        the chunk writes before attending — the same property chunked
-        prefill relies on).  The cache is allocated k positions past
-        P+T because the final step's chunk may probe past the budget;
-        those writes land in the slack and are never attended.
-        """
-        cfg = self.cfg
-        gamma = int(cfg.speculative_k)
-        n = int(cfg.spec_ngram)
-        B, P = prompt_ids.shape
-        T = max_new_tokens
-        eos = self.eos_token_id
-        pad = self.pad_token_id
-
-        from orion_tpu.models.transformer import prep_decode_params
-
-        params = prep_decode_params(params, self.model_cfg,
-                                    cfg.quantize_weights)
-
-        from orion_tpu.ops.sampling import (is_stop_token, sample_tokens,
-                                            transformed_logits)
-
-        stochastic = cfg.temperature != 0.0
-
-        # Chunk slack past the budget (init_cache rounds the cache
-        # length itself to a multiple of 8 for Mosaic tiling; the seq
-        # buffer here tracks the same width so draft windows can read
-        # to the end of the cache).
-        cap = -(-(P + T + gamma) // 8) * 8
-        cache = init_cache(self._decode_cfg, B, cap,
-                           dtype=jnp.dtype(self._decode_cfg.dtype),
-                           quantized=cfg.quantize_kv)
-        positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
-        with jax.named_scope("prefill"):
-            logits, cache = self._decode_model.apply(
-                {"params": params}, prompt_ids, positions, cache,
-                logits_positions=(prompt_lens - 1)[:, None])
-        rng, sub = jax.random.split(rng)
-        # first token: one ordinary draw from the sampling distribution
-        # (greedy argmax at temperature 0) — drafting starts after it
-        tok0, lp0, plp0 = sample_tokens(
-            sub, logits[:, 0], temperature=cfg.temperature,
-            top_k=cfg.top_k, top_p=cfg.top_p)
-
-        bidx = jnp.arange(B)
-        tokens = jnp.full((B, T), pad, jnp.int32).at[:, 0].set(tok0)
-        logps = jnp.zeros((B, T), jnp.float32).at[:, 0].set(lp0)
-        plogps = jnp.zeros((B, T), jnp.float32).at[:, 0].set(plp0)
-        done = is_stop_token(tok0, eos, cfg.stop_token_ids) | (T <= 1)
-        comp_len = jnp.ones((B,), jnp.int32)
-        # full-sequence buffer (draft source): prompt + generated
-        seq = jnp.full((B, cap), pad, jnp.int32)
-        seq = jax.lax.dynamic_update_slice(seq, prompt_ids, (0, 0))
-        seq = seq.at[bidx, prompt_lens].set(tok0)
-        ln = prompt_lens + 1            # total content length
-        cur = tok0                      # last token, KV not yet written
-
-        n_win = cap - n - gamma + 1     # draftable window starts
-        w_idx = jnp.arange(n_win)
-
-        def draft_fn(seq, ln):
-            # trailing n-gram of each row
-            tgt = jnp.stack(
-                [jnp.take_along_axis(seq, (ln - n + i)[:, None],
-                                     axis=1)[:, 0] for i in range(n)],
-                axis=1)                                     # [B, n]
-            eq = jnp.ones((B, n_win), bool)
-            for i in range(n):
-                eq &= seq[:, i: i + n_win] == tgt[:, i: i + 1]
-            # latest PRIOR occurrence whose FULL gamma-token
-            # continuation lies inside the content — a match at the
-            # content edge would draft pads past it (a period-1 cycle
-            # then accepts ~1/gamma instead of the full chunk; found
-            # measuring the continuous port, PR 10)
-            valid = eq & (w_idx[None, :] + n + gamma <= ln[:, None])
-            score = jnp.where(valid, w_idx[None, :], -1)
-            s = jnp.max(score, axis=1)                      # [B], -1 = none
-            s0 = jnp.maximum(s, 0)
-            drafts = jnp.stack(
-                [jnp.take_along_axis(seq, (s0 + n + i)[:, None],
-                                     axis=1)[:, 0] for i in range(gamma)],
-                axis=1)                                     # [B, gamma]
-            # no match -> draft pads; they are verified like any draft
-            return jnp.where((s >= 0)[:, None], drafts, pad)
-
-        def cond(c):
-            it, done = c[0], c[5]
-            return (it < T) & ~jnp.all(done)
-
-        def body(c):
-            (it, rng, seq, ln, cur, done, comp_len, tokens, logps,
-             plogps, cache) = c
-            drafts = draft_fn(seq, ln)
-            chunk = jnp.concatenate([cur[:, None], drafts], axis=1)
-            # done rows idle in place: ln is frozen (n_emit 0), so
-            # their chunk rewrites the same slack slots, never attended
-            pos = (ln - 1)[:, None] + jnp.arange(gamma + 1,
-                                                 dtype=jnp.int32)
-            step_logits, cache = self._spec_verify_model.apply(
-                {"params": params}, chunk, pos, cache)
-            raw_lsm = jax.nn.log_softmax(
-                step_logits.astype(jnp.float32), axis=-1)   # [B, g+1, V]
-            if not stochastic:
-                # greedy acceptance: emitted = the model's own argmax
-                p_lsm = raw_lsm
-                e = jnp.argmax(raw_lsm, axis=-1).astype(jnp.int32)
-                acc = jnp.cumprod(
-                    (drafts == e[:, :gamma]).astype(jnp.int32), axis=1)
-                m = jnp.sum(acc, axis=1)                    # [B] 0..gamma
-            else:
-                # delta-draft speculative sampling: accept draft x
-                # w.p. p(x); on rejection resample from p excluding x;
-                # after a full accept, one ordinary bonus draw.  The
-                # marginal of every emitted token is exactly p.
-                t_logits = transformed_logits(
-                    step_logits, cfg.temperature, cfg.top_k, cfg.top_p)
-                p_lsm = jax.nn.log_softmax(t_logits, axis=-1)
-                rng, k_u, k_cat = jax.random.split(rng, 3)
-                u = jax.random.uniform(k_u, (B, gamma))
-                p_draft = jnp.exp(jnp.take_along_axis(
-                    p_lsm[:, :gamma], drafts[..., None],
-                    axis=-1)[..., 0])                       # [B, gamma]
-                acc = jnp.cumprod((u < p_draft).astype(jnp.int32),
-                                  axis=1)
-                m = jnp.sum(acc, axis=1)                    # [B] 0..gamma
-                # per-position correction draws: position j < gamma →
-                # residual (draft excluded); position gamma → bonus
-                excl = jnp.full((B, gamma + 1, t_logits.shape[-1]),
-                                False).at[
-                    bidx[:, None], jnp.arange(gamma)[None, :],
-                    drafts].set(True)
-                resampled = jax.random.categorical(
-                    k_cat, jnp.where(excl, jnp.float32(-1e10), t_logits),
-                    axis=-1).astype(jnp.int32)              # [B, g+1]
-                e = jnp.where(
-                    jnp.arange(gamma + 1)[None, :] < m[:, None],
-                    jnp.pad(drafts, ((0, 0), (0, 1))), resampled)
-            lp_e = jnp.take_along_axis(p_lsm, e[..., None],
-                                       axis=-1)[..., 0]     # [B, g+1]
-            plp_e = jnp.take_along_axis(raw_lsm, e[..., None],
-                                        axis=-1)[..., 0]
-            stopped = jnp.zeros((B,), bool)
-            n_emit = jnp.zeros((B,), jnp.int32)
-            last_tok = cur
-            for j in range(gamma + 1):
-                e_j = e[:, j]
-                valid = (~done) & (j <= m) & ~stopped & (comp_len + j < T)
-                wi = jnp.where(valid, comp_len + j, T)
-                tokens = tokens.at[bidx, wi].set(e_j, mode="drop")
-                logps = logps.at[bidx, wi].set(lp_e[:, j], mode="drop")
-                plogps = plogps.at[bidx, wi].set(plp_e[:, j],
-                                                 mode="drop")
-                si = jnp.where(valid, ln + j, cap)
-                seq = seq.at[bidx, si].set(e_j, mode="drop")
-                stopped = stopped | (valid & is_stop_token(
-                    e_j, eos, cfg.stop_token_ids))
-                n_emit = n_emit + valid
-                last_tok = jnp.where(valid, e_j, last_tok)
-            comp_len = comp_len + n_emit
-            ln = ln + n_emit
-            done = done | stopped | (comp_len >= T)
-            return (it + 1, rng, seq, ln, last_tok, done, comp_len,
-                    tokens, logps, plogps, cache)
-
-        init = (jnp.int32(1), rng, seq, ln, cur, done, comp_len, tokens,
-                logps, plogps, cache)
-        with jax.named_scope("spec_decode"):
-            (it, rng, seq, ln, cur, done, comp_len, tokens, logps,
-             plogps, cache) = jax.lax.while_loop(cond, body, init)
-
-        mask = (jnp.arange(T)[None, :] < comp_len[:, None]).astype(
-            jnp.float32)
-        sequences = pack_sequences(prompt_ids, prompt_lens, tokens)
-        return dict(
-            sequences=sequences,
-            completions=tokens,
-            completion_mask=mask,
-            completion_lens=comp_len,
-            logprobs=logps,
-            policy_logprobs=plogps,
-            prompt_lens=prompt_lens,
-            total_lens=prompt_lens + comp_len,
-            spec_steps=it - 1,
         )

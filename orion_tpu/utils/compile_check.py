@@ -1,9 +1,10 @@
 """AOT compile checks for shapes one chip can't train (SURVEY.md §6:
 the 8B leg of the BASELINE metric).  Tracing/lowering allocates no
 model buffers, so the FULL llama3_8b shared-trunk PPO update step can
-be verified to build — single-device (bench.py) or sharded over a mesh
-with the real fsdp/tensor layouts (dryrun_multichip, where .compile()
-also runs the SPMD partitioner and checks collective legality).
+be verified to build — single-device or sharded over a mesh with the
+real fsdp/tensor layouts (``__graft_entry__.dryrun_multichip`` and
+``tests/test_chip_compile.py``, where .compile() also runs the SPMD
+partitioner and checks collective legality).
 """
 
 from __future__ import annotations
@@ -85,10 +86,9 @@ def lower_8b_update(mesh=None, compile: bool = False,
                     model_cfg=None) -> str:
     """Trace + lower (and optionally compile) the full 8B update step.
 
-    mesh=None: single-device shapes (bench.py's compile check).  With a
-    mesh: params carry the real fsdp/tensor NamedShardings and
-    ``compile=True`` runs the SPMD partitioner over it.  Returns a
-    short status string.
+    mesh=None: single-device shapes.  With a mesh: params carry the
+    real fsdp/tensor NamedShardings and ``compile=True`` runs the SPMD
+    partitioner over it.  Returns a short status string.
     """
     from orion_tpu import obs
     from orion_tpu.trainers.base import BaseTrainer

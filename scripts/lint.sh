@@ -53,7 +53,7 @@ for arg in "$@"; do
     esac
 done
 if [ "${#paths[@]}" -eq 0 ]; then
-    paths=(orion_tpu tests scripts bench.py __graft_entry__.py)
+    paths=(orion_tpu tests scripts __graft_entry__.py)
 fi
 # ${arr[@]+...} guards the empty-array expansion: under `set -u`,
 # bash < 4.4 treats a bare "${flags[@]}" on an empty array as unbound.

@@ -25,8 +25,7 @@ from orion_tpu.analysis import (RULES, analyze_paths, analyze_source,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-LINT_PATHS = ("orion_tpu", "tests", "scripts", "bench.py",
-              "__graft_entry__.py")
+LINT_PATHS = ("orion_tpu", "tests", "scripts", "__graft_entry__.py")
 
 
 def ids_of(findings):

@@ -93,7 +93,7 @@ def _kernel_names(compiled) -> list:
 
 # (B, Lq, Lk, H, Hkv, D, first q position)
 FLASH_SHAPES = {
-    # the ppo1b update/experience shape (bench.py, chip_smoke.py)
+    # the ppo1b update/experience shape (chip_smoke.py, ppo1b-sync)
     "pythia1b": (16, 384, 384, 8, 8, 256, 0),
     # llama3-8B width (GQA 32/8, D=128)
     "llama8b": (4, 1024, 1024, 32, 8, 128, 0),

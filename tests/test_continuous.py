@@ -174,8 +174,9 @@ def _serving_setup():
 
 def test_chunked_prefill_matches_oneshot():
     """chunked_prefill_tokens splits admission across decode segments;
-    greedy output must equal the one-shot prefill bit-for-bit (the
-    chunk forward attends the gathered pool with the same mask)."""
+    greedy tokens must equal the one-shot prefill's bit-for-bit (the
+    chunk forward attends the gathered pool with the same mask) and
+    the logprobs to 4 float32 ulps."""
     cfg, model, params = _serving_setup()
     rng = np.random.RandomState(0)
     prompts = [rng.randint(1, cfg.vocab_size, n).astype(np.int32)
@@ -192,7 +193,11 @@ def test_chunked_prefill_matches_oneshot():
     for i in base:
         np.testing.assert_array_equal(out[i].tokens, base[i].tokens,
                                       err_msg=f"req {i}")
-        np.testing.assert_array_equal(out[i].logprobs, base[i].logprobs)
+        # the chunk program and the one-shot program differ in shape:
+        # XLA:CPU may round a float32 logprob one ulp apart
+        assert out[i].logprobs.shape == base[i].logprobs.shape
+        np.testing.assert_array_max_ulp(out[i].logprobs, base[i].logprobs,
+                                        maxulp=4)
 
 
 def test_prefix_cache_bit_exact_trajectories():

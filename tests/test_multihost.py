@@ -14,6 +14,8 @@ import os
 import socket
 import subprocess
 import sys
+import tempfile
+import time
 
 import jax
 import pytest
@@ -82,9 +84,13 @@ jax.distributed.shutdown()
 """
 
 
-def _run_two_process(worker_src, timeout=420):
+def _run_two_process(worker_src, timeout=120):
     """Launch two coordinator-joined worker processes running
-    ``worker_src`` and collect their RESULT lines."""
+    ``worker_src`` and collect their RESULT lines.  Each child writes to
+    a file of its own, not a pipe: a pipe that nobody reads fills at
+    64 KB (a warm compile cache logs ~2 KB per hit) and blocks its
+    writer, and the two workers wait for each other.  ``timeout``
+    bounds both children together."""
     with socket.socket() as s:  # orion: ignore[raw-socket] free-port probe, no IO
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
@@ -92,19 +98,30 @@ def _run_two_process(worker_src, timeout=420):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))) + os.pathsep + env.get("PYTHONPATH", "")
+    logs = [tempfile.TemporaryFile(mode="w+") for _ in range(2)]
     procs = [subprocess.Popen(
         [sys.executable, "-c", worker_src, coord, str(i)],
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, env=env,
-        text=True) for i in range(2)]
-    outs = []
+        stdout=log, stderr=subprocess.STDOUT, env=env, text=True)
+        for i, log in enumerate(logs)]
+    deadline = time.monotonic() + timeout
+    hung = False
     for p in procs:
         try:
-            out, _ = p.communicate(timeout=timeout)
+            p.wait(timeout=max(0.0, deadline - time.monotonic()))
         except subprocess.TimeoutExpired:
-            for q in procs:
-                q.kill()
-            pytest.fail("multi-host worker hung")
-        outs.append(out)
+            hung = True
+    if hung:
+        for p in procs:
+            p.kill()
+            p.wait()
+    outs = []
+    for log in logs:
+        log.seek(0)
+        outs.append(log.read())
+        log.close()
+    if hung:
+        pytest.fail(f"multi-host worker hung for {timeout} s:\n" + "\n---\n"
+                    .join(out[-1500:] for out in outs))
     for i, (p, out) in enumerate(zip(procs, outs)):
         assert p.returncode == 0, f"worker {i} failed:\n{out[-3000:]}"
     results = {}
@@ -392,7 +409,6 @@ def test_two_process_async_decoupled(engine):
     SHARDED rollout mesh with direct-sharded snapshot installs;
     engine="continuous" runs the paged continuous engine with
     shared-prefix group admission feeding the same channel."""
-    results = _run_two_process(
-        _ASYNC_WORKER.replace("__ENGINE__", engine), timeout=420)
+    results = _run_two_process(_ASYNC_WORKER.replace("__ENGINE__", engine))
     assert results[1] == ("ok",), results
     assert results[0][0] == "staleness=0,1,1", results

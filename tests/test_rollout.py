@@ -174,3 +174,16 @@ def test_cache_length_rounds_to_multiple_of_8(tiny_setup):
                        max_new_tokens=25)
     assert res.completions.shape == (2, 25)
     assert np.isfinite(np.asarray(res.policy_logprobs)).all()
+
+
+@pytest.mark.parametrize("arch", ["tiny", "tiny_deepseek_v3"])
+def test_fixed_batch_engine_refuses_speculative_k(arch):
+    """One decode path here: speculative decoding is the continuous
+    engine's, and the refusal says so — also for the latent-attention
+    block, whose prompt padding a second decode path once routed
+    through the experts."""
+    cfg = (ModelConfig.tiny(dtype="float32") if arch == "tiny"
+           else ModelConfig.tiny("deepseek_v3", dtype="float32"))
+    with pytest.raises(ValueError, match="rollout.engine=continuous"):
+        RolloutEngine(Transformer(cfg), cfg,
+                      RolloutConfig(speculative_k=4), eos_token_id=None)

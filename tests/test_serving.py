@@ -1,7 +1,7 @@
 """Arrivals-trace serving smoke (PR 8, tier-1): drive the
-ContinuousBatchingEngine as a standing service through a Poisson
-arrivals trace with ragged budgets, shared prefixes and deadlines —
-the exact workload scripts/bench_ragged.py measures — on a tiny model
+ContinuousBatchingEngine as a standing service through a seeded
+arrivals trace (``benchmarks/traffic_gen.py``: Poisson arrivals, shared
+prefixes, ragged heavy-tailed budgets) with deadlines, on a tiny model
 in seconds, so the serving path is exercised by `-m 'not slow'`.
 
 PR 12 added the network front door: ServingGateway/GatewayClient
@@ -9,7 +9,10 @@ end-to-end over real TCP (submit/stream/cancel, typed overload
 backpressure across the wire) and the ``launch.py serve`` entrypoint
 smoke through the in-process harness."""
 
+import importlib.util
+import os
 import queue
+import sys
 import threading
 import time
 
@@ -17,25 +20,91 @@ import jax
 import numpy as np
 import pytest
 
-import scripts.bench_ragged as bench
+_N_REQ, _P, _T = 10, 32, 16
+# ten requests over 0.2 s: one of 3 shared 16-token prefixes + a private
+# part of 4-16 tokens (P = 32), budgets of 2-16 (T = 16)
+_MIX = dict(name="serving-smoke", loop="open", rate_per_s=50.0,
+            arrival_cv=1.0, warm_seconds=0.0, sizes_seed=3,
+            prefix=dict(count=3, tokens=16, zipf_s=1.0),
+            prompt=dict(median=10, sigma=0.5, min=4, max=_P - 16),
+            budget=dict(median=6, sigma=0.8, min=2, max=_T))
 
 
-def _smoke_shape():
-    return dict(model="tiny", n_req=10, B=4, P=32, T=16, page_size=8,
-                seg=4, chunk=16)
+def _traffic_gen():
+    """``benchmarks/traffic_gen.py`` (no package: loaded by path)."""
+    name = "orion_traffic_gen"
+    if name not in sys.modules:
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "traffic_gen.py")
+        spec = importlib.util.spec_from_file_location(name, path)
+        sys.modules[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules[name])
+    return sys.modules[name]
+
+
+def _trace(seed, vocab=200):
+    """(prompts, budgets, arrivals) of the smoke mix for one seed."""
+    reqs = _traffic_gen().open_schedule(_MIX, seed, _N_REQ / 50.0, vocab)
+    assert len(reqs) == _N_REQ
+    return ([r.prompt for r in reqs],
+            np.asarray([r.budget for r in reqs], np.int32),
+            np.asarray([r.due_s for r in reqs]))
+
+
+def _engine():
+    from orion_tpu.config import ModelConfig, RolloutConfig
+    from orion_tpu.models import Transformer, init_params
+    from orion_tpu.rollout.continuous import ContinuousBatchingEngine
+
+    mc = ModelConfig.tiny(dtype="float32")
+    model = Transformer(mc)
+    cont = ContinuousBatchingEngine(
+        model, mc, RolloutConfig(
+            max_prompt_len=_P, max_new_tokens=_T, temperature=1.0,
+            max_batch_size=4, page_size=8, segment_len=4,
+            prefix_cache=True, chunked_prefill_tokens=16,
+            admission_policy="deadline"),
+        eos_token_id=None, pad_token_id=0)
+    cont.load_weights(init_params(model, jax.random.key(0), mc))
+    return cont
+
+
+def _serve(cont, prompts, budgets, arrivals, deadlines):
+    """The standing-service loop: submit each request when it is due,
+    one engine wave per iteration.  Returns every CompletedRequest and
+    the time each finished at (seconds from the start)."""
+    n = len(prompts)
+    cont.reset_rng(jax.random.key(17))
+    t0 = time.monotonic()
+    done, done_t, i_next = {}, np.zeros(n), 0
+    while len(done) < n:
+        now = time.monotonic() - t0
+        while i_next < n and arrivals[i_next] <= now:
+            cont.submit(i_next, prompts[i_next],
+                        budget=int(budgets[i_next]),
+                        deadline=int(deadlines[i_next] * 1e6))
+            i_next += 1
+        if cont.pending == 0:  # idle until the next arrival
+            time.sleep(max(0.0, arrivals[i_next]
+                           - (time.monotonic() - t0)))
+            continue
+        for r in cont.step():
+            done[r.req_id] = r
+            done_t[r.req_id] = time.monotonic() - t0
+    return done, done_t
 
 
 def test_arrivals_trace_end_to_end():
-    sh = _smoke_shape()
-    mc, params, dense, cont = bench.build_engines(sh)
-    prompts, budgets, arrivals, deadlines = bench.make_trace(
-        sh, seed=3, cap_toks_per_sec=None)  # all-at-once: no sleeps
-    wall_d, done_d = bench.serve_dense(dense, sh, prompts, budgets,
-                                       arrivals)
-    wall_c, done_c = bench.serve_continuous(cont, sh, prompts, budgets,
-                                            arrivals, deadlines)
-    assert (done_c > 0).all() and (done_d > 0).all()
-    assert wall_c > 0 and wall_d > 0
+    """Everything due at once: the whole trace queues behind 4 slots,
+    completes to its budgets, hits the shared prefixes, and leaves the
+    scheduler and the page pool empty."""
+    cont = _engine()
+    prompts, budgets, _ = _trace(seed=3)
+    done, done_t = _serve(cont, prompts, budgets, np.zeros(_N_REQ),
+                          np.full(_N_REQ, 1e9))
+    assert (done_t > 0).all()
+    for i in range(_N_REQ):      # eos=None: every budget is used up
+        assert len(done[i].tokens) == budgets[i]
     # the serving loop exercised the new machinery
     assert cont.prefix_cached_pages > 0          # shared templates hit
     assert cont.sched.running == 0 and cont.sched.waiting == 0
@@ -46,30 +115,27 @@ def test_arrivals_trace_with_real_arrivals_and_deadlines():
     """Timed arrivals (short span) through the submit/step service:
     every request completes, respecting budgets, with the deadline
     admission policy active."""
-    sh = _smoke_shape()
-    mc, params, dense, cont = bench.build_engines(sh)
-    rs = np.random.RandomState(0)
-    N = sh["n_req"]
-    prompts = [rs.randint(2, 200, rs.randint(8, sh["P"] + 1))
-               .astype(np.int32) for _ in range(N)]
-    budgets = rs.randint(2, sh["T"] + 1, N).astype(np.int32)
-    arrivals = np.sort(rs.uniform(0.0, 0.2, N))
-    arrivals[0] = 0.0
-    deadlines = arrivals + 30.0
-    wall, done_t = bench.serve_continuous(cont, sh, prompts, budgets,
-                                          arrivals, deadlines)
+    cont = _engine()
+    prompts, budgets, arrivals = _trace(seed=0)
+    assert (np.diff(arrivals) >= 0).all() and arrivals[-1] <= 0.2
+    done, done_t = _serve(cont, prompts, budgets, arrivals,
+                          arrivals + 30.0)
     assert (done_t >= arrivals).all()
+    assert all(len(done[i].tokens) == budgets[i] for i in range(_N_REQ))
     assert cont.pending == 0
 
 
-def test_bench_trace_is_deterministic():
-    sh = _smoke_shape()
-    a = bench.make_trace(sh, seed=5, cap_toks_per_sec=100.0)
-    b = bench.make_trace(sh, seed=5, cap_toks_per_sec=100.0)
+def test_seeded_trace_is_deterministic():
+    """One seed, one trace; another seed, the same sizes and arrival
+    times in another order (what the serving tests above rely on)."""
+    a, b, c = _trace(seed=5), _trace(seed=5), _trace(seed=6)
     for x, y in zip(a[0], b[0]):
         np.testing.assert_array_equal(x, y)
     np.testing.assert_array_equal(a[1], b[1])
     np.testing.assert_allclose(a[2], b[2])
+    assert sorted(a[1]) == sorted(c[1])
+    assert any(len(x) != len(y) or (x != y).any()
+               for x, y in zip(a[0], c[0]))
 
 
 # -- PR 12: streaming gateway over real TCP ---------------------------

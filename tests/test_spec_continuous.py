@@ -58,7 +58,7 @@ def _assert_same(out, base, lp_tol=1e-5):
                                    rtol=lp_tol, atol=lp_tol)
 
 
-@pytest.mark.parametrize("eos,k", [(None, 4), (5, 1), (5, 4)])
+@pytest.mark.parametrize("eos,k", [(None, 4), (5, 1), (5, 4), (None, 1)])
 def test_spec_continuous_matches_plain_greedy(eos, k):
     """Token-identical to the sequential continuous engine at temp 0,
     including EOS retirement mid-chunk, for more requests than slots
@@ -212,6 +212,56 @@ def test_spec_stochastic_second_token_distribution():
     assert tv < 0.12, tv
 
 
+def test_spec_stochastic_logprob_accounting():
+    """At temperature 0.7 every emitted token's behavior logprob must
+    be log p(token) under the TEMPERED distribution and its
+    policy_logprob the raw model logprob, whichever branch emitted it
+    (accepted draft, residual resample, bonus draw): recompute both
+    from the training graph's full forward over prompt + completion.
+    The lm_head is scaled 32x so the random model is peaky enough to
+    repeat itself: drafts match, some are accepted, some rejected."""
+    cfg, model, params = _setup()
+    params = dict(params, lm_head=jax.tree.map(lambda x: x * 32.0,
+                                               params["lm_head"]))
+    temp = 0.7
+    eng = _mk(model, cfg, 4, temperature=temp, max_new_tokens=32)
+    reqs = _reqs(cfg, n=4, seed=11)
+    out = {r.req_id: r for r in eng.generate(reqs, jax.random.key(5),
+                                             params)}
+    st = eng.server_stats()
+    assert 0 < st["spec_accepted"] < st["spec_drafted"], st
+    for rid, prompt in reqs:
+        r = out[rid]
+        n, p = len(r.tokens), len(prompt)
+        assert n == 32
+        seq = np.concatenate([prompt, r.tokens])[None]
+        pos = np.arange(seq.shape[1], dtype=np.int32)[None]
+        logits, _ = model.apply({"params": params}, seq, pos)
+        # logits at position t predict token t + 1
+        pred = np.asarray(logits[0, p - 1: p - 1 + n], np.float32)
+        raw = np.asarray(jax.nn.log_softmax(pred, axis=-1))
+        tempered = np.asarray(jax.nn.log_softmax(pred / temp, axis=-1))
+        idx = np.arange(n)
+        np.testing.assert_allclose(r.policy_logprobs, raw[idx, r.tokens],
+                                   rtol=2e-4, atol=2e-4,
+                                   err_msg=f"req {rid}")
+        np.testing.assert_allclose(r.logprobs, tempered[idx, r.tokens],
+                                   rtol=2e-4, atol=2e-4,
+                                   err_msg=f"req {rid}")
+        assert (r.logprobs != r.policy_logprobs).any()
+
+
+def _cyclic_reqs(cfg, n=6, seed=4, period=2, length=14):
+    """Prompts that repeat a short random pattern: the tiny model's
+    greedy continuation settles into a cycle within a few tokens, so
+    the trailing n-gram recurs and an always-on engine drafts (and
+    accepts) on nearly every wave."""
+    rng = np.random.RandomState(seed)
+    return [(i, np.tile(rng.randint(1, cfg.vocab_size, period),
+                        length // period + 1)[:length].astype(np.int32))
+            for i in range(n)]
+
+
 def test_spec_adaptive_goes_cold_and_probes():
     """Adaptive k with an unreachable breakeven (> k+1, so even a
     fully-accepting request can never qualify): every draftable
@@ -219,32 +269,37 @@ def test_spec_adaptive_goes_cold_and_probes():
     wave runs the plain segment — trajectories stay identical to
     spec-off (greedy: the wave mode never changes content), and the
     chunk tax collapses to the probes.  A shorter spec_probe_period
-    forces extra probe waves on top."""
+    forces extra probe waves on top.  The traffic is cyclic and long
+    (64 tokens a request), so the always-on engine drafts on nearly
+    every wave and the probes are a small part of that."""
     cfg, model, params = _setup()
-    reqs = _reqs(cfg, seed=4)
-    base = {r.req_id: r for r in _mk(model, cfg, 0).generate(
+    reqs = _cyclic_reqs(cfg)
+    k, kw = 4, dict(max_new_tokens=64)
+    base = {r.req_id: r for r in _mk(model, cfg, 0, **kw).generate(
         reqs, jax.random.key(8), params)}
-    always = _mk(model, cfg, 4, spec_adaptive=False)
+    always = _mk(model, cfg, k, spec_adaptive=False, **kw)
     out = {r.req_id: r for r in always.generate(reqs, jax.random.key(8),
                                                 params)}
     _assert_same(out, base)
-    cold = _mk(model, cfg, 4, spec_adaptive=True,
-               spec_breakeven=6.0, spec_probe_period=0)
+    cold = _mk(model, cfg, k, spec_adaptive=True,
+               spec_breakeven=6.0, spec_probe_period=0, **kw)
     out = {r.req_id: r for r in cold.generate(reqs, jax.random.key(8),
                                               params)}
     _assert_same(out, base)
     # proven-cold requests stop drafting: far fewer drafts than the
-    # always-on engine (probes only)
+    # always-on engine (probes only; a probe wave drafts for every
+    # matched slot, so at most slots * k a request)
     d_cold = cold.server_stats()["spec_drafted"]
     d_always = always.server_stats()["spec_drafted"]
+    assert 0 < d_cold <= len(reqs) * cold.slots * k, d_cold
     assert d_cold < d_always / 2, (d_cold, d_always)
 
-    probing = _mk(model, cfg, 4, spec_adaptive=True,
-                  spec_breakeven=6.0, spec_probe_period=2)
+    probing = _mk(model, cfg, k, spec_adaptive=True,
+                  spec_breakeven=6.0, spec_probe_period=2, **kw)
     out = {r.req_id: r for r in probing.generate(reqs, jax.random.key(8),
                                                  params)}
     _assert_same(out, base)  # greedy: probing never changes content
-    assert probing.server_stats()["spec_drafted"] >= d_cold
+    assert d_cold < probing.server_stats()["spec_drafted"] < d_always
 
 
 def test_spec_unstructured_text_never_drafts():

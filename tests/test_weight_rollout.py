@@ -406,6 +406,8 @@ def test_fleet_roll_mid_traffic_zero_drops(fleet, setup):
             assert ev.completed.tokens.size == 6         # full budget
         assert co.version == 1
         assert gw.stats["rollout_commits"] >= 1.0
+        # one drain cycle per engine of the fleet, no more
+        assert gw.stats["rollout_drains"] == float(len(fleet))
         for eng in fleet:
             assert eng.params_snapshot() is new
     finally:
@@ -432,10 +434,15 @@ def test_drain_deadline_migrates_streams(fleet, setup):
         rids = [cl.submit(rng.randint(1, cfg.vocab_size, 12)
                           .astype(np.int32), budget=8)
                 for _ in range(8)]
+        # Hold the pump until all eight submits are queued, then apply
+        # them in ONE step: pumping while they trickle in lets early
+        # requests finish, and a count of pending never reaches 8.
         deadline = time.monotonic() + 60.0
-        while fleet[0].pending < 8:
-            assert time.monotonic() < deadline
-            gw.step()
+        while gw._ops.qsize() < len(rids):
+            assert time.monotonic() < deadline, "submits never arrived"
+            time.sleep(0.005)
+        gw.step()
+        assert fleet[0].pending == len(rids) and fleet[1].pending == 0
         gw.set_engine_admit(1, True)
         co.begin(_perturb(params), version=1)
         chunks, finals, done_counts, restarted = _pump_drain(
