@@ -46,7 +46,11 @@ class ModelConfig:
     mlp_bias: bool = False
     dtype: str = "bfloat16"  # compute dtype
     param_dtype: str = "float32"  # master weights
-    remat: bool = False  # jax.checkpoint each block (HBM <-> FLOPs trade)
+    # jax.checkpoint each block: recompute in the backward what does not
+    # fit.  Kept is the block's input and, in a trainer's update, the
+    # tagged tensors (models/transformer.py REMAT_TAGS) that the device's
+    # free memory holds; nothing more where no budget is known (the CPU).
+    remat: bool = False
     attention_impl: str = "auto"  # "auto" | "reference" | "flash" | "ring"
     scan_layers: bool = False  # lax.scan over stacked layers (compile-time win)
     # Dense layers read int8 kernels (QuantDense layout — see

@@ -42,11 +42,12 @@ class ActorCriticModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions, cache=None,
                  with_values: bool = False, skip_lm_head: bool = False,
-                 logits_positions=None, token_mask=None):
+                 logits_positions=None, token_mask=None,
+                 remat_keep: tuple = ()):
         logits, new_cache, hidden = Transformer(self.cfg, name="backbone")(
             input_ids, positions, cache, return_hidden=True,
             skip_lm_head=skip_lm_head, logits_positions=logits_positions,
-            token_mask=token_mask)
+            token_mask=token_mask, remat_keep=remat_keep)
         vk = self.param(
             "value_head",
             nn.with_logical_partitioning(

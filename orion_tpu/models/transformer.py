@@ -23,7 +23,13 @@ Design notes (TPU-first):
   arrays) rather than a flax mutable collection, so the decode step
   nests cleanly inside ``lax.while_loop`` in the rollout engine.
 - Compute dtype bf16, params f32, softmax/logits/logprobs f32.
-- ``remat=True`` wraps each block in ``jax.checkpoint`` (HBM↔FLOPs).
+- ``remat=True`` wraps each block in ``jax.checkpoint``: recompute in
+  the backward what does not fit.  Kept is the block's input and, of
+  the tensors a block tags (:data:`REMAT_TAGS`: the outputs of matrix
+  products, of the attention kernel and of the expert layer's sort),
+  those the caller names in ``remat_keep``, which a trainer chooses
+  from the device's free memory (:func:`remat_keep`).  With none named,
+  as for every caller that gives no budget, the block's input alone.
 """
 
 from __future__ import annotations
@@ -33,6 +39,7 @@ from typing import Any, List, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from orion_tpu.config import ModelConfig
 from orion_tpu.ops.attention import _NEG_INF, attention
@@ -48,6 +55,82 @@ from orion_tpu.ops.rotary import apply_rotary
 KVCache = Any
 
 _dt = lambda s: jnp.dtype(s)  # noqa: E731
+
+#: What a block tags (``jax.ad_checkpoint.checkpoint_name``) for its
+#: checkpoint to keep, in the order it is kept: milliseconds of
+#: recomputation saved per byte held, by the products' operations over
+#: the tensors' bytes at the widths of the two models with a chip
+#: record (PERF.md section 6, PR 31).  ``moe_route``: the router's
+#: scores and selection and the sort of the pairs (ops/moe.py);
+#: ``attn_resid``: ``x + attn`` of a sequential block, which the MLP's
+#: input is rebuilt from (the output projection is not run again);
+#: ``mlp_pre``: the MLP's up (gate / up) projection, the shared
+#: experts' too; ``attn_out`` and ``attn_qkv``: what the flash kernel
+#: gives and what it takes (ops/pallas/flash_attention.py: its backward
+#: kernels read both as they lie).
+REMAT_TAGS = ("moe_route", "attn_resid", "mlp_pre", "attn_out", "attn_qkv")
+
+
+def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
+                    lane: int = 1):
+    """((tag, bytes), ...) in :data:`REMAT_TAGS` order, for the tags
+    this model's blocks have: what keeping a tag holds over all layers
+    for one minibatch of ``rows`` sequences of ``seq_len``, from the
+    shapes alone.  ``lane``: the multiple a tensor's last dimension is
+    padded to where it is held (128 on a TPU; 1 counts the elements).
+    The attention tags are counted as the flash kernel leaves them: an
+    implementation without tags keeps nothing under those names and is
+    over-reckoned."""
+    def w(d):
+        return -(-d // lane) * lane
+
+    n = rows * seq_len
+    act = _dt(cfg.dtype).itemsize
+    L, H = cfg.num_layers, cfg.num_heads
+    route = 0
+    if cfg.latent_attention:
+        from orion_tpu.ops import moe
+        from orion_tpu.ops.pallas.grouped_matmul import padded_rows
+
+        lead = cfg.first_k_dense_replace
+        shared = cfg.n_shared_experts * cfg.moe_intermediate_size
+        qkv = H * (2 * w(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+                   + w(cfg.v_head_dim))
+        out = H * w(cfg.v_head_dim)
+        mlp = 2 * (lead * w(cfg.intermediate_size) + (L - lead) * w(shared))
+        # scores [n, E] float32 and the selection [n, k] (twice: the
+        # gather of the selected scores keeps its own indices); the
+        # grouped form adds order [m], inverse [n k], sizes [held + 1]
+        k = cfg.num_experts_per_tok
+        route = n * (w(cfg.n_routed_experts) + 2 * w(k))
+        if n > moe.DENSE_MAX_TOKENS:
+            route += w(padded_rows(n * k)) + w(n * k) \
+                + w(cfg.experts_held + 1)
+        route *= 4 * (L - lead)
+    else:
+        qkv = (H + 2 * cfg.num_kv_heads) * w(cfg.head_dim)
+        out = H * w(cfg.head_dim)
+        mlp = 0 if cfg.num_experts else \
+            L * (2 if cfg.arch == "llama" else 1) * w(cfg.intermediate_size)
+    resid = 0 if cfg.use_parallel_residual else L * w(cfg.hidden_size)
+    sizes = (route, n * resid * act, n * mlp * act,
+             # out_t, and lse [rows, H, 1, seq_len] in float32
+             L * (n * out * act + rows * H * w(seq_len) * 4),
+             L * n * qkv * act)
+    return tuple((t, b) for t, b in zip(REMAT_TAGS, sizes) if b)
+
+
+def remat_keep(names_with_bytes, budget_bytes: Optional[int]):
+    """The ladder: the names, in the order given, whose bytes fit into
+    ``budget_bytes`` together, up to the first that does not.  No
+    budget (``None``) or none left keeps nothing."""
+    kept, left = [], budget_bytes or 0
+    for name, size in names_with_bytes:
+        if size > left:
+            break
+        kept.append(name)
+        left -= size
+    return tuple(kept)
 
 
 class QuantDense(nn.Module):
@@ -388,15 +471,18 @@ class MLP(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         if cfg.arch in ("llama", "deepseek_v3"):
-            gate = _dense(cfg.intermediate_size, ("embed", "mlp"),
-                          cfg.mlp_bias, cfg, "gate_proj")(x)
-            up = _dense(cfg.intermediate_size, ("embed", "mlp"),
-                        cfg.mlp_bias, cfg, "up_proj")(x)
+            gate = checkpoint_name(
+                _dense(cfg.intermediate_size, ("embed", "mlp"),
+                       cfg.mlp_bias, cfg, "gate_proj")(x), "mlp_pre")
+            up = checkpoint_name(
+                _dense(cfg.intermediate_size, ("embed", "mlp"),
+                       cfg.mlp_bias, cfg, "up_proj")(x), "mlp_pre")
             h = nn.silu(gate) * up
             return _dense(cfg.hidden_size, ("mlp", "embed"),
                           cfg.mlp_bias, cfg, "down_proj")(h)
-        h = _dense(cfg.intermediate_size, ("embed", "mlp"),
-                   cfg.mlp_bias, cfg, "up_proj")(x)
+        h = checkpoint_name(
+            _dense(cfg.intermediate_size, ("embed", "mlp"),
+                   cfg.mlp_bias, cfg, "up_proj")(x), "mlp_pre")
         h = nn.gelu(h, approximate=False)
         return _dense(cfg.hidden_size, ("mlp", "embed"),
                       cfg.mlp_bias, cfg, "down_proj")(h)
@@ -428,7 +514,7 @@ class Block(nn.Module):
             return (sp(out) if sp else out), new_cache
         attn_out, new_cache = Attention(cfg, name="attn")(
             _norm(cfg, "input_norm")(x), positions, layer_cache)
-        h = x + attn_out
+        h = checkpoint_name(x + attn_out, "attn_resid")
         if sp:
             h = sp(h)
         mlp_out = mlp_cls(cfg, name="mlp")(_norm(cfg, "post_attn_norm")(h))
@@ -447,7 +533,7 @@ class LatentBlock(nn.Module):
         cfg = self.cfg
         attn_out, new_cache = LatentAttention(cfg, name="attn")(
             _norm(cfg, "input_norm")(x), positions, layer_cache)
-        h = x + attn_out
+        h = checkpoint_name(x + attn_out, "attn_resid")
         z = _norm(cfg, "post_attn_norm")(h)
         if self.dense:
             return h + MLP(cfg, name="mlp")(z), new_cache
@@ -469,13 +555,16 @@ class Transformer(nn.Module):
     def __call__(self, input_ids, positions, cache: Optional[KVCache] = None,
                  return_hidden: bool = False, skip_lm_head: bool = False,
                  logits_positions: Optional[jnp.ndarray] = None,
-                 token_mask: Optional[jnp.ndarray] = None):
+                 token_mask: Optional[jnp.ndarray] = None,
+                 remat_keep: tuple = ()):
         """``logits_positions`` [B, T]: compute the vocab projection only
         at these sequence positions (ops.logprobs.completion_window_
         positions) — logits come back [B, T, V].  ``return_hidden``
         always returns the FULL [B, L, E] hidden states.
         ``token_mask`` [B, L] bool (deepseek_v3 only): which positions
-        hold a token; the expert layers route the others nowhere."""
+        hold a token; the expert layers route the others nowhere.
+        ``remat_keep``: the :data:`REMAT_TAGS` a block's checkpoint
+        keeps (``cfg.remat``; nothing to a forward alone)."""
         cfg = self.cfg
         embed = nn.Embed(
             num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
@@ -487,7 +576,10 @@ class Transformer(nn.Module):
 
         block_cls = LatentBlock if cfg.latent_attention else Block
         if cfg.remat:
-            block_cls = nn.remat(block_cls, static_argnums=())
+            block_cls = nn.remat(
+                block_cls, static_argnums=(),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *remat_keep) if remat_keep else None)
         # deepseek_v3: the leading dense layers, layers_0.. in both
         # layouts, stand outside the scanned stack of expert layers.
         n_lead = cfg.first_k_dense_replace if cfg.latent_attention else 0

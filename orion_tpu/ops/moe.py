@@ -25,6 +25,7 @@ from __future__ import annotations
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from orion_tpu.config import ModelConfig
 from orion_tpu.models.transformer import _dt
@@ -159,8 +160,9 @@ def sigmoid_topk_route(z, router_kernel, bias, k: int, scale: float):
     logits = jnp.dot(z.astype(jnp.float32),
                      router_kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = checkpoint_name(jax.nn.sigmoid(logits), "moe_route")
     _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32)[None, :], k)
+    idx = checkpoint_name(idx, "moe_route")
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     gates = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
                               + 1e-20)
@@ -194,7 +196,7 @@ def experts_grouped(x, w_gate_up, w_down, local, gates):
     expert's group), and kernels whose work follows the rows of the
     held experts alone."""
     from orion_tpu.ops.pallas.grouped_matmul import (
-        collect_rows, dispatch_rows, grouped_matmul, row_tile)
+        collect_rows, dispatch_rows, grouped_matmul, padded_rows)
 
     T, k = local.shape
     H = w_gate_up.shape[0]
@@ -207,10 +209,12 @@ def experts_grouped(x, w_gate_up, w_down, local, gates):
         inverse = jnp.zeros((n_pairs,), jnp.int32).at[order].set(
             jnp.arange(n_pairs, dtype=jnp.int32), unique_indices=True)
         sizes = jnp.zeros((H + 1,), jnp.int32).at[key].add(1)
-        m = -(-n_pairs // row_tile(n_pairs)) * row_tile(n_pairs)
+        m = padded_rows(n_pairs)
         if m > n_pairs:   # padding rows join the group nobody computes
             order = jnp.pad(order, (0, m - n_pairs))
             sizes = sizes.at[H].add(m - n_pairs)
+        order, inverse, sizes = (checkpoint_name(t, "moe_route")
+                                 for t in (order, inverse, sizes))
         rows = dispatch_rows(x, order, inverse, k)              # [m, D]
     with jax.named_scope("moe.experts"):
         h = _swiglu(grouped_matmul(rows, w_gate_up, sizes))
@@ -307,10 +311,11 @@ class SigmoidTopKMoE(nn.Module):
             S = cfg.n_shared_experts * I
             shared = 0.0
             if S:
-                h = nn.silu(_dense(S, ("embed", "mlp"), False, cfg,
-                                   "shared_gate_proj")(x)) * \
-                    _dense(S, ("embed", "mlp"), False, cfg,
-                           "shared_up_proj")(x)
+                def pre(name):
+                    return checkpoint_name(_dense(
+                        S, ("embed", "mlp"), False, cfg, name)(x), "mlp_pre")
+
+                h = nn.silu(pre("shared_gate_proj")) * pre("shared_up_proj")
                 shared = _dense(Dm, ("mlp", "embed"), False, cfg,
                                 "shared_down_proj")(h)
         return routed.reshape(B, L, Dm).astype(cdt) + shared

@@ -84,6 +84,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -667,12 +668,14 @@ def flash_attention_gqa(q, k, v, q_positions, scale,
 
 
 def _vjp_fwd(q, k, v, q_positions, scale, blk_q, blk_kv):
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    # The residuals carry the names a block's checkpoint may keep
+    # (models/transformer.py, REMAT_TAGS): kept, the backward kernels
+    # read them as they lie and the forward is not run a second time.
+    qt, kt, vt = (checkpoint_name(t.transpose(0, 2, 1, 3), "attn_qkv")
+                  for t in (q, k, v))
     qpos3 = q_positions[:, None, :]
-    out_t, lse = _fwd(qt, kt, vt, qpos3, None, scale, blk_q, blk_kv,
-                      clamp=True)
+    out_t, lse = (checkpoint_name(t, "attn_out") for t in _fwd(
+        qt, kt, vt, qpos3, None, scale, blk_q, blk_kv, clamp=True))
     return out_t.transpose(0, 2, 1, 3), (qt, kt, vt, qpos3, out_t, lse)
 
 

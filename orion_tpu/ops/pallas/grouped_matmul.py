@@ -41,6 +41,11 @@ def row_tile(n_rows: int) -> int:
     return min(TILE_ROWS, -(-n_rows // 8) * 8)
 
 
+def padded_rows(n_rows: int) -> int:
+    """``n_rows`` rounded up to whole row tiles: the rows of ``lhs``."""
+    return -(-n_rows // row_tile(n_rows)) * row_tile(n_rows)
+
+
 def _fit(size: int, limit: int) -> int:
     """Largest multiple of 128 up to ``limit`` that divides ``size``,
     else the whole of it."""

@@ -137,6 +137,30 @@ def make_optimizer(cfg: OptimizerConfig) -> optax.GradientTransformation:
     return tx
 
 
+#: Held back from the device's free bytes when the update's checkpoints
+#: are given what they may keep: the allocator's fragmentation, and what
+#: the compiler lays out otherwise once tensors are kept.
+_REMAT_MARGIN_BYTES = 512 << 20
+
+
+def _device_free_bytes(tree) -> Optional[int]:
+    """``bytes_limit - bytes_in_use`` of the fullest of this process's
+    devices that hold ``tree``; None where a device does not report
+    them (the CPU)."""
+    devices = set()
+    for x in jax.tree.leaves(tree):
+        devices |= x.devices()
+    free = []
+    for d in devices:
+        if d.process_index != jax.process_index():
+            continue
+        stats = d.memory_stats() or {}
+        if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+            return None
+        free.append(stats["bytes_limit"] - stats["bytes_in_use"])
+    return min(free) if free else None
+
+
 def state_out_shardings(state: "TrainState"):
     """``out_shardings`` for a donated TrainState: mesh-sharded leaves
     keep the layout they came in with; the rest stay unspecified.
@@ -190,6 +214,11 @@ class BaseTrainer:
     """
 
     needs_ref = True
+    #: What the blocks' checkpoints keep in the update (model.remat),
+    #: and the row that says so: chosen once, before the update's first
+    #: trace (:meth:`_choose_remat_keep`).
+    _remat_keep: tuple = ()
+    _remat_info: Optional[dict] = None
 
     def __init__(self, cfg: TrainConfig, model: Transformer, params: Any,
                  reward_fn: Optional[Callable] = None,
@@ -262,9 +291,10 @@ class BaseTrainer:
         self._np_rng = np.random.RandomState(cfg.seed)
         self._jit_logprobs = jax.jit(
             self._logprobs_fn, static_argnames=("max_new",))
-        self._jit_epochs = jax.jit(
-            self._epochs_fn, donate_argnums=(0,),
-            out_shardings=(state_out_shardings(self.state), None))
+        # ``_jit_epochs`` is what runs (and what a harness may wrap);
+        # ``_update_jit`` stays the jit itself, for _choose_remat_keep
+        self._update_jit = self._jit_epochs = self._jit_update(
+            self._epochs_fn, (self.state,))
         self.global_iter = 0
         self.ckpt = None
         if cfg.checkpoint_dir and cfg.checkpoint_every:
@@ -356,6 +386,10 @@ class BaseTrainer:
         truth for the aux aggregation."""
         mc = self.cfg.model
         moe = {}
+        # getattr: tests/bench borrows this method for an object of its own
+        keep = getattr(self, "_remat_keep", ())
+        if keep:
+            apply_kw["remat_keep"] = keep
         if mc.num_experts > 0:
             out, inter = self.model.apply(
                 {"params": params}, sequences, positions,
@@ -623,6 +657,57 @@ class BaseTrainer:
             lambda st, idx: self._update_fn(st, experience, idx),
             state, idx_mat)
 
+    def _jit_update(self, fn, states):
+        """jit of an update program ``fn(*states, experience,
+        idx_mat) -> (*states, stats)``: the TrainStates are donated and
+        come back under their own shardings."""
+        return jax.jit(
+            fn, donate_argnums=tuple(range(len(states))),
+            out_shardings=(*map(state_out_shardings, states), None))
+
+    def _update_program(self):
+        """(jitted program, TrainStates it takes first) of the update
+        that :meth:`_run_epochs` dispatches."""
+        return self._update_jit, (self.state,)
+
+    def _choose_remat_keep(self, experience, idx_mat) -> None:
+        """``model.remat`` recomputes what does not fit: which of the
+        blocks' tagged tensors (models/transformer.py, ``REMAT_TAGS``)
+        the update keeps follows from the bytes the device has free
+        now, less what the update takes with nothing kept (its program
+        compiled for that reading; warm, from the compile cache), less
+        a margin.  Taken once, before the update first runs: where
+        nothing fits the program compiled for the reading is the update,
+        else it is traced once more with the names to keep, and there
+        is one update program from then on.  A device that reports
+        nothing (the CPU) gives no budget, and nothing is kept."""
+        from orion_tpu.models.transformer import remat_keep, remat_tag_bytes
+
+        self._remat_info = info = {
+            "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0}
+        free = _device_free_bytes(self.state.params)
+        if not self.cfg.model.remat or free is None:
+            return
+        program, states = self._update_program()
+        mem = program.lower(
+            *states, experience, idx_mat).compile().memory_analysis()
+        # what the runtime sets aside to run it: the program's peak
+        # beyond its arguments (the donated state is updated in place)
+        need = (mem.peak_memory_in_bytes - mem.argument_size_in_bytes
+                + mem.generated_code_size_in_bytes)
+        budget = max(0, free - need - _REMAT_MARGIN_BYTES)
+        seqs = [v for k, v in experience.items() if k.endswith("sequences")]
+        tags = remat_tag_bytes(
+            self.cfg.model, rows=idx_mat.shape[1] * len(seqs),
+            seq_len=max(v.shape[1] for v in seqs), lane=128)
+        self._remat_keep = remat_keep(tags, budget)
+        if self._remat_keep:
+            program.clear_cache()    # traced with nothing kept
+        info.update(
+            remat_kept=",".join(self._remat_keep), remat_budget_bytes=budget,
+            remat_kept_bytes=sum(b for t, b in tags
+                                 if t in self._remat_keep))
+
     def _run_epochs(self, experience, idx_mat):
         """Dispatch the scanned epoch program; PPO (extra critic state)
         overrides this hook.  Returns stacked per-minibatch stats."""
@@ -643,6 +728,8 @@ class BaseTrainer:
         # explicit H2D put: stays legal under TrainConfig.transfer_guard
         # ("disallow" only rejects IMPLICIT transfers)
         idx_mat = jax.device_put(perms.reshape(-1, mb).astype(np.int32))
+        if self._remat_info is None:
+            self._choose_remat_keep(experience, idx_mat)
         stats = self._run_epochs(experience, idx_mat)
         if defer:
             return stats
@@ -877,8 +964,9 @@ class BaseTrainer:
                         experience, exp_stats = self.make_experience(batch)
                     with guard_scope(self.cfg.transfer_guard), \
                             jax.named_scope("update"), \
-                            obs.span("update", it=it):
+                            obs.span("update", it=it) as sp_upd:
                         upd_dev = self.update_epochs(experience, defer=True)
+                        sp_upd.set(**(self._remat_info or {}))
                     with obs.timed("weight_sync") as sp_sync:
                         self.sync_weights()
                     self.global_iter += 1
@@ -998,6 +1086,7 @@ class BaseTrainer:
                 "host_update_dispatch_s": pending["t2"] - pending["t1"],
                 "samples_per_sec":
                     pending["n"] / max(now - pending["t0"], 1e-9),
+                **(self._remat_info or {}),
             })
             sp.set(**{k: v for k, v in stats.items()
                       if k.startswith("moe_")})

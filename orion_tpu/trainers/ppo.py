@@ -25,8 +25,7 @@ from orion_tpu.algos import (AdaptiveKLController, FixedKLController, gae,
                              ppo_value_loss)
 from orion_tpu.config import PPOConfig
 from orion_tpu.models.heads import ScalarHeadModel
-from orion_tpu.trainers.base import (BaseTrainer, TrainState,
-                                     state_out_shardings)
+from orion_tpu.trainers.base import BaseTrainer, TrainState
 
 
 class PPOTrainer(BaseTrainer):
@@ -63,11 +62,8 @@ class PPOTrainer(BaseTrainer):
                     "critic_params (or set cfg.share_backbone=True)")
             self.critic_model = critic_model
             self.critic_state = TrainState.create(critic_params, self.tx)
-            self._jit_ppo_epochs = jax.jit(
-                self._ppo_epochs_fn, donate_argnums=(0, 1),
-                out_shardings=(state_out_shardings(self.state),
-                               state_out_shardings(self.critic_state),
-                               None))
+            self._jit_ppo_epochs = self._jit_update(
+                self._ppo_epochs_fn, (self.state, self.critic_state))
         self.kl_ctl = (AdaptiveKLController(cfg.kl_coef, cfg.kl_target,
                                             cfg.kl_horizon)
                        if cfg.adaptive_kl else FixedKLController(cfg.kl_coef))
@@ -261,6 +257,11 @@ class PPOTrainer(BaseTrainer):
         (st, cst), stats = jax.lax.scan(
             step, (state, critic_state), idx_mat)
         return st, cst, stats
+
+    def _update_program(self):
+        if self.cfg.share_backbone:
+            return super()._update_program()
+        return self._jit_ppo_epochs, (self.state, self.critic_state)
 
     def _run_epochs(self, experience, idx_mat):
         if self.cfg.share_backbone:

@@ -107,3 +107,17 @@ def test_resume_restores_ppo_critic_and_kl(tmp_path):
 def test_no_checkpoint_returns_false(tmp_path):
     cfg, tr = _grpo(tmp_path)
     assert tr.resume() is False
+
+
+def test_orbax_is_imported_by_who_checkpoints():
+    """orbax takes seconds to import and is set-up time of every
+    process: a job that never checkpoints must not pay for it, and
+    ``orion_tpu.utils.CheckpointManager`` still resolves."""
+    import subprocess
+    import sys
+
+    code = ("import sys; import orion_tpu.launch, orion_tpu.trainers; "
+            "assert not any(m.startswith('orbax') for m in sys.modules); "
+            "from orion_tpu.utils import CheckpointManager; "
+            "assert 'orbax.checkpoint' in sys.modules")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)

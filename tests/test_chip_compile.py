@@ -317,6 +317,43 @@ def test_pythia1b_decode_segment_compiles_for_v5e(one_chip, on_tpu):
     assert total < 16e9, f"decode segment needs {total / 1e9:.1f} GB"
 
 
+@pytest.mark.parametrize("rows,keep,forwards", [
+    (16, (), 2), (16, ("attn_out",), 1), (4, "every tag", 1)],
+    ids=["nothing-kept", "attn_out-kept", "everything-kept-4-rows"])
+def test_pythia1b_update_keeps_the_flash_forward(one_chip, on_tpu, rows,
+                                                 keep, forwards):
+    """The ``ppo1b-sync`` update (minibatches of 16 x 384, one chip)
+    under ``model.remat``: with nothing kept the scanned block's
+    backward runs the flash forward a second time; with the kernel's
+    output among the kept tags (``attn_out``) there is one forward
+    call a layer pass, and the program fits the chip.  Everything kept
+    does not fit beside the update's own 5.8 GB at 16 rows (PERF.md
+    section 6, PR 31), so that case runs a quarter of the minibatch."""
+    from orion_tpu.config import ModelConfig
+    from orion_tpu.models.transformer import REMAT_TAGS
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc = ModelConfig.pythia_1b()
+    shell, pshape, mb = _build_8b_shell(mc)
+    shell._remat_keep = REMAT_TAGS if keep == "every tag" else keep
+    experience = {k: _sds((3 * rows,) + v.shape[1:], v.dtype, one_chip)
+                  for k, v in mb.items()}
+    state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                         _abstract_state(shell, pshape))
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+            donate_argnums=(0,)).lower(
+                state, experience, _sds((3, rows), jnp.int32, one_chip)
+            ).compile()
+    names = _kernel_names(compiled)
+    assert names.count("flash_fwd") == forwards, names
+    assert names.count("flash_bwd_dq") == names.count("flash_bwd_dkv") == 1
+    assert compiled.memory_analysis().peak_memory_in_bytes <= 15.75 * 2**30
+
+
 # -- the deepseek_v3 block's kernels at the published widths ----------------
 
 def test_flash_with_a_narrower_value_compiles_for_v5e(one_chip, on_tpu):
