@@ -17,6 +17,11 @@ from typing import Any, Optional
 # ---------------------------------------------------------------------------
 
 
+#: The archs whose model is a per-layer pattern of (mixer, FFN) kinds in
+#: one pre-norm RMSNorm block (``ModelConfig.layer_kinds``).
+PATTERN_ARCHS = ("deepseek_v3", "kimi_linear")
+
+
 @dataclass
 class ModelConfig:
     """Architecture hyperparameters for the decoder-only transformer.
@@ -27,7 +32,7 @@ class ModelConfig:
     attention+MLP residual, partial rotary — Pythia-1B).
     """
 
-    arch: str = "llama"  # "llama" | "neox" | "deepseek_v3"
+    arch: str = "llama"  # "llama" | "neox" | "deepseek_v3" | "kimi_linear"
     vocab_size: int = 32000
     hidden_size: int = 512
     intermediate_size: int = 1376
@@ -92,10 +97,23 @@ class ModelConfig:
     # n_routed_experts; what the absent experts would add is left out.
     experts_held: int = 0
     expert_offset: int = 0
+    # arch="kimi_linear": the deepseek_v3 block with a mixer per layer.
+    # The published linear_attn_config.kda_layers, whole and 1-based:
+    # layer i runs the delta-rule mixer (ops/kda.py) where i is in it and
+    # latent attention where it is not (the published full_attn_layers
+    # is its complement); the model reads the entries up to num_layers.
+    # mla_use_nope: the latent layers rotate nothing.
+    kda_layers: tuple = ()
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    short_conv_kernel_size: int = 0
+    mla_use_nope: bool = False
 
     def __post_init__(self) -> None:
-        if self.arch == "deepseek_v3":
+        if self.arch in PATTERN_ARCHS:
             self._check_deepseek_v3()
+        if self.arch == "kimi_linear":
+            self._check_kimi_linear()
         if self.head_dim == 0:
             self.head_dim = self.hidden_size // self.num_heads
         if self.arch == "neox":
@@ -108,7 +126,7 @@ class ModelConfig:
                     "v_head_dim", "n_routed_experts", "num_experts_per_tok",
                     "moe_intermediate_size"):
             if getattr(self, key) <= 0:
-                raise ValueError(f"arch='deepseek_v3' needs model.{key} > 0")
+                raise ValueError(f"arch={self.arch!r} needs model.{key} > 0")
         self.num_kv_heads = self.num_heads
         if self.head_dim == 0:
             self.head_dim = self.qk_rope_head_dim   # as published
@@ -124,18 +142,75 @@ class ModelConfig:
         if self.attention_impl in ("ring", "ulysses"):
             raise ValueError(
                 f"attention_impl={self.attention_impl!r} cannot run "
-                "arch='deepseek_v3': the sequence-parallel attentions "
+                f"arch={self.arch!r}: the sequence-parallel attentions "
                 "exchange per-head K/V of one head_dim, and there is no "
-                "exchange of the latent (c, k_rope) yet")
+                "exchange of the latent (c, k_rope) yet, nor a hand-over "
+                "of a recurrent state between sequence shards")
         if self.num_experts or self.quantize_dense or self.tie_word_embeddings:
             raise ValueError(
-                "arch='deepseek_v3' has its own expert layer (num_experts "
+                f"arch={self.arch!r} has its own expert layer (num_experts "
                 "is the GShard layer's), no int8 Dense twin and an untied "
                 "head")
 
+    def _check_kimi_linear(self) -> None:
+        for key in ("kda_num_heads", "kda_head_dim",
+                    "short_conv_kernel_size"):
+            if getattr(self, key) <= 0:
+                raise ValueError(f"arch='kimi_linear' needs model.{key} > 0")
+        self.kda_layers = tuple(self.kda_layers)
+        if any(i < 1 for i in self.kda_layers):
+            raise ValueError("model.kda_layers counts layers from 1 (the "
+                             "published linear_attn_config.kda_layers)")
+
     @property
     def latent_attention(self) -> bool:
-        return self.arch == "deepseek_v3"
+        """The pre-norm RMSNorm block whose layers are a pattern of
+        (mixer, FFN) kinds: see :meth:`layer_kinds`."""
+        return self.arch in PATTERN_ARCHS
+
+    def layer_kinds(self) -> tuple:
+        """((mixer, ffn), ...) per layer: the model's description.
+        mixer: "attention" (per-head K/V cache), "latent" ({c, k_rope}
+        cache) or "kda" (a recurrent state, no position); ffn: "dense",
+        "gshard" (num_experts) or "experts" (the dropless layer)."""
+        if not self.latent_attention:
+            return (("attention",
+                     "gshard" if self.num_experts else "dense"),
+                    ) * self.num_layers
+        return tuple(
+            ("kda" if i + 1 in self.kda_layers else "latent",
+             "dense" if i < self.first_k_dense_replace else "experts")
+            for i in range(self.num_layers))
+
+    def layer_runs(self) -> tuple:
+        """((first, length, mixer, ffn), ...): the stretches of equal
+        consecutive kinds, each of which ``scan_layers`` scans as one
+        stack; the leading dense layers of a pattern model stand alone
+        (length 1, never stacked)."""
+        out = []
+        for i, kind in enumerate(self.layer_kinds()):
+            alone = self.latent_attention and kind[1] == "dense"
+            if out and not alone and out[-1][2:] == kind:
+                first, length = out[-1][:2]
+                out[-1] = (first, length + 1) + kind
+            else:
+                out.append((i, 1) + kind)
+        return tuple(out)
+
+    @property
+    def takes_token_mask(self) -> bool:
+        """Whether a layer treats a position that holds no token apart
+        (the dropless expert layer routes it nowhere, the recurrent
+        mixer leaves its state untouched): callers then pass
+        ``token_mask``."""
+        return any(m == "kda" or f == "experts"
+                   for m, f in self.layer_kinds())
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether some layer's per-sequence state is not indexed by
+        position."""
+        return any(m == "kda" for m, _ in self.layer_kinds())
 
     @staticmethod
     def llama3_8b() -> "ModelConfig":
@@ -179,6 +254,30 @@ class ModelConfig:
         )
 
     @staticmethod
+    def kimi_linear_48b_a3b() -> "ModelConfig":
+        """moonshotai/Kimi-Linear-48B-A3B-Instruct as published
+        (config.json, model_type kimi_linear): every expert held."""
+        return ModelConfig(
+            arch="kimi_linear", vocab_size=163840, hidden_size=2304,
+            intermediate_size=9216, num_layers=27, num_heads=32,
+            max_seq_len=1048576, rope_theta=10000.0, rms_norm_eps=1e-5,
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, n_routed_experts=256, num_experts_per_tok=8,
+            n_shared_experts=1, moe_intermediate_size=1024,
+            first_k_dense_replace=1, routed_scaling_factor=2.446,
+            kda_layers=(1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18,
+                        19, 21, 22, 23, 25, 26),
+            kda_num_heads=32, kda_head_dim=128, short_conv_kernel_size=4,
+            mla_use_nope=True,
+        )
+
+    @staticmethod
+    def tiny_kimi_linear() -> "ModelConfig":
+        """``model_preset=tiny_kimi_linear``: the small sibling of
+        kimi_linear_48b_a3b (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("kimi_linear")
+
+    @staticmethod
     def tiny_deepseek_v3() -> "ModelConfig":
         """``model_preset=tiny_deepseek_v3``: the small sibling of
         kanana_2_30b_a3b (tests, CPU rehearsals)."""
@@ -187,6 +286,21 @@ class ModelConfig:
     @staticmethod
     def tiny(arch: str = "llama", **kw: Any) -> "ModelConfig":
         """Small config for tests (runs on CPU in <1s)."""
+        if arch == "kimi_linear":
+            # one dense layer, then one whole period of the 3 : 1 pattern
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=96, num_layers=5, num_heads=4,
+                max_seq_len=128, rms_norm_eps=1e-5, kv_lora_rank=16,
+                qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+                n_routed_experts=8, num_experts_per_tok=2,
+                n_shared_experts=1, moe_intermediate_size=32,
+                first_k_dense_replace=1, routed_scaling_factor=2.446,
+                kda_layers=(1, 2, 3, 5, 6, 7), kda_num_heads=4, kda_head_dim=16,
+                short_conv_kernel_size=4, mla_use_nope=True,
+            )
+            base.update(kw)
+            return ModelConfig(**base)
         if arch == "deepseek_v3":
             base = dict(
                 arch=arch, vocab_size=256, hidden_size=64,

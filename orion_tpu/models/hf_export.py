@@ -41,6 +41,12 @@ def hf_state_dict(params: dict, cfg: ModelConfig) -> Dict[str, np.ndarray]:
             "HF export of arch='deepseek_v3' is not written: there is no "
             "deepseek_v3 checkpoint layout (per-expert gate/up/down "
             "tensors, kv_a/kv_b projections) on either side yet")
+    if cfg.arch == "kimi_linear":
+        raise ValueError(
+            "HF export of arch='kimi_linear' is not written: there is no "
+            "kimi_linear checkpoint layout on either side yet (the KDA "
+            "layers' convolutions [channels, 1, taps], low-rank gates, "
+            "A_log and dt_bias; per-expert tensors; kv_a/kv_b projections)")
     params = dict(params)
     if "backbone" in params:  # ActorCriticModel / ScalarHeadModel tree
         params = dict(params["backbone"])

@@ -51,6 +51,13 @@ _NO_DSV3_LOADER = (
     "gate/up/down tensors have to be stacked over the experts held "
     "(gate and up fused), and kv_a_proj_with_mqa / kv_b_proj mapped; "
     "arch='deepseek_v3' runs from random weights only")
+_NO_KIMI_LOADER = (
+    "there is no kimi_linear checkpoint loader yet: beside what a "
+    "deepseek_v3 checkpoint needs (experts stacked over those held, "
+    "kv_a / kv_b mapped), the KDA layers' three depthwise convolutions, "
+    "low-rank gates, A_log and dt_bias have no mapping onto "
+    "models.transformer.KimiDeltaAttention's names and [taps, channels] "
+    "layout; arch='kimi_linear' runs from random weights only")
 
 
 def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
@@ -61,6 +68,8 @@ def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
         p = _convert_neox(sd, cfg)
     elif cfg.arch == "deepseek_v3":
         raise ValueError(_NO_DSV3_LOADER)
+    elif cfg.arch == "kimi_linear":
+        raise ValueError(_NO_KIMI_LOADER)
     else:
         raise ValueError(cfg.arch)
     if not include_lm_head:
@@ -91,7 +100,8 @@ def unstack_layer_params(p: dict, num_layers: int) -> dict:
 
     from orion_tpu.models.transformer import unstack_params_tree
 
-    return jax.tree.map(np.asarray, unstack_params_tree(p, num_layers))
+    return jax.tree.map(np.asarray, unstack_params_tree(
+        p, {"layers": (0, num_layers)}))
 
 
 def _convert_llama(sd: Mapping[str, Any], cfg: ModelConfig) -> dict:
@@ -214,6 +224,8 @@ def config_from_hf(hf_cfg: Any) -> ModelConfig:
     mt = getattr(hf_cfg, "model_type", "")
     if mt == "deepseek_v3":
         raise ValueError(_NO_DSV3_LOADER)
+    if mt == "kimi_linear":
+        raise ValueError(_NO_KIMI_LOADER)
     if mt == "llama":
         return ModelConfig(
             arch="llama",

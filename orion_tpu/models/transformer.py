@@ -13,6 +13,11 @@ requires (SURVEY.md §2 #14):
   (``ops.moe.SigmoidTopKMoE``) after them; no biases, untied head.
   Under ``scan_layers`` the leading dense layers stay outside the
   scanned stack of expert layers.
+- ``arch="kimi_linear"``: the same block with a mixer per layer
+  (``ModelConfig.layer_kinds``): :class:`KimiDeltaAttention` (a
+  recurrent state, no position) or :class:`LatentAttention` without
+  rotation.  Under ``scan_layers`` each stretch of equal consecutive
+  kinds is one scanned stack (``ModelConfig.layer_runs``).
 
 Design notes (TPU-first):
 - Params are annotated with *logical* axes via flax logical
@@ -34,6 +39,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import math
 from typing import Any, List, Optional
 
 import flax.linen as nn
@@ -52,6 +58,10 @@ from orion_tpu.ops.rotary import apply_rotary
 # deepseek_v3: a layer caches {"c": [B,L,kv_lora_rank], "k_rope":
 # [B,L,qk_rope_head_dim]}; scan_layers models {"dense": [per layer],
 # "layers": stacked} (the leading dense layers are not in the stack).
+# kimi_linear: a KDA layer caches {"S": f32 [B,H,dk,dv], "conv":
+# [B,taps-1,3*H*dk]}, nothing indexed by position; scan_layers models
+# {"dense": [per layer], "runs": [stacked, one per ModelConfig.
+# layer_runs stretch]}.
 KVCache = Any
 
 _dt = lambda s: jnp.dtype(s)  # noqa: E731
@@ -80,43 +90,54 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
     padded to where it is held (128 on a TPU; 1 counts the elements).
     The attention tags are counted as the flash kernel leaves them: an
     implementation without tags keeps nothing under those names and is
-    over-reckoned."""
+    over-reckoned.  A KDA layer tags its projections (``attn_qkv``) and
+    its recurrence's output (``attn_out``) under the same names."""
     def w(d):
         return -(-d // lane) * lane
 
     n = rows * seq_len
     act = _dt(cfg.dtype).itemsize
-    L, H = cfg.num_layers, cfg.num_heads
-    route = 0
-    if cfg.latent_attention:
-        from orion_tpu.ops import moe
-        from orion_tpu.ops.pallas.grouped_matmul import padded_rows
+    H = cfg.num_heads
+    route = resid = mlp = out = qkv = 0
+    for mixer, ffn in cfg.layer_kinds():
+        if mixer == "kda":
+            # the three projections as they enter the convolution, and
+            # the recurrence's output in float32 (ops/kda.py)
+            wide = cfg.kda_num_heads * cfg.kda_head_dim
+            qkv += n * 3 * w(wide) * act
+            out += n * w(wide) * 4
+        else:
+            if mixer == "latent":
+                per_tok = H * (2 * w(cfg.qk_nope_head_dim
+                                     + cfg.qk_rope_head_dim)
+                               + w(cfg.v_head_dim))
+                per_out = H * w(cfg.v_head_dim)
+            else:
+                per_tok = (H + 2 * cfg.num_kv_heads) * w(cfg.head_dim)
+                per_out = H * w(cfg.head_dim)
+            qkv += n * per_tok * act
+            # out_t, and lse [rows, H, 1, seq_len] in float32
+            out += n * per_out * act + rows * H * w(seq_len) * 4
+        if ffn == "experts":
+            from orion_tpu.ops import moe
+            from orion_tpu.ops.pallas.grouped_matmul import padded_rows
 
-        lead = cfg.first_k_dense_replace
-        shared = cfg.n_shared_experts * cfg.moe_intermediate_size
-        qkv = H * (2 * w(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
-                   + w(cfg.v_head_dim))
-        out = H * w(cfg.v_head_dim)
-        mlp = 2 * (lead * w(cfg.intermediate_size) + (L - lead) * w(shared))
-        # scores [n, E] float32 and the selection [n, k] (twice: the
-        # gather of the selected scores keeps its own indices); the
-        # grouped form adds order [m], inverse [n k], sizes [held + 1]
-        k = cfg.num_experts_per_tok
-        route = n * (w(cfg.n_routed_experts) + 2 * w(k))
-        if n > moe.DENSE_MAX_TOKENS:
-            route += w(padded_rows(n * k)) + w(n * k) \
-                + w(cfg.experts_held + 1)
-        route *= 4 * (L - lead)
-    else:
-        qkv = (H + 2 * cfg.num_kv_heads) * w(cfg.head_dim)
-        out = H * w(cfg.head_dim)
-        mlp = 0 if cfg.num_experts else \
-            L * (2 if cfg.arch == "llama" else 1) * w(cfg.intermediate_size)
-    resid = 0 if cfg.use_parallel_residual else L * w(cfg.hidden_size)
-    sizes = (route, n * resid * act, n * mlp * act,
-             # out_t, and lse [rows, H, 1, seq_len] in float32
-             L * (n * out * act + rows * H * w(seq_len) * 4),
-             L * n * qkv * act)
+            mlp += 2 * w(cfg.n_shared_experts * cfg.moe_intermediate_size)
+            # scores [n, E] float32 and the selection [n, k] (twice: the
+            # gather of the selected scores keeps its own indices); the
+            # grouped form adds order [m], inverse [n k], sizes [held + 1]
+            k = cfg.num_experts_per_tok
+            r = n * (w(cfg.n_routed_experts) + 2 * w(k))
+            if n > moe.DENSE_MAX_TOKENS:
+                r += w(padded_rows(n * k)) + w(n * k) \
+                    + w(cfg.experts_held + 1)
+            route += 4 * r
+        elif ffn == "dense":
+            mlp += (1 if cfg.arch == "neox" else 2) * w(
+                cfg.intermediate_size)
+        if not cfg.use_parallel_residual:
+            resid += w(cfg.hidden_size)
+    sizes = (route, n * resid * act, n * mlp * act, out, qkv)
     return tuple((t, b) for t, b in zip(REMAT_TAGS, sizes) if b)
 
 
@@ -193,7 +214,7 @@ def _dense(features, axes, use_bias, cfg, name):
 
 
 def _norm(cfg, name):
-    if cfg.arch in ("llama", "deepseek_v3"):
+    if cfg.arch == "llama" or cfg.latent_attention:
         return nn.RMSNorm(
             epsilon=cfg.rms_norm_eps, dtype=_dt(cfg.dtype),
             param_dtype=_dt(cfg.param_dtype),
@@ -367,8 +388,8 @@ class LatentAttention(nn.Module):
     head is ``[k_nope ; v]``.  Rotary on ``q_rope`` of every head and on
     the one ``k_rope`` all heads share, the rotary features stored as
     adjacent pairs and brought to the half-split layout first
-    (``rope_interleave``).  Scores ``q . [k_nope ; k_rope] /
-    sqrt(nope + rope)``.
+    (``rope_interleave``); under ``mla_use_nope`` nothing is rotated.
+    Scores ``q . [k_nope ; k_rope] / sqrt(nope + rope)``.
 
     The cache holds ``c`` and the rotated ``k_rope`` of a token, nothing
     per head.  Two paths compute the same attention:
@@ -419,11 +440,16 @@ class LatentAttention(nn.Module):
         def halves(t):   # adjacent pairs -> half-split (rope_interleave)
             return jnp.concatenate([t[..., 0::2], t[..., 1::2]], axis=-1)
 
-        q_nope, q_rope = q[..., :dn], halves(q[..., dn:])
-        k_rope = halves(kva[..., R:])[:, :, None, :]          # one "head"
-        q_rope, k_rope = apply_rotary(q_rope, k_rope, positions, dr,
-                                      cfg.rope_theta)
-        k_rope = k_rope[:, :, 0, :]
+        if cfg.mla_use_nope:
+            # no rotation: the shared features enter the scores as
+            # projected (and are still called k_rope in the cache)
+            q_nope, q_rope, k_rope = q[..., :dn], q[..., dn:], kva[..., R:]
+        else:
+            q_nope, q_rope = q[..., :dn], halves(q[..., dn:])
+            k_rope = halves(kva[..., R:])[:, :, None, :]      # one "head"
+            q_rope, k_rope = apply_rotary(q_rope, k_rope, positions, dr,
+                                          cfg.rope_theta)
+            k_rope = k_rope[:, :, 0, :]
 
         new_cache = None
         if layer_cache is not None:
@@ -464,13 +490,162 @@ class LatentAttention(nn.Module):
                       "o_proj")(out), new_cache
 
 
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention: the delta rule with a per-channel decay
+    (``ops/kda.py``), ``kda_num_heads`` heads of ``kda_head_dim``.
+
+    ``q~, k~, v~ = x W_q, x W_k, x W_v``, each through its own depthwise
+    causal convolution of ``short_conv_kernel_size`` taps and SiLU; per
+    head ``q = l2norm(q~) / sqrt(d)``, ``k = l2norm(k~)``, ``v = v~``;
+    log decay ``g = -exp(A_log) * softplus(x W_fa W_fb + dt_bias)`` per
+    head and key channel, step size ``beta = sigmoid(x W_b)`` per head,
+    both float32; output ``W_o concat_h(RMSNorm(o) * sigmoid(x W_ga
+    W_gb))``.  No position enters it.
+
+    The cache is ``{"S": [B, H, d, d] float32, "conv": [B, taps - 1,
+    3 H d]}``: the state and the last inputs of the convolutions, after
+    the last token a row holds.  One new token against a cache takes
+    :func:`ops.kda.kda_step`; everything else the chunked form.
+
+    ``token_mask`` [B, L]: a position that holds no token leaves the
+    state untouched (decay 1, step 0).  The mask must be a row's
+    prefix (right padding, as the engine's prompts and the trainer's
+    packed sequences are): the convolution of a real token then never
+    reaches over padding, and the inputs handed on to decode are those
+    of each row's last real tokens.
+    """
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None, token_mask=None):
+        from orion_tpu.ops.kda import kda_chunked, kda_step
+
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, d, taps = (cfg.kda_num_heads, cfg.kda_head_dim,
+                      cfg.short_conv_kernel_size)
+        wide = H * d
+        f32, pdt = jnp.float32, _dt(cfg.param_dtype)
+        if layer_cache is not None and "S" not in layer_cache:
+            raise ValueError(
+                "a KDA layer caches {'S', 'conv'} (init_cache): a state, "
+                "not keys and values by position")
+
+        def param(name, init, shape, axes, dtype=pdt):
+            return self.param(
+                name, nn.with_logical_partitioning(init, axes), shape, dtype)
+
+        proj = checkpoint_name(jnp.concatenate(
+            [_dense(wide, ("embed", "heads"), False, cfg, n + "_proj")(x)
+             for n in "qkv"], axis=-1), "attn_qkv")
+        # torch's Conv1d default for a depthwise kernel of 4 taps
+        conv_init = nn.initializers.uniform(scale=1.0)
+        w_conv = jnp.concatenate(
+            [param(n + "_conv", lambda *a: conv_init(*a) - 0.5,
+                   (taps, wide), ("conv", "heads")) for n in "qkv"],
+            axis=-1).astype(f32)
+
+        # The elementwise stretches between the matrix products and the
+        # recurrence are checkpointed each on its own: a backward keeps
+        # their bf16 inputs and recomputes the float32 in between (3 x
+        # [B, L, 3 H d] of it in the first alone).
+        @jax.checkpoint
+        def convolve(ext, w_conv):
+            y = sum(ext[:, j:j + L].astype(f32) * w_conv[j]
+                    for j in range(taps))
+            q, k, v = (t.reshape(B, L, H, d)
+                       for t in jnp.split(nn.silu(y), 3, axis=-1))
+
+            def l2norm(t):
+                return t * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+
+            # the recurrence takes them in the compute dtype (they are
+            # operands of matrix products), in both of its forms
+            return tuple(t.astype(_dt(cfg.dtype)) for t in
+                         (l2norm(q) * d ** -0.5, l2norm(k), v))
+
+        with jax.named_scope("kda.conv"):
+            prev = (layer_cache["conv"] if layer_cache is not None
+                    else jnp.zeros((B, taps - 1, 3 * wide), proj.dtype))
+            ext = jnp.concatenate([prev.astype(proj.dtype), proj], axis=1)
+            q, k, v = convolve(ext, w_conv)
+
+        with jax.named_scope("kda.gate"):
+            A_log = param("A_log", lambda key, shape, dtype: jnp.log(
+                jax.random.uniform(key, shape, dtype, 1.0, 16.0)),
+                (H,), ("norm",), f32)
+
+            def dt_init(key, shape, dtype):
+                # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
+                dt = jnp.exp(jax.random.uniform(
+                    key, shape, dtype, math.log(1e-3), math.log(1e-1)))
+                return dt + jnp.log(-jnp.expm1(-dt))
+
+            dt_bias = param("dt_bias", dt_init, (wide,), ("heads",), f32)
+            f = _dense(wide, ("latent", "heads"), False, cfg, "f_b_proj")(
+                _dense(d, ("embed", "latent"), False, cfg, "f_a_proj")(x))
+            b = _dense(H, ("embed", "norm"), False, cfg, "b_proj")(x)
+
+            @jax.checkpoint
+            def decay_and_step(f, b, A_log, dt_bias):
+                g = -jnp.exp(A_log)[:, None] * jax.nn.softplus(
+                    (f.astype(f32) + dt_bias).reshape(B, L, H, d))
+                beta = jax.nn.sigmoid(b.astype(f32))
+                if token_mask is not None:
+                    g = jnp.where(token_mask[:, :, None, None], g, 0.0)
+                    beta = jnp.where(token_mask[:, :, None], beta, 0.0)
+                return g, beta
+
+            g, beta = decay_and_step(f, b, A_log, dt_bias)
+
+        new_cache = None
+        if layer_cache is not None and L == 1:
+            with jax.named_scope("kda.step"):
+                o, S = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0], layer_cache["S"])
+                o = o[:, None]
+            new_cache = {"S": S, "conv": ext[:, 1:]}
+        else:
+            with jax.named_scope("kda.chunk"):
+                o, S = kda_chunked(
+                    q, k, v, g, beta,
+                    None if layer_cache is None else layer_cache["S"])
+            if layer_cache is not None:
+                # the inputs of each row's last taps - 1 real tokens:
+                # position p is row p + taps - 1 of ``ext``
+                n_real = (jnp.full((B,), L, jnp.int32) if token_mask is None
+                          else jnp.sum(token_mask, axis=1, dtype=jnp.int32))
+                rows = n_real[:, None] + jnp.arange(taps - 1)[None, :]
+                new_cache = {"S": S, "conv": jnp.take_along_axis(
+                    ext, rows[:, :, None], axis=1)}
+        o = checkpoint_name(o, "attn_out")
+
+        gate = _dense(wide, ("latent", "heads"), False, cfg, "g_b_proj")(
+            _dense(d, ("embed", "latent"), False, cfg, "g_a_proj")(x))
+        o_norm = param("o_norm", nn.initializers.ones_init(), (d,),
+                       ("norm",))
+
+        @jax.checkpoint
+        def norm_and_gate(o, gate, o_norm):
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                           keepdims=True) + cfg.rms_norm_eps)
+            o = o * o_norm.astype(f32) * jax.nn.sigmoid(
+                gate.astype(f32)).reshape(B, L, H, d)
+            return o.astype(_dt(cfg.dtype)).reshape(B, L, wide)
+
+        return _dense(cfg.hidden_size, ("heads", "embed"), False, cfg,
+                      "o_proj")(norm_and_gate(o, gate, o_norm)), new_cache
+
+
 class MLP(nn.Module):
     cfg: ModelConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        if cfg.arch in ("llama", "deepseek_v3"):
+        if cfg.arch == "llama" or cfg.latent_attention:
             gate = checkpoint_name(
                 _dense(cfg.intermediate_size, ("embed", "mlp"),
                        cfg.mlp_bias, cfg, "gate_proj")(x), "mlp_pre")
@@ -522,23 +697,78 @@ class Block(nn.Module):
 
 
 class LatentBlock(nn.Module):
-    """deepseek_v3 block: ``a = x + Attn(N1(x))``, ``y = a + FFN(N2(a))``,
-    FFN the SwiGLU MLP (``dense``) or the expert layer."""
+    """deepseek_v3 / kimi_linear block: ``a = x + Mixer(N1(x))``, ``y =
+    a + FFN(N2(a))``; the mixer latent attention or (``mixer="kda"``)
+    the delta rule, FFN the SwiGLU MLP (``dense``) or the expert layer."""
 
     cfg: ModelConfig
     dense: bool = False
+    mixer: str = "latent"
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None, token_mask=None):
         cfg = self.cfg
-        attn_out, new_cache = LatentAttention(cfg, name="attn")(
-            _norm(cfg, "input_norm")(x), positions, layer_cache)
+        h = _norm(cfg, "input_norm")(x)
+        if self.mixer == "kda":
+            attn_out, new_cache = KimiDeltaAttention(cfg, name="attn")(
+                h, positions, layer_cache, token_mask)
+        else:
+            attn_out, new_cache = LatentAttention(cfg, name="attn")(
+                h, positions, layer_cache)
         h = checkpoint_name(x + attn_out, "attn_resid")
         z = _norm(cfg, "post_attn_norm")(h)
         if self.dense:
             return h + MLP(cfg, name="mlp")(z), new_cache
         from orion_tpu.ops.moe import SigmoidTopKMoE
         return h + SigmoidTopKMoE(cfg, name="mlp")(z, token_mask), new_cache
+
+
+def _stack_names(cfg: ModelConfig) -> dict:
+    """{first layer: parameter name} of the stretches that
+    ``scan_layers`` stacks: every ``cfg.layer_runs`` stretch but a
+    pattern model's leading dense layers.  One stack is ``layers`` (the
+    layout of every model before there were patterns); several are
+    ``layers_<first>to<last>``."""
+    stacked = [(first, length) for first, length, _, ffn in cfg.layer_runs()
+               if not (cfg.latent_attention and ffn == "dense")]
+    if len(stacked) == 1:
+        return {stacked[0][0]: "layers"}
+    return {first: f"layers_{first}to{first + length - 1}"
+            for first, length in stacked}
+
+
+def _split_cache(cfg: ModelConfig, cache):
+    """The cache of each ``cfg.layer_runs`` stretch: a list of per-layer
+    entries, or the stacked pytree of a stretch that is scanned."""
+    runs = cfg.layer_runs()
+    if cache is None:
+        return [None] * len(runs)
+    if not cfg.scan_layers:
+        return [cache[first:first + length] for first, length, _, _ in runs]
+    if not isinstance(cache, dict) or "dense" not in cache:
+        return [cache]                      # one stack, nothing beside it
+    stacks = _stack_names(cfg)
+    dense = iter(cache["dense"])
+    stacked = iter(cache["runs"] if "runs" in cache else [cache["layers"]])
+    return [next(stacked) if first in stacks else [next(dense)]
+            for first, _, _, _ in runs]
+
+
+def _join_cache(cfg: ModelConfig, run_caches):
+    """Inverse of :func:`_split_cache`."""
+    runs = cfg.layer_runs()
+    if not cfg.scan_layers:
+        return [c for rc in run_caches for c in rc]
+    stacks = _stack_names(cfg)
+    dense = [rc[0] for (first, _, _, _), rc in zip(runs, run_caches)
+             if first not in stacks]
+    stacked = [rc for (first, _, _, _), rc in zip(runs, run_caches)
+               if first in stacks]
+    if not dense and len(stacked) == 1:
+        return stacked[0]
+    if len(stacked) == 1:
+        return {"dense": dense, "layers": stacked[0]}
+    return {"dense": dense, "runs": stacked}
 
 
 class Transformer(nn.Module):
@@ -561,8 +791,9 @@ class Transformer(nn.Module):
         at these sequence positions (ops.logprobs.completion_window_
         positions) — logits come back [B, T, V].  ``return_hidden``
         always returns the FULL [B, L, E] hidden states.
-        ``token_mask`` [B, L] bool (deepseek_v3 only): which positions
-        hold a token; the expert layers route the others nowhere.
+        ``token_mask`` [B, L] bool (``cfg.takes_token_mask``): which
+        positions hold a token; the expert layers route the others
+        nowhere and the recurrent mixers leave their state untouched.
         ``remat_keep``: the :data:`REMAT_TAGS` a block's checkpoint
         keeps (``cfg.remat``; nothing to a forward alone)."""
         cfg = self.cfg
@@ -574,66 +805,66 @@ class Transformer(nn.Module):
             name="embed")
         x = embed(input_ids)
 
-        block_cls = LatentBlock if cfg.latent_attention else Block
-        if cfg.remat:
-            block_cls = nn.remat(
-                block_cls, static_argnums=(),
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *remat_keep) if remat_keep else None)
-        # deepseek_v3: the leading dense layers, layers_0.. in both
-        # layouts, stand outside the scanned stack of expert layers.
-        n_lead = cfg.first_k_dense_replace if cfg.latent_attention else 0
-        more = () if token_mask is None else (token_mask,)
-        lead_cache = None
-        if n_lead:
-            if cache is not None:
-                lead_cache = (cache["dense"] if cfg.scan_layers
-                              else cache[:n_lead])
-            new_lead = []
-            for i in range(n_lead):
-                x, c_i = block_cls(cfg, dense=True, name=f"layers_{i}")(
-                    x, positions,
-                    lead_cache[i] if lead_cache is not None else None)
-                new_lead.append(c_i)
+        def block(mixer, ffn):
+            """(block class for a layer of this kind, its keywords,
+            whether it takes ``token_mask``)."""
+            if mixer == "attention":
+                cls, kw, masked = Block, {}, False
+            else:
+                kw = {"dense": True} if ffn == "dense" else {}
+                if mixer != "latent":
+                    kw["mixer"] = mixer
+                cls = LatentBlock
+                masked = mixer == "kda" or ffn == "experts"
+            if cfg.remat:
+                cls = nn.remat(
+                    cls, static_argnums=(),
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        *remat_keep) if remat_keep else None)
+            return cls, kw, masked and token_mask is not None
 
-        if cfg.scan_layers:
-            # One Block traced once, lax.scan over a stacked param tree
-            # [num_layers, ...] — compile time is O(1) in depth (the
-            # VERDICT r1 "compile-time win" flag, now real).  The cache
-            # is likewise a stacked pytree (see init_cache /
-            # init_paged_cache with scan_layers=True); positions are
-            # broadcast.  Param metadata gains a leading "layers"
-            # logical axis (replicated by LOGICAL_RULES).
-            scan_block = nn.scan(
-                block_cls,
-                # "intermediates" must be listed or nn.scan silently
-                # DROPS everything sown inside the scanned block — the
-                # MoE router aux loss would read as zero under
-                # scan_layers with no error.
-                variable_axes={"params": 0, "intermediates": 0},
-                split_rngs={"params": True},
-                in_axes=(nn.broadcast, 0) + (nn.broadcast,) * len(more),
-                out_axes=0,
-                length=cfg.num_layers - n_lead,
-                metadata_params={nn.meta.PARTITION_NAME: "layers"},
-            )
-            x, new_cache = scan_block(cfg, name="layers")(
-                x, positions, cache["layers"] if lead_cache is not None
-                else cache, *more)
-            if cache is None:
-                new_cache = None
-            elif n_lead:
-                new_cache = {"dense": new_lead, "layers": new_cache}
-        else:
-            new_cache = None
-            if cache is not None:
-                new_cache = list(new_lead) if n_lead else []
-            for i in range(n_lead, cfg.num_layers):
-                layer_cache = cache[i] if cache is not None else None
-                x, new_layer_cache = block_cls(cfg, name=f"layers_{i}")(
-                    x, positions, layer_cache, *more)
-                if new_cache is not None:
-                    new_cache.append(new_layer_cache)
+        # One stretch of equal kinds at a time (cfg.layer_runs).  A
+        # pattern model's leading dense layers, layers_<i> in both
+        # layouts, stand outside the scanned stacks.
+        runs = cfg.layer_runs()
+        stacks = _stack_names(cfg)
+        run_caches = _split_cache(cfg, cache)
+        new_caches = []
+        for (first, length, mixer, ffn), rc in zip(runs, run_caches):
+            cls, kw, masked = block(mixer, ffn)
+            more = (token_mask,) if masked else ()
+            if cfg.scan_layers and first in stacks:
+                # One Block traced once, lax.scan over a stacked param
+                # tree [length, ...] — compile time is O(1) in depth (the
+                # VERDICT r1 "compile-time win" flag, now real).  The
+                # cache is likewise a stacked pytree (see init_cache /
+                # init_paged_cache with scan_layers=True); positions are
+                # broadcast.  Param metadata gains a leading "layers"
+                # logical axis (replicated by LOGICAL_RULES).
+                scan_block = nn.scan(
+                    cls,
+                    # "intermediates" must be listed or nn.scan silently
+                    # DROPS everything sown inside the scanned block — the
+                    # MoE router aux loss would read as zero under
+                    # scan_layers with no error.
+                    variable_axes={"params": 0, "intermediates": 0},
+                    split_rngs={"params": True},
+                    in_axes=(nn.broadcast, 0) + (nn.broadcast,) * len(more),
+                    out_axes=0,
+                    length=length,
+                    metadata_params={nn.meta.PARTITION_NAME: "layers"},
+                )
+                x, c = scan_block(cfg, name=stacks[first], **kw)(
+                    x, positions, rc, *more)
+                new_caches.append(c)
+            else:
+                out = []
+                for j in range(length):
+                    x, c = cls(cfg, name=f"layers_{first + j}", **kw)(
+                        x, positions, None if rc is None else rc[j], *more)
+                    out.append(c)
+                new_caches.append(out)
+        new_cache = None if cache is None else _join_cache(cfg, new_caches)
 
         x = _norm(cfg, "final_norm")(x)
         hidden = x
@@ -676,28 +907,25 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     # verify chunk).  Slots carry the slot==position causal rule, so
     # the padded tail is masked for every real query.
     max_len = -(-max_len // 8) * 8
-    if cfg.latent_attention:
-        if quantized:
-            raise ValueError(
-                "there is no int8 latent cache (rollout.quantize_kv) for "
-                "arch='deepseek_v3' yet: ops/quant.py scales per head")
+    if cfg.latent_attention and quantized:
+        raise ValueError(
+            "there is no int8 latent cache (rollout.quantize_kv) for "
+            f"arch={cfg.arch!r} yet: ops/quant.py scales per head, and a "
+            "recurrent state has no int8 form")
+    shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
 
-        def latent(pre=()):
+    def entry(mixer, pre=()):
+        if mixer == "kda":
+            H, d = cfg.kda_num_heads, cfg.kda_head_dim
+            return {"S": jnp.zeros(pre + (batch, H, d, d), jnp.float32),
+                    "conv": jnp.zeros(
+                        pre + (batch, cfg.short_conv_kernel_size - 1,
+                               3 * H * d), dtype)}
+        if mixer == "latent":
             return {"c": jnp.zeros(pre + (batch, max_len, cfg.kv_lora_rank),
                                    dtype),
                     "k_rope": jnp.zeros(
                         pre + (batch, max_len, cfg.qk_rope_head_dim), dtype)}
-
-        n_lead = cfg.first_k_dense_replace
-        if cfg.scan_layers and n_lead:
-            return {"dense": [latent() for _ in range(n_lead)],
-                    "layers": latent((cfg.num_layers - n_lead,))}
-        if cfg.scan_layers:
-            return latent((cfg.num_layers,))
-        return [latent() for _ in range(cfg.num_layers)]
-    shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
-
-    def layer(pre=()):
         if quantized:
             return {"k": jnp.zeros(pre + shape, jnp.int8),
                     "v": jnp.zeros(pre + shape, jnp.int8),
@@ -706,9 +934,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         return {"k": jnp.zeros(pre + shape, dtype),
                 "v": jnp.zeros(pre + shape, dtype)}
 
-    if cfg.scan_layers:
-        return layer((cfg.num_layers,))
-    return [layer() for _ in range(cfg.num_layers)]
+    stacks = _stack_names(cfg) if cfg.scan_layers else {}
+    return _join_cache(cfg, [
+        entry(mixer, (length,)) if first in stacks
+        else [entry(mixer) for _ in range(length)]
+        for first, length, mixer, _ in cfg.layer_runs()])
 
 
 def make_decode_twin(model: nn.Module, cfg: ModelConfig):
@@ -732,8 +962,10 @@ def maybe_unstack_for_decode(params: Any, cfg: ModelConfig):
     constant-index slices XLA fuses); identity for unrolled models."""
     if not cfg.scan_layers:
         return params
-    n_lead = cfg.first_k_dense_replace if cfg.latent_attention else 0
-    return unstack_params_tree(params, cfg.num_layers - n_lead, n_lead)
+    stacks = _stack_names(cfg)
+    return unstack_params_tree(params, {
+        stacks[first]: (first, length)
+        for first, length, _, _ in cfg.layer_runs() if first in stacks})
 
 
 def prep_decode_params(params: Any, cfg: ModelConfig,
@@ -757,12 +989,13 @@ def prep_decode_params(params: Any, cfg: ModelConfig,
     return params
 
 
-def unstack_params_tree(params: Any, num_layers: int, first: int = 0):
+def unstack_params_tree(params: Any, stacks: dict):
     """jit-safe inverse of the scan_layers stacking: every subtree
-    holding a stacked "layers" entry [L, ...] becomes
-    layers_<first>..<first+L-1> subtrees (recursing through wrappers
-    like ActorCriticModel's "backbone"; ``first`` > 0 where leading
-    layers already stand unstacked beside the stack).  XLA lowers the constant-index slices to views/copies
+    holding a stacked entry named in ``stacks`` ({name: (first layer,
+    length)}, see :func:`_stack_names`) becomes layers_<first>..
+    subtrees (recursing through wrappers like ActorCriticModel's
+    "backbone"; layers that stand unstacked beside a stack stay as they
+    are).  XLA lowers the constant-index slices to views/copies
     it can fuse — used by the rollout engine to decode with an
     unrolled model twin (the stacked cache carried through nn.scan
     costs ~2x decode time; see RolloutEngine)."""
@@ -770,12 +1003,13 @@ def unstack_params_tree(params: Any, num_layers: int, first: int = 0):
         return params
     out = {}
     for k, v in params.items():
-        if k == "layers":
-            for i in range(num_layers):
+        if k in stacks:
+            first, length = stacks[k]
+            for i in range(length):
                 out[f"layers_{first + i}"] = jax.tree.map(
                     lambda x: x[i], v)
         elif isinstance(v, dict):
-            out[k] = unstack_params_tree(v, num_layers, first)
+            out[k] = unstack_params_tree(v, stacks)
         else:
             out[k] = v
     return out

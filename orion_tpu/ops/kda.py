@@ -1,0 +1,193 @@
+"""The delta rule with a per-channel decay (Kimi Delta Attention), in the
+two forms a model needs of it.
+
+Per head, with a state ``S`` [dk, dv] in float32, zero before the first
+token, a log decay ``g_t`` [dk] (<= 0), a step size ``beta_t`` and
+``q_t, k_t`` [dk], ``v_t`` [dv]::
+
+    S' = Diag(exp(g_t)) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+    o_t = S_t^T q_t
+
+- :func:`kda_step`: one token (decode); the three lines above.
+- :func:`kda_chunked`: a whole sequence (training, the experience
+  forwards, prefill), chunk by chunk; differentiable by autodiff.
+
+A position with ``g = 0`` and ``beta = 0`` leaves the state as it was:
+that is how a caller makes padding inert, and how the chunked form pads
+a sequence to whole chunks.
+
+**The chunked form.**  Inside a chunk of C tokens with ``G_t`` the
+running sum of ``g`` (so ``exp(G_t - G_i)`` is the decay from token i
+to token t), the pseudo-values ``u_t = beta_t (v_t - S_{t-1}'^T k_t)``
+solve the unit lower-triangular system::
+
+    (I + Diag(beta) tril(A, -1)) U = Diag(beta) (V - (K * e^G) S_0)
+    A[t, i] = sum_c k_t[c] k_i[c] exp(G_t[c] - G_i[c])
+
+whose inverse is built by doubling from the inverses of its diagonal
+blocks (``log2 C`` levels of small matrix products; no triangular
+solve, which the TPU does one row at a time, and no Neumann series,
+whose powers overflow where a chunk's keys are alike).  Then
+``O = (Q * e^G) S_0 + tril(B) U`` with ``B`` as ``A`` with ``q_t`` in
+place of ``k_t``, and ``S_C = Diag(e^{G_C}) S_0 + (K * e^{G_C - G})^T
+U``.  One ``lax.scan`` over the chunks carries ``S`` and does all of a
+chunk's work in its body, which is checkpointed: the backward keeps the
+states at the chunk boundaries and recomputes inside.
+
+**Decays that overflow.**  ``exp(G_t - G_i)`` is at most 1, but it is a
+``[C, C, dk]`` tensor; split into ``e^{G_t} e^{-G_i}`` for a matrix
+product, the second factor overflows float32 once a channel has
+decayed by ``e^-88`` inside the chunk, which a strong decay does in a
+few tokens.  So ``A`` and ``B`` are built in ``log2 C`` levels: at the
+level of half-size h every block of 2h tokens is cut in its middle,
+the pairs (t in the upper half, i in the lower half) take the decay
+from the middle, ``e^{G_t - G_mid} e^{G_mid - G_i}``, both factors at
+most 1, and every pair t > i is split by exactly one level.  Underflow
+is harmless (the pair has decayed to nothing).  Each level is one
+matrix product ``[2C, dk] x [dk, C]``: ``log2 C`` times the operations
+of the naive product, all of them on the MXU.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+CHUNK = 64
+
+
+def kda_step(q, k, v, g, beta, state):
+    """One token.  q, k, g [B, H, dk]; v [B, H, dv]; beta [B, H]; state
+    [B, H, dk, dv] float32 -> (o [B, H, dv] float32, new state).
+
+    Elementwise products and sums in float32: a step reads and writes
+    the state once, and a matrix product with one row would round the
+    state on its way into the MXU."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (t.astype(f32) for t in (q, k, v, g, beta))
+    state = jnp.exp(g)[..., None] * state
+    pred = jnp.sum(state * k[..., None], axis=-2)               # [B, H, dv]
+    u = beta[..., None] * (v - pred)
+    state = state + k[..., None] * u[..., None, :]
+    return jnp.sum(state * q[..., None], axis=-2), state
+
+
+def _pair_products(rows, k, G):
+    """sum_c rows[t, c] k[i, c] exp(G[t, c] - G[i, c]) for t > i, zero
+    elsewhere, without forming ``exp(-G)``: the levels of the module
+    docstring.  rows [..., R, C, d] (R stacked sets of rows: k and q
+    share every level's scaling), k, G [..., C, d] -> [..., R, C, C]."""
+    C = k.shape[-2]
+    pos = jnp.arange(C)
+    out = 0.0
+    h = 1
+    while h < C:
+        upper = (pos // h) % 2 == 1                              # [C]
+        # G at the last position of the lower half of each 2h-block
+        mid = (pos // (2 * h)) * (2 * h) + h - 1
+        G_mid = jnp.take(G, mid, axis=-2)
+        scale = jnp.exp(jnp.where(upper[:, None], G - G_mid, G_mid - G))
+        t_side = jnp.where(upper[:, None], rows * scale[..., None, :, :],
+                           0.0)
+        i_side = jnp.where(upper[:, None], 0.0, k * scale)
+        same = (pos[:, None] // (2 * h)) == (pos[None, :] // (2 * h))
+        level = jnp.einsum("...rtc,...ic->...rti", t_side, i_side)
+        out = out + jnp.where(same, level, 0.0)
+        h *= 2
+    return out
+
+
+def _unit_lower_inverse(M):
+    """(I + M)^-1 for strictly lower-triangular M [..., C, C], C a power
+    of two, by doubling: the inverses of the diagonal blocks of size m
+    give those of size 2m, ``[[T1, 0], [-T2 M21 T1, T2]]``.  Every
+    intermediate is the inverse of a diagonal block of ``I + M`` and as
+    well-behaved as the answer, which a Neumann series by squarings is
+    not: where the keys of a chunk are alike and decay slowly (a run of
+    one repeated token) ``M`` is near ``beta`` times the all-ones
+    triangle, its powers reach 1e8 before they cancel, and on the chip
+    the update came out NaN (PERF.md section 6, PR 32).  log2 C levels of
+    two batched products of [m, m]: a thirtieth of the squarings'
+    operations."""
+    C = M.shape[-1]
+    assert C & (C - 1) == 0, "the chunk length must be a power of two"
+    lead = M.shape[:-2]
+    D = jnp.ones(lead + (C, 1, 1), M.dtype)      # the blocks of size 1
+    m = 1
+    while m < C:
+        nb = C // (2 * m)
+        # M's block below the diagonal inside each block of 2m
+        M21 = jnp.einsum(
+            "...iaib->...iab",
+            M.reshape(lead + (nb, 2, m, nb, 2, m))[..., :, 1, :, :, 0, :])
+        pairs = D.reshape(lead + (nb, 2, m, m))
+        T1, T2 = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        T21 = -jnp.matmul(jnp.matmul(T2, M21), T1)
+        D = jnp.concatenate(
+            [jnp.concatenate([T1, jnp.zeros_like(T1)], axis=-1),
+             jnp.concatenate([T21, T2], axis=-1)], axis=-2)
+        m *= 2
+    return D[..., 0, :, :]
+
+
+def _chunk(S, q, k, v, g, beta):
+    """One chunk for every (batch, head): S [B, H, dk, dv]; q, k, g
+    [B, H, C, dk]; v [B, H, C, dv]; beta [B, H, C].  Every matrix
+    product at the backend's default precision (bf16 operands on a TPU);
+    the state itself stays float32."""
+    mm = jnp.matmul
+    C = q.shape[-2]
+    G = jnp.cumsum(g, axis=-2)
+    pairs = _pair_products(jnp.stack([k, q], axis=-3), k, G)
+    A, Bq = pairs[..., 0, :, :], pairs[..., 1, :, :]
+    T = _unit_lower_inverse(beta[..., None] * A)
+    decay = jnp.exp(G)                                           # <= 1
+    rhs = beta[..., None] * jnp.concatenate([v, k * decay], axis=-1)
+    sol = mm(T, rhs)
+    U = sol[..., :v.shape[-1]] - mm(sol[..., v.shape[-1]:], S)   # [.., C, dv]
+    diag = jnp.sum(q * k, axis=-1)                               # t == i
+    o = mm(q * decay, S) + mm(Bq + diag[..., None] * jnp.eye(C), U)
+    G_end = G[..., -1:, :]
+    S = jnp.swapaxes(jnp.exp(G_end), -1, -2) * S + mm(
+        jnp.swapaxes(k * jnp.exp(G_end - G), -1, -2), U)
+    return S, o
+
+
+def kda_chunked(q, k, v, g, beta, state: Optional[jax.Array] = None,
+                chunk: int = CHUNK):
+    """A whole sequence.  q, k, g [B, L, H, dk]; v [B, L, H, dv]; beta
+    [B, L, H]; state [B, H, dk, dv] float32 or None (zero) ->
+    (o [B, L, H, dv] float32, the state after the last position)."""
+    f32 = jnp.float32
+    B, L, H, dk = q.shape
+    dv = v.shape[-1]
+    n = -(-L // chunk)
+    pad = n * chunk - L
+
+    def chunks(t):
+        """[B, L, H, ...] -> [n, B, H, chunk, ...], padded inertly; in
+        the dtype given (a chunk is brought to float32 in the body, so
+        that what the backward keeps of a sequence stays as narrow as
+        the caller made it)."""
+        if pad:
+            t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+        t = t.reshape((B, n, chunk) + t.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(t, 1, 0), 2, 3)
+
+    if state is None:
+        state = jnp.zeros((B, H, dk, dv), f32)
+
+    @jax.checkpoint
+    def body(S, xs):
+        return _chunk(S, *(t.astype(f32) for t in xs))
+
+    state, o = jax.lax.scan(
+        body, state.astype(f32),
+        (chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta)))
+    # [n, B, H, chunk, dv] -> [B, L, H, dv]
+    o = jnp.moveaxis(jnp.moveaxis(o, 3, 2), 0, 1).reshape(
+        B, n * chunk, H, dv)
+    return o[:, :L], state

@@ -15,6 +15,9 @@ Rules (MaxText/T5X-style):
   layers  — scanned layer stack dimension → (replicated)
   latent  — latent attention's compressed KV → (replicated)
   expert  — stacked expert weights         → expert
+  conv    — a depthwise convolution's taps → (replicated; its channels
+            are ``heads``, as are a KDA layer's projections and
+            ``dt_bias``; its low-rank gates run embed → latent → heads)
 """
 
 from __future__ import annotations
@@ -34,6 +37,7 @@ LOGICAL_RULES: dict = {
     "layers": None,
     "norm": None,
     "latent": None,     # latent attention's 512 / 576: replicated
+    "conv": None,       # the 4 taps of a KDA layer's convolutions
     "expert": "expert",
     "batch": ("data", "fsdp"),
     "seq": "seq",

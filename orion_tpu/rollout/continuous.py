@@ -217,7 +217,12 @@ class ContinuousBatchingEngine:
                 ": its page pool, block tables and the Pallas paged-decode "
                 "kernel hold per-head K/V pages of one head_dim; a latent "
                 "paged cache (c, k_rope) and a kernel that attends over it "
-                "are not written yet (use rollout.engine=simple)")
+                "are not written yet"
+                + ("; nor does its cache manager hold a recurrent state "
+                   "per slot (admission, preemption and prefix reuse move "
+                   "pages, and a state is not made of pages)"
+                   if model_cfg.recurrent else "")
+                + " (use rollout.engine=simple)")
         self.mc = model_cfg
         self.cfg = cfg
         cfg.check_stop_ids(model_cfg.vocab_size, eos_token_id)

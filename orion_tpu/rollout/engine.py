@@ -18,7 +18,12 @@ One decode path (XLA-first, static shapes):
 - the cache is dense ([B, P+T] per layer; the latent form for
   ``latent_attention``), int8 under ``quantize_kv``, or paged under
   ``RolloutConfig.paged`` (block tables + the Pallas paged-decode
-  kernel; slower than dense for a fixed batch, ROADMAP D3(a)).
+  kernel; slower than dense for a fixed batch, ROADMAP D3(a));
+- a recurrent layer (``ModelConfig.recurrent``) has no slot to
+  overwrite: its cache entry is a state, and prefill (given
+  ``token_mask``) hands decode each row's state and last convolution
+  inputs after its last real prompt token (models.transformer.
+  KimiDeltaAttention).  The decode loop is the same.
 
 Speculative decoding is not here: a lockstep batch advances at its
 slowest row's acceptance, and it lost on the chip (PERF.md section 6,
@@ -86,17 +91,22 @@ class RolloutEngine:
         self.pad_token_id = pad_token_id
         self._params = None
         self._cache_bytes: dict = {}
+        self._weight_bytes: Optional[int] = None
         from orion_tpu.models.transformer import make_decode_twin
 
         self._decode_model, self._decode_cfg = make_decode_twin(
             model, model_cfg)
         if model_cfg.latent_attention:
+            state = ", and a recurrent state is not made of pages" \
+                if model_cfg.recurrent else ""
             for on, missing in (
                     (cfg.paged, "rollout.paged: there is no latent paged "
                      "cache (ops/paged_kv.py and the Pallas paged-decode "
-                     "kernel hold per-head K/V pages)"),
+                     "kernel hold per-head K/V pages)" + state),
                     (cfg.quantize_kv, "rollout.quantize_kv: there is no "
-                     "int8 latent cache (ops/quant.py scales per head)"),
+                     "int8 latent cache (ops/quant.py scales per head)"
+                     + (", nor an int8 form of a float32 recurrent state"
+                        if model_cfg.recurrent else "")),
                     (cfg.quantize_weights, "rollout.quantize_weights: "
                      "there are no int8 expert stacks or absorbed int8 "
                      "kv_b_proj (ops/quant.py quantises Dense kernels)")):
@@ -121,22 +131,60 @@ class RolloutEngine:
         (SURVEY.md §2 #11)."""
         self._params = params
 
-    def cache_bytes(self, batch: int, prompt_len: int,
-                    max_new_tokens: Optional[int] = None) -> int:
-        """Bytes of the cache ``_generate`` allocates for such a batch
-        (shapes only, nothing is placed)."""
+    def _cache_shapes(self, batch: int, prompt_len: int,
+                      max_new_tokens: Optional[int] = None):
         T = int(max_new_tokens or self.cfg.max_new_tokens)
-        if self.cfg.paged:
-            return 0
         key = (batch, prompt_len + T)
         if key not in self._cache_bytes:
             cache = jax.eval_shape(
                 lambda: init_cache(self._decode_cfg, *key,
                                    dtype=jnp.dtype(self._decode_cfg.dtype),
                                    quantized=self.cfg.quantize_kv))
-            self._cache_bytes[key] = sum(
-                x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+            sizes = {"cache": 0, "state": 0}
+            for layer in cache:       # the decode twin's: one per layer
+                kind = "state" if "S" in layer else "cache"
+                sizes[kind] += sum(x.size * x.dtype.itemsize
+                                   for x in jax.tree.leaves(layer))
+            self._cache_bytes[key] = sizes
         return self._cache_bytes[key]
+
+    def cache_bytes(self, batch: int, prompt_len: int,
+                    max_new_tokens: Optional[int] = None) -> int:
+        """Bytes of what ``_generate`` allocates for such a batch that
+        is indexed by position: the keys and values, or latents, of
+        every slot of every layer that has them (shapes only, nothing
+        is placed; 0 under ``paged``, whose pool is sized apart)."""
+        if self.cfg.paged:
+            return 0
+        return self._cache_shapes(batch, prompt_len, max_new_tokens)["cache"]
+
+    def state_bytes(self, batch: int, prompt_len: int,
+                    max_new_tokens: Optional[int] = None) -> int:
+        """Bytes of the per-sequence state that is NOT indexed by
+        position (the recurrent layers' states and convolution inputs):
+        read and written whole at every decode step.  0 for a model
+        without such layers."""
+        if self.cfg.paged:
+            return 0
+        return self._cache_shapes(batch, prompt_len, max_new_tokens)["state"]
+
+    def weight_bytes(self, params: Any = None) -> int:
+        """Bytes of the copy of the weights a decode step reads
+        (``prep_decode_params``: the compute dtype, int8 kernels under
+        ``quantize_weights``), from shapes."""
+        from orion_tpu.models.transformer import prep_decode_params
+
+        params = params if params is not None else self._params
+        if params is None:
+            return 0
+        if self._weight_bytes is None:
+            tree = jax.eval_shape(
+                lambda p: prep_decode_params(p, self.model_cfg,
+                                             self.cfg.quantize_weights),
+                params)
+            self._weight_bytes = sum(x.size * x.dtype.itemsize
+                                     for x in jax.tree.leaves(tree))
+        return self._weight_bytes
 
     # -- generation -----------------------------------------------------
     def generate(self, prompt_ids: jnp.ndarray, prompt_lens: jnp.ndarray,
@@ -188,9 +236,10 @@ class RolloutEngine:
                                dtype=jnp.dtype(self._decode_cfg.dtype),
                                quantized=cfg.quantize_kv)
         positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
-        # the dropless expert layer routes a prompt's padding nowhere
+        # the dropless expert layer routes a prompt's padding nowhere,
+        # and a recurrent layer's state stops at the last real token
         pad_kw = {"token_mask": positions < prompt_lens[:, None]} \
-            if self.model_cfg.n_routed_experts > 0 else {}
+            if self.model_cfg.takes_token_mask else {}
         with jax.named_scope("prefill"):
             # Only the last real prompt token's logits are needed (they
             # predict completion[0]) — logits_positions skips the other

@@ -436,10 +436,10 @@ class BaseTrainer:
         positions = jnp.broadcast_to(
             jnp.arange(L, dtype=jnp.int32), sequences.shape)
         widx = completion_window_positions(prompt_lens, max_new, L)
-        if self.cfg.model.n_routed_experts > 0:
+        if self.cfg.model.takes_token_mask:
             # behind prompt + completion window a row is padding, whatever
             # the completion's length: the dropless expert layer routes
-            # those positions nowhere
+            # those positions nowhere, a recurrent mixer passes them by
             apply_kw["token_mask"] = positions < (prompt_lens
                                                   + max_new)[:, None]
         out, aux, moe = self._policy_apply(
@@ -510,12 +510,19 @@ class BaseTrainer:
         return self.engine.generate(ids, lens, rng,
                                     params=self.state.params)
 
-    def _rollout_cache_bytes(self, prompts_shape) -> int:
-        """Bytes of the KV cache the fixed-batch engine allocates for
-        this batch (from shapes; 0 for the continuous engine, whose
-        pool is its own)."""
-        cache_bytes = getattr(self.engine, "cache_bytes", None)
-        return cache_bytes(*prompts_shape) if cache_bytes else 0
+    def _rollout_bytes(self, prompts_shape) -> dict:
+        """What a decode step of the fixed-batch engine touches for this
+        batch, from shapes: ``cache_bytes`` (what is indexed by
+        position: keys and values, or latents), ``state_bytes`` (what is
+        not: recurrent states, read and written whole a step) and
+        ``weight_bytes`` (the decode copy of the weights).  All 0 for
+        the continuous engine, whose pool is its own."""
+        eng = self.engine
+        if not hasattr(eng, "state_bytes"):
+            return {"cache_bytes": 0, "state_bytes": 0, "weight_bytes": 0}
+        return {"cache_bytes": eng.cache_bytes(*prompts_shape),
+                "state_bytes": eng.state_bytes(*prompts_shape),
+                "weight_bytes": eng.weight_bytes(self.state.params)}
 
     def _score_result(self, result, host, meta) -> np.ndarray:
         """One place for the device-vs-host reward dispatch (the
@@ -626,7 +633,7 @@ class BaseTrainer:
         with obs.span("rollout.dispatch") as sp:
             ids, lens, meta = self.prepare_prompts(batch)
             sp.set(batch=int(ids.shape[0]), prompt_len=int(ids.shape[1]),
-                   cache_bytes=self._rollout_cache_bytes(ids.shape))
+                   **self._rollout_bytes(ids.shape))
             result = self.generate(
                 ids, lens, group_size=getattr(self.cfg, "group_size", 1))
         pend, self._pending_fetch = self._pending_fetch, None

@@ -406,3 +406,76 @@ def test_grouped_expert_product_compiles_for_v5e(one_chip, on_tpu):
     # gate|up and down: two of each backward half (the second forward
     # product's result is not needed for these gradients)
     assert names.count("moe_gmm_dlhs") == 2 and names.count("moe_tgmm") == 2
+
+
+# -- the kimi_linear block at the published widths --------------------------
+
+def test_delta_rule_compiles_for_v5e(one_chip, on_tpu):
+    """Both forms of the delta rule at the shapes of
+    ``ppo-kimi-linear-ep32-sync``: the chunked form with its backward at
+    the update's minibatch (16 x 1024, 32 heads of 128; no kernel: matrix
+    products and one scan) and one decode step over a batch of 32."""
+    from orion_tpu.ops.kda import kda_chunked, kda_step
+
+    B, L, H, d = 16, 1024, 32, 128
+
+    def loss(q, k, v, g, beta):
+        o, S = kda_chunked(q, k, v, g, beta)
+        return jnp.sum(o) + jnp.sum(S)
+
+    with jax.default_matmul_precision("default"):
+        chunked = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            *(_sds((B, L, H, d), BF16, one_chip),) * 3,
+            _sds((B, L, H, d), jnp.float32, one_chip),
+            _sds((B, L, H), jnp.float32, one_chip)).compile()
+        step = jax.jit(kda_step).lower(
+            *(_sds((32, H, d), BF16, one_chip),) * 3,
+            _sds((32, H, d), jnp.float32, one_chip),
+            _sds((32, H), jnp.float32, one_chip),
+            _sds((32, H, d, d), jnp.float32, one_chip)).compile()
+    assert _kernel_calls(chunked) == 0 and _kernel_calls(step) == 0
+    # what the backward holds of one layer: the states at the 16 chunk
+    # boundaries and the inputs, not the chunks' insides
+    assert chunked.memory_analysis().peak_memory_in_bytes < 4 * 2**30
+
+
+def test_kimi_linear_update_compiles_for_v5e(one_chip, on_tpu):
+    """The shared-backbone PPO update over every kind of layer the
+    ``kimi_linear`` pattern has, at the published widths: a dense KDA
+    layer, a KDA layer and a latent layer without rotation over the
+    expert layer (8 of 256 held), remat, each stretch scanned.  The
+    update has the flash kernels (the latent layer) and the grouped
+    products (the experts) in it and fits the chip."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc = dataclasses.replace(
+        ModelConfig.kimi_linear_48b_a3b(), num_layers=3, kda_layers=(1, 2),
+        experts_held=8, vocab_size=20480,
+        max_seq_len=1024)
+    assert mc.layer_runs() == ((0, 1, "kda", "dense"),
+                               (1, 1, "kda", "experts"),
+                               (2, 1, "latent", "experts"))
+    shell, pshape, mb = _build_8b_shell(mc)
+    rows, S, T = 4, 1024, 512
+    shell.cfg.rollout.max_prompt_len = shell.cfg.rollout.max_new_tokens = T
+    shapes = {k: (S,) if k == "sequences" else () if k == "prompt_lens"
+              else (T,) for k in mb}
+    experience = {k: _sds((2 * rows,) + shapes[k], v.dtype, one_chip)
+                  for k, v in mb.items()}
+    state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                         _abstract_state(shell, pshape))
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+            donate_argnums=(0,)).lower(
+                state, experience, _sds((2, rows), jnp.int32, one_chip)
+            ).compile()
+    assert _kernel_calls(compiled) >= 1          # tpu_custom_call
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
+            "moe_gmm_dlhs", "moe_tgmm"} <= set(_kernel_names(compiled))
+    assert compiled.memory_analysis().peak_memory_in_bytes <= 15.75 * 2**30
