@@ -11,7 +11,16 @@ token, a log decay ``g_t`` [dk] (<= 0), a step size ``beta_t`` and
 
 - :func:`kda_step`: one token (decode); the three lines above.
 - :func:`kda_chunked`: a whole sequence (training, the experience
-  forwards, prefill), chunk by chunk; differentiable by autodiff.
+  forwards, prefill), chunk by chunk, differentiable.  One algorithm in
+  two forms, chosen at trace time by :func:`chunk_form`: where the
+  trace is for a TPU and the head sizes are multiples of 128, the two
+  Pallas kernels of ``ops/pallas/kda_chunk.py`` (a chunk's insides stay
+  in VMEM, the inputs are read as ``[B, L, H d]`` without a transpose,
+  the backward is written by hand behind ``jax.custom_vjp`` and keeps
+  the inputs and the float32 states at the chunk boundaries, ``[B, H,
+  n, dv, dk]``, recomputing the insides); everywhere else (the CPU, odd
+  head sizes) the ``jax.numpy`` form below, differentiated by autodiff,
+  which is also what the kernels are tested against.
 
 A position with ``g = 0`` and ``beta = 0`` leaves the state as it was:
 that is how a caller makes padding inert, and how the chunked form pads
@@ -31,9 +40,12 @@ solve, which the TPU does one row at a time, and no Neumann series,
 whose powers overflow where a chunk's keys are alike).  Then
 ``O = (Q * e^G) S_0 + tril(B) U`` with ``B`` as ``A`` with ``q_t`` in
 place of ``k_t``, and ``S_C = Diag(e^{G_C}) S_0 + (K * e^{G_C - G})^T
-U``.  One ``lax.scan`` over the chunks carries ``S`` and does all of a
-chunk's work in its body, which is checkpointed: the backward keeps the
-states at the chunk boundaries and recomputes inside.
+U``.  In the ``jax.numpy`` form one ``lax.scan`` over the chunks carries
+``S`` and does all of a chunk's work in its body (:func:`_chunk`), which
+is checkpointed: the backward keeps the states at the chunk boundaries
+and recomputes inside.  The kernels do the same arithmetic with the same
+roundings (matrix-product operands in bfloat16 as the MXU takes them at
+default precision; state, decays, ``g`` and accumulation float32).
 
 **Decays that overflow.**  ``exp(G_t - G_i)`` is at most 1, but it is a
 ``[C, C, dk]`` tensor; split into ``e^{G_t} e^{-G_i}`` for a matrix
@@ -156,6 +168,53 @@ def _chunk(S, q, k, v, g, beta):
     return S, o
 
 
+def chunk_form(dk: int, dv: int) -> str:
+    """Which form of the chunked rule a trace takes here: ``"kernel"``
+    (ops/pallas/kda_chunk.py) where the trace is for a TPU and the head
+    sizes are whole lane tiles, ``"jnp"`` (the scan below) everywhere
+    else.  Asked at trace time, as ``ops.attention`` asks for flash; the
+    trainer reports the answer on its ``update`` span."""
+    from orion_tpu.ops.pallas import target_platform
+
+    return ("kernel" if target_platform() == "tpu" and dk % 128 == 0
+            and dv % 128 == 0 else "jnp")
+
+
+def _kernel_on_mesh(q, k, v, g, beta, state, chunk):
+    """The kernels under whatever mesh is ambient, as
+    ``ops.attention._flash_on_mesh``: a Mosaic kernel cannot be
+    partitioned automatically, so under a mesh of several devices it
+    runs in a ``shard_map`` over the batch (data, fsdp) and the heads
+    (tensor), each where it divides.  Rows and heads are independent:
+    no collective."""
+    import math
+
+    from orion_tpu.ops.pallas.kda_chunk import kda_chunk_kernel
+    from orion_tpu.parallel.sharding import ambient_mesh
+
+    def run(*args):
+        return kda_chunk_kernel(*args, chunk)
+
+    mesh = ambient_mesh()
+    if (mesh.empty or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return run(q, k, v, g, beta, state)
+    from jax.sharding import PartitionSpec as P
+
+    from orion_tpu.utils.platform import shard_map
+
+    shape = dict(mesh.shape)
+    batch = tuple(a for a in ("data", "fsdp") if shape.get(a, 1) > 1)
+    n_batch = math.prod(shape[a] for a in batch)
+    b = batch if batch and q.shape[0] % n_batch == 0 else None
+    tp = shape.get("tensor", 1)
+    h = "tensor" if tp > 1 and q.shape[2] % tp == 0 else None
+    seq, st = P(b, None, h, None), P(b, h, None, None)
+    return shard_map(
+        run, mesh=mesh, in_specs=(seq, seq, seq, seq, P(b, None, h), st),
+        out_specs=(seq, st), check_vma=False)(q, k, v, g, beta, state)
+
+
 def kda_chunked(q, k, v, g, beta, state: Optional[jax.Array] = None,
                 chunk: int = CHUNK):
     """A whole sequence.  q, k, g [B, L, H, dk]; v [B, L, H, dv]; beta
@@ -164,6 +223,10 @@ def kda_chunked(q, k, v, g, beta, state: Optional[jax.Array] = None,
     f32 = jnp.float32
     B, L, H, dk = q.shape
     dv = v.shape[-1]
+    if state is None:
+        state = jnp.zeros((B, H, dk, dv), f32)
+    if chunk_form(dk, dv) == "kernel":
+        return _kernel_on_mesh(q, k, v, g, beta, state, chunk)
     n = -(-L // chunk)
     pad = n * chunk - L
 
@@ -176,9 +239,6 @@ def kda_chunked(q, k, v, g, beta, state: Optional[jax.Array] = None,
             t = jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
         t = t.reshape((B, n, chunk) + t.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(t, 1, 0), 2, 3)
-
-    if state is None:
-        state = jnp.zeros((B, H, dk, dv), f32)
 
     @jax.checkpoint
     def body(S, xs):

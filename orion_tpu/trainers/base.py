@@ -690,8 +690,14 @@ class BaseTrainer:
         nothing (the CPU) gives no budget, and nothing is kept."""
         from orion_tpu.models.transformer import remat_keep, remat_tag_bytes
 
+        mc, kda_chunk = self.cfg.model, ""
+        if mc.recurrent:
+            # the form the KDA layers' chunked rule takes in this trace
+            from orion_tpu.ops.kda import chunk_form
+            kda_chunk = chunk_form(mc.kda_head_dim, mc.kda_head_dim)
         self._remat_info = info = {
-            "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0}
+            "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0,
+            "kda_chunk": kda_chunk}
         free = _device_free_bytes(self.state.params)
         if not self.cfg.model.remat or free is None:
             return

@@ -413,11 +413,13 @@ def test_grouped_expert_product_compiles_for_v5e(one_chip, on_tpu):
 def test_delta_rule_compiles_for_v5e(one_chip, on_tpu):
     """Both forms of the delta rule at the shapes of
     ``ppo-kimi-linear-ep32-sync``: the chunked form with its backward at
-    the update's minibatch (16 x 1024, 32 heads of 128; no kernel: matrix
-    products and one scan) and one decode step over a batch of 32."""
-    from orion_tpu.ops.kda import kda_chunked, kda_step
+    the update's minibatch (16 x 1024, 32 heads of 128: the two kernels
+    of ops/pallas/kda_chunk.py, through Mosaic) and one decode step over
+    a batch of 32 (no kernel)."""
+    from orion_tpu.ops.kda import chunk_form, kda_chunked, kda_step
 
     B, L, H, d = 16, 1024, 32, 128
+    assert chunk_form(d, d) == "kernel"
 
     def loss(q, k, v, g, beta):
         o, S = kda_chunked(q, k, v, g, beta)
@@ -433,7 +435,8 @@ def test_delta_rule_compiles_for_v5e(one_chip, on_tpu):
             _sds((32, H, d), jnp.float32, one_chip),
             _sds((32, H), jnp.float32, one_chip),
             _sds((32, H, d, d), jnp.float32, one_chip)).compile()
-    assert _kernel_calls(chunked) == 0 and _kernel_calls(step) == 0
+    assert _kernel_names(chunked) == ["kda_chunk_bwd", "kda_chunk_fwd"]
+    assert _kernel_calls(step) == 0
     # what the backward holds of one layer: the states at the 16 chunk
     # boundaries and the inputs, not the chunks' insides
     assert chunked.memory_analysis().peak_memory_in_bytes < 4 * 2**30
@@ -444,8 +447,9 @@ def test_kimi_linear_update_compiles_for_v5e(one_chip, on_tpu):
     ``kimi_linear`` pattern has, at the published widths: a dense KDA
     layer, a KDA layer and a latent layer without rotation over the
     expert layer (8 of 256 held), remat, each stretch scanned.  The
-    update has the flash kernels (the latent layer) and the grouped
-    products (the experts) in it and fits the chip."""
+    update has the flash kernels (the latent layer), the grouped
+    products (the experts) and the chunked delta rule's two kernels (the
+    KDA layers) in it and fits the chip."""
     import dataclasses
 
     from orion_tpu.config import ModelConfig
@@ -477,5 +481,6 @@ def test_kimi_linear_update_compiles_for_v5e(one_chip, on_tpu):
             ).compile()
     assert _kernel_calls(compiled) >= 1          # tpu_custom_call
     assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
-            "moe_gmm_dlhs", "moe_tgmm"} <= set(_kernel_names(compiled))
+            "moe_gmm_dlhs", "moe_tgmm", "kda_chunk_fwd",
+            "kda_chunk_bwd"} <= set(_kernel_names(compiled))
     assert compiled.memory_analysis().peak_memory_in_bytes <= 15.75 * 2**30

@@ -1,0 +1,192 @@
+"""The chunked delta rule's Pallas kernels (ops/pallas/kda_chunk.py)
+against the ``jax.numpy`` form (ops/kda.py), interpreted on the CPU.
+
+The kernels round their products' operands to bfloat16, as the MXU does
+at default precision; the CPU computes the ``jax.numpy`` form's products
+exactly.  So most cases ask the kernels for float32 operands, where the
+two forms are the same formulas and agree to float32's own noise, and
+one case runs the operands the chip runs, to bfloat16's.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from orion_tpu.ops import kda
+from orion_tpu.ops.pallas import kda_chunk
+
+B, H, D = 2, 2, 128
+NAMES = ("q", "k", "v", "g", "beta", "state")
+
+
+def _inputs(L, decay, seed=0, state=True, H=H):
+    """As ``tests/test_kimi_linear.py::_inputs`` at the kernels' head
+    size: ``init`` (what the initialiser gives), ``overflow`` (-80 a
+    step: ``exp(-G)`` overflows float32 after two tokens),
+    ``repeated_token`` (PR 32's run of one token: alike keys, slow
+    decay, where the triangular system is at its worst)."""
+    rs = np.random.RandomState(seed)
+    q, k, v = (rs.normal(size=(B, L, H, D)) for _ in range(3))
+    if decay == "repeated_token":
+        q, k = (np.broadcast_to(t[:, :1], t.shape) for t in (q, k))
+    q = q / np.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(D)
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    if decay == "init":
+        g = -rs.uniform(1, 16, size=(B, 1, H, 1)) * np.exp(rs.uniform(
+            np.log(1e-3), np.log(1e-1), size=(B, L, H, D)))
+    else:
+        g = np.full((B, L, H, D), {"overflow": -80.0,
+                                   "repeated_token": -1e-3}[decay])
+    beta = 1.0 / (1.0 + np.exp(-rs.normal(size=(B, L, H))))
+    if decay == "repeated_token":
+        beta = np.full_like(beta, 0.9)
+    S = rs.normal(size=(B, H, D, D)) * (0.1 if state else 0.0)
+    return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta, S)]
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel(operands="float32"):
+    return jax.jit(lambda *a: kda_chunk.kda_chunk_kernel(
+        *a, kda.CHUNK, jnp.dtype(operands)))
+
+
+def _loss(fn):
+    def loss(*a):
+        o, S = fn(*a)
+        return jnp.sum(jnp.sin(3.0 * o)) + jnp.sum(jnp.cos(S))
+    return loss
+
+
+@functools.lru_cache(maxsize=None)
+def _grads(form, operands="float32"):
+    fn = kda.kda_chunked if form == "jnp" else _kernel(operands)
+    return jax.jit(jax.grad(_loss(fn), argnums=tuple(range(6))))
+
+
+def _close(got, want, tol, name=""):
+    assert np.isfinite(np.asarray(got)).all(), name
+    scale = float(jnp.max(jnp.abs(want))) + 1e-12
+    np.testing.assert_allclose(got, want, atol=tol * scale, rtol=0,
+                               err_msg=name)
+
+
+@pytest.mark.parametrize("L,decay,state,heads", [
+    (128, "init", False, 2), (150, "init", True, 4), (150, "init", True, 1),
+    (192, "overflow", True, 2), (150, "repeated_token", True, 2)])
+def test_output_and_final_state_are_the_jnp_forms(L, decay, state, heads):
+    """Whole chunks and not (150 = 2 x 64 + 22), from zero and from a
+    given state; a decay under which ``exp(-G)`` overflows inside a
+    chunk; a run of one token; one head (a stack of one), two (one stack
+    of two) and four (two stacks a grid step)."""
+    args = _inputs(L, decay, state=state, H=heads)
+    o, S = _kernel()(*args)
+    want_o, want_S = kda.kda_chunked(*args)
+    assert o.shape == want_o.shape and o.dtype == jnp.float32
+    _close(o, want_o, 1e-5, "o")
+    _close(S, want_S, 1e-5, "state")
+
+
+@pytest.mark.parametrize("decay,tol,heads", [
+    ("init", 1e-5, 2), ("init", 1e-5, 4), ("init", 1e-5, 1),
+    ("overflow", 1e-5, 2), ("repeated_token", 1e-4, 2)])
+def test_gradients_of_all_inputs_and_the_state(decay, tol, heads):
+    """The hand-written backward against autodiff of the ``jax.numpy``
+    form: q, k, v, g, beta and the initial state, under a loss that
+    reads ``o`` and the final state."""
+    args = _inputs(150, decay, seed=1, H=heads)
+    got, want = _grads("kernel")(*args), _grads("jnp")(*args)
+    for name, a, b in zip(NAMES, got, want):
+        _close(a, b, tol, name)
+
+
+def test_bfloat16_operands_as_on_the_chip():
+    """The kernels as the chip runs them (bfloat16 inputs and operands)
+    against the exact products of the CPU: bfloat16's noise, no more."""
+    args = _inputs(150, "init", seed=2)
+    args = [a.astype(jnp.bfloat16) for a in args[:3]] + args[3:]
+    o, S = _kernel("bfloat16")(*args)
+    want_o, want_S = kda.kda_chunked(*args)
+    _close(o, want_o, 2e-2, "o")
+    _close(S, want_S, 2e-2, "state")
+    got, want = _grads("kernel", "bfloat16")(*args), _grads("jnp")(*args)
+    for name, a, b in zip(NAMES, got, want):
+        assert a.dtype == b.dtype, name
+        _close(a.astype(jnp.float32), b.astype(jnp.float32), 3e-2, name)
+
+
+def test_a_split_sequence_hands_its_state_over():
+    """Over the first part, then over the rest from the state it left,
+    is over the whole (chunked prefill), cut inside a chunk."""
+    args = _inputs(192, "init", seed=3)
+    o, S = _kernel()(*args)
+    cut = 83
+    o1, S1 = _kernel()(*(a[:, :cut] for a in args[:5]), args[5])
+    o2, S2 = _kernel()(*(a[:, cut:] for a in args[:5]), S1)
+    _close(jnp.concatenate([o1, o2], axis=1), o, 1e-5, "o")
+    _close(S2, S, 1e-5, "state")
+
+
+def test_positions_without_a_token_are_inert():
+    """``g = 0, beta = 0`` (``token_mask`` false): the state after a
+    padded sequence is the state after its last real token, and no
+    gradient reaches v or beta's value through a padded position."""
+    real, L = 45, 128
+    q, k, v, g, beta, S0 = _inputs(L, "init", seed=4)
+    mask = jnp.arange(L) < real
+    g_m = jnp.where(mask[None, :, None, None], g, 0.0)
+    b_m = jnp.where(mask[None, :, None], beta, 0.0)
+    o, S = _kernel()(q, k, v, g_m, b_m, S0)
+    o_short, S_short = _kernel()(*(a[:, :real] for a in (q, k, v, g, beta)),
+                                 S0)
+    _close(o[:, :real], o_short, 1e-5, "o")
+    _close(S, S_short, 1e-5, "state")
+    dv = _grads("kernel")(q, k, v, g_m, b_m, S0)[2]
+    assert float(jnp.max(jnp.abs(dv[:, real:]))) == 0.0
+
+
+@pytest.mark.parametrize("platform,dk,dv,want", [
+    ("tpu", 128, 128, "kernel"), ("tpu", 256, 128, "kernel"),
+    ("cpu", 128, 128, "jnp"), ("gpu", 128, 128, "jnp"),
+    ("tpu", 64, 64, "jnp"), ("tpu", 128, 96, "jnp"), ("tpu", 16, 16, "jnp")])
+def test_the_choice_follows_the_platform_and_the_head_sizes(
+        monkeypatch, platform, dk, dv, want):
+    import orion_tpu.ops.pallas as pallas
+
+    monkeypatch.setattr(pallas, "target_platform", lambda: platform)
+    assert kda.chunk_form(dk, dv) == want
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2, 2)],
+                         ids=["one_device", "fsdp2_tensor2"])
+def test_kda_chunked_takes_the_kernels_where_the_choice_says(monkeypatch,
+                                                             mesh_shape):
+    """``kda_chunked`` with the choice steered to the kernels
+    (interpreted here), alone and under a mesh of several devices, where
+    they run in a ``shard_map`` over rows and heads: same results, and
+    the kernels are in the program."""
+    import contextlib
+
+    from orion_tpu.config import MeshConfig
+    from orion_tpu.parallel.mesh import make_mesh
+
+    args = _inputs(128, "init", seed=5)
+    want, want_none = kda.kda_chunked(*args), kda.kda_chunked(*args[:5])
+    calls, kernel = [], kda_chunk.kda_chunk_kernel
+    monkeypatch.setattr(kda, "chunk_form", lambda dk, dv: "kernel")
+    monkeypatch.setattr(kda_chunk, "kda_chunk_kernel",
+                        lambda *a: calls.append(a[0].shape) or kernel(*a))
+    mesh = contextlib.nullcontext() if mesh_shape is None else make_mesh(
+        MeshConfig(data=1, fsdp=mesh_shape[0], tensor=mesh_shape[1]),
+        devices=jax.devices()[:4])
+    with mesh:
+        got = jax.jit(kda.kda_chunked)(*args)
+        got_none = jax.jit(lambda *a: kda.kda_chunked(*a))(*args[:5])
+    # each device's share: rows over fsdp, heads over tensor
+    per = (B, 128, H, D) if mesh_shape is None else (
+        B // mesh_shape[0], 128, H // mesh_shape[1], D)
+    assert calls == [per, per]
+    for g_, w_ in zip(got + got_none, want + want_none):
+        _close(g_, w_, 2e-2)

@@ -491,6 +491,9 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
     # every expert is held at the tiny size; 4 expert layers of 5
     assert row["moe_pairs_here"] == row["moe_pairs_total"] == 4 * 2 * 24 * 2
     assert row["moe_load_max"] >= row["moe_load_mean"] > 0
+    # the CPU, heads of 16: the chunked rule's jax.numpy form, and the
+    # row says so (the kernels need a TPU and heads of 128)
+    assert row["kda_chunk"] == "jnp"
     before = kept["before"]["backbone"]
     after = kept["trainer"].state.params["backbone"]
     for name in ("A_log", "dt_bias", "q_conv"):

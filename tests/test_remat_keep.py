@@ -222,7 +222,7 @@ def test_a_device_that_reports_nothing_keeps_nothing():
     hist, spans = _update_spans(trainer)
     assert trainer._remat_keep == () and trainer.update_traces == [()]
     want = {"remat_kept": "", "remat_kept_bytes": 0,
-            "remat_budget_bytes": 0}
+            "remat_budget_bytes": 0, "kda_chunk": ""}    # no KDA layer
     assert len(spans) == 2
     for attrs, row in zip(spans, hist):
         assert {k: attrs[k] for k in want} == want
