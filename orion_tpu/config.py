@@ -17,9 +17,16 @@ from typing import Any, Optional
 # ---------------------------------------------------------------------------
 
 
-#: The archs whose model is a per-layer pattern of (mixer, FFN) kinds in
-#: one pre-norm RMSNorm block (``ModelConfig.layer_kinds``).
-PATTERN_ARCHS = ("deepseek_v3", "kimi_linear")
+#: The archs of the pre-norm RMSNorm block whose attention is latent
+#: (``models.transformer.LatentBlock``).
+LATENT_ARCHS = ("deepseek_v3", "kimi_linear")
+#: The archs whose model is a per-layer pattern of (mixer, FFN) kinds
+#: over RMSNorm blocks (``ModelConfig.layer_kinds``).
+PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid",)
+#: The mixers whose per-sequence state is not indexed by position.
+RECURRENT_MIXERS = ("kda", "gdn")
+#: olmo_hybrid's published ``layer_types`` entries -> mixers.
+LAYER_TYPE_MIXERS = {"linear_attention": "gdn", "full_attention": "attention"}
 
 
 @dataclass
@@ -32,7 +39,8 @@ class ModelConfig:
     attention+MLP residual, partial rotary — Pythia-1B).
     """
 
-    arch: str = "llama"  # "llama" | "neox" | "deepseek_v3" | "kimi_linear"
+    # "llama" | "neox" | "deepseek_v3" | "kimi_linear" | "olmo_hybrid"
+    arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 512
     intermediate_size: int = 1376
@@ -108,12 +116,30 @@ class ModelConfig:
     kda_head_dim: int = 0
     short_conv_kernel_size: int = 0
     mla_use_nope: bool = False
+    # arch="olmo_hybrid": a post-norm RMSNorm block over a dense SwiGLU,
+    # its mixer per layer by the published layer_types, whole:
+    # "linear_attention" (the gated delta rule, models/transformer.py
+    # GatedDeltaNet: one decay a head, heads of linear_key_head_dim x
+    # linear_value_head_dim) or "full_attention" (num_heads heads of
+    # head_dim with a per-head cache, a norm over the whole query and
+    # key projections, rotated only where rope_theta > 0: the published
+    # value is null); the model reads the entries up to num_layers.
+    # linear_allow_neg_eigval: the step size runs to 2, not 1.
+    layer_types: tuple = ()
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    linear_allow_neg_eigval: bool = False
 
     def __post_init__(self) -> None:
-        if self.arch in PATTERN_ARCHS:
+        if self.arch in LATENT_ARCHS:
             self._check_deepseek_v3()
         if self.arch == "kimi_linear":
             self._check_kimi_linear()
+        if self.arch == "olmo_hybrid":
+            self._check_olmo_hybrid()
         if self.head_dim == 0:
             self.head_dim = self.hidden_size // self.num_heads
         if self.arch == "neox":
@@ -162,21 +188,70 @@ class ModelConfig:
             raise ValueError("model.kda_layers counts layers from 1 (the "
                              "published linear_attn_config.kda_layers)")
 
+    def _check_olmo_hybrid(self) -> None:
+        for key in ("linear_num_key_heads", "linear_key_head_dim",
+                    "linear_value_head_dim", "linear_conv_kernel_dim"):
+            if getattr(self, key) <= 0:
+                raise ValueError(f"arch='olmo_hybrid' needs model.{key} > 0")
+        if self.linear_num_value_heads != self.linear_num_key_heads:
+            raise ValueError(
+                "arch='olmo_hybrid' with linear_num_value_heads != "
+                "linear_num_key_heads: there is no delta rule whose value "
+                "heads share a key head (ops/kda.py takes one q, k a head)")
+        self.layer_types = tuple(self.layer_types)
+        unknown = set(self.layer_types) - set(LAYER_TYPE_MIXERS)
+        if unknown or len(self.layer_types) < self.num_layers:
+            raise ValueError(
+                f"model.layer_types names {sorted(LAYER_TYPE_MIXERS)}, one "
+                f"entry a layer, at least num_layers={self.num_layers} of "
+                f"them (got {len(self.layer_types)}, unknown: "
+                f"{sorted(unknown)})")
+        self.num_kv_heads = self.num_heads
+        if self.attention_impl in ("ring", "ulysses"):
+            raise ValueError(
+                f"attention_impl={self.attention_impl!r} cannot run "
+                "arch='olmo_hybrid': there is no hand-over of a recurrent "
+                "state between sequence shards")
+        if (self.num_experts or self.quantize_dense
+                or self.tie_word_embeddings or self.seq_shard_activations):
+            raise ValueError(
+                "arch='olmo_hybrid' is dense (num_experts is the GShard "
+                "layer's), has no int8 Dense twin, an untied head, and "
+                "its recurrent layers take whole sequences "
+                "(seq_shard_activations)")
+
     @property
     def latent_attention(self) -> bool:
-        """The pre-norm RMSNorm block whose layers are a pattern of
-        (mixer, FFN) kinds: see :meth:`layer_kinds`."""
+        """The pre-norm RMSNorm block whose attention is latent
+        (deepseek_v3, kimi_linear)."""
+        return self.arch in LATENT_ARCHS
+
+    @property
+    def pattern(self) -> bool:
+        """Whether the model is a per-layer pattern of (mixer, FFN)
+        kinds over RMSNorm blocks: see :meth:`layer_kinds`."""
         return self.arch in PATTERN_ARCHS
+
+    def delta_head_dims(self) -> tuple:
+        """(dk, dv) of the recurrent layers' heads: what
+        ``ops.kda.chunk_form`` is asked with."""
+        if self.arch == "olmo_hybrid":
+            return self.linear_key_head_dim, self.linear_value_head_dim
+        return self.kda_head_dim, self.kda_head_dim
 
     def layer_kinds(self) -> tuple:
         """((mixer, ffn), ...) per layer: the model's description.
         mixer: "attention" (per-head K/V cache), "latent" ({c, k_rope}
-        cache) or "kda" (a recurrent state, no position); ffn: "dense",
-        "gshard" (num_experts) or "experts" (the dropless layer)."""
-        if not self.latent_attention:
+        cache), "kda" or "gdn" (a recurrent state, no position; a decay
+        a key channel or one a head); ffn: "dense", "gshard"
+        (num_experts) or "experts" (the dropless layer)."""
+        if not self.pattern:
             return (("attention",
                      "gshard" if self.num_experts else "dense"),
                     ) * self.num_layers
+        if self.arch == "olmo_hybrid":
+            return tuple((LAYER_TYPE_MIXERS[t], "dense")
+                         for t in self.layer_types[:self.num_layers])
         return tuple(
             ("kda" if i + 1 in self.kda_layers else "latent",
              "dense" if i < self.first_k_dense_replace else "experts")
@@ -185,8 +260,8 @@ class ModelConfig:
     def layer_runs(self) -> tuple:
         """((first, length, mixer, ffn), ...): the stretches of equal
         consecutive kinds, each of which ``scan_layers`` scans as one
-        stack; the leading dense layers of a pattern model stand alone
-        (length 1, never stacked)."""
+        stack; the leading dense layers of a latent-attention model
+        stand alone (length 1, never stacked)."""
         out = []
         for i, kind in enumerate(self.layer_kinds()):
             alone = self.latent_attention and kind[1] == "dense"
@@ -203,14 +278,14 @@ class ModelConfig:
         (the dropless expert layer routes it nowhere, the recurrent
         mixer leaves its state untouched): callers then pass
         ``token_mask``."""
-        return any(m == "kda" or f == "experts"
+        return any(m in RECURRENT_MIXERS or f == "experts"
                    for m, f in self.layer_kinds())
 
     @property
     def recurrent(self) -> bool:
         """Whether some layer's per-sequence state is not indexed by
         position."""
-        return any(m == "kda" for m, _ in self.layer_kinds())
+        return any(m in RECURRENT_MIXERS for m, _ in self.layer_kinds())
 
     @staticmethod
     def llama3_8b() -> "ModelConfig":
@@ -272,6 +347,28 @@ class ModelConfig:
         )
 
     @staticmethod
+    def olmo_hybrid_7b() -> "ModelConfig":
+        """allenai/Olmo-Hybrid-7B as published (config.json, model_type
+        olmo_hybrid); ``rope_parameters.rope_theta`` is null there:
+        nothing is rotated."""
+        return ModelConfig(
+            arch="olmo_hybrid", vocab_size=100352, hidden_size=3840,
+            intermediate_size=11008, num_layers=32, num_heads=30,
+            max_seq_len=65536, rope_theta=0.0, rms_norm_eps=1e-6,
+            layer_types=(("linear_attention",) * 3
+                         + ("full_attention",)) * 8,
+            linear_num_key_heads=30, linear_num_value_heads=30,
+            linear_key_head_dim=96, linear_value_head_dim=192,
+            linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+        )
+
+    @staticmethod
+    def tiny_olmo_hybrid() -> "ModelConfig":
+        """``model_preset=tiny_olmo_hybrid``: the small sibling of
+        olmo_hybrid_7b (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("olmo_hybrid")
+
+    @staticmethod
     def tiny_kimi_linear() -> "ModelConfig":
         """``model_preset=tiny_kimi_linear``: the small sibling of
         kimi_linear_48b_a3b (tests, CPU rehearsals)."""
@@ -286,6 +383,21 @@ class ModelConfig:
     @staticmethod
     def tiny(arch: str = "llama", **kw: Any) -> "ModelConfig":
         """Small config for tests (runs on CPU in <1s)."""
+        if arch == "olmo_hybrid":
+            # one whole period of two published; heads of (12, 24):
+            # neither side a tile
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=96, num_layers=4, num_heads=4,
+                max_seq_len=128, rope_theta=0.0, rms_norm_eps=1e-6,
+                layer_types=(("linear_attention",) * 3
+                             + ("full_attention",)) * 2,
+                linear_num_key_heads=3, linear_num_value_heads=3,
+                linear_key_head_dim=12, linear_value_head_dim=24,
+                linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+            )
+            base.update(kw)
+            return ModelConfig(**base)
         if arch == "kimi_linear":
             # one dense layer, then one whole period of the 3 : 1 pattern
             base = dict(

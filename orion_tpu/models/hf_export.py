@@ -47,6 +47,12 @@ def hf_state_dict(params: dict, cfg: ModelConfig) -> Dict[str, np.ndarray]:
             "kimi_linear checkpoint layout on either side yet (the KDA "
             "layers' convolutions [channels, 1, taps], low-rank gates, "
             "A_log and dt_bias; per-expert tensors; kv_a/kv_b projections)")
+    if cfg.arch == "olmo_hybrid":
+        raise ValueError(
+            "HF export of arch='olmo_hybrid' is not written: there is no "
+            "olmo_hybrid checkpoint layout on either side yet (the gated-"
+            "delta-rule layers' convolutions [channels, 1, taps], a / b / "
+            "z projections, A_log and dt_bias)")
     params = dict(params)
     if "backbone" in params:  # ActorCriticModel / ScalarHeadModel tree
         params = dict(params["backbone"])

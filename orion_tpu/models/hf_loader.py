@@ -58,6 +58,12 @@ _NO_KIMI_LOADER = (
     "low-rank gates, A_log and dt_bias have no mapping onto "
     "models.transformer.KimiDeltaAttention's names and [taps, channels] "
     "layout; arch='kimi_linear' runs from random weights only")
+_NO_OLMO_HYBRID_LOADER = (
+    "there is no olmo_hybrid checkpoint loader yet: the gated-delta-rule "
+    "layers' convolutions, a / b / z projections, A_log and dt_bias have "
+    "no mapping onto models.transformer.GatedDeltaNet's names and "
+    "[taps, channels] layout; arch='olmo_hybrid' runs from random "
+    "weights only")
 
 
 def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
@@ -70,6 +76,8 @@ def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
         raise ValueError(_NO_DSV3_LOADER)
     elif cfg.arch == "kimi_linear":
         raise ValueError(_NO_KIMI_LOADER)
+    elif cfg.arch == "olmo_hybrid":
+        raise ValueError(_NO_OLMO_HYBRID_LOADER)
     else:
         raise ValueError(cfg.arch)
     if not include_lm_head:
@@ -226,6 +234,8 @@ def config_from_hf(hf_cfg: Any) -> ModelConfig:
         raise ValueError(_NO_DSV3_LOADER)
     if mt == "kimi_linear":
         raise ValueError(_NO_KIMI_LOADER)
+    if mt == "olmo_hybrid":
+        raise ValueError(_NO_OLMO_HYBRID_LOADER)
     if mt == "llama":
         return ModelConfig(
             arch="llama",

@@ -18,6 +18,12 @@ requires (SURVEY.md §2 #14):
   recurrent state, no position) or :class:`LatentAttention` without
   rotation.  Under ``scan_layers`` each stretch of equal consecutive
   kinds is one scanned stack (``ModelConfig.layer_runs``).
+- ``arch="olmo_hybrid"``: a post-norm RMSNorm block
+  (:class:`PostNormBlock`) over a dense SwiGLU, its mixer per layer by
+  the published ``layer_types``: :class:`GatedDeltaNet` (the delta rule
+  with one decay a head, heads of unequal key and value size) or
+  :class:`Attention` with a norm over the whole query and key
+  projections and no rotation.  Every stretch is a scanned stack.
 
 Design notes (TPU-first):
 - Params are annotated with *logical* axes via flax logical
@@ -62,6 +68,9 @@ from orion_tpu.ops.rotary import apply_rotary
 # [B,taps-1,3*H*dk]}, nothing indexed by position; scan_layers models
 # {"dense": [per layer], "runs": [stacked, one per ModelConfig.
 # layer_runs stretch]}.
+# olmo_hybrid: a GDN layer caches {"S": f32 [B,H,dk,dv], "conv":
+# [B,taps-1,H*(2*dk+dv)]}, a full-attention layer {"k","v"} as llama's;
+# scan_layers models {"dense": [], "runs": [stacked, ...]}.
 KVCache = Any
 
 _dt = lambda s: jnp.dtype(s)  # noqa: E731
@@ -90,8 +99,9 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
     padded to where it is held (128 on a TPU; 1 counts the elements).
     The attention tags are counted as the flash kernel leaves them: an
     implementation without tags keeps nothing under those names and is
-    over-reckoned.  A KDA layer tags its projections (``attn_qkv``) and
-    its recurrence's output (``attn_out``) under the same names."""
+    over-reckoned.  A KDA or GDN layer tags its projections
+    (``attn_qkv``) and its recurrence's output (``attn_out``) under the
+    same names."""
     def w(d):
         return -(-d // lane) * lane
 
@@ -106,6 +116,11 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
             wide = cfg.kda_num_heads * cfg.kda_head_dim
             qkv += n * 3 * w(wide) * act
             out += n * w(wide) * 4
+        elif mixer == "gdn":
+            Hl = cfg.linear_num_key_heads
+            qkv += n * w(Hl * (2 * cfg.linear_key_head_dim
+                               + cfg.linear_value_head_dim)) * act
+            out += n * w(Hl * cfg.linear_value_head_dim) * 4
         else:
             if mixer == "latent":
                 per_tok = H * (2 * w(cfg.qk_nope_head_dim
@@ -214,7 +229,7 @@ def _dense(features, axes, use_bias, cfg, name):
 
 
 def _norm(cfg, name):
-    if cfg.arch == "llama" or cfg.latent_attention:
+    if cfg.arch == "llama" or cfg.pattern:
         return nn.RMSNorm(
             epsilon=cfg.rms_norm_eps, dtype=_dt(cfg.dtype),
             param_dtype=_dt(cfg.param_dtype),
@@ -260,7 +275,13 @@ def _cache_writer(positions, B: int, L: int):
 
 
 class Attention(nn.Module):
+    """``qk_norm``: one norm over the whole query and key projections,
+    before the split into heads; ``rotary`` false: nothing is rotated
+    (both olmo_hybrid's, whose recurrent layers carry position)."""
+
     cfg: ModelConfig
+    qk_norm: bool = False
+    rotary: bool = True
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None):
@@ -280,12 +301,17 @@ class Attention(nn.Module):
         q = _dense(H * D, ("embed", "heads"), cfg.attn_bias, cfg, "q_proj")(x)
         k = _dense(Hkv * D, ("embed", "kv_heads"), cfg.attn_bias, cfg, "k_proj")(x)
         v = _dense(Hkv * D, ("embed", "kv_heads"), cfg.attn_bias, cfg, "v_proj")(x)
+        if self.qk_norm:
+            with jax.named_scope("attn.qk_norm"):
+                q = _norm(cfg, "q_norm")(q)
+                k = _norm(cfg, "k_norm")(k)
         q = q.reshape(B, L, H, D)
         k = k.reshape(B, L, Hkv, D)
         v = v.reshape(B, L, Hkv, D)
 
-        rotary_dim = int(D * cfg.rotary_pct)
-        q, k = apply_rotary(q, k, positions, rotary_dim, cfg.rope_theta)
+        if self.rotary:
+            rotary_dim = int(D * cfg.rotary_pct)
+            q, k = apply_rotary(q, k, positions, rotary_dim, cfg.rope_theta)
 
         scale = 1.0 / D ** 0.5
         paged_decode_out = None
@@ -490,6 +516,70 @@ class LatentAttention(nn.Module):
                       "o_proj")(out), new_cache
 
 
+def _l2norm(t):
+    return t * jax.lax.rsqrt(
+        jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+
+
+def _short_conv(ext, w_conv, L: int):
+    """Depthwise causal convolution: ext [B, taps - 1 + L, C] (the last
+    inputs before the block, then the block), w_conv [taps, C] float32,
+    the current token's tap last -> [B, L, C] float32."""
+    return sum(ext[:, j:j + L].astype(jnp.float32) * w_conv[j]
+               for j in range(w_conv.shape[0]))
+
+
+def _conv_handover(ext, token_mask, taps: int):
+    """The convolutions' inputs of each row's last ``taps - 1`` real
+    tokens, for the next call: position p is row p + taps - 1 of
+    ``ext`` [B, taps - 1 + L, C]."""
+    B, L = ext.shape[0], ext.shape[1] - (taps - 1)
+    n_real = (jnp.full((B,), L, jnp.int32) if token_mask is None
+              else jnp.sum(token_mask, axis=1, dtype=jnp.int32))
+    rows = n_real[:, None] + jnp.arange(taps - 1)[None, :]
+    return jnp.take_along_axis(ext, rows[:, :, None], axis=1)
+
+
+def _delta_rule(scope, q, k, v, g, beta, layer_cache, ext, token_mask):
+    """The delta rule in the form the call needs (``ops/kda.py``): one
+    new token against a cache takes the step, everything else the
+    chunked form.  ``ext``: the convolutions' inputs, ``taps - 1`` rows
+    of the past first.  Returns (o [B, L, H, dv] float32, the layer's
+    new cache or None)."""
+    from orion_tpu.ops.kda import kda_chunked, kda_step
+
+    L = q.shape[1]
+    if layer_cache is not None and L == 1:
+        with jax.named_scope(scope + ".step"):
+            o, S = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                            layer_cache["S"])
+        return o[:, None], {"S": S, "conv": ext[:, 1:]}
+    with jax.named_scope(scope + ".chunk"):
+        o, S = kda_chunked(q, k, v, g, beta,
+                           None if layer_cache is None else layer_cache["S"])
+    if layer_cache is None:
+        return o, None
+    return o, {"S": S, "conv": _conv_handover(ext, token_mask,
+                                              ext.shape[1] - L + 1)}
+
+
+def _delta_conv_init(*a):
+    """U(-0.5, 0.5): torch's Conv1d default for a depthwise kernel of 4
+    taps."""
+    return nn.initializers.uniform(scale=1.0)(*a) - 0.5
+
+
+def _delta_A_log_init(key, shape, dtype):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _delta_dt_bias_init(key, shape, dtype):
+    """softplus(dt_bias) log-uniform in [1e-3, 1e-1]."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, dtype, math.log(1e-3), math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 class KimiDeltaAttention(nn.Module):
     """Kimi Delta Attention: the delta rule with a per-channel decay
     (``ops/kda.py``), ``kda_num_heads`` heads of ``kda_head_dim``.
@@ -519,8 +609,6 @@ class KimiDeltaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None, token_mask=None):
-        from orion_tpu.ops.kda import kda_chunked, kda_step
-
         cfg = self.cfg
         B, L, _ = x.shape
         H, d, taps = (cfg.kda_num_heads, cfg.kda_head_dim,
@@ -539,12 +627,9 @@ class KimiDeltaAttention(nn.Module):
         proj = checkpoint_name(jnp.concatenate(
             [_dense(wide, ("embed", "heads"), False, cfg, n + "_proj")(x)
              for n in "qkv"], axis=-1), "attn_qkv")
-        # torch's Conv1d default for a depthwise kernel of 4 taps
-        conv_init = nn.initializers.uniform(scale=1.0)
         w_conv = jnp.concatenate(
-            [param(n + "_conv", lambda *a: conv_init(*a) - 0.5,
-                   (taps, wide), ("conv", "heads")) for n in "qkv"],
-            axis=-1).astype(f32)
+            [param(n + "_conv", _delta_conv_init, (taps, wide),
+                   ("conv", "heads")) for n in "qkv"], axis=-1).astype(f32)
 
         # The elementwise stretches between the matrix products and the
         # recurrence are checkpointed each on its own: a backward keeps
@@ -552,19 +637,12 @@ class KimiDeltaAttention(nn.Module):
         # [B, L, 3 H d] of it in the first alone).
         @jax.checkpoint
         def convolve(ext, w_conv):
-            y = sum(ext[:, j:j + L].astype(f32) * w_conv[j]
-                    for j in range(taps))
-            q, k, v = (t.reshape(B, L, H, d)
-                       for t in jnp.split(nn.silu(y), 3, axis=-1))
-
-            def l2norm(t):
-                return t * jax.lax.rsqrt(
-                    jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
-
+            q, k, v = (t.reshape(B, L, H, d) for t in jnp.split(
+                nn.silu(_short_conv(ext, w_conv, L)), 3, axis=-1))
             # the recurrence takes them in the compute dtype (they are
             # operands of matrix products), in both of its forms
             return tuple(t.astype(_dt(cfg.dtype)) for t in
-                         (l2norm(q) * d ** -0.5, l2norm(k), v))
+                         (_l2norm(q) * d ** -0.5, _l2norm(k), v))
 
         with jax.named_scope("kda.conv"):
             prev = (layer_cache["conv"] if layer_cache is not None
@@ -573,17 +651,9 @@ class KimiDeltaAttention(nn.Module):
             q, k, v = convolve(ext, w_conv)
 
         with jax.named_scope("kda.gate"):
-            A_log = param("A_log", lambda key, shape, dtype: jnp.log(
-                jax.random.uniform(key, shape, dtype, 1.0, 16.0)),
-                (H,), ("norm",), f32)
-
-            def dt_init(key, shape, dtype):
-                # softplus(dt_bias) log-uniform in [1e-3, 1e-1]
-                dt = jnp.exp(jax.random.uniform(
-                    key, shape, dtype, math.log(1e-3), math.log(1e-1)))
-                return dt + jnp.log(-jnp.expm1(-dt))
-
-            dt_bias = param("dt_bias", dt_init, (wide,), ("heads",), f32)
+            A_log = param("A_log", _delta_A_log_init, (H,), ("norm",), f32)
+            dt_bias = param("dt_bias", _delta_dt_bias_init, (wide,),
+                            ("heads",), f32)
             f = _dense(wide, ("latent", "heads"), False, cfg, "f_b_proj")(
                 _dense(d, ("embed", "latent"), False, cfg, "f_a_proj")(x))
             b = _dense(H, ("embed", "norm"), False, cfg, "b_proj")(x)
@@ -600,26 +670,8 @@ class KimiDeltaAttention(nn.Module):
 
             g, beta = decay_and_step(f, b, A_log, dt_bias)
 
-        new_cache = None
-        if layer_cache is not None and L == 1:
-            with jax.named_scope("kda.step"):
-                o, S = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                beta[:, 0], layer_cache["S"])
-                o = o[:, None]
-            new_cache = {"S": S, "conv": ext[:, 1:]}
-        else:
-            with jax.named_scope("kda.chunk"):
-                o, S = kda_chunked(
-                    q, k, v, g, beta,
-                    None if layer_cache is None else layer_cache["S"])
-            if layer_cache is not None:
-                # the inputs of each row's last taps - 1 real tokens:
-                # position p is row p + taps - 1 of ``ext``
-                n_real = (jnp.full((B,), L, jnp.int32) if token_mask is None
-                          else jnp.sum(token_mask, axis=1, dtype=jnp.int32))
-                rows = n_real[:, None] + jnp.arange(taps - 1)[None, :]
-                new_cache = {"S": S, "conv": jnp.take_along_axis(
-                    ext, rows[:, :, None], axis=1)}
+        o, new_cache = _delta_rule("kda", q, k, v, g, beta, layer_cache,
+                                   ext, token_mask)
         o = checkpoint_name(o, "attn_out")
 
         gate = _dense(wide, ("latent", "heads"), False, cfg, "g_b_proj")(
@@ -639,13 +691,119 @@ class KimiDeltaAttention(nn.Module):
                       "o_proj")(norm_and_gate(o, gate, o_norm)), new_cache
 
 
+class GatedDeltaNet(nn.Module):
+    """Gated DeltaNet under Olmo-Hybrid's ``linear_*`` keys: the delta
+    rule of ``ops/kda.py`` with ONE decay a head, ``linear_num_key_heads``
+    heads whose state is ``linear_key_head_dim`` x
+    ``linear_value_head_dim`` (96 x 192: neither side a lane tile).
+
+    ``q~, k~, v~ = x W_q, x W_k, x W_v`` (H dk, H dk, H dv wide), each
+    through its own depthwise causal convolution of
+    ``linear_conv_kernel_dim`` taps and SiLU; per head ``q = l2norm(q~)
+    / sqrt(dk)``, ``k = l2norm(k~)``, ``v = v~``; log decay ``g =
+    -exp(A_log) * softplus(x W_a + dt_bias)`` and step size ``beta =
+    2 sigmoid(x W_b)`` (``linear_allow_neg_eigval``; else ``sigmoid``),
+    one number a head each, float32; output ``W_o concat_h(RMSNorm_dv(o)
+    * silu(x W_z))``.  No position enters it.
+
+    Cache, ``token_mask`` and the two forms of the rule as
+    :class:`KimiDeltaAttention`: ``{"S": [B, H, dk, dv] float32, "conv":
+    [B, taps - 1, H (2 dk + dv)]}``.
+    """
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None, token_mask=None):
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, dk, dv, taps = (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
+                           cfg.linear_value_head_dim,
+                           cfg.linear_conv_kernel_dim)
+        # (value heads that share a key head are refused by the config)
+        widths = {"q": H * dk, "k": H * dk,
+                  "v": cfg.linear_num_value_heads * dv}
+        f32, pdt, cdt = jnp.float32, _dt(cfg.param_dtype), _dt(cfg.dtype)
+        if layer_cache is not None and "S" not in layer_cache:
+            raise ValueError(
+                "a GDN layer caches {'S', 'conv'} (init_cache): a state, "
+                "not keys and values by position")
+
+        def param(name, init, shape, axes, dtype=pdt):
+            return self.param(
+                name, nn.with_logical_partitioning(init, axes), shape, dtype)
+
+        proj = checkpoint_name(jnp.concatenate(
+            [_dense(w, ("embed", "heads"), False, cfg, n + "_proj")(x)
+             for n, w in widths.items()], axis=-1), "attn_qkv")
+        w_conv = jnp.concatenate(
+            [param(n + "_conv", _delta_conv_init, (taps, w),
+                   ("conv", "heads")) for n, w in widths.items()],
+            axis=-1).astype(f32)
+
+        # checkpointed stretch by stretch, as KimiDeltaAttention's
+        @jax.checkpoint
+        def convolve(ext, w_conv):
+            y = nn.silu(_short_conv(ext, w_conv, L))
+            q, k, v = (t.reshape(B, L, H, -1) for t in jnp.split(
+                y, (H * dk, 2 * H * dk), axis=-1))
+            return tuple(t.astype(cdt) for t in
+                         (_l2norm(q) * dk ** -0.5, _l2norm(k), v))
+
+        with jax.named_scope("gdn.conv"):
+            prev = (layer_cache["conv"] if layer_cache is not None
+                    else jnp.zeros((B, taps - 1, proj.shape[-1]),
+                                   proj.dtype))
+            ext = jnp.concatenate([prev.astype(proj.dtype), proj], axis=1)
+            q, k, v = convolve(ext, w_conv)
+
+        with jax.named_scope("gdn.gate"):
+            A_log = param("A_log", _delta_A_log_init, (H,), ("norm",), f32)
+            dt_bias = param("dt_bias", _delta_dt_bias_init, (H,),
+                            ("norm",), f32)
+            a = _dense(H, ("embed", "norm"), False, cfg, "a_proj")(x)
+            b = _dense(H, ("embed", "norm"), False, cfg, "b_proj")(x)
+            top = 2.0 if cfg.linear_allow_neg_eigval else 1.0
+
+            @jax.checkpoint
+            def decay_and_step(a, b, A_log, dt_bias):
+                g = -jnp.exp(A_log) * jax.nn.softplus(
+                    a.astype(f32) + dt_bias)
+                beta = top * jax.nn.sigmoid(b.astype(f32))
+                if token_mask is not None:
+                    g = jnp.where(token_mask[:, :, None], g, 0.0)
+                    beta = jnp.where(token_mask[:, :, None], beta, 0.0)
+                return g[..., None], beta       # one decay a head
+
+            g, beta = decay_and_step(a, b, A_log, dt_bias)
+
+        o, new_cache = _delta_rule("gdn", q, k, v, g, beta, layer_cache,
+                                   ext, token_mask)
+        o = checkpoint_name(o, "attn_out")
+
+        z = _dense(H * dv, ("embed", "heads"), False, cfg, "z_proj")(x)
+        o_norm = param("o_norm", nn.initializers.ones_init(), (dv,),
+                       ("norm",))
+
+        @jax.checkpoint
+        def norm_and_gate(o, z, o_norm):
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                           keepdims=True) + cfg.rms_norm_eps)
+            o = o * o_norm.astype(f32) * nn.silu(
+                z.astype(f32)).reshape(B, L, H, dv)
+            return o.astype(cdt).reshape(B, L, H * dv)
+
+        return _dense(cfg.hidden_size, ("heads", "embed"), False, cfg,
+                      "o_proj")(norm_and_gate(o, z, o_norm)), new_cache
+
+
 class MLP(nn.Module):
     cfg: ModelConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        if cfg.arch == "llama" or cfg.latent_attention:
+        if cfg.arch == "llama" or cfg.pattern:
             gate = checkpoint_name(
                 _dense(cfg.intermediate_size, ("embed", "mlp"),
                        cfg.mlp_bias, cfg, "gate_proj")(x), "mlp_pre")
@@ -721,6 +879,31 @@ class LatentBlock(nn.Module):
             return h + MLP(cfg, name="mlp")(z), new_cache
         from orion_tpu.ops.moe import SigmoidTopKMoE
         return h + SigmoidTopKMoE(cfg, name="mlp")(z, token_mask), new_cache
+
+
+class PostNormBlock(nn.Module):
+    """olmo_hybrid block (the OLMo 2 / 3 order): ``h = x +
+    N_a(Mixer(x))``, ``y = h + N_f(MLP(h))``; no norm before a sublayer.
+    The mixer the gated delta rule (``mixer="gdn"``) or full attention."""
+
+    cfg: ModelConfig
+    mixer: str = "attention"
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None, token_mask=None):
+        cfg = self.cfg
+        if self.mixer == "gdn":
+            attn_out, new_cache = GatedDeltaNet(cfg, name="attn")(
+                x, positions, layer_cache, token_mask)
+        else:
+            # rope_theta is published null (0 here): no rotation
+            attn_out, new_cache = Attention(
+                cfg, qk_norm=True, rotary=cfg.rope_theta > 0, name="attn")(
+                    x, positions, layer_cache)
+        h = checkpoint_name(x + _norm(cfg, "post_attn_norm")(attn_out),
+                            "attn_resid")
+        return h + _norm(cfg, "post_mlp_norm")(
+            MLP(cfg, name="mlp")(h)), new_cache
 
 
 def _stack_names(cfg: ModelConfig) -> dict:
@@ -808,8 +991,11 @@ class Transformer(nn.Module):
         def block(mixer, ffn):
             """(block class for a layer of this kind, its keywords,
             whether it takes ``token_mask``)."""
-            if mixer == "attention":
+            if not cfg.pattern:
                 cls, kw, masked = Block, {}, False
+            elif cfg.arch == "olmo_hybrid":
+                cls, kw, masked = PostNormBlock, {"mixer": mixer}, \
+                    mixer == "gdn"
             else:
                 kw = {"dense": True} if ffn == "dense" else {}
                 if mixer != "latent":
@@ -907,7 +1093,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     # verify chunk).  Slots carry the slot==position causal rule, so
     # the padded tail is masked for every real query.
     max_len = -(-max_len // 8) * 8
-    if cfg.latent_attention and quantized:
+    if cfg.pattern and quantized:
         raise ValueError(
             "there is no int8 latent cache (rollout.quantize_kv) for "
             f"arch={cfg.arch!r} yet: ops/quant.py scales per head, and a "
@@ -921,6 +1107,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                     "conv": jnp.zeros(
                         pre + (batch, cfg.short_conv_kernel_size - 1,
                                3 * H * d), dtype)}
+        if mixer == "gdn":
+            H, dk, dv = (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
+                         cfg.linear_value_head_dim)
+            return {"S": jnp.zeros(pre + (batch, H, dk, dv), jnp.float32),
+                    "conv": jnp.zeros(
+                        pre + (batch, cfg.linear_conv_kernel_dim - 1,
+                               H * (2 * dk + dv)), dtype)}
         if mixer == "latent":
             return {"c": jnp.zeros(pre + (batch, max_len, cfg.kv_lora_rank),
                                    dtype),

@@ -1,9 +1,10 @@
-"""The delta rule with a per-channel decay (Kimi Delta Attention), in the
-two forms a model needs of it.
+"""The delta rule with a per-channel decay (Kimi Delta Attention) or one
+decay a head (Gated DeltaNet: ``g`` of width 1, the same over a head's
+key channels), in the two forms a model needs of it.
 
 Per head, with a state ``S`` [dk, dv] in float32, zero before the first
-token, a log decay ``g_t`` [dk] (<= 0), a step size ``beta_t`` and
-``q_t, k_t`` [dk], ``v_t`` [dv]::
+token, a log decay ``g_t`` [dk] or [1] (<= 0), a step size ``beta_t``
+and ``q_t, k_t`` [dk], ``v_t`` [dv]::
 
     S' = Diag(exp(g_t)) S_{t-1}
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
@@ -13,14 +14,19 @@ token, a log decay ``g_t`` [dk] (<= 0), a step size ``beta_t`` and
 - :func:`kda_chunked`: a whole sequence (training, the experience
   forwards, prefill), chunk by chunk, differentiable.  One algorithm in
   two forms, chosen at trace time by :func:`chunk_form`: where the
-  trace is for a TPU and the head sizes are multiples of 128, the two
-  Pallas kernels of ``ops/pallas/kda_chunk.py`` (a chunk's insides stay
-  in VMEM, the inputs are read as ``[B, L, H d]`` without a transpose,
-  the backward is written by hand behind ``jax.custom_vjp`` and keeps
-  the inputs and the float32 states at the chunk boundaries, ``[B, H,
-  n, dv, dk]``, recomputing the insides); everywhere else (the CPU, odd
-  head sizes) the ``jax.numpy`` form below, differentiated by autodiff,
-  which is also what the kernels are tested against.
+  trace is for a TPU, the two Pallas kernels of
+  ``ops/pallas/kda_chunk.py`` (a chunk's insides stay in VMEM, the
+  inputs are read as ``[B, L, H d]`` without a transpose, the backward
+  is written by hand behind ``jax.custom_vjp`` and keeps the inputs and
+  the float32 states at the chunk boundaries, ``[B, H, n, dv, dk]``,
+  recomputing the insides); everywhere else (the CPU) the ``jax.numpy``
+  form below, differentiated by autodiff, which is also what the
+  kernels are tested against.  The kernels take heads whose sizes are
+  whole lane tiles of 128 and a decay a channel: other heads
+  (Olmo-Hybrid's 96 x 192) are padded with zero key channels and zero
+  value columns around the call, which leave every product and the real
+  part of the state as they were, and one decay a head is broadcast
+  (:func:`_to_lane_tiles`); the padding is the kernel's time, not work.
 
 A position with ``g = 0`` and ``beta = 0`` leaves the state as it was:
 that is how a caller makes padding inert, and how the chunked form pads
@@ -72,8 +78,9 @@ CHUNK = 64
 
 
 def kda_step(q, k, v, g, beta, state):
-    """One token.  q, k, g [B, H, dk]; v [B, H, dv]; beta [B, H]; state
-    [B, H, dk, dv] float32 -> (o [B, H, dv] float32, new state).
+    """One token.  q, k [B, H, dk]; g [B, H, dk] or [B, H, 1]; v
+    [B, H, dv]; beta [B, H]; state [B, H, dk, dv] float32 -> (o
+    [B, H, dv] float32, new state).
 
     Elementwise products and sums in float32: a step reads and writes
     the state once, and a matrix product with one row would round the
@@ -168,16 +175,42 @@ def _chunk(S, q, k, v, g, beta):
     return S, o
 
 
+LANES = 128
+
+
 def chunk_form(dk: int, dv: int) -> str:
     """Which form of the chunked rule a trace takes here: ``"kernel"``
-    (ops/pallas/kda_chunk.py) where the trace is for a TPU and the head
-    sizes are whole lane tiles, ``"jnp"`` (the scan below) everywhere
-    else.  Asked at trace time, as ``ops.attention`` asks for flash; the
-    trainer reports the answer on its ``update`` span."""
+    (ops/pallas/kda_chunk.py) where the trace is for a TPU, whatever the
+    head sizes (those that are no whole lane tiles are padded around the
+    call), ``"jnp"`` (the scan below) everywhere else.  Asked at trace
+    time, as ``ops.attention`` asks for flash; the trainer reports the
+    answer on its ``update`` span."""
     from orion_tpu.ops.pallas import target_platform
 
-    return ("kernel" if target_platform() == "tpu" and dk % 128 == 0
-            and dv % 128 == 0 else "jnp")
+    return "kernel" if target_platform() == "tpu" else "jnp"
+
+
+def _to_lane_tiles(q, k, v, g, state):
+    """The kernels' operands for heads of any size: zero key channels up
+    to the next multiple of 128 and zero value columns likewise, one
+    decay a head broadcast over the (padded) key channels.  Padded key
+    channels of q and k are zero, so no pair product, prediction or
+    output sees them and their rows of the state stay zero whatever they
+    decay by; padded value columns of v and of the state are zero and
+    stay so."""
+    dk, dv = q.shape[-1], v.shape[-1]
+    pk, pv = -dk % LANES, -dv % LANES
+
+    def last(t, n):
+        return jnp.pad(t, ((0, 0),) * (t.ndim - 1) + ((0, n),)) if n else t
+
+    if g.shape[-1] == 1:
+        g = jnp.broadcast_to(g, g.shape[:-1] + (dk + pk,))
+    else:
+        g = last(g, pk)
+    if pk or pv:
+        state = jnp.pad(state, ((0, 0), (0, 0), (0, pk), (0, pv)))
+    return last(q, pk), last(k, pk), last(v, pv), g, state
 
 
 def _kernel_on_mesh(q, k, v, g, beta, state, chunk):
@@ -217,15 +250,20 @@ def _kernel_on_mesh(q, k, v, g, beta, state, chunk):
 
 def kda_chunked(q, k, v, g, beta, state: Optional[jax.Array] = None,
                 chunk: int = CHUNK):
-    """A whole sequence.  q, k, g [B, L, H, dk]; v [B, L, H, dv]; beta
-    [B, L, H]; state [B, H, dk, dv] float32 or None (zero) ->
-    (o [B, L, H, dv] float32, the state after the last position)."""
+    """A whole sequence.  q, k [B, L, H, dk]; g [B, L, H, dk] or
+    [B, L, H, 1] (one decay a head); v [B, L, H, dv]; beta [B, L, H];
+    state [B, H, dk, dv] float32 or None (zero) -> (o [B, L, H, dv]
+    float32, the state after the last position)."""
     f32 = jnp.float32
     B, L, H, dk = q.shape
     dv = v.shape[-1]
     if state is None:
         state = jnp.zeros((B, H, dk, dv), f32)
     if chunk_form(dk, dv) == "kernel":
+        if dk % LANES or dv % LANES or g.shape[-1] == 1:
+            q, k, v, g, padded = _to_lane_tiles(q, k, v, g, state)
+            o, padded = _kernel_on_mesh(q, k, v, g, beta, padded, chunk)
+            return o[..., :dv], padded[:, :, :dk, :dv]
         return _kernel_on_mesh(q, k, v, g, beta, state, chunk)
     n = -(-L // chunk)
     pad = n * chunk - L

@@ -17,7 +17,9 @@ Rules (MaxText/T5X-style):
   expert  — stacked expert weights         → expert
   conv    — a depthwise convolution's taps → (replicated; its channels
             are ``heads``, as are a KDA layer's projections and
-            ``dt_bias``; its low-rank gates run embed → latent → heads)
+            ``dt_bias``; its low-rank gates run embed → latent → heads;
+            a GDN layer's q / k / v / z projections of unequal width
+            are ``heads`` too, its per-head a / b projections ``norm``)
 """
 
 from __future__ import annotations

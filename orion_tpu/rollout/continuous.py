@@ -211,13 +211,15 @@ class ContinuousBatchingEngine:
                  eos_token_id: Optional[int] = None, pad_token_id: int = 0,
                  segment_len: Optional[int] = None,
                  mesh: Optional[Mesh] = None):
-        if model_cfg.latent_attention:
+        if model_cfg.pattern:
             raise ValueError(
                 f"the continuous engine cannot run arch={model_cfg.arch!r}"
                 ": its page pool, block tables and the Pallas paged-decode "
-                "kernel hold per-head K/V pages of one head_dim; a latent "
-                "paged cache (c, k_rope) and a kernel that attends over it "
-                "are not written yet"
+                "kernel hold per-head K/V pages of one head_dim in every "
+                "layer"
+                + ("; a latent paged cache (c, k_rope) and a kernel that "
+                   "attends over it are not written yet"
+                   if model_cfg.latent_attention else "")
                 + ("; nor does its cache manager hold a recurrent state "
                    "per slot (admission, preemption and prefix reuse move "
                    "pages, and a state is not made of pages)"

@@ -16,7 +16,8 @@ One decode path (XLA-first, static shapes):
   overwrites the padded tail slot-by-slot during decode (see
   models.transformer.Attention);
 - the cache is dense ([B, P+T] per layer; the latent form for
-  ``latent_attention``), int8 under ``quantize_kv``, or paged under
+  ``latent_attention``; per layer by kind for a pattern model), int8
+  under ``quantize_kv``, or paged under
   ``RolloutConfig.paged`` (block tables + the Pallas paged-decode
   kernel; slower than dense for a fixed batch, ROADMAP D3(a));
 - a recurrent layer (``ModelConfig.recurrent``) has no slot to
@@ -96,20 +97,29 @@ class RolloutEngine:
 
         self._decode_model, self._decode_cfg = make_decode_twin(
             model, model_cfg)
-        if model_cfg.latent_attention:
+        if model_cfg.pattern:
+            latent = model_cfg.latent_attention
             state = ", and a recurrent state is not made of pages" \
                 if model_cfg.recurrent else ""
             for on, missing in (
-                    (cfg.paged, "rollout.paged: there is no latent paged "
-                     "cache (ops/paged_kv.py and the Pallas paged-decode "
-                     "kernel hold per-head K/V pages)" + state),
+                    (cfg.paged, "rollout.paged: "
+                     + ("there is no latent paged cache (ops/paged_kv.py "
+                        "and the Pallas paged-decode kernel hold per-head "
+                        "K/V pages)" if latent else
+                        "init_paged_cache gives every layer pages")
+                     + state),
                     (cfg.quantize_kv, "rollout.quantize_kv: there is no "
-                     "int8 latent cache (ops/quant.py scales per head)"
+                     + ("int8 latent cache (ops/quant.py scales per head)"
+                        if latent else "int8 cache for a model whose "
+                        "layers do not all hold keys and values")
                      + (", nor an int8 form of a float32 recurrent state"
                         if model_cfg.recurrent else "")),
                     (cfg.quantize_weights, "rollout.quantize_weights: "
-                     "there are no int8 expert stacks or absorbed int8 "
-                     "kv_b_proj (ops/quant.py quantises Dense kernels)")):
+                     + ("there are no int8 expert stacks or absorbed int8 "
+                        "kv_b_proj (ops/quant.py quantises Dense kernels)"
+                        if latent else "the int8 Dense twins do not reach "
+                        "this block (no QuantDense decode twin was run "
+                        "against its reference)"))):
                 if on:
                     raise ValueError(
                         f"arch={model_cfg.arch!r} cannot run with {missing}")

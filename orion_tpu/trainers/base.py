@@ -692,9 +692,10 @@ class BaseTrainer:
 
         mc, kda_chunk = self.cfg.model, ""
         if mc.recurrent:
-            # the form the KDA layers' chunked rule takes in this trace
+            # the form the recurrent layers' chunked rule takes in this
+            # trace
             from orion_tpu.ops.kda import chunk_form
-            kda_chunk = chunk_form(mc.kda_head_dim, mc.kda_head_dim)
+            kda_chunk = chunk_form(*mc.delta_head_dims())
         self._remat_info = info = {
             "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0,
             "kda_chunk": kda_chunk}

@@ -410,31 +410,40 @@ def test_grouped_expert_product_compiles_for_v5e(one_chip, on_tpu):
 
 # -- the kimi_linear block at the published widths --------------------------
 
-def test_delta_rule_compiles_for_v5e(one_chip, on_tpu):
+@pytest.mark.parametrize("H,dk,dv,one_decay", [
+    (32, 128, 128, False), (30, 96, 192, True)],
+    ids=["kimi-32x128x128", "olmo-30x96x192"])
+def test_delta_rule_compiles_for_v5e(one_chip, on_tpu, H, dk, dv, one_decay):
     """Both forms of the delta rule at the shapes of
-    ``ppo-kimi-linear-ep32-sync``: the chunked form with its backward at
-    the update's minibatch (16 x 1024, 32 heads of 128: the two kernels
-    of ops/pallas/kda_chunk.py, through Mosaic) and one decode step over
-    a batch of 32 (no kernel)."""
+    ``ppo-kimi-linear-ep32-sync`` (32 heads of 128, a decay a channel)
+    and of ``ppo-olmo-hybrid-vp8-sync`` (30 heads of 96 x 192, one decay
+    a head: padded to 128 x 256 around the call): the chunked form with
+    its backward at a minibatch of 16 x 1024 (the two kernels of
+    ops/pallas/kda_chunk.py, through Mosaic) and one decode step over a
+    batch of 32 (no kernel)."""
     from orion_tpu.ops.kda import chunk_form, kda_chunked, kda_step
 
-    B, L, H, d = 16, 1024, 32, 128
-    assert chunk_form(d, d) == "kernel"
+    B, L = 16, 1024
+    assert chunk_form(dk, dv) == "kernel"
+    gd = 1 if one_decay else dk
 
     def loss(q, k, v, g, beta):
         o, S = kda_chunked(q, k, v, g, beta)
+        assert o.shape == (B, L, H, dv) and S.shape == (B, H, dk, dv)
         return jnp.sum(o) + jnp.sum(S)
 
     with jax.default_matmul_precision("default"):
         chunked = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-            *(_sds((B, L, H, d), BF16, one_chip),) * 3,
-            _sds((B, L, H, d), jnp.float32, one_chip),
+            *(_sds((B, L, H, dk), BF16, one_chip),) * 2,
+            _sds((B, L, H, dv), BF16, one_chip),
+            _sds((B, L, H, gd), jnp.float32, one_chip),
             _sds((B, L, H), jnp.float32, one_chip)).compile()
         step = jax.jit(kda_step).lower(
-            *(_sds((32, H, d), BF16, one_chip),) * 3,
-            _sds((32, H, d), jnp.float32, one_chip),
+            *(_sds((32, H, dk), BF16, one_chip),) * 2,
+            _sds((32, H, dv), BF16, one_chip),
+            _sds((32, H, gd), jnp.float32, one_chip),
             _sds((32, H), jnp.float32, one_chip),
-            _sds((32, H, d, d), jnp.float32, one_chip)).compile()
+            _sds((32, H, dk, dv), jnp.float32, one_chip)).compile()
     assert _kernel_names(chunked) == ["kda_chunk_bwd", "kda_chunk_fwd"]
     assert _kernel_calls(step) == 0
     # what the backward holds of one layer: the states at the 16 chunk
@@ -484,3 +493,77 @@ def test_kimi_linear_update_compiles_for_v5e(one_chip, on_tpu):
             "moe_gmm_dlhs", "moe_tgmm", "kda_chunk_fwd",
             "kda_chunk_bwd"} <= set(_kernel_names(compiled))
     assert compiled.memory_analysis().peak_memory_in_bytes <= 15.75 * 2**30
+
+
+# -- the olmo_hybrid block at the published widths ---------------------------
+
+#: what the chip holds beside the update's arguments when the update of
+#: ``ppo-olmo-hybrid-vp8-sync`` loads (the bf16 reference, the batch):
+#: ``bytes_in_use`` 9.339 GB less the 7.431 GB of parameters and moments
+#: (my chip run, PR 34); and what the device reports as its limit
+OLMO_RESIDENT_BESIDE_ARGS = 9.339e9 - 7.431e9
+V5E_BYTES_LIMIT = 16.909e9
+
+
+@pytest.mark.parametrize("rows,fits", [(4, "chip"), (8, "compiler"),
+                                       (16, "nowhere")],
+                         ids=["minibatch-4", "minibatch-8", "minibatch-16"])
+def test_olmo_hybrid_update_compiles_for_v5e(one_chip, on_tpu, rows, fits):
+    """The shared-backbone PPO update of ``ppo-olmo-hybrid-vp8-sync``
+    (one period of Olmo-Hybrid-7B at the published widths, 12 544 rows
+    of the vocabulary, remat, each stretch scanned) over 32 sequences of
+    1024 in minibatches of ``rows``.  The update has the chunked delta
+    rule's two kernels (the three GDN layers, heads of 96 x 192 padded
+    to the kernels' tiles) and the flash kernels (the full-attention
+    layer, 30 heads of 128) in it.  At 16 rows the compiler refuses it
+    for a chip of 15.75 GiB (17.8 needed: 6.9 of parameters and
+    moments, 10.9 of gradients and activations).  At 8 rows it compiles
+    (14.1 GiB) but what it needs beside its arguments does not fit
+    beside what else the chip holds then (on the chip: "Attempting to
+    reserve 7.34G ... 7.10G free"), which is why the cell runs the
+    job's ``-mb4`` copy; at 4 rows it fits with room."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc = dataclasses.replace(ModelConfig.olmo_hybrid_7b(), num_layers=4,
+                             vocab_size=12544, max_seq_len=1024)
+    assert mc.layer_runs() == ((0, 3, "gdn", "dense"),
+                               (3, 1, "attention", "dense"))
+    shell, pshape, mb = _build_8b_shell(mc)
+    S, T = 1024, 512
+    shell.cfg.rollout.max_prompt_len = shell.cfg.rollout.max_new_tokens = T
+    shapes = {k: (S,) if k == "sequences" else () if k == "prompt_lens"
+              else (T,) for k in mb}
+    experience = {k: _sds((32,) + shapes[k], v.dtype, one_chip)
+                  for k, v in mb.items()}
+    state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                         _abstract_state(shell, pshape))
+    with jax.default_matmul_precision("default"):
+        lowered = jax.jit(
+            lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+            donate_argnums=(0,)).lower(
+                state, experience,
+                _sds((32 // rows, rows), jnp.int32, one_chip))
+        if fits == "nowhere":
+            with pytest.raises(Exception, match="Ran out of memory in "
+                                                "memory space hbm"):
+                lowered.compile()
+            return
+        compiled = lowered.compile()
+    names = _kernel_names(compiled)
+    # the scanned GDN stack: forward, remat's forward, backward
+    assert names.count("kda_chunk_fwd") == 2
+    assert names.count("kda_chunk_bwd") == 1
+    assert names.count("flash_fwd") == 2
+    assert names.count("flash_bwd_dq") == names.count("flash_bwd_dkv") == 1
+    mem = compiled.memory_analysis()
+    assert mem.peak_memory_in_bytes <= 15.75 * 2**30
+    # the parameters and their moments: 928.9 M x (4 + 2 + 2) bytes
+    assert mem.argument_size_in_bytes == pytest.approx(7.43e9, rel=5e-3)
+    on_chip = (mem.peak_memory_in_bytes + OLMO_RESIDENT_BESIDE_ARGS
+               <= V5E_BYTES_LIMIT)
+    assert on_chip == (fits == "chip")

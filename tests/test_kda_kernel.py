@@ -150,9 +150,14 @@ def test_positions_without_a_token_are_inert():
 @pytest.mark.parametrize("platform,dk,dv,want", [
     ("tpu", 128, 128, "kernel"), ("tpu", 256, 128, "kernel"),
     ("cpu", 128, 128, "jnp"), ("gpu", 128, 128, "jnp"),
-    ("tpu", 64, 64, "jnp"), ("tpu", 128, 96, "jnp"), ("tpu", 16, 16, "jnp")])
+    ("tpu", 64, 64, "kernel"), ("tpu", 128, 96, "kernel"),
+    ("tpu", 16, 16, "kernel"), ("tpu", 96, 192, "kernel"),
+    ("cpu", 96, 192, "jnp")])
 def test_the_choice_follows_the_platform_and_the_head_sizes(
         monkeypatch, platform, dk, dv, want):
+    """On a TPU the kernels, whatever the head sizes (since PR 34 heads
+    that are no whole lane tiles are padded around the call); the
+    ``jax.numpy`` form everywhere else."""
     import orion_tpu.ops.pallas as pallas
 
     monkeypatch.setattr(pallas, "target_platform", lambda: platform)
@@ -190,3 +195,87 @@ def test_kda_chunked_takes_the_kernels_where_the_choice_says(monkeypatch,
     assert calls == [per, per]
     for g_, w_ in zip(got + got_none, want + want_none):
         _close(g_, w_, 2e-2)
+
+
+# -- heads off the lane tile: Olmo-Hybrid's 30 heads of 96 x 192, one decay
+# -- a head, a step size up to 2 ---------------------------------------------
+
+def _gdn_inputs(L, seed=0, heads=30, dk=96, dv=192):
+    rs = np.random.RandomState(seed)
+    q, k = (rs.normal(size=(1, L, heads, dk)) for _ in range(2))
+    v = rs.normal(size=(1, L, heads, dv))
+    q = q / np.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    g = -rs.uniform(1, 16, size=(1, 1, heads, 1)) * np.exp(rs.uniform(
+        np.log(1e-3), np.log(1e-1), size=(1, L, heads, 1)))
+    beta = 2.0 / (1.0 + np.exp(-rs.normal(size=(1, L, heads))))
+    S = rs.normal(size=(1, heads, dk, dv)) * 0.1
+    return [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta, S)]
+
+
+@pytest.fixture
+def padded_kernels(monkeypatch):
+    """``kda_chunked`` as a TPU trace takes it at these heads (zero
+    channels up to 128 x 256 around the kernels, the decay broadcast),
+    the kernels interpreted with float32 operands."""
+    kernel = kda_chunk.kda_chunk_kernel
+    shapes = []
+
+    def run(q, k, v, g, beta, state, chunk):
+        shapes.append((q.shape, v.shape, g.shape, state.shape))
+        return kernel(q, k, v, g, beta, state, chunk, jnp.float32)
+
+    jnp_form = kda.kda_chunked
+    monkeypatch.setattr(kda_chunk, "kda_chunk_kernel", run)
+
+    def padded(*args):
+        with monkeypatch.context() as m:
+            m.setattr(kda, "chunk_form", lambda dk, dv: "kernel")
+            return jnp_form(*args)
+
+    return padded, shapes
+
+
+@pytest.mark.parametrize("case", ["forward", "backward", "carried_state",
+                                  "masked_tail"])
+def test_heads_of_96_by_192_run_the_kernels_padded(padded_kernels, case):
+    """30 heads (two a grid step), key size 96 and value size 192
+    (neither a multiple of 128), ONE decay a head, beta up to 2, against
+    the ``jax.numpy`` form at the true sizes."""
+    padded, shapes = padded_kernels
+    L = 100                                   # 64 + 36: a padded chunk
+    args = _gdn_inputs(L, seed=6)
+    assert float(jnp.max(args[4])) > 1.0
+    if case == "forward":
+        (o, S), (want_o, want_S) = padded(*args[:5]), kda.kda_chunked(
+            *args[:5])
+        assert o.shape == (1, L, 30, 192) and S.shape == (1, 30, 96, 192)
+        assert shapes == [((1, L, 30, 128), (1, L, 30, 256),
+                           (1, L, 30, 128), (1, 30, 128, 256))]
+        _close(o, want_o, 1e-5, "o")
+        _close(S, want_S, 1e-5, "state")
+    elif case == "backward":
+        got = jax.grad(_loss(padded), argnums=tuple(range(6)))(*args)
+        want = _grads("jnp")(*args)
+        for name, a, b in zip(NAMES, got, want):
+            assert a.shape == b.shape, name   # g's gradient: one a head
+            _close(a, b, 1e-5, name)
+    elif case == "carried_state":
+        o, S = padded(*args)
+        cut = 41
+        o1, S1 = padded(*(a[:, :cut] for a in args[:5]), args[5])
+        o2, S2 = padded(*(a[:, cut:] for a in args[:5]), S1)
+        _close(jnp.concatenate([o1, o2], axis=1), o, 1e-5, "o")
+        _close(S2, S, 1e-5, "state")
+        _close(S, kda.kda_chunked(*args)[1], 1e-5, "state vs jnp")
+    else:
+        real = 45
+        q, k, v, g, beta, S0 = args
+        mask = jnp.arange(L) < real
+        g_m = jnp.where(mask[None, :, None, None], g, 0.0)
+        b_m = jnp.where(mask[None, :, None], beta, 0.0)
+        o, S = padded(q, k, v, g_m, b_m, S0)
+        o_short, S_short = padded(*(a[:, :real] for a in (q, k, v, g, beta)),
+                                  S0)
+        _close(o[:, :real], o_short, 1e-5, "o")
+        _close(S, S_short, 1e-5, "state")
