@@ -135,17 +135,20 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
             out += n * per_out * act + rows * H * w(seq_len) * 4
         if ffn == "experts":
             from orion_tpu.ops import moe
-            from orion_tpu.ops.pallas.grouped_matmul import padded_rows
 
             mlp += 2 * w(cfg.n_shared_experts * cfg.moe_intermediate_size)
-            # scores [n, E] float32 and the selection [n, k] (twice: the
-            # gather of the selected scores keeps its own indices); the
-            # grouped form adds order [m], inverse [n k], sizes [held + 1]
+            # scores [n, E] float32 and the selection [n, k] (the gather
+            # of the selected scores keeps its own indices); the dense
+            # form reads the selection again for its weights, the
+            # grouped form's backward reads order [n k, in whole blocks]
+            # and sizes [held + 1] instead
             k = cfg.num_experts_per_tok
-            r = n * (w(cfg.n_routed_experts) + 2 * w(k))
-            if n > moe.DENSE_MAX_TOKENS:
-                r += w(padded_rows(n * k)) + w(n * k) \
-                    + w(cfg.experts_held + 1)
+            r = n * (w(cfg.n_routed_experts) + w(k))
+            block = moe.block_rows(cfg, n)
+            if block:
+                r += w(-(-n * k // block) * block) + w(cfg.experts_held + 1)
+            else:
+                r += n * w(k)
             route += 4 * r
         elif ffn == "dense":
             mlp += (1 if cfg.arch == "neox" else 2) * w(

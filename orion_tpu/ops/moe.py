@@ -17,10 +17,14 @@ of the parallelism table first-class, as the task demands.
 Beside it, :class:`SigmoidTopKMoE` is the expert layer of the
 ``deepseek_v3`` block as published (sigmoid scores, a selection bias,
 top-k of all experts, normalised and scaled gates, shared experts, no
-capacity, no drops, no auxiliary loss), told which experts it holds.
+capacity, no drops, no auxiliary loss), told which experts it holds:
+its rows, products and gradients follow the (token, choice) pairs
+routed to those, a static block of them at a time.
 """
 
 from __future__ import annotations
+
+import functools
 
 import flax.linen as nn
 import jax
@@ -189,15 +193,53 @@ def experts_dense(x, w_gate_up, w_down, local, gates):
         return jnp.einsum("thd,th->td", y, weight.astype(y.dtype))
 
 
-def experts_grouped(x, w_gate_up, w_down, local, gates):
-    """Same result by a grouped matrix product over the (token, choice)
-    pairs sorted by expert: static shapes (all T * k pairs have a row),
-    no drops (a batch whose every pair lands on one held expert is that
-    expert's group), and kernels whose work follows the rows of the
-    held experts alone."""
-    from orion_tpu.ops.pallas.grouped_matmul import (
-        collect_rows, dispatch_rows, grouped_matmul, padded_rows)
+# The rows of one block of the grouped form, as a multiple of what an
+# even routing of UNMASKED tokens sends to the held experts
+# (T k held / all).  Sized for full-length prompts, not for the padded
+# ones the cells send (padding is routed nowhere, so those fill a
+# quarter of it): at 1 an unmasked batch runs a second block in every
+# other layer call, and a further block costs 11 ms forward + backward
+# where a block of twice the rows costs 1.6 ms more to combine.  Read on
+# a v5e in ppo-kanana-ep8-sync (PERF.md section 6, PR 35): 9.53
+# samples/s at 2, 9.95 at 1 (block 0 outside the loop then: 9.66 at 2),
+# 8.65 with a row for every pair.
+BLOCK_ROWS_OVER_EVEN = 2
 
+
+def block_rows(cfg: ModelConfig, n_tokens: int) -> int:
+    """The (token, choice) pair rows that one block of the grouped form
+    moves and multiplies for a step of ``n_tokens`` tokens, from shapes
+    alone: the even share of the held experts times
+    :data:`BLOCK_ROWS_OVER_EVEN`, in whole row tiles, at least one and
+    at most all pairs (a layer that holds every expert works on all
+    pairs at once).  0 where the layer takes its dense form: a small
+    step, or a mesh of several devices (a Mosaic kernel cannot be
+    partitioned automatically)."""
+    from orion_tpu.ops.pallas.grouped_matmul import padded_rows, row_tile
+    from orion_tpu.parallel.sharding import ambient_mesh
+
+    mesh = ambient_mesh()
+    one_device = mesh is None or mesh.empty or mesh.size == 1
+    if not one_device or n_tokens <= DENSE_MAX_TOKENS:
+        return 0
+    n_pairs = n_tokens * cfg.num_experts_per_tok
+    tile = row_tile(n_pairs)
+    even = n_pairs * cfg.experts_held * BLOCK_ROWS_OVER_EVEN
+    tiles = max(1, -(-even // (cfg.n_routed_experts * tile)))
+    return min(tiles * tile, padded_rows(n_pairs))
+
+
+def experts_grouped(x, w_gate_up, w_down, local, gates, block: int):
+    """Same result by a grouped matrix product over the (token, choice)
+    pairs sorted by expert, the held experts' pairs first.  Only those
+    get rows: the sorted pairs are worked on ``block`` rows at a time
+    (static: :func:`block_rows`), block 0 always and further
+    blocks while held pairs are left (one loop of static length), so a batch whose every pair lands
+    on one held expert takes ``T k / block`` blocks and is computed
+    exactly (no capacity, no drops), and a batch routed like the even
+    share takes one.  Rows are gathered, multiplied, activated and added
+    into their tokens' rows of a float32 sum; the kernels' work follows
+    the rows of the held experts inside a block."""
     T, k = local.shape
     H = w_gate_up.shape[0]
     n_pairs = T * k
@@ -206,23 +248,113 @@ def experts_grouped(x, w_gate_up, w_down, local, gates):
         key = jnp.where(held, local, H).reshape(n_pairs)
         _, order = jax.lax.sort_key_val(
             key, jnp.arange(n_pairs, dtype=jnp.int32))
-        inverse = jnp.zeros((n_pairs,), jnp.int32).at[order].set(
-            jnp.arange(n_pairs, dtype=jnp.int32), unique_indices=True)
         sizes = jnp.zeros((H + 1,), jnp.int32).at[key].add(1)
-        m = padded_rows(n_pairs)
-        if m > n_pairs:   # padding rows join the group nobody computes
-            order = jnp.pad(order, (0, m - n_pairs))
-            sizes = sizes.at[H].add(m - n_pairs)
-        order, inverse, sizes = (checkpoint_name(t, "moe_route")
-                                 for t in (order, inverse, sizes))
-        rows = dispatch_rows(x, order, inverse, k)              # [m, D]
-    with jax.named_scope("moe.experts"):
-        h = _swiglu(grouped_matmul(rows, w_gate_up, sizes))
-        y = grouped_matmul(h, w_down, sizes)                    # [m, D]
-    with jax.named_scope("moe.combine"):
-        pairs = collect_rows(y, order, inverse).reshape(T, k, -1)
-        weight = jnp.where(held, gates, 0.0).astype(y.dtype)
-        return jnp.einsum("tkd,tk->td", pairs, weight)
+        # whole blocks: the padding lies behind every pair, in the group
+        # nobody computes, and points at pair 0
+        order = jnp.pad(order, (0, -n_pairs % block))
+        order, sizes = (checkpoint_name(t, "moe_route")
+                        for t in (order, sizes))
+    return _routed(x, w_gate_up, w_down, gates, order, sizes, block)
+
+
+def _block(b, block: int, gates, order, sizes):
+    """Block ``b`` of the sorted pairs: (pair [block], token [block],
+    gate [block, 1], group sizes [H + 1] of the block's rows: what of
+    each held expert's run lies inside it, the rest in the group nobody
+    computes)."""
+    pair = jax.lax.dynamic_slice_in_dim(order, b * block, block)
+    ends = jnp.clip(jnp.cumsum(sizes[:-1]) - b * block, 0, block)
+    sizes_b = jnp.concatenate([jnp.diff(ends, prepend=0), block - ends[-1:]])
+    gate = jnp.take(gates.reshape(-1), pair)[:, None]
+    return pair, pair // gates.shape[1], gate, sizes_b
+
+
+def _over_blocks(body, carry, block: int, order, sizes):
+    """``body(b, carry)`` for block 0, then for blocks 1, 2, ... while
+    ``b * block`` is less than the held pairs.  ONE loop over all the
+    blocks the sorted pairs make, of static length, whose trips past the
+    held pairs do nothing: every block, the first too, is the same call
+    site of the same kernels, and what follows the routing is a branch,
+    not a trip count (on the chip a ``while_loop`` whose trip count
+    followed the routing did not end in a small program of a process
+    that had trained: PERF.md section 6, PR 35)."""
+    n_blocks = order.shape[0] // block
+    if n_blocks == 1:
+        return body(0, carry)
+    n_held = jnp.sum(sizes[:-1])
+
+    def step(b, carry):
+        return jax.lax.cond((b == 0) | (b * block < n_held),
+                            lambda c: body(b, c), lambda c: c, carry)
+
+    return jax.lax.fori_loop(0, n_blocks, step, carry)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _routed(x, w_gate_up, w_down, gates, order, sizes, block: int):
+    """The held experts' part of the layer's sum.  x [T, D]; gates
+    [T, k]; order [whole blocks] the pairs sorted by expert (pair ``p``
+    is token ``p // k``); sizes [H + 1] the pairs of each held expert
+    and of all others -> [T, D] in x.dtype, summed in float32."""
+    from orion_tpu.ops.pallas.grouped_matmul import gmm
+
+    def add_block(b, out):
+        _, tok, gate, sizes_b = _block(b, block, gates, order, sizes)
+        with jax.named_scope("moe.experts"):
+            rows = jnp.take(x, tok, axis=0)
+            h = _swiglu(gmm(rows, w_gate_up, sizes_b))
+            y = gmm(h, w_down, sizes_b)             # zero where not held
+        with jax.named_scope("moe.combine"):
+            return out.at[tok].add(y.astype(jnp.float32) * gate)
+
+    out = _over_blocks(add_block, jnp.zeros(x.shape, jnp.float32), block,
+                       order, sizes)
+    return out.astype(x.dtype)
+
+
+def _routed_fwd(x, w_gate_up, w_down, gates, order, sizes, block):
+    # what is kept is what came in: the backward rebuilds a block's rows
+    # and its activation (one more product on the held rows)
+    return (_routed(x, w_gate_up, w_down, gates, order, sizes, block),
+            (x, w_gate_up, w_down, gates, order, sizes))
+
+
+def _routed_bwd(block, res, g):
+    from orion_tpu.ops.pallas.grouped_matmul import gmm, gmm_dlhs, tgmm
+
+    x, w_gate_up, w_down, gates, order, sizes = res
+    f32 = jnp.float32
+
+    def add_block(b, carry):
+        d_x, d_gate_up, d_down, d_gate_of = carry
+        pair, tok, gate, sizes_b = _block(b, block, gates, order, sizes)
+        with jax.named_scope("moe.experts"):
+            rows = jnp.take(x, tok, axis=0)
+            h, swiglu_vjp = jax.vjp(_swiglu, gmm(rows, w_gate_up, sizes_b))
+            g_rows = jnp.take(g, tok, axis=0)
+            # y enters the sum times its gate: the gate's gradient is
+            # <g, y> = <g w_down^T, h>, and y itself is not rebuilt
+            d_h = gmm_dlhs(g_rows, w_down, sizes_b).astype(f32)
+            d_gate = jnp.sum(d_h * h.astype(f32), axis=-1)
+            d_down = d_down + tgmm(
+                (h.astype(f32) * gate).astype(h.dtype), g_rows, sizes_b, f32)
+            (d_pre,) = swiglu_vjp((d_h * gate).astype(h.dtype))
+            d_gate_up = d_gate_up + tgmm(rows, d_pre, sizes_b, f32)
+            d_rows = gmm_dlhs(d_pre, w_gate_up, sizes_b)
+        with jax.named_scope("moe.dispatch"):
+            return (d_x.at[tok].add(d_rows.astype(f32)), d_gate_up, d_down,
+                    d_gate_of.at[pair].add(d_gate))
+
+    primals = (x, w_gate_up, w_down, gates.reshape(-1))
+    grads = _over_blocks(add_block,
+                         tuple(jnp.zeros(t.shape, f32) for t in primals),
+                         block, order, sizes)
+    d_x, d_gate_up, d_down, d_gate_of = (
+        d.astype(t.dtype) for d, t in zip(grads, primals))
+    return d_x, d_gate_up, d_down, d_gate_of.reshape(gates.shape), None, None
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class SigmoidTopKMoE(nn.Module):
@@ -247,8 +379,11 @@ class SigmoidTopKMoE(nn.Module):
     benchmarks/reference_check_dsv3.py).
 
     Expert weights are stacked on the ``expert`` logical axis.  On one
-    device the large-batch path is the grouped product (Pallas); under
-    a mesh of several devices a Mosaic kernel cannot be partitioned
+    device the large-batch path is the grouped product (Pallas) over
+    blocks of the held pairs (:func:`block_rows`: a share that holds an
+    eighth of the experts moves a quarter of the pair rows, twice its
+    even share, and more only when the routing sends it more); under a
+    mesh of several devices a Mosaic kernel cannot be partitioned
     automatically, so the layer takes its dense form there, which GSPMD
     partitions over the ``expert`` axis like the GShard layer's einsums.
     """
@@ -263,7 +398,6 @@ class SigmoidTopKMoE(nn.Module):
         pad id, would all select the same experts, and would make up
         those experts' whole load.  They get the shared expert alone."""
         from orion_tpu.models.transformer import _dense
-        from orion_tpu.parallel.sharding import ambient_mesh
 
         cfg = self.cfg
         B, L, Dm = x.shape
@@ -300,12 +434,11 @@ class SigmoidTopKMoE(nn.Module):
                      jnp.zeros((H + 1,), jnp.int32).at[
                          jnp.where(held, local, H).reshape(-1)].add(1)[:H])
 
-        mesh = ambient_mesh()
-        one_device = mesh is None or mesh.empty or mesh.size == 1
-        experts = experts_grouped if (
-            one_device and B * L > DENSE_MAX_TOKENS) else experts_dense
-        routed = experts(z.astype(cdt), w_gate_up.astype(cdt),
-                         w_down.astype(cdt), local, gates)
+        block = block_rows(cfg, B * L)
+        operands = (z.astype(cdt), w_gate_up.astype(cdt), w_down.astype(cdt),
+                    local, gates)
+        routed = experts_grouped(*operands, block) if block \
+            else experts_dense(*operands)
 
         with jax.named_scope("moe.shared"):
             S = cfg.n_shared_experts * I

@@ -1,25 +1,25 @@
 """Grouped matrix product for the dropless expert layer (ops/moe.py).
 
 ``out[r] = lhs[r] @ rhs[g]`` for every row ``r`` of group ``g``, the
-rows of a group lying side by side (the caller sorts them by expert).
-``group_sizes`` has one entry MORE than ``rhs`` has groups: the last
-group holds the rows of experts this chip does not hold (and padding),
-which no kernel visits and whose output is zero — the kernels' grids are
-as long as the held groups' row tiles, not as ``lhs``, so the work
-follows the rows routed here.
+rows of a group lying side by side (the caller sorts them by expert and
+hands over one block of the sorted rows at a time).  ``group_sizes`` has
+one entry MORE than ``rhs`` has groups: the last group holds the rows of
+a block that belong to no held expert (the pairs routed elsewhere, and
+padding), which no kernel visits and whose output is zero — the kernels'
+grids are as long as the held groups' row tiles, not as ``lhs``, so the
+work follows the rows routed here.
 
 The kernels are jax's own megablox Pallas kernels
 (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` for the product
 and for the gradient of ``lhs``, ``tgmm`` for the gradient of ``rhs``),
 called under names of this repo so that each reaches the device trace
 (see ``named_pallas_call``): ``moe_gmm``, ``moe_gmm_dlhs``,
-``moe_tgmm``.  The VJP is written here (megablox's own ties all three
+``moe_tgmm``.  The three are plain functions: the expert layer's VJP is
+written in ops/moe.py over whole blocks (megablox's own ties all three
 calls to one tiling).  Off-TPU they run interpreted.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -88,72 +88,25 @@ def _gmm(name, lhs, rhs, group_sizes, transpose_rhs=False):
                  transpose_rhs=transpose_rhs)
 
 
-@jax.custom_vjp
-def grouped_matmul(lhs, rhs, group_sizes):
+def gmm(lhs, rhs, group_sizes):
     """lhs [m, k], rhs [G, k, n], group_sizes [G + 1] int32 summing to
     m -> [m, n] in lhs.dtype; rows of the last group come back zero."""
     return _gmm("moe_gmm", lhs, rhs, group_sizes)
 
 
-def _fwd(lhs, rhs, group_sizes):
-    return grouped_matmul(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+def gmm_dlhs(grad, rhs, group_sizes):
+    """The gradient of ``gmm``'s ``lhs``: grad [m, n], rhs [G, k, n] ->
+    [m, k], rows of the last group zero."""
+    return _gmm("moe_gmm_dlhs", grad, rhs, group_sizes, transpose_rhs=True)
 
 
-def _bwd(res, grad):
-    backend = _backend()
-    lhs, rhs, group_sizes = res
-    grad = grad.astype(lhs.dtype)
-    d_lhs = _gmm("moe_gmm_dlhs", grad, rhs, group_sizes, transpose_rhs=True)
+def tgmm(lhs, grad, group_sizes, out_dtype):
+    """The gradient of ``gmm``'s ``rhs``: lhs [m, k], grad [m, n] ->
+    [G, k, n] in ``out_dtype``, ``G = len(group_sizes) - 1``: group
+    ``g``'s rows of ``lhs``, transposed, times its rows of ``grad``."""
     m, k = lhs.shape
-    d_rhs = _call("moe_tgmm", backend.tgmm, lhs.swapaxes(0, 1), grad,
-                  group_sizes, preferred_element_type=rhs.dtype,
-                  tiling=_tiling(m, k, grad.shape[1]),
-                  group_offset=jnp.zeros((), jnp.int32),
-                  num_actual_groups=rhs.shape[0])
-    return d_lhs, d_rhs, None
-
-
-grouped_matmul.defvjp(_fwd, _bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def dispatch_rows(x, order, inverse, k: int):
-    """x [T, D] -> the rows of the (token, choice) pairs in sorted
-    order, [m, D]: pair ``p`` is token ``p // k``.  ``order`` [m] lists
-    the pairs by expert (padding points anywhere); ``inverse`` [T * k]
-    is where each pair went.  Both directions are gathers: the gradient
-    of a token is the sum of its k pairs' rows."""
-    return jnp.take(x, order // k, axis=0, mode="clip")
-
-
-def _dispatch_fwd(x, order, inverse, k):
-    return dispatch_rows(x, order, inverse, k), (inverse, x.shape[0])
-
-
-def _dispatch_bwd(k, res, g):
-    inverse, n_tokens = res
-    pairs = jnp.take(g, inverse, axis=0, mode="clip")
-    return pairs.reshape(n_tokens, k, -1).sum(axis=1), None, None
-
-
-dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
-
-
-@jax.custom_vjp
-def collect_rows(y, order, inverse):
-    """y [m, D] in sorted order -> [T * k, D] in pair order; the
-    gradient goes back by ``order`` (rows past the pairs get a row that
-    no kernel reads)."""
-    return jnp.take(y, inverse, axis=0, mode="clip")
-
-
-def _collect_fwd(y, order, inverse):
-    return collect_rows(y, order, inverse), (order,)
-
-
-def _collect_bwd(res, g):
-    (order,) = res
-    return jnp.take(g, order, axis=0, mode="clip"), None, None
-
-
-collect_rows.defvjp(_collect_fwd, _collect_bwd)
+    return _call("moe_tgmm", _backend().tgmm, lhs.swapaxes(0, 1), grad,
+                 group_sizes, preferred_element_type=out_dtype,
+                 tiling=_tiling(m, k, grad.shape[1]),
+                 group_offset=jnp.zeros((), jnp.int32),
+                 num_actual_groups=group_sizes.shape[0] - 1)

@@ -183,19 +183,29 @@ def _sown(intermediates, name: str) -> list:
             if any(getattr(k, "key", None) == name for k in path)]
 
 
-def moe_load_stats(loads: list, pairs_per_layer: int) -> dict:
+def moe_load_stats(loads: list, pairs_per_layer: int,
+                   block_rows: int) -> dict:
     """Counters of the dropless expert layer for one forward, from the
     ``moe_load`` arrays its layers sowed ([held] or, scanned, [layers,
     held] pairs routed to each expert held here): the pairs computed
-    here and all pairs routed (over all layers), and the largest and
-    the mean load of a held expert (over layers and experts): max over
-    mean is the padding a grouped product pays."""
+    here and all pairs routed (over all layers), the largest and the
+    mean load of a held expert (over layers and experts: max over mean
+    is the padding a grouped product pays), and what the grouped form
+    moved for them: the rows of a block (``block_rows``,
+    ops/moe.py::block_rows; 0 for the dense form) over all layers, and
+    the most blocks a layer ran (1: its held pairs fit the first)."""
     load = jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])
+    blocks = jnp.zeros((), jnp.int32)
+    if block_rows:      # block 0 always runs
+        blocks = jnp.max(jnp.maximum(
+            1, -(-jnp.sum(load, axis=-1) // block_rows)))
     return {
         "moe_pairs_here": jnp.sum(load).astype(jnp.float32),
         "moe_pairs_total": jnp.float32(pairs_per_layer * load.shape[0]),
         "moe_load_max": jnp.max(load).astype(jnp.float32),
         "moe_load_mean": jnp.mean(load.astype(jnp.float32)),
+        "moe_block_rows": jnp.float32(block_rows * load.shape[0]),
+        "moe_blocks_max": blocks.astype(jnp.float32),
     }
 
 
@@ -409,8 +419,11 @@ class BaseTrainer:
             out, inter = self.model.apply(
                 {"params": params}, sequences, positions,
                 mutable=["intermediates"], **apply_kw)
+            from orion_tpu.ops.moe import block_rows
+
             moe = moe_load_stats(_sown(inter, "moe_load"),
-                                 sequences.size * mc.num_experts_per_tok)
+                                 sequences.size * mc.num_experts_per_tok,
+                                 block_rows(mc, sequences.size))
             aux = jnp.zeros((), jnp.float32)
         else:
             out = self.model.apply({"params": params}, sequences,

@@ -378,24 +378,45 @@ def test_flash_with_a_narrower_value_compiles_for_v5e(one_chip, on_tpu):
                                        "flash_fwd"]
 
 
-def test_grouped_expert_product_compiles_for_v5e(one_chip, on_tpu):
-    """The dropless expert path at the update's shape (16 x 1024 tokens,
-    top-6 of 128, 16 experts held, hidden 2048, expert width 768): the
-    grouped product forward and its two backward kernels, by the names
-    the device trace will carry."""
-    from orion_tpu.ops.moe import experts_grouped
+# (T, hidden, expert width, held, top-k, experts, rows of a block, the
+# same function's ``temp_size_in_bytes`` at PR 34, where every pair had
+# a row): the update's minibatch of the two expert cells
+GROUPED_SHAPES = {
+    "kanana-16of128-top6": (16 * 1024, 2048, 768, 16, 6, 128, 24576,
+                            2_551_555_584),
+    "kimi-8of256-top8": (16 * 1024, 2304, 1024, 8, 8, 256, 8192,
+                         2_551_714_816),
+}
 
-    T, D, I, H, k = 16 * 1024, 2048, 768, 16, 6
+
+@pytest.mark.parametrize("name", sorted(GROUPED_SHAPES))
+def test_grouped_expert_product_compiles_for_v5e(name, one_chip, on_tpu):
+    """The dropless expert path at the update's shape of
+    ``ppo-kanana-ep8-sync`` (16 x 1024 tokens, top-6 of 128, 16 experts
+    held, hidden 2048, expert width 768) and of
+    ``ppo-kimi-linear-ep32-sync`` (top-8 of 256, 8 held, 2304, 1024):
+    the grouped product and its two backward halves by the names the
+    device trace will carry, over a block of the held pairs: nothing in
+    the compiled program has a row for every (token, choice) pair."""
+    import re
+    import types
+
+    from orion_tpu.ops.moe import block_rows, experts_grouped
+
+    T, D, I, H, k, E, rows, temp_before = GROUPED_SHAPES[name]
+    block = block_rows(types.SimpleNamespace(
+        num_experts_per_tok=k, experts_held=H, n_routed_experts=E), T)
+    assert block == rows == 2 * T * k * H // E
 
     def loss(x, w_gate_up, w_down, local, gates):
-        return jnp.sum(experts_grouped(x, w_gate_up, w_down, local,
-                                       gates).astype(jnp.float32))
+        return jnp.sum(experts_grouped(x, w_gate_up, w_down, local, gates,
+                                       block).astype(jnp.float32))
 
     # the suite's "highest" matmul precision is for float32 parity on
     # the CPU; Mosaic refuses it on the kernels' bfloat16 operands, and
     # no program sets it
     with jax.default_matmul_precision("default"):
-        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 4))).lower(
             _sds((T, D), BF16, one_chip),
             _sds((H, D, 2 * I), BF16, one_chip),
             _sds((H, I, D), BF16, one_chip),
@@ -403,9 +424,20 @@ def test_grouped_expert_product_compiles_for_v5e(one_chip, on_tpu):
             _sds((T, k), jnp.float32, one_chip)).compile()
     names = _kernel_names(compiled)
     assert set(names) == {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm"}, names
-    # gate|up and down: two of each backward half (the second forward
-    # product's result is not needed for these gradients)
+    # The written backward of one block: the gate|up product again (the
+    # activation is rebuilt, not kept), the gradient of the rows through
+    # both products and both weight gradients; the forward's own two
+    # products are dead code for these gradients.  Each stands ONCE:
+    # every block, the first too, is a trip of the same loop.
+    assert names.count("moe_gmm") == 1
     assert names.count("moe_gmm_dlhs") == 2 and names.count("moe_tgmm") == 2
+    # rows are gathered, multiplied and summed a block at a time: what
+    # still has T * k entries are vectors (sort keys, order, gates)
+    wide = re.findall(r"= \w+\[%d,(\d+)" % (T * k), compiled.as_text())
+    assert all(int(cols) == 1 for cols in wide), wide
+    # scratch of the compiled program: 843 421 184 (Kanana) and
+    # 609 576 448 bytes (Kimi) at PR 35, a third and a quarter of PR 34's
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_before // 2
 
 
 # -- the kimi_linear block at the published widths --------------------------

@@ -249,30 +249,86 @@ def test_router_bias_selects_and_does_not_gate():
         rtol=1e-5)       # gates from the scores alone, bias nowhere
 
 
-@pytest.mark.parametrize("case", ["all_on_one_held", "none_held"])
-def test_dropless(case):
-    """Static shapes and no drops: a batch whose every pair lands on one
-    held expert is computed exactly; one with no pair on a held expert
+# the grouped form works on BLOCK rows of the sorted pairs at a time: a
+# block of one row tile, of a quarter of the pairs, and of all of them,
+# against routings that leave it empty, fill it partly, fill it exactly,
+# overflow it (more blocks run) and put every pair on one held expert
+DROPLESS_T, DROPLESS_K = 1024, 4                     # 4096 pairs
+
+
+def _dropless_blocks():
+    from orion_tpu.ops.pallas.grouped_matmul import padded_rows, row_tile
+
+    n_pairs = DROPLESS_T * DROPLESS_K
+    return {"one_tile": row_tile(n_pairs), "quarter": n_pairs // 4,
+            "all_pairs": padded_rows(n_pairs)}
+
+
+DROPLESS_CASES = [
+    (block, routing) for block in ("one_tile", "quarter", "all_pairs")
+    for routing in ("none_held", "under", "exact", "over",
+                    "all_on_one_held")
+    # all pairs in one block: more held pairs than its rows cannot be
+    if (block, routing) != ("all_pairs", "over")]
+
+
+def _dropless_routing(rs, routing, block, H):
+    n_pairs = DROPLESS_T * DROPLESS_K
+    if routing == "all_on_one_held":
+        return jnp.ones((DROPLESS_T, DROPLESS_K), jnp.int32)
+    n_held = {"none_held": 0, "under": block // 2, "exact": block,
+              "over": block + block // 2}[routing]
+    local = rs.choice([-3, -1, H, H + 5], size=n_pairs)
+    held = rs.permutation(n_pairs)[:n_held]
+    local[held] = rs.randint(0, H, size=n_held)
+    return jnp.asarray(local.reshape(DROPLESS_T, DROPLESS_K), jnp.int32)
+
+
+@pytest.mark.parametrize("block,routing", DROPLESS_CASES,
+                         ids=[f"{b}-{r}" for b, r in DROPLESS_CASES])
+def test_dropless(block, routing):
+    """Static shapes and no drops, whatever the block: the grouped form
+    equals every held expert on every token in its output AND in its
+    gradients with respect to x, both weight stacks and the gates; a
+    batch whose every pair lands on one held expert is computed exactly
+    (through ``T k / block`` blocks); one with no pair on a held expert
     gives exactly nothing."""
     rs = np.random.RandomState(1)
-    T, D, I, H, k = 24, 16, 8, 3, 2
+    T, k, D, I, H = DROPLESS_T, DROPLESS_K, 16, 8, 3
+    block = _dropless_blocks()[block]
     x = jnp.asarray(rs.normal(size=(T, D)), jnp.float32)
     w_gu = jnp.asarray(rs.normal(size=(H, D, 2 * I)) * 0.3, jnp.float32)
     w_d = jnp.asarray(rs.normal(size=(H, I, D)) * 0.3, jnp.float32)
     gates = jnp.asarray(rs.uniform(0.1, 1.0, size=(T, k)), jnp.float32)
-    if case == "all_on_one_held":
-        local = jnp.ones((T, k), jnp.int32)        # every pair: expert 1
+    cot = jnp.asarray(rs.normal(size=(T, D)), jnp.float32)
+    local = _dropless_routing(rs, routing, block, H)
+
+    def run(fn, *static):
+        def out_and_loss(x, w_gu, w_d, gates):
+            out = fn(x, w_gu, w_d, local, gates, *static)
+            return jnp.sum(out * cot), out
+        (_, out), grads = jax.value_and_grad(
+            out_and_loss, argnums=(0, 1, 2, 3), has_aux=True)(
+                x, w_gu, w_d, gates)
+        return (out,) + grads
+
+    want = run(moe.experts_dense)
+    got = run(moe.experts_grouped, block)
+    if routing == "all_on_one_held":
         h = x @ w_gu[1]
-        want = (jax.nn.silu(h[:, :I]) * h[:, I:]) @ w_d[1] \
-            * jnp.sum(gates, axis=-1, keepdims=True)
-    else:
-        local = jnp.asarray(rs.choice([-3, -1, H, H + 5], size=(T, k)),
-                            jnp.int32)
-        want = jnp.zeros((T, D))
-    for fn in (moe.experts_grouped, moe.experts_dense):
-        got = fn(x, w_gu, w_d, local, gates)
-        assert got.shape == (T, D)
-        np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+        np.testing.assert_allclose(
+            want[0], (jax.nn.silu(h[:, :I]) * h[:, I:]) @ w_d[1]
+            * jnp.sum(gates, axis=-1, keepdims=True), atol=1e-5, rtol=0)
+    if routing == "none_held":
+        assert not np.any(np.asarray(got[0]))
+    for name, g, w in zip(("out", "d_x", "d_w_gate_up", "d_w_down",
+                           "d_gates"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        # float32 on both sides, another order of summation: a weight's
+        # gradient sums over up to 4096 rows, so relative to its size
+        scale = max(1.0, float(jnp.max(jnp.abs(w))))
+        np.testing.assert_allclose(g, w, atol=1e-5 * scale, rtol=0,
+                                   err_msg=name)
 
 
 def test_ppo_iteration_through_the_launcher(tmp_path):
@@ -306,6 +362,8 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
     # every expert is held at the tiny size: all pairs are computed here
     assert row["moe_pairs_here"] == row["moe_pairs_total"] == 2 * 24 * 2 * 2
     assert row["moe_load_max"] >= row["moe_load_mean"] > 0
+    # 48 tokens a minibatch: the dense form, which has no blocks
+    assert row["moe_block_rows"] == row["moe_blocks_max"] == 0
     before = kept["before"]["backbone"]
     after = kept["trainer"].state.params["backbone"]
     moved = np.max(np.abs(np.asarray(after["layers"]["attn"]["q_proj"][
@@ -314,6 +372,41 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
     np.testing.assert_array_equal(        # the selection bias is held
         after["layers"]["mlp"]["e_score_correction_bias"],
         before["layers"]["mlp"]["e_score_correction_bias"])
+
+
+@pytest.mark.parametrize("bound", ["from_shapes", "one_tile"])
+def test_block_counters_through_the_launcher(bound, tmp_path, monkeypatch):
+    """The grouped form's counters reach the metrics row: the rows of a
+    block over the layers of a minibatch's forward, and the most blocks
+    a layer ran.  All 8 experts are held, so from the shapes a block is
+    all 96 pairs of a minibatch and one block runs; with the bound
+    forced to one row tile (of 16 rows) the same pairs take six, and
+    are all computed as before."""
+    from orion_tpu import launch
+    from orion_tpu.ops.pallas import grouped_matmul
+
+    # one device, as on the chip (the suite's 8 would take the dense
+    # form): the update, the experience forwards and the prefill take
+    # the grouped form (interpreted); decode steps of 4 tokens stay dense
+    real = launch.make_mesh
+    monkeypatch.setattr(launch, "make_mesh", lambda cfg, devices=None: real(
+        cfg, jax.devices()[:1]))
+    monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 8)
+    if bound == "one_tile":
+        monkeypatch.setattr(moe, "BLOCK_ROWS_OVER_EVEN", 0)
+        monkeypatch.setattr(grouped_matmul, "TILE_ROWS", 16)
+    row = launch.main([
+        "ppo", "model_preset=tiny_deepseek_v3", "model.remat=true",
+        "model.scan_layers=true", "share_backbone=true",
+        "model.max_seq_len=24", "rollout.max_prompt_len=16",
+        "rollout.max_new_tokens=8", "rollout_batch_size=4",
+        "minibatch_size=2", "num_epochs=1", "data.dataset=synthetic",
+        "reward=length", "total_iterations=1", f"log_dir={tmp_path}"])[-1]
+    assert np.isfinite(row["loss"])
+    assert row["moe_pairs_here"] == row["moe_pairs_total"] == 2 * 24 * 2 * 2
+    rows, blocks = (16, 6) if bound == "one_tile" else (96, 1)
+    assert row["moe_block_rows"] == 2 * rows          # two expert layers
+    assert row["moe_blocks_max"] == blocks
 
 
 @pytest.mark.parametrize("algo", ["grpo", "rloo", "online_dpo"])
