@@ -343,10 +343,11 @@ def check_training(run: dict, iterations: int, shape: Shape,
         "flash_calls_in_update": run["flash_calls_in_update"],
         "warmup_and_compile_s": round(run["warmup_s"], 2),
         "steady_s": round(run["steady_s"], 2),
-        "iter_host_experience_s": [round(h["host_experience_s"], 3)
-                                   for h in hist],
-        "iter_host_update_dispatch_s": [
-            round(h["host_update_dispatch_s"], 3) for h in hist],
+        # each row's account of its wall (trainers/base.py,
+        # _finalize_iteration)
+        **{f"rows_{k}": [round(h[k], 4) for h in hist]
+           for k in ("iter_s", "fetch_wait_s", "fetch_copy_s",
+                     "host_cpu_s", "host_gc_s")},
         "compiles_warmup": run["compiles_warmup"],
         "compiles_steady": run["compiles_steady"],
         "peak_hbm_bytes": device_memory("peak_bytes_in_use"),

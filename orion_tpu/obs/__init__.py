@@ -18,6 +18,9 @@ tree reports through:
 - :mod:`flightrec` — the last ``ring_size`` events dumped to
   ``<log_dir>/flightrec-<ts>.json`` on unhandled exception,
   degradation-ladder transitions, or SIGUSR1.
+- :mod:`gcwatch` — the interpreter's garbage collections: counted per
+  thread always (the metrics row's ``host_gc_s``), a ``host.gc`` span
+  when they are the expensive kind.
 
 Module-global convenience mirrors ``resilience.inject``: one process
 tracer + one flight recorder, armed by ``TrainConfig.obs``
@@ -37,6 +40,7 @@ import os
 from typing import Any, Dict, Optional
 
 from orion_tpu.obs.flightrec import FlightRecorder  # noqa: F401
+from orion_tpu.obs.gcwatch import GcWatch
 from orion_tpu.obs.telemetry import (  # noqa: F401
     RequestTelemetry,
     TokenBucket,
@@ -98,6 +102,29 @@ def timed(name: str, **attrs) -> Span:
 
 def instant(name: str, parent: int = 0, **attrs) -> None:
     _TRACER.instant(name, parent=parent, **attrs)
+
+
+# ---------------------------------------------------------------------------
+# the process's one gc.callbacks hook
+# ---------------------------------------------------------------------------
+
+_GC = GcWatch(get_tracer)
+
+
+def install_gc_watch():
+    """Count this process's garbage collections (and make the
+    generation-2 ones ``host.gc`` spans) for as long as the returned
+    handle is held: ``handle.uninstall()`` is idempotent, several
+    holders share ONE hook, the last to let go removes it
+    (:mod:`gcwatch`)."""
+    return _GC.install()
+
+
+def gc_totals():
+    """``(count, seconds)`` of the collections the calling thread has
+    run under the hook, all generations: a clock to take differences
+    of; it stands still while no hook is installed."""
+    return _GC.totals()
 
 
 # ---------------------------------------------------------------------------

@@ -35,19 +35,30 @@ Design constraints, in order:
    is what shares the device's clock.  A span that opens before a
    session starts, or closes after it stops, is not in the xplane.
 
-What a span costs (measured on this repo's CPU sandbox, python 3.12,
-jax 0.9.0, best of three loops of 2 x 10^6 ``with obs.span("x", a=1):
-pass``, less an empty call): tracer off and no session 0.54 µs (the
-parent commit's singleton path read 0.56 µs in the same loop: the
-``with`` statement and the keyword dict are the cost, the atomic load
-is 0.02-0.04 µs of it); ``obs.timed`` 1.1 µs (it always reads the
-clock); under a recording session 1.9 µs per span (the ``TraceMe``);
-with the ring on 2.1 µs.  tests/test_obs.py holds the off path under
-1 µs.
+5. **Wall is not work.**  A span that measures reads the thread's CPU
+   clock (``time.thread_time``) beside the monotonic one, at both ends:
+   ``Span.cpu`` beside ``Span.duration``, ``"cpu"`` on the ring event
+   (``tdur`` in the Chrome export), ``cpu_us`` on the profiler
+   annotation.  A span that waits on the device, sleeps or sits in a
+   dispatch that blocks burns none; a garbage collection or a spinning
+   wait burns all of its wall; wall that is neither a named wait nor
+   CPU is time the thread was off the processor (ISSUE 36).
+
+What a span costs (this repo's CPU sandbox, python 3.12, jax 0.9.0,
+best of three loops of 2 x 10^5 ``with t.span("x", a=1): pass`` in the
+thread's CPU time, less an empty call; the commit before the CPU clock
+in brackets, same loop, same machine): tracer off and no session
+0.375 µs [0.374] — the ``with`` statement and the keyword dict; the
+atomic load is 0.02-0.04 µs of it, and no clock is read; ``timed``
+2.2 µs [1.4] (it always reads both clocks: a ``thread_time`` read is a
+system call, 0.4 µs, a ``monotonic`` read 0.13 µs); with the ring on
+4.0 µs [3.3]; under a recording session one ``TraceMe`` more (1.9 µs in
+PR 25's loop).  tests/test_obs.py holds the off path under 1 µs of the
+thread's CPU time.
 
 Timestamps are dual: Chrome ``ts`` uses the wall clock (epoch µs) so
 independently-dumped processes align on one timeline; durations come
-from the monotonic clock (immune to NTP steps).  This module is the
+from the monotonic clock (immune to NTP steps).  This package is the
 one place in the tree allowed to read raw clocks for timing — the
 ``naked-timer`` analysis rule routes everyone else through spans.
 """
@@ -79,8 +90,9 @@ def _gen_trace_id() -> int:
 
 class _NullSpan:
     """The shared disabled-path span: no clock reads, no allocation.
-    ``duration``/``elapsed`` report 0.0 — callers that need a real
-    measurement even with tracing off use :meth:`Tracer.timed`."""
+    ``duration``/``elapsed`` report 0.0 (and there is no ``cpu``) —
+    callers that need a real measurement even with tracing off use
+    :meth:`Tracer.timed`."""
 
     __slots__ = ()
     duration = 0.0
@@ -108,10 +120,12 @@ class Span:
     on a disabled tracer) still measures — the duration feeds metrics
     rows — but touches neither the ring nor the context stack.  Either
     way the span is also a profiler annotation while a session records
-    (module docstring, point 4)."""
+    (module docstring, point 4), and measures on two clocks: ``duration``
+    on the monotonic one, ``cpu`` on the thread's own (point 5)."""
 
     __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id",
-                 "duration", "_tracer", "_record", "_t0", "_wall", "_ann")
+                 "duration", "cpu", "_tracer", "_record", "_t0", "_c0",
+                 "_wall", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any],
                  record: bool):
@@ -123,7 +137,9 @@ class Span:
         self.span_id = 0
         self.parent_id = 0
         self.duration = 0.0
+        self.cpu = 0.0
         self._t0 = 0.0
+        self._c0 = 0.0
         self._wall = 0.0
         self._ann = None
 
@@ -139,11 +155,14 @@ class Span:
             stack.append(self)
         self._wall = time.time()
         self._t0 = time.monotonic()
+        self._c0 = time.thread_time()   # inside the wall: cpu <= duration
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self.cpu = time.thread_time() - self._c0
         self.duration = time.monotonic() - self._t0
         if self._ann is not None:
+            self._ann.set_metadata(cpu_us=round(self.cpu * 1e6))
             self._ann.__exit__(exc_type, exc, tb)
         if self._record:
             stack = self._tracer._stack()
@@ -154,7 +173,8 @@ class Span:
                 attrs = dict(attrs, error=exc_type.__name__)
             self._tracer._emit({
                 "name": self.name, "ph": "X", "wall": self._wall,
-                "dur": self.duration, "trace": self.trace_id,
+                "dur": self.duration, "cpu": self.cpu,
+                "trace": self.trace_id,
                 "span": self.span_id, "parent": self.parent_id,
                 "tid": threading.get_ident() & 0x7FFFFFFF,
                 "attrs": attrs,
@@ -176,6 +196,13 @@ class Span:
     def end(self) -> float:
         """Monotonic stamp of ``__exit__`` (``start + duration``)."""
         return self._t0 + self.duration
+
+    @property
+    def cpu_start(self) -> float:
+        """The thread's CPU clock at ``__enter__``: the difference
+        between the ``cpu_start`` of two spans OF ONE THREAD is the CPU
+        time that thread burnt between them."""
+        return self._c0
 
     def set(self, **attrs) -> None:
         """Attributes known only once the work is done (counts, bytes):
@@ -231,9 +258,9 @@ class Tracer:
         return _NULL_SPAN
 
     def timed(self, name: str, **attrs) -> Span:
-        """A span that ALWAYS measures (``.duration``/``.elapsed``)
-        and records only when enabled — for durations that feed
-        metrics rows regardless of tracing."""
+        """A span that ALWAYS measures (``.duration``/``.elapsed``,
+        ``.cpu``) and records only when enabled — for durations that
+        feed metrics rows regardless of tracing."""
         return Span(self, name, attrs, record=self.enabled)
 
     def instant(self, name: str, parent: int = 0, **attrs) -> None:
@@ -296,6 +323,7 @@ class Tracer:
             }
             if ev["ph"] == "X":
                 e["dur"] = ev["dur"] * 1e6
+                e["tdur"] = ev["cpu"] * 1e6   # thread-clock duration
             else:
                 e["s"] = "t"  # thread-scoped instant
             out.append(e)

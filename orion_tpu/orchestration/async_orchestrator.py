@@ -693,7 +693,6 @@ class AsyncOrchestrator:
                     result = GenerationResult(**item.result_host)
                     experience, exp_stats = trainer.build_experience(
                         result, item.scores)
-                    upd_start = sp_it.elapsed()
                     with obs.span("learner.update"):
                         stats = trainer.update_epochs(experience)
                     trainer.global_iter += 1
@@ -719,11 +718,6 @@ class AsyncOrchestrator:
                         "iteration": it,
                         "staleness": self._version - 1 - item.version,
                         "time_learner_wait_s": t_wait,
-                        # host time from the update's dispatch to the
-                        # end of the iteration (same key as the sync
-                        # loop; here it holds the stats fetch and the
-                        # weight hand-over too — not a device time)
-                        "host_update_dispatch_s": t_done - upd_start,
                         "samples_per_sec": n_samples / max(t_done, 1e-9),
                     })
                     stats.update(self._recovery_stats(degraded))
@@ -1115,7 +1109,6 @@ class PoolOrchestrator:
                     result = GenerationResult(**item.result_host)
                     experience, exp_stats = trainer.build_experience(
                         result, item.scores)
-                    upd_start = sp_it.elapsed()
                     with obs.span("learner.update"):
                         stats = trainer.update_epochs(experience)
                     trainer.global_iter += 1
@@ -1136,11 +1129,6 @@ class PoolOrchestrator:
                         "worker": float(wid),
                         "staleness": self._version - 1 - item.version,
                         "time_learner_wait_s": t_wait,
-                        # host time from the update's dispatch to the
-                        # end of the iteration (same key as the sync
-                        # loop; here it holds the stats fetch and the
-                        # weight hand-over too — not a device time)
-                        "host_update_dispatch_s": t_done - upd_start,
                         "samples_per_sec": n_samples / max(t_done, 1e-9),
                     })
                     stats.update(self._recovery_stats(degraded))

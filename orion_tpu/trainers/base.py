@@ -14,6 +14,7 @@ minibatching, the jitted update step, and logging.  Do NOT override
 
 from __future__ import annotations
 
+import resource
 from typing import Any, Callable, Dict, Iterator, Optional
 
 import flax.struct
@@ -161,6 +162,13 @@ def _device_free_bytes(tree) -> Optional[int]:
     return min(free) if free else None
 
 
+def _stamp(span, gc_totals: tuple) -> tuple:
+    """(wall, this thread's CPU clock, its collector's seconds) at the
+    START of ``span``, ``gc_totals`` being ``obs.gc_totals()`` read
+    there: the three clocks the trainer loop stamps an iteration on."""
+    return span.start, span.cpu_start, gc_totals[1]
+
+
 def state_out_shardings(state: "TrainState"):
     """``out_shardings`` for a donated TrainState: mesh-sharded leaves
     keep the layout they came in with; the rest stay unspecified.
@@ -297,6 +305,7 @@ class BaseTrainer:
         self._defer_stats = False
         self._pending_fetch = None
         self._pending_meta = None
+        self._fetch_s = (0.0, 0.0)   # (fetch.wait, fetch.copy) last taken
         self._rng = jax.random.key(cfg.seed)
         self._np_rng = np.random.RandomState(cfg.seed)
         self._jit_logprobs = jax.jit(
@@ -342,9 +351,12 @@ class BaseTrainer:
         # Observability (orion_tpu.obs): cfg.obs.trace arms the span
         # tracer (+ flight recorder, dumping into log_dir) for this
         # process; close() releases it like the recompile sentinel.
-        from orion_tpu.obs import install_from_config as _obs_install
+        from orion_tpu import obs as _obs
 
-        self._obs = _obs_install(cfg)
+        self._obs = _obs.install_from_config(cfg)
+        # The collector's hook is there whether or not obs.trace is on:
+        # every metrics row carries host_gc_s (obs/gcwatch.py).
+        self._gc_watch = _obs.install_gc_watch()
         # Opt-in runtime guards (orion_tpu.analysis.runtime_guards):
         # recompile sentinel installs here; the transfer guard wraps
         # the train() loop body.
@@ -355,8 +367,9 @@ class BaseTrainer:
     def close(self) -> None:
         """Release process-global hooks (the recompile sentinel's log
         handler + jax_log_compiles flag, the obs tracer/flight
-        recorder) and close the metrics writer — THE trainer/
-        orchestrator exit path for every sink.  Idempotent; also runs
+        recorder, the collector's hook) and close the metrics writer —
+        THE trainer/orchestrator exit path for every sink.  Idempotent;
+        also runs
         from __del__ so sweep scripts constructing many trainers don't
         accumulate handlers, but an explicit close() is the reliable
         path."""
@@ -364,10 +377,13 @@ class BaseTrainer:
         if sentinel is not None:
             sentinel.uninstall()
             self._recompile_sentinel = None
-        obs_session = getattr(self, "_obs", None)
-        if obs_session is not None:
-            obs_session.uninstall()
-            self._obs = None
+        # the hook first: a collection it reports wants the tracer that
+        # was there while it ran
+        for attr in ("_gc_watch", "_obs"):
+            held = getattr(self, attr, None)
+            if held is not None:
+                held.uninstall()
+                setattr(self, attr, None)
         writer = getattr(self, "writer", None)
         if writer is not None:
             writer.close()
@@ -641,8 +657,8 @@ class BaseTrainer:
         from orion_tpu import obs
 
         # Each span is named for what it is: a *dispatch* is the host's
-        # enqueue of asynchronous device work, the *fetch* is the one
-        # place this thread blocks on the device.
+        # enqueue of asynchronous device work, the *fetch* (_fetch) is
+        # the one place this thread blocks on the device.
         with obs.span("rollout.dispatch") as sp:
             ids, lens, meta = self.prepare_prompts(batch)
             sp.set(batch=int(ids.shape[0]), prompt_len=int(ids.shape[1]),
@@ -650,10 +666,7 @@ class BaseTrainer:
             result = self.generate(
                 ids, lens, group_size=getattr(self.cfg, "group_size", 1))
         pend, self._pending_fetch = self._pending_fetch, None
-        with obs.span("rollout.fetch") as sp:
-            fetched = jax.device_get({"r": result._fields(), "p": pend})
-            sp.set(bytes=sum(int(getattr(x, "nbytes", 0))
-                             for x in jax.tree.leaves(fetched)))
+        fetched = self._fetch({"r": result._fields(), "p": pend})
         if self._pending_meta is not None:
             # Finalize the previous iteration NOW — before this
             # iteration's build_experience reads kl_ctl.value — so the
@@ -661,11 +674,47 @@ class BaseTrainer:
             # i+1's rewards are shaped, exactly like the eager path.
             meta_p, self._pending_meta = self._pending_meta, None
             self._finalize_iteration(meta_p, fetched["p"],
-                                     now=meta_p["t_next"])
+                                     end=meta_p["end"])
         host = GenerationResult(**fetched["r"])
         scores = self._score_result(result, host, meta)
         with obs.span("experience.dispatch"):
             return self.build_experience(result, scores, host=host)
+
+    def _fetch(self, tree: dict):
+        """``jax.device_get(tree)`` of ``{"r": the rollout's result,
+        "p": the pending update's statistics or None}`` as the span
+        ``rollout.fetch``, the one place this thread blocks on the
+        device, with the thread's time in it told apart: ``fetch.wait``
+        ends when the rollout's results are ready on the device
+        (``update_ready_us``: how far into it the pending statistics
+        were; 0 without any), ``fetch.copy`` runs from there until the
+        tree is numpy on the host.  Still ONE batched transfer: every
+        leaf's host copy is started first (the calls return at once),
+        as ``device_get`` does inside, so the statistics' copies overlap
+        the rollout and nothing is enqueued behind the wait.  The
+        device is idle for the copy's length every iteration: nothing
+        is dispatched before it returns."""
+        from orion_tpu import obs
+
+        with obs.span("rollout.fetch") as sp:
+            with obs.timed("fetch.wait") as sp_wait:
+                for x in jax.tree.leaves(tree):
+                    start = getattr(x, "copy_to_host_async", None)
+                    if start is not None:
+                        start()
+                update_ready = 0.0
+                if tree["p"] is not None:
+                    jax.block_until_ready(tree["p"])
+                    update_ready = sp_wait.elapsed()
+                jax.block_until_ready(tree["r"])
+                sp_wait.set(update_ready_us=round(update_ready * 1e6))
+            with obs.timed("fetch.copy") as sp_copy:
+                fetched = jax.device_get(tree)
+                nbytes = sum(int(getattr(x, "nbytes", 0))
+                             for x in jax.tree.leaves(fetched))
+            sp.set(bytes=nbytes)
+        self._fetch_s = (sp_wait.duration, sp_copy.duration)
+        return fetched
 
     def _epochs_fn(self, state: TrainState, experience, idx_mat):
         """All epochs×minibatches as ONE program: lax.scan threads the
@@ -731,7 +780,9 @@ class BaseTrainer:
         if self._remat_keep:
             program.clear_cache()    # traced with nothing kept
         info.update(
-            remat_kept=",".join(self._remat_keep), remat_budget_bytes=budget,
+            # "+" and no comma: the profiler encodes a span's attributes
+            # as name#k=v,k=v# and cuts a value at its first comma
+            remat_kept="+".join(self._remat_keep), remat_budget_bytes=budget,
             remat_kept_bytes=sum(b for t, b in tags
                                  if t in self._remat_keep))
 
@@ -947,6 +998,7 @@ class BaseTrainer:
 
         pending = None
         self._defer_stats = True
+        usage = resource.getrusage(resource.RUSAGE_THREAD)
         try:
             for it in range(n):
                 # Preemption (resilience.preemption): the in-flight
@@ -966,14 +1018,19 @@ class BaseTrainer:
                 prof.step(it)
                 # Every stamp of a metrics row is the start or the end
                 # of a span (obs.timed measures with tracing off), so
-                # all differences are taken on one clock.  The batch
+                # all differences are taken on one clock.  An iteration
+                # is stamped on three: the wall, this thread's CPU time
+                # and its collector's seconds (_stamp).  The batch
                 # fetch is a sibling BEFORE train.iteration, not its
                 # child: whoever starts or stops a profiler from inside
                 # the iterator then cuts this small span, and the
                 # window holds whole train.iteration spans.
+                gc_begin = obs.gc_totals()
                 with obs.timed("data.next_batch", it=it) as sp_data:
                     batch = next(prompt_iter)
-                with obs.span("train.iteration", it=it):
+                begin = _stamp(sp_data, gc_begin)
+                with obs.span("train.iteration", it=it) as sp_it:
+                    gc_it = obs.gc_totals()
                     if pending is not None:
                         self._pending_fetch = pending["dev"]
                         # steady-state wall attribution: iteration i
@@ -982,27 +1039,26 @@ class BaseTrainer:
                         # iteration right after the batched fetch
                         # (before build_experience reads the KL
                         # coefficient).
-                        pending["t_next"] = sp_data.start
+                        pending["end"] = begin
                         self._pending_meta = pending
                         pending = None
                     with guard_scope(self.cfg.transfer_guard), \
                             jax.named_scope("experience"), \
-                            obs.timed("experience", it=it) as sp_exp:
+                            obs.timed("experience", it=it):
                         experience, exp_stats = self.make_experience(batch)
                     with guard_scope(self.cfg.transfer_guard), \
                             jax.named_scope("update"), \
                             obs.span("update", it=it) as sp_upd:
                         upd_dev = self.update_epochs(experience, defer=True)
                         sp_upd.set(**(self._remat_info or {}))
-                    with obs.timed("weight_sync") as sp_sync:
+                    with obs.timed("weight_sync"):
                         self.sync_weights()
                     self.global_iter += 1
                     pending = {
                         "dev": {"exp": exp_stats, "upd": upd_dev},
                         "n": int(experience["prompt_lens"].shape[0]),
                         "it": it, "giter": self.global_iter,
-                        "t0": sp_data.start, "t1": sp_exp.end,
-                        "t2": sp_sync.end,
+                        "begin": begin, "fetch_s": self._fetch_s,
                     }
                     # Held-out eval on schedule (generates with the
                     # freshest weights — sync_weights already ran).
@@ -1031,6 +1087,18 @@ class BaseTrainer:
                     if do_ckpt:
                         self.save_checkpoint(prompt_iter,
                                              eval_iter=eval_iter)
+                    # What the wall of a long iteration went to, beside
+                    # the span's own cpu_us: the collector (all
+                    # generations, this thread), and the kernel's count
+                    # of this thread being taken off the CPU and of its
+                    # page faults that went to disk.
+                    was, usage = usage, resource.getrusage(
+                        resource.RUSAGE_THREAD)
+                    gc_n, gc_s = obs.gc_totals()
+                    sp_it.set(gc_us=round((gc_s - gc_it[1]) * 1e6),
+                              gc_n=gc_n - gc_it[0],
+                              nivcsw=usage.ru_nivcsw - was.ru_nivcsw,
+                              majflt=usage.ru_majflt - was.ru_majflt)
             if pending is not None:  # flush the last iteration's stats
                 fetched = jax.device_get(pending["dev"])
                 self._finalize_iteration(pending, fetched)
@@ -1080,39 +1148,48 @@ class BaseTrainer:
             self.writer.write(self.global_iter, stats)
 
     def _finalize_iteration(self, pending: dict, fetched: dict,
-                            now: Optional[float] = None) -> None:
+                            end: Optional[tuple] = None) -> None:
         """Materialize a deferred iteration's stats (host side): merge
         experience + update stats, run the KL-controller hook, log.
-        ``samples_per_sec`` uses wall-clock up to *now* — in steady
-        state that is the next iteration's start, i.e. the honest
+        The iteration runs from ``pending["begin"]`` to ``end``, both
+        :func:`_stamp` s — in steady state ``end`` is the next
+        iteration's beginning, i.e. ``samples_per_sec`` is the honest
         end-to-end rate including the deferred update's device
         execution; a flush (the stats were just fetched) passes none
-        and the rate runs up to this call.  ``now`` and the stamps in
-        ``pending`` are starts and ends of obs spans: one clock.
+        and the iteration runs up to this call.
 
-        ``host_experience_s`` / ``host_update_dispatch_s`` are HOST
-        times around asynchronous dispatch, named for that: the first
-        holds the batch fetch and the blocking generation fetch (during
-        which the previous update still runs on the device), the second
-        is the update's and the weight sync's enqueue.  Neither is a
-        phase's device time; that is read from a profiler trace."""
+        The row accounts for the iteration's wall ``iter_s`` (what
+        ``samples_per_sec`` divides by): ``fetch_wait_s`` the thread
+        waited for the device (the previous update and this rollout),
+        ``fetch_copy_s`` it took the results to the host with the
+        device idle, ``host_cpu_s`` is this thread's CPU time over the
+        iteration (the host's WORK: a dispatch that blocks is wall and
+        not this) of which ``host_gc_s`` went to the collector.  What
+        is left of a long iteration the thread neither worked nor
+        waited on a named thing.  A phase's device time is read from a
+        profiler trace."""
         from orion_tpu import obs
 
         def scal(v):
             return float(np.mean(v)) if hasattr(v, "ndim") else v
 
         with obs.timed("stats.finalize", it=pending["it"]) as sp:
-            if now is None:
-                now = sp.start
+            if end is None:
+                end = _stamp(sp, obs.gc_totals())
             stats = {k: scal(v) for k, v in fetched["upd"].items()}
             stats.update({k: scal(v) for k, v in fetched["exp"].items()})
             self._on_host_stats(stats, pending["n"])
+            iter_s, host_cpu_s, host_gc_s = (
+                b - a for a, b in zip(pending["begin"], end))
+            iter_s = max(iter_s, 1e-9)
             stats.update({
                 "iteration": pending["it"],
-                "host_experience_s": pending["t1"] - pending["t0"],
-                "host_update_dispatch_s": pending["t2"] - pending["t1"],
-                "samples_per_sec":
-                    pending["n"] / max(now - pending["t0"], 1e-9),
+                "iter_s": iter_s,
+                "fetch_wait_s": pending["fetch_s"][0],
+                "fetch_copy_s": pending["fetch_s"][1],
+                "host_cpu_s": host_cpu_s,
+                "host_gc_s": host_gc_s,
+                "samples_per_sec": pending["n"] / iter_s,
                 **(self._remat_info or {}),
             })
             sp.set(**{k: v for k, v in stats.items()

@@ -6,6 +6,7 @@ continuous engine's request telemetry, the disabled-tracing overhead
 budget, and (ISSUE 25) the bridge to the profiler: every span is also a
 ``jax.profiler.TraceAnnotation`` while a profiler session records."""
 
+import gc
 import glob
 import json
 import os
@@ -165,6 +166,9 @@ def test_disabled_span_reaches_the_profiler_trace(tmp_path):
     assert th_o == th_i == th_l                     # one thread's line
     assert o0 <= i0 and i1 <= l0 and l1 <= o1       # nested, in order
     assert i1 - i0 >= 1e6                           # the 2 ms sleep, in ns
+    # every span carries its thread's CPU time beside its attributes
+    assert 0 <= o_stats.pop("cpu_us") <= (o1 - o0) / 1e3 + 100
+    assert 0 <= i_stats.pop("cpu_us") < 1000        # it slept its 2 ms
     assert o_stats == {"it": 3, "tag": "abc"}
     assert i_stats == {"rows": 2, "bytes": 1234}
 
@@ -178,6 +182,7 @@ def test_enabled_span_is_in_the_ring_and_in_the_profiler_trace(tmp_path):
     assert ring["inner"]["attrs"] == {"rows": 2, "bytes": 1234}
     assert set(events) >= {"outer", "inner", "lap"}
     (_, _, _, stats), = events["inner"]
+    assert stats.pop("cpu_us") == round(ring["inner"]["cpu"] * 1e6)
     assert stats == {"rows": 2, "bytes": 1234}
     # the two records agree on the duration (different clocks, one scope)
     (_, i0, i1, _), = events["inner"]
@@ -186,24 +191,40 @@ def test_enabled_span_is_in_the_ring_and_in_the_profiler_trace(tmp_path):
 
 def test_no_session_no_ring_event_and_the_off_path_budget():
     """Tracer off and nothing recording: no event anywhere, and a span
-    costs under the microsecond that obs/trace.py's docstring states
-    (best of five loops; the machine is shared)."""
+    costs under the microsecond that obs/trace.py's docstring states.
+    The budget is about instructions, so the loop is measured in the
+    thread's CPU time (a neighbour's load is not the span's cost), in
+    fifty windows of 10^4 (the 5 x 10^5 spans it always ran), and a
+    window in which the kernel took the thread off the CPU (its caches
+    come back cold) does not count while any other is there: the suite
+    runs under six workers on a shared machine."""
+    import resource
+
+    def preempted():
+        return resource.getrusage(resource.RUSAGE_THREAD).ru_nivcsw
+
     assert not jax.profiler.TraceAnnotation.is_enabled()
     prev = obs.set_tracer(None)     # the disabled default
     try:
         t = obs.get_tracer()
         assert not t.enabled
-        n = 100_000
-        best = float("inf")
-        for _ in range(5):
+        n = 10_000
+        quiet, every = [], []
+        for _ in range(50):
+            before = preempted()
             sp = obs.timed("budget-window")
             with sp:
                 for _ in range(n):
                     with obs.span("x", a=1):
                         pass
-            best = min(best, sp.duration / n)
+            every.append(sp.cpu / n)
+            if preempted() == before:
+                quiet.append(sp.cpu / n)
+        best = min(quiet or every)
+        print(f"off-path span: {best * 1e6:.3f} us of thread CPU "
+              f"({len(quiet)} of 50 windows undisturbed)")
         assert t.events() == []
-        assert best < 1e-6, best
+        assert best < 1e-6, (best, len(quiet))
     finally:
         obs.set_tracer(prev)
 
@@ -217,6 +238,131 @@ def test_span_start_and_end_share_one_clock():
         pass
     assert a.end == pytest.approx(a.start + a.duration)
     assert a.start < a.end <= b.start <= b.end
+
+
+# ---------------------------------------------------------------------------
+# the thread's CPU clock on every measuring span (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+_CPU_RESOLUTION = time.get_clock_info("thread_time").resolution + 1e-6
+
+
+def _sleep():
+    time.sleep(0.05)
+
+
+def _spin():
+    # until the thread has BURNT 50 ms: under six workers on a shared
+    # machine a loop of 50 ms of wall got 25 ms of the CPU
+    end = time.thread_time() + 0.05
+    while time.thread_time() < end:
+        pass
+
+
+@pytest.mark.parametrize("enabled", [False, True], ids=["timed", "ring"])
+@pytest.mark.parametrize("body, burns", [(_sleep, False), (_spin, True)],
+                         ids=["sleep", "spin"])
+def test_a_measuring_span_reads_the_threads_cpu_clock(body, burns, enabled):
+    """Wall is not work: ~0 across a sleep, all that a busy loop burnt
+    across it, never more than the wall."""
+    t = Tracer(ring_size=4, enabled=enabled)
+    with t.timed("x") as sp:
+        body()
+    assert 0.0 <= sp.cpu <= sp.duration + _CPU_RESOLUTION
+    if burns:
+        assert sp.cpu >= 0.05
+    else:
+        assert sp.cpu < 0.01
+    if enabled:
+        ev, = t.events()
+        assert ev["cpu"] == sp.cpu and ev["dur"] == sp.duration
+        chrome = [e for e in t.chrome_events() if e["ph"] == "X"]
+        assert chrome[0]["tdur"] == pytest.approx(sp.cpu * 1e6)
+    else:
+        # off and nothing recording: still the shared no-op, no clock read
+        assert t.span("x") is t.span("y")
+        assert not hasattr(t.span("x"), "cpu")
+
+
+# ---------------------------------------------------------------------------
+# the collector's hook (ISSUE 36): counted always, gen 2 is a span
+# ---------------------------------------------------------------------------
+
+
+def _gc_hooks():
+    return [cb for cb in gc.callbacks
+            if getattr(cb, "__self__", None) is obs._GC]
+
+
+def test_a_full_collection_is_a_span_in_the_ring_and_in_the_xplane(tmp_path):
+    """A forced ``gc.collect()`` under a recording profiler session:
+    one ``host.gc`` with ``gen`` 2, nested in the span it interrupted,
+    in both records; young collections are counted and are no spans."""
+    t = Tracer(ring_size=64, enabled=True)
+    prev = obs.set_tracer(t)
+    hold = obs.install_gc_watch()
+    gc.disable()           # the two collections below and no other
+    n0, s0 = obs.gc_totals()
+
+    def body():
+        with obs.span("outer"):
+            gc.collect(0)
+            gc.collect()
+    try:
+        events = _profiled(tmp_path, body)
+        n1, s1 = obs.gc_totals()
+    finally:
+        gc.enable()
+        hold.uninstall()
+        obs.set_tracer(prev)
+    assert n1 - n0 == 2 and s1 > s0      # both counted, one a span
+    ring = {e["name"]: e for e in t.events()}
+    assert [e["name"] for e in t.events()] == ["host.gc", "outer"]
+    assert ring["host.gc"]["parent"] == ring["outer"]["span"]
+    assert ring["host.gc"]["attrs"]["gen"] == 2
+    assert ring["host.gc"]["attrs"]["collected"] >= 0
+    # a collection burns the CPU for its whole length
+    assert ring["host.gc"]["cpu"] <= ring["host.gc"]["dur"] + _CPU_RESOLUTION
+    (th_g, g0, g1, g_stats), = events["host.gc"]
+    (th_o, o0, o1, _), = events["outer"]
+    assert th_g == th_o and o0 <= g0 and g1 <= o1
+    assert g_stats["gen"] == 2 and {"collected", "cpu_us"} <= set(g_stats)
+    by_gen = obs._GC.stats()
+    assert by_gen[2][0] >= 1 and by_gen[2][2] <= by_gen[2][1]
+    assert by_gen[0][0] >= 1
+
+
+def test_the_gc_hook_is_one_and_goes_with_the_last_trainer():
+    """Installed at construction whether or not ``obs.trace`` is on,
+    not doubled by a second trainer, gone after the last ``close()``,
+    which is idempotent; ``gc.callbacks`` is left as it was found."""
+    before = list(gc.callbacks)
+    assert _gc_hooks() == []
+    cfg = _mk(GRPOConfig, group_size=2, kl_coef=0.0, num_epochs=1,
+              minibatch_size=4)
+    assert not cfg.obs.trace
+    model = Transformer(cfg.model)
+    params = init_params(model, jax.random.key(0), cfg.model)
+    a = GRPOTrainer(cfg, model, params, reward_fn=lucky_token_reward,
+                    eos_token_id=None)
+    b = GRPOTrainer(cfg, model, params, reward_fn=lucky_token_reward,
+                    eos_token_id=None)
+    try:
+        assert len(_gc_hooks()) == 1
+        a.close()
+        a.close()
+        assert len(_gc_hooks()) == 1     # b still holds it
+        n0, _ = obs.gc_totals()
+        gc.collect()
+        assert obs.gc_totals()[0] >= n0 + 1
+    finally:
+        a.close()
+        b.close()
+    assert _gc_hooks() == [] and list(gc.callbacks) == before
+    n0, _ = obs.gc_totals()
+    gc.collect()
+    assert obs.gc_totals()[0] == n0      # the clock stands still
+    b.close()
 
 
 def test_session_end_writes_the_ring_once(tmp_path):
@@ -574,7 +720,8 @@ def test_disabled_tracing_overhead_budget():
     """Tracing disabled ⇒ the instrumented serve loop pays effectively
     nothing: the no-op span path is so cheap that thousands of times
     the loop's actual obs touchpoints still fit inside 1% of its
-    wall-clock."""
+    wall-clock.  The touchpoints' cost is the thread's CPU time over
+    the loop (a neighbour's load is not the span's cost)."""
     t = obs.get_tracer()
     assert not t.enabled  # the default process tracer is off
     mc, eng = _tiny_engine(max_new=32, slots=4)
@@ -600,7 +747,7 @@ def test_disabled_tracing_overhead_budget():
             with obs.span("x", a=1):
                 pass
             obs.instant("y", b=2)
-    per_call = sp.duration / (2 * n)
+    per_call = sp.cpu / (2 * n)
     # Upper bound on obs touchpoints inside one measured serve(): one
     # engine.step span per wave (~n_req*32/seg/slots ≈ 32 waves) +
     # ~5 lifecycle instants per request ≈ 112 — bound at 4x that.

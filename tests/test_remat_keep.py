@@ -250,7 +250,7 @@ def test_the_choice_follows_the_free_bytes_and_is_on_the_span(monkeypatch):
     assert 0 < need < 1 << 30           # a tiny update's temporaries
     for attrs, row in zip(spans, hist):
         assert attrs["remat_kept"] == row["remat_kept"] \
-            == "attn_resid,mlp_pre,attn_out,attn_qkv"
+            == "attn_resid+mlp_pre+attn_out+attn_qkv"
         assert attrs["remat_kept_bytes"] == sum(b for _, b in tags)
         assert attrs["remat_budget_bytes"] == budget
     assert all(np.isfinite(r["loss"]) for r in hist)
@@ -269,3 +269,42 @@ def test_the_choice_follows_the_free_bytes_and_is_on_the_span(monkeypatch):
     _, spans = _update_spans(trainer)
     assert trainer.update_traces == [()] and spans[1]["remat_kept"] == ""
     assert spans[1]["remat_budget_bytes"] == 0
+
+
+def test_the_kept_names_survive_the_xplane(monkeypatch, tmp_path):
+    """The profiler writes a span's attributes as ``name#k=v,k=v#`` and
+    cuts a value at its first comma: the kept tags are joined with
+    ``+``, so the ``update`` span read back from a session's xplane by
+    the benchmark's ``host_spans`` holds every name (ISSUE 36)."""
+    import glob
+    import importlib.util
+    import os
+
+    from orion_tpu.trainers import base
+
+    monkeypatch.setattr(base, "_device_free_bytes", lambda tree: 1 << 40)
+    trainer = _ppo()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        hist = trainer.train(prompt_stream(4, 4), num_iterations=2)
+    finally:
+        jax.profiler.stop_trace()
+        trainer.close()
+    kept = trainer._remat_keep
+    assert len(kept) > 1 and hist[0]["remat_kept"] == "+".join(kept)
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    spec = importlib.util.spec_from_file_location(
+        "host_spans_for_remat", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmarks", "host_spans.py"))
+    hs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(hs)
+    updates = [sp for _, spans in hs.load(path).threads for sp in spans
+               if sp.name == "update"]
+    assert len(updates) == 2
+    for sp in updates:
+        assert tuple(sp.stats["remat_kept"].split("+")) == kept
+        assert int(sp.stats["remat_kept_bytes"]) == hist[0]["remat_kept_bytes"]

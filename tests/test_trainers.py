@@ -120,3 +120,145 @@ def test_ppo_kl_penalty_restrains_drift():
                     reward_fn=lucky_token_reward)
     hist = tr.train(prompt_stream(8, 5), num_iterations=4)
     assert abs(hist[-1]["kl"]) < 1.0
+
+
+# ---------------------------------------------------------------------------
+# the iteration accounts for its wall (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+ROW_ACCOUNT = ("iter_s", "fetch_wait_s", "fetch_copy_s", "host_cpu_s",
+               "host_gc_s")
+
+
+def _traced_grpo_run(iterations=4):
+    """(metrics rows, the ring's events) of a tiny GRPO run with the
+    ring on."""
+    from orion_tpu import obs
+
+    cfg = _mk(GRPOConfig, group_size=4, kl_coef=0.0, num_epochs=1)
+    model, params = _policy()
+    tr = GRPOTrainer(cfg, model, params, reward_fn=lucky_token_reward)
+    tracer = obs.Tracer(ring_size=1024, enabled=True)
+    prev = obs.set_tracer(tracer)
+    try:
+        hist = tr.train(prompt_stream(4, 5), num_iterations=iterations)
+    finally:
+        obs.set_tracer(prev)
+        tr.close()
+    return hist, tracer.events()
+
+
+def test_the_fetch_tells_waiting_from_copying():
+    """``fetch.wait`` and ``fetch.copy`` are the children of
+    ``rollout.fetch`` and cover it; the pending update's statistics are
+    ready inside the wait."""
+    hist, events = _traced_grpo_run()
+    fetches = [e for e in events if e["name"] == "rollout.fetch"]
+    assert len(fetches) == len(hist) == 4
+    uncovered = []
+    for i, fetch in enumerate(fetches):
+        wait, = [e for e in events if e["name"] == "fetch.wait"
+                 and e["parent"] == fetch["span"]]
+        copy, = [e for e in events if e["name"] == "fetch.copy"
+                 and e["parent"] == fetch["span"]]
+        parts = wait["dur"] + copy["dur"]
+        assert parts <= fetch["dur"]
+        uncovered.append(fetch["dur"] - 1.01 * parts)
+        ready = wait["attrs"]["update_ready_us"]
+        assert 0 <= ready <= wait["dur"] * 1e6
+        # nothing is pending in the first iteration
+        assert (ready == 0) == (i == 0)
+        assert fetch["attrs"]["bytes"] > 0
+        # the row has what the spans measured
+        assert hist[i]["fetch_wait_s"] == wait["dur"]
+        assert hist[i]["fetch_copy_s"] == copy["dur"]
+    # the two cover the fetch to 1% + 50 us (the median of four: on a
+    # shared machine the thread can lose the CPU between two spans)
+    assert sorted(uncovered)[len(uncovered) // 2 - 1] <= 50e-6, uncovered
+
+
+def test_a_row_accounts_for_its_iteration():
+    hist, events = _traced_grpo_run()
+    its = [e for e in events if e["name"] == "train.iteration"]
+    batches = [e for e in events if e["name"] == "data.next_batch"]
+    assert len(its) == len(batches) == len(hist) == 4
+    for row, it, batch in zip(hist, its, batches):
+        assert set(ROW_ACCOUNT) <= set(row)
+        assert not {"host_experience_s", "host_update_dispatch_s"} & set(row)
+        assert row["samples_per_sec"] == pytest.approx(16 / row["iter_s"])
+        # an iteration's wall holds its parts
+        assert row["fetch_wait_s"] + row["fetch_copy_s"] <= row["iter_s"]
+        assert 0.0 <= row["host_gc_s"] <= row["host_cpu_s"] + 1e-3
+        assert row["host_cpu_s"] <= row["iter_s"] + 1e-3
+        # the span's own account: the thread's CPU time, the collector,
+        # the kernel's counters
+        assert 0.0 < it["cpu"] <= it["dur"] + 1e-3
+        assert {"gc_us", "gc_n", "nivcsw", "majflt"} <= set(it["attrs"])
+        assert it["attrs"]["gc_us"] <= row["host_gc_s"] * 1e6 + 1
+    # in steady state the row's CPU time runs from the batch fetch to the
+    # next one: the spans' own, and the little between them
+    for row, it, batch in list(zip(hist, its, batches))[1:-1]:
+        assert row["host_cpu_s"] >= it["cpu"] + batch["cpu"] - 1e-4
+
+
+def test_the_fetch_is_one_device_get(monkeypatch):
+    """The split fetch returns what ``jax.device_get`` of the tree
+    returns, bit for bit, through ONE batched ``device_get``, with every
+    leaf's host copy started before anything blocks."""
+    import jax.numpy as jnp
+
+    cfg = _mk(GRPOConfig, group_size=4)
+    model, params = _policy()
+    tr = GRPOTrainer(cfg, model, params, reward_fn=lucky_token_reward)
+    k1, k2, k3 = jax.random.split(jax.random.key(3), 3)
+    tree = {"r": {"sequences": jax.random.randint(k1, (8, 13), 0, VOCAB),
+                  "logprobs": jax.random.normal(k2, (8, 8), jnp.float32),
+                  "lens": np.arange(8)},
+            "p": {"upd": {"loss": jax.random.normal(k3, (2,), jnp.bfloat16)},
+                  "exp": {"n": 3, "kl": jnp.float32(0.25)}}}
+    calls = []
+    real_get, real_block = jax.device_get, jax.block_until_ready
+    monkeypatch.setattr(jax, "device_get",
+                        lambda t: calls.append("get") or real_get(t))
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda t: calls.append("block") or real_block(t))
+    try:
+        for t in (tree, dict(tree, p=None)):
+            del calls[:]
+            got = tr._fetch(t)
+            assert calls == ["block"] * (1 + (t["p"] is not None)) + ["get"]
+            ref = real_get(t)
+            assert jax.tree.structure(got) == jax.tree.structure(ref)
+            for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+                assert type(a) is type(b)
+                if isinstance(a, np.ndarray):
+                    assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+                else:
+                    assert a == b
+        assert tr._fetch_s[0] > 0 and tr._fetch_s[1] > 0
+
+        # the order of what a leaf is asked: the overlap of the pending
+        # statistics' copies with the rollout rests on it
+        log = []
+
+        class Leaf:
+            def __init__(self, name):
+                self.name = name
+
+            def copy_to_host_async(self):
+                log.append(("start", self.name))
+
+            def block_until_ready(self):
+                log.append(("block", self.name))
+                return self
+
+            def __array__(self, *a, **k):
+                log.append(("take", self.name))
+                return np.zeros(2)
+
+        tr._fetch({"r": {"a": Leaf("r.a")}, "p": {"b": Leaf("p.b")}})
+        assert sorted(log[:2]) == [("start", "p.b"), ("start", "r.a")]
+        assert log[2:4] == [("block", "p.b"), ("block", "r.a")]
+        assert {e[0] for e in log[4:]} == {"start", "take"}
+    finally:
+        tr.close()
