@@ -10,7 +10,20 @@ and ``q_t, k_t`` [dk], ``v_t`` [dv]::
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
     o_t = S_t^T q_t
 
-- :func:`kda_step`: one token (decode); the three lines above.
+- :func:`kda_step`: one token (decode); the three lines above.  Two
+  forms, chosen at trace time by :func:`step_form`: where the trace is
+  for a TPU the Pallas kernel of ``ops/pallas/kda_step.py``, which holds
+  a head's state in VMEM, so that it is read from the HBM once and
+  written once a step, into the buffer it came in (as ``jax.numpy`` the
+  prediction, a reduction over the whole tile that the update needs
+  before it can write any of it, makes two passes of it, and the decode
+  loop a copy); everywhere else (the CPU) the ``jax.numpy`` lines of
+  :func:`kda_step`, which the kernel is tested against.  In both every
+  product with the state is an elementwise float32 product and every sum
+  a float32 sum, never a matrix product: a product with one row would
+  round the state to bfloat16 on its way into the MXU, and a rounded
+  state stays rounded.  The kernel takes heads of any size and either
+  width of ``g`` as they are (full-dimension blocks: no padding).
 - :func:`kda_chunked`: a whole sequence (training, the experience
   forwards, prefill), chunk by chunk, differentiable.  One algorithm in
   two forms, chosen at trace time by :func:`chunk_form`: where the
@@ -82,9 +95,18 @@ def kda_step(q, k, v, g, beta, state):
     [B, H, dv]; beta [B, H]; state [B, H, dk, dv] float32 -> (o
     [B, H, dv] float32, new state).
 
-    Elementwise products and sums in float32: a step reads and writes
-    the state once, and a matrix product with one row would round the
-    state on its way into the MXU."""
+    Where :func:`step_form` says ``"kernel"`` (a trace for a TPU) the
+    step is ``ops/pallas/kda_step.py``: the state crosses the HBM twice,
+    in place.  Else the lines below, which cross it three times and more
+    and are what the kernel is tested against.  Elementwise products and
+    sums in float32 in both: a matrix product with one row would round
+    the state on its way into the MXU."""
+    if step_form(q.shape[-1], v.shape[-1]) == "kernel":
+        from orion_tpu.ops.pallas.kda_step import kda_step_kernel
+
+        return _kernel_on_mesh(
+            kda_step_kernel, (q, k, v, g, beta, state),
+            heads_at=(1, 1, 1, 1, 1, 1), out_heads_at=(1, 1))
     f32 = jnp.float32
     q, k, v, g, beta = (t.astype(f32) for t in (q, k, v, g, beta))
     state = jnp.exp(g)[..., None] * state
@@ -190,6 +212,19 @@ def chunk_form(dk: int, dv: int) -> str:
     return "kernel" if target_platform() == "tpu" else "jnp"
 
 
+def step_form(dk: int, dv: int) -> str:
+    """Which form one token's step takes in a trace here: ``"kernel"``
+    (ops/pallas/kda_step.py) where the trace is for a TPU, whatever the
+    head sizes (the kernel's blocks span a head's full dimensions: 128 x
+    128 and 96 x 192 both ran faster than the ``jax.numpy`` step on a
+    v5e, PERF.md section 6, PR 37), ``"jnp"`` everywhere else.  Asked at
+    trace time beside :func:`chunk_form`; the trainer reports the answer
+    on its ``rollout.dispatch`` span."""
+    from orion_tpu.ops.pallas import target_platform
+
+    return "kernel" if target_platform() == "tpu" else "jnp"
+
+
 def _to_lane_tiles(q, k, v, g, state):
     """The kernels' operands for heads of any size: zero key channels up
     to the next multiple of 128 and zero value columns likewise, one
@@ -213,25 +248,22 @@ def _to_lane_tiles(q, k, v, g, state):
     return last(q, pk), last(k, pk), last(v, pv), g, state
 
 
-def _kernel_on_mesh(q, k, v, g, beta, state, chunk):
-    """The kernels under whatever mesh is ambient, as
+def _kernel_on_mesh(run, operands, heads_at, out_heads_at):
+    """A kernel of this rule under whatever mesh is ambient, as
     ``ops.attention._flash_on_mesh``: a Mosaic kernel cannot be
     partitioned automatically, so under a mesh of several devices it
-    runs in a ``shard_map`` over the batch (data, fsdp) and the heads
-    (tensor), each where it divides.  Rows and heads are independent:
-    no collective."""
+    runs in a ``shard_map`` over the batch (data, fsdp; every operand's
+    and result's first dimension) and the heads (tensor; the dimension
+    ``heads_at`` / ``out_heads_at`` names for each), each where it
+    divides.  Rows and heads are independent: no collective."""
     import math
 
-    from orion_tpu.ops.pallas.kda_chunk import kda_chunk_kernel
     from orion_tpu.parallel.sharding import ambient_mesh
-
-    def run(*args):
-        return kda_chunk_kernel(*args, chunk)
 
     mesh = ambient_mesh()
     if (mesh.empty or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):
-        return run(q, k, v, g, beta, state)
+        return run(*operands)
     from jax.sharding import PartitionSpec as P
 
     from orion_tpu.utils.platform import shard_map
@@ -239,13 +271,28 @@ def _kernel_on_mesh(q, k, v, g, beta, state, chunk):
     shape = dict(mesh.shape)
     batch = tuple(a for a in ("data", "fsdp") if shape.get(a, 1) > 1)
     n_batch = math.prod(shape[a] for a in batch)
-    b = batch if batch and q.shape[0] % n_batch == 0 else None
+    b = batch if batch and operands[0].shape[0] % n_batch == 0 else None
     tp = shape.get("tensor", 1)
-    h = "tensor" if tp > 1 and q.shape[2] % tp == 0 else None
-    seq, st = P(b, None, h, None), P(b, h, None, None)
+    h = ("tensor" if tp > 1 and operands[0].shape[heads_at[0]] % tp == 0
+         else None)
+
+    def spec(at):
+        return P(b, *(h if i == at else None for i in range(1, at + 1)))
+
     return shard_map(
-        run, mesh=mesh, in_specs=(seq, seq, seq, seq, P(b, None, h), st),
-        out_specs=(seq, st), check_vma=False)(q, k, v, g, beta, state)
+        run, mesh=mesh, in_specs=tuple(map(spec, heads_at)),
+        out_specs=tuple(map(spec, out_heads_at)),
+        check_vma=False)(*operands)
+
+
+def _chunk_kernel_on_mesh(q, k, v, g, beta, state, chunk):
+    """The chunked form's kernels (sequences [B, L, H, d]: heads third,
+    the state's second) under the ambient mesh."""
+    from orion_tpu.ops.pallas.kda_chunk import kda_chunk_kernel
+
+    return _kernel_on_mesh(
+        lambda *a: kda_chunk_kernel(*a, chunk), (q, k, v, g, beta, state),
+        heads_at=(2, 2, 2, 2, 2, 1), out_heads_at=(2, 1))
 
 
 def kda_chunked(q, k, v, g, beta, state: Optional[jax.Array] = None,
@@ -262,9 +309,10 @@ def kda_chunked(q, k, v, g, beta, state: Optional[jax.Array] = None,
     if chunk_form(dk, dv) == "kernel":
         if dk % LANES or dv % LANES or g.shape[-1] == 1:
             q, k, v, g, padded = _to_lane_tiles(q, k, v, g, state)
-            o, padded = _kernel_on_mesh(q, k, v, g, beta, padded, chunk)
+            o, padded = _chunk_kernel_on_mesh(q, k, v, g, beta, padded,
+                                              chunk)
             return o[..., :dv], padded[:, :, :dk, :dv]
-        return _kernel_on_mesh(q, k, v, g, beta, state, chunk)
+        return _chunk_kernel_on_mesh(q, k, v, g, beta, state, chunk)
     n = -(-L // chunk)
     pad = n * chunk - L
 

@@ -543,15 +543,27 @@ class BaseTrainer:
         """What a decode step of the fixed-batch engine touches for this
         batch, from shapes: ``cache_bytes`` (what is indexed by
         position: keys and values, or latents), ``state_bytes`` (what is
-        not: recurrent states, read and written whole a step) and
-        ``weight_bytes`` (the decode copy of the weights).  All 0 for
-        the continuous engine, whose pool is its own."""
+        not: recurrent states, read and written whole a step),
+        ``weight_bytes`` (the decode copy of the weights) and
+        ``kda_step``, the form the delta rule's one-token step takes
+        there (``kernel`` / ``jnp``, from ``ops/kda.py::step_form``;
+        ``""`` without such a layer).  All 0 and ``""`` for the
+        continuous engine, whose pool is its own and which refuses
+        recurrent models."""
         eng = self.engine
         if not hasattr(eng, "state_bytes"):
-            return {"cache_bytes": 0, "state_bytes": 0, "weight_bytes": 0}
+            return {"cache_bytes": 0, "state_bytes": 0, "weight_bytes": 0,
+                    "kda_step": ""}
+        mc, kda_step = self.cfg.model, ""
+        if mc.recurrent:
+            # the form the recurrent layers' one-token step takes in
+            # this process's traces (the fixed-batch engine's decode)
+            from orion_tpu.ops.kda import step_form
+            kda_step = step_form(*mc.delta_head_dims())
         return {"cache_bytes": eng.cache_bytes(*prompts_shape),
                 "state_bytes": eng.state_bytes(*prompts_shape),
-                "weight_bytes": eng.weight_bytes(self.state.params)}
+                "weight_bytes": eng.weight_bytes(self.state.params),
+                "kda_step": kda_step}
 
     def _score_result(self, result, host, meta) -> np.ndarray:
         """One place for the device-vs-host reward dispatch (the

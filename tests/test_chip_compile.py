@@ -91,6 +91,48 @@ def _kernel_names(compiled) -> list:
             compiled.as_text(), re.M))
 
 
+def _state_copies(text: str, shape, moves: bool = False) -> list:
+    """The instructions of a compiled program (or of one computation's
+    lines) that copy a float32 array of ``shape`` from the HBM to the
+    HBM: ``copy``, and a ``copy-start`` neither side of which lies in
+    the memory space ``S(1)``.  XLA's memory-space assignment may move
+    a buffer into or out of ``S(1)`` around its use (a ``copy-start``
+    with ONE side there): a crossing of the HBM that the use then does
+    not make, not one more; ``moves`` counts those too."""
+    import re
+
+    dims = ",".join(map(str, shape))
+    array = r"f32\[%s\]\{[^}]*\}" % dims
+    found = []
+    for ln in text.splitlines():
+        m = re.search(r"= \((%s), (%s), [^)]*\) copy-start\(" % (
+            array, array), ln)
+        if m:
+            if moves or ("S(1)" in m.group(1)) == ("S(1)" in m.group(2)):
+                found.append(ln.strip()[:160])
+        elif re.search(r"= %s copy\(" % array, ln):
+            found.append(ln.strip()[:160])
+    return found
+
+
+def _while_bodies(text: str) -> dict:
+    """{computation name: its lines} of every ``while`` body of a
+    compiled program's text."""
+    import re
+
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", ln)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(ln)
+    return {b: "\n".join(comps[b]) for b in re.findall(
+        r" while\(.*body=%?([\w.\-]+)", text)}
+
+
 # (B, Lq, Lk, H, Hkv, D, first q position)
 FLASH_SHAPES = {
     # the ppo1b update/experience shape (chip_smoke.py, ppo1b-sync)
@@ -452,11 +494,16 @@ def test_delta_rule_compiles_for_v5e(one_chip, on_tpu, H, dk, dv, one_decay):
     a head: padded to 128 x 256 around the call): the chunked form with
     its backward at a minibatch of 16 x 1024 (the two kernels of
     ops/pallas/kda_chunk.py, through Mosaic) and one decode step over a
-    batch of 32 (no kernel)."""
-    from orion_tpu.ops.kda import chunk_form, kda_chunked, kda_step
+    batch of 32: exactly one ``kda_step`` kernel (ops/pallas/
+    kda_step.py, at the heads' own sizes: nothing padded), its state
+    operand aliased to its state result."""
+    import re
+
+    from orion_tpu.ops.kda import (chunk_form, kda_chunked, kda_step,
+                                   step_form)
 
     B, L = 16, 1024
-    assert chunk_form(dk, dv) == "kernel"
+    assert chunk_form(dk, dv) == step_form(dk, dv) == "kernel"
     gd = 1 if one_decay else dk
 
     def loss(q, k, v, g, beta):
@@ -470,17 +517,90 @@ def test_delta_rule_compiles_for_v5e(one_chip, on_tpu, H, dk, dv, one_decay):
             _sds((B, L, H, dv), BF16, one_chip),
             _sds((B, L, H, gd), jnp.float32, one_chip),
             _sds((B, L, H), jnp.float32, one_chip)).compile()
-        step = jax.jit(kda_step).lower(
+        step = jax.jit(kda_step, donate_argnums=(5,)).lower(
             *(_sds((32, H, dk), BF16, one_chip),) * 2,
             _sds((32, H, dv), BF16, one_chip),
             _sds((32, H, gd), jnp.float32, one_chip),
             _sds((32, H), jnp.float32, one_chip),
             _sds((32, H, dk, dv), jnp.float32, one_chip)).compile()
     assert _kernel_names(chunked) == ["kda_chunk_bwd", "kda_chunk_fwd"]
-    assert _kernel_calls(step) == 0
+    assert _kernel_names(step) == ["kda_step"]
+    assert _kernel_calls(step) == 1
+    text = step.as_text()
+    # the kernel writes the new state into the old one's buffer, the
+    # program hands the donated argument's buffer back, and nothing
+    # copies a state on the way
+    assert re.search(r"%kda_step[.\d]* = .*output_to_operand_aliasing="
+                     r"\{\{1\}: \(6, \{\}\)\}", text), text[-3000:]
+    assert re.search(r"input_output_alias=\{[^\n]*\(5, \{\}", text)
+    assert not _state_copies(text, (32, H, dk, dv))
     # what the backward holds of one layer: the states at the 16 chunk
     # boundaries and the inputs, not the chunks' insides
     assert chunked.memory_analysis().peak_memory_in_bytes < 4 * 2**30
+
+
+@pytest.mark.parametrize("name,states", [("kimi_linear", 2),
+                                         ("olmo_hybrid", 3)])
+def test_delta_rule_decode_carries_one_state_buffer_a_layer(
+        one_chip, on_tpu, name, states):
+    """The fixed-batch engine's whole program (prefill + the decode
+    ``while_loop``) of ``ppo-kimi-linear-ep32-sync`` (three of its
+    layers: two KDA, one latent) and ``ppo-olmo-hybrid-vp8-sync`` (one
+    period: three GDN, one full attention) at 32 x 512 + 512: the decode
+    loop's body holds one ``kda_step`` kernel a recurrent layer, each
+    writing its state into the buffer it read (the loop carries one
+    buffer a layer), and no copy of a whole state from the HBM to the
+    HBM."""
+    import dataclasses
+    import re
+
+    from orion_tpu.config import ModelConfig, RolloutConfig
+    from orion_tpu.models import Transformer, init_params
+    from orion_tpu.rollout.engine import RolloutEngine
+
+    if name == "kimi_linear":
+        mc = dataclasses.replace(
+            ModelConfig.kimi_linear_48b_a3b(), num_layers=3,
+            kda_layers=(1, 2), experts_held=8, vocab_size=20480,
+            max_seq_len=1024)
+    else:
+        mc = dataclasses.replace(ModelConfig.olmo_hybrid_7b(), num_layers=4,
+                                 vocab_size=12544, max_seq_len=1024)
+    H = mc.kda_num_heads if name == "kimi_linear" \
+        else mc.linear_num_key_heads
+    state_shape = (32, H) + tuple(mc.delta_head_dims())
+    model = Transformer(mc)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: init_params(model, jax.random.key(0), mc)))
+    eng = RolloutEngine(model, mc, RolloutConfig(
+        max_prompt_len=512, max_new_tokens=512), eos_token_id=0,
+        pad_token_id=0)
+    assert eng.state_bytes(32, 512) >= states * 4 * int(np.prod(state_shape))
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    with jax.default_matmul_precision("default"):
+        compiled = eng._generate_jit.lower(
+            params, _sds((32, 512), jnp.int32, one_chip),
+            _sds((32,), jnp.int32, one_chip),
+            _sds(rng.shape, rng.dtype, one_chip),
+            max_new_tokens=512).compile()
+    text = compiled.as_text()
+    assert _kernel_names(compiled).count("kda_step") == states
+    decode = [body for body in _while_bodies(text).values()
+              if "%kda_step" in body]
+    assert len(decode) == 1                     # the decode loop's body
+    steps = re.findall(r"%kda_step[.\d]* = [^\n]*", decode[0])
+    assert len(steps) == states
+    for call in steps:
+        assert "f32[%s]" % ",".join(map(str, state_shape)) in call
+        assert "output_to_operand_aliasing={{1}: (6, {})}" in call
+    # no copy of a state; at Olmo-Hybrid's three states of 94 MB (as the
+    # HBM's tiles hold 96 x 192) XLA's memory-space assignment moves
+    # states into and out of ``S(1)`` around the kernels, as it did
+    # around the ``jax.numpy`` step: Kimi's loop has none of those either
+    assert not _state_copies(decode[0], state_shape)
+    if name == "kimi_linear":
+        assert not _state_copies(decode[0], state_shape, moves=True)
 
 
 def test_kimi_linear_update_compiles_for_v5e(one_chip, on_tpu):

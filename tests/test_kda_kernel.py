@@ -279,3 +279,154 @@ def test_heads_of_96_by_192_run_the_kernels_padded(padded_kernels, case):
                                   S0)
         _close(o[:, :real], o_short, 1e-5, "o")
         _close(S, S_short, 1e-5, "state")
+
+
+# -- one token: the step kernel (ops/pallas/kda_step.py) ---------------------
+
+def _step_inputs(B, heads, dk, dv, one_decay, seed=0):
+    """One decode position as the mixers hand it over: q, k, v in
+    bfloat16, g (a channel or a head) and beta float32, a float32
+    state."""
+    rs = np.random.RandomState(seed)
+    q, k = (rs.normal(size=(B, heads, dk)) for _ in range(2))
+    q = q / np.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
+    k = k / np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rs.normal(size=(B, heads, dv))
+    g = -rs.uniform(1, 16, size=(B, heads, 1)) * np.exp(rs.uniform(
+        np.log(1e-3), np.log(1e-1), size=(B, heads, 1 if one_decay else dk)))
+    beta = (2.0 if one_decay else 1.0) / (1.0 + np.exp(-rs.normal(
+        size=(B, heads))))
+    S = rs.normal(size=(B, heads, dk, dv)) * 0.1
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    return [jnp.asarray(x, t) for x, t in zip(
+        (q, k, v, g, beta, S), (bf16, bf16, bf16, f32, f32, f32))]
+
+
+@functools.lru_cache(maxsize=None)
+def _step_kernel():
+    from orion_tpu.ops.pallas import kda_step
+
+    return jax.jit(kda_step.kda_step_kernel)
+
+
+STEP_HEADS = {"kimi": (32, 128, 128, False), "olmo": (30, 96, 192, True)}
+
+
+@pytest.mark.parametrize("case", [
+    "kimi_heads", "olmo_heads", "inert_kimi", "inert_olmo", "chained_kimi",
+    "chained_olmo", "mantissa_kimi", "mantissa_olmo", "few_heads"])
+def test_the_step_kernel_is_the_jnp_step(case):
+    """The kernel interpreted here against the ``jax.numpy`` lines of
+    ``kda.kda_step`` (the form the CPU takes), at both models' heads: 32
+    of 128 x 128 with a decay a channel, 30 of 96 x 192 with one a
+    head."""
+    name = case.split("_")[1]
+    heads, dk, dv, one = STEP_HEADS.get(name, (4, 16, 24, False))
+    assert kda.step_form(dk, dv) == "jnp"          # the CPU's own form
+    step = _step_kernel()
+    if case.endswith("_heads"):
+        args = _step_inputs(2, heads, dk, dv, one, seed=3)
+        (o, S), (want_o, want_S) = step(*args), kda.kda_step(*args)
+        assert o.dtype == S.dtype == jnp.float32
+        assert o.shape == (2, heads, dv) and S.shape == (2, heads, dk, dv)
+        _close(o, want_o, 1e-6, "o")
+        _close(S, want_S, 1e-6, "state")
+    elif case.startswith("inert"):
+        # g = 0, beta = 0 on one row: its state comes back bit for bit
+        q, k, v, g, beta, S = _step_inputs(2, heads, dk, dv, one, seed=4)
+        g, beta = g.at[1].set(0.0), beta.at[1].set(0.0)
+        _, S1 = step(q, k, v, g, beta, S)
+        assert np.array_equal(np.asarray(S1[1]), np.asarray(S[1]))
+        assert not np.array_equal(np.asarray(S1[0]), np.asarray(S[0]))
+    elif case.startswith("chained"):
+        # 64 steps, one after the other, are one chunk of the chunked rule
+        L, B = 64, 1
+        rows = [_step_inputs(B, heads, dk, dv, one, seed=10 + t)
+                for t in range(L)]
+        S = S0 = rows[0][5]
+        outs = []
+        for q, k, v, g, beta, _ in rows:
+            o, S = step(q, k, v, g, beta, S)
+            outs.append(o)
+        seq = [jnp.stack([r[i] for r in rows], axis=1).astype(jnp.float32)
+               for i in range(5)]
+        want_o, want_S = kda.kda_chunked(*seq, S0)
+        _close(jnp.stack(outs, axis=1), want_o, 1e-5, "o")
+        _close(S, want_S, 1e-5, "state")
+    else:
+        # a state whose entries need 17 mantissa bits, read through a
+        # one-hot q beside an update of another row: every entry comes
+        # back exactly (a bfloat16 anywhere on the way keeps 8)
+        B = 1
+        S = 1.0 + jnp.arange(dk * dv, dtype=jnp.float32).reshape(
+            dk, dv) * 2.0 ** -17
+        S = jnp.broadcast_to(S, (B, heads, dk, dv))
+        assert not np.array_equal(
+            np.asarray(S), np.asarray(S.astype(jnp.bfloat16), np.float32))
+        q = jnp.zeros((B, heads, dk), jnp.bfloat16).at[..., 3].set(1.0)
+        k = jnp.zeros((B, heads, dk), jnp.bfloat16).at[..., 5].set(1.0)
+        v = jnp.zeros((B, heads, dv), jnp.bfloat16)
+        g = jnp.zeros((B, heads, 1 if one else dk), jnp.float32)
+        beta = jnp.full((B, heads), 0.5, jnp.float32)
+        o, S1 = step(q, k, v, g, beta, S)
+        assert np.array_equal(np.asarray(o), np.asarray(S[:, :, 3]))
+        assert np.array_equal(np.asarray(S1[:, :, 5]),
+                              np.asarray(S[:, :, 5] * 0.5))
+        keep = np.arange(dk) != 5
+        assert np.array_equal(np.asarray(S1)[:, :, keep],
+                              np.asarray(S)[:, :, keep])
+
+
+@pytest.mark.parametrize("platform,dk,dv,want", [
+    ("tpu", 128, 128, "kernel"), ("tpu", 96, 192, "kernel"),
+    ("tpu", 16, 16, "kernel"), ("cpu", 128, 128, "jnp"),
+    ("cpu", 96, 192, "jnp"), ("gpu", 128, 128, "jnp")])
+def test_the_steps_form_follows_the_platform_and_the_head_sizes(
+        monkeypatch, platform, dk, dv, want):
+    """On a TPU the kernel, whatever the head sizes (full-dimension
+    blocks: both models' heads ran faster than the ``jax.numpy`` step on
+    the chip); the ``jax.numpy`` lines everywhere else."""
+    import orion_tpu.ops.pallas as pallas
+
+    monkeypatch.setattr(pallas, "target_platform", lambda: platform)
+    assert kda.step_form(dk, dv) == want
+
+
+@pytest.mark.parametrize("heads,want", [(32, 16), (8, 8), (24, 8), (30, 30),
+                                        (2, 2)])
+def test_a_grid_step_holds_whole_sublane_tiles_of_heads(heads, want):
+    from orion_tpu.ops.pallas.kda_step import heads_per_step
+
+    assert heads_per_step(heads) == want
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2, 2)],
+                         ids=["one_device", "fsdp2_tensor2"])
+def test_kda_step_takes_the_kernel_where_the_choice_says(monkeypatch,
+                                                         mesh_shape):
+    """``kda.kda_step`` with the choice steered to the kernel
+    (interpreted here), alone and under a mesh of several devices, where
+    it runs inside the ``shard_map`` the chunk kernels run in (rows over
+    fsdp, heads over tensor): same results, each device's share."""
+    import contextlib
+
+    from orion_tpu.config import MeshConfig
+    from orion_tpu.ops.pallas import kda_step
+    from orion_tpu.parallel.mesh import make_mesh
+
+    args = _step_inputs(4, 4, 16, 24, False, seed=7)
+    want = kda.kda_step(*args)
+    calls, kernel = [], kda_step.kda_step_kernel
+    monkeypatch.setattr(kda, "step_form", lambda dk, dv: "kernel")
+    monkeypatch.setattr(
+        kda_step, "kda_step_kernel",
+        lambda *a: calls.append((a[0].shape, a[5].shape)) or kernel(*a))
+    mesh = contextlib.nullcontext() if mesh_shape is None else make_mesh(
+        MeshConfig(data=1, fsdp=mesh_shape[0], tensor=mesh_shape[1]),
+        devices=jax.devices()[:4])
+    with mesh:
+        got = jax.jit(kda.kda_step)(*args)
+    n_b, n_h = mesh_shape or (1, 1)
+    assert calls == [((4 // n_b, 4 // n_h, 16), (4 // n_b, 4 // n_h, 16, 24))]
+    for g_, w_ in zip(got, want):
+        _close(g_, w_, 1e-6)

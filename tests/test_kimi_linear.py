@@ -263,12 +263,18 @@ def test_training_forward_matches_reference_float32(tiny):
     assert float(jnp.max(jnp.abs(other - want))) > 1e-2
 
 
-@pytest.mark.parametrize("scan", [False, True])
-def test_prefill_of_unequal_prompts_then_decode_through_the_state(tiny,
-                                                                  scan):
+@pytest.mark.parametrize("scan,step", [(False, "jnp"), (True, "jnp"),
+                                       (False, "kernel")])
+def test_prefill_of_unequal_prompts_then_decode_through_the_state(
+        tiny, scan, step, monkeypatch):
     """A right-padded batch of a full-length and a short prompt, then
     one-token steps: the logits at every real position are the full
-    forward's and the reference's (logits, not tokens)."""
+    forward's and the reference's (logits, not tokens).  ``step``: the
+    form the one-token step takes (``kernel``: ops/pallas/kda_step.py,
+    interpreted here, as a TPU trace takes it)."""
+    from orion_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "step_form", lambda dk, dv: step)
     cfg, model, params, ids = tiny
     if scan:
         cfg = dataclasses.replace(cfg, scan_layers=True)
@@ -422,12 +428,17 @@ def test_eight_shares_add_up_to_the_uncut_layer():
 # through the engine and the trainer
 # ---------------------------------------------------------------------------
 
-def test_the_engine_decodes_through_the_state(tiny):
+@pytest.mark.parametrize("step", ["jnp", "kernel"])
+def test_the_engine_decodes_through_the_state(tiny, step, monkeypatch):
     """``RolloutEngine``: prompts of unequal length in one batch; the
     policy logprobs it recorded are the teacher-forced ones of the
-    reference on what it sampled."""
+    reference on what it sampled.  ``step``: the form the one-token step
+    takes inside the decode ``while_loop`` (``kernel``: interpreted
+    here)."""
+    from orion_tpu.ops import kda
     from orion_tpu.rollout import RolloutEngine
 
+    monkeypatch.setattr(kda, "step_form", lambda dk, dv: step)
     cfg, model, params, ids = tiny
     P, T = 32, 16
     eng = RolloutEngine(model, cfg, RolloutConfig(
@@ -483,10 +494,15 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
             "reward=length", "total_iterations=2",
             "optimizer.learning_rate=1e-3", "ref_param_dtype=bfloat16",
             "optimizer.mu_dtype=bfloat16", "optimizer.nu_dtype=bfloat16",
-            f"log_dir={tmp_path}"])
+            "obs.trace=true", f"log_dir={tmp_path}"])
     finally:
         launch.build_trainer = real
     assert len(hist) == 2 and all(np.isfinite(r["loss"]) for r in hist)
+    # the span says which form the one-token step takes: the CPU's
+    dispatch = _dispatch_spans(tmp_path)
+    assert len(dispatch) == 2
+    assert {sp["kda_step"] for sp in dispatch} == {"jnp"}
+    assert all(sp["state_bytes"] > 0 for sp in dispatch)
     row = hist[-1]
     # every expert is held at the tiny size; 4 expert layers of 5
     assert row["moe_pairs_here"] == row["moe_pairs_total"] == 4 * 2 * 24 * 2
@@ -508,6 +524,37 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
     sizes = trainer._rollout_bytes((4, 16))
     assert sizes["state_bytes"] > sizes["cache_bytes"] > 0
     assert sizes["weight_bytes"] > 0
+    assert sizes["kda_step"] == "jnp"            # the CPU's form
+
+
+def _dispatch_spans(log_dir) -> list:
+    """The attributes of the ``rollout.dispatch`` spans a run with
+    ``obs.trace=true`` wrote into ``log_dir``."""
+    import json
+    import os
+
+    with open(log_dir / f"spans-{os.getpid()}.json") as f:
+        events = json.load(f)["traceEvents"]
+    return [e["args"] for e in events if e["name"] == "rollout.dispatch"]
+
+
+def test_the_rollout_span_of_a_model_without_a_state_says_so(tmp_path):
+    """``rollout.dispatch`` carries ``kda_step``: the form the delta
+    rule's one-token step takes in this process's traces (``jnp`` on the
+    CPU, ``kernel`` on a TPU: the launcher test above), and ``""`` for a
+    model without such a layer."""
+    from orion_tpu import launch
+
+    launch.main([
+        "ppo", "model_preset=tiny", "share_backbone=true",
+        "model.max_seq_len=24", "rollout.max_prompt_len=16",
+        "rollout.max_new_tokens=8", "rollout_batch_size=4",
+        "minibatch_size=2", "num_epochs=1", "data.dataset=synthetic",
+        "reward=length", "total_iterations=1", "obs.trace=true",
+        f"log_dir={tmp_path}"])
+    (span,) = _dispatch_spans(tmp_path)
+    assert span["kda_step"] == "" and span["state_bytes"] == 0
+    assert span["batch"] == 4 and span["cache_bytes"] > 0
 
 
 def test_remat_tags_count_both_kinds_of_mixer():

@@ -241,14 +241,21 @@ def test_scanned_and_unrolled_layouts_agree(tiny):
     assert cache["runs"][1]["k"].shape == (1, 2, 16, 4, 16)
 
 
-def test_the_engine_decodes_through_state_and_per_head_cache(tiny):
+@pytest.mark.parametrize("step", ["jnp", "kernel"])
+def test_the_engine_decodes_through_state_and_per_head_cache(tiny, step,
+                                                             monkeypatch):
     """``RolloutEngine``: a long and a short prompt in one right-padded
     batch; prefill hands decode the state, the convolutions' last inputs
     and a per-head cache with each row's real length; the policy
     logprobs it recorded are the teacher-forced ones of the reference
-    on what it sampled."""
+    on what it sampled.  ``step``: the form the one-token step takes
+    inside the decode ``while_loop`` (``kernel``: ops/pallas/
+    kda_step.py, interpreted here, one decay a head, heads of 12 x
+    24)."""
+    from orion_tpu.ops import kda
     from orion_tpu.rollout import RolloutEngine
 
+    monkeypatch.setattr(kda, "step_form", lambda dk, dv: step)
     cfg, model, params, ids = tiny
     P, T = 32, 16
     eng = RolloutEngine(model, cfg, RolloutConfig(
@@ -315,6 +322,7 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
     sizes = kept["trainer"]._rollout_bytes((4, 16))
     assert sizes["state_bytes"] > 0 and sizes["cache_bytes"] > 0 \
         and sizes["weight_bytes"] > 0
+    assert sizes["kda_step"] == "jnp"            # the CPU's form
 
 
 def test_remat_tags_count_both_kinds_of_mixer():
