@@ -22,7 +22,10 @@ from typing import Any, Optional
 LATENT_ARCHS = ("deepseek_v3", "kimi_linear")
 #: The archs whose model is a per-layer pattern of (mixer, FFN) kinds
 #: over RMSNorm blocks (``ModelConfig.layer_kinds``).
-PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid",)
+PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa")
+#: The archs whose layers end in the dropless expert layer
+#: (``ops.moe.TopKMoE``) and so share its fields and their checks.
+EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa",)
 #: The mixers whose per-sequence state is not indexed by position.
 RECURRENT_MIXERS = ("kda", "gdn")
 #: olmo_hybrid's published ``layer_types`` entries -> mixers.
@@ -40,6 +43,7 @@ class ModelConfig:
     """
 
     # "llama" | "neox" | "deepseek_v3" | "kimi_linear" | "olmo_hybrid"
+    # | "keye_dsa"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -132,10 +136,33 @@ class ModelConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
     linear_allow_neg_eigval: bool = False
+    # arch="keye_dsa" (Keye-VL-2.0's language model): the pre-norm
+    # RMSNorm block of grouped-query attention (an RMSNorm over each
+    # head's head_dim of q and of k, full rotary) over the dropless
+    # expert layer, its attention cut to the sa_topk keys a query that a
+    # learned indexer scores highest (models/transformer.py
+    # SparseAttention, ops/indexer.py): sa_index_heads query heads of
+    # sa_index_head_dim against ONE key head (the published sa_config:
+    # indexer_num_heads, indexer_head_dim, indexer_num_kv_heads = 1,
+    # topk); sa_q_chunk / sa_kv_chunk tile the indexer's scores and
+    # change no equation.  moe_scoring: how the expert layer's router
+    # scores ("sigmoid": deepseek_v3's, with its selection bias;
+    # "softmax": a float32 softmax over all experts, no bias;
+    # norm_topk_prob true in both: gates sum to routed_scaling_factor).
+    sa_topk: int = 0
+    sa_index_heads: int = 0
+    sa_index_head_dim: int = 0
+    sa_q_chunk: int = 512
+    sa_kv_chunk: int = 512
+    moe_scoring: str = "sigmoid"
 
     def __post_init__(self) -> None:
+        if self.arch in EXPERT_ARCHS:
+            self._check_experts()
         if self.arch in LATENT_ARCHS:
             self._check_deepseek_v3()
+        if self.arch == "keye_dsa":
+            self._check_keye_dsa()
         if self.arch == "kimi_linear":
             self._check_kimi_linear()
         if self.arch == "olmo_hybrid":
@@ -147,15 +174,11 @@ class ModelConfig:
             # given — NeoX-family checkpoints exist with either value.)
             self.num_kv_heads = self.num_heads
 
-    def _check_deepseek_v3(self) -> None:
-        for key in ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
-                    "v_head_dim", "n_routed_experts", "num_experts_per_tok",
+    def _check_experts(self) -> None:
+        for key in ("n_routed_experts", "num_experts_per_tok",
                     "moe_intermediate_size"):
             if getattr(self, key) <= 0:
                 raise ValueError(f"arch={self.arch!r} needs model.{key} > 0")
-        self.num_kv_heads = self.num_heads
-        if self.head_dim == 0:
-            self.head_dim = self.qk_rope_head_dim   # as published
         if self.experts_held == 0:
             self.experts_held = self.n_routed_experts
         if not (0 <= self.expert_offset and self.expert_offset
@@ -163,6 +186,24 @@ class ModelConfig:
             raise ValueError(
                 f"experts {self.expert_offset}..+{self.experts_held} are "
                 f"not among the {self.n_routed_experts} routed experts")
+        if self.moe_scoring not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"model.moe_scoring={self.moe_scoring!r}: 'sigmoid' or "
+                "'softmax'")
+        if self.num_experts or self.quantize_dense or self.tie_word_embeddings:
+            raise ValueError(
+                f"arch={self.arch!r} has its own expert layer (num_experts "
+                "is the GShard layer's), no int8 Dense twin and an untied "
+                "head")
+
+    def _check_deepseek_v3(self) -> None:
+        for key in ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+                    "v_head_dim"):
+            if getattr(self, key) <= 0:
+                raise ValueError(f"arch={self.arch!r} needs model.{key} > 0")
+        self.num_kv_heads = self.num_heads
+        if self.head_dim == 0:
+            self.head_dim = self.qk_rope_head_dim   # as published
         if not 0 <= self.first_k_dense_replace <= self.num_layers:
             raise ValueError("first_k_dense_replace outside 0..num_layers")
         if self.attention_impl in ("ring", "ulysses"):
@@ -172,11 +213,28 @@ class ModelConfig:
                 "exchange per-head K/V of one head_dim, and there is no "
                 "exchange of the latent (c, k_rope) yet, nor a hand-over "
                 "of a recurrent state between sequence shards")
-        if self.num_experts or self.quantize_dense or self.tie_word_embeddings:
+
+    def _check_keye_dsa(self) -> None:
+        for key in ("sa_topk", "sa_index_heads", "sa_index_head_dim",
+                    "sa_q_chunk", "sa_kv_chunk"):
+            if getattr(self, key) <= 0:
+                raise ValueError(f"arch='keye_dsa' needs model.{key} > 0")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("arch='keye_dsa': num_kv_heads divides "
+                             "num_heads (grouped-query attention)")
+        if self.attention_impl in ("ring", "ulysses"):
             raise ValueError(
-                f"arch={self.arch!r} has its own expert layer (num_experts "
-                "is the GShard layer's), no int8 Dense twin and an untied "
-                "head")
+                f"attention_impl={self.attention_impl!r} cannot run "
+                "arch='keye_dsa': the sequence-parallel attentions exchange "
+                "keys and values by position and apply the causal rule "
+                "alone; there is no exchange of the indexer's keys nor a "
+                "selection across sequence shards")
+        if self.seq_shard_activations or self.first_k_dense_replace:
+            raise ValueError(
+                "arch='keye_dsa': every layer is an expert layer "
+                "(first_k_dense_replace = 0, the published "
+                "decoder_sparse_step 1 / mlp_only_layers []), and the "
+                "indexer scores whole sequences (seq_shard_activations)")
 
     def _check_kimi_linear(self) -> None:
         for key in ("kda_num_heads", "kda_head_dim",
@@ -241,7 +299,8 @@ class ModelConfig:
 
     def layer_kinds(self) -> tuple:
         """((mixer, ffn), ...) per layer: the model's description.
-        mixer: "attention" (per-head K/V cache), "latent" ({c, k_rope}
+        mixer: "attention" (per-head K/V cache), "sparse" (the same
+        beside the indexer's keys: {k, v, ki}), "latent" ({c, k_rope}
         cache), "kda" or "gdn" (a recurrent state, no position; a decay
         a key channel or one a head); ffn: "dense", "gshard"
         (num_experts) or "experts" (the dropless layer)."""
@@ -252,6 +311,8 @@ class ModelConfig:
         if self.arch == "olmo_hybrid":
             return tuple((LAYER_TYPE_MIXERS[t], "dense")
                          for t in self.layer_types[:self.num_layers])
+        if self.arch == "keye_dsa":
+            return (("sparse", "experts"),) * self.num_layers
         return tuple(
             ("kda" if i + 1 in self.kda_layers else "latent",
              "dense" if i < self.first_k_dense_replace else "experts")
@@ -363,6 +424,28 @@ class ModelConfig:
         )
 
     @staticmethod
+    def keye_vl2_30b_a3b() -> "ModelConfig":
+        """Kwai-Keye/Keye-VL-2.0-30B-A3B's language model as published
+        (config.json, model_type KeyeVL2; the vision tower is not here):
+        every expert held.  For token ids alone the three M-RoPE
+        components are equal: the ordinary rotation."""
+        return ModelConfig(
+            arch="keye_dsa", vocab_size=151936, hidden_size=2048,
+            intermediate_size=6144, num_layers=48, num_heads=32,
+            num_kv_heads=4, head_dim=128, max_seq_len=262144,
+            rope_theta=1e7, rms_norm_eps=1e-6, n_routed_experts=128,
+            num_experts_per_tok=8, moe_intermediate_size=768,
+            moe_scoring="softmax", sa_topk=2048, sa_index_heads=16,
+            sa_index_head_dim=64, sa_q_chunk=512, sa_kv_chunk=512,
+        )
+
+    @staticmethod
+    def tiny_keye_dsa() -> "ModelConfig":
+        """``model_preset=tiny_keye_dsa``: the small sibling of
+        keye_vl2_30b_a3b (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("keye_dsa")
+
+    @staticmethod
     def tiny_olmo_hybrid() -> "ModelConfig":
         """``model_preset=tiny_olmo_hybrid``: the small sibling of
         olmo_hybrid_7b (tests, CPU rehearsals)."""
@@ -383,6 +466,19 @@ class ModelConfig:
     @staticmethod
     def tiny(arch: str = "llama", **kw: Any) -> "ModelConfig":
         """Small config for tests (runs on CPU in <1s)."""
+        if arch == "keye_dsa":
+            # topk 8 of up to 128 keys; 2 query heads a key/value head
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=96, num_layers=2, num_heads=4,
+                num_kv_heads=2, head_dim=16, max_seq_len=128,
+                rope_theta=1e7, rms_norm_eps=1e-6, n_routed_experts=8,
+                num_experts_per_tok=2, moe_intermediate_size=32,
+                moe_scoring="softmax", sa_topk=8, sa_index_heads=4,
+                sa_index_head_dim=8, sa_q_chunk=16, sa_kv_chunk=16,
+            )
+            base.update(kw)
+            return ModelConfig(**base)
         if arch == "olmo_hybrid":
             # one whole period of two published; heads of (12, 24):
             # neither side a tile
@@ -763,6 +859,19 @@ class DataConfig:
     use_chat_template: bool = False
     system_prompt: Optional[str] = None
     synthetic_size: int = 512
+    # Long synthetic prompts: with synthetic_max_len > 0 every synthetic
+    # record is padded IN FRONT with printable filler bytes from the seed
+    # to a length drawn uniformly from synthetic_min_len..synthetic_max_len
+    # byte-tokenizer tokens (the bos counted; the question stays at the
+    # tail).  0 (the default) leaves the records as they always were.
+    synthetic_min_len: int = 0
+    synthetic_max_len: int = 0
+    # synthetic_vocab > 0: the filler is token IDS drawn uniformly from
+    # 4..synthetic_vocab-1 (behind the bos, before the question's bytes)
+    # instead of printable bytes: as many distinct tokens as a real
+    # prompt has, where 95 distinct bytes make every position look alike
+    # to a router.  The caller keeps it inside the model's vocabulary.
+    synthetic_vocab: int = 0
     # Directory of <dataset>.jsonl files in the upstream HF schema —
     # the offline path for real datasets on a zero-egress box.
     data_dir: Optional[str] = None

@@ -172,9 +172,18 @@ def _records_gsm8k(split: str, data_dir: Optional[str] = None) -> List[dict]:
     return out
 
 
-def _records_synthetic(n: int = 512, seed: int = 0) -> List[dict]:
+def _records_synthetic(n: int = 512, seed: int = 0,
+                       len_range: Optional[tuple] = None,
+                       filler_vocab: int = 0) -> List[dict]:
     """Arithmetic word problems with verifiable answers — exercises the
-    full GRPO pipeline (including the math verifier) fully offline."""
+    full GRPO pipeline (including the math verifier) fully offline.
+    ``len_range`` (lo, hi): each prompt is padded in front with
+    printable filler bytes to a length drawn uniformly from lo..hi
+    byte-tokenizer tokens (one a byte, and the bos), lengths and filler
+    from a stream of their own so that the questions are those of the
+    short records.  ``filler_vocab`` > 0: the filler is token ids drawn
+    uniformly from 4..filler_vocab-1 instead (``prefix_ids``, which
+    :class:`PromptIterator` puts behind the bos)."""
     rng = np.random.RandomState(seed)
     out = []
     for _ in range(n):
@@ -183,6 +192,18 @@ def _records_synthetic(n: int = 512, seed: int = 0) -> List[dict]:
         ans = {"+": a + b, "-": a - b, "*": a * b}[op]
         out.append({"prompt": f"Compute {a} {op} {b}. Answer: ",
                     "answer": str(ans)})
+    if len_range:
+        lo, hi = len_range
+        fill = np.random.RandomState((seed + 0x5A17) % (2 ** 31 - 1))
+        for rec in out:
+            n_fill = max(
+                int(fill.randint(lo, hi + 1)) - 1 - len(rec["prompt"]), 0)
+            if filler_vocab > 0:
+                rec["prefix_ids"] = fill.randint(
+                    4, filler_vocab, size=n_fill).astype(np.int32)
+            else:
+                rec["prompt"] = fill.randint(32, 127, size=n_fill).astype(
+                    np.uint8).tobytes().decode("ascii") + rec["prompt"]
     return out
 
 
@@ -196,9 +217,12 @@ _ADAPTERS: Dict[str, Callable] = {
 
 def load_prompt_records(dataset: str, split: str = "train",
                         synthetic_size: int = 512, seed: int = 0,
-                        data_dir: Optional[str] = None) -> List[dict]:
+                        data_dir: Optional[str] = None,
+                        synthetic_len_range: Optional[tuple] = None,
+                        synthetic_vocab: int = 0) -> List[dict]:
     if dataset == "synthetic":
-        return _records_synthetic(synthetic_size, seed)
+        return _records_synthetic(synthetic_size, seed, synthetic_len_range,
+                                  synthetic_vocab)
     if dataset in _ADAPTERS:
         return _ADAPTERS[dataset](split, data_dir)
     # Unknown name: treat as a HF dataset with a "prompt" column.
@@ -278,10 +302,13 @@ class PromptIterator:
         meta: Dict[str, list] = {}
         for i, rec in enumerate(take):
             toks = self._encode(rec["prompt"])
+            if "prefix_ids" in rec:          # behind the bos; keep the tail
+                toks = (toks[:1] + rec["prefix_ids"].tolist()
+                        + toks[1:])[-P:]
             ids[i, : len(toks)] = toks
             lens[i] = len(toks)
             for key, value in rec.items():
-                if key != "prompt":
+                if key not in ("prompt", "prefix_ids"):
                     meta.setdefault(key, []).append(value)
         batch = {"prompt_ids": ids, "prompt_lens": lens}
         for key, values in meta.items():
@@ -294,9 +321,12 @@ def build_prompt_iterator(dataset: str, tokenizer, batch_size: int,
                           seed: int = 0, use_chat_template: bool = False,
                           system_prompt: Optional[str] = None,
                           synthetic_size: int = 512,
-                          data_dir: Optional[str] = None) -> PromptIterator:
+                          data_dir: Optional[str] = None,
+                          synthetic_len_range: Optional[tuple] = None,
+                          synthetic_vocab: int = 0) -> PromptIterator:
     records = load_prompt_records(dataset, split, synthetic_size, seed,
-                                  data_dir)
+                                  data_dir, synthetic_len_range,
+                                  synthetic_vocab)
     return PromptIterator(records, tokenizer, batch_size, max_prompt_len,
                           seed=seed, use_chat_template=use_chat_template,
                           system_prompt=system_prompt)

@@ -81,6 +81,18 @@ ALGOS = {
 _INIT_ARGS = (jnp.zeros((1, 2), jnp.int32), jnp.zeros((1, 2), jnp.int32))
 
 
+def _len_range(data):
+    """(lo, hi) for long synthetic prompts, or None (DataConfig)."""
+    if data.synthetic_max_len <= 0:
+        return None
+    if not 0 < data.synthetic_min_len <= data.synthetic_max_len:
+        raise ValueError(
+            "data.synthetic_min_len..synthetic_max_len must be a range of "
+            f"positive lengths (got {data.synthetic_min_len}.."
+            f"{data.synthetic_max_len})")
+    return data.synthetic_min_len, data.synthetic_max_len
+
+
 def build_reward(cfg, tokenizer, mesh):
     spec = cfg.reward
     if spec == "math":
@@ -230,6 +242,8 @@ def run_pool_worker(cfg, port: int, rank: int,
         use_chat_template=cfg.data.use_chat_template,
         system_prompt=cfg.data.system_prompt,
         synthetic_size=cfg.data.synthetic_size,
+        synthetic_len_range=_len_range(cfg.data),
+        synthetic_vocab=cfg.data.synthetic_vocab,
         data_dir=cfg.data.data_dir)
     k = int(getattr(cfg, "group_size", 1))
     # SIGTERM on a worker = graceful leave (the learner sees a LEAVE,
@@ -605,6 +619,8 @@ def main(argv: Optional[list] = None) -> Any:
         use_chat_template=cfg.data.use_chat_template,
         system_prompt=cfg.data.system_prompt,
         synthetic_size=cfg.data.synthetic_size,
+        synthetic_len_range=_len_range(cfg.data),
+        synthetic_vocab=cfg.data.synthetic_vocab,
         data_dir=cfg.data.data_dir)
     eval_iter = None
     if cfg.eval_every:
@@ -624,6 +640,8 @@ def main(argv: Optional[list] = None) -> Any:
             use_chat_template=cfg.data.use_chat_template,
             system_prompt=cfg.data.system_prompt,
             synthetic_size=cfg.data.synthetic_size,
+        synthetic_len_range=_len_range(cfg.data),
+        synthetic_vocab=cfg.data.synthetic_vocab,
             data_dir=cfg.data.data_dir)
 
     if cfg.async_mode and cfg.resilience.pool_size > 0:

@@ -47,6 +47,11 @@ def hf_state_dict(params: dict, cfg: ModelConfig) -> Dict[str, np.ndarray]:
             "kimi_linear checkpoint layout on either side yet (the KDA "
             "layers' convolutions [channels, 1, taps], low-rank gates, "
             "A_log and dt_bias; per-expert tensors; kv_a/kv_b projections)")
+    if cfg.arch == "keye_dsa":
+        raise ValueError(
+            "HF export of arch='keye_dsa' is not written: there is no "
+            "KeyeVL2 checkpoint layout on either side yet (the indexer's "
+            "projections and norm, per-expert tensors, the vision tower)")
     if cfg.arch == "olmo_hybrid":
         raise ValueError(
             "HF export of arch='olmo_hybrid' is not written: there is no "

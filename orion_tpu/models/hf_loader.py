@@ -64,6 +64,11 @@ _NO_OLMO_HYBRID_LOADER = (
     "no mapping onto models.transformer.GatedDeltaNet's names and "
     "[taps, channels] layout; arch='olmo_hybrid' runs from random "
     "weights only")
+_NO_KEYE_LOADER = (
+    "there is no KeyeVL2 checkpoint loader yet: the indexer's projections "
+    "and norm, the per-expert tensors and the vision tower have no mapping "
+    "onto models.transformer.SparseAttention's and ops.moe.TopKMoE's "
+    "names; arch='keye_dsa' runs from random weights only")
 
 
 def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
@@ -78,6 +83,8 @@ def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
         raise ValueError(_NO_KIMI_LOADER)
     elif cfg.arch == "olmo_hybrid":
         raise ValueError(_NO_OLMO_HYBRID_LOADER)
+    elif cfg.arch == "keye_dsa":
+        raise ValueError(_NO_KEYE_LOADER)
     else:
         raise ValueError(cfg.arch)
     if not include_lm_head:
@@ -236,6 +243,8 @@ def config_from_hf(hf_cfg: Any) -> ModelConfig:
         raise ValueError(_NO_KIMI_LOADER)
     if mt == "olmo_hybrid":
         raise ValueError(_NO_OLMO_HYBRID_LOADER)
+    if mt in ("KeyeVL2", "keye_vl2"):
+        raise ValueError(_NO_KEYE_LOADER)
     if mt == "llama":
         return ModelConfig(
             arch="llama",

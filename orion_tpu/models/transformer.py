@@ -10,7 +10,7 @@ requires (SURVEY.md §2 #14):
 - ``arch="deepseek_v3"``: pre-norm RMSNorm, latent attention
   (:class:`LatentAttention`), a SwiGLU MLP in the first
   ``first_k_dense_replace`` layers and the dropless expert layer
-  (``ops.moe.SigmoidTopKMoE``) after them; no biases, untied head.
+  (``ops.moe.TopKMoE``) after them; no biases, untied head.
   Under ``scan_layers`` the leading dense layers stay outside the
   scanned stack of expert layers.
 - ``arch="kimi_linear"``: the same block with a mixer per layer
@@ -24,6 +24,11 @@ requires (SURVEY.md §2 #14):
   with one decay a head, heads of unequal key and value size) or
   :class:`Attention` with a norm over the whole query and key
   projections and no rotation.  Every stretch is a scanned stack.
+- ``arch="keye_dsa"``: the pre-norm block of deepseek_v3 with
+  :class:`SparseAttention` as its mixer (grouped-query attention with a
+  norm over each head of q and k, cut to the ``sa_topk`` keys a query
+  that a learned indexer selects: ``ops/indexer.py``) over the same
+  expert layer with a softmax router; every layer alike, one stack.
 
 Design notes (TPU-first):
 - Params are annotated with *logical* axes via flax logical
@@ -71,6 +76,8 @@ from orion_tpu.ops.rotary import apply_rotary
 # olmo_hybrid: a GDN layer caches {"S": f32 [B,H,dk,dv], "conv":
 # [B,taps-1,H*(2*dk+dv)]}, a full-attention layer {"k","v"} as llama's;
 # scan_layers models {"dense": [], "runs": [stacked, ...]}.
+# keye_dsa: a layer caches {"k","v"} as llama's and {"ki": [B,L,
+# sa_index_head_dim]}, the indexer's one key head; layouts as llama's.
 KVCache = Any
 
 _dt = lambda s: jnp.dtype(s)  # noqa: E731
@@ -278,13 +285,44 @@ def _cache_writer(positions, B: int, L: int):
 
 
 class Attention(nn.Module):
-    """``qk_norm``: one norm over the whole query and key projections,
-    before the split into heads; ``rotary`` false: nothing is rotated
-    (both olmo_hybrid's, whose recurrent layers carry position)."""
+    """``qk_norm``: true or ``"whole"``, one norm over the whole query
+    and key projections, before the split into heads (olmo_hybrid's);
+    ``"head"``, one norm over each head's ``head_dim`` of q and of k, a
+    weight of ``head_dim`` each that all heads share (keye_dsa's, the
+    Qwen3 family's); ``rotary`` false: nothing is rotated
+    (olmo_hybrid's, whose recurrent layers carry position)."""
 
     cfg: ModelConfig
-    qk_norm: bool = False
+    qk_norm: Any = False
     rotary: bool = True
+
+    def qkv(self, x, positions):
+        """The projections, normed and rotated as configured: q [B, L,
+        H, D], k and v [B, L, Hkv, D].  (Called inside a compact
+        ``__call__``: the submodules are this module's.)"""
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+
+        q = _dense(H * D, ("embed", "heads"), cfg.attn_bias, cfg, "q_proj")(x)
+        k = _dense(Hkv * D, ("embed", "kv_heads"), cfg.attn_bias, cfg, "k_proj")(x)
+        v = _dense(Hkv * D, ("embed", "kv_heads"), cfg.attn_bias, cfg, "v_proj")(x)
+        if self.qk_norm and self.qk_norm != "head":
+            with jax.named_scope("attn.qk_norm"):
+                q = _norm(cfg, "q_norm")(q)
+                k = _norm(cfg, "k_norm")(k)
+        q = q.reshape(B, L, H, D)
+        k = k.reshape(B, L, Hkv, D)
+        v = v.reshape(B, L, Hkv, D)
+        if self.qk_norm == "head":
+            with jax.named_scope("attn.qk_norm"):
+                q = _norm(cfg, "q_norm")(q)
+                k = _norm(cfg, "k_norm")(k)
+
+        if self.rotary:
+            rotary_dim = int(D * cfg.rotary_pct)
+            q, k = apply_rotary(q, k, positions, rotary_dim, cfg.rope_theta)
+        return q, k, v
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None):
@@ -299,22 +337,8 @@ class Attention(nn.Module):
         """
         cfg = self.cfg
         B, L, _ = x.shape
-        H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-
-        q = _dense(H * D, ("embed", "heads"), cfg.attn_bias, cfg, "q_proj")(x)
-        k = _dense(Hkv * D, ("embed", "kv_heads"), cfg.attn_bias, cfg, "k_proj")(x)
-        v = _dense(Hkv * D, ("embed", "kv_heads"), cfg.attn_bias, cfg, "v_proj")(x)
-        if self.qk_norm:
-            with jax.named_scope("attn.qk_norm"):
-                q = _norm(cfg, "q_norm")(q)
-                k = _norm(cfg, "k_norm")(k)
-        q = q.reshape(B, L, H, D)
-        k = k.reshape(B, L, Hkv, D)
-        v = v.reshape(B, L, Hkv, D)
-
-        if self.rotary:
-            rotary_dim = int(D * cfg.rotary_pct)
-            q, k = apply_rotary(q, k, positions, rotary_dim, cfg.rope_theta)
+        H, D = cfg.num_heads, cfg.head_dim
+        q, k, v = self.qkv(x, positions)
 
         scale = 1.0 / D ** 0.5
         paged_decode_out = None
@@ -403,6 +427,111 @@ class Attention(nn.Module):
             mask = key_slots[None, None, :] <= positions[:, :, None]
             out = attention(q, keys, values, mask, scale=scale,
                             impl=cfg.attention_impl, q_positions=positions)
+        out = out.reshape(B, L, H * D)
+        out = _dense(cfg.hidden_size, ("heads", "embed"),
+                     cfg.attn_bias, cfg, "o_proj")(out)
+        return out, new_cache
+
+
+class SparseAttention(Attention):
+    """Grouped-query attention over the keys a learned indexer selects
+    (keye_dsa; the lightning indexer of DeepSeek-V3.2-Exp on grouped
+    heads).  Projections, per-head q/k norm, rotary and cache writer are
+    :class:`Attention`'s.
+
+    Indexer: ``qI = h W_Iq`` as ``sa_index_heads`` heads of
+    ``sa_index_head_dim``, ``kI = LayerNorm(h W_Ik)`` ONE head, the same
+    rotary on the whole of both, ``w = h W_Iw`` a head; ``I[t, s] =
+    (heads x dim)^-1/2 sum_j w[t, j] ReLU(qI[t, j] . kI[s])`` over the
+    causal pairs, float32; a query keeps its ``sa_topk`` largest (all,
+    where it has no more; the lower slot on a tie): ``ops/indexer.py``.
+    ``h`` enters it under ``stop_gradient`` and the selection is
+    discrete: its parameters take no gradient and no alignment loss is
+    added (RL here holds the indexer fixed; the published recipe trains
+    it by a separate KL term).  bf16 inputs, float32 accumulation; the
+    published FP8 quantisation and Hadamard rotation of qI, kI are left
+    out (an orthogonal map: no product changes).
+
+    The cache holds ``ki`` [B, Lmax, dim] beside ``k`` and ``v``.
+    Whole sequences and prefill run the flash kernels over all causal
+    blocks with the selection as an operand (``sparse_fwd`` ...); one
+    new token against the cache gathers its selected rows of k and v.
+    Where a call has no more keys than ``sa_topk`` nothing is selected
+    and the attention is :class:`Attention`'s, exactly.
+    """
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None):
+        from orion_tpu.ops import indexer
+        from orion_tpu.ops.attention import sparse_attention
+
+        cfg = self.cfg
+        B, L, _ = x.shape
+        H, D = cfg.num_heads, cfg.head_dim
+        Hi, Di, topk = cfg.sa_index_heads, cfg.sa_index_head_dim, cfg.sa_topk
+        if is_paged(layer_cache) or (layer_cache is not None
+                                     and "ki" not in layer_cache):
+            raise ValueError(
+                "sparse attention caches {'k', 'v', 'ki'} (init_cache); "
+                "there is no paged or int8 cache under a selection yet")
+        q, k, v = self.qkv(x, positions)
+        scale = 1.0 / D ** 0.5
+
+        with jax.named_scope("attn.indexer"):
+            h = jax.lax.stop_gradient(x)
+            qi = _dense(Hi * Di, ("embed", "index"), False, cfg,
+                        "index_q_proj")(h).reshape(B, L, Hi, Di)
+            ki = nn.LayerNorm(
+                epsilon=cfg.rms_norm_eps, dtype=_dt(cfg.dtype),
+                param_dtype=_dt(cfg.param_dtype),
+                scale_init=nn.with_logical_partitioning(
+                    nn.initializers.ones_init(), ("norm",)),
+                bias_init=nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), ("norm",)),
+                name="index_k_norm")(
+                    _dense(Di, ("embed", "index"), False, cfg,
+                           "index_k_proj")(h))
+            qi, ki = apply_rotary(qi, ki[:, :, None, :], positions, Di,
+                                  cfg.rope_theta)
+            ki = ki[:, :, 0, :]
+            w = _dense(Hi, ("embed", "index"), False, cfg,
+                       "index_w_proj")(h).astype(jnp.float32) \
+                * (Hi * Di) ** -0.5
+            qi, ki, w = (jax.lax.stop_gradient(t) for t in (qi, ki, w))
+
+        new_cache = None
+        if layer_cache is not None:
+            write = _cache_writer(positions, B, L)
+            new_cache = {"k": write(layer_cache["k"], k),
+                         "v": write(layer_cache["v"], v),
+                         "ki": write(layer_cache["ki"], ki)}
+            k, v, ki = new_cache["k"], new_cache["v"], new_cache["ki"]
+        Lk = k.shape[1]
+        key_slots = jnp.arange(Lk, dtype=positions.dtype)
+        mask = key_slots[None, None, :] <= positions[:, :, None]
+
+        if Lk <= topk:
+            # no query has more keys than it may keep
+            out = attention(q, k, v, mask, scale=scale,
+                            impl=cfg.attention_impl, q_positions=positions)
+        elif layer_cache is not None and L == 1:
+            idx, valid = indexer.select_step(qi[:, 0], ki, w[:, 0],
+                                             positions[:, 0], topk)
+            with jax.named_scope("attn.sparse"):
+                rows = idx[:, :, None, None]
+                out = attention(q, jnp.take_along_axis(k, rows, axis=1),
+                                jnp.take_along_axis(v, rows, axis=1),
+                                valid[:, None, :], scale=scale,
+                                impl="reference")
+        else:
+            sel_t = indexer.select(qi, ki, w, positions, topk,
+                                   cfg.sa_q_chunk, cfg.sa_kv_chunk)
+            # read only by a caller that makes "selections" mutable (the
+            # reference checks): [B, keys, queries] int8
+            self.sow("selections", "sa_selected", sel_t)
+            with jax.named_scope("attn.sparse"):
+                out = sparse_attention(q, k, v, mask, sel_t, positions,
+                                       scale=scale, impl=cfg.attention_impl)
         out = out.reshape(B, L, H * D)
         out = _dense(cfg.hidden_size, ("heads", "embed"),
                      cfg.attn_bias, cfg, "o_proj")(out)
@@ -858,9 +987,11 @@ class Block(nn.Module):
 
 
 class LatentBlock(nn.Module):
-    """deepseek_v3 / kimi_linear block: ``a = x + Mixer(N1(x))``, ``y =
-    a + FFN(N2(a))``; the mixer latent attention or (``mixer="kda"``)
-    the delta rule, FFN the SwiGLU MLP (``dense``) or the expert layer."""
+    """deepseek_v3 / kimi_linear / keye_dsa block: ``a = x +
+    Mixer(N1(x))``, ``y = a + FFN(N2(a))``; the mixer latent attention,
+    (``mixer="kda"``) the delta rule or (``mixer="sparse"``)
+    grouped-query attention under a selection, FFN the SwiGLU MLP
+    (``dense``) or the expert layer."""
 
     cfg: ModelConfig
     dense: bool = False
@@ -873,6 +1004,9 @@ class LatentBlock(nn.Module):
         if self.mixer == "kda":
             attn_out, new_cache = KimiDeltaAttention(cfg, name="attn")(
                 h, positions, layer_cache, token_mask)
+        elif self.mixer == "sparse":
+            attn_out, new_cache = SparseAttention(
+                cfg, qk_norm="head", name="attn")(h, positions, layer_cache)
         else:
             attn_out, new_cache = LatentAttention(cfg, name="attn")(
                 h, positions, layer_cache)
@@ -880,8 +1014,8 @@ class LatentBlock(nn.Module):
         z = _norm(cfg, "post_attn_norm")(h)
         if self.dense:
             return h + MLP(cfg, name="mlp")(z), new_cache
-        from orion_tpu.ops.moe import SigmoidTopKMoE
-        return h + SigmoidTopKMoE(cfg, name="mlp")(z, token_mask), new_cache
+        from orion_tpu.ops.moe import TopKMoE
+        return h + TopKMoE(cfg, name="mlp")(z, token_mask), new_cache
 
 
 class PostNormBlock(nn.Module):
@@ -1036,7 +1170,8 @@ class Transformer(nn.Module):
                     # DROPS everything sown inside the scanned block — the
                     # MoE router aux loss would read as zero under
                     # scan_layers with no error.
-                    variable_axes={"params": 0, "intermediates": 0},
+                    variable_axes={"params": 0, "intermediates": 0,
+                                   "selections": 0},
                     split_rngs={"params": True},
                     in_axes=(nn.broadcast, 0) + (nn.broadcast,) * len(more),
                     out_axes=0,
@@ -1099,8 +1234,9 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     if cfg.pattern and quantized:
         raise ValueError(
             "there is no int8 latent cache (rollout.quantize_kv) for "
-            f"arch={cfg.arch!r} yet: ops/quant.py scales per head, and a "
-            "recurrent state has no int8 form")
+            f"arch={cfg.arch!r} yet: ops/quant.py scales per head, a "
+            "recurrent state has no int8 form, and no int8 cache was run "
+            "under a selection")
     shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
 
     def entry(mixer, pre=()):
@@ -1122,6 +1258,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                                    dtype),
                     "k_rope": jnp.zeros(
                         pre + (batch, max_len, cfg.qk_rope_head_dim), dtype)}
+        if mixer == "sparse":
+            return {"k": jnp.zeros(pre + shape, dtype),
+                    "v": jnp.zeros(pre + shape, dtype),
+                    "ki": jnp.zeros(
+                        pre + (batch, max_len, cfg.sa_index_head_dim), dtype)}
         if quantized:
             return {"k": jnp.zeros(pre + shape, jnp.int8),
                     "v": jnp.zeros(pre + shape, jnp.int8),

@@ -112,9 +112,11 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     CONTRACT: every non-"reference" path ignores ``mask`` and applies
     the positional rule ``kv_position <= q_position`` — which holds for
-    every mask built in models/transformer.py.  A mask with extra
-    structure (padding-aware, bidirectional, packed-segment) requires
-    impl="reference".  Decode steps (Lq == 1) always take the reference
+    every mask this function is given in models/transformer.py.  A mask
+    with extra structure (padding-aware, bidirectional, packed-segment)
+    requires impl="reference"; a SELECTION of keys a query (learned
+    sparse attention) goes through :func:`sparse_attention`, whose
+    kernels take it as an operand.  Decode steps (Lq == 1) always take the reference
     path — a 1-row MXU tile would waste the systolic array; the paged
     decode kernel covers that case from the rollout engine.
     """
@@ -147,6 +149,32 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             raise ValueError("flash attention requires q_positions")
         return _flash_on_mesh(q, k, v, q_positions, scale)
     return reference_attention_gqa(q, k, v, mask, scale)
+
+
+def sparse_attention(q, k, v, mask, sel_t, q_positions, scale: float,
+                     impl: str = "auto") -> jnp.ndarray:
+    """Attention under the positional rule AND a selection: ``sel_t``
+    [B, Lk, Lq] int8 (ops/indexer.py: keys by queries, one selection
+    for all heads), ``mask`` [B, Lq, Lk] the positional rule as for
+    :func:`attention`.  On one TPU device (``auto`` / ``flash``, more
+    than one query) the flash kernels with the selection as an operand;
+    elsewhere, and under a mesh of several devices (a Mosaic kernel
+    cannot be partitioned automatically, and no shard_map was written
+    for the selection), the einsum over ``mask & selection``."""
+    from orion_tpu.ops.pallas import target_platform
+    from orion_tpu.parallel.sharding import ambient_mesh
+
+    if impl in ("ring", "ulysses"):
+        raise ValueError(f"attention_impl={impl!r} takes no selection")
+    mesh = ambient_mesh()
+    one_device = mesh is None or mesh.empty or mesh.size == 1
+    if q.shape[1] > 1 and one_device and (
+            impl == "flash" or (impl == "auto"
+                                and target_platform() == "tpu")):
+        from orion_tpu.ops.pallas.flash_attention import sparse_attention_gqa
+        return sparse_attention_gqa(q, k, v, q_positions, sel_t, scale)
+    return reference_attention_gqa(
+        q, k, v, mask & (sel_t.swapaxes(1, 2) != 0), scale)
 
 
 def _flash_on_mesh(q, k, v, q_positions, scale):

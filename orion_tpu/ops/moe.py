@@ -14,10 +14,12 @@ the same trade the rollout engine makes with paged KV.
 No SPEC config uses MoE (BASELINE.json); this exists to make the EP row
 of the parallelism table first-class, as the task demands.
 
-Beside it, :class:`SigmoidTopKMoE` is the expert layer of the
-``deepseek_v3`` block as published (sigmoid scores, a selection bias,
-top-k of all experts, normalised and scaled gates, shared experts, no
-capacity, no drops, no auxiliary loss), told which experts it holds:
+Beside it, :class:`TopKMoE` is the expert layer of the ``deepseek_v3``
+block as published (sigmoid scores, a selection bias, top-k of all
+experts, normalised and scaled gates, shared experts, no capacity, no
+drops, no auxiliary loss) and, with ``moe_scoring="softmax"``, of the
+Qwen3-MoE family's (a float32 softmax over all experts, no bias, no
+shared expert), told which experts it holds:
 its rows, products and gradients follow the (token, choice) pairs
 routed to those, a static block of them at a time.
 """
@@ -170,6 +172,23 @@ def sigmoid_topk_route(z, router_kernel, bias, k: int, scale: float):
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     gates = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True)
                               + 1e-20)
+    return idx.astype(jnp.int32), gates
+
+
+def softmax_topk_route(z, router_kernel, k: int, scale: float):
+    """The Qwen3-MoE router (``norm_topk_prob`` true).  z [T, D],
+    router_kernel [D, E] -> (idx [T, k] int32 over ALL E experts, gates
+    [T, k] f32): a float32 softmax over all experts (the product at the
+    highest precision, as :func:`sigmoid_topk_route`'s), its k largest,
+    a gate ``scale * p / (sum of the k selected)``.  No bias."""
+    logits = jnp.dot(z.astype(jnp.float32),
+                     router_kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = checkpoint_name(jax.nn.softmax(logits, axis=-1), "moe_route")
+    _, idx = jax.lax.top_k(probs, k)
+    idx = checkpoint_name(idx, "moe_route")
+    chosen = jnp.take_along_axis(probs, idx, axis=-1)
+    gates = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
     return idx.astype(jnp.int32), gates
 
 
@@ -357,8 +376,11 @@ def _routed_bwd(block, res, g):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-class SigmoidTopKMoE(nn.Module):
-    """The ``deepseek_v3`` expert layer and the chip's share of it.
+class TopKMoE(nn.Module):
+    """The dropless expert layer and the chip's share of it
+    (``deepseek_v3``'s as published; ``cfg.moe_scoring`` says how the
+    router scores: :func:`sigmoid_topk_route` with its selection bias,
+    or :func:`softmax_topk_route`, which has none).
 
     ``FFN(z) = sum_{i in top-k} w_i E_i(z) + S(z)``: E_i a SwiGLU of
     ``moe_intermediate_size``, S one SwiGLU of ``n_shared_experts``
@@ -413,18 +435,24 @@ class SigmoidTopKMoE(nn.Module):
 
         normal = nn.initializers.normal(stddev=0.02)
         router = param("router", normal, (Dm, E), ("embed", "norm"))
-        bias = param("e_score_correction_bias",
-                     nn.initializers.normal(stddev=0.02), (E,), ("norm",),
-                     jnp.float32)
+        sigmoid = cfg.moe_scoring == "sigmoid"
+        if sigmoid:
+            bias = param("e_score_correction_bias",
+                         nn.initializers.normal(stddev=0.02), (E,),
+                         ("norm",), jnp.float32)
         w_gate_up = param("experts_gate_up_proj", normal, (H, Dm, 2 * I),
                           ("expert", "embed", "mlp"))
         w_down = param("experts_down_proj", normal, (H, I, Dm),
                        ("expert", "mlp", "embed"))
 
         with jax.named_scope("moe.route"):
-            idx, gates = sigmoid_topk_route(
-                z, router, jax.lax.stop_gradient(bias), k,
-                cfg.routed_scaling_factor)
+            if sigmoid:
+                idx, gates = sigmoid_topk_route(
+                    z, router, jax.lax.stop_gradient(bias), k,
+                    cfg.routed_scaling_factor)
+            else:
+                idx, gates = softmax_topk_route(
+                    z, router, k, cfg.routed_scaling_factor)
             local = idx - cfg.expert_offset
             if token_mask is not None:
                 local = jnp.where(token_mask.reshape(B * L, 1), local, H)
@@ -452,3 +480,7 @@ class SigmoidTopKMoE(nn.Module):
                 shared = _dense(Dm, ("mlp", "embed"), False, cfg,
                                 "shared_down_proj")(h)
         return routed.reshape(B, L, Dm).astype(cdt) + shared
+
+
+#: the layer's name before the scoring became data
+SigmoidTopKMoE = TopKMoE

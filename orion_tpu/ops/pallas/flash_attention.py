@@ -241,10 +241,16 @@ def _for_each_extent(live, body, leading: bool):
         pl.when(edge == c)(functools.partial(body, c))
 
 
-def _seen(qpos_ref, kvpos_ref, kv_start, kv_rows, q_cols, shape):
+def _seen(qpos_ref, kvpos_ref, kv_start, kv_rows, q_cols, shape,
+          sel_ref=None):
     """[keys, queries] bool (``shape``): key rows ``kv_rows`` of the kv
     block, which starts at slot ``kv_start``, against query columns
-    ``q_cols`` of the q block."""
+    ``q_cols`` of the q block.  ``sel_ref``: the block of a selection
+    ([1, keys, queries] int8, ops/indexer.py), which a pair must also
+    hold."""
+    if sel_ref is not None:
+        return _seen(qpos_ref, kvpos_ref, kv_start, kv_rows, q_cols,
+                     shape) & (sel_ref[0, kv_rows, q_cols] != 0)
     if kvpos_ref is not None:
         kvcol = kvpos_ref[0, kv_rows, :]                         # [w, 1]
     else:
@@ -256,10 +262,13 @@ def _seen(qpos_ref, kvpos_ref, kv_start, kv_rows, q_cols, shape):
 
 
 def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-                scale: float, use_kvpos: bool, nq_sub: int, n_sub: int):
-    kvpos_ref = None
+                scale: float, use_kvpos: bool, nq_sub: int, n_sub: int,
+                use_sel: bool = False):
+    kvpos_ref = sel_ref = None
     if use_kvpos:
         kvpos_ref, *rest = rest
+    if use_sel:
+        sel_ref, *rest = rest
     q_ref, k_ref, vt_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
@@ -278,7 +287,8 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         st = _dot(k_ref[0, 0, :width, :], q_ref[0, 0, cols, :],
                   _NT) * scale                                   # [w, bq]
         st = jnp.where(_seen(qpos_ref, kvpos_ref, j * major,
-                             slice(0, width), cols, st.shape), st, NEG_INF)
+                             slice(0, width), cols, st.shape, sel_ref),
+                       st, NEG_INF)
         m_prev, l_prev = m_sc[:, cols], l_sc[:, cols]            # [1, bq]
         m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
         pt = jnp.exp(st - m_new)
@@ -307,10 +317,12 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
 
 
 def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
-         clamp: bool):
+         clamp: bool, sel_t=None):
     """qt [B,H,Lq,D], kt/vt [B,Hkv,Lk,D], qpos3 [B,1,Lq], kvpos3
     [B,Lk,1] -> out [B,H,Lq,Dv], lse [B,H,1,Lq].  clamp=True enables
-    the contiguous-path fetch clamps."""
+    the contiguous-path fetch clamps.  ``sel_t`` [B, Lk, Lq] int8: a
+    selection every head shares, an operand only where it is given (the
+    kernel is then ``sparse_fwd``)."""
     B, H, Lq, D = qt.shape
     Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
@@ -337,6 +349,10 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
             + ([pl.BlockSpec((1, major, 1),
                              lambda b, h, i, j, qm, im, km: (b, j, 0))]
                if use_kvpos else [])
+            + ([pl.BlockSpec((1, major, qmajor),
+                             lambda b, h, i, j, qm, im, km:
+                             (b, fetch(qm, b, i, j), i))]
+               if sel_t is not None else [])
             + [pl.BlockSpec((1, 1, qmajor, D),
                             lambda b, h, i, j, qm, im, km: (b, h, i, 0)),
                pl.BlockSpec((1, 1, major, D), k_map),
@@ -353,13 +369,16 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
     operands = [*p.tables, qpos3]
     if use_kvpos:
         operands.append(kvpos3)
+    if sel_t is not None:
+        operands.append(sel_t)
     # v and o cross the kernel's boundary transposed ([.., Dv, L]); the
     # callers' own [B, L, H, D] <-> [B, H, L, D] transposes absorb it
     operands += [qt, kt, vt.swapaxes(2, 3)]
     out_t, lse = named_pallas_call(
-        "flash_fwd",
+        "flash_fwd" if sel_t is None else "sparse_fwd",
         functools.partial(_fwd_kernel, scale=scale, use_kvpos=use_kvpos,
-                          nq_sub=p.nq_sub, n_sub=p.n_sub),
+                          nq_sub=p.nq_sub, n_sub=p.n_sub,
+                          use_sel=sel_t is not None),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Dv, Lq), qt.dtype),
@@ -376,10 +395,13 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
 
 
 def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-               scale: float, use_kvpos: bool, nq_sub: int, n_sub: int):
-    kvpos_ref = None
+               scale: float, use_kvpos: bool, nq_sub: int, n_sub: int,
+               use_sel: bool = False):
+    kvpos_ref = sel_ref = None
     if use_kvpos:
         kvpos_ref, *rest = rest
+    if use_sel:
+        sel_ref, *rest = rest
     (q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
      dq_sc) = rest
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
@@ -398,7 +420,7 @@ def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         st = _dot(k_ref[0, 0, :width, :], q_ref[0, 0, cols, :],
                   _NT) * scale                                   # [w, bq]
         pt = jnp.where(_seen(qpos_ref, kvpos_ref, j * major,
-                             slice(0, width), cols, st.shape),
+                             slice(0, width), cols, st.shape, sel_ref),
                        jnp.exp(st - lse_ref[0, 0, :, cols]), 0.0)
         dpt = _dot(v_ref[0, 0, :width, :], do, _NT)              # [w, bq]
         dst = pt * (dpt - delta_ref[0, 0, :, cols])
@@ -418,10 +440,13 @@ def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
 
 
 def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-                scale: float, use_kvpos: bool, nq_sub: int, n_sub: int):
-    kvpos_ref = None
+                scale: float, use_kvpos: bool, nq_sub: int, n_sub: int,
+                use_sel: bool = False):
+    kvpos_ref = sel_ref = None
     if use_kvpos:
         kvpos_ref, *rest = rest
+    if use_sel:
+        sel_ref, *rest = rest
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
      dv_ref, dk_sc, dv_sc) = rest
     b, j, i = pl.program_id(0), pl.program_id(2), pl.program_id(3)
@@ -440,7 +465,7 @@ def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         do = do_ref[0, 0, cols, :]                               # [w, Dv]
         st = _dot(k_ref[0, 0, rows, :], q, _NT) * scale          # [bkv, w]
         pt = jnp.where(_seen(qpos_ref, kvpos_ref, j * major + rows.start,
-                             rows, cols, st.shape),
+                             rows, cols, st.shape, sel_ref),
                        jnp.exp(st - lse_ref[0, 0, :, cols]), 0.0)
         dv_sc[rows, :] = dv_sc[rows, :] + _dot(pt.astype(do.dtype), do, _NN)
         dpt = _dot(v_ref[0, 0, rows, :], do, _NT)                # [bkv, w]
@@ -463,7 +488,7 @@ def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
 
 
 def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
-             blk_q, blk_kv, clamp: bool):
+             blk_q, blk_kv, clamp: bool, sel_t=None):
     B, H, Lq, D = qt.shape
     Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
@@ -490,6 +515,10 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
         + ([pl.BlockSpec((1, major, 1),
                          lambda b, h, i, j, qm, im, km: (b, j, 0))]
            if use_kvpos else [])
+        + ([pl.BlockSpec((1, major, qmajor),
+                         lambda b, h, i, j, qm, im, km:
+                         (b, fetch(qm, b, i, j), i))]
+           if sel_t is not None else [])
         + [pl.BlockSpec((1, 1, qmajor, D), q_rows),
            pl.BlockSpec((1, 1, major, D), kv_map),
            pl.BlockSpec((1, 1, D, major), kt_map),
@@ -501,13 +530,16 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
     operands = [*p.tables, qpos3]
     if use_kvpos:
         operands.append(kvpos3)
+    if sel_t is not None:
+        operands.append(sel_t)
     # k enters twice: as it lies for s^T = k q^T, transposed for
     # dq^T = k^T ds^T; dq leaves transposed like the forward's output
     operands += [qt, kt, kt.swapaxes(2, 3), vt, dout_t, lse, delta]
     dq_t = named_pallas_call(
-        "flash_bwd_dq",
+        "flash_bwd_dq" if sel_t is None else "sparse_bwd_dq",
         functools.partial(_dq_kernel, scale=scale, use_kvpos=use_kvpos,
-                          nq_sub=p.nq_sub, n_sub=p.n_sub),
+                          nq_sub=p.nq_sub, n_sub=p.n_sub,
+                          use_sel=sel_t is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, H, p.nq, p.nkv),
@@ -522,7 +554,7 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
 
 
 def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
-              blk_q, blk_kv, clamp: bool):
+              blk_q, blk_kv, clamp: bool, sel_t=None):
     """Per-q-head dK/dV [B, H, Lk, D]: float32 where the caller still
     has to group-sum them (GQA), else in the inputs' dtype."""
     B, H, Lq, D = qt.shape
@@ -557,6 +589,10 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
         + ([pl.BlockSpec((1, major, 1),
                          lambda b, h, j, i, qm, im, km: (b, j, 0))]
            if use_kvpos else [])
+        + ([pl.BlockSpec((1, major, qmajor),
+                         lambda b, h, j, i, qm, im, km:
+                         (b, j, first(im, b, j, i)))]
+           if sel_t is not None else [])
         + [pl.BlockSpec((1, 1, qmajor, D), q_rows),
            pl.BlockSpec((1, 1, major, D), kv_in),
            pl.BlockSpec((1, 1, major, Dv), kv_in),
@@ -567,12 +603,15 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
     operands = [*p.tables, qpos3]
     if use_kvpos:
         operands.append(kvpos3)
+    if sel_t is not None:
+        operands.append(sel_t)
     operands += [qt, kt, vt, dout_t, lse, delta]
     grad_dtype = jnp.float32 if n_rep > 1 else kt.dtype
     dk_h, dv_h = named_pallas_call(
-        "flash_bwd_dkv",
+        "flash_bwd_dkv" if sel_t is None else "sparse_bwd_dkv",
         functools.partial(_dkv_kernel, scale=scale, use_kvpos=use_kvpos,
-                          nq_sub=p.nq_sub, n_sub=p.n_sub),
+                          nq_sub=p.nq_sub, n_sub=p.n_sub,
+                          use_sel=sel_t is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B, H, p.nkv, p.nq),
@@ -594,7 +633,7 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
 
 
 def _bwd_impl(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv, out_t,
-              lse, dout_t, clamp: bool):
+              lse, dout_t, clamp: bool, sel_t=None):
     B, H, Lq, D = qt.shape
     Hkv, Lk = kt.shape[1], kt.shape[2]
     n_rep = H // Hkv
@@ -602,9 +641,9 @@ def _bwd_impl(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv, out_t,
     delta = jnp.sum(dout_t.astype(jnp.float32) * out_t.astype(jnp.float32),
                     axis=-1)[:, :, None, :]                   # [B, H, 1, Lq]
     dq = _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
-                  blk_q, blk_kv, clamp)
+                  blk_q, blk_kv, clamp, sel_t)
     dk_h, dv_h = _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta,
-                           scale, blk_q, blk_kv, clamp)
+                           scale, blk_q, blk_kv, clamp, sel_t)
     if n_rep > 1:
         dk = dk_h.reshape(B, Hkv, n_rep, Lk, D).sum(axis=2)
         dv = dv_h.reshape(B, Hkv, n_rep, Lk, vt.shape[3]).sum(axis=2)
@@ -691,6 +730,53 @@ def _vjp_bwd(scale, blk_q, blk_kv, residuals, dout):
 
 
 flash_attention_gqa.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# the same kernels under a selection (learned sparse attention)
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def sparse_attention_gqa(q, k, v, q_positions, sel_t, scale,
+                         blk_q: int = 512, blk_kv: int = 512):
+    """:func:`flash_attention_gqa` where a query attends to slot j iff
+    ``j <= its position`` AND ``sel_t[b, j, query]`` is set: ``sel_t``
+    [B, Lk, Lq] int8 is one selection for all heads (ops/indexer.py),
+    laid out as the kernels' transposed score tiles are.  The kernels
+    (``sparse_fwd``, ``sparse_bwd_dq``, ``sparse_bwd_dkv``) go over all
+    causal blocks and mask inside them: a block none of whose keys is
+    selected is computed like any other.  No gradient reaches the
+    selection."""
+    out, _ = _fwd(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                  v.transpose(0, 2, 1, 3), q_positions[:, None, :],
+                  None, scale, blk_q, blk_kv, clamp=True, sel_t=sel_t)
+    return out.transpose(0, 2, 1, 3)
+
+
+def _sparse_vjp_fwd(q, k, v, q_positions, sel_t, scale, blk_q, blk_kv):
+    qt, kt, vt = (checkpoint_name(t.transpose(0, 2, 1, 3), "attn_qkv")
+                  for t in (q, k, v))
+    qpos3 = q_positions[:, None, :]
+    out_t, lse = (checkpoint_name(t, "attn_out") for t in _fwd(
+        qt, kt, vt, qpos3, None, scale, blk_q, blk_kv, clamp=True,
+        sel_t=sel_t))
+    return out_t.transpose(0, 2, 1, 3), (qt, kt, vt, qpos3, sel_t, out_t,
+                                         lse)
+
+
+def _sparse_vjp_bwd(scale, blk_q, blk_kv, residuals, dout):
+    qt, kt, vt, qpos3, sel_t, out_t, lse = residuals
+    dq, dk, dv = _bwd_impl(qt, kt, vt, qpos3, None, scale, blk_q,
+                           blk_kv, out_t, lse, dout.transpose(0, 2, 1, 3),
+                           clamp=True, sel_t=sel_t)
+    return (dq.transpose(0, 2, 1, 3),
+            dk.transpose(0, 2, 1, 3).astype(kt.dtype),
+            dv.transpose(0, 2, 1, 3).astype(vt.dtype),
+            None, None)
+
+
+sparse_attention_gqa.defvjp(_sparse_vjp_fwd, _sparse_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,9 @@ Rules (MaxText/T5X-style):
             ``dt_bias``; its low-rank gates run embed → latent → heads;
             a GDN layer's q / k / v / z projections of unequal width
             are ``heads`` too, its per-head a / b projections ``norm``)
+  index   — the sparse-attention indexer's heads x dim, its one key
+            head and its per-head weights → (replicated: every shard
+            scores and selects for all heads of a whole sequence)
 """
 
 from __future__ import annotations
@@ -40,6 +43,7 @@ LOGICAL_RULES: dict = {
     "norm": None,
     "latent": None,     # latent attention's 512 / 576: replicated
     "conv": None,       # the 4 taps of a KDA layer's convolutions
+    "index": None,      # the sparse-attention indexer's projections
     "expert": "expert",
     "batch": ("data", "fsdp"),
     "seq": "seq",

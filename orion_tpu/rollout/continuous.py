@@ -220,6 +220,9 @@ class ContinuousBatchingEngine:
                 + ("; a latent paged cache (c, k_rope) and a kernel that "
                    "attends over it are not written yet"
                    if model_cfg.latent_attention else "")
+                + ("; nor is there a selection inside paged attention or "
+                   "a page pool for the indexer's keys"
+                   if model_cfg.arch == "keye_dsa" else "")
                 + ("; nor does its cache manager hold a recurrent state "
                    "per slot (admission, preemption and prefix reuse move "
                    "pages, and a state is not made of pages)"

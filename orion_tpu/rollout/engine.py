@@ -16,7 +16,8 @@ One decode path (XLA-first, static shapes):
   overwrites the padded tail slot-by-slot during decode (see
   models.transformer.Attention);
 - the cache is dense ([B, P+T] per layer; the latent form for
-  ``latent_attention``; per layer by kind for a pattern model), int8
+  ``latent_attention``; per layer by kind for a pattern model; with the
+  indexer's keys beside k and v under sparse attention), int8
   under ``quantize_kv``, or paged under
   ``RolloutConfig.paged`` (block tables + the Pallas paged-decode
   kernel; slower than dense for a fixed batch, ROADMAP D3(a));
@@ -99,6 +100,7 @@ class RolloutEngine:
             model, model_cfg)
         if model_cfg.pattern:
             latent = model_cfg.latent_attention
+            sparse = model_cfg.arch == "keye_dsa"
             state = ", and a recurrent state is not made of pages" \
                 if model_cfg.recurrent else ""
             for on, missing in (
@@ -106,18 +108,26 @@ class RolloutEngine:
                      + ("there is no latent paged cache (ops/paged_kv.py "
                         "and the Pallas paged-decode kernel hold per-head "
                         "K/V pages)" if latent else
+                        "there is no selection inside paged attention nor "
+                        "a page pool for the indexer's keys "
+                        "(ops/paged_kv.py)" if sparse else
                         "init_paged_cache gives every layer pages")
                      + state),
                     (cfg.quantize_kv, "rollout.quantize_kv: there is no "
                      + ("int8 latent cache (ops/quant.py scales per head)"
-                        if latent else "int8 cache for a model whose "
+                        if latent else "int8 cache under a selection (the "
+                        "gathered step reads rows of bf16 keys and values)"
+                        if sparse else "int8 cache for a model whose "
                         "layers do not all hold keys and values")
                      + (", nor an int8 form of a float32 recurrent state"
                         if model_cfg.recurrent else "")),
                     (cfg.quantize_weights, "rollout.quantize_weights: "
                      + ("there are no int8 expert stacks or absorbed int8 "
                         "kv_b_proj (ops/quant.py quantises Dense kernels)"
-                        if latent else "the int8 Dense twins do not reach "
+                        if latent else "there are no int8 expert stacks, "
+                        "and an int8 indexer would select other keys than "
+                        "the update's" if sparse else
+                        "the int8 Dense twins do not reach "
                         "this block (no QuantDense decode twin was run "
                         "against its reference)"))):
                 if on:
@@ -150,11 +160,14 @@ class RolloutEngine:
                 lambda: init_cache(self._decode_cfg, *key,
                                    dtype=jnp.dtype(self._decode_cfg.dtype),
                                    quantized=self.cfg.quantize_kv))
-            sizes = {"cache": 0, "state": 0}
+            sizes = {"cache": 0, "state": 0, "index": 0}
             for layer in cache:       # the decode twin's: one per layer
                 kind = "state" if "S" in layer else "cache"
                 sizes[kind] += sum(x.size * x.dtype.itemsize
                                    for x in jax.tree.leaves(layer))
+                if "ki" in layer:
+                    sizes["index"] += (layer["ki"].size
+                                       * layer["ki"].dtype.itemsize)
             self._cache_bytes[key] = sizes
         return self._cache_bytes[key]
 
@@ -167,6 +180,17 @@ class RolloutEngine:
         if self.cfg.paged:
             return 0
         return self._cache_shapes(batch, prompt_len, max_new_tokens)["cache"]
+
+    def index_cache_bytes(self, batch: int, prompt_len: int,
+                          max_new_tokens: Optional[int] = None) -> int:
+        """The part of :meth:`cache_bytes` that is a sparse-attention
+        indexer's keys (one head of ``sa_index_head_dim`` a slot and
+        layer), which a decode step reads up to where it is filled,
+        where it reads only the selected rows of the keys and values.
+        0 for a model without an indexer."""
+        if self.cfg.paged:
+            return 0
+        return self._cache_shapes(batch, prompt_len, max_new_tokens)["index"]
 
     def state_bytes(self, batch: int, prompt_len: int,
                     max_new_tokens: Optional[int] = None) -> int:

@@ -169,6 +169,33 @@ def _stamp(span, gc_totals: tuple) -> tuple:
     return span.start, span.cpu_start, gc_totals[1]
 
 
+def hold_fixed(updates, cfg_model):
+    """The optimizer's updates with those of the parameters that RL
+    holds fixed set to zero: a sparse-attention indexer's projections
+    and norm (``index_*`` under a ``keye_dsa`` mixer).  No gradient
+    reaches them (the selection is discrete and its input stops the
+    gradient), so this only keeps weight decay off them; every other
+    model's updates pass through as they are."""
+    if cfg_model.arch != "keye_dsa":
+        return updates
+    return jax.tree_util.tree_map_with_path(
+        lambda path, u: jnp.zeros_like(u) if any(
+            str(getattr(k, "key", "")).startswith("index_") for k in path)
+        else u, updates)
+
+
+def sa_key_counts(lens, topk: int) -> dict:
+    """{sa_keys_valid, sa_keys_selected} summed over the real queries of
+    sequences of ``lens`` real tokens: query t (0-based) has t + 1 valid
+    keys and keeps ``min(topk, t + 1)``.  Host integers, from lengths."""
+    n = np.asarray(lens, np.int64)
+    valid = n * (n + 1) // 2
+    m = np.minimum(n, topk)
+    selected = m * (m + 1) // 2 + (n - m) * topk
+    return {"sa_keys_valid": int(valid.sum()),
+            "sa_keys_selected": int(selected.sum())}
+
+
 def state_out_shardings(state: "TrainState"):
     """``out_shardings`` for a donated TrainState: mesh-sharded leaves
     keep the layout they came in with; the rest stay unspecified.
@@ -496,7 +523,8 @@ class BaseTrainer:
             self.loss_fn, has_aux=True)(state.params, mb)
         updates, opt_state = self.tx.update(grads, state.opt_state,
                                             state.params)
-        params = optax.apply_updates(state.params, updates)
+        params = optax.apply_updates(
+            state.params, hold_fixed(updates, self.cfg.model))
         stats = dict(stats)
         stats["grad_norm"] = optax.global_norm(grads)
         stats["loss"] = loss
@@ -554,6 +582,13 @@ class BaseTrainer:
         if not hasattr(eng, "state_bytes"):
             return {"cache_bytes": 0, "state_bytes": 0, "weight_bytes": 0,
                     "kda_step": ""}
+        sparse = {}
+        if self.cfg.model.sa_topk:
+            # the indexer's keys, part of cache_bytes: a step reads them
+            # up to where they are filled, and of k and v sa_topk rows
+            sparse = {"index_cache_bytes":
+                      eng.index_cache_bytes(*prompts_shape),
+                      "sa_topk": self.cfg.model.sa_topk}
         mc, kda_step = self.cfg.model, ""
         if mc.recurrent:
             # the form the recurrent layers' one-token step takes in
@@ -563,7 +598,7 @@ class BaseTrainer:
         return {"cache_bytes": eng.cache_bytes(*prompts_shape),
                 "state_bytes": eng.state_bytes(*prompts_shape),
                 "weight_bytes": eng.weight_bytes(self.state.params),
-                "kda_step": kda_step}
+                "kda_step": kda_step, **sparse}
 
     def _score_result(self, result, host, meta) -> np.ndarray:
         """One place for the device-vs-host reward dispatch (the
@@ -675,6 +710,10 @@ class BaseTrainer:
             ids, lens, meta = self.prepare_prompts(batch)
             sp.set(batch=int(ids.shape[0]), prompt_len=int(ids.shape[1]),
                    **self._rollout_bytes(ids.shape))
+            if self.cfg.model.sa_topk:
+                # over the prefill's real queries, from the host's lengths
+                sp.set(**sa_key_counts(lens,
+                                       self.cfg.model.sa_topk))
             result = self.generate(
                 ids, lens, group_size=getattr(self.cfg, "group_size", 1))
         pend, self._pending_fetch = self._pending_fetch, None
@@ -688,6 +727,13 @@ class BaseTrainer:
             self._finalize_iteration(meta_p, fetched["p"],
                                      end=meta_p["end"])
         host = GenerationResult(**fetched["r"])
+        if self.cfg.model.sa_topk:
+            # over the real queries of ONE whole-sequence forward of this
+            # batch (the experience forwards and the update's each make
+            # it), from the lengths the fetch brought: the update span's
+            self._sa_counts = dict(
+                sa_key_counts(host.total_lens, self.cfg.model.sa_topk),
+                sa_topk=self.cfg.model.sa_topk)
         scores = self._score_result(result, host, meta)
         with obs.span("experience.dispatch"):
             return self.build_experience(result, scores, host=host)
@@ -1062,7 +1108,8 @@ class BaseTrainer:
                             jax.named_scope("update"), \
                             obs.span("update", it=it) as sp_upd:
                         upd_dev = self.update_epochs(experience, defer=True)
-                        sp_upd.set(**(self._remat_info or {}))
+                        sp_upd.set(**(self._remat_info or {}),
+                                   **getattr(self, "_sa_counts", {}))
                     with obs.timed("weight_sync"):
                         self.sync_weights()
                     self.global_iter += 1

@@ -719,3 +719,96 @@ def test_olmo_hybrid_update_compiles_for_v5e(one_chip, on_tpu, rows, fits):
     on_chip = (mem.peak_memory_in_bytes + OLMO_RESIDENT_BESIDE_ARGS
                <= V5E_BYTES_LIMIT)
     assert on_chip == (fits == "chip")
+
+
+# -- learned sparse attention (keye_dsa) at the published widths -------------
+
+def _sparse_args(B, Lq, Lk, sharding):
+    return (_sds((B, Lq, 32, 128), BF16, sharding),
+            _sds((B, Lk, 4, 128), BF16, sharding),
+            _sds((B, Lk, 4, 128), BF16, sharding),
+            _sds((B, Lk, Lq), jnp.int8, sharding))
+
+
+@pytest.mark.parametrize("Lq", [8192, 7680], ids=["whole", "prefill"])
+def test_selection_kernel_compiles_for_v5e(Lq, one_chip, on_tpu):
+    """``dsa_select`` at Keye-VL-2.0's indexer (16 heads of 64, one key
+    head, top 2048) over 8192 slots: whole sequences, and the prefill's
+    7680 queries against the whole cache.  A tile's [8192, 128] ordered
+    scores (4 MiB) live in VMEM beside the keys and the int8 output."""
+    from orion_tpu.ops.indexer import select_kernel
+
+    def fn(qi, ki, w):
+        pos = jnp.broadcast_to(jnp.arange(Lq, dtype=jnp.int32), (2, Lq))
+        return select_kernel(qi, ki, w, pos, 2048)
+
+    compiled = jax.jit(fn).lower(
+        _sds((2, Lq, 16, 64), BF16, one_chip),
+        _sds((2, 8192, 64), BF16, one_chip),
+        _sds((2, Lq, 16), jnp.float32, one_chip)).compile()
+    assert _kernel_names(compiled) == ["dsa_select"]
+
+
+@pytest.mark.parametrize("Lq", [8192, 7680], ids=["whole", "prefill"])
+def test_sparse_attention_compiles_for_v5e(Lq, one_chip, on_tpu):
+    """The flash kernels with the selection as an operand (GQA 32 / 4 of
+    128, an int8 [keys, queries] block of 1024 x 1024 a grid step beside
+    q, k and v), forward and both backward, under their own names."""
+    from orion_tpu.ops.pallas.flash_attention import sparse_attention_gqa
+
+    def loss(q, k, v, sel_t):
+        pos = jnp.broadcast_to(jnp.arange(Lq, dtype=jnp.int32), (2, Lq))
+        return jnp.sum(sparse_attention_gqa(q, k, v, pos, sel_t, 128 ** -0.5
+                                            ).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_sparse_args(2, Lq, 8192, one_chip)).compile()
+    assert _kernel_names(compiled) == ["sparse_bwd_dkv", "sparse_bwd_dq",
+                                       "sparse_fwd"]
+
+
+def test_keye_dsa_update_compiles_for_v5e(one_chip, on_tpu):
+    """The shared-backbone PPO update of ``ppo-keye-dsa-ep8-sync`` (6 of
+    Keye-VL-2.0's 48 layers at the published widths, 16 of 128 experts,
+    18 992 rows of the vocabulary, remat, one scanned stack) over 8
+    sequences of 8192 in minibatches of 2.  The update has the selection
+    kernel and the selection-taking flash kernels in it (forward and
+    remat's forward; one backward each), the grouped products of the
+    experts, NONE of the dense flash kernels, and fits the chip: 5.27 GB
+    of parameters and moments (659.2 M x 8 bytes), 10.7 GiB at its
+    peak."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc = dataclasses.replace(ModelConfig.keye_vl2_30b_a3b(), num_layers=6,
+                             experts_held=16, vocab_size=18992,
+                             max_seq_len=8192)
+    assert mc.layer_runs() == ((0, 6, "sparse", "experts"),)
+    shell, pshape, mb = _build_8b_shell(mc)
+    rows, S, T = 2, 8192, 512
+    shell.cfg.rollout.max_prompt_len = S - T
+    shell.cfg.rollout.max_new_tokens = T
+    shapes = {k: (S,) if k == "sequences" else () if k == "prompt_lens"
+              else (T,) for k in mb}
+    experience = {k: _sds((8,) + shapes[k], v.dtype, one_chip)
+                  for k, v in mb.items()}
+    state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                         _abstract_state(shell, pshape))
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+            donate_argnums=(0,)).lower(
+                state, experience,
+                _sds((8 // rows, rows), jnp.int32, one_chip)).compile()
+    names = _kernel_names(compiled)
+    assert names.count("dsa_select") == names.count("sparse_fwd") == 2
+    assert names.count("sparse_bwd_dq") == names.count("sparse_bwd_dkv") == 1
+    assert {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm"} <= set(names)
+    assert not {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} & set(names)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == pytest.approx(5.274e9, rel=5e-3)
+    assert mem.peak_memory_in_bytes <= 12.0 * 2**30

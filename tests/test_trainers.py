@@ -178,6 +178,11 @@ def test_the_fetch_tells_waiting_from_copying():
 
 
 def test_a_row_accounts_for_its_iteration():
+    # The thread's CPU clock may tick in steps of 10 ms (the chip tool's
+    # sandboxed kernel, a loaded box) where the wall clock reads to the
+    # microsecond: a CPU time may then exceed the wall it lies in, or a
+    # sum of two the whole that holds them, by one tick.
+    tick = 0.01
     hist, events = _traced_grpo_run()
     its = [e for e in events if e["name"] == "train.iteration"]
     batches = [e for e in events if e["name"] == "data.next_batch"]
@@ -188,17 +193,17 @@ def test_a_row_accounts_for_its_iteration():
         assert row["samples_per_sec"] == pytest.approx(16 / row["iter_s"])
         # an iteration's wall holds its parts
         assert row["fetch_wait_s"] + row["fetch_copy_s"] <= row["iter_s"]
-        assert 0.0 <= row["host_gc_s"] <= row["host_cpu_s"] + 1e-3
-        assert row["host_cpu_s"] <= row["iter_s"] + 1e-3
+        assert 0.0 <= row["host_gc_s"] <= row["host_cpu_s"] + tick
+        assert row["host_cpu_s"] <= row["iter_s"] + tick
         # the span's own account: the thread's CPU time, the collector,
         # the kernel's counters
-        assert 0.0 < it["cpu"] <= it["dur"] + 1e-3
+        assert 0.0 < it["cpu"] <= it["dur"] + tick
         assert {"gc_us", "gc_n", "nivcsw", "majflt"} <= set(it["attrs"])
         assert it["attrs"]["gc_us"] <= row["host_gc_s"] * 1e6 + 1
     # in steady state the row's CPU time runs from the batch fetch to the
     # next one: the spans' own, and the little between them
     for row, it, batch in list(zip(hist, its, batches))[1:-1]:
-        assert row["host_cpu_s"] >= it["cpu"] + batch["cpu"] - 1e-4
+        assert row["host_cpu_s"] >= it["cpu"] + batch["cpu"] - tick
 
 
 def test_the_fetch_is_one_device_get(monkeypatch):
