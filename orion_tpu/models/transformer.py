@@ -455,7 +455,10 @@ class SparseAttention(Attention):
     The cache holds ``ki`` [B, Lmax, dim] beside ``k`` and ``v``.
     Whole sequences and prefill run the flash kernels over all causal
     blocks with the selection as an operand (``sparse_fwd`` ...); one
-    new token against the cache gathers its selected rows of k and v.
+    new token against the cache reads k and v where they lie, under a
+    mask of the kept slots (``ops/pallas/sparse_step.py``: the kernel
+    ``sparse_step`` over the filled slots on one TPU device, XLA's
+    einsum over the whole cache elsewhere).
     Where a call has no more keys than ``sa_topk`` nothing is selected
     and the attention is :class:`Attention`'s, exactly.
     """
@@ -464,6 +467,7 @@ class SparseAttention(Attention):
     def __call__(self, x, positions, layer_cache=None):
         from orion_tpu.ops import indexer
         from orion_tpu.ops.attention import sparse_attention
+        from orion_tpu.ops.pallas import sparse_step
 
         cfg = self.cfg
         B, L, _ = x.shape
@@ -515,14 +519,17 @@ class SparseAttention(Attention):
             out = attention(q, k, v, mask, scale=scale,
                             impl=cfg.attention_impl, q_positions=positions)
         elif layer_cache is not None and L == 1:
-            idx, valid = indexer.select_step(qi[:, 0], ki, w[:, 0],
-                                             positions[:, 0], topk)
+            keep = indexer.select_step(qi[:, 0], ki, w[:, 0],
+                                       positions[:, 0], topk)
             with jax.named_scope("attn.sparse"):
-                rows = idx[:, :, None, None]
-                out = attention(q, jnp.take_along_axis(k, rows, axis=1),
-                                jnp.take_along_axis(v, rows, axis=1),
-                                valid[:, None, :], scale=scale,
-                                impl="reference")
+                # k and v where they lie, the softmax over the kept
+                # slots (which lie under the positional rule already)
+                if sparse_step.step_form(Lk) == "kernel":
+                    out = sparse_step.sparse_step(q, k, v, keep,
+                                                  positions[:, 0], scale)
+                else:
+                    out = attention(q, k, v, keep[:, None, :], scale=scale,
+                                    impl="reference")
         else:
             sel_t = indexer.select(qi, ki, w, positions, topk,
                                    cfg.sa_q_chunk, cfg.sa_kv_chunk)
@@ -1216,6 +1223,17 @@ class Transformer(nn.Module):
 # ---------------------------------------------------------------------------
 
 
+def cache_slots(max_len: int) -> int:
+    """The slots :func:`init_cache` allocates for ``max_len`` positions.
+    Round the length up to a multiple of 8: Mosaic tiles the cache
+    axis and needs multiple-of-8 blocks (an unlucky max_len like 350
+    = 2·5²·7 would otherwise force one full-length block — VMEM
+    pressure at long context, found on-chip r5 via the speculative
+    verify chunk).  Slots carry the slot==position causal rule, so
+    the padded tail is masked for every real query."""
+    return -(-max_len // 8) * 8
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype: Optional[Any] = None, quantized: bool = False):
     """Dense pre-allocated KV cache.  ``scan_layers`` models use a
@@ -1224,13 +1242,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     per-token-per-head f32 scales (RolloutConfig.quantize_kv — see
     ops/quant.py)."""
     dtype = dtype or _dt(cfg.dtype)
-    # Round the length up to a multiple of 8: Mosaic tiles the cache
-    # axis and needs multiple-of-8 blocks (an unlucky max_len like 350
-    # = 2·5²·7 would otherwise force one full-length block — VMEM
-    # pressure at long context, found on-chip r5 via the speculative
-    # verify chunk).  Slots carry the slot==position causal rule, so
-    # the padded tail is masked for every real query.
-    max_len = -(-max_len // 8) * 8
+    max_len = cache_slots(max_len)
     if cfg.pattern and quantized:
         raise ValueError(
             "there is no int8 latent cache (rollout.quantize_kv) for "

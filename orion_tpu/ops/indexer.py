@@ -24,7 +24,8 @@ change no equation).
   the last tie that fits by a search over the slots' bits.  Elsewhere
   ``jax.lax.top_k`` (a stable sort: the lower slot first).
 - :func:`select_step` gives one new query's selection against a cache of
-  indexer keys as slot indices, for the gathered one-token step.
+  indexer keys as a mask of the slots, for the one-token step that reads
+  k and v in place (``ops/pallas/sparse_step.py``).
 
 No gradient passes: the selection is discrete, the callers hand over
 ``stop_gradient`` operands (models/transformer.py SparseAttention).
@@ -248,12 +249,19 @@ def select(qi, ki, w, q_positions, topk: int, q_chunk: int = 512,
 
 def select_step(qi, ki_cache, w, positions, topk: int):
     """One new query a sequence against the cache: qi [B, Hi, Di],
-    ki_cache [B, Lmax, Di], w [B, Hi] float32, positions [B] -> (idx [B,
-    k] int32 slots, the kept ones first by score, valid [B, k] bool), k
-    = min(topk, Lmax).  ``jax.lax.top_k``: exact, the lower slot first on
-    a tie."""
+    ki_cache [B, Lmax, Di], w [B, Hi] float32, positions [B] -> keep [B,
+    Lmax] bool, the set ``jax.lax.top_k`` keeps (exact; the lower slot
+    first on a tie; every valid slot where there are no more than
+    ``topk``; never a slot past the position).  From the k-th largest
+    score: the larger ones, and of the equal ones those up to the last
+    slot ``top_k`` took (it takes the lowest first)."""
     with jax.named_scope("attn.select"):
         scores = index_scores(qi[:, None], ki_cache, w[:, None],
                               positions[:, None], ki_cache.shape[1])[:, 0]
         vals, idx = jax.lax.top_k(scores, min(topk, ki_cache.shape[1]))
-        return idx.astype(jnp.int32), vals > -jnp.inf
+        kth = vals[:, -1:]
+        edge = jnp.max(jnp.where(vals == kth, idx, -1), axis=-1,
+                       keepdims=True)
+        slots = jnp.arange(scores.shape[1], dtype=idx.dtype)
+        keep = (scores > kth) | ((scores == kth) & (slots[None, :] <= edge))
+        return keep & (scores > -jnp.inf)

@@ -24,7 +24,7 @@ import numpy as np
 import optax
 
 from orion_tpu.config import OptimizerConfig, TrainConfig
-from orion_tpu.models.transformer import Transformer
+from orion_tpu.models.transformer import Transformer, cache_slots
 from orion_tpu.ops.logprobs import completion_logprobs, entropy_from_logits
 from orion_tpu.rollout import GenerationResult, RolloutEngine
 
@@ -194,6 +194,24 @@ def sa_key_counts(lens, topk: int) -> dict:
     selected = m * (m + 1) // 2 + (n - m) * topk
     return {"sa_keys_valid": int(valid.sum()),
             "sa_keys_selected": int(selected.sum())}
+
+
+def sa_step_read(lens, cache_len: int, new_tokens: int, mc) -> dict:
+    """{sparse_step, sa_step_bytes}: the form the selected one-token
+    step takes against a cache of ``cache_len`` slots in this process's
+    traces (``ops/pallas/sparse_step.py::step_form``: ``kernel`` /
+    ``masked``; not under an ``sa_`` name: the benchmark's reader of the
+    span, ``roofline_keye_dsa.py::span_counts``, takes every ``sa_*``
+    attribute for a number) and the bytes of k and v one step then reads
+    a layer, the mean over the ``new_tokens`` steps of prompts of
+    ``lens`` real tokens (``step_slots``).  Host integers, from shapes
+    and lengths."""
+    from orion_tpu.ops.pallas import sparse_step
+
+    form = sparse_step.step_form(cache_len)
+    slots = sparse_step.step_slots(form, lens, cache_len, new_tokens)
+    row = mc.num_kv_heads * mc.head_dim * jnp.dtype(mc.dtype).itemsize
+    return {"sparse_step": form, "sa_step_bytes": int(2 * slots * row)}
 
 
 def state_out_shardings(state: "TrainState"):
@@ -585,7 +603,8 @@ class BaseTrainer:
         sparse = {}
         if self.cfg.model.sa_topk:
             # the indexer's keys, part of cache_bytes: a step reads them
-            # up to where they are filled, and of k and v sa_topk rows
+            # up to where they are filled; what it reads of k and v is
+            # sa_step_bytes, from the prompts' lengths (make_experience)
             sparse = {"index_cache_bytes":
                       eng.index_cache_bytes(*prompts_shape),
                       "sa_topk": self.cfg.model.sa_topk}
@@ -714,6 +733,10 @@ class BaseTrainer:
                 # over the prefill's real queries, from the host's lengths
                 sp.set(**sa_key_counts(lens,
                                        self.cfg.model.sa_topk))
+                T = self.engine.cfg.max_new_tokens
+                sp.set(**sa_step_read(
+                    lens, cache_slots(int(ids.shape[1]) + T), T,
+                    self.cfg.model))
             result = self.generate(
                 ids, lens, group_size=getattr(self.cfg, "group_size", 1))
         pend, self._pending_fetch = self._pending_fetch, None

@@ -812,3 +812,65 @@ def test_keye_dsa_update_compiles_for_v5e(one_chip, on_tpu):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(5.274e9, rel=5e-3)
     assert mem.peak_memory_in_bytes <= 12.0 * 2**30
+
+
+@pytest.mark.parametrize("new_tokens, form", [(512, "kernel"),
+                                              (256, "masked")])
+def test_selected_step_reads_the_cache_in_place(new_tokens, form, one_chip,
+                                                on_tpu):
+    """The fixed-batch engine's whole program (prefill + the decode
+    ``while_loop``) of ``ppo-keye-dsa-ep8-sync`` (two of its six layers)
+    at 8 x 7680 + 512: the decode loop's body holds one ``sparse_step``
+    kernel a layer, which takes k and v as they lie in the loop's cache
+    (a bitcast: no copy or fusion of the cache's shape feeds it), and no
+    gather of 2048 rows a sequence (``bf16[16384,4,128]``).  At 7680 +
+    256 the cache is 15.5 of the kernel's blocks of 512 slots: XLA's
+    einsum under the mask, no kernel, and no gather.  (7680 + 500, a
+    cache of 8 x 1023 slots, compiles under neither: the prefill's
+    ``sparse_fwd`` runs out of VMEM over such a cache.)"""
+    import dataclasses
+    import re
+
+    from orion_tpu.config import ModelConfig, RolloutConfig
+    from orion_tpu.models import Transformer, init_params
+    from orion_tpu.models.transformer import cache_slots
+    from orion_tpu.ops.pallas import sparse_step
+    from orion_tpu.rollout.engine import RolloutEngine
+
+    mc = dataclasses.replace(ModelConfig.keye_vl2_30b_a3b(), num_layers=2,
+                             experts_held=16, vocab_size=18992,
+                             max_seq_len=8192)
+    assert sparse_step.step_form(cache_slots(7680 + new_tokens)) == form
+    model = Transformer(mc)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: init_params(model, jax.random.key(0), mc)))
+    eng = RolloutEngine(model, mc, RolloutConfig(
+        max_prompt_len=7680, max_new_tokens=new_tokens), eos_token_id=0,
+        pad_token_id=0)
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    with jax.default_matmul_precision("default"):
+        compiled = eng._generate_jit.lower(
+            params, _sds((8, 7680), jnp.int32, one_chip),
+            _sds((8,), jnp.int32, one_chip),
+            _sds(rng.shape, rng.dtype, one_chip),
+            max_new_tokens=new_tokens).compile()
+    text = compiled.as_text()
+    assert "bf16[16384,4,128]" not in text
+    if form == "masked":
+        assert "sparse_step" not in _kernel_names(compiled)
+        return
+    assert _kernel_names(compiled).count("sparse_step") == 2
+    decode = [body for body in _while_bodies(text).values()
+              if "%sparse_step" in body]
+    assert len(decode) == 1                     # the decode loop's body
+    steps = re.findall(r"%sparse_step[.\d]* = [^\n]*", decode[0])
+    assert len(steps) == 2
+    for call in steps:
+        operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+        k, v = [o.strip().lstrip("%") for o in operands.split(",")][2:4]
+        for name in (k, v):
+            made = re.search(r"%%%s = (\S+) (\w[\w\-]*)\(" % re.escape(name),
+                             decode[0])
+            assert made and made.group(2) == "bitcast", (name, made)
+            assert made.group(1).startswith("bf16[8,32768,128]")

@@ -168,21 +168,39 @@ def test_no_more_keys_than_topk_is_dense_grouped_query_attention():
     assert float(jnp.max(jnp.abs(other - want))) > 1e-3
 
 
-@pytest.mark.parametrize("scan", [False, True])
-def test_prefill_then_decode_through_the_cache_equals_the_full_forward(scan):
+def _step_form(monkeypatch, form):
+    """The one-token step through one of its forms whatever the device
+    (the CPU's own is ``masked``)."""
+    from orion_tpu.ops.pallas import sparse_step
+
+    monkeypatch.setattr(sparse_step, "step_form", lambda cache_len: form)
+
+
+@pytest.mark.parametrize("scan, form", [
+    (False, "masked"), (True, "masked"), (False, "kernel"),
+    (True, "kernel")])
+def test_prefill_then_decode_through_the_cache_equals_the_full_forward(
+        scan, form, monkeypatch):
     """Prefill of unequal prompts (24 and 17 of 24 slots) through the
-    cache, then one-token steps that select and gather: the logits of
-    every real position equal the full forward's."""
+    cache, then one-token steps that select and attend in place under
+    the mask (XLA's einsum; the kernel, interpreted, over blocks of 16
+    slots that no filled length is a multiple of): the logits of every
+    real position equal the full forward's."""
+    from orion_tpu.ops.pallas import sparse_step
+
+    P, T = 24, 24
+    Lmax = P + T
+    _step_form(monkeypatch, form)
+    monkeypatch.setattr(sparse_step, "BLOCK_SLOTS", 16)
     cfg, model, params = _model(scan_layers=scan)
     rs = np.random.RandomState(4)
-    P, T = 24, 24
     lens = np.array([24, 17])
     full_ids = rs.randint(2, 256, (2, P + T)).astype(np.int32)
     dmodel, dcfg = make_decode_twin(model, cfg)
     dparams = prep_decode_params(params, cfg)
-    cache = init_cache(dcfg, 2, P + T)
+    cache = init_cache(dcfg, 2, Lmax)
     assert set(cache[0]) == {"k", "v", "ki"}
-    assert cache[0]["ki"].shape == (2, P + T, cfg.sa_index_head_dim)
+    assert cache[0]["ki"].shape == (2, Lmax, cfg.sa_index_head_dim)
     prompt = np.where(np.arange(P)[None] < lens[:, None], full_ids[:, :P], 0)
     pos = _positions(jnp.asarray(prompt))
     logits, cache = dmodel.apply(
@@ -197,11 +215,12 @@ def test_prefill_then_decode_through_the_cache_equals_the_full_forward(scan):
     for b in range(2):
         np.testing.assert_allclose(logits[b, :lens[b]],
                                    want[b][:lens[b]], atol=2e-5)
+    one_step = jax.jit(lambda tok, cur, cache: dmodel.apply(
+        {"params": dparams}, tok[:, None], cur[:, None], cache))
     cur = jnp.asarray(lens, jnp.int32)
     for t in range(T):
         tok = jnp.asarray([seqs[b][lens[b] + t] for b in range(2)])
-        step, cache = dmodel.apply({"params": dparams}, tok[:, None],
-                                   cur[:, None], cache)
+        step, cache = one_step(tok, cur, cache)
         for b in range(2):
             np.testing.assert_allclose(step[b, 0], want[b][lens[b] + t],
                                        atol=2e-5)
@@ -278,16 +297,49 @@ def test_selection_over_a_longer_cache_than_the_queries(monkeypatch):
 
 
 def test_one_step_selects_what_the_whole_sequence_selects():
-    """The decode step's slots for the query at position t are row t of
-    the whole-sequence selection: identical, ties included."""
+    """The decode step's mask of the slots for the query at position t
+    is row t of the whole-sequence selection: identical, ties
+    included."""
     qi, ki, w, pos = _index_inputs(ties=True)
     whole = np.asarray(indexer._select_jnp(qi, ki, w, pos, 8, 16, 16))
     for t in (3, 7, 8, 40, 63):
-        idx, valid = indexer.select_step(qi[:, t], ki, w[:, t], pos[:, t], 8)
-        for b in range(2):
-            got = np.zeros(64, np.int8)
-            got[np.asarray(idx[b])[np.asarray(valid[b])]] = 1
-            np.testing.assert_array_equal(got, whole[b, :, t])
+        mask = indexer.select_step(qi[:, t], ki, w[:, t], pos[:, t], 8)
+        np.testing.assert_array_equal(np.asarray(mask, np.int8),
+                                      whole[:, :, t])
+
+
+@pytest.mark.parametrize("case", [
+    "ties_at_the_kth_score", "every_score_equal", "fewer_valid_than_topk",
+    "rows_of_different_filled_lengths"])
+def test_the_steps_mask_is_top_ks_set(case):
+    """``select_step``'s mask against a stable sort a row (numpy),
+    where the k-th score is shared."""
+    qi, ki, w, _ = _index_inputs()
+    qi, w = qi[:, 50], w[:, 50]
+    pos = jnp.asarray([63, 63])
+    if case == "ties_at_the_kth_score":
+        # three distinct keys: every score is one of three values, so
+        # the 8th largest sits inside a run of ~21 equal ones
+        ki = jnp.tile(ki[:, :3], (1, 22, 1))[:, :64]
+    elif case == "every_score_equal":
+        w = w * 0
+    elif case == "fewer_valid_than_topk":
+        pos = jnp.asarray([4, 6])
+    else:
+        pos = jnp.asarray([5, 40])
+        ki = ki.at[:, 10:20].set(ki[:, 0:10])
+    mask = np.asarray(indexer.select_step(qi, ki, w, pos, 8), np.int8)
+    want = _brute_force(qi[:, None], ki, w[:, None], pos[:, None], 8)[..., 0]
+    np.testing.assert_array_equal(mask, want)
+    np.testing.assert_array_equal(mask.sum(-1),
+                                  np.minimum(np.asarray(pos) + 1, 8))
+    if case == "ties_at_the_kth_score":
+        scores = np.asarray(indexer.index_scores(
+            qi[:, None], ki, w[:, None], pos[:, None], 64))[:, 0]
+        kth = np.sort(scores, axis=-1)[:, -8]
+        assert ((scores == kth[:, None]).sum(-1) > 8).all()
+    if case == "every_score_equal":
+        assert (mask[:, :8] == 1).all()
 
 
 # ---------------------------------------------------------------------------
@@ -335,6 +387,83 @@ def test_sparse_forward_over_a_cache_longer_than_the_queries(monkeypatch):
     got = fa.sparse_attention_gqa(q, k, v, pos, sel_t, 0.25, 128, 128)
     np.testing.assert_allclose(got, reference_attention_gqa(
         q, k, v, mask, 0.25), atol=5e-6)
+
+
+@pytest.mark.parametrize("block", [16, 64])
+def test_the_step_kernel_equals_the_masked_einsum(block, monkeypatch):
+    """``sparse_step`` interpreted, 8 query heads on 2 key heads over a
+    cache of 64 slots in blocks of 16 (and one block of 64): filled
+    lengths that are no multiple of the block, a row that keeps nothing
+    in its first block, a row that keeps one slot."""
+    from orion_tpu.ops.pallas import sparse_step
+
+    monkeypatch.setattr(sparse_step, "BLOCK_SLOTS", block)
+    k = jax.random.split(jax.random.key(6), 4)
+    q = jax.random.normal(k[0], (3, 1, 8, 16))
+    kk = jax.random.normal(k[1], (3, 64, 2, 16))
+    v = jax.random.normal(k[2], (3, 64, 2, 16))
+    pos = jnp.asarray([63, 37, 21])
+    slots = jnp.arange(64)
+    keep = (jax.random.uniform(k[3], (3, 64)) < 0.4) \
+        & (slots[None, :] <= pos[:, None])
+    keep = keep.at[1, :20].set(False).at[1, 37].set(True)
+    keep = keep.at[2].set(slots == 9)
+    got = sparse_step.sparse_step(q, kk, v, keep, pos, 0.25)
+    want = reference_attention_gqa(q, kk, v, keep[:, None, :], 0.25)
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    np.testing.assert_allclose(got[2, 0], jnp.repeat(v[2, 9], 4, axis=0),
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("form", ["masked", "kernel"])
+def test_the_masked_step_gathers_no_row_of_the_cache(form, monkeypatch):
+    """The decode program of the tiny model: no ``gather`` takes an
+    operand of the cache's shape (k and v are read where they lie), and
+    the kernel is in it once a layer where the form is the kernel's."""
+    from orion_tpu.ops.pallas import sparse_step
+
+    _step_form(monkeypatch, form)
+    monkeypatch.setattr(sparse_step, "BLOCK_SLOTS", 16)
+    cfg, model, params = _model()
+    dmodel, dcfg = make_decode_twin(model, cfg)
+    dparams = prep_decode_params(params, cfg)
+    cache = init_cache(dcfg, 2, 48)
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
+
+    jaxpr = jax.make_jaxpr(lambda tok, cur, cache: dmodel.apply(
+        {"params": dparams}, tok, cur, cache))(
+            jnp.zeros((2, 1), jnp.int32), jnp.full((2, 1), 30), cache)
+    eqns = list(walk(jaxpr.jaxpr))
+    of_the_cache = [e for e in eqns if e.primitive.name == "gather"
+                    and e.invars[0].aval.shape == cache[0]["k"].shape]
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert not of_the_cache
+    assert kernels.count("sparse_step") == (cfg.num_layers
+                                            if form == "kernel" else 0)
+
+
+@pytest.mark.parametrize("cache_len, device, want", [
+    (8192, "kernel", "kernel"), (512, "kernel", "kernel"),
+    # 7680 + 500 new tokens, rounded up to 8: 8 x 1023 slots
+    (8184, "kernel", "masked"), (48, "kernel", "masked"),
+    (8192, "jnp", "masked")])
+def test_the_step_is_the_kernels_over_whole_blocks_only(
+        cache_len, device, want, monkeypatch):
+    """The rule that picks the step's form: the kernel where the trace
+    is for one TPU device AND the cache is whole blocks of 512 slots
+    (no smaller block is tried: a cache of 8 x 1023 slots would run in
+    blocks of 8); XLA's einsum under the mask elsewhere."""
+    from orion_tpu.ops.pallas import sparse_step
+
+    monkeypatch.setattr(indexer, "select_form", lambda: device)
+    assert sparse_step.BLOCK_SLOTS == 512
+    assert sparse_step.step_form(cache_len) == want
 
 
 def test_the_dense_kernels_take_no_selection_operand():
@@ -544,6 +673,59 @@ def test_key_counts_from_lengths():
     for n in (6656, 8192):
         c = sa_key_counts([n], topk=2048)
         assert 0.43 < c["sa_keys_selected"] / c["sa_keys_valid"] < 0.53
+
+
+@pytest.mark.parametrize("form, slots", [
+    # blocks of 16 up to each filled slot, the mean over 16 steps: 20..35
+    # reads 32 slots twelve times and 48 four, 30..45 reads 32 twice
+    ("kernel", 36 + 46),
+    ("masked", 2 * 48)])
+def test_step_read_from_lengths(form, slots, monkeypatch):
+    """What the ``rollout.dispatch`` span says of the one-token step: its
+    form, and the bytes of k and v a step reads a layer (2 key heads of
+    16, float32)."""
+    from orion_tpu.ops.pallas import sparse_step
+    from orion_tpu.trainers.base import sa_step_read
+
+    _step_form(monkeypatch, form)
+    monkeypatch.setattr(sparse_step, "BLOCK_SLOTS", 16)
+    cfg = ModelConfig.tiny("keye_dsa", dtype="float32")
+    got = sa_step_read([20, 30], 48, 16, cfg)
+    assert got == {"sparse_step": form,
+                   "sa_step_bytes": 2 * slots * 2 * 16 * 4}
+
+
+def test_the_rollout_span_says_how_the_step_reads_k_and_v(tmp_path):
+    """One iteration through the launcher with ``obs.trace=true``: the
+    ``rollout.dispatch`` span carries the step's form (the CPU's: XLA's
+    einsum under the mask, over all 64 slots of 4 sequences) and the
+    bytes of k and v it reads a layer, beside ``sa_topk``; every
+    ``sa_*`` attribute is a number (the benchmark's reader takes them
+    for such)."""
+    import json
+
+    from orion_tpu import launch
+
+    hist = launch.main([
+        "ppo", "model_preset=tiny_keye_dsa", "model.remat=true",
+        "model.scan_layers=true", "share_backbone=true",
+        "model.max_seq_len=64", "rollout.max_prompt_len=48",
+        "rollout.max_new_tokens=16", "data.synthetic_min_len=30",
+        "data.synthetic_max_len=48", "rollout_batch_size=4",
+        "minibatch_size=2", "num_epochs=1", "data.dataset=synthetic",
+        "reward=length", "total_iterations=1", "obs.trace=true",
+        f"log_dir={tmp_path}"])
+    assert len(hist) == 1 and np.isfinite(hist[0]["loss"])
+    with open(tmp_path / f"spans-{os.getpid()}.json") as f:
+        events = json.load(f)["traceEvents"]
+    (span,) = [e["args"] for e in events if e["name"] == "rollout.dispatch"]
+    cfg = ModelConfig.tiny("keye_dsa")
+    row = cfg.num_kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
+    assert span["sparse_step"] == "masked"
+    assert span["sa_step_bytes"] == 2 * 4 * 64 * row
+    assert span["sa_topk"] == cfg.sa_topk == 8
+    assert all(isinstance(v, (int, float)) for k, v in span.items()
+               if k.startswith(("sa_", "index_")))
 
 
 def test_long_synthetic_prompts_and_the_short_ones_unchanged():
