@@ -22,12 +22,18 @@ from typing import Any, Optional
 LATENT_ARCHS = ("deepseek_v3", "kimi_linear")
 #: The archs whose model is a per-layer pattern of (mixer, FFN) kinds
 #: over RMSNorm blocks (``ModelConfig.layer_kinds``).
-PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa")
+PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa", "nemotron_h")
 #: The archs whose layers end in the dropless expert layer
 #: (``ops.moe.TopKMoE``) and so share its fields and their checks.
-EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa",)
+EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa", "nemotron_h")
+#: The mixers that run the delta rule (``ops/kda.py``).
+DELTA_MIXERS = ("kda", "gdn")
 #: The mixers whose per-sequence state is not indexed by position.
-RECURRENT_MIXERS = ("kda", "gdn")
+RECURRENT_MIXERS = DELTA_MIXERS + ("mamba2",)
+#: nemotron_h's ``hybrid_override_pattern`` characters -> (mixer, ffn)
+#: halves of a block.
+PATTERN_HALVES = {"M": ("mamba2", None), "*": ("attention", None),
+                  "E": (None, "experts")}
 #: olmo_hybrid's published ``layer_types`` entries -> mixers.
 LAYER_TYPE_MIXERS = {"linear_attention": "gdn", "full_attention": "attention"}
 
@@ -43,7 +49,7 @@ class ModelConfig:
     """
 
     # "llama" | "neox" | "deepseek_v3" | "kimi_linear" | "olmo_hybrid"
-    # | "keye_dsa"
+    # | "keye_dsa" | "nemotron_h"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -99,7 +105,7 @@ class ModelConfig:
     v_head_dim: int = 0
     n_routed_experts: int = 0      # the router's width: ALL experts
     num_experts_per_tok: int = 0
-    n_shared_experts: int = 0      # one SwiGLU of n x moe_intermediate_size
+    n_shared_experts: int = 0      # ONE shared expert of n x its width
     moe_intermediate_size: int = 0
     first_k_dense_replace: int = 0
     routed_scaling_factor: float = 1.0
@@ -155,6 +161,42 @@ class ModelConfig:
     sa_q_chunk: int = 512
     sa_kv_chunk: int = 512
     moe_scoring: str = "sigmoid"
+    # arch="nemotron_h" (NVIDIA Nemotron-3's language model): pre-norm
+    # RMSNorm blocks whose halves hybrid_override_pattern names, one
+    # character a published layer, read up to num_layers characters: "M"
+    # a Mamba-2 mixer (models/transformer.py Mamba2, ops/mamba2.py:
+    # mamba_num_heads heads of mamba_head_dim, a float32 state of
+    # head_dim x ssm_state_size a head, B and C shared by the heads of
+    # one of mamba_n_groups groups (the published n_groups), ONE
+    # depthwise convolution of mamba_conv_kernel taps over x, B and C,
+    # chunks of mamba_chunk_size), "*" grouped-query attention, "E" the
+    # expert layer.  A mixer and an "E" behind it are one block of this
+    # repo; any other character is a block with that half alone
+    # (layer_kinds).  The expert layer's own keys: moe_activation
+    # ("swiglu": silu(gate) * up of a fused gate|up product; "relu2":
+    # relu(up)^2, no gate), moe_latent_size > 0: the routed experts
+    # work in a latent of that width between fc1_latent_proj and
+    # fc2_latent_proj (router and shared expert read the block's own
+    # width), moe_shared_expert_intermediate_size > 0: the shared
+    # expert's own width (0: moe_intermediate_size).
+    hybrid_override_pattern: str = ""
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 0
+    ssm_state_size: int = 0
+    mamba_conv_kernel: int = 0
+    mamba_chunk_size: int = 128
+    moe_activation: str = "swiglu"
+    moe_latent_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    # The chip's share of every mixer's heads under tensor parallelism:
+    # (which share, of how many).  A share holds its consecutive part of
+    # the heads (num_heads, mamba_num_heads: the keys keep the published
+    # counts) and what follows from them: a Mamba-2 layer's groups, and
+    # the key-value heads its query heads read (one repeated where the
+    # shares outnumber them).  What the absent heads would add to a
+    # mixer's output is left out, as for the experts.  (0, 1): all.
+    head_share: tuple = (0, 1)
 
     def __post_init__(self) -> None:
         if self.arch in EXPERT_ARCHS:
@@ -167,6 +209,14 @@ class ModelConfig:
             self._check_kimi_linear()
         if self.arch == "olmo_hybrid":
             self._check_olmo_hybrid()
+        self.head_share = tuple(self.head_share)
+        if self.arch == "nemotron_h":
+            self._check_nemotron_h()
+        elif self.head_share != (0, 1):
+            raise ValueError(
+                f"model.head_share={self.head_share}: only arch="
+                "'nemotron_h' was run against its reference on a share of "
+                "its heads")
         if self.head_dim == 0:
             self.head_dim = self.hidden_size // self.num_heads
         if self.arch == "neox":
@@ -190,6 +240,10 @@ class ModelConfig:
             raise ValueError(
                 f"model.moe_scoring={self.moe_scoring!r}: 'sigmoid' or "
                 "'softmax'")
+        if self.moe_activation not in ("swiglu", "relu2"):
+            raise ValueError(
+                f"model.moe_activation={self.moe_activation!r}: 'swiglu' "
+                "or 'relu2'")
         if self.num_experts or self.quantize_dense or self.tie_word_embeddings:
             raise ValueError(
                 f"arch={self.arch!r} has its own expert layer (num_experts "
@@ -235,6 +289,47 @@ class ModelConfig:
                 "(first_k_dense_replace = 0, the published "
                 "decoder_sparse_step 1 / mlp_only_layers []), and the "
                 "indexer scores whole sequences (seq_shard_activations)")
+
+    def _check_nemotron_h(self) -> None:
+        for key in ("mamba_num_heads", "mamba_head_dim", "mamba_n_groups",
+                    "ssm_state_size", "mamba_conv_kernel",
+                    "mamba_chunk_size"):
+            if getattr(self, key) <= 0:
+                raise ValueError(f"arch='nemotron_h' needs model.{key} > 0")
+        pattern = self.hybrid_override_pattern[:self.num_layers]
+        unknown = set(pattern) - set(PATTERN_HALVES)
+        if unknown or len(pattern) < self.num_layers:
+            raise ValueError(
+                f"model.hybrid_override_pattern names {sorted(PATTERN_HALVES)}"
+                f", one character a layer, at least num_layers="
+                f"{self.num_layers} of them (got "
+                f"{len(self.hybrid_override_pattern)}, unknown: "
+                f"{sorted(unknown)}; '-', a dense MLP alone, is not "
+                "written)")
+        which, of = self.head_share if len(self.head_share) == 2 else (0, 0)
+        if not 0 <= which < of:
+            raise ValueError(f"model.head_share={self.head_share}: (which "
+                             "share, of how many)")
+        if self.mamba_num_heads % self.mamba_n_groups \
+                or self.mamba_n_groups % of or self.num_heads % of \
+                or self.num_heads % self.num_kv_heads \
+                or max(of, self.num_kv_heads) % min(of, self.num_kv_heads):
+            raise ValueError(
+                f"arch='nemotron_h' with {of} head shares: a share holds "
+                "whole Mamba-2 groups (the gated norm runs over a group: "
+                "part of one would need an exchange), so mamba_n_groups "
+                "divides by it, as do the query heads; key-value heads "
+                "divide by the shares or the shares by them")
+        if self.attention_impl in ("ring", "ulysses"):
+            raise ValueError(
+                f"attention_impl={self.attention_impl!r} cannot run "
+                "arch='nemotron_h': there is no hand-over of a state-space "
+                "layer's state between sequence shards")
+        if self.seq_shard_activations or self.first_k_dense_replace:
+            raise ValueError(
+                "arch='nemotron_h': the pattern names every layer "
+                "(first_k_dense_replace = 0), and a state-space layer takes "
+                "whole sequences (seq_shard_activations)")
 
     def _check_kimi_linear(self) -> None:
         for key in ("kda_num_heads", "kda_head_dim",
@@ -290,20 +385,50 @@ class ModelConfig:
         kinds over RMSNorm blocks: see :meth:`layer_kinds`."""
         return self.arch in PATTERN_ARCHS
 
-    def delta_head_dims(self) -> tuple:
-        """(dk, dv) of the recurrent layers' heads: what
-        ``ops.kda.chunk_form`` is asked with."""
+    def heads_held(self) -> dict:
+        """What ``head_share`` leaves here of each mixer's heads:
+        {"q", "kv": attention's query and key-value heads, "mamba",
+        "groups": a Mamba-2 layer's heads and groups}."""
+        of = self.head_share[1]
+        return {"q": self.num_heads // of,
+                "kv": max(1, self.num_kv_heads // of),
+                "mamba": self.mamba_num_heads // of,
+                "groups": self.mamba_n_groups // of}
+
+    def delta_head_dims(self) -> Optional[tuple]:
+        """(dk, dv) of the delta-rule layers' heads: what
+        ``ops.kda.chunk_form`` is asked with; None without such a
+        layer."""
+        if not any(m in DELTA_MIXERS for m, _ in self.layer_kinds()):
+            return None
         if self.arch == "olmo_hybrid":
             return self.linear_key_head_dim, self.linear_value_head_dim
         return self.kda_head_dim, self.kda_head_dim
 
     def layer_kinds(self) -> tuple:
-        """((mixer, ffn), ...) per layer: the model's description.
+        """((mixer, ffn), ...) per block: the model's description.
         mixer: "attention" (per-head K/V cache), "sparse" (the same
         beside the indexer's keys: {k, v, ki}), "latent" ({c, k_rope}
-        cache), "kda" or "gdn" (a recurrent state, no position; a decay
-        a key channel or one a head); ffn: "dense", "gshard"
-        (num_experts) or "experts" (the dropless layer)."""
+        cache), "kda" or "gdn" (the delta rule: a recurrent state, no
+        position; a decay a key channel or one a head), "mamba2" (a
+        state-space layer: {S, conv}, no position) or None (no mixer
+        half: the block caches {}); ffn: "dense" (a SwiGLU or GELU MLP),
+        "gshard" (num_experts), "experts" (the dropless layer, its
+        activation ``moe_activation``) or None (no feed-forward half).
+        Every model but nemotron_h has both halves in every block, one
+        block a published layer; nemotron_h's pattern names halves, and
+        a mixer with an "E" behind it make one block ("M*" leaves the
+        first without a feed-forward half, "EE" the second without a
+        mixer)."""
+        if self.arch == "nemotron_h":
+            out = []
+            for mixer, ffn in (PATTERN_HALVES[c] for c in
+                               self.hybrid_override_pattern[:self.num_layers]):
+                if ffn and out and out[-1][1] is None and out[-1][0]:
+                    out[-1] = (out[-1][0], ffn)
+                else:
+                    out.append((mixer, ffn))
+            return tuple(out)
         if not self.pattern:
             return (("attention",
                      "gshard" if self.num_experts else "dense"),
@@ -440,6 +565,34 @@ class ModelConfig:
         )
 
     @staticmethod
+    def nemotron_3_super_120b_a12b() -> "ModelConfig":
+        """nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16 as published
+        (config.json, model_type nemotron_h): every head and expert
+        held.  The multi-token-prediction module
+        (num_nextn_predict_layers 1) is not here."""
+        return ModelConfig(
+            arch="nemotron_h", vocab_size=131072, hidden_size=4096,
+            intermediate_size=2688, num_layers=88, num_heads=32,
+            num_kv_heads=2, head_dim=128, max_seq_len=262144,
+            rope_theta=1e4, rms_norm_eps=1e-5,
+            hybrid_override_pattern=(
+                "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+            mamba_num_heads=128, mamba_head_dim=64, mamba_n_groups=8,
+            ssm_state_size=128, mamba_conv_kernel=4, mamba_chunk_size=128,
+            n_routed_experts=512, num_experts_per_tok=22,
+            n_shared_experts=1, moe_intermediate_size=2688,
+            moe_shared_expert_intermediate_size=5376, moe_latent_size=1024,
+            moe_activation="relu2", routed_scaling_factor=5.0,
+        )
+
+    @staticmethod
+    def tiny_nemotron_h() -> "ModelConfig":
+        """``model_preset=tiny_nemotron_h``: the small sibling of
+        nemotron_3_super_120b_a12b (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("nemotron_h")
+
+    @staticmethod
     def tiny_keye_dsa() -> "ModelConfig":
         """``model_preset=tiny_keye_dsa``: the small sibling of
         keye_vl2_30b_a3b (tests, CPU rehearsals)."""
@@ -466,6 +619,25 @@ class ModelConfig:
     @staticmethod
     def tiny(arch: str = "llama", **kw: Any) -> "ModelConfig":
         """Small config for tests (runs on CPU in <1s)."""
+        if arch == "nemotron_h":
+            # one period with every kind of block: ME, M alone, *E;
+            # 8 heads in 4 groups and 4 query / 2 key-value heads, so
+            # that 2 and 4 head shares both divide them
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=48, num_layers=7, num_heads=4,
+                num_kv_heads=2, head_dim=16, max_seq_len=128,
+                rope_theta=1e4, rms_norm_eps=1e-5,
+                hybrid_override_pattern="MEMEM*E",
+                mamba_num_heads=8, mamba_head_dim=8, mamba_n_groups=4,
+                ssm_state_size=16, mamba_conv_kernel=4, mamba_chunk_size=16,
+                n_routed_experts=8, num_experts_per_tok=3,
+                n_shared_experts=1, moe_intermediate_size=48,
+                moe_shared_expert_intermediate_size=80, moe_latent_size=32,
+                moe_activation="relu2", routed_scaling_factor=5.0,
+            )
+            base.update(kw)
+            return ModelConfig(**base)
         if arch == "keye_dsa":
             # topk 8 of up to 128 keys; 2 query heads a key/value head
             base = dict(

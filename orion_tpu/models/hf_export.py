@@ -52,6 +52,13 @@ def hf_state_dict(params: dict, cfg: ModelConfig) -> Dict[str, np.ndarray]:
             "HF export of arch='keye_dsa' is not written: there is no "
             "KeyeVL2 checkpoint layout on either side yet (the indexer's "
             "projections and norm, per-expert tensors, the vision tower)")
+    if cfg.arch == "nemotron_h":
+        raise ValueError(
+            "HF export of arch='nemotron_h' is not written: there is no "
+            "nemotron_h checkpoint layout on either side yet (a Mamba-2 "
+            "layer's convolution [channels, 1, taps], A_log, D, dt_bias and "
+            "gated norm; per-expert up / down tensors and the latent "
+            "projections; a share of the heads is part of a checkpoint)")
     if cfg.arch == "olmo_hybrid":
         raise ValueError(
             "HF export of arch='olmo_hybrid' is not written: there is no "

@@ -64,6 +64,13 @@ _NO_OLMO_HYBRID_LOADER = (
     "no mapping onto models.transformer.GatedDeltaNet's names and "
     "[taps, channels] layout; arch='olmo_hybrid' runs from random "
     "weights only")
+_NO_NEMOTRON_H_LOADER = (
+    "there is no nemotron_h checkpoint loader yet: a Mamba-2 layer's "
+    "in_proj / conv1d / A_log / D / dt_bias / norm / out_proj, the "
+    "per-expert up / down tensors and fc1 / fc2_latent_proj have no "
+    "mapping onto models.transformer.Mamba2's and ops.moe.TopKMoE's names "
+    "and [taps, channels] layout, nor is there a cut of a checkpoint to a "
+    "share of the heads; arch='nemotron_h' runs from random weights only")
 _NO_KEYE_LOADER = (
     "there is no KeyeVL2 checkpoint loader yet: the indexer's projections "
     "and norm, the per-expert tensors and the vision tower have no mapping "
@@ -85,6 +92,8 @@ def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
         raise ValueError(_NO_OLMO_HYBRID_LOADER)
     elif cfg.arch == "keye_dsa":
         raise ValueError(_NO_KEYE_LOADER)
+    elif cfg.arch == "nemotron_h":
+        raise ValueError(_NO_NEMOTRON_H_LOADER)
     else:
         raise ValueError(cfg.arch)
     if not include_lm_head:
@@ -245,6 +254,8 @@ def config_from_hf(hf_cfg: Any) -> ModelConfig:
         raise ValueError(_NO_OLMO_HYBRID_LOADER)
     if mt in ("KeyeVL2", "keye_vl2"):
         raise ValueError(_NO_KEYE_LOADER)
+    if mt == "nemotron_h":
+        raise ValueError(_NO_NEMOTRON_H_LOADER)
     if mt == "llama":
         return ModelConfig(
             arch="llama",

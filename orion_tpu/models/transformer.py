@@ -29,6 +29,12 @@ requires (SURVEY.md §2 #14):
   norm over each head of q and k, cut to the ``sa_topk`` keys a query
   that a learned indexer selects: ``ops/indexer.py``) over the same
   expert layer with a softmax router; every layer alike, one stack.
+- ``arch="nemotron_h"``: the same pre-norm block with the halves that
+  ``hybrid_override_pattern`` names (``ModelConfig.layer_kinds``):
+  :class:`Mamba2` (a state-space layer: ``ops/mamba2.py``) or
+  :class:`Attention` as its mixer, the expert layer with ``relu(.)^2``
+  experts in a latent behind it, or one half alone; a chip may hold a
+  share of every mixer's heads (``ModelConfig.head_share``).
 
 Design notes (TPU-first):
 - Params are annotated with *logical* axes via flax logical
@@ -58,7 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from orion_tpu.config import ModelConfig
+from orion_tpu.config import RECURRENT_MIXERS, ModelConfig
 from orion_tpu.ops.attention import _NEG_INF, attention
 from orion_tpu.ops.paged_kv import is_paged, write_paged_tokens
 from orion_tpu.ops.rotary import apply_rotary
@@ -78,6 +84,10 @@ from orion_tpu.ops.rotary import apply_rotary
 # scan_layers models {"dense": [], "runs": [stacked, ...]}.
 # keye_dsa: a layer caches {"k","v"} as llama's and {"ki": [B,L,
 # sa_index_head_dim]}, the indexer's one key head; layouts as llama's.
+# nemotron_h: a Mamba-2 block caches {"S": f32 [B,H,P,N], "conv":
+# [B,taps-1,H*P+2*G*N]} (H, G the heads and groups held), an attention
+# block {"k","v"} of the key-value heads held, a block without a mixer
+# {}; scan_layers models {"dense": [], "runs": [stacked, ...]}.
 KVCache = Any
 
 _dt = lambda s: jnp.dtype(s)  # noqa: E731
@@ -114,10 +124,20 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
 
     n = rows * seq_len
     act = _dt(cfg.dtype).itemsize
-    H = cfg.num_heads
+    held = cfg.heads_held()
+    H = held["q"]
     route = resid = mlp = out = qkv = 0
     for mixer, ffn in cfg.layer_kinds():
-        if mixer == "kda":
+        if mixer is None:
+            pass
+        elif mixer == "mamba2":
+            # the input projection whole, and the recurrence's output in
+            # float32 (ops/mamba2.py)
+            Hm, d_in = held["mamba"], held["mamba"] * cfg.mamba_head_dim
+            qkv += n * w(2 * d_in + 2 * held["groups"] * cfg.ssm_state_size
+                         + Hm) * act
+            out += n * w(d_in) * 4
+        elif mixer == "kda":
             # the three projections as they enter the convolution, and
             # the recurrence's output in float32 (ops/kda.py)
             wide = cfg.kda_num_heads * cfg.kda_head_dim
@@ -135,7 +155,7 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
                                + w(cfg.v_head_dim))
                 per_out = H * w(cfg.v_head_dim)
             else:
-                per_tok = (H + 2 * cfg.num_kv_heads) * w(cfg.head_dim)
+                per_tok = (H + 2 * held["kv"]) * w(cfg.head_dim)
                 per_out = H * w(cfg.head_dim)
             qkv += n * per_tok * act
             # out_t, and lse [rows, H, 1, seq_len] in float32
@@ -143,7 +163,8 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
         if ffn == "experts":
             from orion_tpu.ops import moe
 
-            mlp += 2 * w(cfg.n_shared_experts * cfg.moe_intermediate_size)
+            mlp += (2 if cfg.moe_activation == "swiglu" else 1) * w(
+                moe.shared_width(cfg))
             # scores [n, E] float32 and the selection [n, k] (the gather
             # of the selected scores keeps its own indices); the dense
             # form reads the selection again for its weights, the
@@ -160,7 +181,7 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
         elif ffn == "dense":
             mlp += (1 if cfg.arch == "neox" else 2) * w(
                 cfg.intermediate_size)
-        if not cfg.use_parallel_residual:
+        if not cfg.use_parallel_residual and mixer and ffn:
             resid += w(cfg.hidden_size)
     sizes = (route, n * resid * act, n * mlp * act, out, qkv)
     return tuple((t, b) for t, b in zip(REMAT_TAGS, sizes) if b)
@@ -290,7 +311,10 @@ class Attention(nn.Module):
     ``"head"``, one norm over each head's ``head_dim`` of q and of k, a
     weight of ``head_dim`` each that all heads share (keye_dsa's, the
     Qwen3 family's); ``rotary`` false: nothing is rotated
-    (olmo_hybrid's, whose recurrent layers carry position)."""
+    (olmo_hybrid's, whose recurrent layers carry position).  The heads
+    are those ``cfg.heads_held()`` leaves here (all, but under
+    ``head_share``: that share's query heads against the key-value heads
+    they read, and their part of the output projection's sum)."""
 
     cfg: ModelConfig
     qk_norm: Any = False
@@ -302,7 +326,8 @@ class Attention(nn.Module):
         ``__call__``: the submodules are this module's.)"""
         cfg = self.cfg
         B, L, _ = x.shape
-        H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        held = cfg.heads_held()
+        H, Hkv, D = held["q"], held["kv"], cfg.head_dim
 
         q = _dense(H * D, ("embed", "heads"), cfg.attn_bias, cfg, "q_proj")(x)
         k = _dense(Hkv * D, ("embed", "kv_heads"), cfg.attn_bias, cfg, "k_proj")(x)
@@ -337,7 +362,7 @@ class Attention(nn.Module):
         """
         cfg = self.cfg
         B, L, _ = x.shape
-        H, D = cfg.num_heads, cfg.head_dim
+        H, D = cfg.heads_held()["q"], cfg.head_dim
         q, k, v = self.qkv(x, positions)
 
         scale = 1.0 / D ** 0.5
@@ -936,6 +961,114 @@ class GatedDeltaNet(nn.Module):
                       "o_proj")(norm_and_gate(o, z, o_norm)), new_cache
 
 
+class Mamba2(nn.Module):
+    """A Mamba-2 mixer (nemotron_h's ``M``): the state-space recurrence
+    of ``ops/mamba2.py`` on the H heads of ``mamba_head_dim`` P and the G
+    groups that ``cfg.heads_held()`` leaves here, state ``ssm_state_size``
+    N.
+
+    ``[z | xBC | dt] = u W_in`` (widths H P, H P + 2 G N, H: ONE
+    projection); ``xBC <- silu(conv(xBC) + b)``, ONE depthwise causal
+    convolution of ``mamba_conv_kernel`` taps over x, B and C together;
+    ``[x | B | C] = xBC``, x as [H, P], B and C as [G, N], head h reads
+    group ``h // (H / G)``; ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(A_log)``, one scalar a head each, float32; the recurrence; then
+    ``W_out RMSNorm_g(y * silu(z))``: the gate first, the norm over each
+    group's ``(H / G) P`` channels with a learned weight.  No position
+    enters it.
+
+    The cache is ``{"S": [B, H, P, N] float32, "conv": [B, taps - 1,
+    H P + 2 G N]}``: the state and the convolution's last inputs, after
+    the last token a row holds.  One new token against a cache takes
+    :func:`ops.mamba2.mamba2_step`; everything else the chunked form.
+    ``token_mask`` as :class:`KimiDeltaAttention`'s: a position that
+    holds no token has ``dt = 0`` (decay 1, no input) and the mask must
+    be a row's prefix.
+    """
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None, token_mask=None):
+        from orion_tpu.ops.mamba2 import mamba2_chunked, mamba2_step
+
+        cfg = self.cfg
+        B, L, _ = x.shape
+        held = cfg.heads_held()
+        H, G = held["mamba"], held["groups"]
+        P, N, taps = (cfg.mamba_head_dim, cfg.ssm_state_size,
+                      cfg.mamba_conv_kernel)
+        d_in, wide = H * P, H * P + 2 * G * N
+        f32, pdt, cdt = jnp.float32, _dt(cfg.param_dtype), _dt(cfg.dtype)
+        if layer_cache is not None and "S" not in layer_cache:
+            raise ValueError(
+                "a Mamba-2 layer caches {'S', 'conv'} (init_cache): a "
+                "state, not keys and values by position")
+
+        def param(name, init, shape, axes, dtype=pdt):
+            return self.param(
+                name, nn.with_logical_partitioning(init, axes), shape, dtype)
+
+        proj = checkpoint_name(
+            _dense(d_in + wide + H, ("embed", "ssm"), False, cfg,
+                   "in_proj")(x), "attn_qkv")
+        z, xBC, dt = jnp.split(proj, (d_in, d_in + wide), axis=-1)
+        w_conv = param("conv_weight", _delta_conv_init, (taps, wide),
+                       ("conv", "ssm")).astype(f32)
+        b_conv = param("conv_bias", _delta_conv_init, (wide,),
+                       ("ssm",)).astype(f32)
+        A_log = param("A_log", _delta_A_log_init, (H,), ("norm",), f32)
+        dt_bias = param("dt_bias", _delta_dt_bias_init, (H,), ("norm",), f32)
+        D = param("D", nn.initializers.ones_init(), (H,), ("norm",), f32)
+
+        # checkpointed stretch by stretch, as KimiDeltaAttention's
+        @jax.checkpoint
+        def convolve(ext, w_conv, b_conv, dt, dt_bias):
+            y = nn.silu(_short_conv(ext, w_conv, L) + b_conv).astype(cdt)
+            xs, Bm, Cm = jnp.split(y, (d_in, d_in + G * N), axis=-1)
+            dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
+            if token_mask is not None:
+                dt = jnp.where(token_mask[:, :, None], dt, 0.0)
+            return (xs.reshape(B, L, H, P), Bm.reshape(B, L, G, N),
+                    Cm.reshape(B, L, G, N), dt)
+
+        with jax.named_scope("mamba2.conv"):
+            prev = (layer_cache["conv"] if layer_cache is not None
+                    else jnp.zeros((B, taps - 1, wide), xBC.dtype))
+            ext = jnp.concatenate([prev.astype(xBC.dtype), xBC], axis=1)
+            xs, Bm, Cm, dt = convolve(ext, w_conv, b_conv, dt, dt_bias)
+
+        A = -jnp.exp(A_log)
+        new_cache = None
+        if layer_cache is not None and L == 1:
+            with jax.named_scope("mamba2_step"):
+                y, S = mamba2_step(xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                                   D, layer_cache["S"])
+            y, new_cache = y[:, None], {"S": S, "conv": ext[:, 1:]}
+        else:
+            with jax.named_scope("mamba2_chunk"):
+                y, S = mamba2_chunked(
+                    xs, dt, A, Bm, Cm, D,
+                    None if layer_cache is None else layer_cache["S"],
+                    cfg.mamba_chunk_size)
+            if layer_cache is not None:
+                new_cache = {"S": S,
+                             "conv": _conv_handover(ext, token_mask, taps)}
+        y = checkpoint_name(y, "attn_out")
+        norm = param("norm", nn.initializers.ones_init(), (d_in,), ("ssm",))
+
+        @jax.checkpoint
+        def gate_and_norm(y, z, norm):
+            y = (y.reshape(B, L, d_in) * nn.silu(z.astype(f32))).reshape(
+                B, L, G, d_in // G)
+            y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1,
+                                           keepdims=True) + cfg.rms_norm_eps)
+            return (y.reshape(B, L, d_in) * norm.astype(f32)).astype(cdt)
+
+        return _dense(cfg.hidden_size, ("ssm", "embed"), False, cfg,
+                      "out_proj")(gate_and_norm(y, z, norm)), new_cache
+
+
 class MLP(nn.Module):
     cfg: ModelConfig
 
@@ -994,32 +1127,46 @@ class Block(nn.Module):
 
 
 class LatentBlock(nn.Module):
-    """deepseek_v3 / kimi_linear / keye_dsa block: ``a = x +
-    Mixer(N1(x))``, ``y = a + FFN(N2(a))``; the mixer latent attention,
-    (``mixer="kda"``) the delta rule or (``mixer="sparse"``)
-    grouped-query attention under a selection, FFN the SwiGLU MLP
-    (``dense``) or the expert layer."""
+    """deepseek_v3 / kimi_linear / keye_dsa / nemotron_h block: ``a = x
+    + Mixer(N1(x))``, ``y = a + FFN(N2(a))``; the mixer latent
+    attention, (``mixer="kda"``) the delta rule, (``"sparse"``)
+    grouped-query attention under a selection, (``"mamba2"``) a
+    state-space layer, (``"attention"``) grouped-query attention or
+    (None) absent: ``a = x``; FFN the SwiGLU MLP (``ffn="dense"``), the
+    expert layer or (None) absent: ``y = a``."""
 
     cfg: ModelConfig
-    dense: bool = False
-    mixer: str = "latent"
+    ffn: Optional[str] = "experts"
+    mixer: Optional[str] = "latent"
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None, token_mask=None):
         cfg = self.cfg
-        h = _norm(cfg, "input_norm")(x)
-        if self.mixer == "kda":
-            attn_out, new_cache = KimiDeltaAttention(cfg, name="attn")(
-                h, positions, layer_cache, token_mask)
-        elif self.mixer == "sparse":
-            attn_out, new_cache = SparseAttention(
-                cfg, qk_norm="head", name="attn")(h, positions, layer_cache)
-        else:
-            attn_out, new_cache = LatentAttention(cfg, name="attn")(
-                h, positions, layer_cache)
-        h = checkpoint_name(x + attn_out, "attn_resid")
+        h, new_cache = x, (None if layer_cache is None else {})
+        if self.mixer is not None:
+            h = _norm(cfg, "input_norm")(x)
+            if self.mixer in ("kda", "mamba2"):
+                mixer_cls = KimiDeltaAttention if self.mixer == "kda" \
+                    else Mamba2
+                attn_out, new_cache = mixer_cls(cfg, name="attn")(
+                    h, positions, layer_cache, token_mask)
+            elif self.mixer == "sparse":
+                attn_out, new_cache = SparseAttention(
+                    cfg, qk_norm="head", name="attn")(
+                        h, positions, layer_cache)
+            elif self.mixer == "attention":
+                attn_out, new_cache = Attention(cfg, name="attn")(
+                    h, positions, layer_cache)
+            else:
+                attn_out, new_cache = LatentAttention(cfg, name="attn")(
+                    h, positions, layer_cache)
+            h = x + attn_out
+        if self.ffn is None:
+            return h, new_cache
+        if self.mixer is not None:
+            h = checkpoint_name(h, "attn_resid")
         z = _norm(cfg, "post_attn_norm")(h)
-        if self.dense:
+        if self.ffn == "dense":
             return h + MLP(cfg, name="mlp")(z), new_cache
         from orion_tpu.ops.moe import TopKMoE
         return h + TopKMoE(cfg, name="mlp")(z, token_mask), new_cache
@@ -1141,11 +1288,8 @@ class Transformer(nn.Module):
                 cls, kw, masked = PostNormBlock, {"mixer": mixer}, \
                     mixer == "gdn"
             else:
-                kw = {"dense": True} if ffn == "dense" else {}
-                if mixer != "latent":
-                    kw["mixer"] = mixer
-                cls = LatentBlock
-                masked = mixer == "kda" or ffn == "experts"
+                cls, kw = LatentBlock, {"mixer": mixer, "ffn": ffn}
+                masked = mixer in RECURRENT_MIXERS or ffn == "experts"
             if cfg.remat:
                 cls = nn.remat(
                     cls, static_argnums=(),
@@ -1249,9 +1393,20 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
             f"arch={cfg.arch!r} yet: ops/quant.py scales per head, a "
             "recurrent state has no int8 form, and no int8 cache was run "
             "under a selection")
-    shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    held = cfg.heads_held()
+    shape = (batch, max_len, held["kv"], cfg.head_dim)
 
     def entry(mixer, pre=()):
+        if mixer is None:
+            return {}
+        if mixer == "mamba2":
+            H, P = held["mamba"], cfg.mamba_head_dim
+            return {"S": jnp.zeros(pre + (batch, H, P, cfg.ssm_state_size),
+                                   jnp.float32),
+                    "conv": jnp.zeros(
+                        pre + (batch, cfg.mamba_conv_kernel - 1, H * P
+                               + 2 * held["groups"] * cfg.ssm_state_size),
+                        dtype)}
         if mixer == "kda":
             H, d = cfg.kda_num_heads, cfg.kda_head_dim
             return {"S": jnp.zeros(pre + (batch, H, d, d), jnp.float32),

@@ -17,9 +17,11 @@ of the parallelism table first-class, as the task demands.
 Beside it, :class:`TopKMoE` is the expert layer of the ``deepseek_v3``
 block as published (sigmoid scores, a selection bias, top-k of all
 experts, normalised and scaled gates, shared experts, no capacity, no
-drops, no auxiliary loss) and, with ``moe_scoring="softmax"``, of the
+drops, no auxiliary loss), with ``moe_scoring="softmax"`` of the
 Qwen3-MoE family's (a float32 softmax over all experts, no bias, no
-shared expert), told which experts it holds:
+shared expert) and, with ``moe_activation="relu2"`` and
+``moe_latent_size``, of ``nemotron_h``'s (experts without a gate that
+work in a latent between two projections), told which experts it holds:
 its rows, products and gradients follow the (token, choice) pairs
 routed to those, a static block of them at a time.
 """
@@ -197,16 +199,35 @@ def _swiglu(h):
     return nn.silu(gate) * up
 
 
-def experts_dense(x, w_gate_up, w_down, local, gates):
+def _relu2(h):
+    return jnp.square(nn.relu(h))
+
+
+#: ``cfg.moe_activation`` -> (what an expert does to its first product
+#: [.., F], the columns F of that product for a width of I): a SwiGLU
+#: splits a fused gate|up product, relu(.)^2 has no gate.
+ACTIVATIONS = {"swiglu": (_swiglu, 2), "relu2": (_relu2, 1)}
+
+
+def shared_width(cfg: ModelConfig) -> int:
+    """The shared expert's width: ``n_shared_experts`` times its own
+    ``moe_shared_expert_intermediate_size`` where that is given, times
+    the routed experts' ``moe_intermediate_size`` where not."""
+    return cfg.n_shared_experts * (cfg.moe_shared_expert_intermediate_size
+                                   or cfg.moe_intermediate_size)
+
+
+def experts_dense(x, w_up, w_down, local, gates, act: str = "swiglu"):
     """Every held expert on every token, weighted.  x [T, D];
-    w_gate_up [H, D, 2I]; w_down [H, I, D]; local [T, k] (expert index
-    among the held ones, anything outside 0..H-1 = not held); gates
-    [T, k].  Exact for any routing; its work does not follow it."""
-    H = w_gate_up.shape[0]
+    w_up [H, D, F] (F: ``ACTIVATIONS[act]``); w_down [H, I, D]; local
+    [T, k] (expert index among the held ones, anything outside 0..H-1 =
+    not held); gates [T, k].  Exact for any routing; its work does not
+    follow it."""
+    H = w_up.shape[0]
     weight = jnp.sum(jax.nn.one_hot(local, H, dtype=jnp.float32)
                      * gates[..., None], axis=1)               # [T, H]
     with jax.named_scope("moe.experts"):
-        h = _swiglu(jnp.einsum("td,hdf->thf", x, w_gate_up))
+        h = ACTIVATIONS[act][0](jnp.einsum("td,hdf->thf", x, w_up))
         y = jnp.einsum("thf,hfd->thd", h, w_down)
     with jax.named_scope("moe.combine"):
         return jnp.einsum("thd,th->td", y, weight.astype(y.dtype))
@@ -248,7 +269,8 @@ def block_rows(cfg: ModelConfig, n_tokens: int) -> int:
     return min(tiles * tile, padded_rows(n_pairs))
 
 
-def experts_grouped(x, w_gate_up, w_down, local, gates, block: int):
+def experts_grouped(x, w_up, w_down, local, gates, block: int,
+                    act: str = "swiglu"):
     """Same result by a grouped matrix product over the (token, choice)
     pairs sorted by expert, the held experts' pairs first.  Only those
     get rows: the sorted pairs are worked on ``block`` rows at a time
@@ -260,7 +282,7 @@ def experts_grouped(x, w_gate_up, w_down, local, gates, block: int):
     into their tokens' rows of a float32 sum; the kernels' work follows
     the rows of the held experts inside a block."""
     T, k = local.shape
-    H = w_gate_up.shape[0]
+    H = w_up.shape[0]
     n_pairs = T * k
     with jax.named_scope("moe.dispatch"):
         held = (local >= 0) & (local < H)
@@ -273,7 +295,7 @@ def experts_grouped(x, w_gate_up, w_down, local, gates, block: int):
         order = jnp.pad(order, (0, -n_pairs % block))
         order, sizes = (checkpoint_name(t, "moe_route")
                         for t in (order, sizes))
-    return _routed(x, w_gate_up, w_down, gates, order, sizes, block)
+    return _routed(x, w_up, w_down, gates, order, sizes, block, act)
 
 
 def _block(b, block: int, gates, order, sizes):
@@ -309,8 +331,8 @@ def _over_blocks(body, carry, block: int, order, sizes):
     return jax.lax.fori_loop(0, n_blocks, step, carry)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _routed(x, w_gate_up, w_down, gates, order, sizes, block: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _routed(x, w_up, w_down, gates, order, sizes, block: int, act: str):
     """The held experts' part of the layer's sum.  x [T, D]; gates
     [T, k]; order [whole blocks] the pairs sorted by expert (pair ``p``
     is token ``p // k``); sizes [H + 1] the pairs of each held expert
@@ -321,7 +343,7 @@ def _routed(x, w_gate_up, w_down, gates, order, sizes, block: int):
         _, tok, gate, sizes_b = _block(b, block, gates, order, sizes)
         with jax.named_scope("moe.experts"):
             rows = jnp.take(x, tok, axis=0)
-            h = _swiglu(gmm(rows, w_gate_up, sizes_b))
+            h = ACTIVATIONS[act][0](gmm(rows, w_up, sizes_b))
             y = gmm(h, w_down, sizes_b)             # zero where not held
         with jax.named_scope("moe.combine"):
             return out.at[tok].add(y.astype(jnp.float32) * gate)
@@ -331,25 +353,26 @@ def _routed(x, w_gate_up, w_down, gates, order, sizes, block: int):
     return out.astype(x.dtype)
 
 
-def _routed_fwd(x, w_gate_up, w_down, gates, order, sizes, block):
+def _routed_fwd(x, w_up, w_down, gates, order, sizes, block, act):
     # what is kept is what came in: the backward rebuilds a block's rows
     # and its activation (one more product on the held rows)
-    return (_routed(x, w_gate_up, w_down, gates, order, sizes, block),
-            (x, w_gate_up, w_down, gates, order, sizes))
+    return (_routed(x, w_up, w_down, gates, order, sizes, block, act),
+            (x, w_up, w_down, gates, order, sizes))
 
 
-def _routed_bwd(block, res, g):
+def _routed_bwd(block, act, res, g):
     from orion_tpu.ops.pallas.grouped_matmul import gmm, gmm_dlhs, tgmm
 
-    x, w_gate_up, w_down, gates, order, sizes = res
+    x, w_up, w_down, gates, order, sizes = res
     f32 = jnp.float32
 
     def add_block(b, carry):
-        d_x, d_gate_up, d_down, d_gate_of = carry
+        d_x, d_up, d_down, d_gate_of = carry
         pair, tok, gate, sizes_b = _block(b, block, gates, order, sizes)
         with jax.named_scope("moe.experts"):
             rows = jnp.take(x, tok, axis=0)
-            h, swiglu_vjp = jax.vjp(_swiglu, gmm(rows, w_gate_up, sizes_b))
+            h, act_vjp = jax.vjp(ACTIVATIONS[act][0],
+                                 gmm(rows, w_up, sizes_b))
             g_rows = jnp.take(g, tok, axis=0)
             # y enters the sum times its gate: the gate's gradient is
             # <g, y> = <g w_down^T, h>, and y itself is not rebuilt
@@ -357,20 +380,20 @@ def _routed_bwd(block, res, g):
             d_gate = jnp.sum(d_h * h.astype(f32), axis=-1)
             d_down = d_down + tgmm(
                 (h.astype(f32) * gate).astype(h.dtype), g_rows, sizes_b, f32)
-            (d_pre,) = swiglu_vjp((d_h * gate).astype(h.dtype))
-            d_gate_up = d_gate_up + tgmm(rows, d_pre, sizes_b, f32)
-            d_rows = gmm_dlhs(d_pre, w_gate_up, sizes_b)
+            (d_pre,) = act_vjp((d_h * gate).astype(h.dtype))
+            d_up = d_up + tgmm(rows, d_pre, sizes_b, f32)
+            d_rows = gmm_dlhs(d_pre, w_up, sizes_b)
         with jax.named_scope("moe.dispatch"):
-            return (d_x.at[tok].add(d_rows.astype(f32)), d_gate_up, d_down,
+            return (d_x.at[tok].add(d_rows.astype(f32)), d_up, d_down,
                     d_gate_of.at[pair].add(d_gate))
 
-    primals = (x, w_gate_up, w_down, gates.reshape(-1))
+    primals = (x, w_up, w_down, gates.reshape(-1))
     grads = _over_blocks(add_block,
                          tuple(jnp.zeros(t.shape, f32) for t in primals),
                          block, order, sizes)
-    d_x, d_gate_up, d_down, d_gate_of = (
+    d_x, d_up, d_down, d_gate_of = (
         d.astype(t.dtype) for d, t in zip(grads, primals))
-    return d_x, d_gate_up, d_down, d_gate_of.reshape(gates.shape), None, None
+    return d_x, d_up, d_down, d_gate_of.reshape(gates.shape), None, None
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -382,9 +405,19 @@ class TopKMoE(nn.Module):
     router scores: :func:`sigmoid_topk_route` with its selection bias,
     or :func:`softmax_topk_route`, which has none).
 
-    ``FFN(z) = sum_{i in top-k} w_i E_i(z) + S(z)``: E_i a SwiGLU of
-    ``moe_intermediate_size``, S one SwiGLU of ``n_shared_experts``
-    times that.  The router scores, selects and normalises over ALL
+    ``FFN(z) = W_2 (sum_{i in top-k} w_i E_i(W_1 z)) + S(z)``.  An
+    expert E_i is what ``cfg.moe_activation`` says (:data:`ACTIVATIONS`):
+    a SwiGLU of ``moe_intermediate_size`` (``swiglu``: ``W_down(silu(W_gate
+    v) * W_up v)``, gate and up one fused product, deepseek_v3's and the
+    Qwen3-MoE family's) or ``W_down relu(W_up v)^2`` without a gate
+    (``relu2``, nemotron_h's).  With ``moe_latent_size`` the routed
+    experts work in a latent of that width: ``fc1_latent_proj`` ``W_1``
+    before the dispatch and ``fc2_latent_proj`` ``W_2`` behind the
+    combine, which every chip holds whole (where it is 0 there is
+    neither, and E_i reads ``z``); the router and the shared expert read
+    ``z`` itself.  S is ONE shared expert of the same activation, of
+    :func:`shared_width` (absent where that is 0).  The router scores,
+    selects and normalises over ALL
     ``n_routed_experts``; this layer holds the ``experts_held``
     consecutive experts from ``expert_offset`` on and adds their part
     of the sum; what the absent ones would add is left out (on one chip
@@ -425,7 +458,8 @@ class TopKMoE(nn.Module):
         B, L, Dm = x.shape
         E, H, k = cfg.n_routed_experts, cfg.experts_held, \
             cfg.num_experts_per_tok
-        I = cfg.moe_intermediate_size
+        I, Dl = cfg.moe_intermediate_size, cfg.moe_latent_size or Dm
+        act, act_fn = cfg.moe_activation, ACTIVATIONS[cfg.moe_activation][0]
         cdt, pdt = _dt(cfg.dtype), _dt(cfg.param_dtype)
         z = x.reshape(B * L, Dm)
 
@@ -440,9 +474,12 @@ class TopKMoE(nn.Module):
             bias = param("e_score_correction_bias",
                          nn.initializers.normal(stddev=0.02), (E,),
                          ("norm",), jnp.float32)
-        w_gate_up = param("experts_gate_up_proj", normal, (H, Dm, 2 * I),
-                          ("expert", "embed", "mlp"))
-        w_down = param("experts_down_proj", normal, (H, I, Dm),
+        # the first product's name says what it holds
+        w_up = param(
+            "experts_gate_up_proj" if act == "swiglu" else "experts_up_proj",
+            normal, (H, Dl, ACTIVATIONS[act][1] * I),
+            ("expert", "embed", "mlp"))
+        w_down = param("experts_down_proj", normal, (H, I, Dl),
                        ("expert", "mlp", "embed"))
 
         with jax.named_scope("moe.route"):
@@ -462,21 +499,31 @@ class TopKMoE(nn.Module):
                      jnp.zeros((H + 1,), jnp.int32).at[
                          jnp.where(held, local, H).reshape(-1)].add(1)[:H])
 
+        z_in = z
+        if cfg.moe_latent_size:
+            with jax.named_scope("moe_latent"):
+                z_in = _dense(Dl, ("embed", "latent"), False, cfg,
+                              "fc1_latent_proj")(z)
         block = block_rows(cfg, B * L)
-        operands = (z.astype(cdt), w_gate_up.astype(cdt), w_down.astype(cdt),
+        operands = (z_in.astype(cdt), w_up.astype(cdt), w_down.astype(cdt),
                     local, gates)
-        routed = experts_grouped(*operands, block) if block \
-            else experts_dense(*operands)
+        routed = experts_grouped(*operands, block, act) if block \
+            else experts_dense(*operands, act)
+        if cfg.moe_latent_size:
+            with jax.named_scope("moe_latent"):
+                routed = _dense(Dm, ("latent", "embed"), False, cfg,
+                                "fc2_latent_proj")(routed.astype(cdt))
 
         with jax.named_scope("moe.shared"):
-            S = cfg.n_shared_experts * I
+            S = shared_width(cfg)
             shared = 0.0
             if S:
                 def pre(name):
                     return checkpoint_name(_dense(
                         S, ("embed", "mlp"), False, cfg, name)(x), "mlp_pre")
 
-                h = nn.silu(pre("shared_gate_proj")) * pre("shared_up_proj")
+                h = nn.silu(pre("shared_gate_proj")) * pre("shared_up_proj") \
+                    if act == "swiglu" else act_fn(pre("shared_up_proj"))
                 shared = _dense(Dm, ("mlp", "embed"), False, cfg,
                                 "shared_down_proj")(h)
         return routed.reshape(B, L, Dm).astype(cdt) + shared

@@ -23,6 +23,11 @@ Rules (MaxText/T5X-style):
   index   — the sparse-attention indexer's heads x dim, its one key
             head and its per-head weights → (replicated: every shard
             scores and selects for all heads of a whole sequence)
+  ssm     — a Mamba-2 layer's one input projection (z | x | B | C | dt,
+            parts of unequal width), its convolution's channels, gated
+            norm and output projection → (replicated: a tensor shard of
+            the whole width would cut across the parts; the chip's share
+            of the heads is ``ModelConfig.head_share``, not a mesh axis)
 """
 
 from __future__ import annotations
@@ -44,6 +49,7 @@ LOGICAL_RULES: dict = {
     "latent": None,     # latent attention's 512 / 576: replicated
     "conv": None,       # the 4 taps of a KDA layer's convolutions
     "index": None,      # the sparse-attention indexer's projections
+    "ssm": None,        # a Mamba-2 layer's projections, conv and norm
     "expert": "expert",
     "batch": ("data", "fsdp"),
     "seq": "seq",

@@ -225,7 +225,8 @@ class ContinuousBatchingEngine:
                    if model_cfg.arch == "keye_dsa" else "")
                 + ("; nor does its cache manager hold a recurrent state "
                    "per slot (admission, preemption and prefix reuse move "
-                   "pages, and a state is not made of pages)"
+                   "pages, and a state is not made of pages: a delta-rule "
+                   "layer's or a state-space layer's {S, conv})"
                    if model_cfg.recurrent else "")
                 + " (use rollout.engine=simple)")
         self.mc = model_cfg

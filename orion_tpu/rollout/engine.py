@@ -21,11 +21,12 @@ One decode path (XLA-first, static shapes):
   under ``quantize_kv``, or paged under
   ``RolloutConfig.paged`` (block tables + the Pallas paged-decode
   kernel; slower than dense for a fixed batch, ROADMAP D3(a));
-- a recurrent layer (``ModelConfig.recurrent``) has no slot to
-  overwrite: its cache entry is a state, and prefill (given
-  ``token_mask``) hands decode each row's state and last convolution
-  inputs after its last real prompt token (models.transformer.
-  KimiDeltaAttention).  The decode loop is the same.
+- a recurrent layer (``ModelConfig.recurrent``: the delta rule's or a
+  state-space layer) has no slot to overwrite: its cache entry is a
+  state, and prefill (given ``token_mask``) hands decode each row's
+  state and last convolution inputs after its last real prompt token
+  (models.transformer.KimiDeltaAttention, Mamba2).  A block without a
+  mixer caches nothing ({}).  The decode loop is the same.
 
 Speculative decoding is not here: a lockstep batch advances at its
 slowest row's acceptance, and it lost on the chip (PERF.md section 6,
@@ -101,6 +102,7 @@ class RolloutEngine:
         if model_cfg.pattern:
             latent = model_cfg.latent_attention
             sparse = model_cfg.arch == "keye_dsa"
+            relu2 = model_cfg.moe_activation == "relu2"
             state = ", and a recurrent state is not made of pages" \
                 if model_cfg.recurrent else ""
             for on, missing in (
@@ -127,6 +129,10 @@ class RolloutEngine:
                         if latent else "there are no int8 expert stacks, "
                         "and an int8 indexer would select other keys than "
                         "the update's" if sparse else
+                        "there are no int8 expert stacks, and ops/quant.py "
+                        "was not run on experts without a gate or on a "
+                        "state-space layer's one input projection"
+                        if relu2 else
                         "the int8 Dense twins do not reach "
                         "this block (no QuantDense decode twin was run "
                         "against its reference)"))):

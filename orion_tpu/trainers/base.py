@@ -214,6 +214,19 @@ def sa_step_read(lens, cache_len: int, new_tokens: int, mc) -> dict:
     return {"sparse_step": form, "sa_step_bytes": int(2 * slots * row)}
 
 
+def share_counters(cfg_model) -> dict:
+    """What of every layer this chip holds under ``head_share``, for the
+    ``update`` span (the benchmark's operation counts read it): the
+    state-space layers' heads and groups, attention's query and
+    key-value heads, the routed experts.  {} for a model held whole."""
+    if cfg_model.head_share == (0, 1):
+        return {}
+    held = cfg_model.heads_held()
+    return {"heads_held": held["mamba"], "groups_held": held["groups"],
+            "attn_heads_held": held["q"], "kv_heads_held": held["kv"],
+            "experts_held": cfg_model.experts_held}
+
+
 def state_out_shardings(state: "TrainState"):
     """``out_shardings`` for a donated TrainState: mesh-sharded leaves
     keep the layout they came in with; the rest stay unspecified.
@@ -608,12 +621,12 @@ class BaseTrainer:
             sparse = {"index_cache_bytes":
                       eng.index_cache_bytes(*prompts_shape),
                       "sa_topk": self.cfg.model.sa_topk}
-        mc, kda_step = self.cfg.model, ""
-        if mc.recurrent:
-            # the form the recurrent layers' one-token step takes in
+        dims, kda_step = self.cfg.model.delta_head_dims(), ""
+        if dims:
+            # the form the delta-rule layers' one-token step takes in
             # this process's traces (the fixed-batch engine's decode)
             from orion_tpu.ops.kda import step_form
-            kda_step = step_form(*mc.delta_head_dims())
+            kda_step = step_form(*dims)
         return {"cache_bytes": eng.cache_bytes(*prompts_shape),
                 "state_bytes": eng.state_bytes(*prompts_shape),
                 "weight_bytes": eng.weight_bytes(self.state.params),
@@ -833,12 +846,12 @@ class BaseTrainer:
         nothing (the CPU) gives no budget, and nothing is kept."""
         from orion_tpu.models.transformer import remat_keep, remat_tag_bytes
 
-        mc, kda_chunk = self.cfg.model, ""
-        if mc.recurrent:
-            # the form the recurrent layers' chunked rule takes in this
+        dims, kda_chunk = self.cfg.model.delta_head_dims(), ""
+        if dims:
+            # the form the delta-rule layers' chunked rule takes in this
             # trace
             from orion_tpu.ops.kda import chunk_form
-            kda_chunk = chunk_form(*mc.delta_head_dims())
+            kda_chunk = chunk_form(*dims)
         self._remat_info = info = {
             "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0,
             "kda_chunk": kda_chunk}
@@ -1132,7 +1145,8 @@ class BaseTrainer:
                             obs.span("update", it=it) as sp_upd:
                         upd_dev = self.update_epochs(experience, defer=True)
                         sp_upd.set(**(self._remat_info or {}),
-                                   **getattr(self, "_sa_counts", {}))
+                                   **getattr(self, "_sa_counts", {}),
+                                   **share_counters(self.cfg.model))
                     with obs.timed("weight_sync"):
                         self.sync_weights()
                     self.global_iter += 1

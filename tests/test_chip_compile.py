@@ -874,3 +874,55 @@ def test_selected_step_reads_the_cache_in_place(new_tokens, form, one_chip,
                              decode[0])
             assert made and made.group(2) == "bitcast", (name, made)
             assert made.group(1).startswith("bf16[8,32768,128]")
+
+
+# -- the nemotron_h model at the published widths -----------------------------
+
+def test_nemotron_h_update_compiles_for_v5e(one_chip, on_tpu):
+    """The shared-backbone PPO update of ``ppo-nemotron-h-tp4-sync`` (the
+    first period of Nemotron-3-Super at the published widths on a
+    quarter of every mixer's heads, 8 of 512 experts, 16 384 rows of the
+    vocabulary; remat, each stretch scanned) over 32 sequences of 1280
+    in minibatches of 8.  The update has the flash kernels (the one
+    attention block, 8 query heads against 1 key-value head) and the
+    grouped products (relu^2 experts in the 1024-wide latent) in it; the
+    Mamba-2 recurrence is XLA's.  773.6 M parameters: 6.19 GB of
+    arguments (float32 master, two bf16 moments)."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc = dataclasses.replace(
+        ModelConfig.nemotron_3_super_120b_a12b(), num_layers=11,
+        head_share=(0, 4), experts_held=8, vocab_size=16384,
+        max_seq_len=1280)
+    assert mc.layer_runs() == ((0, 3, "mamba2", "experts"),
+                               (3, 1, "mamba2", None),
+                               (4, 1, "attention", "experts"),
+                               (5, 1, "mamba2", "experts"))
+    shell, pshape, mb = _build_8b_shell(mc)
+    rows, S, T = 8, 1280, 1024
+    shell.cfg.rollout.max_prompt_len = S - T
+    shell.cfg.rollout.max_new_tokens = T
+    shapes = {k: (S,) if k == "sequences" else () if k == "prompt_lens"
+              else (T,) for k in mb}
+    experience = {k: _sds((32,) + shapes[k], v.dtype, one_chip)
+                  for k, v in mb.items()}
+    state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                         _abstract_state(shell, pshape))
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(
+            lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+            donate_argnums=(0,)).lower(
+                state, experience,
+                _sds((32 // rows, rows), jnp.int32, one_chip)).compile()
+    names = _kernel_names(compiled)
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
+            "moe_gmm_dlhs", "moe_tgmm"} <= set(names)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == pytest.approx(6.19e9, rel=1e-2)
+    # beside it the chip holds the bf16 reference (1.55 GB) and the batch
+    assert mem.peak_memory_in_bytes + 1.6e9 <= V5E_BYTES_LIMIT
