@@ -57,15 +57,17 @@ Design notes (TPU-first):
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any, List, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from orion_tpu.config import RECURRENT_MIXERS, ModelConfig
-from orion_tpu.ops.attention import _NEG_INF, attention
+from orion_tpu.ops.attention import _NEG_INF, attention, step_attention
 from orion_tpu.ops.paged_kv import is_paged, write_paged_tokens
 from orion_tpu.ops.rotary import apply_rotary
 
@@ -305,6 +307,52 @@ def _cache_writer(positions, B: int, L: int):
     return write
 
 
+# A one-token step's attention reads a static prefix of its slot cache:
+# blocks of at least _PREFIX_SLOTS slots, at most _PREFIX_COUNT prefixes.
+_PREFIX_SLOTS = 128
+_PREFIX_COUNT = 8
+#: the mixers (``ModelConfig.layer_kinds``) whose one-token step against
+#: a dense slot cache goes through :func:`prefix_step`
+PREFIX_STEP_MIXERS = ("attention", "latent")
+
+
+def prefix_lengths(Lmax: int) -> list:
+    """The static prefixes :func:`prefix_step` chooses among over a
+    cache of ``Lmax`` slots: whole blocks of ``max(128, Lmax / 8)``
+    slots (rounded up to 8: 128 at 384 and 1024, 160 at 1280), the last
+    one ragged where ``Lmax`` is no whole blocks (128 ... 896, 1000 at
+    1000); ``[Lmax]`` alone up to one block."""
+    block = max(_PREFIX_SLOTS, -(-Lmax // (8 * _PREFIX_COUNT)) * 8)
+    return [*range(block, Lmax, block), Lmax]
+
+
+def prefix_step(positions, Lmax: int, fn):
+    """``fn(m)`` for the least prefix ``m`` of :func:`prefix_lengths`
+    that holds every row's ``positions`` (one new token a row, [B, 1]):
+    ``fn(m)`` is the step's attention over slots ``[:m]`` of a dense
+    cache of ``Lmax`` slots and of the mask ``slot <= position``.  Slots
+    fill from 0 up (right-padded prompts, decode overwrites the tail
+    slot by slot), so the slots past the batch's furthest position have
+    probability exactly 0 and are not fetched: ONE ``lax.switch`` over
+    static slices, each branch XLA's own fusions on fewer slots (a
+    cache of one block: ``fn(Lmax)``, no switch).  The slots inside the
+    last block that a row has not reached stay masked as before."""
+    ms = prefix_lengths(Lmax)
+    return jax.lax.switch(jnp.max(positions) // ms[0],
+                          [partial(fn, m) for m in ms])
+
+
+def prefix_step_slots(lens, Lmax: int, new_tokens: int) -> float:
+    """The slots one row's one-token step reads a layer under
+    :func:`prefix_step`, the mean over the ``new_tokens - 1`` steps the
+    fixed-batch engine makes after prompts of ``lens`` real tokens (step
+    ``t`` stands at position ``len + t``; the batch's longest prompt
+    decides).  Host arithmetic, from lengths."""
+    ms = np.asarray(prefix_lengths(Lmax))
+    at = int(np.max(lens)) + np.arange(max(new_tokens - 1, 1))
+    return float(ms[np.minimum(at // ms[0], len(ms) - 1)].mean())
+
+
 class Attention(nn.Module):
     """``qk_norm``: true or ``"whole"``, one norm over the whole query
     and key projections, before the split into heads (olmo_hybrid's);
@@ -357,7 +405,10 @@ class Attention(nn.Module):
         is given, the L new keys/values are written at per-sequence
         slots starting at ``positions[:, 0]`` — one formula covers
         prefill (positions 0..L-1), chunked prefill (P..P+L-1) and
-        decode (positions = current lengths).
+        decode (positions = current lengths).  The cache is dense
+        ([B, Lmax] slots a layer, int8 with scales under
+        ``quantize_kv``); a one-token step reads its filled prefix in
+        blocks (:func:`prefix_step`).
         Returns (out [B, L, E], new_layer_cache).
         """
         cfg = self.cfg
@@ -398,8 +449,6 @@ class Attention(nn.Module):
                 # int8 KV cache (RolloutConfig.quantize_kv): quantize
                 # the new tokens' K/V per (token, head) over D and
                 # write both values and scales (ops/quant.py).
-                from orion_tpu.ops.attention import (
-                    int8_decode_attention as _int8_decode_attention)
                 from orion_tpu.ops.quant import dequant_kv, quantize_kv
                 kq_, ks_ = quantize_kv(k)
                 vq_, vs_ = quantize_kv(v)
@@ -409,49 +458,52 @@ class Attention(nn.Module):
                     "k_scale": write(layer_cache["k_scale"], ks_),
                     "v_scale": write(layer_cache["v_scale"], vs_),
                 }
-                if L == 1:
-                    # Decode: int8-specialized attention — scales land
-                    # on scores/probs, the int8 cache operands enter
-                    # the einsums as bare fused converts, and no
-                    # dequantized [B, Lmax, Hkv, D] copy ever exists.
-                    key_slots = jnp.arange(new_cache["k"].shape[1],
-                                           dtype=positions.dtype)
-                    mask = key_slots[None, None, :] <= positions[:, :, None]
-                    paged_decode_out = _int8_decode_attention(
-                        q, new_cache["k"], new_cache["k_scale"],
-                        new_cache["v"], new_cache["v_scale"], mask,
-                        scale)[:, 0]
-                    keys = values = None
-                else:
+                if L > 1:
                     # Prefill: the standard attention below consumes
                     # the dequantized cache (convert+mul fuse into its
-                    # operand reads).
+                    # operand reads); a one-token step reads the int8
+                    # cache itself (step_attention).
                     keys = dequant_kv(new_cache["k"], new_cache["k_scale"],
                                       _dt(cfg.dtype))
                     values = dequant_kv(new_cache["v"],
                                         new_cache["v_scale"],
                                         _dt(cfg.dtype))
             else:
-                ck = write(layer_cache["k"], k)
-                cv = write(layer_cache["v"], v)
-                new_cache = {"k": ck, "v": cv}
-                keys, values = ck, cv
+                new_cache = {"k": write(layer_cache["k"], k),
+                             "v": write(layer_cache["v"], v)}
+                keys, values = new_cache["k"], new_cache["v"]
         else:
             new_cache = None
             keys, values = k, v
 
+        # Mask: query at absolute position p attends to cache slots
+        # j <= p.  Slots map 1:1 to absolute positions in the train,
+        # prefill, decode and paged-gather paths (decode overwrites the
+        # right-padded prompt tail slot by slot), so one formula covers
+        # all of them.
+        def mask(slots):
+            key_slots = jnp.arange(slots, dtype=positions.dtype)
+            return key_slots[None, None, :] <= positions[:, :, None]
+
         if paged_decode_out is not None:
             out = paged_decode_out[:, None, :, :]
+        elif L == 1 and new_cache is not None:
+            # one new token against the dense slot cache, int8 or not:
+            # over the filled prefix of its slots
+            Lmax = new_cache["k"].shape[1]
+            whole = mask(Lmax)
+
+            def attend(m):
+                c = {n: a[:, :m] for n, a in new_cache.items()}
+                return step_attention(
+                    q, c["k"], c["v"], whole[..., :m], scale,
+                    c.get("k_scale"), c.get("v_scale"))
+
+            out = prefix_step(positions, Lmax, attend)
         else:
-            # Mask: query at absolute position p attends to cache slots
-            # j <= p.  Slots map 1:1 to absolute positions in the train,
-            # prefill, decode and paged-gather paths (decode overwrites
-            # the right-padded prompt tail slot by slot), so one formula
-            # covers all of them.
-            key_slots = jnp.arange(keys.shape[1], dtype=positions.dtype)
-            mask = key_slots[None, None, :] <= positions[:, :, None]
-            out = attention(q, keys, values, mask, scale=scale,
-                            impl=cfg.attention_impl, q_positions=positions)
+            out = attention(q, keys, values, mask(keys.shape[1]),
+                            scale=scale, impl=cfg.attention_impl,
+                            q_positions=positions)
         out = out.reshape(B, L, H * D)
         out = _dense(cfg.hidden_size, ("heads", "embed"),
                      cfg.attn_bias, cfg, "o_proj")(out)
@@ -570,6 +622,19 @@ class SparseAttention(Attention):
         return out, new_cache
 
 
+def _absorbed_step(q_lat, q_rope, c, k_rope, mask, scale: float):
+    """The absorbed one-token step's softmax(scores) c over the latents
+    handed in: q_lat [B, H, R], q_rope [B, H, dr], c [B, m, R], k_rope
+    [B, m, dr], mask [B, 1, m] -> [B, H, R]."""
+    scores = (jnp.einsum("bhr,blr->bhl", q_lat, c,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhd,bld->bhl", q_rope, k_rope,
+                           preferred_element_type=jnp.float32)) * scale
+    scores = jnp.where(mask, scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+    return jnp.einsum("bhl,blr->bhr", probs, c)
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (deepseek_v3, ``q_lora_rank: null``).
 
@@ -591,7 +656,9 @@ class LatentAttention(nn.Module):
     - **absorb** (one new token against the cache): with ``W_kvb``
       split per head into ``W_uk`` and ``W_uv``, ``score = (q_nope
       W_uk^T) . c + q_rope . k_rope`` and ``o = (P c) W_uv``, so no
-      per-head key or value of the context is ever formed.
+      per-head key or value of the context is ever formed; ``c`` and
+      ``k_rope`` are read up to the filled prefix of the cache, in
+      blocks (:func:`prefix_step`).
     """
 
     cfg: ModelConfig
@@ -655,14 +722,13 @@ class LatentAttention(nn.Module):
                 w = w_kvb.reshape(R, H, dn + dv)
                 q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0],
                                    w[..., :dn])
-                scores = (jnp.einsum("bhr,blr->bhl", q_lat, c,
-                                     preferred_element_type=jnp.float32)
-                          + jnp.einsum("bhd,bld->bhl", q_rope[:, 0], k_rope,
-                                       preferred_element_type=jnp.float32)
-                          ) * scale
-                scores = jnp.where(mask, scores, _NEG_INF)
-                probs = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-                o_lat = jnp.einsum("bhl,blr->bhr", probs, c)
+
+                def attend(m):
+                    return _absorbed_step(q_lat, q_rope[:, 0], c[:, :m],
+                                          k_rope[:, :m], mask[..., :m],
+                                          scale)
+
+                o_lat = prefix_step(positions, c.shape[1], attend)
                 out = jnp.einsum("bhr,rhd->bhd", o_lat,
                                  w[..., dn:])[:, None]
         else:

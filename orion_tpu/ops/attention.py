@@ -64,34 +64,47 @@ def reference_attention_gqa(q: jnp.ndarray, k: jnp.ndarray,
     return out.reshape(B, Lq, H, D)
 
 
-def int8_decode_attention(q: jnp.ndarray,
-                          kq: jnp.ndarray, k_scale: jnp.ndarray,
-                          vq: jnp.ndarray, v_scale: jnp.ndarray,
-                          mask: jnp.ndarray, scale: float) -> jnp.ndarray:
-    """Decode attention over an int8 KV cache (RolloutConfig.quantize_kv).
+def step_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                   mask: jnp.ndarray, scale: float,
+                   k_scale: Optional[jnp.ndarray] = None,
+                   v_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """ONE query a sequence over (a static prefix of) its slot cache:
+    a branch of models/transformer.py::prefix_step's ``lax.switch``.
 
-    q [B, 1, H, D]; kq/vq [B, L, Hkv, D] int8; k_scale/v_scale
-    [B, L, Hkv] f32; mask [B, 1, L].  Dequantization never materializes
-    a [B, L, Hkv, D] float copy: the per-token K scales multiply the
-    *scores* and the V scales fold into the *probs* (both [B, Hkv, g,
-    1, L]-sized), so the int8 cache operands enter both einsums as bare
-    int8→bf16 converts, which XLA fuses into the dot reads — HBM
-    traffic stays 1 byte per cache element (the point: decode is
-    bandwidth-bound, PERF.md anatomy)."""
-    B, Lq, H, D = q.shape
-    Hkv = kq.shape[2]
+    q [B, 1, H, D]; k, v [B, m, Hkv, D] in q's dtype, or int8
+    (RolloutConfig.quantize_kv) with k_scale, v_scale [B, m, Hkv] f32;
+    mask [B, 1, m].  The numbers of :func:`reference_attention_gqa`.
+    An int8 cache is never dequantized into a [B, m, Hkv, D] float
+    copy: the per-token K scales multiply the *scores* and the V scales
+    fold into the *probs* (both [B, Hkv, g, m]-sized), so the cache
+    operands enter both products as bare int8->bf16 converts, which XLA
+    fuses into the reads: HBM traffic stays 1 byte a cache element
+    (decode is bandwidth-bound).  With one query head a key head
+    ``p v`` is a matrix-vector product and is written as a product and a
+    sum over the slots: inside a branch the TPU compiler leaves that
+    einsum a convolution, whose operand a slice of the cache is first
+    copied into, where this form reads the slice inside its fusion as
+    ``q k^T`` does (PERF.md section 6, PR 43).  A group of query heads a
+    key head makes it a matrix product: the einsum."""
+    B, _, H, D = q.shape
+    Hkv = k.shape[2]
     g = H // Hkv
-    qg = q.reshape(B, Lq, Hkv, g, D)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, kq.astype(q.dtype),
+    qg = q.reshape(B, Hkv, g, D)
+    scores = jnp.einsum("bhgd,bkhd->bhgk", qg, k.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
-    # k_scale [B, L, Hkv] -> [B, Hkv, 1, 1, L]
-    scores = scores * k_scale.transpose(0, 2, 1)[:, :, None, None, :]
-    scores = jnp.where(mask[:, None, None, :, :], scores, _NEG_INF)
+    if k_scale is not None:
+        scores = scores * k_scale.transpose(0, 2, 1)[:, :, None, :]
+    scores = jnp.where(mask[:, None, :, :], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
-    pv = probs * v_scale.transpose(0, 2, 1)[:, :, None, None, :]
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", pv.astype(q.dtype),
-                     vq.astype(q.dtype))
-    return out.reshape(B, Lq, H, D)
+    if v_scale is not None:
+        probs = probs * v_scale.transpose(0, 2, 1)[:, :, None, :]
+    probs = probs.astype(q.dtype)
+    if g == 1:
+        out = jnp.sum(probs[:, :, 0, :, None].astype(jnp.float32)
+                      * v.transpose(0, 2, 1, 3).astype(jnp.float32), axis=2)
+    else:
+        out = jnp.einsum("bhgk,bkhd->bhgd", probs, v.astype(q.dtype))
+    return out.astype(q.dtype).reshape(B, 1, H, D)
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

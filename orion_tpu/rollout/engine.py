@@ -20,7 +20,10 @@ One decode path (XLA-first, static shapes):
   indexer's keys beside k and v under sparse attention), int8
   under ``quantize_kv``, or paged under
   ``RolloutConfig.paged`` (block tables + the Pallas paged-decode
-  kernel; slower than dense for a fixed batch, ROADMAP D3(a));
+  kernel; slower than dense for a fixed batch, ROADMAP D3(a)); a
+  one-token step reads the dense cache's filled prefix in blocks
+  (models.transformer.prefix_step: slots fill from 0 up, so what lies
+  past the batch's furthest position is never fetched);
 - a recurrent layer (``ModelConfig.recurrent``: the delta rule's or a
   state-space layer) has no slot to overwrite: its cache entry is a
   state, and prefill (given ``token_mask``) hands decode each row's
@@ -44,7 +47,9 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.config import ModelConfig, RolloutConfig
-from orion_tpu.models.transformer import init_cache
+from orion_tpu.models.transformer import (PREFIX_STEP_MIXERS, cache_slots,
+                                         init_cache, prefix_lengths,
+                                         prefix_step_slots)
 from orion_tpu.ops.logprobs import pack_sequences
 from orion_tpu.ops.sampling import sample_tokens
 from orion_tpu.resilience import fault_point
@@ -207,6 +212,28 @@ class RolloutEngine:
         if self.cfg.paged:
             return 0
         return self._cache_shapes(batch, prompt_len, max_new_tokens)["state"]
+
+    def kv_step_read(self, lens, prompt_len: int,
+                     max_new_tokens: Optional[int] = None) -> dict:
+        """{kv_step_form, kv_step_slots}: how a one-token step's
+        attention reads a dense slot cache after prompts of ``lens``
+        real tokens (``models/transformer.py::prefix_step``): ``prefix``
+        (the filled blocks) / ``whole`` (a cache of one block), and the
+        slots one row's step then reads a layer, the mean over the
+        steps.  {} where no step goes through ``prefix_step``: under
+        ``paged``, or a model whose mixers are not among
+        ``PREFIX_STEP_MIXERS`` (a selection, recurrent layers alone).
+        Host numbers, from shapes and lengths; not under an ``sa_`` name
+        (``trainers/base.py::sa_step_read``)."""
+        if self.cfg.paged or not any(
+                m in PREFIX_STEP_MIXERS
+                for m, _ in self.model_cfg.layer_kinds()):
+            return {}
+        T = int(max_new_tokens or self.cfg.max_new_tokens)
+        slots = cache_slots(prompt_len + T)
+        return {"kv_step_form":
+                "prefix" if len(prefix_lengths(slots)) > 1 else "whole",
+                "kv_step_slots": prefix_step_slots(lens, slots, T)}
 
     def weight_bytes(self, params: Any = None) -> int:
         """Bytes of the copy of the weights a decode step reads

@@ -598,15 +598,17 @@ class BaseTrainer:
         return self.engine.generate(ids, lens, rng,
                                     params=self.state.params)
 
-    def _rollout_bytes(self, prompts_shape) -> dict:
+    def _rollout_bytes(self, prompts_shape, lens) -> dict:
         """What a decode step of the fixed-batch engine touches for this
         batch, from shapes: ``cache_bytes`` (what is indexed by
         position: keys and values, or latents), ``state_bytes`` (what is
         not: recurrent states, read and written whole a step),
-        ``weight_bytes`` (the decode copy of the weights) and
+        ``weight_bytes`` (the decode copy of the weights),
         ``kda_step``, the form the delta rule's one-token step takes
         there (``kernel`` / ``jnp``, from ``ops/kda.py::step_form``;
-        ``""`` without such a layer).  All 0 and ``""`` for the
+        ``""`` without such a layer), and what the engine says of its
+        attention step's read after prompts of ``lens`` real tokens
+        (``RolloutEngine.kv_step_read``).  All 0 and ``""`` for the
         continuous engine, whose pool is its own and which refuses
         recurrent models."""
         eng = self.engine
@@ -630,7 +632,8 @@ class BaseTrainer:
         return {"cache_bytes": eng.cache_bytes(*prompts_shape),
                 "state_bytes": eng.state_bytes(*prompts_shape),
                 "weight_bytes": eng.weight_bytes(self.state.params),
-                "kda_step": kda_step, **sparse}
+                "kda_step": kda_step, **sparse,
+                **eng.kv_step_read(lens, prompts_shape[1])}
 
     def _score_result(self, result, host, meta) -> np.ndarray:
         """One place for the device-vs-host reward dispatch (the
@@ -741,7 +744,7 @@ class BaseTrainer:
         with obs.span("rollout.dispatch") as sp:
             ids, lens, meta = self.prepare_prompts(batch)
             sp.set(batch=int(ids.shape[0]), prompt_len=int(ids.shape[1]),
-                   **self._rollout_bytes(ids.shape))
+                   **self._rollout_bytes(ids.shape, lens))
             if self.cfg.model.sa_topk:
                 # over the prefill's real queries, from the host's lengths
                 sp.set(**sa_key_counts(lens,

@@ -115,9 +115,8 @@ def _state_copies(text: str, shape, moves: bool = False) -> list:
     return found
 
 
-def _while_bodies(text: str) -> dict:
-    """{computation name: its lines} of every ``while`` body of a
-    compiled program's text."""
+def _computations(text: str) -> dict:
+    """{computation name: its lines} of a compiled program's text."""
     import re
 
     comps, cur = {}, None
@@ -129,7 +128,16 @@ def _while_bodies(text: str) -> dict:
             cur = None
         elif cur is not None:
             cur.append(ln)
-    return {b: "\n".join(comps[b]) for b in re.findall(
+    return {name: "\n".join(lines) for name, lines in comps.items()}
+
+
+def _while_bodies(text: str) -> dict:
+    """{computation name: its lines} of every ``while`` body of a
+    compiled program's text."""
+    import re
+
+    comps = _computations(text)
+    return {b: comps[b] for b in re.findall(
         r" while\(.*body=%?([\w.\-]+)", text)}
 
 
@@ -539,25 +547,24 @@ def test_delta_rule_compiles_for_v5e(one_chip, on_tpu, H, dk, dv, one_decay):
     assert chunked.memory_analysis().peak_memory_in_bytes < 4 * 2**30
 
 
-@pytest.mark.parametrize("name,states", [("kimi_linear", 2),
-                                         ("olmo_hybrid", 3)])
-def test_delta_rule_decode_carries_one_state_buffer_a_layer(
-        one_chip, on_tpu, name, states):
-    """The fixed-batch engine's whole program (prefill + the decode
-    ``while_loop``) of ``ppo-kimi-linear-ep32-sync`` (three of its
-    layers: two KDA, one latent) and ``ppo-olmo-hybrid-vp8-sync`` (one
-    period: three GDN, one full attention) at 32 x 512 + 512: the decode
-    loop's body holds one ``kda_step`` kernel a recurrent layer, each
-    writing its state into the buffer it read (the loop carries one
-    buffer a layer), and no copy of a whole state from the HBM to the
-    HBM."""
+_DELTA_ENGINES: dict = {}
+
+
+def _delta_engine(name, one_chip):
+    """(compiled text, kernel names, state shape) of the fixed-batch
+    engine's whole program (prefill + the decode ``while_loop``) of
+    ``ppo-kimi-linear-ep32-sync`` (three of its layers: two KDA, one
+    latent) or ``ppo-olmo-hybrid-vp8-sync`` (one period: three GDN, one
+    full attention) at 32 x 512 + 512, compiled once a process (the
+    caller holds ``on_tpu``)."""
     import dataclasses
-    import re
 
     from orion_tpu.config import ModelConfig, RolloutConfig
     from orion_tpu.models import Transformer, init_params
     from orion_tpu.rollout.engine import RolloutEngine
 
+    if name in _DELTA_ENGINES:
+        return _DELTA_ENGINES[name]
     if name == "kimi_linear":
         mc = dataclasses.replace(
             ModelConfig.kimi_linear_48b_a3b(), num_layers=3,
@@ -576,6 +583,7 @@ def test_delta_rule_decode_carries_one_state_buffer_a_layer(
     eng = RolloutEngine(model, mc, RolloutConfig(
         max_prompt_len=512, max_new_tokens=512), eos_token_id=0,
         pad_token_id=0)
+    states = len([m for m, _ in mc.layer_kinds() if m in ("kda", "gdn")])
     assert eng.state_bytes(32, 512) >= states * 4 * int(np.prod(state_shape))
     rng = jax.eval_shape(lambda: jax.random.key(0))
     with jax.default_matmul_precision("default"):
@@ -584,8 +592,23 @@ def test_delta_rule_decode_carries_one_state_buffer_a_layer(
             _sds((32,), jnp.int32, one_chip),
             _sds(rng.shape, rng.dtype, one_chip),
             max_new_tokens=512).compile()
-    text = compiled.as_text()
-    assert _kernel_names(compiled).count("kda_step") == states
+    _DELTA_ENGINES[name] = (compiled.as_text(), _kernel_names(compiled),
+                            state_shape)
+    return _DELTA_ENGINES[name]
+
+
+@pytest.mark.parametrize("name,states", [("kimi_linear", 2),
+                                         ("olmo_hybrid", 3)])
+def test_delta_rule_decode_carries_one_state_buffer_a_layer(
+        one_chip, on_tpu, name, states):
+    """The decode loop's body holds one ``kda_step`` kernel a recurrent
+    layer, each writing its state into the buffer it read (the loop
+    carries one buffer a layer), and no copy of a whole state from the
+    HBM to the HBM."""
+    import re
+
+    text, kernels, state_shape = _delta_engine(name, one_chip)
+    assert kernels.count("kda_step") == states
     decode = [body for body in _while_bodies(text).values()
               if "%kda_step" in body]
     assert len(decode) == 1                     # the decode loop's body
@@ -601,6 +624,89 @@ def test_delta_rule_decode_carries_one_state_buffer_a_layer(
     assert not _state_copies(decode[0], state_shape)
     if name == "kimi_linear":
         assert not _state_copies(decode[0], state_shape, moves=True)
+
+
+@pytest.mark.parametrize("name,cache", [
+    ("olmo_hybrid", "bf16[32,1024,30,128]"),
+    ("kimi_linear", "bf16[32,1024,512]")])
+def test_decode_step_switches_over_prefixes_of_the_cache_in_place(
+        one_chip, on_tpu, name, cache):
+    """The same programs' decode loop: one ``conditional`` of 8 branches
+    a layer with a slot cache (Olmo-Hybrid's full-attention layer,
+    Kimi's latent layer); the cache reaches it as the loop carries it
+    (no ``copy`` / ``copy-start`` of a cache-shaped buffer in the body
+    or in a branch), and no branch makes a standalone ``slice`` or
+    ``copy`` of a prefix of the per-head cache: the fusions slice their
+    operand themselves (``ops/attention.py::step_attention``)."""
+    import re
+
+    text, _, _ = _delta_engine(name, one_chip)
+    comps = _computations(text)
+    (decode,) = [body for body in _while_bodies(text).values()
+                 if "%kda_step" in body]
+    found = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                       decode)
+    assert len(found) == 1
+    branches = [b.strip().lstrip("%") for b in found[0].split(",")]
+    assert len(branches) == 8
+    whole = re.escape(cache)
+    for where in [decode] + [comps[b] for b in branches]:
+        assert not re.search(r"= %s\S* copy(-start)?\(" % whole, where)
+    if name == "olmo_hybrid":
+        for b in branches:                  # bf16[32,<prefix>,30,128]
+            assert not re.search(
+                r"= bf16\[32,\d+,30,128\]\S* (slice|copy)\(", comps[b])
+            assert "multiply_reduce_fusion" in comps[b]
+
+
+def test_kanana_decode_loop_copies_no_whole_latent_cache(one_chip, on_tpu):
+    """The fixed-batch engine's whole program of ``ppo-kanana-ep8-sync``
+    (all six layers: the dense one and five of 16 held experts, latent
+    attention in each) at 32 x 512 + 512: the decode loop holds one
+    ``conditional`` of 8 branches a layer, and neither its body nor a
+    branch copies a whole layer's latents (``bf16[32,1024,512]``; no
+    ``copy``, ``copy-start`` or ``copy-done``).  This pins what the
+    compile for a described chip shows, and that is less than the chip
+    showed: the cell's trace of a first written form of this step had
+    the whole ``c`` staged through ``S(1)``, this tree's has not, and
+    what could be rebuilt of the difference (the step behind a
+    ``jax.jit`` or inline) leaves this text the same (PERF.md section
+    7, PR 43)."""
+    import dataclasses
+    import re
+
+    from orion_tpu.config import ModelConfig, RolloutConfig
+    from orion_tpu.models import Transformer, init_params
+    from orion_tpu.rollout.engine import RolloutEngine
+
+    mc = dataclasses.replace(
+        ModelConfig.kanana_2_30b_a3b(), num_layers=6, experts_held=16,
+        vocab_size=16032, max_seq_len=1024, scan_layers=True)
+    model = Transformer(mc)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: init_params(model, jax.random.key(0), mc)))
+    eng = RolloutEngine(model, mc, RolloutConfig(
+        max_prompt_len=512, max_new_tokens=512), eos_token_id=0,
+        pad_token_id=0)
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    with jax.default_matmul_precision("default"):
+        text = eng._generate_jit.lower(
+            params, _sds((32, 512), jnp.int32, one_chip),
+            _sds((32,), jnp.int32, one_chip),
+            _sds(rng.shape, rng.dtype, one_chip),
+            max_new_tokens=512).compile().as_text()
+    comps = _computations(text)
+    switch = r" conditional\(.*branch_computations=\{([^}]*)\}"
+    (decode,) = [body for body in _while_bodies(text).values()
+                 if re.search(switch, body)
+                 and re.search(switch, body).group(1).count(",") == 7]
+    found = re.findall(switch, decode)
+    assert [f.count(",") + 1 for f in found] == [8] * 6
+    branches = [b.strip().lstrip("%") for f in found for b in f.split(",")]
+    for where in [decode] + [comps[b] for b in branches]:
+        assert not re.search(
+            r"= bf16\[32,1024,512\]\S* copy(-start|-done)?\(", where)
 
 
 def test_kimi_linear_update_compiles_for_v5e(one_chip, on_tpu):

@@ -521,7 +521,7 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
         after["layers_1to2"]["mlp"]["e_score_correction_bias"],
         before["layers_1to2"]["mlp"]["e_score_correction_bias"])
     trainer = kept["trainer"]
-    sizes = trainer._rollout_bytes((4, 16))
+    sizes = trainer._rollout_bytes((4, 16), [16] * 4)
     assert sizes["state_bytes"] > sizes["cache_bytes"] > 0
     assert sizes["weight_bytes"] > 0
     assert sizes["kda_step"] == "jnp"            # the CPU's form

@@ -546,7 +546,7 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
     # half the heads, half the experts
     assert after["layers_0to1"]["attn"]["A_log"].shape == (2, 4)
     assert after["layers_0to1"]["mlp"]["experts_up_proj"].shape[:2] == (2, 4)
-    sizes = kept["trainer"]._rollout_bytes((4, 16))
+    sizes = kept["trainer"]._rollout_bytes((4, 16), [16] * 4)
     assert sizes["state_bytes"] > 0 and sizes["cache_bytes"] > 0 \
         and sizes["weight_bytes"] > 0
     assert sizes["kda_step"] == ""

@@ -319,7 +319,7 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
             after["layers_0to2"]["attn"][name])
             - before["layers_0to2"]["attn"][name]))
         assert moved > 0, name
-    sizes = kept["trainer"]._rollout_bytes((4, 16))
+    sizes = kept["trainer"]._rollout_bytes((4, 16), [16] * 4)
     assert sizes["state_bytes"] > 0 and sizes["cache_bytes"] > 0 \
         and sizes["weight_bytes"] > 0
     assert sizes["kda_step"] == "jnp"            # the CPU's form
