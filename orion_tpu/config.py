@@ -395,6 +395,18 @@ class ModelConfig:
                 "mamba": self.mamba_num_heads // of,
                 "groups": self.mamba_n_groups // of}
 
+    def attn_heads_a_step(self) -> int:
+        """The query heads one grid step of the flash kernels holds
+        (ops/pallas/flash_attention.py: the heads that share a key
+        head, of what ``head_share`` leaves here); 1 where no layer
+        keeps a per-head K/V cache ("attention", "sparse": latent
+        attention expands one key head a query head)."""
+        if not any(m in ("attention", "sparse")
+                   for m, _ in self.layer_kinds()):
+            return 1
+        held = self.heads_held()
+        return held["q"] // held["kv"]
+
     def delta_head_dims(self) -> Optional[tuple]:
         """(dk, dv) of the delta-rule layers' heads: what
         ``ops.kda.chunk_form`` is asked with; None without such a

@@ -9,7 +9,7 @@ kernels.  Design:
   the MXU — or, for v, o and dq, (head-dim, seq-block): see the
   transposed tiles below.
 - Both loop dimensions are *grid* dimensions: the forward/dq grid is
-  (B, H, q-block, kv-block) and the dkv grid is (B, H, kv-block,
+  (B, Hkv, q-block, kv-block) and the dkv grid is (B, Hkv, kv-block,
   q-block), with online-softmax / gradient accumulators carried in VMEM
   scratch across the innermost dimension (sequential on TPU).  VMEM
   footprint is therefore O(block), not O(L) — long-context safe.
@@ -31,8 +31,18 @@ kernels.  Design:
   transposed: the wrappers' own layout transposes absorb it),
   ``dv = p^T dO``, ``dp^T = v dO^T``, ``dk = ds^T q`` and
   ``dq^T = k^T ds^T`` (k enters dq a second time, transposed).
-- GQA via BlockSpec index maps (``h // n_rep``) — no materialized
-  ``repeat_kv``.
+- GQA: a grid step holds the GROUP of ``n_rep = H // Hkv`` query heads
+  that share a key head (q, o^T, dO, dq^T, ``lse``, ``delta`` blocks
+  are ``n_rep`` heads thick, the accumulators one slab a head), so K,
+  V and the selection's block are fetched once a group, and the mask
+  of a wide tile (causal rule, ``kv_positions``, the selection's int8
+  block unpacked) is built once a group, as a float32 tile that every
+  head adds to its scores (``_TileMask``), in a rolled loop over the
+  heads (``_for_each_head``).  A group's forward goes over an extent
+  of several kv tiles in two passes, tile by tile (``_fwd_kernel``).
+  No materialized ``repeat_kv``.  ``n_rep`` = 1 (one key head a query
+  head) is the program of a grid over query heads: the same grid
+  extents and blocks, the select on the boolean mask, no loop.
 - The value width ``Dv`` may differ from the query/key width ``D``
   (latent attention expands keys of 192 and values of 128): v, o, dO
   and dV blocks are ``Dv`` wide, q, k, dQ and dK blocks ``D`` wide.
@@ -56,8 +66,10 @@ kernels.  Design:
   lse ≈ -inf — exactly the neutral element of the streaming-softmax
   merge in parallel.longctx.ring_attention.
 - Backward is the standard two-kernel flash split: dQ over kv-blocks,
-  dK/dV over q-blocks, recomputing P from the saved LSE.  For GQA the
-  dK/dV kernel emits per-q-head gradients, group-summed outside.  The
+  dK/dV over q-blocks, recomputing P from the saved LSE.  The query
+  heads of a group add into ONE float32 dK / dV accumulator inside the
+  dK/dV kernel, which leaves [B, Hkv, Lk, D] in the inputs' dtype: the
+  group's sum is rounded once, and no per-q-head gradient exists.  The
   per-chunk entry points (``flash_chunk_*``) take a caller-supplied
   GLOBAL lse, which is what makes the ring-attention backward exact.
 - Precision follows the INPUT dtype and nothing else.  All five
@@ -165,9 +177,10 @@ def _dot(a, b, contract):
 # softmax reductions run down sublanes, element-wise, and every
 # product takes its operands as they lie: s^T = k q^T, o^T = v^T p^T,
 # dv = p^T dO, dp^T = v dO^T, dk = ds^T q.  kvpos is [B, Lk, 1].
-# Forward and dq: grid (B, H, nq, nkv), kv innermost; dkv: grid
-# (B, H, nkv, nq), q innermost; one grid step holds a MAJOR block of
-# several tiles along each sequence dim (see _tiles).
+# Forward and dq: grid (B, Hkv, nq, nkv), kv innermost; dkv: grid
+# (B, Hkv, nkv, nq), q innermost; one grid step holds a MAJOR block of
+# several tiles along each sequence dim (see _tiles), for the H // Hkv
+# query heads of one key head.
 # ---------------------------------------------------------------------------
 
 # The most keys or queries one grid step holds.
@@ -261,6 +274,67 @@ def _seen(qpos_ref, kvpos_ref, kv_start, kv_rows, q_cols, shape,
     return kvcol <= qpos_ref[0, :, q_cols]
 
 
+class _TileMask:
+    """The mask of one wide tile (``_seen``), built ONCE and applied by
+    every query head of the grid step's group.
+
+    One head (``bias_ref`` None): the select itself on the boolean
+    tile, the program of a grid step that held one head (``seen`` is
+    called where that step built it: after the head's scores).  A
+    group: a float32 tile in VMEM scratch, 0 where the pair is seen and
+    ``fill`` elsewhere, written here, which every head ADDS to its
+    scores (one add a head instead of the causal compare, the
+    selection's unpacking and the select).  The sums are exact:
+    ``s + 0`` is ``s``; the forward's ``fill`` is ``NEG_INF``, whose
+    ulp (7.6e22) no score comes near, so ``s + NEG_INF`` IS
+    ``NEG_INF``; the backward's is ``_DEAD``, under which
+    ``exp(s + _DEAD - lse)`` is 0 for every ``lse`` a forward can leave
+    (``NEG_INF`` itself would give ``exp(0)`` on a row whose ``lse`` is
+    ``NEG_INF``: a ring chunk's row with no key)."""
+
+    def __init__(self, seen, bias_ref, fill, at):
+        self._seen, self.bias_ref, self.at = seen, bias_ref, at
+        if bias_ref is not None:
+            bias_ref[at] = jnp.where(seen(), 0.0, fill)
+
+    def scores(self, st, rows=None):
+        """``st`` where the pair is seen, ``NEG_INF`` elsewhere; a
+        group may pass the tile's ``rows`` that ``st`` holds."""
+        if self.bias_ref is None:
+            return jnp.where(self._seen(), st, NEG_INF)
+        return st + self.bias_ref[self.at if rows is None
+                                  else (rows, self.at[1])]
+
+    def probs(self, st, lse):
+        """``exp(st - lse)`` where the pair is seen, 0 elsewhere."""
+        if self.bias_ref is None:
+            return jnp.where(self._seen(), jnp.exp(st - lse), 0.0)
+        return jnp.exp(st + self.bias_ref[self.at] - lse)
+
+
+def _for_each_head(n_rep: int, body):
+    """``body(h)`` for the ``n_rep`` query heads of the step's group:
+    one head is the call itself; a group is a ROLLED loop.  (Unrolled,
+    eight heads' wide tiles are 67 000 bundles of straight-line code in
+    which the scheduler overlaps nothing across heads, and they ran
+    1.5 x slower on the chip than one head a step at FEWER bundles a
+    head: the code's size, not its schedule; PERF.md section 6,
+    PR 44.)"""
+    if n_rep == 1:
+        body(0)
+        return
+
+    def turn(h, carry):
+        body(h)
+        return carry
+
+    jax.lax.fori_loop(0, n_rep, turn, None)
+
+
+# The backward's fill of a group's mask tile: see _TileMask.
+_DEAD = -3e38
+
+
 def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
                 scale: float, use_kvpos: bool, nq_sub: int, n_sub: int,
                 use_sel: bool = False):
@@ -269,34 +343,75 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         kvpos_ref, *rest = rest
     if use_sel:
         sel_ref, *rest = rest
-    q_ref, k_ref, vt_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc = rest
+    (q_ref, k_ref, vt_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
+     *bias_sc) = rest
+    bias_sc, st_sc = bias_sc or (None, None)
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
+    n_rep = q_ref.shape[1]
     blk_q, major = q_ref.shape[2] // nq_sub, k_ref.shape[2]
     blk_kv = major // n_sub
 
     @pl.when(j == 0)
     def _():
-        m_sc[:, :] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:, :] = jnp.zeros_like(l_sc)
-        acc_sc[:, :] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
     def update(cols, c):
         width = (c + 1) * blk_kv
-        vt = vt_ref[0, 0, :, :width]                             # [Dv, w]
-        st = _dot(k_ref[0, 0, :width, :], q_ref[0, 0, cols, :],
-                  _NT) * scale                                   # [w, bq]
-        st = jnp.where(_seen(qpos_ref, kvpos_ref, j * major,
-                             slice(0, width), cols, st.shape, sel_ref),
-                       st, NEG_INF)
-        m_prev, l_prev = m_sc[:, cols], l_sc[:, cols]            # [1, bq]
-        m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
-        pt = jnp.exp(st - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_sc[:, cols] = m_new
-        l_sc[:, cols] = l_prev * alpha + jnp.sum(pt, axis=0, keepdims=True)
-        acc_sc[:, cols] = acc_sc[:, cols] * alpha + _dot(
-            vt, pt.astype(vt.dtype), _NN)                        # [Dv, bq]
+        mask = _TileMask(
+            functools.partial(_seen, qpos_ref, kvpos_ref, j * major,
+                              slice(0, width), cols, (width, blk_q),
+                              sel_ref),
+            bias_sc, NEG_INF, (slice(0, width), slice(None)))
+
+        def group_head(h):
+            # An extent of several kv tiles, a head of a group: two
+            # passes over the tiles, the scores between them in VMEM.
+            # Tile by tile the scheduler overlaps one tile's product
+            # with the element-wise work of its neighbour, which it does
+            # not inside one wide tile (there the two products and the
+            # softmax run one after the other: PERF.md section 6, PR 44).
+            q = q_ref[0, h, cols, :]
+            m_prev, l_prev = m_sc[h, :, cols], l_sc[h, :, cols]  # [1, bq]
+            m_new = m_prev
+            for t in range(c + 1):
+                rows = slice(t * blk_kv, (t + 1) * blk_kv)
+                st = mask.scores(_dot(k_ref[0, 0, rows, :], q, _NT) * scale,
+                                 rows)                           # [bkv, bq]
+                st_sc[rows, :] = st
+                m_new = jnp.maximum(m_new,
+                                    jnp.max(st, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            m_sc[h, :, cols] = m_new
+            l_new = l_prev * alpha
+            acc_sc[h, :, cols] = acc_sc[h, :, cols] * alpha
+            for t in range(c + 1):
+                rows = slice(t * blk_kv, (t + 1) * blk_kv)
+                pt = jnp.exp(st_sc[rows, :] - m_new)
+                l_new = l_new + jnp.sum(pt, axis=0, keepdims=True)
+                acc_sc[h, :, cols] = acc_sc[h, :, cols] + _dot(
+                    vt_ref[0, 0, :, rows], pt.astype(vt_ref.dtype), _NN)
+            l_sc[h, :, cols] = l_new
+
+        def one_head(h):             # ONE wide tile: the step of one head
+            vt = vt_ref[0, 0, :, :width]                         # [Dv, w]
+            st = mask.scores(_dot(k_ref[0, 0, :width, :],
+                                  q_ref[0, h, cols, :], _NT) * scale)
+            m_prev, l_prev = m_sc[h, :, cols], l_sc[h, :, cols]  # [1, bq]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(st, axis=0, keepdims=True))
+            pt = jnp.exp(st - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_sc[h, :, cols] = m_new
+            l_sc[h, :, cols] = l_prev * alpha + jnp.sum(
+                pt, axis=0, keepdims=True)
+            acc_sc[h, :, cols] = acc_sc[h, :, cols] * alpha + _dot(
+                vt, pt.astype(vt.dtype), _NN)                    # [Dv, bq]
+
+        _for_each_head(n_rep,
+                       group_head if n_rep > 1 and c > 0 else one_head)
 
     for r in range(nq_sub):
         # kv tile c of this major block holds a key some row of q tile r sees
@@ -311,9 +426,35 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         # Rows with no valid key at all (possible per ring chunk) keep
         # l = 0: guard the division -> o = 0, lse ≈ NEG_INF (the merge
         # neutral element).
-        l_safe = jnp.maximum(l_sc[:, :], 1e-30)
-        o_ref[0, 0, :, :] = (acc_sc[:, :] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0, :, :] = m_sc[:, :] + jnp.log(l_safe)
+        def head(h):
+            l_safe = jnp.maximum(l_sc[h], 1e-30)
+            o_ref[0, h] = (acc_sc[h] / l_safe).astype(o_ref.dtype)
+            lse_ref[0, h] = m_sc[h] + jnp.log(l_safe)
+
+        _for_each_head(n_rep, head)
+
+
+# Mosaic's default scoped VMEM limit on a v5e: what a grid step of ONE
+# head's blocks compiles under, with its wide tile's temporaries.
+_SCOPED_VMEM = 16 << 20
+
+
+def _group_params(n_rep: int, head_bytes: int, tile: tuple, tiles: int = 1):
+    """(compiler params, scratch tiles) of a call whose grid step holds
+    ``n_rep`` heads.  One head: nothing, the default limit and no
+    scratch tile.  A group asks for the default limit plus what it
+    adds, from the blocks' sizes: ``head_bytes`` (a head's blocks,
+    double buffered, and its accumulators) for every further head, and
+    ``tiles`` float32 scratch tiles of shape ``tile`` (the mask's,
+    ``_TileMask``; the forward's scores) with one more for a
+    temporary."""
+    if n_rep == 1:
+        return None, []
+    tile_bytes = 4 * tile[0] * tile[1]
+    return (pltpu.CompilerParams(vmem_limit_bytes=_SCOPED_VMEM
+                                 + (n_rep - 1) * head_bytes
+                                 + (tiles + 1) * tile_bytes),
+            [pltpu.VMEM(tile, jnp.float32)] * tiles)
 
 
 def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
@@ -322,7 +463,8 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
     [B,Lk,1] -> out [B,H,Lq,Dv], lse [B,H,1,Lq].  clamp=True enables
     the contiguous-path fetch clamps.  ``sel_t`` [B, Lk, Lq] int8: a
     selection every head shares, an operand only where it is given (the
-    kernel is then ``sparse_fwd``)."""
+    kernel is then ``sparse_fwd``).  A grid step holds the ``H // Hkv``
+    query heads of one key head."""
     B, H, Lq, D = qt.shape
     Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
@@ -330,41 +472,44 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
     qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
     fetch = _kv_fetch(p, clamp)
+    params, bias = _group_params(
+        n_rep, qmajor * (2 * qt.dtype.itemsize * (D + Dv) + 4 * (Dv + 32)),
+        (major, p.bq), tiles=2)
 
-    def k_map(b, h, i, j, qm, im, km):
-        return (b, h // n_rep, fetch(qm, b, i, j), 0)
+    def k_map(b, g, i, j, qm, im, km):
+        return (b, g, fetch(qm, b, i, j), 0)
 
-    def vt_map(b, h, i, j, qm, im, km):
-        return (b, h // n_rep, 0, fetch(qm, b, i, j))
+    def vt_map(b, g, i, j, qm, im, km):
+        return (b, g, 0, fetch(qm, b, i, j))
 
-    def q_lanes(b, h, i, j, qm, im, km):
-        return (b, h, 0, i)
+    def q_lanes(b, g, i, j, qm, im, km):
+        return (b, g, 0, i)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, H, p.nq, p.nkv),
+        grid=(B, Hkv, p.nq, p.nkv),
         in_specs=(
             [pl.BlockSpec((1, 1, qmajor),
-                          lambda b, h, i, j, qm, im, km: (b, 0, i))]
+                          lambda b, g, i, j, qm, im, km: (b, 0, i))]
             + ([pl.BlockSpec((1, major, 1),
-                             lambda b, h, i, j, qm, im, km: (b, j, 0))]
+                             lambda b, g, i, j, qm, im, km: (b, j, 0))]
                if use_kvpos else [])
             + ([pl.BlockSpec((1, major, qmajor),
-                             lambda b, h, i, j, qm, im, km:
+                             lambda b, g, i, j, qm, im, km:
                              (b, fetch(qm, b, i, j), i))]
                if sel_t is not None else [])
-            + [pl.BlockSpec((1, 1, qmajor, D),
-                            lambda b, h, i, j, qm, im, km: (b, h, i, 0)),
+            + [pl.BlockSpec((1, n_rep, qmajor, D),
+                            lambda b, g, i, j, qm, im, km: (b, g, i, 0)),
                pl.BlockSpec((1, 1, major, D), k_map),
                pl.BlockSpec((1, 1, Dv, major), vt_map)]
         ),
-        out_specs=[pl.BlockSpec((1, 1, Dv, qmajor), q_lanes),
-                   pl.BlockSpec((1, 1, 1, qmajor), q_lanes)],
-        scratch_shapes=[
-            pltpu.VMEM((1, qmajor), jnp.float32),    # running max
-            pltpu.VMEM((1, qmajor), jnp.float32),    # running sumexp
-            pltpu.VMEM((Dv, qmajor), jnp.float32),   # running accumulator
-        ],
+        out_specs=[pl.BlockSpec((1, n_rep, Dv, qmajor), q_lanes),
+                   pl.BlockSpec((1, n_rep, 1, qmajor), q_lanes)],
+        scratch_shapes=[          # one slab a head of the group
+            pltpu.VMEM((n_rep, 1, qmajor), jnp.float32),   # running max
+            pltpu.VMEM((n_rep, 1, qmajor), jnp.float32),   # running sumexp
+            pltpu.VMEM((n_rep, Dv, qmajor), jnp.float32),  # accumulator
+        ] + bias,
     )
     operands = [*p.tables, qpos3]
     if use_kvpos:
@@ -384,6 +529,7 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
             jax.ShapeDtypeStruct((B, H, Dv, Lq), qt.dtype),
             jax.ShapeDtypeStruct((B, H, 1, Lq), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret_mode(),
     )(*operands)
     return out_t.swapaxes(2, 3), lse
@@ -403,29 +549,38 @@ def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
     if use_sel:
         sel_ref, *rest = rest
     (q_ref, k_ref, kt_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-     dq_sc) = rest
+     dq_sc, *bias_sc) = rest
+    (bias_sc,) = bias_sc or (None,)
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
+    n_rep = q_ref.shape[1]
     blk_q, major = q_ref.shape[2] // nq_sub, k_ref.shape[2]
     blk_kv = major // n_sub
 
     @pl.when(j == 0)
     def _():
-        dq_sc[:, :] = jnp.zeros_like(dq_sc)
+        dq_sc[...] = jnp.zeros_like(dq_sc)
 
     def update(cols, c):
         width = (c + 1) * blk_kv
-        kt = kt_ref[0, 0, :, :width]                             # [D, w]
-        do = do_ref[0, 0, cols, :]                               # [bq, Dv]
-        st = _dot(k_ref[0, 0, :width, :], q_ref[0, 0, cols, :],
-                  _NT) * scale                                   # [w, bq]
-        pt = jnp.where(_seen(qpos_ref, kvpos_ref, j * major,
-                             slice(0, width), cols, st.shape, sel_ref),
-                       jnp.exp(st - lse_ref[0, 0, :, cols]), 0.0)
-        dpt = _dot(v_ref[0, 0, :width, :], do, _NT)              # [w, bq]
-        dst = pt * (dpt - delta_ref[0, 0, :, cols])
-        dq_sc[:, cols] = dq_sc[:, cols] + _dot(
-            kt, dst.astype(kt.dtype), _NN)                       # [D, bq]
+        mask = _TileMask(
+            functools.partial(_seen, qpos_ref, kvpos_ref, j * major,
+                              slice(0, width), cols, (width, blk_q),
+                              sel_ref),
+            bias_sc, _DEAD, (slice(0, width), slice(None)))
+
+        def head(h):                 # one of those that share k, v, the mask
+            kt = kt_ref[0, 0, :, :width]                         # [D, w]
+            do = do_ref[0, h, cols, :]                           # [bq, Dv]
+            st = _dot(k_ref[0, 0, :width, :], q_ref[0, h, cols, :],
+                      _NT) * scale                               # [w, bq]
+            pt = mask.probs(st, lse_ref[0, h, :, cols])
+            dpt = _dot(v_ref[0, 0, :width, :], do, _NT)          # [w, bq]
+            dst = pt * (dpt - delta_ref[0, h, :, cols])
+            dq_sc[h, :, cols] = dq_sc[h, :, cols] + _dot(
+                kt, dst.astype(kt.dtype), _NN)                   # [D, bq]
+
+        _for_each_head(n_rep, head)
 
     for r in range(nq_sub):
         _for_each_extent(
@@ -436,7 +591,10 @@ def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
 
     @pl.when(j == nj - 1)
     def _():
-        dq_ref[0, 0, :, :] = (dq_sc[:, :] * scale).astype(dq_ref.dtype)
+        def head(h):
+            dq_ref[0, h] = (dq_sc[h] * scale).astype(dq_ref.dtype)
+
+        _for_each_head(n_rep, head)
 
 
 def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
@@ -448,9 +606,11 @@ def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
     if use_sel:
         sel_ref, *rest = rest
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-     dv_ref, dk_sc, dv_sc) = rest
+     dv_ref, dk_sc, dv_sc, *bias_sc) = rest
+    (bias_sc,) = bias_sc or (None,)
     b, j, i = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     ni = pl.num_programs(3)
+    n_rep = q_ref.shape[1]
     qmajor, major = q_ref.shape[2], k_ref.shape[2]
     blk_q, blk_kv = qmajor // nq_sub, major // n_sub
 
@@ -461,16 +621,26 @@ def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
 
     def update(rows, c):
         cols = slice(c * blk_q, qmajor)     # from q tile c to the block's end
-        q = q_ref[0, 0, cols, :]                                 # [w, D]
-        do = do_ref[0, 0, cols, :]                               # [w, Dv]
-        st = _dot(k_ref[0, 0, rows, :], q, _NT) * scale          # [bkv, w]
-        pt = jnp.where(_seen(qpos_ref, kvpos_ref, j * major + rows.start,
-                             rows, cols, st.shape, sel_ref),
-                       jnp.exp(st - lse_ref[0, 0, :, cols]), 0.0)
-        dv_sc[rows, :] = dv_sc[rows, :] + _dot(pt.astype(do.dtype), do, _NN)
-        dpt = _dot(v_ref[0, 0, rows, :], do, _NT)                # [bkv, w]
-        dst = pt * (dpt - delta_ref[0, 0, :, cols])
-        dk_sc[rows, :] = dk_sc[rows, :] + _dot(dst.astype(q.dtype), q, _NN)
+        width = qmajor - cols.start
+        mask = _TileMask(
+            functools.partial(_seen, qpos_ref, kvpos_ref,
+                              j * major + rows.start, rows, cols,
+                              (blk_kv, width), sel_ref),
+            bias_sc, _DEAD, (slice(None), slice(0, width)))
+
+        def head(h):     # the group's heads add into ONE dk / dv, in float32
+            q = q_ref[0, h, cols, :]                             # [w, D]
+            do = do_ref[0, h, cols, :]                           # [w, Dv]
+            st = _dot(k_ref[0, 0, rows, :], q, _NT) * scale      # [bkv, w]
+            pt = mask.probs(st, lse_ref[0, h, :, cols])
+            dv_sc[rows, :] = dv_sc[rows, :] + _dot(
+                pt.astype(do.dtype), do, _NN)
+            dpt = _dot(v_ref[0, 0, rows, :], do, _NT)            # [bkv, w]
+            dst = pt * (dpt - delta_ref[0, h, :, cols])
+            dk_sc[rows, :] = dk_sc[rows, :] + _dot(
+                dst.astype(q.dtype), q, _NN)
+
+        _for_each_head(n_rep, head)
 
     for r in range(n_sub):
         # q tile c of this major block holds a query that sees a key of
@@ -496,36 +666,39 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
     qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
     fetch = _kv_fetch(p, clamp)
+    params, bias = _group_params(
+        n_rep, qmajor * (2 * qt.dtype.itemsize * (2 * D + Dv) + 4 * (D + 32)),
+        (major, p.bq))
 
-    def kv_map(b, h, i, j, qm, im, km):
-        return (b, h // n_rep, fetch(qm, b, i, j), 0)
+    def kv_map(b, g, i, j, qm, im, km):
+        return (b, g, fetch(qm, b, i, j), 0)
 
-    def kt_map(b, h, i, j, qm, im, km):
-        return (b, h // n_rep, 0, fetch(qm, b, i, j))
+    def kt_map(b, g, i, j, qm, im, km):
+        return (b, g, 0, fetch(qm, b, i, j))
 
-    def q_rows(b, h, i, j, qm, im, km):
-        return (b, h, i, 0)
+    def q_rows(b, g, i, j, qm, im, km):
+        return (b, g, i, 0)
 
-    def q_lanes(b, h, i, j, qm, im, km):
-        return (b, h, 0, i)
+    def q_lanes(b, g, i, j, qm, im, km):
+        return (b, g, 0, i)
 
     in_specs = (
         [pl.BlockSpec((1, 1, qmajor),
-                      lambda b, h, i, j, qm, im, km: (b, 0, i))]
+                      lambda b, g, i, j, qm, im, km: (b, 0, i))]
         + ([pl.BlockSpec((1, major, 1),
-                         lambda b, h, i, j, qm, im, km: (b, j, 0))]
+                         lambda b, g, i, j, qm, im, km: (b, j, 0))]
            if use_kvpos else [])
         + ([pl.BlockSpec((1, major, qmajor),
-                         lambda b, h, i, j, qm, im, km:
+                         lambda b, g, i, j, qm, im, km:
                          (b, fetch(qm, b, i, j), i))]
            if sel_t is not None else [])
-        + [pl.BlockSpec((1, 1, qmajor, D), q_rows),
+        + [pl.BlockSpec((1, n_rep, qmajor, D), q_rows),
            pl.BlockSpec((1, 1, major, D), kv_map),
            pl.BlockSpec((1, 1, D, major), kt_map),
            pl.BlockSpec((1, 1, major, Dv), kv_map),
-           pl.BlockSpec((1, 1, qmajor, Dv), q_rows),
-           pl.BlockSpec((1, 1, 1, qmajor), q_lanes),
-           pl.BlockSpec((1, 1, 1, qmajor), q_lanes)]
+           pl.BlockSpec((1, n_rep, qmajor, Dv), q_rows),
+           pl.BlockSpec((1, n_rep, 1, qmajor), q_lanes),
+           pl.BlockSpec((1, n_rep, 1, qmajor), q_lanes)]
     )
     operands = [*p.tables, qpos3]
     if use_kvpos:
@@ -542,12 +715,14 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
                           use_sel=sel_t is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, H, p.nq, p.nkv),
+            grid=(B, Hkv, p.nq, p.nkv),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, D, qmajor), q_lanes),
-            scratch_shapes=[pltpu.VMEM((D, qmajor), jnp.float32)],
+            out_specs=pl.BlockSpec((1, n_rep, D, qmajor), q_lanes),
+            scratch_shapes=[pltpu.VMEM((n_rep, D, qmajor), jnp.float32)]
+            + bias,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, D, Lq), qt.dtype),
+        compiler_params=params,
         interpret=interpret_mode(),
     )(*operands)
     return dq_t.swapaxes(2, 3)
@@ -555,14 +730,18 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
 
 def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
               blk_q, blk_kv, clamp: bool, sel_t=None):
-    """Per-q-head dK/dV [B, H, Lk, D]: float32 where the caller still
-    has to group-sum them (GQA), else in the inputs' dtype."""
+    """dK [B, Hkv, Lk, D] and dV [B, Hkv, Lk, Dv] in the inputs' dtype:
+    the query heads of a key head add into one float32 accumulator
+    inside the kernel, which is rounded once."""
     B, H, Lq, D = qt.shape
     Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
     p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3)
     qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
+    params, bias = _group_params(
+        n_rep, qmajor * (2 * qt.dtype.itemsize * (D + Dv) + 128),
+        (p.bkv, qmajor))
 
     def first(im, b, j, i):
         # q major blocks before this kv block's causal frontier (its
@@ -570,35 +749,32 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
         # relevant one: monotone positions only
         return jnp.maximum(i, im[b, j * p.n_sub] // p.nq_sub) if clamp else i
 
-    def q_rows(b, h, j, i, qm, im, km):
-        return (b, h, first(im, b, j, i), 0)
+    def q_rows(b, g, j, i, qm, im, km):
+        return (b, g, first(im, b, j, i), 0)
 
-    def q_lanes(b, h, j, i, qm, im, km):
-        return (b, h, 0, first(im, b, j, i))
+    def q_lanes(b, g, j, i, qm, im, km):
+        return (b, g, 0, first(im, b, j, i))
 
-    def kv_in(b, h, j, i, qm, im, km):
-        return (b, h // n_rep, j, 0)
-
-    def kv_out(b, h, j, i, qm, im, km):
-        return (b, h, j, 0)
+    def kv(b, g, j, i, qm, im, km):
+        return (b, g, j, 0)
 
     in_specs = (
         [pl.BlockSpec((1, 1, qmajor),
-                      lambda b, h, j, i, qm, im, km:
+                      lambda b, g, j, i, qm, im, km:
                       (b, 0, first(im, b, j, i)))]
         + ([pl.BlockSpec((1, major, 1),
-                         lambda b, h, j, i, qm, im, km: (b, j, 0))]
+                         lambda b, g, j, i, qm, im, km: (b, j, 0))]
            if use_kvpos else [])
         + ([pl.BlockSpec((1, major, qmajor),
-                         lambda b, h, j, i, qm, im, km:
+                         lambda b, g, j, i, qm, im, km:
                          (b, j, first(im, b, j, i)))]
            if sel_t is not None else [])
-        + [pl.BlockSpec((1, 1, qmajor, D), q_rows),
-           pl.BlockSpec((1, 1, major, D), kv_in),
-           pl.BlockSpec((1, 1, major, Dv), kv_in),
-           pl.BlockSpec((1, 1, qmajor, Dv), q_rows),
-           pl.BlockSpec((1, 1, 1, qmajor), q_lanes),
-           pl.BlockSpec((1, 1, 1, qmajor), q_lanes)]
+        + [pl.BlockSpec((1, n_rep, qmajor, D), q_rows),
+           pl.BlockSpec((1, 1, major, D), kv),
+           pl.BlockSpec((1, 1, major, Dv), kv),
+           pl.BlockSpec((1, n_rep, qmajor, Dv), q_rows),
+           pl.BlockSpec((1, n_rep, 1, qmajor), q_lanes),
+           pl.BlockSpec((1, n_rep, 1, qmajor), q_lanes)]
     )
     operands = [*p.tables, qpos3]
     if use_kvpos:
@@ -606,49 +782,40 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
     if sel_t is not None:
         operands.append(sel_t)
     operands += [qt, kt, vt, dout_t, lse, delta]
-    grad_dtype = jnp.float32 if n_rep > 1 else kt.dtype
-    dk_h, dv_h = named_pallas_call(
+    return named_pallas_call(
         "flash_bwd_dkv" if sel_t is None else "sparse_bwd_dkv",
         functools.partial(_dkv_kernel, scale=scale, use_kvpos=use_kvpos,
                           nq_sub=p.nq_sub, n_sub=p.n_sub,
                           use_sel=sel_t is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(B, H, p.nkv, p.nq),
+            grid=(B, Hkv, p.nkv, p.nq),
             in_specs=in_specs,
-            out_specs=[pl.BlockSpec((1, 1, major, D), kv_out),
-                       pl.BlockSpec((1, 1, major, Dv), kv_out)],
+            out_specs=[pl.BlockSpec((1, 1, major, D), kv),
+                       pl.BlockSpec((1, 1, major, Dv), kv)],
             scratch_shapes=[
                 pltpu.VMEM((major, D), jnp.float32),
                 pltpu.VMEM((major, Dv), jnp.float32),
-            ],
+            ] + bias,
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, Lk, D), grad_dtype),
-            jax.ShapeDtypeStruct((B, H, Lk, Dv), grad_dtype),
+            jax.ShapeDtypeStruct((B, Hkv, Lk, D), kt.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, Lk, Dv), vt.dtype),
         ],
+        compiler_params=params,
         interpret=interpret_mode(),
     )(*operands)
-    return dk_h, dv_h
 
 
 def _bwd_impl(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv, out_t,
               lse, dout_t, clamp: bool, sel_t=None):
-    B, H, Lq, D = qt.shape
-    Hkv, Lk = kt.shape[1], kt.shape[2]
-    n_rep = H // Hkv
     # delta = rowsum(dO * O) — cheap elementwise, plain XLA.
     delta = jnp.sum(dout_t.astype(jnp.float32) * out_t.astype(jnp.float32),
                     axis=-1)[:, :, None, :]                   # [B, H, 1, Lq]
     dq = _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
                   blk_q, blk_kv, clamp, sel_t)
-    dk_h, dv_h = _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta,
-                           scale, blk_q, blk_kv, clamp, sel_t)
-    if n_rep > 1:
-        dk = dk_h.reshape(B, Hkv, n_rep, Lk, D).sum(axis=2)
-        dv = dv_h.reshape(B, Hkv, n_rep, Lk, vt.shape[3]).sum(axis=2)
-    else:
-        dk, dv = dk_h, dv_h
+    dk, dv = _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta,
+                       scale, blk_q, blk_kv, clamp, sel_t)
     return dq, dk, dv
 
 
@@ -724,8 +891,7 @@ def _vjp_bwd(scale, blk_q, blk_kv, residuals, dout):
                            blk_kv, out_t, lse, dout.transpose(0, 2, 1, 3),
                            clamp=True)
     return (dq.transpose(0, 2, 1, 3),
-            dk.transpose(0, 2, 1, 3).astype(kt.dtype),
-            dv.transpose(0, 2, 1, 3).astype(vt.dtype),
+            dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3),
             None)
 
 
@@ -771,8 +937,7 @@ def _sparse_vjp_bwd(scale, blk_q, blk_kv, residuals, dout):
                            blk_kv, out_t, lse, dout.transpose(0, 2, 1, 3),
                            clamp=True, sel_t=sel_t)
     return (dq.transpose(0, 2, 1, 3),
-            dk.transpose(0, 2, 1, 3).astype(kt.dtype),
-            dv.transpose(0, 2, 1, 3).astype(vt.dtype),
+            dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3),
             None, None)
 
 
@@ -816,5 +981,4 @@ def flash_chunk_grads(q, k, v, q_positions, kv_positions, out, lse,
         scale, blk_q, blk_kv, out.transpose(0, 2, 1, 3), lse[:, :, None, :],
         dout.transpose(0, 2, 1, 3), clamp=False)
     return (dq.transpose(0, 2, 1, 3),
-            dk.transpose(0, 2, 1, 3).astype(k.dtype),
-            dv.transpose(0, 2, 1, 3).astype(v.dtype))
+            dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3))

@@ -606,15 +606,18 @@ class BaseTrainer:
         ``weight_bytes`` (the decode copy of the weights),
         ``kda_step``, the form the delta rule's one-token step takes
         there (``kernel`` / ``jnp``, from ``ops/kda.py::step_form``;
-        ``""`` without such a layer), and what the engine says of its
+        ``""`` without such a layer), ``attn_heads_a_step`` (the query
+        heads a grid step of the prefill's flash kernels holds:
+        ``ModelConfig.attn_heads_a_step``), and what the engine says of its
         attention step's read after prompts of ``lens`` real tokens
         (``RolloutEngine.kv_step_read``).  All 0 and ``""`` for the
         continuous engine, whose pool is its own and which refuses
         recurrent models."""
         eng = self.engine
+        heads = {"attn_heads_a_step": self.cfg.model.attn_heads_a_step()}
         if not hasattr(eng, "state_bytes"):
             return {"cache_bytes": 0, "state_bytes": 0, "weight_bytes": 0,
-                    "kda_step": ""}
+                    "kda_step": "", **heads}
         sparse = {}
         if self.cfg.model.sa_topk:
             # the indexer's keys, part of cache_bytes: a step reads them
@@ -632,7 +635,7 @@ class BaseTrainer:
         return {"cache_bytes": eng.cache_bytes(*prompts_shape),
                 "state_bytes": eng.state_bytes(*prompts_shape),
                 "weight_bytes": eng.weight_bytes(self.state.params),
-                "kda_step": kda_step, **sparse,
+                "kda_step": kda_step, **heads, **sparse,
                 **eng.kv_step_read(lens, prompts_shape[1])}
 
     def _score_result(self, result, host, meta) -> np.ndarray:
@@ -857,7 +860,8 @@ class BaseTrainer:
             kda_chunk = chunk_form(*dims)
         self._remat_info = info = {
             "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0,
-            "kda_chunk": kda_chunk}
+            "kda_chunk": kda_chunk,
+            "attn_heads_a_step": self.cfg.model.attn_heads_a_step()}
         free = _device_free_bytes(self.state.params)
         if not self.cfg.model.remat or free is None:
             return

@@ -147,6 +147,12 @@ FLASH_SHAPES = {
     "pythia1b": (16, 384, 384, 8, 8, 256, 0),
     # llama3-8B width (GQA 32/8, D=128)
     "llama8b": (4, 1024, 1024, 32, 8, 128, 0),
+    # grouped calls: Nemotron-H's attention layer as ppo-nemotron-h-tp4-
+    # sync cuts it (8 query heads on ONE key head, 1280 tokens) and
+    # Keye-VL-2.0's heads without a selection (32 on 4, 8192 tokens:
+    # eight heads' blocks and 1024-wide major blocks in one grid step)
+    "nemotron_h": (16, 1280, 1280, 8, 1, 128, 0),
+    "keye_dense": (2, 8192, 8192, 32, 4, 128, 0),
     # tests/test_tpu_smoke.py regression shapes: a cache length that is
     # no multiple of 128 ...
     "odd_cache_144": (2, 16, 144, 8, 4, 64, 128),
@@ -183,7 +189,50 @@ def test_flash_fwd_compiles_for_v5e(name, one_chip, on_tpu):
     assert _kernel_names(compiled) == ["flash_fwd"]
 
 
-@pytest.mark.parametrize("name", ["pythia1b", "llama8b"])
+def _group_vmem_asked(compiled) -> dict:
+    """{kernel name: the scoped VMEM its custom call asks for, bytes}
+    of the compiled program's Pallas kernels (0 where a call asks for
+    nothing: Mosaic's default limit)."""
+    import re
+
+    asked = {}
+    for ln in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = .*custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', ln)
+        if m:
+            size = re.search(
+                r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', ln)
+            asked[m.group(1)] = int(size.group(1)) if size else 0
+    return asked
+
+
+def _assert_group_gradients_leave_summed(compiled, name):
+    """``flash_bwd_dkv`` / ``sparse_bwd_dkv`` of a call whose query
+    heads share key heads write dK and dV of the KEY heads in the
+    inputs' dtype: no float32 gradient a query head, and no reduce over
+    a group behind the kernel.  A call of one query head a key head asks
+    for no VMEM beyond the default; a group's kernels ask for what their
+    blocks take and compile under it, far below the chip's 128 MiB."""
+    import re
+
+    B, Lq, Lk, H, Hkv, D, _ = FLASH_SHAPES[name]
+    text = compiled.as_text()
+    assert f"f32[{B},{H},{Lk},{D}]" not in text
+    assert not re.search(
+        r"= \w+\[%d,(%d,%d|%d,%d),%d\]\S* reduce\(" % (
+            B, Hkv, Lk, Lk, Hkv, D), text)
+    asked = _group_vmem_asked(compiled)
+    assert len(asked) == 3
+    if H == Hkv:
+        assert set(asked.values()) == {0}
+    else:
+        assert all(16 << 20 < a <= 48 << 20 for a in asked.values()), asked
+        assert re.search(r"bf16\[%d,%d,%d,%d\]\S*, bf16\[%d,%d,%d,%d\]\S*\) "
+                         r"custom-call" % ((B, Hkv, Lk, D) * 2), text)
+
+
+@pytest.mark.parametrize("name", ["pythia1b", "llama8b", "nemotron_h",
+                                  "keye_dense"])
 def test_flash_fwd_bwd_compiles_for_v5e(name, one_chip, on_tpu):
     fwd = _flash(name)
 
@@ -196,6 +245,7 @@ def test_flash_fwd_bwd_compiles_for_v5e(name, one_chip, on_tpu):
     assert _kernel_calls(compiled) == 3
     assert _kernel_names(compiled) == ["flash_bwd_dkv", "flash_bwd_dq",
                                        "flash_fwd"]
+    _assert_group_gradients_leave_summed(compiled, name)
 
 
 def test_flash_ring_chunk_compiles_for_v5e(one_chip, on_tpu):
@@ -871,6 +921,16 @@ def test_sparse_attention_compiles_for_v5e(Lq, one_chip, on_tpu):
         *_sparse_args(2, Lq, 8192, one_chip)).compile()
     assert _kernel_names(compiled) == ["sparse_bwd_dkv", "sparse_bwd_dq",
                                        "sparse_fwd"]
+    # a grid step holds the 8 query heads of a key head: the kernels ask
+    # for the VMEM of their blocks (q, o^T, dO, dq^T 2 MiB each, double
+    # buffered, the mask and score tiles), and dK / dV leave summed
+    asked = _group_vmem_asked(compiled)
+    assert all(16 << 20 < a <= 48 << 20 for a in asked.values()), asked
+    text = compiled.as_text()
+    assert "f32[2,32,8192,128]" not in text
+    assert "bf16[2,4,8192,128]" in text
+    import re
+    assert not re.search(r"= \w+\[2,(4,8192|8192,4),128\]\S* reduce\(", text)
 
 
 def test_keye_dsa_update_compiles_for_v5e(one_chip, on_tpu):

@@ -394,3 +394,214 @@ def test_kernel_products_take_the_input_dtype(dtype):
         assert [x.aval.dtype for x in e.invars] == [dtype, dtype], e
         assert e.outvars[0].aval.dtype == jnp.float32, e
     assert not upcasts, upcasts
+
+
+# -- a grid step holds the query heads of one key head (ISSUE 44) ----------
+
+def _group_case(n_rep, sel, Dv=16, ragged=False, dtype=jnp.float32,
+                Hkv=2, L=256, D=16):
+    """Operands of one grouped call at tiles of 128 (L = 256: the second
+    q tile's extent is two kv tiles wide, the first's one), their
+    selection (or None) and positions."""
+    B, H = 2, Hkv * n_rep
+    ks = jax.random.split(jax.random.key(7 * n_rep + Dv), 5)
+    q = jax.random.normal(ks[0], (B, L, H, D), dtype)
+    k = jax.random.normal(ks[1], (B, L, Hkv, D), dtype)
+    v = jax.random.normal(ks[2], (B, L, Hkv, Dv), dtype)
+    dout = jax.random.normal(ks[3], (B, L, H, Dv), dtype)
+    pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+    if ragged:     # the second row's first 37 slots hold no token of its own
+        pos = jnp.maximum(pos - jnp.array([[0], [37]], jnp.int32), 0)
+    sel_t = None
+    if sel:     # half the pairs, and slot 0 always: no query without a key
+        sel_t = (jax.random.uniform(ks[4], (B, L, L)) < 0.5).at[:, 0].set(
+            True).astype(jnp.int8)
+    return q, k, v, dout, pos, sel_t
+
+
+def _one_head_a_step(fn, q, k, v, dout, n_rep):
+    """``fn(q, k, v)`` and its gradients with k and v repeated a query
+    head (``n_rep`` = 1 inside: the kernels' one-head program, which is
+    the parent's), the group's dk and dv summed in float32."""
+    out, vjp = jax.vjp(fn, q, repeat_kv(k, n_rep), repeat_kv(v, n_rep))
+    dq, dk, dv = vjp(dout)
+    B, L, H, _ = dk.shape
+
+    def group_sum(g):
+        return g.astype(jnp.float32).reshape(
+            B, L, H // n_rep, n_rep, -1).sum(axis=3)
+
+    return out, dq, group_sum(dk), group_sum(dv)
+
+
+def _assert_float32_close(got, want, name, steps=4):
+    """Equal to the order of a few float32 sums: ``steps`` ulps of the
+    largest entry (bf16 results: one step of theirs)."""
+    eps = jnp.finfo(got.dtype).eps
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    tol = steps * float(eps) * max(1.0, float(np.abs(want).max()))
+    assert np.abs(got - want).max() <= tol, (name, np.abs(got - want).max(),
+                                             tol)
+
+
+GROUP_CASES = {
+    # name: (n_rep, selection, value width, ragged positions, dtype)
+    "one_head": (1, False, 16, False, jnp.float32),
+    "one_head_selected": (1, True, 16, False, jnp.float32),
+    "pair": (2, False, 16, False, jnp.float32),
+    "pair_selected_ragged": (2, True, 16, True, jnp.float32),
+    "eight": (8, False, 16, False, jnp.float32),
+    "eight_selected": (8, True, 16, False, jnp.float32),
+    "pair_values_wider_than_keys": (2, False, 32, False, jnp.float32),
+    "four_selected_bf16": (4, True, 16, True, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUP_CASES))
+def test_a_group_of_heads_a_step_equals_one_head_a_step(name):
+    """The kernels with the ``n_rep`` query heads of a key head in one
+    grid step against the same kernels with one head a step (k and v
+    repeated: the parent's program).  One head a key head IS that
+    program: bit for bit.  A group's forward sums its extent tile by
+    tile and its dk / dv over the heads inside the kernel: equal to the
+    order of float32 sums, and dk / dv are rounded once."""
+    from orion_tpu.ops.pallas.flash_attention import sparse_attention_gqa
+
+    n_rep, sel, Dv, ragged, dtype = GROUP_CASES[name]
+    q, k, v, dout, pos, sel_t = _group_case(n_rep, sel, Dv, ragged, dtype,
+                                            Hkv=1 if n_rep == 8 else 2)
+
+    def fn(q, k, v):
+        if sel_t is None:
+            return flash_attention_gqa(q, k, v, pos, 0.25, 128, 128)
+        return sparse_attention_gqa(q, k, v, pos, sel_t, 0.25, 128, 128)
+
+    out, vjp = jax.vjp(fn, q, k, v)
+    got = (out,) + vjp(dout)
+    want = _one_head_a_step(fn, q, k, v, dout, n_rep)
+    assert got[2].shape == k.shape and got[3].shape == v.shape
+    assert got[2].dtype == got[3].dtype == dtype
+    for g, w, what in zip(got, want, ("out", "dq", "dk", "dv")):
+        if n_rep == 1:
+            np.testing.assert_array_equal(
+                np.asarray(g, np.float32), np.asarray(w, np.float32), what)
+        else:
+            _assert_float32_close(g, w, what,
+                                  steps=1 if dtype == jnp.bfloat16 else 16)
+    # and the einsum under the same mask, so that both are not wrong alike
+    mask = jnp.arange(q.shape[1])[None, None, :] <= pos[:, :, None]
+    if sel_t is not None:
+        mask = mask & (sel_t.swapaxes(1, 2) != 0)
+    ref = reference_attention_gqa(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep),
+                                  mask, 0.25)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=2e-2 if dtype == jnp.bfloat16 else 2e-5,
+                               atol=2e-2 if dtype == jnp.bfloat16 else 2e-5)
+
+
+CHUNKS = {
+    # name: (q positions, kv positions) of the two tiles of 128 each
+    # a zigzag query chunk over a ROTATED kv chunk: the first q tile's
+    # rows under position 64 see nothing of the first kv tile of their
+    # two-tile extent, all of them something of the second
+    "rotated": ((0, 384), (64, 0)),
+    # the first q tile sees no key at all (both kv tiles skipped: l = 0);
+    # the second's rows under position 300 see none either, INSIDE a
+    # tile that is computed (all of it masked for them: lse = NEG_INF)
+    "future": ((0, 200), (300, 600)),
+}
+
+
+@pytest.mark.parametrize("n_rep", [2, 8])
+@pytest.mark.parametrize("chunk", sorted(CHUNKS))
+def test_a_group_over_a_ring_chunk_equals_one_head_a_step(chunk, n_rep):
+    """The ring entries (``kv_positions`` an operand, no fetch clamp)
+    group the same way, rows without a key included: out = 0 and lse at
+    ``NEG_INF`` where every tile was skipped (the ``l = 0`` guard), the
+    parent's mean of v where a computed tile was all masked, and a
+    backward whose ``exp(s - lse)`` is still 0 on such rows (``_DEAD``:
+    the forward's ``NEG_INF`` fill would make it ``exp(0)``)."""
+    from orion_tpu.ops.pallas import NEG_INF
+    from orion_tpu.ops.pallas.flash_attention import (flash_chunk_fwd,
+                                                      flash_chunk_grads)
+
+    q, k, v, dout, _, _ = _group_case(n_rep, False, Hkv=1, L=256)
+    ar = jnp.arange(128, dtype=jnp.int32)
+    (q0, q1), (k0, k1) = CHUNKS[chunk]
+    qpos = jnp.broadcast_to(jnp.concatenate([q0 + ar, q1 + ar]), (2, 256))
+    kvpos = jnp.broadcast_to(jnp.concatenate([k0 + ar, k1 + ar]), (2, 256))
+
+    def run(q, k, v):
+        out, lse = flash_chunk_fwd(q, k, v, qpos, kvpos, 0.25, 128, 128)
+        return (out, lse) + tuple(flash_chunk_grads(
+            q, k, v, qpos, kvpos, out, lse, dout, 0.25, 128, 128))
+
+    got = run(q, k, v)
+    out, lse, dq, dk, dv = run(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep))
+    want = (out, lse, dq, dk.reshape(2, 256, 1, n_rep, -1).sum(axis=3),
+            dv.reshape(2, 256, 1, n_rep, -1).sum(axis=3))
+    for g, w, what in zip(got, want, ("out", "lse", "dq", "dk", "dv")):
+        assert np.isfinite(np.asarray(g)).all(), what
+        if what == "lse":      # NEG_INF rows: steps of THAT size
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-6, err_msg=what)
+        else:
+            _assert_float32_close(g, w, what, steps=16)
+    if chunk == "future":
+        out, lse, dq = (np.asarray(x) for x in got[:3])
+        assert not out[:, :128].any() and not dq[:, :228].any()
+        assert lse[:, :, :228].max() <= 0.9 * NEG_INF
+        assert lse[:, :, 228:].min() > -1e3
+
+
+def test_one_head_a_key_head_keeps_the_parents_grid_and_blocks():
+    """``n_rep`` = 1 is the program of before the grouping: the grids
+    and every block shape of the three kernels at two of the benchmark's
+    shapes (Pythia-1B's 8 heads of 256 over 384 tokens; the 32 expanded
+    latent heads, keys 192 / values 128, over 1024), written down from
+    the parent commit.  A group's grid is over the KEY heads, its q-side
+    blocks ``n_rep`` heads thick, dk / dv one key head's."""
+    def kernels(B, L, H, Hkv, D, Dv):
+        q = jnp.zeros((B, L, H, D), jnp.bfloat16)
+        k = jnp.zeros((B, L, Hkv, D), jnp.bfloat16)
+        v = jnp.zeros((B, L, Hkv, Dv), jnp.bfloat16)
+        pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+
+        def fwd_bwd(q, k, v):
+            out, vjp = jax.vjp(
+                lambda q, k, v: flash_attention_gqa(q, k, v, pos, 0.1),
+                q, k, v)
+            return vjp(out)
+
+        found = {}
+        for e in _walk_eqns(jax.make_jaxpr(fwd_bwd)(q, k, v).jaxpr):
+            if e.primitive.name == "pallas_call":
+                gm = e.params["grid_mapping"]
+                found[e.params["name"]] = (
+                    tuple(gm.grid),
+                    [tuple(getattr(d, "block_size", d)
+                           for d in bm.block_shape)
+                     for bm in gm.block_mappings])
+        return found
+
+    def parent(B, H, L, D, Dv):
+        pos, q, k, v = (1, 1, L), (1, 1, L, D), (1, 1, L, D), (1, 1, L, Dv)
+        row = (1, 1, 1, L)
+        return {
+            "flash_fwd": ((B, H, 1, 1), [pos, q, k, (1, 1, Dv, L),
+                                         (1, 1, Dv, L), row]),
+            "flash_bwd_dq": ((B, H, 1, 1), [pos, q, k, (1, 1, D, L), v, v,
+                                            row, row, (1, 1, D, L)]),
+            "flash_bwd_dkv": ((B, H, 1, 1), [pos, q, k, v, v, row, row,
+                                             k, v])}
+
+    assert kernels(16, 384, 8, 8, 256, 256) == parent(16, 8, 384, 256, 256)
+    assert kernels(16, 1024, 32, 32, 192, 128) == parent(16, 32, 1024, 192,
+                                                         128)
+    grouped = kernels(2, 2048, 32, 8, 128, 128)
+    assert {name: grid for name, (grid, _) in grouped.items()} == {
+        "flash_fwd": (2, 8, 2, 2), "flash_bwd_dq": (2, 8, 2, 2),
+        "flash_bwd_dkv": (2, 8, 2, 2)}
+    assert grouped["flash_fwd"][1][1] == (1, 4, 1024, 128)        # q
+    assert grouped["flash_bwd_dkv"][1][-2:] == [(1, 1, 1024, 128)] * 2

@@ -342,8 +342,45 @@ def test_the_rollout_span_says_how_the_step_reads_its_cache(
     with open(tmp_path / f"spans-{os.getpid()}.json") as f:
         events = json.load(f)["traceEvents"]
     (span,) = [e["args"] for e in events if e["name"] == "rollout.dispatch"]
+    # beside it, on this span and the update's: the query heads a grid
+    # step of the flash kernels holds (ISSUE 44); the tiny llama and
+    # keye_dsa have 4 on 2 key heads, latent attention one a key head
+    (update,) = [e["args"] for e in events if e["name"] == "update"]
+    heads = 1 if preset == "tiny_deepseek_v3" else 2
+    assert span["attn_heads_a_step"] == update["attn_heads_a_step"] == heads
     if want is None:
         assert "kv_step_form" not in span and "kv_step_slots" not in span
         return
     assert (span["kv_step_form"], span["kv_step_slots"]) == want
     assert "," not in span["kv_step_form"]
+
+
+@pytest.mark.parametrize("preset, overrides, heads", [
+    ("pythia_1b", {}, 1),
+    ("keye_vl2_30b_a3b", {}, 8),
+    # ppo-nemotron-h-tp4-sync: a quarter of 32 query heads on 2 key heads
+    ("nemotron_3_super_120b_a12b", {"head_share": (0, 4)}, 8),
+    ("nemotron_3_super_120b_a12b", {}, 16),
+    ("tiny_nemotron_h", {}, 2),
+    ("tiny_keye_dsa", {}, 2),
+    ("tiny_olmo_hybrid", {}, 1),
+    ("olmo_hybrid_7b", {}, 1),
+    ("kanana_2_30b_a3b", {}, 1),
+    ("kimi_linear_48b_a3b", {}, 1),
+    # latent attention expands a key head a query head; no attention
+    # layer groups in a model of KDA and latent layers
+    ("tiny_deepseek_v3", {}, 1),
+    ("tiny_kimi_linear", {}, 1),
+])
+def test_query_heads_a_grid_step_from_the_configuration(preset, overrides,
+                                                        heads):
+    """``attn_heads_a_step``, the spans' attribute: the query heads that
+    share a key head in the layers that keep a per-head K/V cache, of
+    what ``head_share`` leaves here (the ``n_rep`` the flash kernels
+    read from their operands' shapes); 1 where no layer groups."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+
+    cfg = dataclasses.replace(getattr(ModelConfig, preset)(), **overrides)
+    assert cfg.attn_heads_a_step() == heads
