@@ -17,8 +17,8 @@ from typing import Any, Optional
 # ---------------------------------------------------------------------------
 
 
-#: The archs of the pre-norm RMSNorm block whose attention is latent
-#: (``models.transformer.LatentBlock``).
+#: The archs whose attention is latent
+#: (``models.transformer.LatentAttention``).
 LATENT_ARCHS = ("deepseek_v3", "kimi_linear")
 #: The archs whose model is a per-layer pattern of (mixer, FFN) kinds
 #: over RMSNorm blocks (``ModelConfig.layer_kinds``).
@@ -26,10 +26,6 @@ PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa", "nemotron_h")
 #: The archs whose layers end in the dropless expert layer
 #: (``ops.moe.TopKMoE``) and so share its fields and their checks.
 EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa", "nemotron_h")
-#: The mixers that run the delta rule (``ops/kda.py``).
-DELTA_MIXERS = ("kda", "gdn")
-#: The mixers whose per-sequence state is not indexed by position.
-RECURRENT_MIXERS = DELTA_MIXERS + ("mamba2",)
 #: nemotron_h's ``hybrid_override_pattern`` characters -> (mixer, ffn)
 #: halves of a block.
 PATTERN_HALVES = {"M": ("mamba2", None), "*": ("attention", None),
@@ -40,16 +36,17 @@ LAYER_TYPE_MIXERS = {"linear_attention": "gdn", "full_attention": "attention"}
 
 @dataclass
 class ModelConfig:
-    """Architecture hyperparameters for the decoder-only transformer.
-
-    One configurable implementation covers both model families the spec
-    requires (SURVEY.md §2 #14): ``arch="llama"`` (RMSNorm, SwiGLU, full
-    rotary, GQA — Llama-3-8B) and ``arch="neox"`` (LayerNorm, parallel
-    attention+MLP residual, partial rotary — Pythia-1B).
+    """Architecture hyperparameters for the decoder-only transformer:
+    flat fields under the published key names of seven model families,
+    a ``_check_<arch>`` each, and the model's description derived from
+    them, :meth:`layer_kinds`: one (mixer, feed-forward) pair per block.
+    What follows from a kind is ``models/transformer.py``'s
+    (``MIXERS``); the properties here that speak of kinds read it there.
     """
 
-    # "llama" | "neox" | "deepseek_v3" | "kimi_linear" | "olmo_hybrid"
-    # | "keye_dsa" | "nemotron_h"
+    # the family whose published keys and checks apply: "llama" | "neox"
+    # | "deepseek_v3" | "kimi_linear" | "olmo_hybrid" | "keye_dsa"
+    # | "nemotron_h"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -223,6 +220,14 @@ class ModelConfig:
             # GPT-NeoX has no GQA.  (use_parallel_residual stays as
             # given — NeoX-family checkpoints exist with either value.)
             self.num_kv_heads = self.num_heads
+        if self.attention_impl in ("ring", "ulysses"):
+            from orion_tpu.models.transformer import cannot_run
+
+            why = cannot_run(self, "sequence_parallel")
+            if why:
+                raise ValueError(
+                    f"attention_impl={self.attention_impl!r} cannot run "
+                    f"arch={self.arch!r}: {why}")
 
     def _check_experts(self) -> None:
         for key in ("n_routed_experts", "num_experts_per_tok",
@@ -260,13 +265,6 @@ class ModelConfig:
             self.head_dim = self.qk_rope_head_dim   # as published
         if not 0 <= self.first_k_dense_replace <= self.num_layers:
             raise ValueError("first_k_dense_replace outside 0..num_layers")
-        if self.attention_impl in ("ring", "ulysses"):
-            raise ValueError(
-                f"attention_impl={self.attention_impl!r} cannot run "
-                f"arch={self.arch!r}: the sequence-parallel attentions "
-                "exchange per-head K/V of one head_dim, and there is no "
-                "exchange of the latent (c, k_rope) yet, nor a hand-over "
-                "of a recurrent state between sequence shards")
 
     def _check_keye_dsa(self) -> None:
         for key in ("sa_topk", "sa_index_heads", "sa_index_head_dim",
@@ -276,13 +274,6 @@ class ModelConfig:
         if self.num_heads % self.num_kv_heads:
             raise ValueError("arch='keye_dsa': num_kv_heads divides "
                              "num_heads (grouped-query attention)")
-        if self.attention_impl in ("ring", "ulysses"):
-            raise ValueError(
-                f"attention_impl={self.attention_impl!r} cannot run "
-                "arch='keye_dsa': the sequence-parallel attentions exchange "
-                "keys and values by position and apply the causal rule "
-                "alone; there is no exchange of the indexer's keys nor a "
-                "selection across sequence shards")
         if self.seq_shard_activations or self.first_k_dense_replace:
             raise ValueError(
                 "arch='keye_dsa': every layer is an expert layer "
@@ -320,11 +311,6 @@ class ModelConfig:
                 "part of one would need an exchange), so mamba_n_groups "
                 "divides by it, as do the query heads; key-value heads "
                 "divide by the shares or the shares by them")
-        if self.attention_impl in ("ring", "ulysses"):
-            raise ValueError(
-                f"attention_impl={self.attention_impl!r} cannot run "
-                "arch='nemotron_h': there is no hand-over of a state-space "
-                "layer's state between sequence shards")
         if self.seq_shard_activations or self.first_k_dense_replace:
             raise ValueError(
                 "arch='nemotron_h': the pattern names every layer "
@@ -360,11 +346,6 @@ class ModelConfig:
                 f"them (got {len(self.layer_types)}, unknown: "
                 f"{sorted(unknown)})")
         self.num_kv_heads = self.num_heads
-        if self.attention_impl in ("ring", "ulysses"):
-            raise ValueError(
-                f"attention_impl={self.attention_impl!r} cannot run "
-                "arch='olmo_hybrid': there is no hand-over of a recurrent "
-                "state between sequence shards")
         if (self.num_experts or self.quantize_dense
                 or self.tie_word_embeddings or self.seq_shard_activations):
             raise ValueError(
@@ -375,9 +356,29 @@ class ModelConfig:
 
     @property
     def latent_attention(self) -> bool:
-        """The pre-norm RMSNorm block whose attention is latent
-        (deepseek_v3, kimi_linear)."""
+        """The attention is latent (deepseek_v3, kimi_linear)."""
         return self.arch in LATENT_ARCHS
+
+    @property
+    def rms_norm(self) -> bool:
+        """RMSNorm (neox: LayerNorm with a bias)."""
+        return self.arch == "llama" or self.pattern
+
+    @property
+    def gated_mlp(self) -> bool:
+        """The dense MLP is a SwiGLU (neox: GELU, no gate)."""
+        return self.arch == "llama" or self.pattern
+
+    @property
+    def post_norm(self) -> bool:
+        """A block norms each half's OUTPUT and nothing before it (the
+        OLMo 2 / 3 order)."""
+        return self.arch == "olmo_hybrid"
+
+    def _kinds(self) -> tuple:
+        from orion_tpu.models.transformer import kinds
+
+        return kinds(self)
 
     @property
     def pattern(self) -> bool:
@@ -399,10 +400,8 @@ class ModelConfig:
         """The query heads one grid step of the flash kernels holds
         (ops/pallas/flash_attention.py: the heads that share a key
         head, of what ``head_share`` leaves here); 1 where no layer
-        keeps a per-head K/V cache ("attention", "sparse": latent
-        attention expands one key head a query head)."""
-        if not any(m in ("attention", "sparse")
-                   for m, _ in self.layer_kinds()):
+        keeps a per-head K/V cache (``Kind.per_head_kv``)."""
+        if not any(kind.per_head_kv for kind in self._kinds()):
             return 1
         held = self.heads_held()
         return held["q"] // held["kv"]
@@ -411,22 +410,16 @@ class ModelConfig:
         """(dk, dv) of the delta-rule layers' heads: what
         ``ops.kda.chunk_form`` is asked with; None without such a
         layer."""
-        if not any(m in DELTA_MIXERS for m, _ in self.layer_kinds()):
-            return None
-        if self.arch == "olmo_hybrid":
-            return self.linear_key_head_dim, self.linear_value_head_dim
-        return self.kda_head_dim, self.kda_head_dim
+        return next((kind.head_dims(self) for kind in self._kinds()
+                     if hasattr(kind, "head_dims")), None)
 
     def layer_kinds(self) -> tuple:
         """((mixer, ffn), ...) per block: the model's description.
-        mixer: "attention" (per-head K/V cache), "sparse" (the same
-        beside the indexer's keys: {k, v, ki}), "latent" ({c, k_rope}
-        cache), "kda" or "gdn" (the delta rule: a recurrent state, no
-        position; a decay a key channel or one a head), "mamba2" (a
-        state-space layer: {S, conv}, no position) or None (no mixer
-        half: the block caches {}); ffn: "dense" (a SwiGLU or GELU MLP),
-        "gshard" (num_experts), "experts" (the dropless layer, its
-        activation ``moe_activation``) or None (no feed-forward half).
+        mixer: a key of ``models.transformer.MIXERS`` ("attention",
+        "sparse", "latent", "kda", "gdn", "mamba2": what each is and
+        caches is stated there) or None (no mixer half); ffn: "dense" (a
+        SwiGLU or GELU MLP), "gshard" (num_experts), "experts" (the
+        dropless layer) or None (no feed-forward half).
         Every model but nemotron_h has both halves in every block, one
         block a published layer; nemotron_h's pattern names halves, and
         a mixer with an "E" behind it make one block ("M*" leaves the
@@ -476,14 +469,13 @@ class ModelConfig:
         (the dropless expert layer routes it nowhere, the recurrent
         mixer leaves its state untouched): callers then pass
         ``token_mask``."""
-        return any(m in RECURRENT_MIXERS or f == "experts"
-                   for m, f in self.layer_kinds())
+        return any(kind.takes_token_mask for kind in self._kinds())
 
     @property
     def recurrent(self) -> bool:
         """Whether some layer's per-sequence state is not indexed by
         position."""
-        return any(m in RECURRENT_MIXERS for m, _ in self.layer_kinds())
+        return any(kind.cache_kind == "state" for kind in self._kinds())
 
     @staticmethod
     def llama3_8b() -> "ModelConfig":

@@ -1,40 +1,23 @@
 """The decoder-only transformer (policy / reference / critic / RM backbone).
 
-One configurable implementation covers the two model families the spec
-requires (SURVEY.md §2 #14):
-
-- ``arch="llama"``: RMSNorm, SwiGLU MLP, full rotary, optional GQA
-  (Llama-3 family).
-- ``arch="neox"``: LayerNorm with bias, parallel attention+MLP residual,
-  partial rotary (``rotary_pct``), biased projections (Pythia family).
-- ``arch="deepseek_v3"``: pre-norm RMSNorm, latent attention
-  (:class:`LatentAttention`), a SwiGLU MLP in the first
-  ``first_k_dense_replace`` layers and the dropless expert layer
-  (``ops.moe.TopKMoE``) after them; no biases, untied head.
-  Under ``scan_layers`` the leading dense layers stay outside the
-  scanned stack of expert layers.
-- ``arch="kimi_linear"``: the same block with a mixer per layer
-  (``ModelConfig.layer_kinds``): :class:`KimiDeltaAttention` (a
-  recurrent state, no position) or :class:`LatentAttention` without
-  rotation.  Under ``scan_layers`` each stretch of equal consecutive
-  kinds is one scanned stack (``ModelConfig.layer_runs``).
-- ``arch="olmo_hybrid"``: a post-norm RMSNorm block
-  (:class:`PostNormBlock`) over a dense SwiGLU, its mixer per layer by
-  the published ``layer_types``: :class:`GatedDeltaNet` (the delta rule
-  with one decay a head, heads of unequal key and value size) or
-  :class:`Attention` with a norm over the whole query and key
-  projections and no rotation.  Every stretch is a scanned stack.
-- ``arch="keye_dsa"``: the pre-norm block of deepseek_v3 with
-  :class:`SparseAttention` as its mixer (grouped-query attention with a
-  norm over each head of q and k, cut to the ``sa_topk`` keys a query
-  that a learned indexer selects: ``ops/indexer.py``) over the same
-  expert layer with a softmax router; every layer alike, one stack.
-- ``arch="nemotron_h"``: the same pre-norm block with the halves that
-  ``hybrid_override_pattern`` names (``ModelConfig.layer_kinds``):
-  :class:`Mamba2` (a state-space layer: ``ops/mamba2.py``) or
-  :class:`Attention` as its mixer, the expert layer with ``relu(.)^2``
-  experts in a latent behind it, or one half alone; a chip may hold a
-  share of every mixer's heads (``ModelConfig.head_share``).
+A model is what ``ModelConfig.layer_kinds()`` says: one ``(mixer, ffn)``
+pair per block, every block a :class:`Block`.  What follows from a kind
+is stated ONCE, by the module that implements it (:class:`Kind`), and
+read off :data:`MIXERS` and :func:`ffn_class` by everything else: a
+mixer's cache entry and whether it is indexed by position, a kind's
+share of :func:`remat_tag_bytes`, whether it takes ``token_mask``, what
+it reports on the trainer's spans (:func:`decode_attrs`,
+:func:`update_attrs`), which parameters RL holds fixed and the forms it
+cannot run (:func:`cannot_run`).  The mixers: :class:`Attention`,
+:class:`SparseAttention`, :class:`LatentAttention`,
+:class:`KimiDeltaAttention`, :class:`GatedDeltaNet`, :class:`Mamba2`;
+the feed-forward halves: :class:`MLP`, ``ops.moe.MoEMLP`` (GShard,
+``num_experts``), ``ops.moe.TopKMoE`` (the dropless expert layer).
+:func:`mixer_spec` is the one place where an arch picks anything; where
+the norms sit follows from the configuration (:class:`Block`).  Under
+``scan_layers`` each stretch of equal consecutive kinds is one scanned
+stack (``ModelConfig.layer_runs``); a latent-attention model's leading
+dense layers stay outside the stacks.
 
 Design notes (TPU-first):
 - Params are annotated with *logical* axes via flax logical
@@ -66,30 +49,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from orion_tpu.config import RECURRENT_MIXERS, ModelConfig
+from orion_tpu.config import ModelConfig
 from orion_tpu.ops.attention import _NEG_INF, attention, step_attention
 from orion_tpu.ops.paged_kv import is_paged, write_paged_tokens
 from orion_tpu.ops.rotary import apply_rotary
 
-# Unrolled models: per-layer list of {"k": [B,L,Hkv,D], "v": ...}.
-# scan_layers models: ONE stacked dict {"k": [N,B,L,Hkv,D], "v": ...}
-# scanned over axis 0 (likewise for the paged-cache pytrees).
-# deepseek_v3: a layer caches {"c": [B,L,kv_lora_rank], "k_rope":
-# [B,L,qk_rope_head_dim]}; scan_layers models {"dense": [per layer],
-# "layers": stacked} (the leading dense layers are not in the stack).
-# kimi_linear: a KDA layer caches {"S": f32 [B,H,dk,dv], "conv":
-# [B,taps-1,3*H*dk]}, nothing indexed by position; scan_layers models
-# {"dense": [per layer], "runs": [stacked, one per ModelConfig.
-# layer_runs stretch]}.
-# olmo_hybrid: a GDN layer caches {"S": f32 [B,H,dk,dv], "conv":
-# [B,taps-1,H*(2*dk+dv)]}, a full-attention layer {"k","v"} as llama's;
-# scan_layers models {"dense": [], "runs": [stacked, ...]}.
-# keye_dsa: a layer caches {"k","v"} as llama's and {"ki": [B,L,
-# sa_index_head_dim]}, the indexer's one key head; layouts as llama's.
-# nemotron_h: a Mamba-2 block caches {"S": f32 [B,H,P,N], "conv":
-# [B,taps-1,H*P+2*G*N]} (H, G the heads and groups held), an attention
-# block {"k","v"} of the key-value heads held, a block without a mixer
-# {}; scan_layers models {"dense": [], "runs": [stacked, ...]}.
+# Unrolled models: a per-layer list of cache entries, each what its
+# layer's mixer states (``<Mixer>.cache_entry``; {} for a block without
+# a mixer).  scan_layers models: ONE stacked entry scanned over axis 0
+# where the model is one stack and nothing beside it (likewise for the
+# paged-cache pytrees); {"dense": [per layer], "layers": stacked} where
+# leading dense layers stand beside one stack; {"dense": [...], "runs":
+# [stacked, one per ModelConfig.layer_runs stretch]} for several.
 KVCache = Any
 
 _dt = lambda s: jnp.dtype(s)  # noqa: E731
@@ -109,84 +80,70 @@ _dt = lambda s: jnp.dtype(s)  # noqa: E731
 REMAT_TAGS = ("moe_route", "attn_resid", "mlp_pre", "attn_out", "attn_qkv")
 
 
+class Kind:
+    """What follows from a layer's kind, stated by the module that
+    implements it (a mixer of :data:`MIXERS`, a feed-forward class of
+    :func:`ffn_class`) and read by everything else.  The defaults: a
+    kind that keeps nothing, reports nothing and runs everywhere."""
+
+    #: a mixer's cache entry is indexed by position ("cache") or is a
+    #: per-sequence "state", read and written whole a step
+    cache_kind = "cache"
+    index_leaves = ()          # the entry's leaves that are an indexer's keys
+    takes_token_mask = False   # __call__ takes it behind its other arguments
+    per_head_kv = False        # a K/V cache per key head (attn_heads_a_step)
+    steps_over_prefix = False  # the one-token step goes through prefix_step
+    rl_fixed = ()              # prefixes of the parameter names RL holds fixed
+
+    @staticmethod
+    def lacks(cfg) -> dict:
+        """{form: why} for the forms it has none of: RolloutConfig's
+        ``paged``, ``quantize_kv``, ``quantize_weights``; ``continuous``
+        (that engine); ``sequence_parallel`` (ring / ulysses)."""
+        return {}
+
+    @staticmethod
+    def tag_bytes(cfg, rows: int, seq_len: int, w) -> dict:
+        """{tag of :data:`REMAT_TAGS`: bytes one layer holds under it}
+        for ``rows`` sequences of ``seq_len``; ``w`` pads a last
+        dimension to where it is held."""
+        return {}
+
+    @staticmethod
+    def decode_attrs(cfg, lens, slots: int, new_tokens: int) -> dict:
+        """Its part of :func:`decode_attrs`: host numbers."""
+        return {}
+
+    @staticmethod
+    def forward_attrs(cfg, total_lens) -> dict:
+        """Its part of :func:`update_attrs`: host numbers."""
+        return {}
+
+
 def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
                     lane: int = 1):
     """((tag, bytes), ...) in :data:`REMAT_TAGS` order, for the tags
     this model's blocks have: what keeping a tag holds over all layers
     for one minibatch of ``rows`` sequences of ``seq_len``, from the
-    shapes alone.  ``lane``: the multiple a tensor's last dimension is
-    padded to where it is held (128 on a TPU; 1 counts the elements).
-    The attention tags are counted as the flash kernel leaves them: an
-    implementation without tags keeps nothing under those names and is
-    over-reckoned.  A KDA or GDN layer tags its projections
-    (``attn_qkv``) and its recurrence's output (``attn_out``) under the
-    same names."""
+    shapes alone (each kind's ``tag_bytes``).  ``lane``: the multiple a
+    tensor's last dimension is padded to where it is held (128 on a
+    TPU; 1 counts the elements).  The attention tags are counted as the
+    flash kernel leaves them: an implementation without tags keeps
+    nothing under those names and is over-reckoned."""
     def w(d):
         return -(-d // lane) * lane
 
-    n = rows * seq_len
-    act = _dt(cfg.dtype).itemsize
-    held = cfg.heads_held()
-    H = held["q"]
-    route = resid = mlp = out = qkv = 0
+    total = dict.fromkeys(REMAT_TAGS, 0)
+    resid = rows * seq_len * w(cfg.hidden_size) * _dt(cfg.dtype).itemsize
     for mixer, ffn in cfg.layer_kinds():
-        if mixer is None:
-            pass
-        elif mixer == "mamba2":
-            # the input projection whole, and the recurrence's output in
-            # float32 (ops/mamba2.py)
-            Hm, d_in = held["mamba"], held["mamba"] * cfg.mamba_head_dim
-            qkv += n * w(2 * d_in + 2 * held["groups"] * cfg.ssm_state_size
-                         + Hm) * act
-            out += n * w(d_in) * 4
-        elif mixer == "kda":
-            # the three projections as they enter the convolution, and
-            # the recurrence's output in float32 (ops/kda.py)
-            wide = cfg.kda_num_heads * cfg.kda_head_dim
-            qkv += n * 3 * w(wide) * act
-            out += n * w(wide) * 4
-        elif mixer == "gdn":
-            Hl = cfg.linear_num_key_heads
-            qkv += n * w(Hl * (2 * cfg.linear_key_head_dim
-                               + cfg.linear_value_head_dim)) * act
-            out += n * w(Hl * cfg.linear_value_head_dim) * 4
-        else:
-            if mixer == "latent":
-                per_tok = H * (2 * w(cfg.qk_nope_head_dim
-                                     + cfg.qk_rope_head_dim)
-                               + w(cfg.v_head_dim))
-                per_out = H * w(cfg.v_head_dim)
-            else:
-                per_tok = (H + 2 * held["kv"]) * w(cfg.head_dim)
-                per_out = H * w(cfg.head_dim)
-            qkv += n * per_tok * act
-            # out_t, and lse [rows, H, 1, seq_len] in float32
-            out += n * per_out * act + rows * H * w(seq_len) * 4
-        if ffn == "experts":
-            from orion_tpu.ops import moe
-
-            mlp += (2 if cfg.moe_activation == "swiglu" else 1) * w(
-                moe.shared_width(cfg))
-            # scores [n, E] float32 and the selection [n, k] (the gather
-            # of the selected scores keeps its own indices); the dense
-            # form reads the selection again for its weights, the
-            # grouped form's backward reads order [n k, in whole blocks]
-            # and sizes [held + 1] instead
-            k = cfg.num_experts_per_tok
-            r = n * (w(cfg.n_routed_experts) + w(k))
-            block = moe.block_rows(cfg, n)
-            if block:
-                r += w(-(-n * k // block) * block) + w(cfg.experts_held + 1)
-            else:
-                r += n * w(k)
-            route += 4 * r
-        elif ffn == "dense":
-            mlp += (1 if cfg.arch == "neox" else 2) * w(
-                cfg.intermediate_size)
+        parts = [cls.tag_bytes(cfg, rows, seq_len, w) for cls in
+                 (mixer and MIXERS[mixer], ffn and ffn_class(ffn)) if cls]
         if not cfg.use_parallel_residual and mixer and ffn:
-            resid += w(cfg.hidden_size)
-    sizes = (route, n * resid * act, n * mlp * act, out, qkv)
-    return tuple((t, b) for t, b in zip(REMAT_TAGS, sizes) if b)
+            parts.append({"attn_resid": resid})
+        for part in parts:
+            for tag, size in part.items():
+                total[tag] += size
+    return tuple((t, b) for t, b in total.items() if b)
 
 
 def remat_keep(names_with_bytes, budget_bytes: Optional[int]):
@@ -262,7 +219,7 @@ def _dense(features, axes, use_bias, cfg, name):
 
 
 def _norm(cfg, name):
-    if cfg.arch == "llama" or cfg.pattern:
+    if cfg.rms_norm:
         return nn.RMSNorm(
             epsilon=cfg.rms_norm_eps, dtype=_dt(cfg.dtype),
             param_dtype=_dt(cfg.param_dtype),
@@ -311,9 +268,6 @@ def _cache_writer(positions, B: int, L: int):
 # blocks of at least _PREFIX_SLOTS slots, at most _PREFIX_COUNT prefixes.
 _PREFIX_SLOTS = 128
 _PREFIX_COUNT = 8
-#: the mixers (``ModelConfig.layer_kinds``) whose one-token step against
-#: a dense slot cache goes through :func:`prefix_step`
-PREFIX_STEP_MIXERS = ("attention", "latent")
 
 
 def prefix_lengths(Lmax: int) -> list:
@@ -353,7 +307,15 @@ def prefix_step_slots(lens, Lmax: int, new_tokens: int) -> float:
     return float(ms[np.minimum(at // ms[0], len(ms) - 1)].mean())
 
 
-class Attention(nn.Module):
+def _flash_tag_bytes(cfg, rows, seq_len, w, heads, per_tok, per_out) -> dict:
+    """What the flash kernel takes (``per_tok`` elements a token) and
+    gives (``per_out``, and lse [rows, heads, 1, seq_len] in float32)."""
+    n, act = rows * seq_len, _dt(cfg.dtype).itemsize
+    return {"attn_qkv": n * per_tok * act,
+            "attn_out": n * per_out * act + rows * heads * w(seq_len) * 4}
+
+
+class Attention(nn.Module, Kind):
     """``qk_norm``: true or ``"whole"``, one norm over the whole query
     and key projections, before the split into heads (olmo_hybrid's);
     ``"head"``, one norm over each head's ``head_dim`` of q and of k, a
@@ -367,6 +329,30 @@ class Attention(nn.Module):
     cfg: ModelConfig
     qk_norm: Any = False
     rotary: bool = True
+
+    per_head_kv = True
+    steps_over_prefix = True
+
+    @staticmethod
+    def cache_entry(cfg, batch, slots, dtype, pre=(), quantized=False):
+        """{"k", "v"} [B, slots, Hkv, D] of the key-value heads held;
+        ``quantized``: int8 values beside per-token-per-head float32
+        scales (RolloutConfig.quantize_kv, ops/quant.py)."""
+        shape = pre + (batch, slots, cfg.heads_held()["kv"], cfg.head_dim)
+        entry = {n: jnp.zeros(shape, jnp.int8 if quantized else dtype)
+                 for n in "kv"}
+        if quantized:
+            entry.update({n + "_scale": jnp.zeros(shape[:-1], jnp.float32)
+                          for n in "kv"})
+        return entry
+
+    @staticmethod
+    def tag_bytes(cfg, rows, seq_len, w):
+        held = cfg.heads_held()
+        return _flash_tag_bytes(
+            cfg, rows, seq_len, w, held["q"],
+            (held["q"] + 2 * held["kv"]) * w(cfg.head_dim),
+            held["q"] * w(cfg.head_dim))
 
     def qkv(self, x, positions):
         """The projections, normed and rotated as configured: q [B, L,
@@ -540,6 +526,75 @@ class SparseAttention(Attention):
     and the attention is :class:`Attention`'s, exactly.
     """
 
+    index_leaves = ("ki",)
+    steps_over_prefix = False
+    rl_fixed = ("index_",)
+
+    @staticmethod
+    def cache_entry(cfg, batch, slots, dtype, pre=()):
+        """:class:`Attention`'s beside {"ki": [B, slots,
+        sa_index_head_dim]}, the indexer's one key head."""
+        return {**Attention.cache_entry(cfg, batch, slots, dtype, pre),
+                "ki": jnp.zeros(
+                    pre + (batch, slots, cfg.sa_index_head_dim), dtype)}
+
+    @staticmethod
+    def lacks(cfg):
+        pages = ("there is no selection inside paged attention nor a page "
+                 "pool for the indexer's keys (ops/paged_kv.py)")
+        return {
+            "paged": pages, "continuous": pages,
+            "quantize_kv": "there is no int8 cache under a selection (the "
+            "selected step reads bf16 keys and values where they lie, and "
+            "none was run against the reference)",
+            "quantize_weights": "an int8 indexer would select other keys "
+            "than the update's",
+            "sequence_parallel": "the sequence-parallel attentions exchange "
+            "keys and values by position and apply the causal rule alone; "
+            "there is no exchange of the indexer's keys nor a selection "
+            "across sequence shards"}
+
+    @staticmethod
+    def key_counts(lens, topk: int) -> dict:
+        """The valid and the selected keys summed over the real queries
+        of sequences of ``lens`` real tokens: query t (0-based) has t +
+        1 and keeps ``min(topk, t + 1)``."""
+        n = np.asarray(lens, np.int64)
+        m = np.minimum(n, topk)
+        return {"sa_topk": topk,
+                "sa_keys_valid": int((n * (n + 1) // 2).sum()),
+                "sa_keys_selected": int(
+                    (m * (m + 1) // 2 + (n - m) * topk).sum())}
+
+    @staticmethod
+    def step_read(cfg, lens, slots, new_tokens) -> dict:
+        """{sparse_step, sa_step_bytes}: the form the selected one-token
+        step takes against a cache of ``slots`` slots in this process's
+        traces (``ops/pallas/sparse_step.py::step_form``: ``kernel`` /
+        ``masked``; not under an ``sa_`` name: the benchmark's reader of
+        the span, ``roofline_keye_dsa.py::span_counts``, takes every
+        ``sa_*`` attribute for a number) and the bytes of k and v one
+        step then reads a layer, the mean over the ``new_tokens`` steps
+        of prompts of ``lens`` real tokens (``step_slots``)."""
+        from orion_tpu.ops.pallas import sparse_step
+
+        form = sparse_step.step_form(slots)
+        read = sparse_step.step_slots(form, lens, slots, new_tokens)
+        row = cfg.num_kv_heads * cfg.head_dim * _dt(cfg.dtype).itemsize
+        return {"sparse_step": form, "sa_step_bytes": int(2 * read * row)}
+
+    @staticmethod
+    def decode_attrs(cfg, lens, slots, new_tokens):
+        """The prefill's key counts and how the steps read k and v."""
+        return {**SparseAttention.key_counts(lens, cfg.sa_topk),
+                **SparseAttention.step_read(cfg, lens, slots, new_tokens)}
+
+    @staticmethod
+    def forward_attrs(cfg, total_lens):
+        """Of ONE whole-sequence forward of the batch (the experience
+        forwards and the update's each make it)."""
+        return SparseAttention.key_counts(total_lens, cfg.sa_topk)
+
     @nn.compact
     def __call__(self, x, positions, layer_cache=None):
         from orion_tpu.ops import indexer
@@ -635,7 +690,7 @@ def _absorbed_step(q_lat, q_rope, c, k_rope, mask, scale: float):
     return jnp.einsum("bhl,blr->bhr", probs, c)
 
 
-class LatentAttention(nn.Module):
+class LatentAttention(nn.Module, Kind):
     """Multi-head latent attention (deepseek_v3, ``q_lora_rank: null``).
 
     ``q = h W_q`` per head is ``[q_nope ; q_rope]``; ``h W_kva`` is
@@ -663,6 +718,37 @@ class LatentAttention(nn.Module):
 
     cfg: ModelConfig
 
+    steps_over_prefix = True
+
+    @staticmethod
+    def cache_entry(cfg, batch, slots, dtype, pre=()):
+        return {n: jnp.zeros(pre + (batch, slots, width), dtype)
+                for n, width in (("c", cfg.kv_lora_rank),
+                                 ("k_rope", cfg.qk_rope_head_dim))}
+
+    @staticmethod
+    def tag_bytes(cfg, rows, seq_len, w):
+        H = cfg.heads_held()["q"]
+        return _flash_tag_bytes(
+            cfg, rows, seq_len, w, H,
+            H * (2 * w(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+                 + w(cfg.v_head_dim)), H * w(cfg.v_head_dim))
+
+    @staticmethod
+    def lacks(cfg):
+        return {
+            "paged": "there is no latent paged cache (ops/paged_kv.py and "
+            "the Pallas paged-decode kernel hold per-head K/V pages)",
+            "continuous": "a latent paged cache (c, k_rope) and a kernel "
+            "that attends over it are not written yet",
+            "quantize_kv": "there is no int8 latent cache (ops/quant.py "
+            "scales per head)",
+            "quantize_weights": "there is no absorbed int8 kv_b_proj "
+            "(ops/quant.py quantises Dense kernels)",
+            "sequence_parallel": "the sequence-parallel attentions exchange "
+            "per-head K/V of one head_dim, and there is no exchange of the "
+            "latent (c, k_rope) yet"}
+
     @nn.compact
     def __call__(self, x, positions, layer_cache=None):
         cfg = self.cfg
@@ -681,12 +767,7 @@ class LatentAttention(nn.Module):
                    "q_proj")(x).reshape(B, L, H, dn + dr)
         kva = _dense(R + dr, ("embed", "latent"), False, cfg,
                      "kv_a_proj_with_mqa")(x)
-        c = nn.RMSNorm(
-            epsilon=cfg.rms_norm_eps, dtype=_dt(cfg.dtype),
-            param_dtype=_dt(cfg.param_dtype),
-            scale_init=nn.with_logical_partitioning(
-                nn.initializers.ones_init(), ("norm",)),
-            name="kv_a_norm")(kva[..., :R])
+        c = _norm(cfg, "kv_a_norm")(kva[..., :R])
         # a bare kernel, not a Dense: the absorbed path applies it to
         # the query and the output, split per head
         w_kvb = self.param(
@@ -793,6 +874,80 @@ def _delta_rule(scope, q, k, v, g, beta, layer_cache, ext, token_mask):
                                               ext.shape[1] - L + 1)}
 
 
+class StateKind(Kind):
+    """A mixer whose cache entry is a state: {"S": float32 [B, *state],
+    "conv": [B, taps - 1, conv]}, the recurrence's state and its
+    convolutions' last inputs after the last token a row holds.
+    ``sizes(cfg)`` -> (state, taps, conv, projs, out): those, the widths
+    of the input projections it tags ``attn_qkv`` and the width of the
+    recurrence's float32 output it tags ``attn_out``."""
+
+    cache_kind = "state"
+    takes_token_mask = True
+    # what differs in lacks()
+    whose, handed = "a delta-rule layer's", "a recurrent state"
+    no_int8 = ("the int8 Dense twins do not reach this block (no "
+               "QuantDense decode twin was run against its reference)")
+
+    @classmethod
+    def cache_entry(cls, cfg, batch, slots, dtype, pre=()):
+        state, taps, conv, _, _ = cls.sizes(cfg)
+        return {"S": jnp.zeros(pre + (batch,) + state, jnp.float32),
+                "conv": jnp.zeros(pre + (batch, taps - 1, conv), dtype)}
+
+    @classmethod
+    def check_entry(cls, layer_cache):
+        if layer_cache is not None and "S" not in layer_cache:
+            raise ValueError(
+                f"{cls.__name__} caches {{'S', 'conv'}} (init_cache): a "
+                "state, not keys and values by position")
+
+    @classmethod
+    def tag_bytes(cls, cfg, rows, seq_len, w):
+        *_, projs, out = cls.sizes(cfg)
+        n = rows * seq_len
+        return {"attn_qkv": n * sum(map(w, projs)) * _dt(cfg.dtype).itemsize,
+                "attn_out": n * w(out) * 4}
+
+    @classmethod
+    def lacks(cls, cfg):
+        return {
+            "paged": "a recurrent state is not made of pages "
+            "(init_paged_cache gives every layer pages)",
+            "continuous": "its cache manager holds no recurrent state per "
+            "slot (admission, preemption and prefix reuse move pages, and "
+            f"a state is not made of pages: {cls.whose} {{S, conv}})",
+            "quantize_kv": "a recurrent state has no int8 form "
+            "(ops/quant.py scales keys and values per head; an int8 form "
+            "of a float32 recurrent state is not written)",
+            "quantize_weights": cls.no_int8,
+            "sequence_parallel": f"there is no hand-over of {cls.handed} "
+            "between sequence shards"}
+
+
+class DeltaKind(StateKind):
+    """A mixer that runs the delta rule (``ops/kda.py``): its span
+    attributes are the forms the one-token step and the chunked rule
+    take in this process's traces (``kernel`` / ``jnp``)."""
+
+    @classmethod
+    def head_dims(cls, cfg):
+        """(dk, dv) of a head's state."""
+        return cls.sizes(cfg)[0][1:]
+
+    @classmethod
+    def decode_attrs(cls, cfg, lens, slots, new_tokens):
+        from orion_tpu.ops.kda import step_form
+
+        return {"kda_step": step_form(*cls.head_dims(cfg))}
+
+    @classmethod
+    def forward_attrs(cls, cfg, total_lens):
+        from orion_tpu.ops.kda import chunk_form
+
+        return {"kda_chunk": chunk_form(*cls.head_dims(cfg))}
+
+
 def _delta_conv_init(*a):
     """U(-0.5, 0.5): torch's Conv1d default for a depthwise kernel of 4
     taps."""
@@ -810,7 +965,7 @@ def _delta_dt_bias_init(key, shape, dtype):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-class KimiDeltaAttention(nn.Module):
+class KimiDeltaAttention(nn.Module, DeltaKind):
     """Kimi Delta Attention: the delta rule with a per-channel decay
     (``ops/kda.py``), ``kda_num_heads`` heads of ``kda_head_dim``.
 
@@ -822,9 +977,7 @@ class KimiDeltaAttention(nn.Module):
     both float32; output ``W_o concat_h(RMSNorm(o) * sigmoid(x W_ga
     W_gb))``.  No position enters it.
 
-    The cache is ``{"S": [B, H, d, d] float32, "conv": [B, taps - 1,
-    3 H d]}``: the state and the last inputs of the convolutions, after
-    the last token a row holds.  One new token against a cache takes
+    One new token against a cache (:class:`StateKind`) takes
     :func:`ops.kda.kda_step`; everything else the chunked form.
 
     ``token_mask`` [B, L]: a position that holds no token leaves the
@@ -837,6 +990,12 @@ class KimiDeltaAttention(nn.Module):
 
     cfg: ModelConfig
 
+    @staticmethod
+    def sizes(cfg):
+        H, d = cfg.kda_num_heads, cfg.kda_head_dim
+        return ((H, d, d), cfg.short_conv_kernel_size, 3 * H * d,
+                (H * d,) * 3, H * d)
+
     @nn.compact
     def __call__(self, x, positions, layer_cache=None, token_mask=None):
         cfg = self.cfg
@@ -845,10 +1004,7 @@ class KimiDeltaAttention(nn.Module):
                       cfg.short_conv_kernel_size)
         wide = H * d
         f32, pdt = jnp.float32, _dt(cfg.param_dtype)
-        if layer_cache is not None and "S" not in layer_cache:
-            raise ValueError(
-                "a KDA layer caches {'S', 'conv'} (init_cache): a state, "
-                "not keys and values by position")
+        self.check_entry(layer_cache)
 
         def param(name, init, shape, axes, dtype=pdt):
             return self.param(
@@ -921,7 +1077,7 @@ class KimiDeltaAttention(nn.Module):
                       "o_proj")(norm_and_gate(o, gate, o_norm)), new_cache
 
 
-class GatedDeltaNet(nn.Module):
+class GatedDeltaNet(nn.Module, DeltaKind):
     """Gated DeltaNet under Olmo-Hybrid's ``linear_*`` keys: the delta
     rule of ``ops/kda.py`` with ONE decay a head, ``linear_num_key_heads``
     heads whose state is ``linear_key_head_dim`` x
@@ -937,11 +1093,17 @@ class GatedDeltaNet(nn.Module):
     * silu(x W_z))``.  No position enters it.
 
     Cache, ``token_mask`` and the two forms of the rule as
-    :class:`KimiDeltaAttention`: ``{"S": [B, H, dk, dv] float32, "conv":
-    [B, taps - 1, H (2 dk + dv)]}``.
+    :class:`KimiDeltaAttention`.
     """
 
     cfg: ModelConfig
+
+    @staticmethod
+    def sizes(cfg):
+        H, dk, dv = (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
+                     cfg.linear_value_head_dim)
+        wide = H * (2 * dk + dv)
+        return (H, dk, dv), cfg.linear_conv_kernel_dim, wide, (wide,), H * dv
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None, token_mask=None):
@@ -954,10 +1116,7 @@ class GatedDeltaNet(nn.Module):
         widths = {"q": H * dk, "k": H * dk,
                   "v": cfg.linear_num_value_heads * dv}
         f32, pdt, cdt = jnp.float32, _dt(cfg.param_dtype), _dt(cfg.dtype)
-        if layer_cache is not None and "S" not in layer_cache:
-            raise ValueError(
-                "a GDN layer caches {'S', 'conv'} (init_cache): a state, "
-                "not keys and values by position")
+        self.check_entry(layer_cache)
 
         def param(name, init, shape, axes, dtype=pdt):
             return self.param(
@@ -1027,7 +1186,7 @@ class GatedDeltaNet(nn.Module):
                       "o_proj")(norm_and_gate(o, z, o_norm)), new_cache
 
 
-class Mamba2(nn.Module):
+class Mamba2(nn.Module, StateKind):
     """A Mamba-2 mixer (nemotron_h's ``M``): the state-space recurrence
     of ``ops/mamba2.py`` on the H heads of ``mamba_head_dim`` P and the G
     groups that ``cfg.heads_held()`` leaves here, state ``ssm_state_size``
@@ -1043,9 +1202,7 @@ class Mamba2(nn.Module):
     group's ``(H / G) P`` channels with a learned weight.  No position
     enters it.
 
-    The cache is ``{"S": [B, H, P, N] float32, "conv": [B, taps - 1,
-    H P + 2 G N]}``: the state and the convolution's last inputs, after
-    the last token a row holds.  One new token against a cache takes
+    One new token against a cache (:class:`StateKind`) takes
     :func:`ops.mamba2.mamba2_step`; everything else the chunked form.
     ``token_mask`` as :class:`KimiDeltaAttention`'s: a position that
     holds no token has ``dt = 0`` (decay 1, no input) and the mask must
@@ -1053,6 +1210,19 @@ class Mamba2(nn.Module):
     """
 
     cfg: ModelConfig
+
+    whose, handed = "a state-space layer's", "a state-space layer's state"
+    no_int8 = ("ops/quant.py was not run on a state-space layer's one "
+               "input projection")
+
+    @staticmethod
+    def sizes(cfg):
+        held = cfg.heads_held()
+        H, P, N = held["mamba"], cfg.mamba_head_dim, cfg.ssm_state_size
+        wide = H * P + 2 * held["groups"] * N
+        # the input projection whole: [z | xBC | dt]
+        return ((H, P, N), cfg.mamba_conv_kernel, wide,
+                (H * P + wide + H,), H * P)
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None, token_mask=None):
@@ -1066,10 +1236,7 @@ class Mamba2(nn.Module):
                       cfg.mamba_conv_kernel)
         d_in, wide = H * P, H * P + 2 * G * N
         f32, pdt, cdt = jnp.float32, _dt(cfg.param_dtype), _dt(cfg.dtype)
-        if layer_cache is not None and "S" not in layer_cache:
-            raise ValueError(
-                "a Mamba-2 layer caches {'S', 'conv'} (init_cache): a "
-                "state, not keys and values by position")
+        self.check_entry(layer_cache)
 
         def param(name, init, shape, axes, dtype=pdt):
             return self.param(
@@ -1135,13 +1302,18 @@ class Mamba2(nn.Module):
                       "out_proj")(gate_and_norm(y, z, norm)), new_cache
 
 
-class MLP(nn.Module):
+class MLP(nn.Module, Kind):
     cfg: ModelConfig
+
+    @staticmethod
+    def tag_bytes(cfg, rows, seq_len, w):
+        return {"mlp_pre": rows * seq_len * (2 if cfg.gated_mlp else 1)
+                * w(cfg.intermediate_size) * _dt(cfg.dtype).itemsize}
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        if cfg.arch == "llama" or cfg.pattern:
+        if cfg.gated_mlp:
             gate = checkpoint_name(
                 _dense(cfg.intermediate_size, ("embed", "mlp"),
                        cfg.mlp_bias, cfg, "gate_proj")(x), "mlp_pre")
@@ -1159,108 +1331,144 @@ class MLP(nn.Module):
                       cfg.mlp_bias, cfg, "down_proj")(h)
 
 
+#: ``ModelConfig.layer_kinds``' mixers -> the modules that implement
+#: them and state what follows from them (:class:`Kind`).
+MIXERS = {"attention": Attention, "sparse": SparseAttention,
+          "latent": LatentAttention, "kda": KimiDeltaAttention,
+          "gdn": GatedDeltaNet, "mamba2": Mamba2}
+
+
+def mixer_spec(cfg: ModelConfig, kind: str):
+    """(module class, its keywords) of the mixer ``kind`` in ``cfg``'s
+    blocks: the one place where an arch picks anything."""
+    kw = {}
+    if cfg.arch == "olmo_hybrid" and kind == "attention":
+        # rope_theta is published null (0 here): no rotation
+        kw = {"qk_norm": True, "rotary": cfg.rope_theta > 0}
+    elif cfg.arch == "keye_dsa":
+        kw = {"qk_norm": "head"}
+    return MIXERS[kind], kw
+
+
+def ffn_class(kind: str):
+    """The module class of the feed-forward half ``kind``."""
+    if kind == "dense":
+        return MLP
+    from orion_tpu.ops import moe
+
+    return {"gshard": moe.MoEMLP, "experts": moe.TopKMoE}[kind]
+
+
+def kinds(cfg: ModelConfig) -> tuple:
+    """The kind classes of ``cfg``'s layers, each once: the mixers,
+    then the feed-forward halves."""
+    pairs = cfg.layer_kinds()
+    return tuple(dict.fromkeys([MIXERS[m] for m, _ in pairs if m]
+                               + [ffn_class(f) for _, f in pairs if f]))
+
+
+def cannot_run(cfg: ModelConfig, form: str) -> Optional[str]:
+    """Why ``cfg``'s layers cannot run under ``form`` (the keys of
+    :meth:`Kind.lacks`): every kind's reason, each once; None where
+    every kind has the form."""
+    reasons = dict.fromkeys(kind.lacks(cfg).get(form) for kind in kinds(cfg))
+    return "; ".join(r for r in reasons if r) or None
+
+
+def decode_attrs(cfg: ModelConfig, lens=None, slots: int = 0,
+                 new_tokens: int = 0) -> dict:
+    """What the ``rollout.dispatch`` span carries of the model:
+    ``attn_heads_a_step`` and ``kda_step`` (``""`` without a delta-rule
+    layer) always; where the decode loop steps over a dense cache of
+    ``slots`` slots after prompts of ``lens`` real tokens (None: a page
+    pool), each kind's own and how the steps that go through
+    :func:`prefix_step` read it: ``kv_step_form``, ``prefix`` (the
+    filled blocks) / ``whole`` (a cache of one block), and
+    ``kv_step_slots``, the slots one row's step reads a layer (mean)."""
+    attrs = {"kda_step": "", "attn_heads_a_step": cfg.attn_heads_a_step()}
+    if lens is None:
+        return attrs
+    of = kinds(cfg)
+    if any(kind.steps_over_prefix for kind in of):
+        attrs.update(
+            kv_step_form="prefix" if len(prefix_lengths(slots)) > 1
+            else "whole",
+            kv_step_slots=prefix_step_slots(lens, slots, new_tokens))
+    for kind in of:
+        attrs.update(kind.decode_attrs(cfg, lens, slots, new_tokens))
+    return attrs
+
+
+def update_attrs(cfg: ModelConfig, total_lens) -> dict:
+    """What the ``update`` span carries of the model for a batch of
+    sequences of ``total_lens`` real tokens: ``attn_heads_a_step``,
+    ``kda_chunk`` (``""`` without a delta-rule layer), each mixer's own
+    and, for a model held in part (``head_share``), what of every layer
+    this chip holds (the benchmark's operation counts read it): the
+    state-space layers' heads and groups, attention's query and
+    key-value heads, the routed experts."""
+    attrs = {"kda_chunk": "", "attn_heads_a_step": cfg.attn_heads_a_step()}
+    for kind in kinds(cfg):
+        attrs.update(kind.forward_attrs(cfg, total_lens))
+    if cfg.head_share != (0, 1):
+        held = cfg.heads_held()
+        attrs.update(heads_held=held["mamba"], groups_held=held["groups"],
+                     attn_heads_held=held["q"], kv_heads_held=held["kv"],
+                     experts_held=cfg.experts_held)
+    return attrs
+
+
 class Block(nn.Module):
-    cfg: ModelConfig
-
-    @nn.compact
-    def __call__(self, x, positions, layer_cache=None):
-        cfg = self.cfg
-        sp = None
-        if cfg.seq_shard_activations:
-            from orion_tpu.parallel.sharding import constrain_seq_activation
-            sp = constrain_seq_activation
-            x = sp(x)
-        if cfg.num_experts > 0:
-            from orion_tpu.ops.moe import MoEMLP
-            mlp_cls = MoEMLP
-        else:
-            mlp_cls = MLP
-        if cfg.use_parallel_residual:
-            # GPT-NeoX: x + attn(ln1(x)) + mlp(ln2(x))
-            attn_out, new_cache = Attention(cfg, name="attn")(
-                _norm(cfg, "input_norm")(x), positions, layer_cache)
-            mlp_out = mlp_cls(cfg, name="mlp")(
-                _norm(cfg, "post_attn_norm")(x))
-            out = x + attn_out + mlp_out
-            return (sp(out) if sp else out), new_cache
-        attn_out, new_cache = Attention(cfg, name="attn")(
-            _norm(cfg, "input_norm")(x), positions, layer_cache)
-        h = checkpoint_name(x + attn_out, "attn_resid")
-        if sp:
-            h = sp(h)
-        mlp_out = mlp_cls(cfg, name="mlp")(_norm(cfg, "post_attn_norm")(h))
-        return (sp(h + mlp_out) if sp else h + mlp_out), new_cache
-
-
-class LatentBlock(nn.Module):
-    """deepseek_v3 / kimi_linear / keye_dsa / nemotron_h block: ``a = x
-    + Mixer(N1(x))``, ``y = a + FFN(N2(a))``; the mixer latent
-    attention, (``mixer="kda"``) the delta rule, (``"sparse"``)
-    grouped-query attention under a selection, (``"mamba2"``) a
-    state-space layer, (``"attention"``) grouped-query attention or
-    (None) absent: ``a = x``; FFN the SwiGLU MLP (``ffn="dense"``), the
-    expert layer or (None) absent: ``y = a``."""
+    """One block of ``mixer`` and ``ffn`` (``ModelConfig.layer_kinds``;
+    None: that half is absent).  ``a = x + Mixer(N1(x))``, ``y = a +
+    FFN(N2(a))`` (``input_norm``, ``post_attn_norm``); under
+    ``use_parallel_residual`` (GPT-NeoX) ``y = x + Mixer(N1(x)) +
+    FFN(N2(x))``; under ``post_norm`` (the OLMo 2 / 3 order) ``a = x +
+    N_a(Mixer(x))``, ``y = a + N_f(FFN(a))`` (``post_attn_norm``,
+    ``post_mlp_norm``).  A block of both halves tags ``a``
+    (``attn_resid``) unless they run in parallel."""
 
     cfg: ModelConfig
-    ffn: Optional[str] = "experts"
-    mixer: Optional[str] = "latent"
+    mixer: Optional[str]
+    ffn: Optional[str]
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None, token_mask=None):
         cfg = self.cfg
+        if cfg.seq_shard_activations:
+            from orion_tpu.parallel.sharding import \
+                constrain_seq_activation as sp
+        else:
+            def sp(t):
+                return t
+
+        def norm(name, t, after: bool = False):
+            return _norm(cfg, name)(t) if after == cfg.post_norm else t
+
+        def run(kind, module, *args):
+            more = (token_mask,) if kind.takes_token_mask else ()
+            return module(*args, *more)
+
+        x = sp(x)
         h, new_cache = x, (None if layer_cache is None else {})
         if self.mixer is not None:
-            h = _norm(cfg, "input_norm")(x)
-            if self.mixer in ("kda", "mamba2"):
-                mixer_cls = KimiDeltaAttention if self.mixer == "kda" \
-                    else Mamba2
-                attn_out, new_cache = mixer_cls(cfg, name="attn")(
-                    h, positions, layer_cache, token_mask)
-            elif self.mixer == "sparse":
-                attn_out, new_cache = SparseAttention(
-                    cfg, qk_norm="head", name="attn")(
-                        h, positions, layer_cache)
-            elif self.mixer == "attention":
-                attn_out, new_cache = Attention(cfg, name="attn")(
-                    h, positions, layer_cache)
-            else:
-                attn_out, new_cache = LatentAttention(cfg, name="attn")(
-                    h, positions, layer_cache)
-            h = x + attn_out
+            kind, kw = mixer_spec(cfg, self.mixer)
+            attn_out, new_cache = run(
+                kind, kind(cfg, name="attn", **kw), norm("input_norm", x),
+                positions, layer_cache)
+            attn_out = norm("post_attn_norm", attn_out, after=True)
         if self.ffn is None:
-            return h, new_cache
+            return sp(x + attn_out), new_cache
+        kind = ffn_class(self.ffn)
+        ffn = kind(cfg, name="mlp")
+        if cfg.use_parallel_residual:
+            mlp_out = run(kind, ffn, norm("post_attn_norm", x))
+            out = x + attn_out + norm("post_mlp_norm", mlp_out, after=True)
+            return sp(out), new_cache
         if self.mixer is not None:
-            h = checkpoint_name(h, "attn_resid")
-        z = _norm(cfg, "post_attn_norm")(h)
-        if self.ffn == "dense":
-            return h + MLP(cfg, name="mlp")(z), new_cache
-        from orion_tpu.ops.moe import TopKMoE
-        return h + TopKMoE(cfg, name="mlp")(z, token_mask), new_cache
-
-
-class PostNormBlock(nn.Module):
-    """olmo_hybrid block (the OLMo 2 / 3 order): ``h = x +
-    N_a(Mixer(x))``, ``y = h + N_f(MLP(h))``; no norm before a sublayer.
-    The mixer the gated delta rule (``mixer="gdn"``) or full attention."""
-
-    cfg: ModelConfig
-    mixer: str = "attention"
-
-    @nn.compact
-    def __call__(self, x, positions, layer_cache=None, token_mask=None):
-        cfg = self.cfg
-        if self.mixer == "gdn":
-            attn_out, new_cache = GatedDeltaNet(cfg, name="attn")(
-                x, positions, layer_cache, token_mask)
-        else:
-            # rope_theta is published null (0 here): no rotation
-            attn_out, new_cache = Attention(
-                cfg, qk_norm=True, rotary=cfg.rope_theta > 0, name="attn")(
-                    x, positions, layer_cache)
-        h = checkpoint_name(x + _norm(cfg, "post_attn_norm")(attn_out),
-                            "attn_resid")
-        return h + _norm(cfg, "post_mlp_norm")(
-            MLP(cfg, name="mlp")(h)), new_cache
+            h = sp(checkpoint_name(x + attn_out, "attn_resid"))
+        mlp_out = run(kind, ffn, norm("post_attn_norm", h))
+        return sp(h + norm("post_mlp_norm", mlp_out, after=True)), new_cache
 
 
 def _stack_names(cfg: ModelConfig) -> dict:
@@ -1345,24 +1553,6 @@ class Transformer(nn.Module):
             name="embed")
         x = embed(input_ids)
 
-        def block(mixer, ffn):
-            """(block class for a layer of this kind, its keywords,
-            whether it takes ``token_mask``)."""
-            if not cfg.pattern:
-                cls, kw, masked = Block, {}, False
-            elif cfg.arch == "olmo_hybrid":
-                cls, kw, masked = PostNormBlock, {"mixer": mixer}, \
-                    mixer == "gdn"
-            else:
-                cls, kw = LatentBlock, {"mixer": mixer, "ffn": ffn}
-                masked = mixer in RECURRENT_MIXERS or ffn == "experts"
-            if cfg.remat:
-                cls = nn.remat(
-                    cls, static_argnums=(),
-                    policy=jax.checkpoint_policies.save_only_these_names(
-                        *remat_keep) if remat_keep else None)
-            return cls, kw, masked and token_mask is not None
-
         # One stretch of equal kinds at a time (cfg.layer_runs).  A
         # pattern model's leading dense layers, layers_<i> in both
         # layouts, stand outside the scanned stacks.
@@ -1371,8 +1561,12 @@ class Transformer(nn.Module):
         run_caches = _split_cache(cfg, cache)
         new_caches = []
         for (first, length, mixer, ffn), rc in zip(runs, run_caches):
-            cls, kw, masked = block(mixer, ffn)
-            more = (token_mask,) if masked else ()
+            cls = Block
+            if cfg.remat:
+                cls = nn.remat(
+                    Block, static_argnums=(),
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        *remat_keep) if remat_keep else None)
             if cfg.scan_layers and first in stacks:
                 # One Block traced once, lax.scan over a stacked param
                 # tree [length, ...] — compile time is O(1) in depth (the
@@ -1390,19 +1584,20 @@ class Transformer(nn.Module):
                     variable_axes={"params": 0, "intermediates": 0,
                                    "selections": 0},
                     split_rngs={"params": True},
-                    in_axes=(nn.broadcast, 0) + (nn.broadcast,) * len(more),
+                    in_axes=(nn.broadcast, 0, nn.broadcast),
                     out_axes=0,
                     length=length,
                     metadata_params={nn.meta.PARTITION_NAME: "layers"},
                 )
-                x, c = scan_block(cfg, name=stacks[first], **kw)(
-                    x, positions, rc, *more)
+                x, c = scan_block(cfg, mixer, ffn, name=stacks[first])(
+                    x, positions, rc, token_mask)
                 new_caches.append(c)
             else:
                 out = []
                 for j in range(length):
-                    x, c = cls(cfg, name=f"layers_{first + j}", **kw)(
-                        x, positions, None if rc is None else rc[j], *more)
+                    x, c = cls(cfg, mixer, ffn, name=f"layers_{first + j}")(
+                        x, positions, None if rc is None else rc[j],
+                        token_mask)
                     out.append(c)
                 new_caches.append(out)
         new_cache = None if cache is None else _join_cache(cfg, new_caches)
@@ -1444,65 +1639,33 @@ def cache_slots(max_len: int) -> int:
     return -(-max_len // 8) * 8
 
 
+def cache_entry(cfg: ModelConfig, mixer: Optional[str], batch: int,
+                slots: int, dtype, pre: tuple = (), quantized: bool = False):
+    """The cache entry of one layer of ``mixer`` (what the mixer states;
+    {} for a block without one), ``pre`` the leading axes of a stack."""
+    if mixer is None:
+        return {}
+    kw = {"quantized": True} if quantized else {}      # Attention's alone
+    return MIXERS[mixer].cache_entry(cfg, batch, slots, dtype, pre, **kw)
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype: Optional[Any] = None, quantized: bool = False):
-    """Dense pre-allocated KV cache.  ``scan_layers`` models use a
-    stacked [num_layers, ...] pytree (scanned over axis 0); unrolled
-    models a per-layer list.  ``quantized`` stores int8 values with
-    per-token-per-head f32 scales (RolloutConfig.quantize_kv — see
-    ops/quant.py)."""
+    """Dense pre-allocated cache: per layer what its mixer states
+    (``cache_entry``; {} for a block without a mixer).  ``scan_layers``
+    models use a stacked [num_layers, ...] pytree (scanned over axis
+    0); unrolled models a per-layer list.  ``quantized`` stores int8
+    values with per-token-per-head f32 scales (RolloutConfig.quantize_kv
+    — see ops/quant.py)."""
     dtype = dtype or _dt(cfg.dtype)
-    max_len = cache_slots(max_len)
-    if cfg.pattern and quantized:
-        raise ValueError(
-            "there is no int8 latent cache (rollout.quantize_kv) for "
-            f"arch={cfg.arch!r} yet: ops/quant.py scales per head, a "
-            "recurrent state has no int8 form, and no int8 cache was run "
-            "under a selection")
-    held = cfg.heads_held()
-    shape = (batch, max_len, held["kv"], cfg.head_dim)
+    slots = cache_slots(max_len)
+    why = quantized and cannot_run(cfg, "quantize_kv")
+    if why:
+        raise ValueError("there is no int8 cache (rollout.quantize_kv) for "
+                         f"arch={cfg.arch!r} yet: {why}")
 
     def entry(mixer, pre=()):
-        if mixer is None:
-            return {}
-        if mixer == "mamba2":
-            H, P = held["mamba"], cfg.mamba_head_dim
-            return {"S": jnp.zeros(pre + (batch, H, P, cfg.ssm_state_size),
-                                   jnp.float32),
-                    "conv": jnp.zeros(
-                        pre + (batch, cfg.mamba_conv_kernel - 1, H * P
-                               + 2 * held["groups"] * cfg.ssm_state_size),
-                        dtype)}
-        if mixer == "kda":
-            H, d = cfg.kda_num_heads, cfg.kda_head_dim
-            return {"S": jnp.zeros(pre + (batch, H, d, d), jnp.float32),
-                    "conv": jnp.zeros(
-                        pre + (batch, cfg.short_conv_kernel_size - 1,
-                               3 * H * d), dtype)}
-        if mixer == "gdn":
-            H, dk, dv = (cfg.linear_num_key_heads, cfg.linear_key_head_dim,
-                         cfg.linear_value_head_dim)
-            return {"S": jnp.zeros(pre + (batch, H, dk, dv), jnp.float32),
-                    "conv": jnp.zeros(
-                        pre + (batch, cfg.linear_conv_kernel_dim - 1,
-                               H * (2 * dk + dv)), dtype)}
-        if mixer == "latent":
-            return {"c": jnp.zeros(pre + (batch, max_len, cfg.kv_lora_rank),
-                                   dtype),
-                    "k_rope": jnp.zeros(
-                        pre + (batch, max_len, cfg.qk_rope_head_dim), dtype)}
-        if mixer == "sparse":
-            return {"k": jnp.zeros(pre + shape, dtype),
-                    "v": jnp.zeros(pre + shape, dtype),
-                    "ki": jnp.zeros(
-                        pre + (batch, max_len, cfg.sa_index_head_dim), dtype)}
-        if quantized:
-            return {"k": jnp.zeros(pre + shape, jnp.int8),
-                    "v": jnp.zeros(pre + shape, jnp.int8),
-                    "k_scale": jnp.zeros(pre + shape[:-1], jnp.float32),
-                    "v_scale": jnp.zeros(pre + shape[:-1], jnp.float32)}
-        return {"k": jnp.zeros(pre + shape, dtype),
-                "v": jnp.zeros(pre + shape, dtype)}
+        return cache_entry(cfg, mixer, batch, slots, dtype, pre, quantized)
 
     stacks = _stack_names(cfg) if cfg.scan_layers else {}
     return _join_cache(cfg, [

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from orion_tpu.config import ModelConfig
-from orion_tpu.models.transformer import _dt
+from orion_tpu.models.transformer import Kind, _dt
 
 
 def top2_routing(router_logits: jnp.ndarray, n_experts: int,
@@ -85,7 +85,7 @@ def top2_routing(router_logits: jnp.ndarray, n_experts: int,
     return dispatch, combine, aux
 
 
-class MoEMLP(nn.Module):
+class MoEMLP(nn.Module, Kind):
     """Expert-parallel SwiGLU MLP (drop-in for the dense MLP inside a
     Block when ``cfg.num_experts > 0``).
 
@@ -399,7 +399,7 @@ def _routed_bwd(block, act, res, g):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-class TopKMoE(nn.Module):
+class TopKMoE(nn.Module, Kind):
     """The dropless expert layer and the chip's share of it
     (``deepseek_v3``'s as published; ``cfg.moe_scoring`` says how the
     router scores: :func:`sigmoid_topk_route` with its selection bias,
@@ -444,6 +444,35 @@ class TopKMoE(nn.Module):
     """
 
     cfg: ModelConfig
+
+    takes_token_mask = True
+
+    @staticmethod
+    def lacks(cfg):
+        return {"quantize_weights":
+                "there are no int8 expert stacks (ops/quant.py quantises "
+                "Dense kernels" + (
+                    ", and was not run on experts without a gate)"
+                    if cfg.moe_activation == "relu2" else ")")}
+
+    @staticmethod
+    def tag_bytes(cfg, rows, seq_len, w):
+        """``mlp_pre``: the shared expert's first product; ``moe_route``:
+        scores [n, E] float32 and the selection [n, k] (the gather of the
+        selected scores keeps its own indices); the dense form reads the
+        selection again for its weights, the grouped form's backward
+        reads order [n k, in whole blocks] and sizes [held + 1]
+        instead."""
+        n, k = rows * seq_len, cfg.num_experts_per_tok
+        route = n * (w(cfg.n_routed_experts) + w(k))
+        block = block_rows(cfg, n)
+        if block:
+            route += w(-(-n * k // block) * block) + w(cfg.experts_held + 1)
+        else:
+            route += n * w(k)
+        return {"mlp_pre": n * ACTIVATIONS[cfg.moe_activation][1]
+                * w(shared_width(cfg)) * jnp.dtype(cfg.dtype).itemsize,
+                "moe_route": 4 * route}
 
     @nn.compact
     def __call__(self, x, token_mask=None):
@@ -527,7 +556,3 @@ class TopKMoE(nn.Module):
                 shared = _dense(Dm, ("mlp", "embed"), False, cfg,
                                 "shared_down_proj")(h)
         return routed.reshape(B, L, Dm).astype(cdt) + shared
-
-
-#: the layer's name before the scoring became data
-SigmoidTopKMoE = TopKMoE

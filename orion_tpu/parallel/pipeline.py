@@ -71,8 +71,8 @@ def _stage_apply(cfg: ModelConfig, stage_params, x, positions):
         length=n_local,
         metadata_params={nn.meta.PARTITION_NAME: "layers"},
     )
-    x, _ = scan_block(cfg).apply({"params": stage_params}, x, positions,
-                                 None)
+    x, _ = scan_block(cfg, *cfg.layer_kinds()[0]).apply(
+        {"params": stage_params}, x, positions, None)
     return x
 
 

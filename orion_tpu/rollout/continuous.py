@@ -211,24 +211,15 @@ class ContinuousBatchingEngine:
                  eos_token_id: Optional[int] = None, pad_token_id: int = 0,
                  segment_len: Optional[int] = None,
                  mesh: Optional[Mesh] = None):
-        if model_cfg.pattern:
+        from orion_tpu.models.transformer import cannot_run
+
+        why = cannot_run(model_cfg, "continuous")
+        if why:
             raise ValueError(
                 f"the continuous engine cannot run arch={model_cfg.arch!r}"
                 ": its page pool, block tables and the Pallas paged-decode "
                 "kernel hold per-head K/V pages of one head_dim in every "
-                "layer"
-                + ("; a latent paged cache (c, k_rope) and a kernel that "
-                   "attends over it are not written yet"
-                   if model_cfg.latent_attention else "")
-                + ("; nor is there a selection inside paged attention or "
-                   "a page pool for the indexer's keys"
-                   if model_cfg.arch == "keye_dsa" else "")
-                + ("; nor does its cache manager hold a recurrent state "
-                   "per slot (admission, preemption and prefix reuse move "
-                   "pages, and a state is not made of pages: a delta-rule "
-                   "layer's or a state-space layer's {S, conv})"
-                   if model_cfg.recurrent else "")
-                + " (use rollout.engine=simple)")
+                f"layer; {why} (use rollout.engine=simple)")
         self.mc = model_cfg
         self.cfg = cfg
         cfg.check_stop_ids(model_cfg.vocab_size, eos_token_id)
@@ -597,6 +588,15 @@ class ContinuousBatchingEngine:
         every decode step reads 2 bytes/param instead of 4 (int8 when
         quantize_weights is on)."""
         self._params = self._prep_params(params)
+
+    def dispatch_attrs(self, prompts_shape, lens, params=None) -> dict:
+        """``RolloutEngine.dispatch_attrs``' names for the trainer's
+        ``rollout.dispatch`` span: the page pool is this engine's own
+        and is sized apart from a batch, so the bytes are 0."""
+        from orion_tpu.models.transformer import decode_attrs
+
+        return {"cache_bytes": 0, "state_bytes": 0, "weight_bytes": 0,
+                **decode_attrs(self.mc)}
 
     # -- blue/green rollout surface (PR 18) ------------------------------
     @property

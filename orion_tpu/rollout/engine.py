@@ -15,21 +15,18 @@ One decode path (XLA-first, static shapes):
 - right-padded prompts with per-sequence lengths; the cache write path
   overwrites the padded tail slot-by-slot during decode (see
   models.transformer.Attention);
-- the cache is dense ([B, P+T] per layer; the latent form for
-  ``latent_attention``; per layer by kind for a pattern model; with the
-  indexer's keys beside k and v under sparse attention), int8
-  under ``quantize_kv``, or paged under
-  ``RolloutConfig.paged`` (block tables + the Pallas paged-decode
-  kernel; slower than dense for a fixed batch, ROADMAP D3(a)); a
-  one-token step reads the dense cache's filled prefix in blocks
+- the cache is dense: per layer what its mixer states
+  (models.transformer.MIXERS: ``cache_entry``, [B, P+T] slots where it
+  is indexed by position, a state handed from prefill to decode where
+  it is not, {} for a block without a mixer), int8 under
+  ``quantize_kv``, or paged under ``RolloutConfig.paged`` (block tables
+  + the Pallas paged-decode kernel; slower than dense for a fixed
+  batch, ROADMAP D3(a)); what a model's kinds cannot run is refused
+  with their own reasons (models.transformer.cannot_run); a one-token
+  step reads the dense cache's filled prefix in blocks
   (models.transformer.prefix_step: slots fill from 0 up, so what lies
-  past the batch's furthest position is never fetched);
-- a recurrent layer (``ModelConfig.recurrent``: the delta rule's or a
-  state-space layer) has no slot to overwrite: its cache entry is a
-  state, and prefill (given ``token_mask``) hands decode each row's
-  state and last convolution inputs after its last real prompt token
-  (models.transformer.KimiDeltaAttention, Mamba2).  A block without a
-  mixer caches nothing ({}).  The decode loop is the same.
+  past the batch's furthest position is never fetched).  The decode
+  loop is the same for every kind.
 
 Speculative decoding is not here: a lockstep batch advances at its
 slowest row's acceptance, and it lost on the chip (PERF.md section 6,
@@ -47,9 +44,9 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.config import ModelConfig, RolloutConfig
-from orion_tpu.models.transformer import (PREFIX_STEP_MIXERS, cache_slots,
-                                         init_cache, prefix_lengths,
-                                         prefix_step_slots)
+from orion_tpu.models.transformer import (MIXERS, cache_entry, cache_slots,
+                                         cannot_run, decode_attrs, init_cache,
+                                         make_decode_twin, prep_decode_params)
 from orion_tpu.ops.logprobs import pack_sequences
 from orion_tpu.ops.sampling import sample_tokens
 from orion_tpu.resilience import fault_point
@@ -100,50 +97,13 @@ class RolloutEngine:
         self._params = None
         self._cache_bytes: dict = {}
         self._weight_bytes: Optional[int] = None
-        from orion_tpu.models.transformer import make_decode_twin
-
         self._decode_model, self._decode_cfg = make_decode_twin(
             model, model_cfg)
-        if model_cfg.pattern:
-            latent = model_cfg.latent_attention
-            sparse = model_cfg.arch == "keye_dsa"
-            relu2 = model_cfg.moe_activation == "relu2"
-            state = ", and a recurrent state is not made of pages" \
-                if model_cfg.recurrent else ""
-            for on, missing in (
-                    (cfg.paged, "rollout.paged: "
-                     + ("there is no latent paged cache (ops/paged_kv.py "
-                        "and the Pallas paged-decode kernel hold per-head "
-                        "K/V pages)" if latent else
-                        "there is no selection inside paged attention nor "
-                        "a page pool for the indexer's keys "
-                        "(ops/paged_kv.py)" if sparse else
-                        "init_paged_cache gives every layer pages")
-                     + state),
-                    (cfg.quantize_kv, "rollout.quantize_kv: there is no "
-                     + ("int8 latent cache (ops/quant.py scales per head)"
-                        if latent else "int8 cache under a selection (the "
-                        "gathered step reads rows of bf16 keys and values)"
-                        if sparse else "int8 cache for a model whose "
-                        "layers do not all hold keys and values")
-                     + (", nor an int8 form of a float32 recurrent state"
-                        if model_cfg.recurrent else "")),
-                    (cfg.quantize_weights, "rollout.quantize_weights: "
-                     + ("there are no int8 expert stacks or absorbed int8 "
-                        "kv_b_proj (ops/quant.py quantises Dense kernels)"
-                        if latent else "there are no int8 expert stacks, "
-                        "and an int8 indexer would select other keys than "
-                        "the update's" if sparse else
-                        "there are no int8 expert stacks, and ops/quant.py "
-                        "was not run on experts without a gate or on a "
-                        "state-space layer's one input projection"
-                        if relu2 else
-                        "the int8 Dense twins do not reach "
-                        "this block (no QuantDense decode twin was run "
-                        "against its reference)"))):
-                if on:
-                    raise ValueError(
-                        f"arch={model_cfg.arch!r} cannot run with {missing}")
+        for form in ("paged", "quantize_kv", "quantize_weights"):
+            why = getattr(cfg, form) and cannot_run(model_cfg, form)
+            if why:
+                raise ValueError(f"arch={model_cfg.arch!r} cannot run with "
+                                 f"rollout.{form}: {why}")
         if cfg.quantize_weights:
             # int8 decode twin (ops/quant.py): same architecture, Dense
             # layers read int8 kernels.  Params are quantized inside
@@ -162,96 +122,55 @@ class RolloutEngine:
         (SURVEY.md §2 #11)."""
         self._params = params
 
-    def _cache_shapes(self, batch: int, prompt_len: int,
-                      max_new_tokens: Optional[int] = None):
-        T = int(max_new_tokens or self.cfg.max_new_tokens)
-        key = (batch, prompt_len + T)
+    def _cache_shapes(self, batch: int, slots: int) -> dict:
+        """Bytes of what ``_generate`` allocates for such a batch, from
+        shapes (nothing is placed): ``cache_bytes``, what is indexed by
+        position (keys and values, or latents); ``state_bytes``, what
+        is not (recurrent states and convolution inputs: read and
+        written whole at every decode step); with an indexer,
+        ``index_cache_bytes``: its keys' part of ``cache_bytes``."""
+        key = (batch, slots)
         if key not in self._cache_bytes:
-            cache = jax.eval_shape(
-                lambda: init_cache(self._decode_cfg, *key,
-                                   dtype=jnp.dtype(self._decode_cfg.dtype),
-                                   quantized=self.cfg.quantize_kv))
-            sizes = {"cache": 0, "state": 0, "index": 0}
-            for layer in cache:       # the decode twin's: one per layer
-                kind = "state" if "S" in layer else "cache"
-                sizes[kind] += sum(x.size * x.dtype.itemsize
-                                   for x in jax.tree.leaves(layer))
-                if "ki" in layer:
-                    sizes["index"] += (layer["ki"].size
-                                       * layer["ki"].dtype.itemsize)
+            mc = self._decode_cfg
+            mixers = [m for m, _ in mc.layer_kinds() if m]
+            entries = jax.eval_shape(lambda: [
+                cache_entry(mc, m, batch, slots, jnp.dtype(mc.dtype),
+                            quantized=self.cfg.quantize_kv) for m in mixers])
+            sizes = {"cache_bytes": 0, "state_bytes": 0}
+            for kind, entry in zip(map(MIXERS.get, mixers), entries):
+                for leaf, x in entry.items():
+                    size = x.size * x.dtype.itemsize
+                    sizes[kind.cache_kind + "_bytes"] += size
+                    if leaf in kind.index_leaves:
+                        sizes["index_cache_bytes"] = size + sizes.get(
+                            "index_cache_bytes", 0)
             self._cache_bytes[key] = sizes
         return self._cache_bytes[key]
 
-    def cache_bytes(self, batch: int, prompt_len: int,
-                    max_new_tokens: Optional[int] = None) -> int:
-        """Bytes of what ``_generate`` allocates for such a batch that
-        is indexed by position: the keys and values, or latents, of
-        every slot of every layer that has them (shapes only, nothing
-        is placed; 0 under ``paged``, whose pool is sized apart)."""
-        if self.cfg.paged:
-            return 0
-        return self._cache_shapes(batch, prompt_len, max_new_tokens)["cache"]
-
-    def index_cache_bytes(self, batch: int, prompt_len: int,
-                          max_new_tokens: Optional[int] = None) -> int:
-        """The part of :meth:`cache_bytes` that is a sparse-attention
-        indexer's keys (one head of ``sa_index_head_dim`` a slot and
-        layer), which a decode step reads up to where it is filled,
-        where it reads only the selected rows of the keys and values.
-        0 for a model without an indexer."""
-        if self.cfg.paged:
-            return 0
-        return self._cache_shapes(batch, prompt_len, max_new_tokens)["index"]
-
-    def state_bytes(self, batch: int, prompt_len: int,
-                    max_new_tokens: Optional[int] = None) -> int:
-        """Bytes of the per-sequence state that is NOT indexed by
-        position (the recurrent layers' states and convolution inputs):
-        read and written whole at every decode step.  0 for a model
-        without such layers."""
-        if self.cfg.paged:
-            return 0
-        return self._cache_shapes(batch, prompt_len, max_new_tokens)["state"]
-
-    def kv_step_read(self, lens, prompt_len: int,
-                     max_new_tokens: Optional[int] = None) -> dict:
-        """{kv_step_form, kv_step_slots}: how a one-token step's
-        attention reads a dense slot cache after prompts of ``lens``
-        real tokens (``models/transformer.py::prefix_step``): ``prefix``
-        (the filled blocks) / ``whole`` (a cache of one block), and the
-        slots one row's step then reads a layer, the mean over the
-        steps.  {} where no step goes through ``prefix_step``: under
-        ``paged``, or a model whose mixers are not among
-        ``PREFIX_STEP_MIXERS`` (a selection, recurrent layers alone).
-        Host numbers, from shapes and lengths; not under an ``sa_`` name
-        (``trainers/base.py::sa_step_read``)."""
-        if self.cfg.paged or not any(
-                m in PREFIX_STEP_MIXERS
-                for m, _ in self.model_cfg.layer_kinds()):
-            return {}
-        T = int(max_new_tokens or self.cfg.max_new_tokens)
-        slots = cache_slots(prompt_len + T)
-        return {"kv_step_form":
-                "prefix" if len(prefix_lengths(slots)) > 1 else "whole",
-                "kv_step_slots": prefix_step_slots(lens, slots, T)}
-
-    def weight_bytes(self, params: Any = None) -> int:
-        """Bytes of the copy of the weights a decode step reads
-        (``prep_decode_params``: the compute dtype, int8 kernels under
-        ``quantize_weights``), from shapes."""
-        from orion_tpu.models.transformer import prep_decode_params
-
+    def dispatch_attrs(self, prompts_shape, lens, params: Any = None) -> dict:
+        """What the ``rollout.dispatch`` span carries of a batch of
+        prompts of ``prompts_shape`` with ``lens`` real tokens, from
+        shapes and lengths: what a decode step touches of the cache
+        (:meth:`_cache_shapes`; 0 under ``paged``, whose pool is sized
+        apart), ``weight_bytes`` (the copy of the weights it reads,
+        ``prep_decode_params``) and what the model says of its steps
+        (``models.transformer.decode_attrs``)."""
         params = params if params is not None else self._params
-        if params is None:
-            return 0
-        if self._weight_bytes is None:
+        if self._weight_bytes is None and params is not None:
             tree = jax.eval_shape(
                 lambda p: prep_decode_params(p, self.model_cfg,
                                              self.cfg.quantize_weights),
                 params)
             self._weight_bytes = sum(x.size * x.dtype.itemsize
                                      for x in jax.tree.leaves(tree))
-        return self._weight_bytes
+        weights = {"weight_bytes": self._weight_bytes or 0}
+        if self.cfg.paged:
+            return {"cache_bytes": 0, "state_bytes": 0, **weights,
+                    **decode_attrs(self.model_cfg)}
+        T = self.cfg.max_new_tokens
+        slots = cache_slots(prompts_shape[1] + T)
+        return {**self._cache_shapes(prompts_shape[0], slots), **weights,
+                **decode_attrs(self.model_cfg, lens, slots, T)}
 
     # -- generation -----------------------------------------------------
     def generate(self, prompt_ids: jnp.ndarray, prompt_lens: jnp.ndarray,
@@ -284,8 +203,6 @@ class RolloutEngine:
         # per-layer promote_dtype is NOT hoisted out of while_loop by
         # XLA, measured ~2x decode bandwidth — plus unstack + optional
         # int8) lives in one place for all engine paths.
-        from orion_tpu.models.transformer import prep_decode_params
-
         params = prep_decode_params(params, self.model_cfg,
                                     cfg.quantize_weights)
 

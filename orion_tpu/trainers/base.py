@@ -24,7 +24,8 @@ import numpy as np
 import optax
 
 from orion_tpu.config import OptimizerConfig, TrainConfig
-from orion_tpu.models.transformer import Transformer, cache_slots
+from orion_tpu.models.transformer import (Transformer, kinds, remat_keep,
+                                         remat_tag_bytes, update_attrs)
 from orion_tpu.ops.logprobs import completion_logprobs, entropy_from_logits
 from orion_tpu.rollout import GenerationResult, RolloutEngine
 
@@ -171,60 +172,17 @@ def _stamp(span, gc_totals: tuple) -> tuple:
 
 def hold_fixed(updates, cfg_model):
     """The optimizer's updates with those of the parameters that RL
-    holds fixed set to zero: a sparse-attention indexer's projections
-    and norm (``index_*`` under a ``keye_dsa`` mixer).  No gradient
-    reaches them (the selection is discrete and its input stops the
-    gradient), so this only keeps weight decay off them; every other
-    model's updates pass through as they are."""
-    if cfg_model.arch != "keye_dsa":
+    holds fixed set to zero: the ones the model's mixers name by prefix
+    (``models.transformer.Kind.rl_fixed``).  No gradient reaches them,
+    so this only keeps weight decay off them; a model that names none
+    gets its updates back as they are."""
+    fixed = tuple(p for kind in kinds(cfg_model) for p in kind.rl_fixed)
+    if not fixed:
         return updates
     return jax.tree_util.tree_map_with_path(
         lambda path, u: jnp.zeros_like(u) if any(
-            str(getattr(k, "key", "")).startswith("index_") for k in path)
+            str(getattr(k, "key", "")).startswith(fixed) for k in path)
         else u, updates)
-
-
-def sa_key_counts(lens, topk: int) -> dict:
-    """{sa_keys_valid, sa_keys_selected} summed over the real queries of
-    sequences of ``lens`` real tokens: query t (0-based) has t + 1 valid
-    keys and keeps ``min(topk, t + 1)``.  Host integers, from lengths."""
-    n = np.asarray(lens, np.int64)
-    valid = n * (n + 1) // 2
-    m = np.minimum(n, topk)
-    selected = m * (m + 1) // 2 + (n - m) * topk
-    return {"sa_keys_valid": int(valid.sum()),
-            "sa_keys_selected": int(selected.sum())}
-
-
-def sa_step_read(lens, cache_len: int, new_tokens: int, mc) -> dict:
-    """{sparse_step, sa_step_bytes}: the form the selected one-token
-    step takes against a cache of ``cache_len`` slots in this process's
-    traces (``ops/pallas/sparse_step.py::step_form``: ``kernel`` /
-    ``masked``; not under an ``sa_`` name: the benchmark's reader of the
-    span, ``roofline_keye_dsa.py::span_counts``, takes every ``sa_*``
-    attribute for a number) and the bytes of k and v one step then reads
-    a layer, the mean over the ``new_tokens`` steps of prompts of
-    ``lens`` real tokens (``step_slots``).  Host integers, from shapes
-    and lengths."""
-    from orion_tpu.ops.pallas import sparse_step
-
-    form = sparse_step.step_form(cache_len)
-    slots = sparse_step.step_slots(form, lens, cache_len, new_tokens)
-    row = mc.num_kv_heads * mc.head_dim * jnp.dtype(mc.dtype).itemsize
-    return {"sparse_step": form, "sa_step_bytes": int(2 * slots * row)}
-
-
-def share_counters(cfg_model) -> dict:
-    """What of every layer this chip holds under ``head_share``, for the
-    ``update`` span (the benchmark's operation counts read it): the
-    state-space layers' heads and groups, attention's query and
-    key-value heads, the routed experts.  {} for a model held whole."""
-    if cfg_model.head_share == (0, 1):
-        return {}
-    held = cfg_model.heads_held()
-    return {"heads_held": held["mamba"], "groups_held": held["groups"],
-            "attn_heads_held": held["q"], "kv_heads_held": held["kv"],
-            "experts_held": cfg_model.experts_held}
 
 
 def state_out_shardings(state: "TrainState"):
@@ -363,6 +321,8 @@ class BaseTrainer:
         self._defer_stats = False
         self._pending_fetch = None
         self._pending_meta = None
+        # models.transformer.update_attrs of the batch last fetched
+        self._update_attrs: dict = {}
         self._fetch_s = (0.0, 0.0)   # (fetch.wait, fetch.copy) last taken
         self._rng = jax.random.key(cfg.seed)
         self._np_rng = np.random.RandomState(cfg.seed)
@@ -598,46 +558,6 @@ class BaseTrainer:
         return self.engine.generate(ids, lens, rng,
                                     params=self.state.params)
 
-    def _rollout_bytes(self, prompts_shape, lens) -> dict:
-        """What a decode step of the fixed-batch engine touches for this
-        batch, from shapes: ``cache_bytes`` (what is indexed by
-        position: keys and values, or latents), ``state_bytes`` (what is
-        not: recurrent states, read and written whole a step),
-        ``weight_bytes`` (the decode copy of the weights),
-        ``kda_step``, the form the delta rule's one-token step takes
-        there (``kernel`` / ``jnp``, from ``ops/kda.py::step_form``;
-        ``""`` without such a layer), ``attn_heads_a_step`` (the query
-        heads a grid step of the prefill's flash kernels holds:
-        ``ModelConfig.attn_heads_a_step``), and what the engine says of its
-        attention step's read after prompts of ``lens`` real tokens
-        (``RolloutEngine.kv_step_read``).  All 0 and ``""`` for the
-        continuous engine, whose pool is its own and which refuses
-        recurrent models."""
-        eng = self.engine
-        heads = {"attn_heads_a_step": self.cfg.model.attn_heads_a_step()}
-        if not hasattr(eng, "state_bytes"):
-            return {"cache_bytes": 0, "state_bytes": 0, "weight_bytes": 0,
-                    "kda_step": "", **heads}
-        sparse = {}
-        if self.cfg.model.sa_topk:
-            # the indexer's keys, part of cache_bytes: a step reads them
-            # up to where they are filled; what it reads of k and v is
-            # sa_step_bytes, from the prompts' lengths (make_experience)
-            sparse = {"index_cache_bytes":
-                      eng.index_cache_bytes(*prompts_shape),
-                      "sa_topk": self.cfg.model.sa_topk}
-        dims, kda_step = self.cfg.model.delta_head_dims(), ""
-        if dims:
-            # the form the delta-rule layers' one-token step takes in
-            # this process's traces (the fixed-batch engine's decode)
-            from orion_tpu.ops.kda import step_form
-            kda_step = step_form(*dims)
-        return {"cache_bytes": eng.cache_bytes(*prompts_shape),
-                "state_bytes": eng.state_bytes(*prompts_shape),
-                "weight_bytes": eng.weight_bytes(self.state.params),
-                "kda_step": kda_step, **heads, **sparse,
-                **eng.kv_step_read(lens, prompts_shape[1])}
-
     def _score_result(self, result, host, meta) -> np.ndarray:
         """One place for the device-vs-host reward dispatch (the
         wants_device_result contract) — used by make_experience,
@@ -747,15 +667,8 @@ class BaseTrainer:
         with obs.span("rollout.dispatch") as sp:
             ids, lens, meta = self.prepare_prompts(batch)
             sp.set(batch=int(ids.shape[0]), prompt_len=int(ids.shape[1]),
-                   **self._rollout_bytes(ids.shape, lens))
-            if self.cfg.model.sa_topk:
-                # over the prefill's real queries, from the host's lengths
-                sp.set(**sa_key_counts(lens,
-                                       self.cfg.model.sa_topk))
-                T = self.engine.cfg.max_new_tokens
-                sp.set(**sa_step_read(
-                    lens, cache_slots(int(ids.shape[1]) + T), T,
-                    self.cfg.model))
+                   **self.engine.dispatch_attrs(ids.shape, lens,
+                                                self.state.params))
             result = self.generate(
                 ids, lens, group_size=getattr(self.cfg, "group_size", 1))
         pend, self._pending_fetch = self._pending_fetch, None
@@ -769,13 +682,9 @@ class BaseTrainer:
             self._finalize_iteration(meta_p, fetched["p"],
                                      end=meta_p["end"])
         host = GenerationResult(**fetched["r"])
-        if self.cfg.model.sa_topk:
-            # over the real queries of ONE whole-sequence forward of this
-            # batch (the experience forwards and the update's each make
-            # it), from the lengths the fetch brought: the update span's
-            self._sa_counts = dict(
-                sa_key_counts(host.total_lens, self.cfg.model.sa_topk),
-                sa_topk=self.cfg.model.sa_topk)
+        # what the update span and the row say of the model's forward
+        # over this batch, from the lengths the fetch brought
+        self._update_attrs = update_attrs(self.cfg.model, host.total_lens)
         scores = self._score_result(result, host, meta)
         with obs.span("experience.dispatch"):
             return self.build_experience(result, scores, host=host)
@@ -850,18 +759,8 @@ class BaseTrainer:
         else it is traced once more with the names to keep, and there
         is one update program from then on.  A device that reports
         nothing (the CPU) gives no budget, and nothing is kept."""
-        from orion_tpu.models.transformer import remat_keep, remat_tag_bytes
-
-        dims, kda_chunk = self.cfg.model.delta_head_dims(), ""
-        if dims:
-            # the form the delta-rule layers' chunked rule takes in this
-            # trace
-            from orion_tpu.ops.kda import chunk_form
-            kda_chunk = chunk_form(*dims)
         self._remat_info = info = {
-            "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0,
-            "kda_chunk": kda_chunk,
-            "attn_heads_a_step": self.cfg.model.attn_heads_a_step()}
+            "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0}
         free = _device_free_bytes(self.state.params)
         if not self.cfg.model.remat or free is None:
             return
@@ -1152,8 +1051,7 @@ class BaseTrainer:
                             obs.span("update", it=it) as sp_upd:
                         upd_dev = self.update_epochs(experience, defer=True)
                         sp_upd.set(**(self._remat_info or {}),
-                                   **getattr(self, "_sa_counts", {}),
-                                   **share_counters(self.cfg.model))
+                                   **self._update_attrs)
                     with obs.timed("weight_sync"):
                         self.sync_weights()
                     self.global_iter += 1
@@ -1162,6 +1060,7 @@ class BaseTrainer:
                         "n": int(experience["prompt_lens"].shape[0]),
                         "it": it, "giter": self.global_iter,
                         "begin": begin, "fetch_s": self._fetch_s,
+                        "model": self._update_attrs,
                     }
                     # Held-out eval on schedule (generates with the
                     # freshest weights — sync_weights already ran).
@@ -1293,7 +1192,7 @@ class BaseTrainer:
                 "host_cpu_s": host_cpu_s,
                 "host_gc_s": host_gc_s,
                 "samples_per_sec": pending["n"] / iter_s,
-                **(self._remat_info or {}),
+                **(self._remat_info or {}), **pending["model"],
             })
             sp.set(**{k: v for k, v in stats.items()
                       if k.startswith("moe_")})

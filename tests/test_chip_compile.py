@@ -634,7 +634,8 @@ def _delta_engine(name, one_chip):
         max_prompt_len=512, max_new_tokens=512), eos_token_id=0,
         pad_token_id=0)
     states = len([m for m, _ in mc.layer_kinds() if m in ("kda", "gdn")])
-    assert eng.state_bytes(32, 512) >= states * 4 * int(np.prod(state_shape))
+    assert eng.dispatch_attrs((32, 512), [512] * 32)["state_bytes"] \
+        >= states * 4 * int(np.prod(state_shape))
     rng = jax.eval_shape(lambda: jax.random.key(0))
     with jax.default_matmul_precision("default"):
         compiled = eng._generate_jit.lower(

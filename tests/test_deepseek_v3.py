@@ -222,7 +222,7 @@ def test_eight_shares_add_up_to_the_uncut_layer():
         share = dict(p, experts_gate_up_proj=p["experts_gate_up_proj"][
             offset:offset + 1], experts_down_proj=p["experts_down_proj"][
             offset:offset + 1])
-        out = moe.SigmoidTopKMoE(cfg).apply({"params": share}, z)
+        out = moe.TopKMoE(cfg).apply({"params": share}, z)
         total = total + (out - shared)        # this share's routed part
     np.testing.assert_allclose(total + shared, want, atol=1e-5, rtol=0)
 
@@ -457,7 +457,8 @@ def test_paths_that_cannot_run_it_say_so():
     eng = RolloutEngine(model, cfg, RolloutConfig(max_prompt_len=8,
                                                   max_new_tokens=8))
     per_token = cfg.num_layers * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-    assert eng.cache_bytes(2, 8) == 2 * 16 * per_token * 2
+    assert eng.dispatch_attrs((2, 8), [8, 8])["cache_bytes"] \
+        == 2 * 16 * per_token * 2
 
 
 @pytest.mark.parametrize("scan", [False, True])

@@ -570,9 +570,10 @@ def test_the_sigmoid_cells_keep_their_layer_and_its_bias():
     cfg = ModelConfig.tiny("deepseek_v3", dtype="float32")
     assert cfg.moe_scoring == "sigmoid"
     z = jax.random.normal(jax.random.key(10), (1, 8, cfg.hidden_size))
-    params = moe.SigmoidTopKMoE(cfg).init(jax.random.key(11), z)["params"]
+    layer = moe.TopKMoE(cfg)
+    params = layer.init(jax.random.key(11), z)["params"]
     assert "e_score_correction_bias" in params
-    assert moe.SigmoidTopKMoE is moe.TopKMoE
+    assert isinstance(layer, moe.TopKMoE)
 
 
 # ---------------------------------------------------------------------------
@@ -664,8 +665,9 @@ def test_the_cut_gives_the_program_what_the_file_states():
 
 
 def test_key_counts_from_lengths():
-    from orion_tpu.trainers.base import sa_key_counts
+    from orion_tpu.models.transformer import SparseAttention
 
+    sa_key_counts = SparseAttention.key_counts
     got = sa_key_counts([5, 12], topk=8)
     assert got["sa_keys_valid"] == 15 + 78
     assert got["sa_keys_selected"] == 15 + (36 + 4 * 8)
@@ -685,12 +687,12 @@ def test_step_read_from_lengths(form, slots, monkeypatch):
     form, and the bytes of k and v a step reads a layer (2 key heads of
     16, float32)."""
     from orion_tpu.ops.pallas import sparse_step
-    from orion_tpu.trainers.base import sa_step_read
+    from orion_tpu.models.transformer import SparseAttention
 
     _step_form(monkeypatch, form)
     monkeypatch.setattr(sparse_step, "BLOCK_SLOTS", 16)
     cfg = ModelConfig.tiny("keye_dsa", dtype="float32")
-    got = sa_step_read([20, 30], 48, 16, cfg)
+    got = SparseAttention.step_read(cfg, [20, 30], 48, 16)
     assert got == {"sparse_step": form,
                    "sa_step_bytes": 2 * slots * 2 * 16 * 4}
 

@@ -419,7 +419,7 @@ def test_eight_shares_add_up_to_the_uncut_layer():
         share = dict(p, experts_gate_up_proj=p["experts_gate_up_proj"][
             offset:offset + 1], experts_down_proj=p["experts_down_proj"][
             offset:offset + 1])
-        out = moe.SigmoidTopKMoE(cfg).apply({"params": share}, z)
+        out = moe.TopKMoE(cfg).apply({"params": share}, z)
         total = total + (out - shared)        # this share's routed part
     np.testing.assert_allclose(total + shared, want, atol=1e-5, rtol=0)
 
@@ -461,14 +461,16 @@ def test_the_engine_decodes_through_the_state(tiny, step, monkeypatch):
     latent = 2 * (P + T) * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * 4
     H, d = cfg.kda_num_heads, cfg.kda_head_dim
     state = 4 * 2 * (H * d * d * 4 + 3 * 3 * H * d * 4)
-    assert eng.cache_bytes(2, P) == latent
-    assert eng.state_bytes(2, P) == state
+    sizes = eng.dispatch_attrs((2, P), lens)
+    assert sizes["cache_bytes"] == latent
+    assert sizes["state_bytes"] == state
     n_params = sum(x.size for x in jax.tree.leaves(params))
-    assert eng.weight_bytes() == 4 * n_params    # float32 at this size
+    assert sizes["weight_bytes"] == 4 * n_params    # float32 at this size
     plain = ModelConfig.tiny()
     eng0 = RolloutEngine(Transformer(plain), plain, RolloutConfig(
         max_prompt_len=8, max_new_tokens=8))
-    assert eng0.state_bytes(2, 8) == 0 and eng0.cache_bytes(2, 8) > 0
+    sizes0 = eng0.dispatch_attrs((2, 8), [8, 8])
+    assert sizes0["state_bytes"] == 0 and sizes0["cache_bytes"] > 0
 
 
 def test_ppo_iteration_through_the_launcher(tmp_path):
@@ -521,7 +523,8 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
         after["layers_1to2"]["mlp"]["e_score_correction_bias"],
         before["layers_1to2"]["mlp"]["e_score_correction_bias"])
     trainer = kept["trainer"]
-    sizes = trainer._rollout_bytes((4, 16), [16] * 4)
+    sizes = trainer.engine.dispatch_attrs((4, 16), [16] * 4,
+                                          trainer.state.params)
     assert sizes["state_bytes"] > sizes["cache_bytes"] > 0
     assert sizes["weight_bytes"] > 0
     assert sizes["kda_step"] == "jnp"            # the CPU's form

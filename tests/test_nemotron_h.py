@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from orion_tpu.config import ModelConfig, RolloutConfig
-from orion_tpu.models.transformer import (LatentBlock, Mamba2, Attention,
+from orion_tpu.models.transformer import (Block, Mamba2, Attention,
                                           Transformer, init_cache,
                                           init_params, remat_tag_bytes)
 from orion_tpu.ops.mamba2 import mamba2_chunked, mamba2_scan, mamba2_step
@@ -329,11 +329,12 @@ def test_the_engine_decodes_through_state_and_per_head_cache(tiny):
     # what a decode step touches, from shapes: all three non-zero.  Two
     # periods: 6 Mamba-2 blocks, 2 attention blocks
     H, Pd, G, N = 8, 8, 4, 16
-    assert eng.state_bytes(2, P) == 6 * 2 * (
+    sizes = eng.dispatch_attrs((2, P), lens)
+    assert sizes["state_bytes"] == 6 * 2 * (
         H * Pd * N * 4 + 3 * (H * Pd + 2 * G * N) * 4)
-    assert eng.cache_bytes(2, P) == 2 * 2 * (2 * (P + T) * 2 * 16 * 4)
+    assert sizes["cache_bytes"] == 2 * 2 * (2 * (P + T) * 2 * 16 * 4)
     n_params = sum(x.size for x in jax.tree.leaves(params))
-    assert eng.weight_bytes() == 4 * n_params    # float32 at this size
+    assert sizes["weight_bytes"] == 4 * n_params    # float32 at this size
 
 
 @pytest.mark.parametrize("mixer,ffn", [("mamba2", None), (None, "experts"),
@@ -342,7 +343,7 @@ def test_a_block_with_an_absent_half(mixer, ffn):
     """``h + part(norm(h))`` once, not twice: the absent half adds
     nothing, has no parameters and (a mixer) caches nothing."""
     cfg = ModelConfig.tiny("nemotron_h", dtype="float32")
-    block = LatentBlock(cfg, mixer=mixer, ffn=ffn)
+    block = Block(cfg, mixer=mixer, ffn=ffn)
     x = jax.random.normal(jax.random.key(0), (2, 12, cfg.hidden_size))
     pos = jnp.broadcast_to(jnp.arange(12), (2, 12))
     params = block.init(jax.random.key(1), x, pos)["params"]
@@ -546,13 +547,19 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
     # half the heads, half the experts
     assert after["layers_0to1"]["attn"]["A_log"].shape == (2, 4)
     assert after["layers_0to1"]["mlp"]["experts_up_proj"].shape[:2] == (2, 4)
-    sizes = kept["trainer"]._rollout_bytes((4, 16), [16] * 4)
+    trainer = kept["trainer"]
+    sizes = trainer.engine.dispatch_attrs((4, 16), [16] * 4,
+                                          trainer.state.params)
     assert sizes["state_bytes"] > 0 and sizes["cache_bytes"] > 0 \
         and sizes["weight_bytes"] > 0
     assert sizes["kda_step"] == ""
-    from orion_tpu.trainers.base import share_counters
+    from orion_tpu.models.transformer import update_attrs
 
-    assert share_counters(kept["trainer"].cfg.model) == {
+    def share_counters(cfg_model):
+        return {k: v for k, v in update_attrs(cfg_model, [16] * 4).items()
+                if k.endswith("_held")}
+
+    assert share_counters(trainer.cfg.model) == {
         "heads_held": 4, "groups_held": 2, "attn_heads_held": 2,
         "kv_heads_held": 1, "experts_held": 4}
     assert share_counters(ModelConfig.tiny("deepseek_v3")) == {}

@@ -276,11 +276,12 @@ def test_the_engine_decodes_through_state_and_per_head_cache(tiny, step,
                                    rtol=0)
     # what a decode step touches, from shapes: both kinds non-zero
     H, dk, dv = 3, 12, 24
-    assert eng.cache_bytes(2, P) == 2 * 2 * (2 * (P + T) * 4 * 16 * 4)
-    assert eng.state_bytes(2, P) == 6 * 2 * (
+    sizes = eng.dispatch_attrs((2, P), lens)
+    assert sizes["cache_bytes"] == 2 * 2 * (2 * (P + T) * 4 * 16 * 4)
+    assert sizes["state_bytes"] == 6 * 2 * (
         H * dk * dv * 4 + 3 * H * (2 * dk + dv) * 4)
     n_params = sum(x.size for x in jax.tree.leaves(params))
-    assert eng.weight_bytes() == 4 * n_params    # float32 at this size
+    assert sizes["weight_bytes"] == 4 * n_params    # float32 at this size
 
 
 def test_ppo_iteration_through_the_launcher(tmp_path):
@@ -319,7 +320,8 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
             after["layers_0to2"]["attn"][name])
             - before["layers_0to2"]["attn"][name]))
         assert moved > 0, name
-    sizes = kept["trainer"]._rollout_bytes((4, 16), [16] * 4)
+    sizes = kept["trainer"].engine.dispatch_attrs(
+        (4, 16), [16] * 4, kept["trainer"].state.params)
     assert sizes["state_bytes"] > 0 and sizes["cache_bytes"] > 0 \
         and sizes["weight_bytes"] > 0
     assert sizes["kda_step"] == "jnp"            # the CPU's form

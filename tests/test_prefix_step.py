@@ -300,7 +300,8 @@ def test_step_read_from_lengths(lens, Lmax, T, form, slots):
     """What the engine says of the one-token step for ``rollout.
     dispatch``: its form and the slots one row's step reads a layer, the
     mean over the steps (346-odd of 1024 in the Olmo cell)."""
-    got = _engine().kv_step_read(lens, Lmax - T, T)
+    got = _engine(max_prompt_len=Lmax - T, max_new_tokens=T) \
+        .dispatch_attrs((len(lens), Lmax - T), lens)
     assert got["kv_step_form"] == form
     assert got["kv_step_slots"] == pytest.approx(slots)
     assert isinstance(got["kv_step_slots"], float)
@@ -310,11 +311,12 @@ def test_step_read_from_lengths(lens, Lmax, T, form, slots):
     ("llama", {"paged": True}), ("keye_dsa", {}), ("deepseek_v3", {})],
     ids=["paged", "a_selection", "latent"])
 def test_the_engine_says_nothing_where_no_step_reads_a_prefix(arch, rollout):
-    """{} under ``paged`` and for a model none of whose mixers goes
-    through ``prefix_step`` (``PREFIX_STEP_MIXERS``)."""
+    """Nothing under ``paged`` and for a model none of whose mixers
+    goes through ``prefix_step`` (``Kind.steps_over_prefix``)."""
     got = _engine(arch, max_prompt_len=232, max_new_tokens=24,
-                  **rollout).kv_step_read([100], 232)
-    assert bool(got) == (arch == "deepseek_v3")
+                  **rollout).dispatch_attrs((1, 232), [100])
+    assert ("kv_step_form" in got) == ("kv_step_slots" in got) \
+        == (arch == "deepseek_v3")
 
 
 @pytest.mark.parametrize("preset, extra, want", [
