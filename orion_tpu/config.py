@@ -22,10 +22,11 @@ from typing import Any, Optional
 LATENT_ARCHS = ("deepseek_v3", "kimi_linear")
 #: The archs whose model is a per-layer pattern of (mixer, FFN) kinds
 #: over RMSNorm blocks (``ModelConfig.layer_kinds``).
-PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa", "nemotron_h")
+PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa", "nemotron_h",
+                                "sdar_moe")
 #: The archs whose layers end in the dropless expert layer
 #: (``ops.moe.TopKMoE``) and so share its fields and their checks.
-EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa", "nemotron_h")
+EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa", "nemotron_h", "sdar_moe")
 #: nemotron_h's ``hybrid_override_pattern`` characters -> (mixer, ffn)
 #: halves of a block.
 PATTERN_HALVES = {"M": ("mamba2", None), "*": ("attention", None),
@@ -37,7 +38,7 @@ LAYER_TYPE_MIXERS = {"linear_attention": "gdn", "full_attention": "attention"}
 @dataclass
 class ModelConfig:
     """Architecture hyperparameters for the decoder-only transformer:
-    flat fields under the published key names of seven model families,
+    flat fields under the published key names of eight model families,
     a ``_check_<arch>`` each, and the model's description derived from
     them, :meth:`layer_kinds`: one (mixer, feed-forward) pair per block.
     What follows from a kind is ``models/transformer.py``'s
@@ -46,7 +47,7 @@ class ModelConfig:
 
     # the family whose published keys and checks apply: "llama" | "neox"
     # | "deepseek_v3" | "kimi_linear" | "olmo_hybrid" | "keye_dsa"
-    # | "nemotron_h"
+    # | "nemotron_h" | "sdar_moe"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -194,6 +195,25 @@ class ModelConfig:
     # shares outnumber them).  What the absent heads would add to a
     # mixer's output is left out, as for the experts.  (0, 1): all.
     head_share: tuple = (0, 1)
+    # arch="sdar_moe" (SDAR's language model): keye_dsa's block without
+    # the indexer (grouped-query attention with the per-head q/k norm
+    # over softmax-routed experts) that GENERATES BY DIFFUSION OVER
+    # BLOCKS.  Positions fall into blocks of block_length, aligned to
+    # position 0; a key is visible to a query iff its block is not a
+    # later one (causal across blocks, both directions inside one); the
+    # logit AT a position scores the token AT it (no shift).  A block
+    # starts as mask_token_id wherever the prompt does not reach and is
+    # revealed over denoising_steps forwards, block_length /
+    # denoising_steps positions a forward, those of the highest
+    # confidence (rollout/engine.py); a completion's log-probabilities
+    # are those of its sampling trace (trainers/base.py).  These three
+    # are the model's description, not a speed knob: no flag turns the
+    # rule off.  mask_token_id counts from the vocabulary's end when
+    # negative (-1: its last row, whatever slice of it a chip holds:
+    # ``mask_id``).
+    block_length: int = 0
+    denoising_steps: int = 0
+    mask_token_id: int = -1
 
     def __post_init__(self) -> None:
         if self.arch in EXPERT_ARCHS:
@@ -204,6 +224,12 @@ class ModelConfig:
             self._check_keye_dsa()
         if self.arch == "kimi_linear":
             self._check_kimi_linear()
+        if self.arch == "sdar_moe":
+            self._check_sdar_moe()
+        elif self.block_length or self.denoising_steps:
+            raise ValueError(
+                f"model.block_length={self.block_length}: only arch="
+                "'sdar_moe' generates by diffusion over blocks")
         if self.arch == "olmo_hybrid":
             self._check_olmo_hybrid()
         self.head_share = tuple(self.head_share)
@@ -280,6 +306,25 @@ class ModelConfig:
                 "(first_k_dense_replace = 0, the published "
                 "decoder_sparse_step 1 / mlp_only_layers []), and the "
                 "indexer scores whole sequences (seq_shard_activations)")
+
+    def _check_sdar_moe(self) -> None:
+        bd, steps = self.block_length, self.denoising_steps
+        if bd < 1 or steps < 1 or bd % steps:
+            raise ValueError(
+                f"arch='sdar_moe' needs model.block_length >= 1 and "
+                f"model.denoising_steps dividing it (a forward reveals "
+                f"block_length / denoising_steps positions), got {bd}, "
+                f"{steps}")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("arch='sdar_moe': num_kv_heads divides "
+                             "num_heads (grouped-query attention)")
+        if self.seq_shard_activations or self.first_k_dense_replace:
+            raise ValueError(
+                "arch='sdar_moe': every layer is an expert layer "
+                "(first_k_dense_replace = 0, the published "
+                "decoder_sparse_step 1 / mlp_only_layers []), and a "
+                "block's positions see each other in both directions "
+                "(seq_shard_activations)")
 
     def _check_nemotron_h(self) -> None:
         for key in ("mamba_num_heads", "mamba_head_dim", "mamba_n_groups",
@@ -375,6 +420,20 @@ class ModelConfig:
         OLMo 2 / 3 order)."""
         return self.arch == "olmo_hybrid"
 
+    @property
+    def mask_id(self) -> int:
+        """The id a block-diffusion model shows for a position not yet
+        revealed: ``mask_token_id``, from the end of the vocabulary held
+        here when negative."""
+        return self.mask_token_id % self.vocab_size
+
+    def blocks_spanned(self, new_tokens: int) -> int:
+        """The most blocks of ``block_length`` that ``new_tokens``
+        consecutive positions lie in, wherever they start: what a
+        block-diffusion rollout's loop runs at most and a trace
+        forward's noisy streams hold a row."""
+        return (new_tokens + self.block_length - 2) // self.block_length + 1
+
     def _kinds(self) -> tuple:
         from orion_tpu.models.transformer import kinds
 
@@ -443,6 +502,8 @@ class ModelConfig:
                          for t in self.layer_types[:self.num_layers])
         if self.arch == "keye_dsa":
             return (("sparse", "experts"),) * self.num_layers
+        if self.arch == "sdar_moe":
+            return (("attention", "experts"),) * self.num_layers
         return tuple(
             ("kda" if i + 1 in self.kda_layers else "latent",
              "dense" if i < self.first_k_dense_replace else "experts")
@@ -569,6 +630,29 @@ class ModelConfig:
         )
 
     @staticmethod
+    def sdar_30b_a3b() -> "ModelConfig":
+        """JetLM/SDAR-30B-A3B-Chat as published (config.json, model_type
+        sdar_moe): every expert held.  config.json is silent on the
+        generation rule: blocks of 4 revealed in 4 steps are the
+        family's released defaults, and the mask is the vocabulary's
+        last row (the tokenizer's own id lies outside a slice of it)."""
+        return ModelConfig(
+            arch="sdar_moe", vocab_size=151936, hidden_size=2048,
+            intermediate_size=6144, num_layers=48, num_heads=32,
+            num_kv_heads=4, head_dim=128, max_seq_len=32768,
+            rope_theta=1e6, rms_norm_eps=1e-6, n_routed_experts=128,
+            num_experts_per_tok=8, moe_intermediate_size=768,
+            moe_scoring="softmax", block_length=4, denoising_steps=4,
+            mask_token_id=-1,
+        )
+
+    @staticmethod
+    def tiny_sdar_moe() -> "ModelConfig":
+        """``model_preset=tiny_sdar_moe``: the small sibling of
+        sdar_30b_a3b (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("sdar_moe")
+
+    @staticmethod
     def nemotron_3_super_120b_a12b() -> "ModelConfig":
         """nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16 as published
         (config.json, model_type nemotron_h): every head and expert
@@ -639,6 +723,19 @@ class ModelConfig:
                 n_shared_experts=1, moe_intermediate_size=48,
                 moe_shared_expert_intermediate_size=80, moe_latent_size=32,
                 moe_activation="relu2", routed_scaling_factor=5.0,
+            )
+            base.update(kw)
+            return ModelConfig(**base)
+        if arch == "sdar_moe":
+            # blocks of 4 revealed one position a step; 2 query heads a
+            # key/value head; the mask is id 255
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=96, num_layers=2, num_heads=4,
+                num_kv_heads=2, head_dim=16, max_seq_len=128,
+                rope_theta=1e6, rms_norm_eps=1e-6, n_routed_experts=8,
+                num_experts_per_tok=2, moe_intermediate_size=32,
+                moe_scoring="softmax", block_length=4, denoising_steps=4,
             )
             base.update(kw)
             return ModelConfig(**base)

@@ -43,11 +43,11 @@ class ActorCriticModel(nn.Module):
     def __call__(self, input_ids, positions, cache=None,
                  with_values: bool = False, skip_lm_head: bool = False,
                  logits_positions=None, token_mask=None,
-                 remat_keep: tuple = ()):
+                 remat_keep: tuple = (), visible=None):
         logits, new_cache, hidden = Transformer(self.cfg, name="backbone")(
             input_ids, positions, cache, return_hidden=True,
             skip_lm_head=skip_lm_head, logits_positions=logits_positions,
-            token_mask=token_mask, remat_keep=remat_keep)
+            token_mask=token_mask, remat_keep=remat_keep, visible=visible)
         vk = self.param(
             "value_head",
             nn.with_logical_partitioning(

@@ -47,6 +47,11 @@ def hf_state_dict(params: dict, cfg: ModelConfig) -> Dict[str, np.ndarray]:
             "kimi_linear checkpoint layout on either side yet (the KDA "
             "layers' convolutions [channels, 1, taps], low-rank gates, "
             "A_log and dt_bias; per-expert tensors; kv_a/kv_b projections)")
+    if cfg.arch == "sdar_moe":
+        raise ValueError(
+            "HF export of arch='sdar_moe' is not written: there is no "
+            "sdar_moe checkpoint layout on either side yet (per-expert "
+            "tensors, the tokenizer's mask id against mask_token_id)")
     if cfg.arch == "keye_dsa":
         raise ValueError(
             "HF export of arch='keye_dsa' is not written: there is no "

@@ -77,6 +77,12 @@ _NO_KEYE_LOADER = (
     "onto models.transformer.SparseAttention's and ops.moe.TopKMoE's "
     "names; arch='keye_dsa' runs from random weights only")
 
+_NO_SDAR_LOADER = (
+    "there is no sdar_moe checkpoint loader yet: the per-expert tensors "
+    "have no mapping onto ops.moe.TopKMoE's names and the tokenizer's mask "
+    "id none onto model.mask_token_id; arch='sdar_moe' runs from random "
+    "weights only")
+
 
 def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
                           include_lm_head: bool = True) -> dict:
@@ -92,6 +98,8 @@ def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
         raise ValueError(_NO_OLMO_HYBRID_LOADER)
     elif cfg.arch == "keye_dsa":
         raise ValueError(_NO_KEYE_LOADER)
+    elif cfg.arch == "sdar_moe":
+        raise ValueError(_NO_SDAR_LOADER)
     elif cfg.arch == "nemotron_h":
         raise ValueError(_NO_NEMOTRON_H_LOADER)
     else:
@@ -254,6 +262,8 @@ def config_from_hf(hf_cfg: Any) -> ModelConfig:
         raise ValueError(_NO_OLMO_HYBRID_LOADER)
     if mt in ("KeyeVL2", "keye_vl2"):
         raise ValueError(_NO_KEYE_LOADER)
+    if mt == "sdar_moe":
+        raise ValueError(_NO_SDAR_LOADER)
     if mt == "nemotron_h":
         raise ValueError(_NO_NEMOTRON_H_LOADER)
     if mt == "llama":

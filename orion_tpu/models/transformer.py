@@ -44,13 +44,15 @@ from functools import partial
 from typing import Any, List, Optional
 
 import flax.linen as nn
+import flax.struct
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from orion_tpu.config import ModelConfig
-from orion_tpu.ops.attention import _NEG_INF, attention, step_attention
+from orion_tpu.ops.attention import (_NEG_INF, attention, step_attention,
+                                     streams_attention)
 from orion_tpu.ops.paged_kv import is_paged, write_paged_tokens
 from orion_tpu.ops.rotary import apply_rotary
 
@@ -236,11 +238,74 @@ def _norm(cfg, name):
         name=name)
 
 
-def _cache_writer(positions, B: int, L: int):
+@flax.struct.dataclass
+class Visible:
+    """What a block-diffusion model's attention masks by, beside the
+    positions it rotates by (``ModelConfig.block_length``).  A row is
+    ``[clean stream ; noisy streams]``: its first ``clean`` entries hold
+    tokens at slot == position and are the keys every query may see;
+    the rest, none outside a trainer's trace forward, whole groups of
+    ``block`` entries, each one block of one noisy stream at its true
+    positions.  A query sees the clean key at slot j iff ``j <= see``
+    (the last position of its block under the clean rule: causal across
+    blocks, both directions inside one; the last position BEFORE its
+    block for a noisy query), and a noisy query also its own group."""
+
+    see: jnp.ndarray                                      # [B, L] int
+    clean: int = flax.struct.field(pytree_node=False)
+    block: int = flax.struct.field(pytree_node=False)
+
+
+def clean_rule(positions, block: int) -> Visible:
+    """The clean stream's rule on ``positions`` [B, L]: key j is visible
+    to the query at p iff ``j // block <= p // block``."""
+    return Visible(see=positions // block * block + (block - 1),
+                   clean=positions.shape[1], block=block)
+
+
+def trace_row_length(cfg: ModelConfig, seq_len: int, new_tokens: int) -> int:
+    """The entries of one row of a block-diffusion model's trace forward
+    (:func:`trace_inputs`): the clean stream's ``seq_len``, one noisy
+    stream a denoising step of every block ``new_tokens`` completion
+    positions can lie in, and what the kernels' tiling pads that by."""
+    from orion_tpu.ops.attention import noisy_length
+
+    noisy = (cfg.denoising_steps * cfg.blocks_spanned(new_tokens)
+             * cfg.block_length)
+    return seq_len + noisy_length(noisy, cfg.block_length)
+
+
+def trace_inputs(cfg: ModelConfig, sequences, prompt_lens, reveal_step):
+    """(ids, positions, keywords) of the ONE forward that scores a
+    block-diffusion model's completions along their sampling trace
+    (``ops.logprobs.trace_streams`` has the layout): the keywords are
+    ``logits_positions`` (the T noisy entries (step, position) that
+    logits and values are read at), ``token_mask`` and ``visible``."""
+    from orion_tpu.ops.logprobs import trace_streams
+
+    L, T = sequences.shape[1], reveal_step.shape[1]
+    row = trace_streams(
+        sequences, prompt_lens, reveal_step, cfg.block_length,
+        cfg.denoising_steps, cfg.mask_id, cfg.blocks_spanned(T),
+        trace_row_length(cfg, L, T) - L)
+    return row["ids"], row["positions"], {
+        "logits_positions": row["read_at"], "token_mask": row["token_mask"],
+        "visible": Visible(see=row["see"], clean=L, block=cfg.block_length)}
+
+
+def _cache_writer(positions, B: int, L: int, step: bool = False):
     """``write(cache, new)``: the L new entries of every sequence at
-    slots starting at ``positions[:, 0]``."""
+    slots starting at ``positions[:, 0]``.  ``step``: the L entries are
+    one step of a decode loop (a block-diffusion model's block)."""
     starts = positions[:, 0]
-    if L == 1:
+    if step and L > 1:
+        # one batched scatter with unique indices, as the one-token step
+        rows = jnp.arange(B)[:, None]
+        slots = starts[:, None] + jnp.arange(L, dtype=starts.dtype)
+
+        def write(cache, new):
+            return cache.at[rows, slots].set(new, unique_indices=True)
+    elif L == 1:
         # Decode: ONE batched scatter with unique indices.  The
         # vmap(dynamic_update_slice) form lowers to a serial
         # scatter-WHILE per array on TPU — profiled at 5.2 ms of
@@ -384,7 +449,7 @@ class Attention(nn.Module, Kind):
         return q, k, v
 
     @nn.compact
-    def __call__(self, x, positions, layer_cache=None):
+    def __call__(self, x, positions, layer_cache=None, visible=None):
         """x: [B, L, E]; positions: [B, L] absolute positions.
 
         layer_cache: {"k","v"} [B, Lmax, Hkv, D] or None.  When a cache
@@ -394,13 +459,23 @@ class Attention(nn.Module, Kind):
         decode (positions = current lengths).  The cache is dense
         ([B, Lmax] slots a layer, int8 with scales under
         ``quantize_kv``); a one-token step reads its filled prefix in
-        blocks (:func:`prefix_step`).
+        blocks (:func:`prefix_step`), and so does the step of a
+        block-diffusion model, ``block_length`` tokens a row.
+        ``visible`` (:class:`Visible`): what to mask by where that is
+        not ``positions``; a block-diffusion model given none masks by
+        the clean rule (:func:`clean_rule`).
         Returns (out [B, L, E], new_layer_cache).
         """
         cfg = self.cfg
         B, L, _ = x.shape
         H, D = cfg.heads_held()["q"], cfg.head_dim
         q, k, v = self.qkv(x, positions)
+        if visible is None and cfg.block_length:
+            visible = clean_rule(positions, cfg.block_length)
+        # a query sees the slots up to ``see``
+        see = positions if visible is None else visible.see
+        # one step of a decode loop: one token, or one block's
+        step = layer_cache is not None and L in (1, cfg.block_length)
 
         scale = 1.0 / D ** 0.5
         paged_decode_out = None
@@ -429,7 +504,7 @@ class Attention(nn.Module, Kind):
                 from orion_tpu.ops.paged_kv import gather_paged_kv
                 keys, values = gather_paged_kv(new_cache, _dt(cfg.dtype))
         elif layer_cache is not None:
-            write = _cache_writer(positions, B, L)
+            write = _cache_writer(positions, B, L, step)
 
             if "k_scale" in layer_cache:
                 # int8 KV cache (RolloutConfig.quantize_kv): quantize
@@ -469,13 +544,13 @@ class Attention(nn.Module, Kind):
         # all of them.
         def mask(slots):
             key_slots = jnp.arange(slots, dtype=positions.dtype)
-            return key_slots[None, None, :] <= positions[:, :, None]
+            return key_slots[None, None, :] <= see[:, :, None]
 
         if paged_decode_out is not None:
             out = paged_decode_out[:, None, :, :]
-        elif L == 1 and new_cache is not None:
-            # one new token against the dense slot cache, int8 or not:
-            # over the filled prefix of its slots
+        elif step and not is_paged(layer_cache):
+            # one new token (one block's) against the dense slot cache,
+            # int8 or not: over the filled prefix of its slots
             Lmax = new_cache["k"].shape[1]
             whole = mask(Lmax)
 
@@ -485,11 +560,17 @@ class Attention(nn.Module, Kind):
                     q, c["k"], c["v"], whole[..., :m], scale,
                     c.get("k_scale"), c.get("v_scale"))
 
-            out = prefix_step(positions, Lmax, attend)
+            out = prefix_step(see, Lmax, attend)
+        elif visible is not None and visible.clean < L:
+            # a trainer's trace forward: the clean stream and its noisy
+            # streams in one row, no cache
+            out = streams_attention(q, k, v, see, visible.clean,
+                                    visible.block, scale,
+                                    impl=cfg.attention_impl)
         else:
             out = attention(q, keys, values, mask(keys.shape[1]),
                             scale=scale, impl=cfg.attention_impl,
-                            q_positions=positions)
+                            q_positions=see)
         out = out.reshape(B, L, H * D)
         out = _dense(cfg.hidden_size, ("heads", "embed"),
                      cfg.attn_bias, cfg, "o_proj")(out)
@@ -1345,7 +1426,7 @@ def mixer_spec(cfg: ModelConfig, kind: str):
     if cfg.arch == "olmo_hybrid" and kind == "attention":
         # rope_theta is published null (0 here): no rotation
         kw = {"qk_norm": True, "rotary": cfg.rope_theta > 0}
-    elif cfg.arch == "keye_dsa":
+    elif cfg.arch in ("keye_dsa", "sdar_moe"):
         kw = {"qk_norm": "head"}
     return MIXERS[kind], kw
 
@@ -1367,11 +1448,40 @@ def kinds(cfg: ModelConfig) -> tuple:
                                + [ffn_class(f) for _, f in pairs if f]))
 
 
+#: What generation by diffusion over blocks (``ModelConfig.block_length``)
+#: has none of, whatever the layers' kinds: :func:`cannot_run`'s forms.
+BLOCK_DIFFUSION_LACKS = {
+    "continuous": "the continuous engine admits, steps and retires a slot "
+    "one token at a time; a block-diffusion slot's step is a block "
+    "(admission at block boundaries, a cache step of block_length rows) "
+    "and that engine has none",
+    "speculative": "a draft is verified against one next-token "
+    "distribution a row; a block is revealed out of order over several "
+    "forwards",
+    "paged": "the paged cache writes and the paged-attention kernel reads "
+    "one token a row a step; a block's step writes block_length slots and "
+    "sees them in both directions",
+    "quantize_kv": "a block's keys and values are rewritten at every "
+    "denoising step; an int8 cache under a step of block_length rows was "
+    "not run against the reference",
+    "quantize_weights": "the confidences that order a block's reveals "
+    "would come from int8 weights and the update's trace "
+    "log-probabilities from the float ones: another trace",
+    "sequence_parallel": "the sequence-parallel attentions apply the "
+    "causal rule by position; a block's positions see each other in both "
+    "directions and a noisy stream sees its own block beside the clean "
+    "stream's earlier ones",
+}
+
+
 def cannot_run(cfg: ModelConfig, form: str) -> Optional[str]:
-    """Why ``cfg``'s layers cannot run under ``form`` (the keys of
-    :meth:`Kind.lacks`): every kind's reason, each once; None where
-    every kind has the form."""
+    """Why ``cfg``'s model cannot run under ``form`` (the keys of
+    :meth:`Kind.lacks`): every layer kind's reason, each once, and the
+    generation rule's (:data:`BLOCK_DIFFUSION_LACKS`); None where
+    nothing stands in the way."""
     reasons = dict.fromkeys(kind.lacks(cfg).get(form) for kind in kinds(cfg))
+    if cfg.block_length:
+        reasons[BLOCK_DIFFUSION_LACKS.get(form)] = None
     return "; ".join(r for r in reasons if r) or None
 
 
@@ -1396,18 +1506,89 @@ def decode_attrs(cfg: ModelConfig, lens=None, slots: int = 0,
             kv_step_slots=prefix_step_slots(lens, slots, new_tokens))
     for kind in of:
         attrs.update(kind.decode_attrs(cfg, lens, slots, new_tokens))
+    if cfg.block_length:
+        attrs.update(block_decode_attrs(cfg, lens, slots, new_tokens))
     return attrs
 
 
-def update_attrs(cfg: ModelConfig, total_lens) -> dict:
+def block_decode_attrs(cfg: ModelConfig, lens, slots: int,
+                       new_tokens: int) -> dict:
+    """What ``rollout.dispatch`` carries of a block-diffusion rollout
+    after prompts of ``lens`` real tokens, where no row stops early:
+    ``blocks`` the loop runs (the row that spans most decides),
+    ``denoise_forwards`` of ``block_length`` positions a row
+    (``denoising_steps`` + the commit, a block), ``kv_step_slots``,
+    the slots one of them reads a layer (mean): its prefix of
+    :func:`prefix_lengths` holds the block's last position, and
+    ``decode_pairs``, the (query, key) pairs of the prompts' real
+    tokens in prefill and of every forward of every row's own blocks
+    (a query has the keys through its block's end)."""
+    Bd, S = cfg.block_length, cfg.denoising_steps
+    lens = np.asarray(lens, np.int64)
+    need = (lens % Bd + new_tokens - 1) // Bd + 1        # blocks a row
+    blocks = int(np.max(need))
+    ms = np.asarray(prefix_lengths(slots))
+    ends = (int(np.max(lens // Bd)) + np.arange(blocks)) * Bd + Bd - 1
+    pairs = sum(
+        int(_seen_keys(np.arange(n), Bd).sum()
+            + (S + 1) * _seen_keys(np.arange(
+                n // Bd * Bd, (n // Bd + k) * Bd), Bd).sum())
+        for n, k in zip(lens, need))
+    return {"block_length": Bd, "denoising_steps": S, "blocks": blocks,
+            "denoise_forwards": blocks * (S + 1), "decode_pairs": pairs,
+            "kv_step_slots": float(
+                ms[np.minimum(ends // ms[0], len(ms) - 1)].mean())}
+
+
+def _seen_keys(positions, block: int):
+    """The clean keys a query at each of ``positions`` sees under the
+    clean rule: those through its block's end."""
+    return (np.asarray(positions, np.int64) // block + 1) * block
+
+
+def stream_attrs(cfg: ModelConfig, prompt_lens, seq_len: int,
+                 new_tokens: int) -> dict:
+    """What ONE trace forward of a block-diffusion model goes over for
+    sequences of ``seq_len`` entries after prompts of ``prompt_lens``
+    real tokens, ``new_tokens`` of them a completion's
+    (trainers/base.py::_trace_forward; the experience forwards and the
+    update's each make it): ``streams`` noisy streams a row, the
+    ``clean_tokens`` and ``noisy_tokens`` entries of all rows,
+    ``row_tokens``, all of a row's entries with what the noisy part is
+    padded by (``ops.attention.noisy_length``), and ``trace_pairs``, the
+    (query, key) pairs the two-part mask leaves the real entries: a
+    clean or a noisy query at position p has the ``(p // block + 1)
+    block`` keys through its block's end, clean ones and its own
+    block's.  {} for every other model."""
+    if not cfg.block_length:
+        return {}
+    Bd, S = cfg.block_length, cfg.denoising_steps
+    noisy = S * cfg.blocks_spanned(new_tokens) * Bd
+    pairs = 0
+    for n in np.asarray(prompt_lens, np.int64):
+        end = n + new_tokens
+        pairs += int(_seen_keys(np.arange(end), Bd).sum()
+                     + S * _seen_keys(np.arange(
+                         n // Bd * Bd, -(-end // Bd) * Bd), Bd).sum())
+    batch = len(prompt_lens)
+    return {"streams": S, "clean_tokens": batch * seq_len,
+            "noisy_tokens": batch * noisy, "trace_pairs": pairs,
+            "row_tokens": batch * trace_row_length(cfg, seq_len, new_tokens)}
+
+
+def update_attrs(cfg: ModelConfig, total_lens, prompt_lens=(),
+                 seq_len: int = 0, new_tokens: int = 0) -> dict:
     """What the ``update`` span carries of the model for a batch of
-    sequences of ``total_lens`` real tokens: ``attn_heads_a_step``,
+    sequences of ``total_lens`` real tokens (``prompt_lens`` of them the
+    prompts', padded to ``seq_len``, ``new_tokens`` a completion's
+    window): :func:`stream_attrs`, ``attn_heads_a_step``,
     ``kda_chunk`` (``""`` without a delta-rule layer), each mixer's own
     and, for a model held in part (``head_share``), what of every layer
     this chip holds (the benchmark's operation counts read it): the
     state-space layers' heads and groups, attention's query and
     key-value heads, the routed experts."""
-    attrs = {"kda_chunk": "", "attn_heads_a_step": cfg.attn_heads_a_step()}
+    attrs = {"kda_chunk": "", "attn_heads_a_step": cfg.attn_heads_a_step(),
+             **stream_attrs(cfg, prompt_lens, seq_len, new_tokens)}
     for kind in kinds(cfg):
         attrs.update(kind.forward_attrs(cfg, total_lens))
     if cfg.head_share != (0, 1):
@@ -1433,7 +1614,8 @@ class Block(nn.Module):
     ffn: Optional[str]
 
     @nn.compact
-    def __call__(self, x, positions, layer_cache=None, token_mask=None):
+    def __call__(self, x, positions, layer_cache=None, token_mask=None,
+                 visible=None):
         cfg = self.cfg
         if cfg.seq_shard_activations:
             from orion_tpu.parallel.sharding import \
@@ -1445,9 +1627,9 @@ class Block(nn.Module):
         def norm(name, t, after: bool = False):
             return _norm(cfg, name)(t) if after == cfg.post_norm else t
 
-        def run(kind, module, *args):
+        def run(kind, module, *args, **kw):
             more = (token_mask,) if kind.takes_token_mask else ()
-            return module(*args, *more)
+            return module(*args, *more, **kw)
 
         x = sp(x)
         h, new_cache = x, (None if layer_cache is None else {})
@@ -1455,7 +1637,8 @@ class Block(nn.Module):
             kind, kw = mixer_spec(cfg, self.mixer)
             attn_out, new_cache = run(
                 kind, kind(cfg, name="attn", **kw), norm("input_norm", x),
-                positions, layer_cache)
+                positions, layer_cache,
+                **({} if visible is None else {"visible": visible}))
             attn_out = norm("post_attn_norm", attn_out, after=True)
         if self.ffn is None:
             return sp(x + attn_out), new_cache
@@ -1534,7 +1717,8 @@ class Transformer(nn.Module):
                  return_hidden: bool = False, skip_lm_head: bool = False,
                  logits_positions: Optional[jnp.ndarray] = None,
                  token_mask: Optional[jnp.ndarray] = None,
-                 remat_keep: tuple = ()):
+                 remat_keep: tuple = (),
+                 visible: Optional[Visible] = None):
         """``logits_positions`` [B, T]: compute the vocab projection only
         at these sequence positions (ops.logprobs.completion_window_
         positions) — logits come back [B, T, V].  ``return_hidden``
@@ -1543,7 +1727,9 @@ class Transformer(nn.Module):
         positions hold a token; the expert layers route the others
         nowhere and the recurrent mixers leave their state untouched.
         ``remat_keep``: the :data:`REMAT_TAGS` a block's checkpoint
-        keeps (``cfg.remat``; nothing to a forward alone)."""
+        keeps (``cfg.remat``; nothing to a forward alone).
+        ``visible``: what a block-diffusion model's attention masks by
+        (:class:`Visible`; none: the clean rule on ``positions``)."""
         cfg = self.cfg
         embed = nn.Embed(
             num_embeddings=cfg.vocab_size, features=cfg.hidden_size,
@@ -1584,20 +1770,20 @@ class Transformer(nn.Module):
                     variable_axes={"params": 0, "intermediates": 0,
                                    "selections": 0},
                     split_rngs={"params": True},
-                    in_axes=(nn.broadcast, 0, nn.broadcast),
+                    in_axes=(nn.broadcast, 0, nn.broadcast, nn.broadcast),
                     out_axes=0,
                     length=length,
                     metadata_params={nn.meta.PARTITION_NAME: "layers"},
                 )
                 x, c = scan_block(cfg, mixer, ffn, name=stacks[first])(
-                    x, positions, rc, token_mask)
+                    x, positions, rc, token_mask, visible)
                 new_caches.append(c)
             else:
                 out = []
                 for j in range(length):
                     x, c = cls(cfg, mixer, ffn, name=f"layers_{first + j}")(
                         x, positions, None if rc is None else rc[j],
-                        token_mask)
+                        token_mask, visible)
                     out.append(c)
                 new_caches.append(out)
         new_cache = None if cache is None else _join_cache(cfg, new_caches)

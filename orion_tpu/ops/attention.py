@@ -9,6 +9,7 @@ repeated-KV expansion never materializes (see reference_attention_gqa).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -68,12 +69,16 @@ def step_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    mask: jnp.ndarray, scale: float,
                    k_scale: Optional[jnp.ndarray] = None,
                    v_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """ONE query a sequence over (a static prefix of) its slot cache:
-    a branch of models/transformer.py::prefix_step's ``lax.switch``.
+    """ONE query a sequence (or the few of one block of a
+    block-diffusion model: the step of its decode loop) over (a static
+    prefix of) its slot cache: a branch of
+    models/transformer.py::prefix_step's ``lax.switch``.
 
-    q [B, 1, H, D]; k, v [B, m, Hkv, D] in q's dtype, or int8
+    q [B, Lq, H, D]; k, v [B, m, Hkv, D] in q's dtype, or int8
     (RolloutConfig.quantize_kv) with k_scale, v_scale [B, m, Hkv] f32;
-    mask [B, 1, m].  The numbers of :func:`reference_attention_gqa`.
+    mask [B, Lq, m].  The numbers of :func:`reference_attention_gqa`.
+    The ``Lq`` queries of a key head's group stand as ``Lq * g`` rows
+    of one product.
     An int8 cache is never dequantized into a [B, m, Hkv, D] float
     copy: the per-token K scales multiply the *scores* and the V scales
     fold into the *probs* (both [B, Hkv, g, m]-sized), so the cache
@@ -86,10 +91,17 @@ def step_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     copied into, where this form reads the slice inside its fusion as
     ``q k^T`` does (PERF.md section 6, PR 43).  A group of query heads a
     key head makes it a matrix product: the einsum."""
-    B, _, H, D = q.shape
+    B, Lq, H, D = q.shape
     Hkv = k.shape[2]
     g = H // Hkv
-    qg = q.reshape(B, Hkv, g, D)
+    if Lq == 1:
+        qg = q.reshape(B, Hkv, g, D)
+    else:
+        # [B, Lq, Hkv, g, D] -> [B, Hkv, Lq g, D]: row = query * g + head
+        qg = q.reshape(B, Lq, Hkv, g, D).transpose(0, 2, 1, 3, 4).reshape(
+            B, Hkv, Lq * g, D)
+        mask = jnp.repeat(mask, g, axis=1)
+        g = Lq * g
     scores = jnp.einsum("bhgd,bkhd->bhgk", qg, k.astype(q.dtype),
                         preferred_element_type=jnp.float32) * scale
     if k_scale is not None:
@@ -104,7 +116,11 @@ def step_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       * v.transpose(0, 2, 1, 3).astype(jnp.float32), axis=2)
     else:
         out = jnp.einsum("bhgk,bkhd->bhgd", probs, v.astype(q.dtype))
-    return out.astype(q.dtype).reshape(B, 1, H, D)
+    out = out.astype(q.dtype)
+    if Lq == 1:
+        return out.reshape(B, 1, H, D)
+    return out.reshape(B, Hkv, Lq, H // Hkv, D).transpose(
+        0, 2, 1, 3, 4).reshape(B, Lq, H, D)
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -188,6 +204,170 @@ def sparse_attention(q, k, v, mask, sel_t, q_positions, scale: float,
         return sparse_attention_gqa(q, k, v, q_positions, sel_t, scale)
     return reference_attention_gqa(
         q, k, v, mask & (sel_t.swapaxes(1, 2) != 0), scale)
+
+
+def streams_mask(see, n_clean: int, block: int):
+    """[B, L, L] bool, the two-part mask of a block-diffusion training
+    row ``[clean stream (n_clean entries, slot == position) ; noisy
+    streams]``, the noisy part whole groups of ``block`` consecutive
+    entries (one block of one stream each): query i sees the clean key
+    j iff ``j <= see[b, i]``, and a noisy query also the keys of its own
+    group, itself included."""
+    L = see.shape[1]
+    idx = jnp.arange(L, dtype=see.dtype)
+    noisy = idx >= n_clean
+    group = (idx - n_clean) // block
+    own = noisy[:, None] & noisy[None, :] & (group[:, None] == group[None, :])
+    clean = ~noisy[None, None, :] & (idx[None, None, :] <= see[:, :, None])
+    return clean | own[None]
+
+
+def noisy_length(n: int, block: int) -> int:
+    """The entries a row's noisy part of ``n`` is padded to so that
+    :func:`streams_attention`'s kernels can tile it (every tile
+    dimension is a lane dimension somewhere) and it stays whole groups
+    of ``block``: one tile up to 128, beyond that a multiple of 128 and
+    of ``block``."""
+    lanes = math.lcm(128, block)
+    return n if n <= 128 else -(-n // lanes) * lanes
+
+
+def streams_attention(q, k, v, see, n_clean: int, block: int, scale: float,
+                      impl: str = "auto") -> jnp.ndarray:
+    """Attention under :func:`streams_mask` (the training forward of a
+    block-diffusion model: the clean stream and its noisy streams in one
+    row).  On one TPU device (``auto`` / ``flash``) as two attentions
+    merged by their log-sum-exp: the clean queries through the flash
+    kernels as they are, under the positional rule on ``see``; a noisy
+    query's clean keys through the same kernels' per-chunk entry
+    (``see`` is not monotone over several streams: the compute skip
+    alone) and its own group's few keys as a dense product
+    (:func:`noisy_streams_attention`).  Elsewhere, and under a mesh of
+    several devices (no shard_map was written for it), the einsum over
+    the mask."""
+    from orion_tpu.ops.pallas import target_platform
+    from orion_tpu.parallel.sharding import ambient_mesh
+
+    if impl in ("ring", "ulysses"):
+        raise ValueError(f"attention_impl={impl!r} takes no noisy streams")
+    mesh = ambient_mesh()
+    one_device = mesh is None or mesh.empty or mesh.size == 1
+    if not (one_device and (impl == "flash" or (
+            impl == "auto" and target_platform() == "tpu"))):
+        return reference_attention_gqa(
+            q, k, v, streams_mask(see, n_clean, block), scale)
+    from orion_tpu.ops.pallas.flash_attention import flash_attention_gqa
+
+    kc, vc = k[:, :n_clean], v[:, :n_clean]
+    with jax.named_scope("attn.clean"):
+        clean = flash_attention_gqa(q[:, :n_clean], kc, vc, see[:, :n_clean],
+                                    scale)
+    noisy = noisy_streams_attention(q[:, n_clean:], kc, vc, k[:, n_clean:],
+                                    v[:, n_clean:], see[:, n_clean:], scale,
+                                    block)
+    return jnp.concatenate([clean, noisy], axis=1)
+
+
+def _own_groups(q, k, v, block: int):
+    """The noisy part in its groups: q [B, G, block, Hkv, g, D], k and
+    v [B, G, block, Hkv, D]."""
+    B, Ln, H, D = q.shape
+    Hkv = k.shape[2]
+    G = Ln // block
+    return (q.reshape(B, G, block, Hkv, H // Hkv, D),
+            k.reshape(B, G, block, Hkv, D),
+            v.reshape(B, G, block, Hkv, v.shape[-1]))
+
+
+def _per_group(t, block: int, Hkv: int):
+    """[B, Ln, H] -> [B, G, Hkv, g, block], as the groups' scores lie."""
+    B, Ln, H = t.shape
+    return t.reshape(B, Ln // block, block, Hkv, H // Hkv).transpose(
+        0, 1, 3, 4, 2)
+
+
+def _noisy_fwd(qn, kc, vc, kn, vn, see, scale, block):
+    from orion_tpu.ops.pallas.flash_attention import flash_chunk_fwd
+
+    B, Ln, H, _ = qn.shape
+    Hkv = kn.shape[2]
+    f32 = jnp.float32
+    kv_positions = jnp.broadcast_to(
+        jnp.arange(kc.shape[1], dtype=see.dtype), kc.shape[:2])
+    with jax.named_scope("attn.noisy_clean"):
+        o1, lse1 = flash_chunk_fwd(qn, kc, vc, see, kv_positions, scale)
+    with jax.named_scope("attn.noisy_own"):
+        qg, kg, vg = _own_groups(qn, kn, vn, block)
+        s2 = jnp.einsum("bnqhgd,bnkhd->bnhgqk", qg, kg,
+                        preferred_element_type=f32) * scale
+        lse2 = jax.nn.logsumexp(s2, axis=-1).transpose(
+            0, 1, 4, 2, 3).reshape(B, Ln, H)
+        lse = jnp.logaddexp(lse1.transpose(0, 2, 1), lse2)   # [B, Ln, H]
+        p2 = jnp.exp(s2 - _per_group(lse, block, Hkv)[..., None])
+        o2 = jnp.einsum("bnhgqk,bnkhd->bnqhgd", p2.astype(qn.dtype), vg,
+                        preferred_element_type=f32).reshape(o1.shape)
+        w1 = jnp.exp(lse1.transpose(0, 2, 1) - lse)
+        out = (o1.astype(f32) * w1[..., None] + o2).astype(qn.dtype)
+    return out, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def noisy_streams_attention(qn, kc, vc, kn, vn, see, scale: float,
+                            block: int):
+    """The noisy queries of :func:`streams_attention`: qn [B, Ln, H, D]
+    against the clean keys kc, vc [B, Lc, Hkv, D] under ``slot <=
+    see`` (the flash kernels' per-chunk entry: chunk-normalised output
+    and log-sum-exp; a row that sees no clean key gives 0 and about
+    minus infinity, the merge's neutral element) and against the keys
+    of its own group of ``block`` entries of kn, vn [B, Ln, Hkv, D]
+    (dense: ``block`` keys a query), merged by the streaming softmax of
+    ``parallel/longctx``.  The backward runs the kernels' per-chunk
+    gradients against the MERGED log-sum-exp, which makes them exact,
+    and the group's part in ``jax.numpy`` from the same statistics.
+    ``Ln`` must tile as a ring chunk does (a multiple of 128, or one
+    block)."""
+    return _noisy_fwd(qn, kc, vc, kn, vn, see, scale, block)[0]
+
+
+def _noisy_vjp_fwd(qn, kc, vc, kn, vn, see, scale, block):
+    out, lse = _noisy_fwd(qn, kc, vc, kn, vn, see, scale, block)
+    return out, (qn, kc, vc, kn, vn, see, out, lse)
+
+
+def _noisy_vjp_bwd(scale, block, residuals, dout):
+    from orion_tpu.ops.pallas.flash_attention import flash_chunk_grads
+
+    qn, kc, vc, kn, vn, see, out, lse = residuals
+    Hkv = kn.shape[2]
+    f32 = jnp.float32
+    kv_positions = jnp.broadcast_to(
+        jnp.arange(kc.shape[1], dtype=see.dtype), kc.shape[:2])
+    with jax.named_scope("attn.noisy_clean"):
+        dq1, dkc, dvc = flash_chunk_grads(
+            qn, kc, vc, see, kv_positions, out, lse.transpose(0, 2, 1), dout,
+            scale)
+    with jax.named_scope("attn.noisy_own"):
+        qg, kg, vg = _own_groups(qn, kn, vn, block)
+        dog = dout.reshape(qg.shape[:-1] + (dout.shape[-1],))
+        s2 = jnp.einsum("bnqhgd,bnkhd->bnhgqk", qg, kg,
+                        preferred_element_type=f32) * scale
+        p2 = jnp.exp(s2 - _per_group(lse, block, Hkv)[..., None])
+        dp = jnp.einsum("bnqhgd,bnkhd->bnhgqk", dog, vg,
+                        preferred_element_type=f32)
+        delta = jnp.sum(dout.astype(f32) * out.astype(f32), axis=-1)
+        ds = (p2 * (dp - _per_group(delta, block, Hkv)[..., None])
+              * scale).astype(qn.dtype)
+        dq2 = jnp.einsum("bnhgqk,bnkhd->bnqhgd", ds, kg,
+                         preferred_element_type=f32).reshape(qn.shape)
+        dkn = jnp.einsum("bnhgqk,bnqhgd->bnkhd", ds, qg,
+                         preferred_element_type=f32).reshape(kn.shape)
+        dvn = jnp.einsum("bnhgqk,bnqhgd->bnkhd", p2.astype(qn.dtype), dog,
+                         preferred_element_type=f32).reshape(vn.shape)
+    return ((dq1.astype(f32) + dq2).astype(qn.dtype), dkc, dvc,
+            dkn.astype(kn.dtype), dvn.astype(vn.dtype), None)
+
+
+noisy_streams_attention.defvjp(_noisy_vjp_fwd, _noisy_vjp_bwd)
 
 
 def _flash_on_mesh(q, k, v, q_positions, scale):

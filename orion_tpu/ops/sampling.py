@@ -94,6 +94,16 @@ def is_stop_token(tokens: jnp.ndarray, eos_id,
     return done
 
 
+def bar_token(logits: jnp.ndarray, token_id: int) -> jnp.ndarray:
+    """``logits`` [..., V] with ``token_id`` out of the distribution:
+    the mask token of a block-diffusion model, which a denoiser never
+    emits.  It is barred in the POLICY itself, by the engine's draw and
+    by the trainers' log-probabilities alike (a large finite value: the
+    entropy stays finite), not in the sampling transform only as
+    ``forbid`` is."""
+    return logits.at[..., token_id].set(_NEG_INF.astype(logits.dtype))
+
+
 def transformed_logits(logits: jnp.ndarray, temperature: float,
                        top_k: int = 0, top_p: float = 1.0) -> jnp.ndarray:
     """The sampling-distribution transform pipeline of sample_tokens

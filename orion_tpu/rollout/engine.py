@@ -1,12 +1,26 @@
 """The fixed-batch rollout engine — TPU-native equivalent of the
 reference's vLLM generation engine (SURVEY.md §2 #5, §3c).
 
-One decode path (XLA-first, static shapes):
-- one jitted program per (batch, prompt_len, max_new_tokens) bucket:
-  prefill (full-seq forward filling the KV cache) then a
-  ``lax.while_loop`` of one-token steps with per-sequence EOS early
-  exit — the loop terminates as soon as every sequence is done, so
-  wall-clock tracks the longest completion, not the static bound;
+One jitted program per (batch, prompt_len, max_new_tokens) bucket
+(XLA-first, static shapes): prefill (full-seq forward filling the KV
+cache) then ONE ``lax.while_loop`` with per-sequence early exit — the
+loop terminates as soon as every sequence is done, so wall-clock tracks
+the longest completion, not the static bound.  Which loop follows from
+the model's description, not from a rollout flag:
+
+- an autoregressive model: one-token steps, a token a row a step;
+- a block-diffusion model (``ModelConfig.block_length``,
+  :meth:`RolloutEngine._generate_blocks`): a row's positions in blocks;
+  a block starts as the mask token wherever the prompt does not reach
+  and takes ``denoising_steps`` forwards of its ``block_length``
+  positions, each of which reveals the masked positions whose sampled
+  candidate is the most probable, then one more forward that commits
+  its keys and values: a step yields no fixed token a row, a row ends
+  at a block's end, and the result carries at which step every token
+  was revealed (``reveal_step``), without which no trainer can score
+  it.
+
+Both:
 - per-token logprobs captured in f32 under the *actual* sampling
   distribution (temperature/top-k/top-p applied), and the raw policy
   logprobs beside them;
@@ -21,12 +35,11 @@ One decode path (XLA-first, static shapes):
   it is not, {} for a block without a mixer), int8 under
   ``quantize_kv``, or paged under ``RolloutConfig.paged`` (block tables
   + the Pallas paged-decode kernel; slower than dense for a fixed
-  batch, ROADMAP D3(a)); what a model's kinds cannot run is refused
-  with their own reasons (models.transformer.cannot_run); a one-token
-  step reads the dense cache's filled prefix in blocks
-  (models.transformer.prefix_step: slots fill from 0 up, so what lies
-  past the batch's furthest position is never fetched).  The decode
-  loop is the same for every kind.
+  batch, ROADMAP D3(a)); what a model cannot run is refused with its
+  own reasons (models.transformer.cannot_run); a step reads the dense
+  cache's filled prefix in blocks (models.transformer.prefix_step:
+  slots fill from 0 up, so what lies past the batch's furthest position
+  is never fetched).
 
 Speculative decoding is not here: a lockstep batch advances at its
 slowest row's acceptance, and it lost on the chip (PERF.md section 6,
@@ -64,6 +77,10 @@ class GenerationResult:
     policy_logprobs: jnp.ndarray  # [B, T] f32 raw (untempered) policy logprobs
     prompt_lens: jnp.ndarray      # [B]
     total_lens: jnp.ndarray       # [B] prompt + completion lengths
+    # a block-diffusion model's sampling trace: [B, T] int, the
+    # denoising step of its block at which each completion position was
+    # revealed (``denoising_steps`` where it never was); None otherwise
+    reveal_step: Optional[jnp.ndarray] = None
 
     def _fields(self) -> dict:
         return {f.name: getattr(self, f.name)
@@ -90,8 +107,17 @@ class RolloutEngine:
         if cfg.speculative_k > 0:
             raise ValueError(
                 "rollout.speculative_k > 0 needs rollout.engine=continuous: "
-                "the fixed-batch engine has one decode path, one token a "
-                "step")
+                "the fixed-batch engine steps a lockstep batch, one token "
+                "or one block a row, and verifies no draft"
+                + (f" ({cannot_run(model_cfg, 'speculative')})"
+                   if model_cfg.block_length else ""))
+        if model_cfg.block_length and (
+                cfg.repetition_penalty != 1.0
+                or cfg.effective_min_new(eos_token_id) > 0):
+            raise ValueError(
+                "rollout.repetition_penalty / min_new_tokens are defined on "
+                "tokens generated in order; a block-diffusion model reveals "
+                "a block's positions out of order")
         self.eos_token_id = eos_token_id
         self.pad_token_id = pad_token_id
         self._params = None
@@ -168,9 +194,18 @@ class RolloutEngine:
             return {"cache_bytes": 0, "state_bytes": 0, **weights,
                     **decode_attrs(self.model_cfg)}
         T = self.cfg.max_new_tokens
-        slots = cache_slots(prompts_shape[1] + T)
+        slots = cache_slots(self._positions(prompts_shape[1], T))
         return {**self._cache_shapes(prompts_shape[0], slots), **weights,
                 **decode_attrs(self.model_cfg, lens, slots, T)}
+
+    def _positions(self, prompt_len: int, new_tokens: int) -> int:
+        """The positions a row of the cache must hold: prompt and new
+        tokens; for a block-diffusion model whole blocks, as far as a
+        lockstep row's last block can reach."""
+        mc = self.model_cfg
+        if not mc.block_length:
+            return prompt_len + new_tokens
+        return prompt_len + mc.blocks_spanned(new_tokens) * mc.block_length
 
     # -- generation -----------------------------------------------------
     def generate(self, prompt_ids: jnp.ndarray, prompt_lens: jnp.ndarray,
@@ -189,6 +224,9 @@ class RolloutEngine:
 
     def _generate(self, params, prompt_ids, prompt_lens, rng,
                   max_new_tokens: int):
+        if self.model_cfg.block_length:
+            return self._generate_blocks(params, prompt_ids, prompt_lens,
+                                         rng, max_new_tokens)
         cfg = self.cfg
         B, P = prompt_ids.shape
         T = max_new_tokens
@@ -314,4 +352,148 @@ class RolloutEngine:
             policy_logprobs=plogps,
             prompt_lens=prompt_lens,
             total_lens=prompt_lens + comp_len,
+        )
+
+    def _generate_blocks(self, params, prompt_ids, prompt_lens, rng,
+                         max_new_tokens: int):
+        """``_generate`` for a block-diffusion model (the same jitted
+        program, so the same name in a trace).  Rows run in lockstep,
+        row b's i-th block being block ``prompt_lens[b] // block_length
+        + i`` of its positions (blocks are aligned to position 0: the
+        prompt's last ``len % block_length`` tokens share the first
+        block with the first new ones).
+
+        Prefill writes the prompt's keys and values under the clean
+        rule.  Then per block: its state shows the prompt's tokens that
+        lie in it and the mask token elsewhere; ``denoising_steps``
+        forwards of its ``block_length`` positions against the cache of
+        the earlier blocks and its own slots (both directions), each
+        followed by a draw of one candidate a still-masked position from
+        ``softmax(logits / temperature)`` (top-k / top-p as for a
+        one-token step; the mask token's logit barred) and the reveal of
+        the ``block_length / denoising_steps`` masked positions whose
+        candidate is the most probable under that distribution (the
+        lowest position on a tie); the other candidates are thrown away.
+        One more forward then commits the block's keys and values.  A
+        position past ``prompt_len + max_new_tokens`` is never revealed
+        (it stays the mask token, so the trace of what was revealed is
+        whole), a row is done when a committed block holds a stop token
+        or its last new position.  What follows the first stop token has
+        completion mask 0; the tokens its block revealed there stay in
+        ``sequences`` (the states other tokens were drawn from showed
+        them) and are padding in ``completions``."""
+        from orion_tpu.ops.sampling import bar_token, is_stop_token
+
+        cfg, mc = self.cfg, self._decode_cfg
+        B, P = prompt_ids.shape
+        T = max_new_tokens
+        Bd, S, mask_id = mc.block_length, mc.denoising_steps, mc.mask_id
+        per_step = Bd // S
+        eos, pad = self.eos_token_id, self.pad_token_id
+        sample = partial(sample_tokens, temperature=cfg.temperature,
+                         top_k=cfg.top_k, top_p=cfg.top_p)
+        params = prep_decode_params(params, self.model_cfg, False)
+        apply = partial(self._decode_model.apply, {"params": params})
+
+        n_blocks = mc.blocks_spanned(T)         # the most a row needs
+        slots = self._positions(P, T)
+        cache = init_cache(mc, B, slots, dtype=jnp.dtype(mc.dtype))
+        positions = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32), (B, P))
+        with jax.named_scope("prefill"):
+            # the blocks the prompt fills are final; the slots of its
+            # last, partial block are written again by that block's own
+            # forwards before any query sees them
+            _, cache = apply(
+                prompt_ids, positions, cache,
+                logits_positions=jnp.zeros((B, 1), jnp.int32),
+                token_mask=positions < prompt_lens[:, None])
+
+        first = prompt_lens // Bd                       # [B] block index
+        last = prompt_lens + T                          # [B] one past
+        rows = jnp.arange(B)[:, None]
+        offs = jnp.arange(Bd, dtype=jnp.int32)
+
+        def blank(fill, dtype):
+            return jnp.full((B, slots), fill, dtype)
+
+        seq = blank(pad, jnp.int32).at[:, :P].set(jnp.where(
+            positions < prompt_lens[:, None], prompt_ids, pad))
+        out0 = {"seq": seq, "lp": blank(0.0, jnp.float32),
+                "plp": blank(0.0, jnp.float32), "step": blank(S, jnp.int32)}
+
+        def cond(c):
+            i, done = c[0], c[1]
+            return (i < n_blocks) & ~jnp.all(done)
+
+        def body(c):
+            i, done, comp_len, cache, rng, out = c
+            pos = (first + i)[:, None] * Bd + offs                # [B, Bd]
+            new = (pos >= prompt_lens[:, None]) & (pos < last[:, None])
+            z = jnp.where(pos < prompt_lens[:, None], out["seq"][rows, pos],
+                          mask_id)
+            rec = {"lp": jnp.zeros((B, Bd), jnp.float32),
+                   "plp": jnp.zeros((B, Bd), jnp.float32),
+                   "step": jnp.full((B, Bd), S, jnp.int32)}
+
+            def denoise(s, c):
+                z, masked, cache, rng, rec = c
+                with jax.named_scope("denoise"):
+                    logits, cache = apply(z, pos, cache)
+                rng, sub = jax.random.split(rng)
+                cand, lp, plp = sample(
+                    sub, bar_token(logits, mask_id).reshape(B * Bd, -1))
+                cand, lp, plp = (t.reshape(B, Bd) for t in (cand, lp, plp))
+                # the most probable candidates among the masked positions,
+                # the lower position first on a tie (top_k is stable)
+                conf = jnp.where(masked, jnp.exp(lp), -1.0)
+                top, where = jax.lax.top_k(conf, per_step)
+                reveal = jnp.zeros((B, Bd), bool).at[rows, where].set(
+                    top >= 0.0)
+                z = jnp.where(reveal, cand, z)
+                rec = {"lp": jnp.where(reveal, lp, rec["lp"]),
+                       "plp": jnp.where(reveal, plp, rec["plp"]),
+                       "step": jnp.where(reveal, s, rec["step"])}
+                return z, masked & ~reveal, cache, rng, rec
+
+            z, _, cache, rng, rec = jax.lax.fori_loop(
+                0, S, denoise, (z, new & ~done[:, None], cache, rng, rec))
+            with jax.named_scope("commit"):
+                _, cache = apply(z, pos, cache, skip_lm_head=True)[:2]
+            live = new & ~done[:, None]
+            # dropped where a row is done or the position is not new
+            at = jnp.where(live, pos, slots)
+            out = {"seq": out["seq"].at[rows, at].set(z, mode="drop"),
+                   **{n: out[n].at[rows, at].set(rec[n], mode="drop")
+                      for n in rec}}
+            stop = live & is_stop_token(z.reshape(-1), eos,
+                                        cfg.stop_token_ids).reshape(B, Bd)
+            # up to the first stop token of the block, else all of it
+            upto = jnp.where(jnp.any(stop, axis=1),
+                             pos[:, 0] + jnp.argmax(stop, axis=1) + 1,
+                             jnp.minimum(pos[:, -1] + 1, last))
+            comp_len = jnp.where(done, comp_len, upto - prompt_lens)
+            done = done | jnp.any(stop, axis=1) | (pos[:, -1] + 1 >= last)
+            return i + 1, done, comp_len, cache, rng, out
+
+        init = (jnp.int32(0), jnp.zeros((B,), bool),
+                jnp.zeros((B,), jnp.int32), cache, rng, out0)
+        with jax.named_scope("decode"):
+            _, _, comp_len, _, _, out = jax.lax.while_loop(cond, body, init)
+
+        at = prompt_lens[:, None] + jnp.arange(T)[None, :]
+        real = jnp.arange(T)[None, :] < comp_len[:, None]
+
+        def window(name):
+            return jnp.take_along_axis(out[name], at, axis=1)
+
+        return dict(
+            sequences=out["seq"][:, :P + T],
+            completions=jnp.where(real, window("seq"), pad),
+            completion_mask=real.astype(jnp.float32),
+            completion_lens=comp_len,
+            logprobs=jnp.where(real, window("lp"), 0.0),
+            policy_logprobs=jnp.where(real, window("plp"), 0.0),
+            prompt_lens=prompt_lens,
+            total_lens=prompt_lens + comp_len,
+            reveal_step=window("step"),
         )

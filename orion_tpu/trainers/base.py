@@ -25,7 +25,9 @@ import optax
 
 from orion_tpu.config import OptimizerConfig, TrainConfig
 from orion_tpu.models.transformer import (Transformer, kinds, remat_keep,
-                                         remat_tag_bytes, update_attrs)
+                                         remat_tag_bytes, stream_attrs,
+                                         trace_inputs, trace_row_length,
+                                         update_attrs)
 from orion_tpu.ops.logprobs import completion_logprobs, entropy_from_logits
 from orion_tpu.rollout import GenerationResult, RolloutEngine
 
@@ -231,6 +233,13 @@ def moe_load_stats(loads: list, pairs_per_layer: int,
         "moe_block_rows": jnp.float32(block_rows * load.shape[0]),
         "moe_blocks_max": blocks.astype(jnp.float32),
     }
+
+
+def _read_at(extra, read_at):
+    """A forward's per-position outputs beyond the logits ([B, row]: the
+    values) at ``read_at`` [B, T]; anything else as it is."""
+    return tuple(jnp.take_along_axis(x, read_at, axis=1)
+                 if getattr(x, "ndim", 0) == 2 else x for x in extra)
 
 
 class BaseTrainer:
@@ -467,7 +476,7 @@ class BaseTrainer:
 
     def _windowed_forward(self, params, sequences, prompt_lens,
                           max_new: int, with_entropy: bool = True,
-                          **apply_kw):
+                          reveal_step=None, **apply_kw):
         """Shared completion-window forward: the vocab projection runs
         only at the T completion positions (ops.logprobs.completion_
         window_positions) — the [B, L, V] f32 logits at full length are
@@ -475,10 +484,17 @@ class BaseTrainer:
         away (r3 perf).  Returns (lp [B,T], ent [B,T] | None, extra
         apply outputs, aux, moe) where ``extra`` carries whatever the
         module returned beyond logits (e.g. values for
-        ActorCriticModel) and ``aux``, ``moe`` are _policy_apply's."""
+        ActorCriticModel), each read at the T positions the logits are
+        (the value of completion token t: the hidden state its
+        log-probability is computed from), and ``aux``, ``moe`` are
+        _policy_apply's.  A block-diffusion model's completion is scored
+        along its sampling trace ``reveal_step`` (:meth:`_trace_forward`)."""
         from orion_tpu.ops.logprobs import (completion_window_positions,
                                             windowed_completion_logprobs)
 
+        if self.cfg.model.block_length:
+            return self._trace_forward(params, sequences, prompt_lens,
+                                       reveal_step, with_entropy, **apply_kw)
         L = sequences.shape[1]
         positions = jnp.broadcast_to(
             jnp.arange(L, dtype=jnp.int32), sequences.shape)
@@ -492,17 +508,63 @@ class BaseTrainer:
         out, aux, moe = self._policy_apply(
             params, sequences, positions, logits_positions=widx,
             **apply_kw)
-        logits_w, extra = out[0], out[1:]
+        logits_w, extra = out[0], _read_at(out[1:], widx)
         lp = windowed_completion_logprobs(logits_w, sequences, prompt_lens,
                                           max_new)
         ent = entropy_from_logits(logits_w) if with_entropy else None
         return lp, ent, extra, aux, moe
 
-    def _logprobs_fn(self, params, sequences, prompt_lens, max_new: int):
+    def _trace_forward(self, params, sequences, prompt_lens, reveal_step,
+                       with_entropy: bool = True, **apply_kw):
+        """:meth:`_windowed_forward` for a block-diffusion model: token p
+        of a completion is scored at its own position, given the state
+        of its block at the step it was revealed and the clean blocks
+        before it: by construction the distribution it was drawn from.
+        ONE forward does it for all tokens: the row ``[clean ; one noisy
+        stream a denoising step]`` under the two-part mask
+        (``models.transformer.trace_inputs``), logits and values read at
+        the T noisy entries (step(p), p) only.  The
+        mask token is barred from the distribution as the engine bars
+        it."""
+        from orion_tpu.ops.sampling import bar_token
+
+        mc = self.cfg.model
+        if reveal_step is None:
+            raise ValueError(
+                "a block-diffusion completion is scored along its sampling "
+                "trace: pass the rollout's reveal_step")
+        T = reveal_step.shape[1]
+        ids, positions, kw = trace_inputs(mc, sequences, prompt_lens,
+                                          reveal_step)
+        out, aux, moe = self._policy_apply(params, ids, positions, **kw,
+                                           **apply_kw)
+        logits_w = bar_token(out[0], mc.mask_id)
+        extra = _read_at(out[1:], kw["logits_positions"])
+        at = jnp.clip(prompt_lens[:, None] + jnp.arange(T)[None, :], 0,
+                      sequences.shape[1] - 1)
+        targets = jnp.take_along_axis(sequences, at, axis=1)
+        lp = jnp.take_along_axis(
+            jax.nn.log_softmax(logits_w, axis=-1), targets[..., None],
+            axis=-1)[..., 0]
+        ent = entropy_from_logits(logits_w) if with_entropy else None
+        return lp, ent, extra, aux, moe
+
+    @staticmethod
+    def _trace_kw(source, name: str = "reveal_step") -> dict:
+        """``{"reveal_step": ...}`` where ``source`` (a rollout's result
+        or a minibatch) carries a block-diffusion model's sampling trace
+        under ``name``; {} for every other model."""
+        trace = source.get(name) if isinstance(source, dict) \
+            else getattr(source, name, None)
+        return {} if trace is None else {"reveal_step": trace}
+
+    def _logprobs_fn(self, params, sequences, prompt_lens, max_new: int,
+                     reveal_step=None):
         """Completion logprobs + entropy (+ MoE aux loss and counters)
         under the training graph, over the completion window."""
         lp, ent, _, aux, moe = self._windowed_forward(
-            params, sequences, prompt_lens, max_new)
+            params, sequences, prompt_lens, max_new,
+            reveal_step=reveal_step)
         return lp, (ent, aux, moe)
 
     def loss_fn(self, params, mb: Dict[str, jnp.ndarray]):
@@ -639,7 +701,7 @@ class BaseTrainer:
         T = result.completions.shape[1]
         lp, _ = self._jit_logprobs(
             self.state.params, result.sequences, result.prompt_lens,
-            max_new=T)
+            max_new=T, **self._trace_kw(result))
         return lp
 
     def build_experience(self, result: GenerationResult, scores,
@@ -684,9 +746,17 @@ class BaseTrainer:
         host = GenerationResult(**fetched["r"])
         # what the update span and the row say of the model's forward
         # over this batch, from the lengths the fetch brought
-        self._update_attrs = update_attrs(self.cfg.model, host.total_lens)
+        shape = (host.prompt_lens, host.sequences.shape[1],
+                 host.completions.shape[1])
+        self._update_attrs = update_attrs(self.cfg.model, host.total_lens,
+                                          *shape)
         scores = self._score_result(result, host, meta)
-        with obs.span("experience.dispatch"):
+        with obs.span("experience.dispatch") as sp:
+            streams = stream_attrs(self.cfg.model, *shape)
+            if streams:     # what the rollout's forwards placed
+                streams["completion_tokens"] = int(
+                    np.sum(host.completion_lens))
+            sp.set(**streams)
             return self.build_experience(result, scores, host=host)
 
     def _fetch(self, tree: dict):
@@ -773,9 +843,15 @@ class BaseTrainer:
                 + mem.generated_code_size_in_bytes)
         budget = max(0, free - need - _REMAT_MARGIN_BYTES)
         seqs = [v for k, v in experience.items() if k.endswith("sequences")]
+        seq_len = max(v.shape[1] for v in seqs)
+        if self.cfg.model.block_length:
+            # the update's forward goes over the trace's whole row
+            new = next(v.shape[1] for k, v in experience.items()
+                       if k.endswith("mask"))
+            seq_len = trace_row_length(self.cfg.model, seq_len, new)
         tags = remat_tag_bytes(
             self.cfg.model, rows=idx_mat.shape[1] * len(seqs),
-            seq_len=max(v.shape[1] for v in seqs), lane=128)
+            seq_len=seq_len, lane=128)
         self._remat_keep = remat_keep(tags, budget)
         if self._remat_keep:
             program.clear_cache()    # traced with nothing kept

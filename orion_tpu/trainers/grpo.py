@@ -31,7 +31,8 @@ class GRPOTrainer(BaseTrainer):
         # behavior policy's logprobs (see BaseTrainer.behavior_logprobs).
         old_lp = self.behavior_logprobs(result)
         ref_lp, _ = self._jit_logprobs(
-            self.ref_params, result.sequences, result.prompt_lens, max_new=T)
+            self.ref_params, result.sequences, result.prompt_lens, max_new=T,
+            **self._trace_kw(result))
 
         adv_seq = grpo_advantages(
             jnp.asarray(scores), k,
@@ -46,6 +47,7 @@ class GRPOTrainer(BaseTrainer):
             # overflow exp() before the mask can zero the product.
             "ref_logprobs": ref_lp,
             "advantages": adv_seq[:, None] * result.completion_mask,
+            **self._trace_kw(result),
         }
         lens = (host or result).completion_lens
         stats = {  # host-side: no device fetches
@@ -58,7 +60,8 @@ class GRPOTrainer(BaseTrainer):
     def loss_fn(self, params, mb: Dict[str, jnp.ndarray]):
         T = mb["mask"].shape[1]
         lp, (ent, aux, moe) = self._logprobs_fn(
-            params, mb["sequences"], mb["prompt_lens"], max_new=T)
+            params, mb["sequences"], mb["prompt_lens"], max_new=T,
+            **self._trace_kw(mb))
         pg_loss, stats = ppo_policy_loss(
             lp, mb["old_logprobs"], mb["advantages"], mb["mask"],
             self.cfg.clip_ratio)

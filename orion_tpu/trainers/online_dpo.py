@@ -25,7 +25,8 @@ class OnlineDPOTrainer(BaseTrainer):
         host = host or result
         T = result.completions.shape[1]
         ref_lp, _ = self._jit_logprobs(
-            self.ref_params, result.sequences, result.prompt_lens, max_new=T)
+            self.ref_params, result.sequences, result.prompt_lens, max_new=T,
+            **self._trace_kw(result))
         # one scalar-array fetch (ref logprobs live on device)
         ref_seq_lp = jax.device_get(
             jnp.sum(ref_lp * result.completion_mask, axis=1))
@@ -57,6 +58,12 @@ class OnlineDPOTrainer(BaseTrainer):
             "ref_rejected_lp": jnp.asarray(ref_seq_lp[r_idx]),
             "pair_weight": jnp.asarray(pair_weight),
         }
+        if host.reveal_step is not None:
+            # a block-diffusion pair's sampling traces
+            steps = np.asarray(host.reveal_step)
+            experience.update(
+                chosen_reveal_step=jnp.asarray(steps[c_idx]),
+                rejected_reveal_step=jnp.asarray(steps[r_idx]))
         stats = {
             "reward_mean": float(scores.mean()),
             "reward_margin": float(
@@ -69,10 +76,11 @@ class OnlineDPOTrainer(BaseTrainer):
     def loss_fn(self, params, mb: Dict[str, jnp.ndarray]):
         T = mb["chosen_mask"].shape[1]
         c_lp, (_, c_aux, c_moe) = self._logprobs_fn(
-            params, mb["chosen_sequences"], mb["prompt_lens"], max_new=T)
+            params, mb["chosen_sequences"], mb["prompt_lens"], max_new=T,
+            **self._trace_kw(mb, "chosen_reveal_step"))
         r_lp, (_, r_aux, _) = self._logprobs_fn(
             params, mb["rejected_sequences"], mb["rejected_prompt_lens"],
-            max_new=T)
+            max_new=T, **self._trace_kw(mb, "rejected_reveal_step"))
         c_seq = jnp.sum(c_lp * mb["chosen_mask"], axis=1)
         r_seq = jnp.sum(r_lp * mb["rejected_mask"], axis=1)
         loss, stats = dpo_loss(

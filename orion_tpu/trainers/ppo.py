@@ -45,6 +45,13 @@ class PPOTrainer(BaseTrainer):
                  critic_model: Optional[ScalarHeadModel] = None,
                  critic_params: Any = None, **kw):
         super().__init__(cfg, model, params, **kw)
+        if cfg.model.block_length and (not cfg.share_backbone
+                                       or cfg.async_mode):
+            raise ValueError(
+                "a block-diffusion policy's values are read from the state "
+                "a token was revealed from, in the policy's own trace "
+                "forward: PPO needs share_backbone=true and the synchronous "
+                "loop (a separate critic has no trace forward)")
         if cfg.share_backbone:
             if critic_model is not None or critic_params is not None:
                 raise ValueError(
@@ -96,7 +103,8 @@ class PPOTrainer(BaseTrainer):
         return self._gather_completion(values, prompt_lens, mask)
 
     def _lp_values_fwd(self, params, sequences, prompt_lens, mask,
-                       max_new: int, with_entropy: bool = True):
+                       max_new: int, with_entropy: bool = True,
+                       reveal_step=None):
         """Shared-trunk forward: completion logprobs (+ entropy when the
         caller needs it — a full-vocab softmax reduce it should not pay
         for on the experience pass) AND values from one backbone pass.
@@ -105,10 +113,10 @@ class PPOTrainer(BaseTrainer):
         hidden states)."""
         lp, ent, extra, aux, moe = self._windowed_forward(
             params, sequences, prompt_lens, max_new,
-            with_entropy=with_entropy, with_values=True)
-        values = extra[0]
-        return (lp, ent,
-                self._gather_completion(values, prompt_lens, mask), aux, moe)
+            with_entropy=with_entropy, reveal_step=reveal_step,
+            with_values=True)
+        # read where the logits were (_windowed_forward)
+        return lp, ent, extra[0] * mask, aux, moe
 
     # ------------------------------------------------------------------
     def build_experience(self, result, scores, host=None):
@@ -118,7 +126,8 @@ class PPOTrainer(BaseTrainer):
             # One fused trunk pass yields old logprobs AND values.
             old_lp, _, values, _, _ = self._jit_lp_values(
                 self.state.params, result.sequences, result.prompt_lens,
-                mask, max_new=T, with_entropy=False)
+                mask, max_new=T, with_entropy=False,
+                **self._trace_kw(result))
         else:
             old_lp = self.behavior_logprobs(result)
             critic_params = (self.state.params if self.cfg.share_backbone
@@ -126,7 +135,8 @@ class PPOTrainer(BaseTrainer):
             values = self._jit_values(
                 critic_params, result.sequences, result.prompt_lens, mask)
         ref_lp, _ = self._jit_logprobs(
-            self.ref_params, result.sequences, result.prompt_lens, max_new=T)
+            self.ref_params, result.sequences, result.prompt_lens, max_new=T,
+            **self._trace_kw(result))
 
         kl = kl_penalty(old_lp, ref_lp, "k1") * mask
         # Logged below as `kl_coef`: the PRE-update coefficient — the one
@@ -166,6 +176,7 @@ class PPOTrainer(BaseTrainer):
             "old_values": values,
             "advantages": advantages,
             "returns": returns,
+            **self._trace_kw(result),
         }
         lens = (host or result).completion_lens
         stats = {
@@ -190,7 +201,7 @@ class PPOTrainer(BaseTrainer):
         T = mb["mask"].shape[1]
         lp, ent, values, aux, moe = self._lp_values_fwd(
             params, mb["sequences"], mb["prompt_lens"], mb["mask"],
-            max_new=T)
+            max_new=T, **self._trace_kw(mb))
         p_loss, p_stats = ppo_policy_loss(
             lp, mb["old_logprobs"], mb["advantages"], mb["mask"],
             self.cfg.clip_ratio)
@@ -205,7 +216,8 @@ class PPOTrainer(BaseTrainer):
     def _policy_loss(self, params, mb):
         T = mb["mask"].shape[1]
         lp, (ent, aux, moe) = self._logprobs_fn(
-            params, mb["sequences"], mb["prompt_lens"], max_new=T)
+            params, mb["sequences"], mb["prompt_lens"], max_new=T,
+            **self._trace_kw(mb))
         loss, stats = ppo_policy_loss(
             lp, mb["old_logprobs"], mb["advantages"], mb["mask"],
             self.cfg.clip_ratio)

@@ -27,7 +27,8 @@ class RLOOTrainer(BaseTrainer):
         mask = result.completion_mask
         old_lp = self.behavior_logprobs(result)
         ref_lp, _ = self._jit_logprobs(
-            self.ref_params, result.sequences, result.prompt_lens, max_new=T)
+            self.ref_params, result.sequences, result.prompt_lens, max_new=T,
+            **self._trace_kw(result))
 
         kl_seq = jnp.sum(kl_penalty(old_lp, ref_lp, "k1") * mask, axis=1)
         adjusted = jnp.asarray(scores) - (self.cfg.kl_coef * kl_seq
@@ -40,6 +41,7 @@ class RLOOTrainer(BaseTrainer):
             "mask": mask,
             "old_logprobs": old_lp * mask,
             "advantages": adv,  # [B] sequence-level
+            **self._trace_kw(result),
         }
         lens = (host or result).completion_lens
         kl_mean = jnp.mean(kl_seq)
@@ -57,7 +59,8 @@ class RLOOTrainer(BaseTrainer):
     def loss_fn(self, params, mb: Dict[str, jnp.ndarray]):
         T = mb["mask"].shape[1]
         lp, (ent, aux, moe) = self._logprobs_fn(
-            params, mb["sequences"], mb["prompt_lens"], max_new=T)
+            params, mb["sequences"], mb["prompt_lens"], max_new=T,
+            **self._trace_kw(mb))
         seq_lp = jnp.sum(lp * mb["mask"], axis=1)
         # REINFORCE on whole-sequence logprob with a stop-grad sequence
         # importance ratio: exactly 1 on the first epoch (old_lp comes

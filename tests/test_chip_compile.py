@@ -1093,3 +1093,100 @@ def test_nemotron_h_update_compiles_for_v5e(one_chip, on_tpu):
     assert mem.argument_size_in_bytes == pytest.approx(6.19e9, rel=1e-2)
     # beside it the chip holds the bf16 reference (1.55 GB) and the batch
     assert mem.peak_memory_in_bytes + 1.6e9 <= V5E_BYTES_LIMIT
+
+
+# -- the sdar_moe model (block diffusion) at the published widths -------------
+
+def _sdar_cell():
+    """(model configuration, B, P, T) of ``ppo-sdar-ep8-sync``."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+
+    mc = dataclasses.replace(ModelConfig.sdar_30b_a3b(), num_layers=6,
+                             experts_held=16, vocab_size=18992,
+                             max_seq_len=1024)
+    assert mc.layer_runs() == ((0, 6, "attention", "experts"),)
+    return mc, 32, 256, 512
+
+
+@pytest.mark.parametrize("program", ["generate", "experience", "update"])
+def test_sdar_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
+    """The three programs of ``ppo-sdar-ep8-sync`` (6 of SDAR's 48 layers
+    at the published widths, 16 of 128 experts, 18 992 rows of the
+    vocabulary; remat, one scanned stack) at the timed shapes, 32
+    prompts padded to 256 and 512 new tokens.  ``generate``: prefill and
+    the block loop (4 denoising forwards and a commit of 4 rows a
+    sequence a block: the prefill's flash kernel, no kernel in the
+    steps' attention).  ``experience``: one trace forward of all 32 rows
+    of 768 clean + 2176 noisy entries: ``flash_fwd`` twice a layer (the
+    clean stream; the noisy queries' clean keys through the per-chunk
+    entry).  ``update``: the same forward and its backward in
+    minibatches of 4: the three flash kernels twice each and the grouped
+    products.  Each fits beside what else the chip holds: 645.3 M
+    parameters are 5.16 GB of float32 master and bf16 moments, the bf16
+    reference 1.29 GB more."""
+    from orion_tpu.config import RolloutConfig
+    from orion_tpu.rollout.engine import RolloutEngine
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.trainers.ppo import PPOTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc, B, P, T = _sdar_cell()
+    shell, pshape, mb = _build_8b_shell(mc)
+    shell.cfg.rollout.max_prompt_len = P
+    shell.cfg.rollout.max_new_tokens = T
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), pshape)
+    ids = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+    resident = 1.29e9              # the bf16 reference
+    with jax.default_matmul_precision("default"):
+        if program == "generate":
+            eng = RolloutEngine(shell.model, mc, RolloutConfig(
+                max_prompt_len=P, max_new_tokens=T), eos_token_id=None,
+                pad_token_id=0)
+            rng = jax.eval_shape(lambda: jax.random.key(0))
+            compiled = eng._generate_jit.lower(
+                params, ids(B, P), ids(B), _sds(rng.shape, rng.dtype,
+                                                one_chip),
+                max_new_tokens=T).compile()
+            resident += 2 * 1.29e9          # and the moments
+        elif program == "experience":
+            compiled = jax.jit(
+                lambda p, s, n, m, r: PPOTrainer._lp_values_fwd(
+                    shell, p, s, n, m, max_new=T, with_entropy=False,
+                    reveal_step=r)).lower(
+                        params, ids(B, P + T), ids(B),
+                        _sds((B, T), jnp.float32, one_chip),
+                        ids(B, T)).compile()
+            resident += 2 * 1.29e9
+        else:
+            rows = 4
+            mb["reveal_step"] = jax.ShapeDtypeStruct((1, T), jnp.int32)
+            shapes = {k: (P + T,) if k == "sequences"
+                      else () if k == "prompt_lens" else (T,) for k in mb}
+            experience = {k: _sds((B,) + shapes[k], v.dtype, one_chip)
+                          for k, v in mb.items()}
+            state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                                 _abstract_state(shell, pshape))
+            compiled = jax.jit(
+                lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+                donate_argnums=(0,)).lower(
+                    state, experience,
+                    _sds((B // rows, rows), jnp.int32, one_chip)).compile()
+    names = _kernel_names(compiled)
+    mem = compiled.memory_analysis()
+    if program == "generate":
+        assert "flash_fwd" in names                      # the prefill's
+        assert not {"flash_bwd_dq", "paged_decode"} & set(names)
+        assert mem.argument_size_in_bytes == pytest.approx(2.58e9, rel=1e-2)
+    elif program == "experience":
+        assert names.count("flash_fwd") == 2
+        assert "moe_gmm" in names
+    else:
+        assert names.count("flash_fwd") == 4             # forward and remat's
+        assert names.count("flash_bwd_dq") == 2
+        assert names.count("flash_bwd_dkv") == 2
+        assert {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm"} <= set(names)
+        assert mem.argument_size_in_bytes == pytest.approx(5.16e9, rel=1e-2)
+    assert mem.peak_memory_in_bytes + resident <= V5E_BYTES_LIMIT
