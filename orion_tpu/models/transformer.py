@@ -296,15 +296,17 @@ def trace_inputs(cfg: ModelConfig, sequences, prompt_lens, reveal_step):
 def _cache_writer(positions, B: int, L: int, step: bool = False):
     """``write(cache, new)``: the L new entries of every sequence at
     slots starting at ``positions[:, 0]``.  ``step``: the L entries are
-    one step of a decode loop (a block-diffusion model's block)."""
+    one step of a decode loop (a block-diffusion model's block, or two:
+    the one it commits in front of the one it denoises), each written
+    at its own position: one past the cache's end is not written."""
     starts = positions[:, 0]
     if step and L > 1:
         # one batched scatter with unique indices, as the one-token step
+        # (a scatter drops what lies out of bounds)
         rows = jnp.arange(B)[:, None]
-        slots = starts[:, None] + jnp.arange(L, dtype=starts.dtype)
 
         def write(cache, new):
-            return cache.at[rows, slots].set(new, unique_indices=True)
+            return cache.at[rows, positions].set(new, unique_indices=True)
     elif L == 1:
         # Decode: ONE batched scatter with unique indices.  The
         # vmap(dynamic_update_slice) form lowers to a serial
@@ -347,7 +349,8 @@ def prefix_lengths(Lmax: int) -> list:
 
 def prefix_step(positions, Lmax: int, fn):
     """``fn(m)`` for the least prefix ``m`` of :func:`prefix_lengths`
-    that holds every row's ``positions`` (one new token a row, [B, 1]):
+    that holds every row's ``positions`` (one new token a row, [B, 1];
+    the last slots a block-diffusion model's step sees, [B, L]):
     ``fn(m)`` is the step's attention over slots ``[:m]`` of a dense
     cache of ``Lmax`` slots and of the mask ``slot <= position``.  Slots
     fill from 0 up (right-padded prompts, decode overwrites the tail
@@ -355,7 +358,9 @@ def prefix_step(positions, Lmax: int, fn):
     probability exactly 0 and are not fetched: ONE ``lax.switch`` over
     static slices, each branch XLA's own fusions on fewer slots (a
     cache of one block: ``fn(Lmax)``, no switch).  The slots inside the
-    last block that a row has not reached stay masked as before."""
+    last block that a row has not reached stay masked as before; a
+    position past the cache's end reads it whole (``lax.switch`` clamps
+    its index)."""
     ms = prefix_lengths(Lmax)
     return jax.lax.switch(jnp.max(positions) // ms[0],
                           [partial(fn, m) for m in ms])
@@ -460,7 +465,10 @@ class Attention(nn.Module, Kind):
         ([B, Lmax] slots a layer, int8 with scales under
         ``quantize_kv``); a one-token step reads its filled prefix in
         blocks (:func:`prefix_step`), and so does the step of a
-        block-diffusion model, ``block_length`` tokens a row.
+        block-diffusion model: ``block_length`` tokens a row, or twice
+        that, the block before riding in front to be committed (the L
+        keys and values are written before any query reads, and the
+        clean rule lets the later block see the earlier one's).
         ``visible`` (:class:`Visible`): what to mask by where that is
         not ``positions``; a block-diffusion model given none masks by
         the clean rule (:func:`clean_rule`).
@@ -474,8 +482,9 @@ class Attention(nn.Module, Kind):
             visible = clean_rule(positions, cfg.block_length)
         # a query sees the slots up to ``see``
         see = positions if visible is None else visible.see
-        # one step of a decode loop: one token, or one block's
-        step = layer_cache is not None and L in (1, cfg.block_length)
+        # one step of a decode loop: one token; one block's, or two's
+        step = layer_cache is not None and L in (
+            1, cfg.block_length, 2 * cfg.block_length)
 
         scale = 1.0 / D ** 0.5
         paged_decode_out = None
@@ -549,8 +558,8 @@ class Attention(nn.Module, Kind):
         if paged_decode_out is not None:
             out = paged_decode_out[:, None, :, :]
         elif step and not is_paged(layer_cache):
-            # one new token (one block's) against the dense slot cache,
-            # int8 or not: over the filled prefix of its slots
+            # one new token (one block's, two's) against the dense slot
+            # cache, int8 or not: over the filled prefix of its slots
             Lmax = new_cache["k"].shape[1]
             whole = mask(Lmax)
 
@@ -1516,26 +1525,34 @@ def block_decode_attrs(cfg: ModelConfig, lens, slots: int,
     """What ``rollout.dispatch`` carries of a block-diffusion rollout
     after prompts of ``lens`` real tokens, where no row stops early:
     ``blocks`` the loop runs (the row that spans most decides),
-    ``denoise_forwards`` of ``block_length`` positions a row
-    (``denoising_steps`` + the commit, a block), ``kv_step_slots``,
-    the slots one of them reads a layer (mean): its prefix of
-    :func:`prefix_lengths` holds the block's last position, and
-    ``decode_pairs``, the (query, key) pairs of the prompts' real
-    tokens in prefill and of every forward of every row's own blocks
-    (a query has the keys through its block's end)."""
+    ``denoise_forwards``, the forwards of the loop, each one read of the
+    weights (``denoising_steps`` a block), ``commit_rows``, the rows a
+    sequence that ride in them beside a block's own ``block_length``
+    (every block but the last the loop runs is committed in the first
+    forward of the next: its ``block_length`` final tokens),
+    ``kv_step_slots``, the slots a forward reads a layer (mean): its
+    prefix of :func:`prefix_lengths` holds the block's last position,
+    and ``decode_pairs``, the (query, key) pairs of the prompts' real
+    tokens in prefill, of the ``denoising_steps`` forwards of every
+    row's own blocks and of the commit of all but its last (a query has
+    the keys through its block's end)."""
     Bd, S = cfg.block_length, cfg.denoising_steps
     lens = np.asarray(lens, np.int64)
     need = (lens % Bd + new_tokens - 1) // Bd + 1        # blocks a row
     blocks = int(np.max(need))
     ms = np.asarray(prefix_lengths(slots))
     ends = (int(np.max(lens // Bd)) + np.arange(blocks)) * Bd + Bd - 1
-    pairs = sum(
-        int(_seen_keys(np.arange(n), Bd).sum()
-            + (S + 1) * _seen_keys(np.arange(
-                n // Bd * Bd, (n // Bd + k) * Bd), Bd).sum())
-        for n, k in zip(lens, need))
+
+    def own(n, k):
+        """The pairs of one forward over k blocks from prompt n's last."""
+        return int(_seen_keys(
+            np.arange(n // Bd * Bd, (n // Bd + k) * Bd), Bd).sum())
+
+    pairs = sum(int(_seen_keys(np.arange(n), Bd).sum())
+                + S * own(n, k) + own(n, k - 1) for n, k in zip(lens, need))
     return {"block_length": Bd, "denoising_steps": S, "blocks": blocks,
-            "denoise_forwards": blocks * (S + 1), "decode_pairs": pairs,
+            "denoise_forwards": blocks * S,
+            "commit_rows": (blocks - 1) * Bd, "decode_pairs": pairs,
             "kv_step_slots": float(
                 ms[np.minimum(ends // ms[0], len(ms) - 1)].mean())}
 

@@ -14,11 +14,11 @@ the model's description, not from a rollout flag:
   a block starts as the mask token wherever the prompt does not reach
   and takes ``denoising_steps`` forwards of its ``block_length``
   positions, each of which reveals the masked positions whose sampled
-  candidate is the most probable, then one more forward that commits
-  its keys and values: a step yields no fixed token a row, a row ends
-  at a block's end, and the result carries at which step every token
-  was revealed (``reveal_step``), without which no trainer can score
-  it.
+  candidate is the most probable; its final tokens' keys and values are
+  committed inside the next block's first forward, whose rows they ride
+  in front of: a step yields no fixed token a row, a row ends at a
+  block's end, and the result carries at which step every token was
+  revealed (``reveal_step``), without which no trainer can score it.
 
 Both:
 - per-token logprobs captured in f32 under the *actual* sampling
@@ -374,10 +374,20 @@ class RolloutEngine:
         the ``block_length / denoising_steps`` masked positions whose
         candidate is the most probable under that distribution (the
         lowest position on a tie); the other candidates are thrown away.
-        One more forward then commits the block's keys and values.  A
+        The slots then hold the keys and values of the block's state
+        BEFORE its last reveal: the block is committed by the next
+        block's first forward, which carries its ``block_length`` final
+        tokens in front of its own rows (``2 * block_length`` positions
+        a row: a forward writes its rows' keys and values before any of
+        its queries reads, so the next block's queries see the final
+        keys), and reads logits off its own rows alone.  The first block
+        a row generates has none before it to commit (the prompt's are
+        prefill's): its riding rows stand past the cache's end, where
+        nothing is written and no query sees; the last block the loop
+        runs is never committed: nothing reads its slots.  A
         position past ``prompt_len + max_new_tokens`` is never revealed
         (it stays the mask token, so the trace of what was revealed is
-        whole), a row is done when a committed block holds a stop token
+        whole), a row is done when a finished block holds a stop token
         or its last new position.  What follows the first stop token has
         completion mask 0; the tokens its block revealed there stay in
         ``sequences`` (the states other tokens were drawn from showed
@@ -426,7 +436,7 @@ class RolloutEngine:
             return (i < n_blocks) & ~jnp.all(done)
 
         def body(c):
-            i, done, comp_len, cache, rng, out = c
+            i, done, comp_len, cache, rng, out, prev = c
             pos = (first + i)[:, None] * Bd + offs                # [B, Bd]
             new = (pos >= prompt_lens[:, None]) & (pos < last[:, None])
             z = jnp.where(pos < prompt_lens[:, None], out["seq"][rows, pos],
@@ -434,11 +444,23 @@ class RolloutEngine:
             rec = {"lp": jnp.zeros((B, Bd), jnp.float32),
                    "plp": jnp.zeros((B, Bd), jnp.float32),
                    "step": jnp.full((B, Bd), S, jnp.int32)}
+            # the block before (``prev``: its final tokens) rides in front
+            # of this one's first forward; the first block has none: its
+            # riding rows stand past the cache's end
+            ride = jnp.concatenate(
+                [jnp.where(i > 0, pos - Bd, cache_slots(slots) + offs), pos],
+                axis=1)
 
-            def denoise(s, c):
+            def denoise(s, c, riding: bool = False):
                 z, masked, cache, rng, rec = c
                 with jax.named_scope("denoise"):
-                    logits, cache = apply(z, pos, cache)
+                    if riding:
+                        logits, cache = apply(
+                            jnp.concatenate([prev, z], axis=1), ride, cache,
+                            logits_positions=jnp.broadcast_to(
+                                Bd + offs, (B, Bd)))
+                    else:
+                        logits, cache = apply(z, pos, cache)
                 rng, sub = jax.random.split(rng)
                 cand, lp, plp = sample(
                     sub, bar_token(logits, mask_id).reshape(B * Bd, -1))
@@ -455,11 +477,9 @@ class RolloutEngine:
                        "step": jnp.where(reveal, s, rec["step"])}
                 return z, masked & ~reveal, cache, rng, rec
 
-            z, _, cache, rng, rec = jax.lax.fori_loop(
-                0, S, denoise, (z, new & ~done[:, None], cache, rng, rec))
-            with jax.named_scope("commit"):
-                _, cache = apply(z, pos, cache, skip_lm_head=True)[:2]
             live = new & ~done[:, None]
+            state = denoise(0, (z, live, cache, rng, rec), riding=True)
+            z, _, cache, rng, rec = jax.lax.fori_loop(1, S, denoise, state)
             # dropped where a row is done or the position is not new
             at = jnp.where(live, pos, slots)
             out = {"seq": out["seq"].at[rows, at].set(z, mode="drop"),
@@ -473,12 +493,14 @@ class RolloutEngine:
                              jnp.minimum(pos[:, -1] + 1, last))
             comp_len = jnp.where(done, comp_len, upto - prompt_lens)
             done = done | jnp.any(stop, axis=1) | (pos[:, -1] + 1 >= last)
-            return i + 1, done, comp_len, cache, rng, out
+            return i + 1, done, comp_len, cache, rng, out, z
 
         init = (jnp.int32(0), jnp.zeros((B,), bool),
-                jnp.zeros((B,), jnp.int32), cache, rng, out0)
+                jnp.zeros((B,), jnp.int32), cache, rng, out0,
+                jnp.full((B, Bd), mask_id, jnp.int32))
         with jax.named_scope("decode"):
-            _, _, comp_len, _, _, out = jax.lax.while_loop(cond, body, init)
+            _, _, comp_len, _, _, out, _ = jax.lax.while_loop(
+                cond, body, init)
 
         at = prompt_lens[:, None] + jnp.arange(T)[None, :]
         real = jnp.arange(T)[None, :] < comp_len[:, None]

@@ -1116,8 +1116,9 @@ def test_sdar_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
     at the published widths, 16 of 128 experts, 18 992 rows of the
     vocabulary; remat, one scanned stack) at the timed shapes, 32
     prompts padded to 256 and 512 new tokens.  ``generate``: prefill and
-    the block loop (4 denoising forwards and a commit of 4 rows a
-    sequence a block: the prefill's flash kernel, no kernel in the
+    the block loop (4 denoising forwards a block: the first of 8 rows a
+    sequence, the block before riding in front to be committed, the
+    other three of 4: the prefill's flash kernel, no kernel in the
     steps' attention).  ``experience``: one trace forward of all 32 rows
     of 768 clean + 2176 noisy entries: ``flash_fwd`` twice a layer (the
     clean stream; the noisy queries' clean keys through the per-chunk
