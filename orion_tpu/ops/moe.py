@@ -278,15 +278,22 @@ def experts_grouped(x, w_up, w_down, local, gates, block: int,
     blocks while held pairs are left (one loop of static length), so a batch whose every pair lands
     on one held expert takes ``T k / block`` blocks and is computed
     exactly (no capacity, no drops), and a batch routed like the even
-    share takes one.  Rows are gathered, multiplied, activated and added
-    into their tokens' rows of a float32 sum; the kernels' work follows
-    the rows of the held experts inside a block."""
+    share takes one.  Rows are gathered, multiplied, activated and
+    placed into their tokens' rows of a float32 sum, a tile of tokens at
+    a time (ops/pallas/moe_combine.py); the kernels' work follows the
+    rows of the held experts inside a block."""
     T, k = local.shape
     H = w_up.shape[0]
     n_pairs = T * k
     with jax.named_scope("moe.dispatch"):
         held = (local >= 0) & (local < H)
         key = jnp.where(held, local, H).reshape(n_pairs)
+        # The combine kernel's contract (ops/pallas/moe_combine.py): this
+        # sort is STABLE and a token selects an expert at most once, so
+        # inside one held expert's run the tokens ascend and are distinct
+        # and a tile of tokens owns ONE contiguous range of the run.  The
+        # kernel places that range by a 0/1 product and sums in float32:
+        # it is wrong the day this sort is not stable.
         _, order = jax.lax.sort_key_val(
             key, jnp.arange(n_pairs, dtype=jnp.int32))
         sizes = jnp.zeros((H + 1,), jnp.int32).at[key].add(1)
@@ -295,7 +302,7 @@ def experts_grouped(x, w_up, w_down, local, gates, block: int,
         order = jnp.pad(order, (0, -n_pairs % block))
         order, sizes = (checkpoint_name(t, "moe_route")
                         for t in (order, sizes))
-    return _routed(x, w_up, w_down, gates, order, sizes, block, act)
+    return _routed(x, w_up, w_down, gates, local, order, sizes, block, act)
 
 
 def _block(b, block: int, gates, order, sizes):
@@ -331,40 +338,83 @@ def _over_blocks(body, carry, block: int, order, sizes):
     return jax.lax.fori_loop(0, n_blocks, step, carry)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _routed(x, w_up, w_down, gates, order, sizes, block: int, act: str):
-    """The held experts' part of the layer's sum.  x [T, D]; gates
-    [T, k]; order [whole blocks] the pairs sorted by expert (pair ``p``
-    is token ``p // k``); sizes [H + 1] the pairs of each held expert
-    and of all others -> [T, D] in x.dtype, summed in float32."""
+def _gate_of(local, gates, n_held: int):
+    """[T, H] float32: the gate of each token for each held expert, 0
+    where it did not select it.  A token that holds an expert more than
+    once (no router's top-k does; a test's routing may) gets the MEAN of
+    those gates: its rows of that expert are equal and the combine
+    places their sum."""
+    hit = local[:, :, None] == jnp.arange(n_held, dtype=local.dtype)
+    total = jnp.sum(jnp.where(hit, gates[:, :, None], 0.0), axis=1)
+    return total / jnp.maximum(jnp.sum(hit, axis=1), 1)
+
+
+def _token_ranges(local, n_held: int, width: int):
+    """:func:`moe_combine.ranges` over the tiles of tokens that the
+    combine of a sum ``width`` wide works on."""
+    from orion_tpu.ops.pallas import moe_combine as mc
+
+    return mc.ranges(local, n_held, mc.token_tile(local.shape[0], width))
+
+
+def combine_work(local, n_held: int, width: int, block: int):
+    """[2] int32: the work items that place rows, over all the blocks
+    of one layer call, and the held rows they place (the layer's
+    counters: an item is a product over 128 rows, filled rows / (items x
+    128))."""
+    from orion_tpu.ops.pallas import moe_combine as mc
+
+    starts, ends = _token_ranges(local, n_held, width)
+    blocks = jnp.arange(-(-local.size // block))[:, None, None]
+    lo, hi, chunks = mc.range_chunks(starts, ends, blocks, block)
+    return jnp.stack([jnp.sum(chunks), jnp.sum(hi - lo)]).astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _routed(x, w_up, w_down, gates, local, order, sizes, block: int,
+            act: str):
+    """The held experts' part of the layer's sum.  x [T, D]; gates,
+    local [T, k]; order [whole blocks] the pairs sorted by expert (pair
+    ``p`` is token ``p // k``); sizes [H + 1] the pairs of each held
+    expert and of all others -> [T, D] in x.dtype, summed in float32."""
     from orion_tpu.ops.pallas.grouped_matmul import gmm
+    from orion_tpu.ops.pallas.moe_combine import moe_combine, work_items
+
+    n_held = w_up.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        spans = _token_ranges(local, n_held, x.shape[1])
+        gate_of = _gate_of(local, gates, n_held)
 
     def add_block(b, out):
-        _, tok, gate, sizes_b = _block(b, block, gates, order, sizes)
+        _, tok, _, sizes_b = _block(b, block, gates, order, sizes)
         with jax.named_scope("moe.experts"):
             rows = jnp.take(x, tok, axis=0)
             h = ACTIVATIONS[act][0](gmm(rows, w_up, sizes_b))
             y = gmm(h, w_down, sizes_b)             # zero where not held
         with jax.named_scope("moe.combine"):
-            return out.at[tok].add(y.astype(jnp.float32) * gate)
+            items, _ = work_items(*spans, b, block)
+            return moe_combine(out, y, tok, items, gate_of)
 
     out = _over_blocks(add_block, jnp.zeros(x.shape, jnp.float32), block,
                        order, sizes)
     return out.astype(x.dtype)
 
 
-def _routed_fwd(x, w_up, w_down, gates, order, sizes, block, act):
+def _routed_fwd(x, w_up, w_down, gates, local, order, sizes, block, act):
     # what is kept is what came in: the backward rebuilds a block's rows
     # and its activation (one more product on the held rows)
-    return (_routed(x, w_up, w_down, gates, order, sizes, block, act),
-            (x, w_up, w_down, gates, order, sizes))
+    return (_routed(x, w_up, w_down, gates, local, order, sizes, block, act),
+            (x, w_up, w_down, gates, local, order, sizes))
 
 
 def _routed_bwd(block, act, res, g):
     from orion_tpu.ops.pallas.grouped_matmul import gmm, gmm_dlhs, tgmm
+    from orion_tpu.ops.pallas.moe_combine import moe_combine, work_items
 
-    x, w_up, w_down, gates, order, sizes = res
+    x, w_up, w_down, gates, local, order, sizes = res
     f32 = jnp.float32
+    with jax.named_scope("moe.dispatch"):
+        spans = _token_ranges(local, w_up.shape[0], x.shape[1])
 
     def add_block(b, carry):
         d_x, d_up, d_down, d_gate_of = carry
@@ -384,7 +434,9 @@ def _routed_bwd(block, act, res, g):
             d_up = d_up + tgmm(rows, d_pre, sizes_b, f32)
             d_rows = gmm_dlhs(d_pre, w_up, sizes_b)
         with jax.named_scope("moe.dispatch"):
-            return (d_x.at[tok].add(d_rows.astype(f32)), d_up, d_down,
+            # the rows carry their gate already: placed as they are
+            items, _ = work_items(*spans, b, block)
+            return (moe_combine(d_x, d_rows, tok, items), d_up, d_down,
                     d_gate_of.at[pair].add(d_gate))
 
     primals = (x, w_up, w_down, gates.reshape(-1))
@@ -393,7 +445,8 @@ def _routed_bwd(block, act, res, g):
                          block, order, sizes)
     d_x, d_up, d_down, d_gate_of = (
         d.astype(t.dtype) for d, t in zip(grads, primals))
-    return d_x, d_up, d_down, d_gate_of.reshape(gates.shape), None, None
+    return (d_x, d_up, d_down, d_gate_of.reshape(gates.shape), None, None,
+            None)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -428,7 +481,8 @@ class TopKMoE(nn.Module, Kind):
     then some 1.7 times the mean), so that it does change selections.
 
     Sows ``moe_load`` [experts_held] int32, the pairs computed by each
-    held expert, for the trainer's counters, and ``moe_selected``
+    held expert, and in the grouped form ``moe_combine`` [2]
+    (:func:`combine_work`), for the trainer's counters, and ``moe_selected``
     [B, L, k], the experts each token selected (the reference check
     reads it: a selection is discrete, see
     benchmarks/reference_check_dsv3.py).
@@ -459,17 +513,16 @@ class TopKMoE(nn.Module, Kind):
     def tag_bytes(cfg, rows, seq_len, w):
         """``mlp_pre``: the shared expert's first product; ``moe_route``:
         scores [n, E] float32 and the selection [n, k] (the gather of the
-        selected scores keeps its own indices); the dense form reads the
-        selection again for its weights, the grouped form's backward
-        reads order [n k, in whole blocks] and sizes [held + 1]
-        instead."""
+        selected scores keeps its own indices); both forms read the
+        selection again (the dense one for its weights, the grouped
+        one's backward for the ranges its combine places), and the
+        grouped form's backward reads order [n k, in whole blocks] and
+        sizes [held + 1] besides."""
         n, k = rows * seq_len, cfg.num_experts_per_tok
-        route = n * (w(cfg.n_routed_experts) + w(k))
+        route = n * (w(cfg.n_routed_experts) + 2 * w(k))
         block = block_rows(cfg, n)
         if block:
             route += w(-(-n * k // block) * block) + w(cfg.experts_held + 1)
-        else:
-            route += n * w(k)
         return {"mlp_pre": n * ACTIVATIONS[cfg.moe_activation][1]
                 * w(shared_width(cfg)) * jnp.dtype(cfg.dtype).itemsize,
                 "moe_route": 4 * route}
@@ -534,6 +587,9 @@ class TopKMoE(nn.Module, Kind):
                 z_in = _dense(Dl, ("embed", "latent"), False, cfg,
                               "fc1_latent_proj")(z)
         block = block_rows(cfg, B * L)
+        if block:
+            self.sow("intermediates", "moe_combine",
+                     combine_work(local, H, Dl, block))
         operands = (z_in.astype(cdt), w_up.astype(cdt), w_down.astype(cdt),
                     local, gates)
         routed = experts_grouped(*operands, block, act) if block \

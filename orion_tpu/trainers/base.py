@@ -210,7 +210,7 @@ def _sown(intermediates, name: str) -> list:
 
 
 def moe_load_stats(loads: list, pairs_per_layer: int,
-                   block_rows: int) -> dict:
+                   block_rows: int, combines: list = ()) -> dict:
     """Counters of the dropless expert layer for one forward, from the
     ``moe_load`` arrays its layers sowed ([held] or, scanned, [layers,
     held] pairs routed to each expert held here): the pairs computed
@@ -219,8 +219,16 @@ def moe_load_stats(loads: list, pairs_per_layer: int,
     is the padding a grouped product pays), and what the grouped form
     moved for them: the rows of a block (``block_rows``,
     ops/moe.py::block_rows; 0 for the dense form) over all layers, and
-    the most blocks a layer ran (1: its held pairs fit the first)."""
+    the most blocks a layer ran (1: its held pairs fit the first); from
+    the ``moe_combine`` arrays ([2] a layer: work items, rows placed),
+    how full the combine kernel ran: the most items a layer call took
+    and the rows placed over the rows the items' products span (both 0
+    for the dense form)."""
+    from orion_tpu.ops.pallas.moe_combine import chunk_rows
+
     load = jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in loads])
+    items, placed = jnp.concatenate(
+        [x.reshape(-1, 2) for x in combines] or [jnp.zeros((1, 2))]).T
     blocks = jnp.zeros((), jnp.int32)
     if block_rows:      # block 0 always runs
         blocks = jnp.max(jnp.maximum(
@@ -232,6 +240,9 @@ def moe_load_stats(loads: list, pairs_per_layer: int,
         "moe_load_mean": jnp.mean(load.astype(jnp.float32)),
         "moe_block_rows": jnp.float32(block_rows * load.shape[0]),
         "moe_blocks_max": blocks.astype(jnp.float32),
+        "moe_combine_items": jnp.max(items).astype(jnp.float32),
+        "moe_combine_fill": (jnp.sum(placed) / jnp.maximum(
+            jnp.sum(items) * chunk_rows(block_rows), 1)).astype(jnp.float32),
     }
 
 
@@ -466,7 +477,8 @@ class BaseTrainer:
 
             moe = moe_load_stats(_sown(inter, "moe_load"),
                                  sequences.size * mc.num_experts_per_tok,
-                                 block_rows(mc, sequences.size))
+                                 block_rows(mc, sequences.size),
+                                 _sown(inter, "moe_combine"))
             aux = jnp.zeros((), jnp.float32)
         else:
             out = self.model.apply({"params": params}, sequences,

@@ -523,7 +523,8 @@ def test_grouped_expert_product_compiles_for_v5e(name, one_chip, on_tpu):
             _sds((T, k), jnp.int32, one_chip),
             _sds((T, k), jnp.float32, one_chip)).compile()
     names = _kernel_names(compiled)
-    assert set(names) == {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm"}, names
+    assert set(names) == {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm",
+                          "moe_combine"}, names
     # The written backward of one block: the gate|up product again (the
     # activation is rebuilt, not kept), the gradient of the rows through
     # both products and both weight gradients; the forward's own two
@@ -531,6 +532,16 @@ def test_grouped_expert_product_compiles_for_v5e(name, one_chip, on_tpu):
     # every block, the first too, is a trip of the same loop.
     assert names.count("moe_gmm") == 1
     assert names.count("moe_gmm_dlhs") == 2 and names.count("moe_tgmm") == 2
+    # ... and so does the combine kernel: the backward's own, which
+    # places the rows' gradients into ``d_x`` without a gate; the
+    # forward's, with its gates, is dead code here like its products
+    assert names.count("moe_combine") == 1
+    # no row scatter-add into a float32 [T, D] is left (the sum's tile
+    # stays in VMEM and the rows come to it: ops/pallas/moe_combine.py);
+    # the scatter of the gates' gradients is one of scalars
+    scatters = [line for line in compiled.as_text().splitlines()
+                if re.search(r"= f32\[%d,%d\]\S* scatter\(" % (T, D), line)]
+    assert not scatters, scatters
     # rows are gathered, multiplied and summed a block at a time: what
     # still has T * k entries are vectors (sort keys, order, gates)
     wide = re.findall(r"= \w+\[%d,(\d+)" % (T * k), compiled.as_text())
@@ -799,7 +810,7 @@ def test_kimi_linear_update_compiles_for_v5e(one_chip, on_tpu):
             ).compile()
     assert _kernel_calls(compiled) >= 1          # tpu_custom_call
     assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
-            "moe_gmm_dlhs", "moe_tgmm", "kda_chunk_fwd",
+            "moe_gmm_dlhs", "moe_tgmm", "moe_combine", "kda_chunk_fwd",
             "kda_chunk_bwd"} <= set(_kernel_names(compiled))
     assert compiled.memory_analysis().peak_memory_in_bytes <= 15.75 * 2**30
 
@@ -974,7 +985,8 @@ def test_keye_dsa_update_compiles_for_v5e(one_chip, on_tpu):
     names = _kernel_names(compiled)
     assert names.count("dsa_select") == names.count("sparse_fwd") == 2
     assert names.count("sparse_bwd_dq") == names.count("sparse_bwd_dkv") == 1
-    assert {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm"} <= set(names)
+    assert {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm", "moe_combine"} <= set(
+        names)
     assert not {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} & set(names)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(5.274e9, rel=5e-3)
@@ -1088,7 +1100,7 @@ def test_nemotron_h_update_compiles_for_v5e(one_chip, on_tpu):
                 _sds((32 // rows, rows), jnp.int32, one_chip)).compile()
     names = _kernel_names(compiled)
     assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
-            "moe_gmm_dlhs", "moe_tgmm"} <= set(names)
+            "moe_gmm_dlhs", "moe_tgmm", "moe_combine"} <= set(names)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(6.19e9, rel=1e-2)
     # beside it the chip holds the bf16 reference (1.55 GB) and the batch
@@ -1183,11 +1195,12 @@ def test_sdar_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
         assert mem.argument_size_in_bytes == pytest.approx(2.58e9, rel=1e-2)
     elif program == "experience":
         assert names.count("flash_fwd") == 2
-        assert "moe_gmm" in names
+        assert {"moe_gmm", "moe_combine"} <= set(names)
     else:
         assert names.count("flash_fwd") == 4             # forward and remat's
         assert names.count("flash_bwd_dq") == 2
         assert names.count("flash_bwd_dkv") == 2
-        assert {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm"} <= set(names)
+        assert {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm",
+                "moe_combine"} <= set(names)
         assert mem.argument_size_in_bytes == pytest.approx(5.16e9, rel=1e-2)
     assert mem.peak_memory_in_bytes + resident <= V5E_BYTES_LIMIT

@@ -364,6 +364,7 @@ def test_ppo_iteration_through_the_launcher(tmp_path):
     assert row["moe_load_max"] >= row["moe_load_mean"] > 0
     # 48 tokens a minibatch: the dense form, which has no blocks
     assert row["moe_block_rows"] == row["moe_blocks_max"] == 0
+    assert row["moe_combine_items"] == row["moe_combine_fill"] == 0
     before = kept["before"]["backbone"]
     after = kept["trainer"].state.params["backbone"]
     moved = np.max(np.abs(np.asarray(after["layers"]["attn"]["q_proj"][
@@ -407,6 +408,10 @@ def test_block_counters_through_the_launcher(bound, tmp_path, monkeypatch):
     rows, blocks = (16, 6) if bound == "one_tile" else (96, 1)
     assert row["moe_block_rows"] == 2 * rows          # two expert layers
     assert row["moe_blocks_max"] == blocks
+    # the combine kernel's work items place every held row, in products
+    # that the rows of their ranges fill in part
+    assert row["moe_combine_items"] >= blocks
+    assert 0 < row["moe_combine_fill"] <= 1
 
 
 @pytest.mark.parametrize("algo", ["grpo", "rloo", "online_dpo"])
