@@ -23,22 +23,25 @@ LATENT_ARCHS = ("deepseek_v3", "kimi_linear")
 #: The archs whose model is a per-layer pattern of (mixer, FFN) kinds
 #: over RMSNorm blocks (``ModelConfig.layer_kinds``).
 PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa", "nemotron_h",
-                                "sdar_moe")
+                                "sdar_moe", "lfm2_moe")
 #: The archs whose layers end in the dropless expert layer
 #: (``ops.moe.TopKMoE``) and so share its fields and their checks.
-EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa", "nemotron_h", "sdar_moe")
+EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa", "nemotron_h", "sdar_moe",
+                               "lfm2_moe")
 #: nemotron_h's ``hybrid_override_pattern`` characters -> (mixer, ffn)
 #: halves of a block.
 PATTERN_HALVES = {"M": ("mamba2", None), "*": ("attention", None),
                   "E": (None, "experts")}
-#: olmo_hybrid's published ``layer_types`` entries -> mixers.
-LAYER_TYPE_MIXERS = {"linear_attention": "gdn", "full_attention": "attention"}
+#: The published ``layer_types`` entries -> mixers, by arch.
+LAYER_TYPE_MIXERS = {
+    "olmo_hybrid": {"linear_attention": "gdn", "full_attention": "attention"},
+    "lfm2_moe": {"conv": "conv", "full_attention": "attention"}}
 
 
 @dataclass
 class ModelConfig:
     """Architecture hyperparameters for the decoder-only transformer:
-    flat fields under the published key names of eight model families,
+    flat fields under the published key names of nine model families,
     a ``_check_<arch>`` each, and the model's description derived from
     them, :meth:`layer_kinds`: one (mixer, feed-forward) pair per block.
     What follows from a kind is ``models/transformer.py``'s
@@ -47,7 +50,7 @@ class ModelConfig:
 
     # the family whose published keys and checks apply: "llama" | "neox"
     # | "deepseek_v3" | "kimi_linear" | "olmo_hybrid" | "keye_dsa"
-    # | "nemotron_h" | "sdar_moe"
+    # | "nemotron_h" | "sdar_moe" | "lfm2_moe"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -214,6 +217,20 @@ class ModelConfig:
     block_length: int = 0
     denoising_steps: int = 0
     mask_token_id: int = -1
+    # arch="lfm2_moe" (LiquidAI's LFM2 with experts): pre-norm RMSNorm
+    # blocks whose mixer the published layer_types names, whole and read
+    # up to num_layers: "conv", a gated short convolution
+    # (models/transformer.py ShortConv: [b | c | z] = u W_in, a depthwise
+    # causal convolution of conv_L_cache taps over b * z with no bias and
+    # no activation, W_out(c * conv); it keeps the convolution's last
+    # conv_L_cache - 1 inputs a sequence and nothing else), or
+    # "full_attention" (grouped-query attention with keye_dsa's per-head
+    # q/k norm and full rotary).  The first first_k_dense_replace layers
+    # (the published num_dense_layers) end in a dense SwiGLU of
+    # intermediate_size, the others in the dropless expert layer with
+    # sigmoid scores and the selection bias (the published
+    # use_expert_bias); the head is the embedding (tie_word_embeddings).
+    conv_L_cache: int = 0
 
     def __post_init__(self) -> None:
         if self.arch in EXPERT_ARCHS:
@@ -232,6 +249,8 @@ class ModelConfig:
                 "'sdar_moe' generates by diffusion over blocks")
         if self.arch == "olmo_hybrid":
             self._check_olmo_hybrid()
+        if self.arch == "lfm2_moe":
+            self._check_lfm2_moe()
         self.head_share = tuple(self.head_share)
         if self.arch == "nemotron_h":
             self._check_nemotron_h()
@@ -275,7 +294,8 @@ class ModelConfig:
             raise ValueError(
                 f"model.moe_activation={self.moe_activation!r}: 'swiglu' "
                 "or 'relu2'")
-        if self.num_experts or self.quantize_dense or self.tie_word_embeddings:
+        tied = self.tie_word_embeddings and self.arch != "lfm2_moe"
+        if self.num_experts or self.quantize_dense or tied:
             raise ValueError(
                 f"arch={self.arch!r} has its own expert layer (num_experts "
                 "is the GShard layer's), no int8 Dense twin and an untied "
@@ -382,14 +402,7 @@ class ModelConfig:
                 "arch='olmo_hybrid' with linear_num_value_heads != "
                 "linear_num_key_heads: there is no delta rule whose value "
                 "heads share a key head (ops/kda.py takes one q, k a head)")
-        self.layer_types = tuple(self.layer_types)
-        unknown = set(self.layer_types) - set(LAYER_TYPE_MIXERS)
-        if unknown or len(self.layer_types) < self.num_layers:
-            raise ValueError(
-                f"model.layer_types names {sorted(LAYER_TYPE_MIXERS)}, one "
-                f"entry a layer, at least num_layers={self.num_layers} of "
-                f"them (got {len(self.layer_types)}, unknown: "
-                f"{sorted(unknown)})")
+        self._check_layer_types()
         self.num_kv_heads = self.num_heads
         if (self.num_experts or self.quantize_dense
                 or self.tie_word_embeddings or self.seq_shard_activations):
@@ -398,6 +411,34 @@ class ModelConfig:
                 "layer's), has no int8 Dense twin, an untied head, and "
                 "its recurrent layers take whole sequences "
                 "(seq_shard_activations)")
+
+    def _check_layer_types(self) -> None:
+        names = LAYER_TYPE_MIXERS[self.arch]
+        self.layer_types = tuple(self.layer_types)
+        unknown = set(self.layer_types) - set(names)
+        if unknown or len(self.layer_types) < self.num_layers:
+            raise ValueError(
+                f"model.layer_types names {sorted(names)}, one "
+                f"entry a layer, at least num_layers={self.num_layers} of "
+                f"them (got {len(self.layer_types)}, unknown: "
+                f"{sorted(unknown)})")
+
+    def _check_lfm2_moe(self) -> None:
+        if self.conv_L_cache < 2:
+            raise ValueError("arch='lfm2_moe' needs model.conv_L_cache >= 2 "
+                             "(the convolution's taps)")
+        self._check_layer_types()
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("arch='lfm2_moe': num_kv_heads divides "
+                             "num_heads (grouped-query attention)")
+        if not 0 <= self.first_k_dense_replace <= self.num_layers:
+            raise ValueError("first_k_dense_replace (the published "
+                             "num_dense_layers) outside 0..num_layers")
+        if self.moe_scoring != "sigmoid" or self.seq_shard_activations:
+            raise ValueError(
+                "arch='lfm2_moe': the router scores by a sigmoid and selects "
+                "under the expert bias (moe_scoring), and a convolution "
+                "takes whole sequences (seq_shard_activations)")
 
     @property
     def latent_attention(self) -> bool:
@@ -475,8 +516,8 @@ class ModelConfig:
     def layer_kinds(self) -> tuple:
         """((mixer, ffn), ...) per block: the model's description.
         mixer: a key of ``models.transformer.MIXERS`` ("attention",
-        "sparse", "latent", "kda", "gdn", "mamba2": what each is and
-        caches is stated there) or None (no mixer half); ffn: "dense" (a
+        "sparse", "latent", "kda", "gdn", "mamba2", "conv": what each is
+        and caches is stated there) or None (no mixer half); ffn: "dense" (a
         SwiGLU or GELU MLP), "gshard" (num_experts), "experts" (the
         dropless layer) or None (no feed-forward half).
         Every model but nemotron_h has both halves in every block, one
@@ -498,8 +539,13 @@ class ModelConfig:
                      "gshard" if self.num_experts else "dense"),
                     ) * self.num_layers
         if self.arch == "olmo_hybrid":
-            return tuple((LAYER_TYPE_MIXERS[t], "dense")
+            return tuple((LAYER_TYPE_MIXERS[self.arch][t], "dense")
                          for t in self.layer_types[:self.num_layers])
+        if self.arch == "lfm2_moe":
+            return tuple(
+                (LAYER_TYPE_MIXERS[self.arch][t],
+                 "dense" if i < self.first_k_dense_replace else "experts")
+                for i, t in enumerate(self.layer_types[:self.num_layers]))
         if self.arch == "keye_dsa":
             return (("sparse", "experts"),) * self.num_layers
         if self.arch == "sdar_moe":
@@ -513,7 +559,8 @@ class ModelConfig:
         """((first, length, mixer, ffn), ...): the stretches of equal
         consecutive kinds, each of which ``scan_layers`` scans as one
         stack; the leading dense layers of a latent-attention model
-        stand alone (length 1, never stacked)."""
+        stand alone (length 1, never stacked), any other model's are a
+        stretch like the rest."""
         out = []
         for i, kind in enumerate(self.layer_kinds()):
             alone = self.latent_attention and kind[1] == "dense"
@@ -647,6 +694,35 @@ class ModelConfig:
         )
 
     @staticmethod
+    def lfm2_8b_a1b() -> "ModelConfig":
+        """LiquidAI/LFM2-8B-A1B as published (config.json, model_type
+        lfm2_moe): every expert held.  ``num_dense_layers`` is
+        ``first_k_dense_replace`` here, ``num_experts`` is
+        ``n_routed_experts`` (``num_experts`` is the GShard layer's
+        switch), ``norm_eps`` is ``rms_norm_eps``; config.json as the
+        catalog has it is silent on the head: tied, as the published
+        8.3 B count implies."""
+        return ModelConfig(
+            arch="lfm2_moe", vocab_size=65536, hidden_size=2048,
+            intermediate_size=7168, num_layers=24, num_heads=32,
+            num_kv_heads=8, head_dim=64, max_seq_len=128000,
+            rope_theta=1e6, rms_norm_eps=1e-5, tie_word_embeddings=True,
+            layer_types=(("conv",) * 2 + ("full_attention",)
+                         + (("conv",) * 3 + ("full_attention",)) * 4
+                         + ("conv",) * 2 + ("full_attention",)
+                         + ("conv",) * 2),
+            conv_L_cache=3, n_routed_experts=32, num_experts_per_tok=4,
+            moe_intermediate_size=1792, first_k_dense_replace=2,
+            routed_scaling_factor=1.0, moe_scoring="sigmoid",
+        )
+
+    @staticmethod
+    def tiny_lfm2_moe() -> "ModelConfig":
+        """``model_preset=tiny_lfm2_moe``: the small sibling of
+        lfm2_8b_a1b (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("lfm2_moe")
+
+    @staticmethod
     def tiny_sdar_moe() -> "ModelConfig":
         """``model_preset=tiny_sdar_moe``: the small sibling of
         sdar_30b_a3b (tests, CPU rehearsals)."""
@@ -723,6 +799,23 @@ class ModelConfig:
                 n_shared_experts=1, moe_intermediate_size=48,
                 moe_shared_expert_intermediate_size=80, moe_latent_size=32,
                 moe_activation="relu2", routed_scaling_factor=5.0,
+            )
+            base.update(kw)
+            return ModelConfig(**base)
+        if arch == "lfm2_moe":
+            # the published first eight layers' pattern: two dense conv
+            # layers, then c c A c c c A c cut to A c c A c; 2 query
+            # heads a key/value head; a tied head
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=96, num_layers=7, num_heads=4,
+                num_kv_heads=2, head_dim=16, max_seq_len=128,
+                rope_theta=1e6, rms_norm_eps=1e-5, tie_word_embeddings=True,
+                layer_types=("conv", "conv", "full_attention", "conv",
+                             "conv", "full_attention", "conv"),
+                conv_L_cache=3, n_routed_experts=8, num_experts_per_tok=2,
+                moe_intermediate_size=32, first_k_dense_replace=2,
+                routed_scaling_factor=1.0, moe_scoring="sigmoid",
             )
             base.update(kw)
             return ModelConfig(**base)

@@ -64,6 +64,12 @@ def hf_state_dict(params: dict, cfg: ModelConfig) -> Dict[str, np.ndarray]:
             "layer's convolution [channels, 1, taps], A_log, D, dt_bias and "
             "gated norm; per-expert up / down tensors and the latent "
             "projections; a share of the heads is part of a checkpoint)")
+    if cfg.arch == "lfm2_moe":
+        raise ValueError(
+            "HF export of arch='lfm2_moe' is not written: there is no "
+            "lfm2_moe checkpoint layout on either side yet (a convolution "
+            "layer's in_proj / conv [channels, 1, taps] / out_proj, "
+            "per-expert w1 / w2 / w3 tensors, expert_bias)")
     if cfg.arch == "olmo_hybrid":
         raise ValueError(
             "HF export of arch='olmo_hybrid' is not written: there is no "

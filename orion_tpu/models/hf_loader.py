@@ -83,6 +83,14 @@ _NO_SDAR_LOADER = (
     "id none onto model.mask_token_id; arch='sdar_moe' runs from random "
     "weights only")
 
+_NO_LFM2_LOADER = (
+    "there is no lfm2_moe checkpoint loader yet: a convolution layer's "
+    "in_proj / conv [channels, 1, taps] / out_proj, the per-expert w1 / w2 "
+    "/ w3 tensors and expert_bias have no mapping onto "
+    "models.transformer.ShortConv's and ops.moe.TopKMoE's names and "
+    "[taps, channels] layout; arch='lfm2_moe' runs from random weights "
+    "only")
+
 
 def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
                           include_lm_head: bool = True) -> dict:
@@ -102,6 +110,8 @@ def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
         raise ValueError(_NO_SDAR_LOADER)
     elif cfg.arch == "nemotron_h":
         raise ValueError(_NO_NEMOTRON_H_LOADER)
+    elif cfg.arch == "lfm2_moe":
+        raise ValueError(_NO_LFM2_LOADER)
     else:
         raise ValueError(cfg.arch)
     if not include_lm_head:
@@ -266,6 +276,8 @@ def config_from_hf(hf_cfg: Any) -> ModelConfig:
         raise ValueError(_NO_SDAR_LOADER)
     if mt == "nemotron_h":
         raise ValueError(_NO_NEMOTRON_H_LOADER)
+    if mt == "lfm2_moe":
+        raise ValueError(_NO_LFM2_LOADER)
     if mt == "llama":
         return ModelConfig(
             arch="llama",

@@ -10,7 +10,8 @@ it reports on the trainer's spans (:func:`decode_attrs`,
 :func:`update_attrs`), which parameters RL holds fixed and the forms it
 cannot run (:func:`cannot_run`).  The mixers: :class:`Attention`,
 :class:`SparseAttention`, :class:`LatentAttention`,
-:class:`KimiDeltaAttention`, :class:`GatedDeltaNet`, :class:`Mamba2`;
+:class:`KimiDeltaAttention`, :class:`GatedDeltaNet`, :class:`Mamba2`,
+:class:`ShortConv`;
 the feed-forward halves: :class:`MLP`, ``ops.moe.MoEMLP`` (GShard,
 ``num_experts``), ``ops.moe.TopKMoE`` (the dropless expert layer).
 :func:`mixer_spec` is the one place where an arch picks anything; where
@@ -965,31 +966,41 @@ def _delta_rule(scope, q, k, v, g, beta, layer_cache, ext, token_mask):
 
 
 class StateKind(Kind):
-    """A mixer whose cache entry is a state: {"S": float32 [B, *state],
-    "conv": [B, taps - 1, conv]}, the recurrence's state and its
-    convolutions' last inputs after the last token a row holds.
-    ``sizes(cfg)`` -> (state, taps, conv, projs, out): those, the widths
-    of the input projections it tags ``attn_qkv`` and the width of the
-    recurrence's float32 output it tags ``attn_out``."""
+    """A mixer whose cache entry is a state: not indexed by position,
+    read and written whole a step.  What every such mixer keeps is its
+    convolution's window, {"conv": [B, taps - 1, conv]}: the last inputs
+    after the last token a row holds (a recurrence's own state is
+    :class:`RecurrentKind`'s).  ``sizes(cfg)`` -> (state, taps, conv,
+    projs, out): a recurrence's state (None without one), the window's
+    sizes, the widths of the input projections it tags ``attn_qkv`` and
+    the width of the output it tags ``attn_out``, held in ``out_dtype``
+    (None: the compute dtype)."""
 
     cache_kind = "state"
     takes_token_mask = True
+    leaves = ("conv",)
+    out_dtype = None
     # what differs in lacks()
-    whose, handed = "a delta-rule layer's", "a recurrent state"
+    state = "a convolution window"
+    int8_of = "a convolution's last inputs, two rows a sequence"
+    whose, handed = "a gated short convolution's", None     # None: state
     no_int8 = ("the int8 Dense twins do not reach this block (no "
                "QuantDense decode twin was run against its reference)")
 
     @classmethod
     def cache_entry(cls, cfg, batch, slots, dtype, pre=()):
-        state, taps, conv, _, _ = cls.sizes(cfg)
-        return {"S": jnp.zeros(pre + (batch,) + state, jnp.float32),
-                "conv": jnp.zeros(pre + (batch, taps - 1, conv), dtype)}
+        _, taps, conv, _, _ = cls.sizes(cfg)
+        return {"conv": jnp.zeros(pre + (batch, taps - 1, conv), dtype)}
+
+    @classmethod
+    def _leaves(cls) -> str:
+        return "{" + ", ".join(cls.leaves) + "}"
 
     @classmethod
     def check_entry(cls, layer_cache):
-        if layer_cache is not None and "S" not in layer_cache:
+        if layer_cache is not None and cls.leaves[0] not in layer_cache:
             raise ValueError(
-                f"{cls.__name__} caches {{'S', 'conv'}} (init_cache): a "
+                f"{cls.__name__} caches {cls._leaves()} (init_cache): a "
                 "state, not keys and values by position")
 
     @classmethod
@@ -997,25 +1008,43 @@ class StateKind(Kind):
         *_, projs, out = cls.sizes(cfg)
         n = rows * seq_len
         return {"attn_qkv": n * sum(map(w, projs)) * _dt(cfg.dtype).itemsize,
-                "attn_out": n * w(out) * 4}
+                "attn_out": n * w(out)
+                * _dt(cls.out_dtype or cfg.dtype).itemsize}
 
     @classmethod
     def lacks(cls, cfg):
         return {
-            "paged": "a recurrent state is not made of pages "
+            "paged": f"{cls.state} is not made of pages "
             "(init_paged_cache gives every layer pages)",
-            "continuous": "its cache manager holds no recurrent state per "
+            "continuous": f"its cache manager holds no {cls.state[2:]} per "
             "slot (admission, preemption and prefix reuse move pages, and "
-            f"a state is not made of pages: {cls.whose} {{S, conv}})",
-            "quantize_kv": "a recurrent state has no int8 form "
+            f"a state is not made of pages: {cls.whose} {cls._leaves()})",
+            "quantize_kv": f"{cls.state} has no int8 form "
             "(ops/quant.py scales keys and values per head; an int8 form "
-            "of a float32 recurrent state is not written)",
+            f"of {cls.int8_of} is not written)",
             "quantize_weights": cls.no_int8,
-            "sequence_parallel": f"there is no hand-over of {cls.handed} "
-            "between sequence shards"}
+            "sequence_parallel": "there is no hand-over of "
+            f"{cls.handed or cls.state} between sequence shards"}
 
 
-class DeltaKind(StateKind):
+class RecurrentKind(StateKind):
+    """A state-kind mixer with a recurrence: its entry holds the
+    recurrence's float32 state {"S": [B, *state]} before the window, and
+    its tagged output is the recurrence's, float32."""
+
+    leaves = ("S", "conv")
+    out_dtype = "float32"
+    state, int8_of = "a recurrent state", "a float32 recurrent state"
+    whose = "a delta-rule layer's"
+
+    @classmethod
+    def cache_entry(cls, cfg, batch, slots, dtype, pre=()):
+        return {"S": jnp.zeros(pre + (batch,) + cls.sizes(cfg)[0],
+                               jnp.float32),
+                **super().cache_entry(cfg, batch, slots, dtype, pre)}
+
+
+class DeltaKind(RecurrentKind):
     """A mixer that runs the delta rule (``ops/kda.py``): its span
     attributes are the forms the one-token step and the chunked rule
     take in this process's traces (``kernel`` / ``jnp``)."""
@@ -1067,7 +1096,7 @@ class KimiDeltaAttention(nn.Module, DeltaKind):
     both float32; output ``W_o concat_h(RMSNorm(o) * sigmoid(x W_ga
     W_gb))``.  No position enters it.
 
-    One new token against a cache (:class:`StateKind`) takes
+    One new token against a cache (:class:`RecurrentKind`) takes
     :func:`ops.kda.kda_step`; everything else the chunked form.
 
     ``token_mask`` [B, L]: a position that holds no token leaves the
@@ -1276,7 +1305,7 @@ class GatedDeltaNet(nn.Module, DeltaKind):
                       "o_proj")(norm_and_gate(o, z, o_norm)), new_cache
 
 
-class Mamba2(nn.Module, StateKind):
+class Mamba2(nn.Module, RecurrentKind):
     """A Mamba-2 mixer (nemotron_h's ``M``): the state-space recurrence
     of ``ops/mamba2.py`` on the H heads of ``mamba_head_dim`` P and the G
     groups that ``cfg.heads_held()`` leaves here, state ``ssm_state_size``
@@ -1292,7 +1321,7 @@ class Mamba2(nn.Module, StateKind):
     group's ``(H / G) P`` channels with a learned weight.  No position
     enters it.
 
-    One new token against a cache (:class:`StateKind`) takes
+    One new token against a cache (:class:`RecurrentKind`) takes
     :func:`ops.mamba2.mamba2_step`; everything else the chunked form.
     ``token_mask`` as :class:`KimiDeltaAttention`'s: a position that
     holds no token has ``dt = 0`` (decay 1, no input) and the mask must
@@ -1392,6 +1421,87 @@ class Mamba2(nn.Module, StateKind):
                       "out_proj")(gate_and_norm(y, z, norm)), new_cache
 
 
+class ShortConv(nn.Module, StateKind):
+    """LFM2's gated short convolution (``layer_types`` entry ``conv``).
+
+    ``[b | c | z] = u W_in`` (hidden -> 3 hidden, no bias, the thirds in
+    this order); ``s = b * z``; ``v_t = sum_j w_j * s_{t - taps + 1 +
+    j}``, a depthwise causal convolution of ``conv_L_cache`` taps, no
+    bias, no activation, zeros before the sequence; ``W_out (c * v)``.
+    No position enters it.
+
+    ``s`` is held in the compute dtype (it is what the cache keeps:
+    :class:`StateKind`, the last ``taps - 1`` of it a sequence, and
+    nothing else); the taps' multiply-adds and the gate run in float32.
+    One new token against a cache reads the kept rows and its own
+    (``short_conv.step``); everything else is one pass over the
+    sequence (``short_conv.chunk``).  ``token_mask`` as
+    :class:`KimiDeltaAttention`'s: the mask must be a row's prefix, and
+    prefill hands on each row's last real inputs.
+    """
+
+    cfg: ModelConfig
+
+    @staticmethod
+    def sizes(cfg):
+        E = cfg.hidden_size
+        return None, cfg.conv_L_cache, E, (3 * E,), E
+
+    @staticmethod
+    def forward_attrs(cfg, total_lens):
+        """How many layers run it and over how many taps, and the
+        experts this chip holds beside them (the benchmark's operation
+        count reads all three off the span: it hard-codes no count)."""
+        return {"conv_layers": sum(m == "conv" for m, _ in cfg.layer_kinds()),
+                "conv_taps": cfg.conv_L_cache,
+                "experts_held": cfg.experts_held}
+
+    @staticmethod
+    def decode_attrs(cfg, lens, slots, new_tokens):
+        return ShortConv.forward_attrs(cfg, lens)
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None, token_mask=None):
+        cfg = self.cfg
+        B, L, E = x.shape
+        taps = cfg.conv_L_cache
+        f32 = jnp.float32
+        self.check_entry(layer_cache)
+        step = layer_cache is not None and L == 1
+
+        proj = checkpoint_name(
+            _dense(3 * E, ("embed", "gates"), False, cfg, "in_proj")(x),
+            "attn_qkv")
+        w_conv = self.param(
+            "conv_weight", nn.with_logical_partitioning(
+                _delta_conv_init, ("conv", "gates")), (taps, E),
+            _dt(cfg.param_dtype)).astype(f32)
+        prev = (layer_cache["conv"].astype(proj.dtype)
+                if layer_cache is not None
+                else jnp.zeros((B, taps - 1, E), proj.dtype))
+
+        # one elementwise stretch between the two products: a backward
+        # keeps its bf16 inputs and recomputes the float32 in between
+        @jax.checkpoint
+        def gate_and_convolve(proj, prev, w_conv):
+            b, c, z = jnp.split(proj, 3, axis=-1)
+            ext = jnp.concatenate([prev, b * z], axis=1)
+            y = c.astype(f32) * _short_conv(ext, w_conv, L)
+            return y.astype(proj.dtype), ext
+
+        with jax.named_scope("short_conv.step" if step
+                             else "short_conv.chunk"):
+            y, ext = gate_and_convolve(proj, prev, w_conv)
+        new_cache = None
+        if step:
+            new_cache = {"conv": ext[:, 1:]}
+        elif layer_cache is not None:
+            new_cache = {"conv": _conv_handover(ext, token_mask, taps)}
+        y = checkpoint_name(y, "attn_out")
+        return _dense(E, ("gates", "embed"), False, cfg,
+                      "out_proj")(y), new_cache
+
+
 class MLP(nn.Module, Kind):
     cfg: ModelConfig
 
@@ -1425,7 +1535,7 @@ class MLP(nn.Module, Kind):
 #: them and state what follows from them (:class:`Kind`).
 MIXERS = {"attention": Attention, "sparse": SparseAttention,
           "latent": LatentAttention, "kda": KimiDeltaAttention,
-          "gdn": GatedDeltaNet, "mamba2": Mamba2}
+          "gdn": GatedDeltaNet, "mamba2": Mamba2, "conv": ShortConv}
 
 
 def mixer_spec(cfg: ModelConfig, kind: str):
@@ -1435,7 +1545,8 @@ def mixer_spec(cfg: ModelConfig, kind: str):
     if cfg.arch == "olmo_hybrid" and kind == "attention":
         # rope_theta is published null (0 here): no rotation
         kw = {"qk_norm": True, "rotary": cfg.rope_theta > 0}
-    elif cfg.arch in ("keye_dsa", "sdar_moe"):
+    elif cfg.arch in ("keye_dsa", "sdar_moe", "lfm2_moe") \
+            and issubclass(MIXERS[kind], Attention):
         kw = {"qk_norm": "head"}
     return MIXERS[kind], kw
 

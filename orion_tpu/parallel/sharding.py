@@ -28,6 +28,10 @@ Rules (MaxText/T5X-style):
             norm and output projection → (replicated: a tensor shard of
             the whole width would cut across the parts; the chip's share
             of the heads is ``ModelConfig.head_share``, not a mesh axis)
+  gates   — a gated short convolution's one input projection (b | c | z,
+            hidden → 3 x hidden), its taps' channels ([taps, hidden])
+            and its output projection's rows → (replicated: a tensor
+            shard of the 3 x hidden would cut across the thirds)
 """
 
 from __future__ import annotations
@@ -50,6 +54,7 @@ LOGICAL_RULES: dict = {
     "conv": None,       # the 4 taps of a KDA layer's convolutions
     "index": None,      # the sparse-attention indexer's projections
     "ssm": None,        # a Mamba-2 layer's projections, conv and norm
+    "gates": None,      # a gated short convolution's b | c | z and taps
     "expert": "expert",
     "batch": ("data", "fsdp"),
     "seq": "seq",

@@ -153,6 +153,9 @@ FLASH_SHAPES = {
     # eight heads' blocks and 1024-wide major blocks in one grid step)
     "nemotron_h": (16, 1280, 1280, 8, 1, 128, 0),
     "keye_dense": (2, 8192, 8192, 32, 4, 128, 0),
+    # LFM2's attention layers as ppo-lfm2-ep4-sync runs them: heads of
+    # 64 (half a lane tile), four query heads a key head, 1280 tokens
+    "lfm2": (16, 1280, 1280, 32, 8, 64, 0),
     # tests/test_tpu_smoke.py regression shapes: a cache length that is
     # no multiple of 128 ...
     "odd_cache_144": (2, 16, 144, 8, 4, 64, 128),
@@ -232,7 +235,7 @@ def _assert_group_gradients_leave_summed(compiled, name):
 
 
 @pytest.mark.parametrize("name", ["pythia1b", "llama8b", "nemotron_h",
-                                  "keye_dense"])
+                                  "keye_dense", "lfm2"])
 def test_flash_fwd_bwd_compiles_for_v5e(name, one_chip, on_tpu):
     fwd = _flash(name)
 
@@ -1203,4 +1206,115 @@ def test_sdar_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
         assert {"moe_gmm", "moe_gmm_dlhs", "moe_tgmm",
                 "moe_combine"} <= set(names)
         assert mem.argument_size_in_bytes == pytest.approx(5.16e9, rel=1e-2)
+    assert mem.peak_memory_in_bytes + resident <= V5E_BYTES_LIMIT
+
+
+# -- the lfm2_moe model (gated short convolutions) at the published widths ----
+
+def _lfm2_cell():
+    """(model configuration, B, P, T, minibatch) of ``ppo-lfm2-ep4-sync``."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+
+    mc = dataclasses.replace(ModelConfig.lfm2_8b_a1b(), num_layers=8,
+                             experts_held=8, vocab_size=16384,
+                             max_seq_len=1280)
+    assert mc.layer_runs() == (
+        (0, 2, "conv", "dense"), (2, 1, "attention", "experts"),
+        (3, 3, "conv", "experts"), (6, 1, "attention", "experts"),
+        (7, 1, "conv", "experts"))
+    return mc, 64, 256, 1024, 16
+
+
+@pytest.mark.parametrize("program", ["generate", "experience", "update"])
+def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
+    """The three programs of ``ppo-lfm2-ep4-sync`` (the published layers
+    0-7 of LFM2-8B-A1B, 8 of 32 experts, 16 384 rows of the vocabulary,
+    the head tied; remat, each stretch scanned) at the timed shapes, 64
+    prompts padded to 256 and 1024 new tokens.  ``generate``: prefill
+    (``short_conv.chunk``, flash at heads of 64) and the decode loop
+    (``short_conv.step`` on six layers, the prefix step over the
+    per-head cache on two: one ``conditional`` of 8 branches each, four
+    query heads a key head).  ``experience``: one forward of all 64
+    rows.  ``update``: the forward, remat's and the backward in
+    minibatches of 16.  No kernel of the convolution's own: XLA fuses
+    the taps between the two products.  Each fits beside what else the
+    chip holds: 772.2 M parameters are 6.18 GB of float32 master and
+    bf16 moments, the bf16 reference 1.54 GB more."""
+    import re
+
+    from orion_tpu.config import RolloutConfig
+    from orion_tpu.rollout.engine import RolloutEngine
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.trainers.ppo import PPOTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc, B, P, T, rows = _lfm2_cell()
+    shell, pshape, mb = _build_8b_shell(mc)
+    shell.cfg.rollout.max_prompt_len = P
+    shell.cfg.rollout.max_new_tokens = T
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), pshape)
+    ids = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+    resident = 1.54e9              # the bf16 reference
+    with jax.default_matmul_precision("default"):
+        if program == "generate":
+            eng = RolloutEngine(shell.model, mc, RolloutConfig(
+                max_prompt_len=P, max_new_tokens=T), eos_token_id=None,
+                pad_token_id=0)
+            rng = jax.eval_shape(lambda: jax.random.key(0))
+            lowered = eng._generate_jit.lower(
+                params, ids(B, P), ids(B), _sds(rng.shape, rng.dtype,
+                                                one_chip),
+                max_new_tokens=T)
+            resident += 2 * 1.54e9          # and the moments
+        elif program == "experience":
+            lowered = jax.jit(
+                lambda p, s, n, m: PPOTrainer._lp_values_fwd(
+                    shell, p, s, n, m, max_new=T, with_entropy=False)).lower(
+                        params, ids(B, P + T), ids(B),
+                        _sds((B, T), jnp.float32, one_chip))
+            resident += 2 * 1.54e9
+        else:
+            shapes = {k: (P + T,) if k == "sequences"
+                      else () if k == "prompt_lens" else (T,) for k in mb}
+            experience = {k: _sds((B,) + shapes[k], v.dtype, one_chip)
+                          for k, v in mb.items()}
+            state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                                 _abstract_state(shell, pshape))
+            lowered = jax.jit(
+                lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+                donate_argnums=(0,)).lower(
+                    state, experience,
+                    _sds((B // rows, rows), jnp.int32, one_chip))
+        scopes = lowered.as_text(debug_info=True)
+        compiled = lowered.compile()
+    names = _kernel_names(compiled)
+    mem = compiled.memory_analysis()
+    assert "short_conv.chunk" in scopes
+    if program == "generate":
+        assert "short_conv.step" in scopes
+        assert "flash_fwd" in names                      # the prefill's
+        assert not {"flash_bwd_dq", "paged_decode"} & set(names)
+        assert mem.argument_size_in_bytes == pytest.approx(3.09e9, rel=1e-2)
+        text = compiled.as_text()
+        bodies = [body for body in _while_bodies(text).values()
+                  if " conditional(" in body]
+        decode = max(bodies, key=len)
+        found = re.findall(
+            r" conditional\(.*branch_computations=\{([^}]*)\}", decode)
+        assert len(found) == 2 and all(
+            len(f.split(",")) == 8 for f in found)
+        # the two-row windows ride the loop; no cache-shaped copy in it
+        assert not re.search(r"= bf16\[64,1280,8,64\]\S* copy(-start)?\(",
+                             decode)
+    elif program == "experience":
+        assert "short_conv.step" not in scopes
+        assert names.count("flash_fwd") == 2     # one a stretch of attention
+        assert {"moe_gmm", "moe_combine"} <= set(names)
+    else:
+        assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm",
+                "moe_gmm_dlhs", "moe_tgmm", "moe_combine"} <= set(names)
+        assert mem.argument_size_in_bytes == pytest.approx(6.18e9, rel=1e-2)
     assert mem.peak_memory_in_bytes + resident <= V5E_BYTES_LIMIT
