@@ -55,6 +55,7 @@ from orion_tpu.config import ModelConfig
 from orion_tpu.ops.attention import (_NEG_INF, attention, step_attention,
                                      streams_attention)
 from orion_tpu.ops.paged_kv import is_paged, write_paged_tokens
+from orion_tpu.ops.pallas import dense_step
 from orion_tpu.ops.rotary import apply_rotary
 
 # Unrolled models: a per-layer list of cache entries, each what its
@@ -560,17 +561,25 @@ class Attention(nn.Module, Kind):
             out = paged_decode_out[:, None, :, :]
         elif step and not is_paged(layer_cache):
             # one new token (one block's, two's) against the dense slot
-            # cache, int8 or not: over the filled prefix of its slots
+            # cache, int8 or not
             Lmax = new_cache["k"].shape[1]
-            whole = mask(Lmax)
+            if dense_step.step_form(L, H, k.shape[2], Lmax,
+                                    "k_scale" in new_cache):
+                # a group of query heads on each of several key heads:
+                # the kernel, over each row's filled blocks
+                out = dense_step.dense_step(
+                    q, new_cache["k"], new_cache["v"], see[:, 0], scale)
+            else:
+                # over the filled prefix of its slots
+                whole = mask(Lmax)
 
-            def attend(m):
-                c = {n: a[:, :m] for n, a in new_cache.items()}
-                return step_attention(
-                    q, c["k"], c["v"], whole[..., :m], scale,
-                    c.get("k_scale"), c.get("v_scale"))
+                def attend(m):
+                    c = {n: a[:, :m] for n, a in new_cache.items()}
+                    return step_attention(
+                        q, c["k"], c["v"], whole[..., :m], scale,
+                        c.get("k_scale"), c.get("v_scale"))
 
-            out = prefix_step(see, Lmax, attend)
+                out = prefix_step(see, Lmax, attend)
         elif visible is not None and visible.clean < L:
             # a trainer's trace forward: the clean stream and its noisy
             # streams in one row, no cache
@@ -1606,20 +1615,29 @@ def cannot_run(cfg: ModelConfig, form: str) -> Optional[str]:
 
 
 def decode_attrs(cfg: ModelConfig, lens=None, slots: int = 0,
-                 new_tokens: int = 0) -> dict:
+                 new_tokens: int = 0, quantized: bool = False) -> dict:
     """What the ``rollout.dispatch`` span carries of the model:
     ``attn_heads_a_step`` and ``kda_step`` (``""`` without a delta-rule
     layer) always; where the decode loop steps over a dense cache of
-    ``slots`` slots after prompts of ``lens`` real tokens (None: a page
-    pool), each kind's own and how the steps that go through
-    :func:`prefix_step` read it: ``kv_step_form``, ``prefix`` (the
-    filled blocks) / ``whole`` (a cache of one block), and
-    ``kv_step_slots``, the slots one row's step reads a layer (mean)."""
+    ``slots`` slots (int8 under ``quantized``) after prompts of ``lens``
+    real tokens (None: a page pool), each kind's own and how the
+    one-token steps over a slot cache read it: ``kv_step_form``,
+    ``kernel`` (``ops/pallas/dense_step.py``: each row's filled blocks),
+    under :func:`prefix_step` ``prefix`` (the batch's filled blocks) /
+    ``whole`` (a cache of one block), and ``kv_step_slots``, the slots
+    one row's step reads a layer (mean)."""
     attrs = {"kda_step": "", "attn_heads_a_step": cfg.attn_heads_a_step()}
     if lens is None:
         return attrs
     of = kinds(cfg)
-    if any(kind.steps_over_prefix for kind in of):
+    held = cfg.heads_held()
+    if Attention in of and dense_step.step_form(
+            cfg.block_length or 1, held["q"], held["kv"], slots, quantized):
+        attrs.update(
+            kv_step_form="kernel",
+            kv_step_slots=dense_step.step_slots(lens, slots, held["kv"],
+                                                new_tokens - 1))
+    elif any(kind.steps_over_prefix for kind in of):
         attrs.update(
             kv_step_form="prefix" if len(prefix_lengths(slots)) > 1
             else "whole",

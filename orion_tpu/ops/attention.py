@@ -90,7 +90,16 @@ def step_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     einsum a convolution, whose operand a slice of the cache is first
     copied into, where this form reads the slice inside its fusion as
     ``q k^T`` does (PERF.md section 6, PR 43).  A group of query heads a
-    key head makes it a matrix product: the einsum."""
+    key head makes it a matrix product: the einsum.  Over ONE key head
+    (Nemotron-H's quarter) that einsum has nothing to re-lay and is what
+    runs; over SEVERAL the TPU compiler first copies the prefix of K and
+    of V, slots minor-most, at every step, so on one TPU device one
+    token's grouped step over a bf16 cache does not come here but goes
+    to the kernel ``ops/pallas/dense_step.py`` (its ``step_form``: the
+    rule is ``Hkv > 1 and g > 1``, not ``g > 1`` alone: at Nemotron-H's
+    shapes, 32 rows of 1280 slots, 8 query heads on one key head of 128,
+    this einsum's step took 23 / 34 / 44 us at 256 / 768 / 1280 filled
+    slots and the kernel 30 / 52 / 78; PERF.md section 6, PR 50)."""
     B, Lq, H, D = q.shape
     Hkv = k.shape[2]
     g = H // Hkv

@@ -56,12 +56,15 @@ _DEFAULT = jax.lax.Precision.DEFAULT
 BLOCK_SLOTS = 512
 
 
-def _kernel(last_ref, q_ref, k_ref, v_ref, keep_ref, head_ref, o_ref,
-            m_sc, l_sc, acc_sc, *, scale):
-    """last [B] in SMEM; q [1, H, D]; k, v [1, rows, D]; keep [1, nseg,
-    W] float32 bias (0 kept, NEG_INF not; segment i is columns i W .. of
-    the block's rows); head [H, W] float32 bias (0 where the row is of
-    the query head's key head) -> o [1, H, D]."""
+def flash_block(last_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
+                masked):
+    """One grid step (sequence b, key block j) of a one-token flash
+    decode, shared by this kernel and ``dense_step.py``'s: last [B] in
+    SMEM (the row's last filled block; later steps do nothing), q [1, H,
+    D], k, v [1, rows, D] -> o [1, H, D] at the last step.  ``masked``
+    takes the products ``q . k`` [H, rows] float32 to the scaled scores
+    under the caller's bias (NEG_INF where a query head may not see a
+    row)."""
     b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -72,14 +75,9 @@ def _kernel(last_ref, q_ref, k_ref, v_ref, keep_ref, head_ref, o_ref,
 
     @pl.when(j <= last_ref[b])
     def _():
-        s = jax.lax.dot_general(
+        s = masked(jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=F32, precision=_DEFAULT)  # [H, rows]
-        nseg, W = keep_ref.shape[1:]
-        head = head_ref[...]
-        s = jnp.concatenate(
-            [s[:, i * W:(i + 1) * W] * scale + head + keep_ref[0, i:i + 1]
-             for i in range(nseg)], axis=1)
+            preferred_element_type=F32, precision=_DEFAULT))  # [H, rows]
         m_prev = m_sc[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a row with nothing kept yet sums its masked columns at weight
@@ -95,6 +93,24 @@ def _kernel(last_ref, q_ref, k_ref, v_ref, keep_ref, head_ref, o_ref,
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
         o_ref[0] = (acc_sc[...] / l_sc[...]).astype(o_ref.dtype)
+
+
+def _kernel(last_ref, q_ref, k_ref, v_ref, keep_ref, head_ref, o_ref,
+            m_sc, l_sc, acc_sc, *, scale):
+    """:func:`flash_block` under keep [1, nseg, W] float32 bias (0 kept,
+    NEG_INF not; segment i is columns i W .. of the block's rows) and
+    head [H, W] float32 bias (0 where the row is of the query head's key
+    head)."""
+
+    def masked(s):
+        nseg, W = keep_ref.shape[1:]
+        head = head_ref[...]
+        return jnp.concatenate(
+            [s[:, i * W:(i + 1) * W] * scale + head + keep_ref[0, i:i + 1]
+             for i in range(nseg)], axis=1)
+
+    flash_block(last_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
+                masked)
 
 
 def step_form(cache_len: int) -> str:

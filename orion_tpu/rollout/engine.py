@@ -196,7 +196,8 @@ class RolloutEngine:
         T = self.cfg.max_new_tokens
         slots = cache_slots(self._positions(prompts_shape[1], T))
         return {**self._cache_shapes(prompts_shape[0], slots), **weights,
-                **decode_attrs(self.model_cfg, lens, slots, T)}
+                **decode_attrs(self.model_cfg, lens, slots, T,
+                               self.cfg.quantize_kv)}
 
     def _positions(self, prompt_len: int, new_tokens: int) -> int:
         """The positions a row of the cache must hold: prompt and new

@@ -94,9 +94,29 @@ def pytest_configure(config):
         "markers", "slow: excluded from the tier-1 gate "
         "(`-m 'not slow'`) — wall-clock-heavy scenarios (e.g. watchdog "
         "stall detection) that the nightly full suite still runs")
+    # xdist's load scheduling hands a worker that has just run slow
+    # tests half its share of everything pending as ONE run of
+    # consecutive tests, so where that run fell decided the wall: 35 of
+    # ``test_chip_compile.py``'s cases on one worker kept it busy for
+    # 1300 s while five idled, and 31 new tests elsewhere were enough
+    # to move the boundary there.  A worker is topped up a few tests at
+    # a time instead (an explicit --maxschedchunk wins).
+    if getattr(config.option, "maxschedchunk", 0) is None:
+        config.option.maxschedchunk = SCHED_CHUNK
+
+
+# This file's compiles for a described chip run on many threads each
+# and, where their results are not kept yet (``_compile_once`` there),
+# are half of the suite's CPU seconds.
+COMPILES_FIRST = "test_chip_compile.py"
+SCHED_CHUNK = 4
 
 
 def pytest_collection_modifyitems(config, items):
+    # The long compiles start the run, dealt out SCHED_CHUNK at a time
+    # to whichever worker is free, and the short tests fill the end: a
+    # stable sort, the same in every worker.
+    items.sort(key=lambda item: item.path.name != COMPILES_FIRST)
     skip_tpu = pytest.mark.skip(
         reason="TPU smoke: run with `pytest -m tpu` on a TPU box")
     for item in items:

@@ -6,7 +6,7 @@ shows what interpret mode cannot (tiling, VMEM, Mosaic lowering rules)
 at no chip time.  A compile that passes is not a chip run.
 
 Rules this file keeps (the suite runs under ``-p xdist -n 6 --dist
-loadfile``; every worker imports every test file):
+load``; every worker imports every test file):
 
 - the topology is described INSIDE a module-scoped fixture that skips
   on failure — never at import, in a ``skipif``, in ``parametrize``, in
@@ -16,13 +16,23 @@ loadfile``; every worker imports every test file):
 - all such tests live in THIS one file (a second file could land on a
   worker that cannot load the library and skip in silence);
 - the persistent compile cache is off around these compiles: an entry
-  written for a described chip cannot be read back without one.
+  written for a described chip cannot be read back without one.  What
+  the tests read off a compiled program, its text and its memory
+  analysis, needs no chip: ``_compile_once`` keeps those two under the
+  compile cache's directory by the digest of the compiler's whole input
+  (these compiles were half of the suite's CPU seconds, 4700 of them,
+  the same every run; ``ORION_TEST_NO_COMPILE_CACHE=1`` compiles anew).
 
 ``interpret_mode()`` asks the default backend (CPU here); the tests
 steer it with monkeypatch — the program gets no option for it.
 """
 
+import contextlib
+import hashlib
+import json
 import os
+import types
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -32,25 +42,153 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 BF16 = jnp.bfloat16
+TOPOLOGY = "v5e:2x2"    # the one chip described here; in every digest
 
 
 @pytest.fixture(scope="module")
 def topo():
     from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache as cc
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         t = topologies.get_topology_desc(platform="tpu",
-                                         topology_name="v5e:2x2")
+                                         topology_name=TOPOLOGY)
     except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        pytest.skip(f"no {TOPOLOGY} topology can be described here: {e}")
+    with _compile_cache_off() as was_on:
+        patch = pytest.MonkeyPatch()
+        if was_on and jax.config.jax_compilation_cache_dir:
+            patch.setattr(jax.stages.Lowered, "compile", _compile_once)
+        yield t
+        patch.undo()
+
+
+@contextlib.contextmanager
+def _compile_cache_off():
+    """jax's persistent compile cache off inside; yields whether it was
+    on."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield t
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
+    try:
+        yield prev
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+class _Compiled:
+    """The two things this file reads off a compiled program."""
+
+    def __init__(self, text: str, memory: dict):
+        self._text, self._memory = text, types.SimpleNamespace(**memory)
+
+    def as_text(self) -> str:
+        return self._text
+
+    def memory_analysis(self):
+        return self._memory
+
+
+_MEMORY = ("alias_size_in_bytes", "argument_size_in_bytes",
+           "generated_code_size_in_bytes", "output_size_in_bytes",
+           "peak_memory_in_bytes", "temp_size_in_bytes")
+_real_compile = jax.stages.Lowered.compile
+
+
+def _kept_dir() -> str:
+    return os.path.join(jax.config.jax_compilation_cache_dir,
+                        "described_chip")
+
+
+def _compile_once(lowered, *args, **kwargs):
+    """``Lowered.compile`` while this module's tests run: the compiled
+    text and memory analysis of a program the compiler has seen before
+    (the same lowered module with its kernels' bodies, the same chip,
+    jax, jaxlib and libtpu, the same flags in the environment) are read
+    back from ``<compile cache>/described_chip/<digest>``; anything
+    else is compiled, and kept if it compiles.  A refusal is never
+    kept."""
+    import importlib.metadata as md
+
+    if args or kwargs:
+        return _real_compile(lowered, *args, **kwargs)
+    versions = [md.version(name) for name in ("jax", "jaxlib", "libtpu")]
+    flags = [os.environ.get(name, "") for name in ("XLA_FLAGS",
+                                                   "LIBTPU_INIT_ARGS")]
+    digest = hashlib.sha256("\0".join(
+        [TOPOLOGY] + versions + flags + [lowered.as_text(debug_info=True)]
+    ).encode()).hexdigest()
+    path = os.path.join(_kept_dir(), digest)
+    try:
+        with open(path, "rb") as f:
+            kept = json.loads(zlib.decompress(f.read()))
+        return _Compiled(kept["text"], kept["memory"])
+    except (OSError, ValueError, KeyError, zlib.error):
+        pass
+    compiled = _real_compile(lowered)
+    stats = compiled.memory_analysis()
+    kept = {"text": compiled.as_text(),
+            "memory": {name: getattr(stats, name) for name in _MEMORY}}
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    scratch = "%s.%d" % (path, os.getpid())
+    with open(scratch, "wb") as f:
+        f.write(zlib.compress(json.dumps(kept).encode(), 1))
+    os.replace(scratch, path)
+    return _Compiled(kept["text"], kept["memory"])
+
+
+@pytest.fixture(autouse=True)
+def traced_afresh():
+    """Every test traces its program itself.  The scopes and lines a
+    compiled text names come from the locations of the lowered
+    operations, which are in the digest; a function traced by an earlier
+    test would bring that test's call stack, and the digest would follow
+    the schedule."""
+    jax.clear_caches()
+
+
+def test_a_compiled_text_is_kept_by_the_whole_of_the_compilers_input(
+        tmp_path, monkeypatch):
+    """``_compile_once`` (here on a CPU program: no topology) compiles a
+    program it has not seen, hands the same text and memory analysis
+    back for one it has, and takes a scope's name, which only the
+    locations carry, for another program; a file that is not a kept
+    entry is compiled over."""
+    import sys
+
+    here = sys.modules[__name__]
+    compiles, real = [], _real_compile
+    monkeypatch.setattr(here, "_kept_dir", lambda: str(tmp_path))
+    monkeypatch.setattr(here, "_real_compile",
+                        lambda lowered: compiles.append(1) or real(lowered))
+
+    def program(scope):
+        def double(x):
+            with jax.named_scope(scope):
+                return x * 2
+
+        return jax.jit(double).lower(jnp.ones(4))
+
+    # (jax's own cache leaves names out of its key and would hand the
+    # first program's text back for the renamed one)
+    with _compile_cache_off():
+        passes = []
+        for torn in (False, True):  # one call site: the same locations
+            if torn:
+                (tmp_path / min(os.listdir(tmp_path))).write_bytes(b"torn")
+            passes.append([_compile_once(program(scope))
+                           for scope in ("one", "one", "other")])
+            assert len(compiles) == 2 + torn
+    first, again, other = passes[0]
+    assert "/one/" in first.as_text() and "/other/" in other.as_text()
+    assert first.memory_analysis().argument_size_in_bytes == 16
+    for got in [again] + passes[1][:2]:
+        assert got.as_text() == first.as_text()
+        assert vars(got.memory_analysis()) == vars(first.memory_analysis())
+    assert passes[1][2].as_text() == other.as_text()
 
 
 @pytest.fixture(scope="module")
@@ -129,6 +267,22 @@ def _computations(text: str) -> dict:
         elif cur is not None:
             cur.append(ln)
     return {name: "\n".join(lines) for name, lines in comps.items()}
+
+
+def _called(comps: dict, name: str) -> set:
+    """``name`` and every computation it calls, directly or not."""
+    import re
+
+    seen, todo = set(), [name]
+    while todo:
+        cur = todo.pop()
+        if cur in seen or cur not in comps:
+            continue
+        seen.add(cur)
+        todo += re.findall(r"%?([\w.\-]+)", " ".join(re.findall(
+            r"(?:calls|to_apply|body|condition|branch_computations)="
+            r"\{?([^}\s,]+(?:, *[^}\s,]+)*)", comps[cur])))
+    return seen
 
 
 def _while_bodies(text: str) -> dict:
@@ -1234,9 +1388,10 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
     the head tied; remat, each stretch scanned) at the timed shapes, 64
     prompts padded to 256 and 1024 new tokens.  ``generate``: prefill
     (``short_conv.chunk``, flash at heads of 64) and the decode loop
-    (``short_conv.step`` on six layers, the prefix step over the
-    per-head cache on two: one ``conditional`` of 8 branches each, four
-    query heads a key head).  ``experience``: one forward of all 64
+    (``short_conv.step`` on six layers, the kernel ``dense_step`` over
+    the per-head cache on two, four query heads a key head: no
+    ``conditional`` over prefixes and no copy of the cache or of a
+    prefix of it, ISSUE 50).  ``experience``: one forward of all 64
     rows.  ``update``: the forward, remat's and the backward in
     minibatches of 16.  No kernel of the convolution's own: XLA fuses
     the taps between the two products.  Each fits beside what else the
@@ -1299,16 +1454,29 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
         assert not {"flash_bwd_dq", "paged_decode"} & set(names)
         assert mem.argument_size_in_bytes == pytest.approx(3.09e9, rel=1e-2)
         text = compiled.as_text()
-        bodies = [body for body in _while_bodies(text).values()
-                  if " conditional(" in body]
-        decode = max(bodies, key=len)
-        found = re.findall(
-            r" conditional\(.*branch_computations=\{([^}]*)\}", decode)
-        assert len(found) == 2 and all(
-            len(f.split(",")) == 8 for f in found)
-        # the two-row windows ride the loop; no cache-shaped copy in it
-        assert not re.search(r"= bf16\[64,1280,8,64\]\S* copy(-start)?\(",
-                             decode)
+        assert names.count("dense_step") == 2
+        (decode,) = [name for name, body in _while_bodies(text).items()
+                     if "%dense_step" in body]
+        comps = _computations(text)
+        # the step does not switch over prefixes of the cache, and
+        # nothing the loop runs copies or re-lays the cache or a prefix
+        # of it (PR 49's 32 ``copy bf16[64,<prefix>,8,64]{1,3,2,0}``)
+        for name in _called(comps, decode):
+            assert " conditional(" not in comps[name]
+            assert not re.search(
+                r"= bf16\[64,\d+,8,64\]\S* copy(-start)?\(", comps[name]), name
+        # the kernel takes k and v as the loop carries them: a bitcast
+        steps = re.findall(r"%dense_step[.\d]* = [^\n]*", comps[decode])
+        assert len(steps) == 2
+        for call in steps:
+            operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
+            for name in [o.strip().lstrip("%")
+                         for o in operands.split(",")][3:5]:
+                made = re.search(
+                    r"%%%s = (\S+) (\w[\w\-]*)\(" % re.escape(name),
+                    comps[decode])
+                assert made and made.group(2) == "bitcast", (name, made)
+                assert made.group(1).startswith("bf16[64,10240,64]")
     elif program == "experience":
         assert "short_conv.step" not in scopes
         assert names.count("flash_fwd") == 2     # one a stretch of attention

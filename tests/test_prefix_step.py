@@ -235,6 +235,145 @@ def test_step_attention_has_the_reference_numbers(g, int8):
                                atol=2e-6, rtol=2e-6)
 
 
+# -- a group of query heads on several key heads: the kernel, interpreted ------
+
+def _kernel_form(monkeypatch, block=None):
+    """The trace believes it is for one TPU device (the kernel runs
+    interpreted here); ``block``: the most slots a grid step holds."""
+    from orion_tpu.ops import indexer
+    from orion_tpu.ops.pallas import dense_step
+
+    monkeypatch.setattr(indexer, "select_form", lambda: "kernel")
+    if block:
+        monkeypatch.setattr(dense_step, "BLOCK_SLOTS", block)
+    return dense_step
+
+
+def _grouped(dtype, B=4, Lmax=320, H=32, Hkv=8, D=64, seed=0):
+    rs = np.random.RandomState(seed)
+    return [jnp.asarray(rs.standard_normal(shape), dtype) for shape in (
+        (B, 1, H, D), (B, Lmax, Hkv, D), (B, Lmax, Hkv, D))]
+
+
+@pytest.mark.parametrize("dtype, tol", [("float32", 2e-6),
+                                        ("bfloat16", 2e-2)])
+def test_dense_step_has_the_reference_numbers(dtype, tol):
+    """``dense_step`` interpreted at LFM2's heads (32 on 8 of 64) over a
+    cache of 320 slots (no whole blocks of 512: two of 160), rows filled
+    to unequal lengths, against ``reference_attention_gqa``."""
+    from orion_tpu.ops.attention import reference_attention_gqa
+    from orion_tpu.ops.pallas import dense_step
+
+    q, k, v = _grouped(dtype)
+    assert dense_step.block_slots(320, 8) == 160
+    pos = jnp.asarray([3, 159, 160, 319])
+    mask = jnp.arange(320)[None, None, :] <= pos[:, None, None]
+    got = dense_step.dense_step(q, k, v, pos, 0.125)
+    want = reference_attention_gqa(q, k, v, mask, 0.125)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("positions", [[3, 159, 100], [160, 5, 319]],
+                         ids=["first_block", "mixed_rows"])
+def test_blocks_past_a_rows_position_are_never_read(positions):
+    """NaN in every slot past the block that holds a ROW's position
+    (finer than the batch's furthest) leaves the kernel's output finite
+    and equal to the clean cache's; the einsum over the whole cache
+    would read them (probability 0 times NaN)."""
+    from orion_tpu.ops.attention import reference_attention_gqa
+    from orion_tpu.ops.pallas import dense_step
+
+    q, k, v = _grouped("float32", B=3)
+    pos = jnp.asarray(positions)
+    past = jnp.arange(320)[None, :] >= ((pos // 160 + 1) * 160)[:, None]
+    kp, vp = (jnp.where(past[:, :, None, None], jnp.nan, a) for a in (k, v))
+    mask = jnp.arange(320)[None, None, :] <= pos[:, None, None]
+    got = dense_step.dense_step(q, kp, vp, pos, 0.125)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(reference_attention_gqa(q, k, v, mask,
+                                                            0.125)),
+        atol=2e-6, rtol=2e-6)
+    read = ~np.isfinite(np.asarray(reference_attention_gqa(
+        q, kp, vp, mask, 0.125))).all(axis=(1, 2, 3))
+    np.testing.assert_array_equal(read, np.asarray(past.any(axis=1)))
+
+
+@pytest.mark.parametrize("where", sorted(POSITIONS))
+def test_the_grouped_layer_steps_through_the_kernel(where, monkeypatch):
+    """The ``gqa`` layer (4 query heads on 2 key heads) under the kernel
+    form (blocks of 64 slots of its 384) gives the whole-cache step's
+    output and writes the same cache."""
+    mod, cache, params, x = _layer("gqa")
+    want, new_whole = _step(mod, params, x, POSITIONS[where], cache,
+                            monkeypatch)
+    dense_step = _kernel_form(monkeypatch, block=64)
+    assert dense_step.step_form(1, 4, 2, LMAX) == "kernel"
+    calls = []
+    kernel = dense_step.dense_step
+    monkeypatch.setattr(dense_step, "dense_step",
+                        lambda *a: calls.append(1) or kernel(*a))
+    got, new = _step(mod, params, x, POSITIONS[where], cache)
+    assert calls == [1]
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+    for name in new:
+        np.testing.assert_array_equal(np.asarray(new[name]),
+                                      np.asarray(new_whole[name]))
+
+
+# what the one-token step of each cell's model sees: (preset, overrides,
+# slots, int8 cache) -> ``kv_step_form`` on one TPU device
+CELL_STEPS = {
+    # 8 heads on 8, int8: the product-and-sum form under prefix_step
+    "ppo1b-sync": ("pythia_1b", {}, 384, True, "prefix"),
+    # latent attention: its own absorbed step
+    "ppo-kanana-ep8-sync": ("kanana_2_30b_a3b", {}, 1024, False, "prefix"),
+    "ppo-kimi-linear-ep32-sync": ("kimi_linear_48b_a3b", {}, 1024, False,
+                                  "prefix"),
+    # 30 heads on 30
+    "ppo-olmo-hybrid-vp8-sync": ("olmo_hybrid_7b", {}, 1024, False,
+                                 "prefix"),
+    # a selection: sparse_step, not this step
+    "ppo-keye-dsa-ep8-sync": ("keye_vl2_30b_a3b", {}, 8192, False, None),
+    # 8 query heads on the ONE key head a quarter holds: the einsum has
+    # nothing to re-lay
+    "ppo-nemotron-h-tp4-sync": ("nemotron_3_super_120b_a12b",
+                                {"head_share": (0, 4)}, 1280, False,
+                                "prefix"),
+    # a block's 4 or 8 rows a sequence
+    "ppo-sdar-ep8-sync": ("sdar_30b_a3b", {}, 1024, False, "prefix"),
+    # 32 heads on 8, one token: the kernel
+    "ppo-lfm2-ep4-sync": ("lfm2_8b_a1b", {}, 1280, False, "kernel"),
+    # ... but an int8 cache, and a cache of no whole blocks, are not its
+    "lfm2, int8": ("lfm2_8b_a1b", {}, 1280, True, "prefix"),
+    "lfm2, 1000 slots": ("lfm2_8b_a1b", {}, 1000, False, "prefix"),
+}
+
+
+@pytest.mark.parametrize("device", ["kernel", "jnp"], ids=["tpu", "cpu"])
+@pytest.mark.parametrize("cell", sorted(CELL_STEPS))
+def test_the_step_form_from_what_the_step_sees(cell, device, monkeypatch):
+    """The form follows the queries a row, the heads held, the cache's
+    dtype and length, and the trace's target: the kernel for LFM2's
+    shapes on one TPU device alone; everything as it was on the CPU (and
+    under a mesh: ``select_form``)."""
+    import dataclasses
+
+    from orion_tpu.ops import indexer
+
+    preset, overrides, slots, int8, want = CELL_STEPS[cell]
+    monkeypatch.setattr(indexer, "select_form", lambda: device)
+    cfg = dataclasses.replace(getattr(ModelConfig, preset)(), **overrides)
+    got = tr.decode_attrs(cfg, [200, 256], slots, slots - 256, int8)
+    if device == "jnp" and want == "kernel":
+        want = "prefix"
+    assert got.get("kv_step_form") == want
+    assert ("kv_step_slots" in got) == (want is not None)
+
+
 def _generate(arch, monkeypatch, whole, **rollout):
     """tokens, logprobs, policy logprobs of a right-padded batch whose
     rows cross from the first block of a 256-slot cache into the second
@@ -274,6 +413,18 @@ def test_the_engine_generates_what_the_whole_cache_step_did(
                                atol=5e-5, rtol=0)
 
 
+def test_the_engine_generates_under_the_kernel_what_the_einsum_did(
+        monkeypatch):
+    """The tiny llama (4 query heads on 2 key heads): 24 steps through
+    the interpreted kernel over blocks of 64 of 256 slots."""
+    want = _generate("llama", monkeypatch, True)
+    _kernel_form(monkeypatch, block=64)
+    got = _generate("llama", monkeypatch, False)
+    np.testing.assert_array_equal(got.completions, want.completions)
+    np.testing.assert_allclose(got.logprobs, want.logprobs, atol=5e-5,
+                               rtol=0)
+
+
 def _engine(arch="llama", **rollout):
     cfg = ModelConfig.tiny(arch, dtype="float32")
     return RolloutEngine(Transformer(cfg), cfg, RolloutConfig(**rollout))
@@ -307,6 +458,23 @@ def test_step_read_from_lengths(lens, Lmax, T, form, slots):
     assert isinstance(got["kv_step_slots"], float)
 
 
+def test_the_kernels_read_from_lengths(monkeypatch):
+    """Under the kernel ``kv_step_slots`` is the blocks (256 slots) up
+    to each ROW's filled slot, the mean over rows and steps: row 0 at 24
+    .. 534, row 1 at 300 .. 810 of 1024 slots; an int8 cache steps as it
+    did."""
+    _kernel_form(monkeypatch)
+    got = _engine(max_prompt_len=512, max_new_tokens=512) \
+        .dispatch_attrs((2, 512), [24, 300])
+    assert got["kv_step_form"] == "kernel"
+    assert got["kv_step_slots"] == pytest.approx(
+        (232 * 256 + 256 * 512 + 23 * 768
+         + 212 * 512 + 256 * 768 + 43 * 1024) / (2 * 511))
+    got = _engine(max_prompt_len=512, max_new_tokens=512, quantize_kv=True) \
+        .dispatch_attrs((2, 512), [24, 300])
+    assert got["kv_step_form"] == "prefix"
+
+
 @pytest.mark.parametrize("arch, rollout", [
     ("llama", {"paged": True}), ("keye_dsa", {}), ("deepseek_v3", {})],
     ids=["paged", "a_selection", "latent"])
@@ -330,10 +498,19 @@ def test_the_engine_says_nothing_where_no_step_reads_a_prefix(arch, rollout):
     # a selection everywhere: not this step's
     ("tiny_keye_dsa", ["model.max_seq_len=64", "rollout.max_prompt_len=40",
                        "data.synthetic_min_len=30",
-                       "data.synthetic_max_len=40"], None)])
+                       "data.synthetic_max_len=40"], None),
+    # the tiny llama's 4 query heads on 2 key heads under the kernel
+    # form: blocks of 64 slots, every row's 23 steps cross from its
+    # first into its second
+    ("tiny", ["model.max_seq_len=256", "rollout.max_prompt_len=232",
+              "data.synthetic_min_len=50", "data.synthetic_max_len=60"],
+     ("kernel", (64.0, 128.0)))])
 def test_the_rollout_span_says_how_the_step_reads_its_cache(
-        preset, extra, want, tmp_path):
+        preset, extra, want, tmp_path, monkeypatch):
     from orion_tpu import launch
+
+    if want and want[0] == "kernel":
+        _kernel_form(monkeypatch, block=64)
 
     launch.main([
         "ppo", f"model_preset={preset}", "share_backbone=true",
@@ -352,6 +529,11 @@ def test_the_rollout_span_says_how_the_step_reads_its_cache(
     assert span["attn_heads_a_step"] == update["attn_heads_a_step"] == heads
     if want is None:
         assert "kv_step_form" not in span and "kv_step_slots" not in span
+        return
+    if want[0] == "kernel":
+        low, high = want[1]
+        assert span["kv_step_form"] == "kernel"
+        assert low < span["kv_step_slots"] < high
         return
     assert (span["kv_step_form"], span["kv_step_slots"]) == want
     assert "," not in span["kv_step_form"]
