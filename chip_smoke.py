@@ -98,11 +98,8 @@ CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 0,
 
 
 def watch_jax() -> None:
-    """Count persistent-cache hits/misses, and keep the per-compile
-    chatter that ``jax_log_compiles`` (the RecompileSentinel's source)
-    prints at WARNING level off stderr — real warnings still show."""
-    import logging
-
+    """Count persistent-cache hits and entries written (jax fires
+    ``cache_misses`` where it writes one)."""
     import jax.monitoring
 
     def on_event(name, **kw):
@@ -110,19 +107,6 @@ def watch_jax() -> None:
             CACHE_EVENTS[name] += 1
 
     jax.monitoring.register_event_listener(on_event)
-
-    class NoCompileChatter(logging.Filter):
-        def filter(self, record):
-            return not record.getMessage().startswith(
-                ("Finished ", "Compiling ", "Persistent compilation cache",
-                 "Not writing persistent cache entry"))
-
-    log = logging.getLogger("jax")
-    if not log.handlers:     # jax installs its own when a level is set
-        log.addHandler(logging.StreamHandler())
-    for handler in log.handlers:
-        handler.addFilter(NoCompileChatter())
-    log.propagate = False
 
 
 def emit(**row) -> None:

@@ -2,13 +2,13 @@
 visible while a program is actually running.
 
 - :class:`RecompileSentinel` — counts XLA compilations per jitted
-  function (via ``jax_log_compiles`` log records, which carry the
-  function name on this jax; a ``jax.monitoring`` duration listener
-  keeps the global count as a cross-check) and warns once a function
-  recompiles past its budget.  A silently-unhashable static arg or a
-  shape that changes every step turns a 2 ms train step into a
-  minutes-long compile loop — on a TPU pod that is the single most
-  expensive silent failure.
+  function and warns once a function recompiles past its budget.  It
+  reads the process's compile watch (``orion_tpu/obs/compilewatch.py``:
+  one pair of ``jax.monitoring`` listeners whose duration events carry
+  ``fun_name`` on this jax), holding it while installed.  A
+  silently-unhashable static arg or a shape that changes every step
+  turns a 2 ms train step into a minutes-long compile loop — on a TPU
+  pod that is the single most expensive silent failure.
 - :func:`guard_scope` — opt-in ``jax.transfer_guard`` wiring for the
   trainers (TrainConfig.transfer_guard): "log" prints every *implicit*
   host transfer inside the training loop, "disallow" raises on them.
@@ -19,47 +19,20 @@ visible while a program is actually running.
 from __future__ import annotations
 
 import contextlib
-import logging
-import re
 import threading
 import warnings
 from typing import Dict, Optional
 
-# jax logs "Compiling jit(<name>) with global shapes ..."; counts are
-# keyed by the bare function name, so the jit(...) wrapper is stripped.
-_COMPILE_RE = re.compile(
-    r"^Compiling (?:jit\()?([^\s()]+)\)? with global shapes")
 
-# jax_log_compiles emits through child loggers of "jax"
-# (jax._src.interpreters.pxla); attaching to the parent survives the
-# module moving.
-_JAX_LOGGER = "jax"
-
-# Shared install state: refcounted so two live sentinels don't fight —
-# the FIRST install snapshots jax_log_compiles, the LAST uninstall
-# restores it (a per-sentinel snapshot would record the first
-# sentinel's True and make the original value unrecoverable).  The
-# jax.monitoring API has no unregister, so exactly ONE listener is
-# ever registered; it dispatches to whatever sentinels are active.
-_shared_lock = threading.Lock()
-_active_sentinels: set = set()
-_prev_log_compiles: Optional[bool] = None
-_monitor_registered = False
-
-
-def _on_compile_duration(event: str, duration: float, **kw) -> None:
-    if not event.endswith("backend_compile_duration"):
-        return
-    with _shared_lock:
-        targets = list(_active_sentinels)
-    for s in targets:
-        with s._lock:
-            s.total_compiles += 1
-
-
-class RecompileSentinel(logging.Handler):
+class RecompileSentinel:
     """Warns when any single jitted function compiles more than
     ``budget`` times.
+
+    ``counts[name]`` is the number of times ``jit(name)`` was LOWERED
+    while the sentinel was installed (where jax's "Compiling <name> with
+    global shapes" line is written: a ``.lower()`` that is never
+    compiled counts too), ``total_compiles`` the number of backend
+    compiles.
 
     Usage::
 
@@ -70,26 +43,23 @@ class RecompileSentinel(logging.Handler):
     """
 
     def __init__(self, budget: int = 3):
-        super().__init__(level=logging.DEBUG)
         self.budget = int(budget)
         self.counts: Dict[str, int] = {}
         self.total_compiles = 0
         self._lock = threading.Lock()
         self._warned: set = set()
-        self._installed = False
+        self._hold = None
 
-    # -- logging.Handler ------------------------------------------------
-    def emit(self, record: logging.LogRecord) -> None:
-        try:
-            m = _COMPILE_RE.match(record.getMessage())
-        except Exception:  # pragma: no cover - malformed record
+    def _on_compile_event(self, kind: str, name: str) -> None:
+        """The watch's observer: called on the compiling thread after
+        every trace, lowering and backend compile."""
+        if kind == "trace":
             return
-        if not m:
-            return
-        name = m.group(1)
         with self._lock:
-            self.counts[name] = self.counts.get(name, 0) + 1
-            n = self.counts[name]
+            if kind == "backend":
+                self.total_compiles += 1
+                return
+            self.counts[name] = n = self.counts.get(name, 0) + 1
             fire = n > self.budget and name not in self._warned
             if fire:
                 self._warned.add(name)
@@ -101,46 +71,21 @@ class RecompileSentinel(logging.Handler):
                 "per step", RuntimeWarning, stacklevel=2)
 
     # -- lifecycle ------------------------------------------------------
+    @property
+    def installed(self) -> bool:
+        return self._hold is not None
+
     def install(self) -> "RecompileSentinel":
-        global _prev_log_compiles, _monitor_registered
-        import jax
+        from orion_tpu import obs
 
-        if self._installed:
-            return self
-        with _shared_lock:
-            if not _active_sentinels:
-                _prev_log_compiles = bool(jax.config.jax_log_compiles)
-            _active_sentinels.add(self)
-            register_monitor = not _monitor_registered
-            _monitor_registered = True
-        jax.config.update("jax_log_compiles", True)
-        logging.getLogger(_JAX_LOGGER).addHandler(self)
-        if register_monitor:
-            # Global compile count via jax.monitoring: no per-function
-            # metadata on this jax, but it catches compiles that bypass
-            # the log path.
-            try:
-                import jax.monitoring as monitoring
-
-                monitoring.register_event_duration_secs_listener(
-                    _on_compile_duration)
-            except Exception:  # pragma: no cover - monitoring moved
-                pass
-        self._installed = True
+        if self._hold is None:
+            self._hold = obs.install_compile_watch(self._on_compile_event)
         return self
 
     def uninstall(self) -> None:
-        import jax
-
-        if not self._installed:
-            return
-        logging.getLogger(_JAX_LOGGER).removeHandler(self)
-        with _shared_lock:
-            _active_sentinels.discard(self)
-            restore = not _active_sentinels
-        if restore and _prev_log_compiles is not None:
-            jax.config.update("jax_log_compiles", _prev_log_compiles)
-        self._installed = False
+        hold, self._hold = self._hold, None
+        if hold is not None:
+            hold.uninstall()
 
 
 @contextlib.contextmanager

@@ -49,6 +49,7 @@ Multi-host bring-up: set JAX_COORDINATOR/process env and
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 from typing import Any, Optional
@@ -80,6 +81,10 @@ ALGOS = {
 
 _INIT_ARGS = (jnp.zeros((1, 2), jnp.int32), jnp.zeros((1, 2), jnp.int32))
 
+# The imports are done, and the eager compile the line above costs on a
+# TPU: closes ``setup.import`` (obs/compilewatch.py).
+obs.setup_imported()
+
 
 def _len_range(data):
     """(lo, hi) for long synthetic prompts, or None (DataConfig)."""
@@ -91,6 +96,17 @@ def _len_range(data):
             f"positive lengths (got {data.synthetic_min_len}.."
             f"{data.synthetic_max_len})")
     return data.synthetic_min_len, data.synthetic_max_len
+
+
+def _holds_compile_watch(fn):
+    """``fn`` under a hold on the process's compile watch
+    (obs/compilewatch.py): taken before anything is built, released
+    when the process body returns or raises."""
+    @functools.wraps(fn)
+    def held(*args, **kw):
+        with obs.install_compile_watch():
+            return fn(*args, **kw)
+    return held
 
 
 def build_reward(cfg, tokenizer, mesh):
@@ -204,6 +220,7 @@ def build_rollout_engine(cfg, tokenizer):
     return engine, eos, pad
 
 
+@_holds_compile_watch
 def run_pool_worker(cfg, port: int, rank: int,
                     host: str = "localhost",
                     n_batches: Optional[int] = None) -> int:
@@ -286,6 +303,7 @@ def run_pool_worker(cfg, port: int, rank: int,
     return client.run(gen, n_batches=n_batches, preemption=handler)
 
 
+@_holds_compile_watch
 def run_serve(cfg, port: int = 0, tenant_spec: Optional[str] = None,
               host: str = "localhost", stop=None,
               on_ready=None, n_engines: int = 1,
@@ -539,7 +557,18 @@ def build_trainer(algo: str, cfg, mesh, tokenizer):
     return trainer_cls(cfg, model, params, **kw)
 
 
+def _trainer(algo: str, cfg, mesh, tokenizer, prompt_iter, eval_iter):
+    """The trainer, built and resumed: two phases of set-up."""
+    with obs.setup_phase("setup.build_trainer"):
+        trainer = build_trainer(algo, cfg, mesh, tokenizer)
+    with obs.setup_phase("setup.resume"):
+        trainer.resume(prompt_iter, eval_iter=eval_iter)
+    return trainer
+
+
+@_holds_compile_watch
 def main(argv: Optional[list] = None) -> Any:
+    obs.setup_begin()
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or (argv[0] not in ALGOS and argv[0] != "serve"):
         print(f"usage: python -m orion_tpu.launch "
@@ -577,7 +606,8 @@ def main(argv: Optional[list] = None) -> Any:
             argv.remove("--rollout")
             rollout = True
     cfg_cls, _ = ALGOS.get(algo, (GRPOConfig, None))
-    cfg = load_config(cfg_cls, yaml_path=yaml_path, cli_args=argv)
+    with obs.setup_phase("setup.config"):
+        cfg = load_config(cfg_cls, yaml_path=yaml_path, cli_args=argv)
 
     if algo == "serve":
         return run_serve(cfg, port=serve_port, tenant_spec=tenant_spec,
@@ -598,51 +628,53 @@ def main(argv: Optional[list] = None) -> Any:
             host=os.environ.get("ORION_POOL_WORKER_HOST", "localhost"))
 
     if os.environ.get("JAX_COORDINATOR_ADDRESS"):
-        jax.distributed.initialize()
+        with obs.setup_phase("setup.mesh"):
+            jax.distributed.initialize()
 
-    tokenizer = load_tokenizer(cfg.data.tokenizer)
-    if cfg.data.tokenizer in (None, "byte"):
-        cfg.model.vocab_size = max(cfg.model.vocab_size, 260)
-    else:
-        tok_vocab = len(tokenizer)
-        if tok_vocab > cfg.model.vocab_size:
-            # XLA gather clamps out-of-range ids silently — training on
-            # garbage embeddings with no error.  Fail loudly instead.
-            raise ValueError(
-                f"tokenizer vocab {tok_vocab} exceeds model.vocab_size "
-                f"{cfg.model.vocab_size}; set model_preset/hf_path or "
-                "model.vocab_size to match the tokenizer")
+    with obs.setup_phase("setup.config"):
+        tokenizer = load_tokenizer(cfg.data.tokenizer)
+        if cfg.data.tokenizer in (None, "byte"):
+            cfg.model.vocab_size = max(cfg.model.vocab_size, 260)
+        else:
+            tok_vocab = len(tokenizer)
+            if tok_vocab > cfg.model.vocab_size:
+                # XLA gather clamps out-of-range ids silently — training on
+                # garbage embeddings with no error.  Fail loudly instead.
+                raise ValueError(
+                    f"tokenizer vocab {tok_vocab} exceeds model.vocab_size "
+                    f"{cfg.model.vocab_size}; set model_preset/hf_path or "
+                    "model.vocab_size to match the tokenizer")
 
-    prompt_iter = build_prompt_iterator(
-        cfg.data.dataset, tokenizer, cfg.rollout_batch_size,
-        cfg.rollout.max_prompt_len, split=cfg.data.split, seed=cfg.seed,
-        use_chat_template=cfg.data.use_chat_template,
-        system_prompt=cfg.data.system_prompt,
-        synthetic_size=cfg.data.synthetic_size,
-        synthetic_len_range=_len_range(cfg.data),
-        synthetic_vocab=cfg.data.synthetic_vocab,
-        data_dir=cfg.data.data_dir)
-    eval_iter = None
-    if cfg.eval_every:
-        if cfg.eval_batches < 1:
-            # Catch it HERE, not hours in at the first scheduled eval.
-            raise ValueError(
-                f"eval_every={cfg.eval_every} needs eval_batches >= 1 "
-                f"(got {cfg.eval_batches}); disable eval with "
-                "eval_every=0")
-        # Held-out split (synthetic: a disjoint seed stream).
-        eval_iter = build_prompt_iterator(
+        prompt_iter = build_prompt_iterator(
             cfg.data.dataset, tokenizer, cfg.rollout_batch_size,
-            cfg.rollout.max_prompt_len,
-            split=(cfg.data.split if cfg.data.dataset == "synthetic"
-                   else cfg.data.eval_split),
-            seed=cfg.seed + 1000003,
+            cfg.rollout.max_prompt_len, split=cfg.data.split, seed=cfg.seed,
             use_chat_template=cfg.data.use_chat_template,
             system_prompt=cfg.data.system_prompt,
             synthetic_size=cfg.data.synthetic_size,
-        synthetic_len_range=_len_range(cfg.data),
-        synthetic_vocab=cfg.data.synthetic_vocab,
+            synthetic_len_range=_len_range(cfg.data),
+            synthetic_vocab=cfg.data.synthetic_vocab,
             data_dir=cfg.data.data_dir)
+        eval_iter = None
+        if cfg.eval_every:
+            if cfg.eval_batches < 1:
+                # Catch it HERE, not hours in at the first scheduled eval.
+                raise ValueError(
+                    f"eval_every={cfg.eval_every} needs eval_batches >= 1 "
+                    f"(got {cfg.eval_batches}); disable eval with "
+                    "eval_every=0")
+            # Held-out split (synthetic: a disjoint seed stream).
+            eval_iter = build_prompt_iterator(
+                cfg.data.dataset, tokenizer, cfg.rollout_batch_size,
+                cfg.rollout.max_prompt_len,
+                split=(cfg.data.split if cfg.data.dataset == "synthetic"
+                       else cfg.data.eval_split),
+                seed=cfg.seed + 1000003,
+                use_chat_template=cfg.data.use_chat_template,
+                system_prompt=cfg.data.system_prompt,
+                synthetic_size=cfg.data.synthetic_size,
+                synthetic_len_range=_len_range(cfg.data),
+                synthetic_vocab=cfg.data.synthetic_vocab,
+                data_dir=cfg.data.data_dir)
 
     if cfg.async_mode and cfg.resilience.pool_size > 0:
         # Cross-process rollout pool (PR 10): the
@@ -659,10 +691,11 @@ def main(argv: Optional[list] = None) -> Any:
         # before the trainer is built: a doomed spawn must not cost a
         # model init first
         _require_pool_worker_routing(range(cfg.resilience.pool_size))
-        mesh = make_mesh(cfg.mesh)
+        with obs.setup_phase("setup.mesh"):
+            mesh = make_mesh(cfg.mesh)
         with mesh:
-            trainer = build_trainer(algo, cfg, mesh, tokenizer)
-            trainer.resume(prompt_iter, eval_iter=eval_iter)
+            trainer = _trainer(algo, cfg, mesh, tokenizer, prompt_iter,
+                               eval_iter)
             orch = PoolOrchestrator(trainer)  # pool built from config
             procs = spawn_pool_workers(algo, raw_argv, orch.pool.port,
                                        cfg.resilience.pool_size)
@@ -690,12 +723,13 @@ def main(argv: Optional[list] = None) -> Any:
     if cfg.async_mode:
         from orion_tpu.orchestration import AsyncOrchestrator, split_devices
 
-        n_roll = cfg.rollout_devices or max(1, len(jax.devices()) // 2)
-        rollout_devs, train_devs = split_devices(jax.devices(), n_roll)
-        mesh = make_mesh(cfg.mesh, devices=train_devs)
+        with obs.setup_phase("setup.mesh"):
+            n_roll = cfg.rollout_devices or max(1, len(jax.devices()) // 2)
+            rollout_devs, train_devs = split_devices(jax.devices(), n_roll)
+            mesh = make_mesh(cfg.mesh, devices=train_devs)
         with mesh:
-            trainer = build_trainer(algo, cfg, mesh, tokenizer)
-            trainer.resume(prompt_iter, eval_iter=eval_iter)
+            trainer = _trainer(algo, cfg, mesh, tokenizer, prompt_iter,
+                               eval_iter)
             orch = AsyncOrchestrator(trainer, rollout_devs)
             try:
                 return orch.train(prompt_iter, eval_iter=eval_iter)
@@ -705,10 +739,11 @@ def main(argv: Optional[list] = None) -> Any:
                 # recompile sentinel) — crash or clean.
                 trainer.close()
 
-    mesh = make_mesh(cfg.mesh)
+    with obs.setup_phase("setup.mesh"):
+        mesh = make_mesh(cfg.mesh)
     with mesh:
-        trainer = build_trainer(algo, cfg, mesh, tokenizer)
-        trainer.resume(prompt_iter, eval_iter=eval_iter)
+        trainer = _trainer(algo, cfg, mesh, tokenizer, prompt_iter,
+                           eval_iter)
         try:
             return trainer.train(prompt_iter, eval_iter=eval_iter)
         finally:

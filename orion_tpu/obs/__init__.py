@@ -21,6 +21,11 @@ tree reports through:
 - :mod:`gcwatch` — the interpreter's garbage collections: counted per
   thread always (the metrics row's ``host_gc_s``), a ``host.gc`` span
   when they are the expensive kind.
+- :mod:`compilewatch` — every trace, lowering, compile and cache load
+  by program, from one pair of ``jax.monitoring`` listeners: counted
+  per thread always (the metrics row's ``compile_s`` / ``compiles`` /
+  ``cache_misses``), ``compile.*`` ring events, and with the ``setup.*``
+  phases the one ``setup`` row that says where set-up went.
 
 Module-global convenience mirrors ``resilience.inject``: one process
 tracer + one flight recorder, armed by ``TrainConfig.obs``
@@ -34,6 +39,9 @@ half a microsecond (measured: :mod:`trace`'s docstring).
 """
 
 from __future__ import annotations
+
+# first: its first statements stamp the start of the process's set-up
+from orion_tpu.obs.compilewatch import CompileWatch, SetupAccount
 
 import logging
 import os
@@ -125,6 +133,64 @@ def gc_totals():
     run under the hook, all generations: a clock to take differences
     of; it stands still while no hook is installed."""
     return _GC.totals()
+
+
+# ---------------------------------------------------------------------------
+# the process's one pair of jax.monitoring listeners, and its set-up
+# ---------------------------------------------------------------------------
+
+_COMPILE = CompileWatch(get_tracer)
+_SETUP = SetupAccount(_COMPILE, get_tracer)
+
+
+def install_compile_watch(observer=None):
+    """Account for this process's traces, lowerings, compiles and cache
+    loads for as long as the returned handle is held (also a context
+    manager): ``handle.uninstall()`` is idempotent, several holders
+    share ONE pair of listeners, the last to let go unregisters them;
+    ``observer(kind, program name)`` is called after every event while
+    this hold lasts (:mod:`compilewatch`)."""
+    return _COMPILE.install(observer)
+
+
+def compile_totals():
+    """The calling thread's compile clock (``programs``, ``trace_s``,
+    ``lower_s``, ``compile_s``, ``load_s``, ``hits``, ``misses``;
+    ``.seconds`` their sum): a clock to take differences of, like
+    :func:`gc_totals`."""
+    return _COMPILE.totals()
+
+
+def compile_programs():
+    """By program name, process-wide and complete
+    (:meth:`CompileWatch.programs`)."""
+    return _COMPILE.programs()
+
+
+def compile_events():
+    """The kept events, bounded (:meth:`CompileWatch.events`)."""
+    return _COMPILE.events()
+
+
+def setup_phase(name: str, **attrs):
+    """``timed(name)`` as a phase of set-up: the account keeps it until
+    the ``setup`` row is written (:class:`SetupAccount`)."""
+    return _SETUP.phase(name, **attrs)
+
+
+def setup_begin() -> None:
+    """A job starts in this process (``launch.main``)."""
+    _SETUP.begin()
+
+
+def setup_imported() -> None:
+    """The entry point's imports are done: closes ``setup.import``."""
+    _SETUP.imported()
+
+
+def setup_row(begins, steady) -> dict:
+    """The ``setup`` row (:meth:`SetupAccount.row`)."""
+    return _SETUP.row(begins, steady)
 
 
 # ---------------------------------------------------------------------------

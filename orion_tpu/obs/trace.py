@@ -279,6 +279,29 @@ class Tracer:
             "attrs": attrs,
         })
 
+    def record_closed(self, name: str, start: float, duration: float,
+                      cpu: float = 0.0, tid: Optional[int] = None,
+                      **attrs) -> None:
+        """A span measured elsewhere, written with its own monotonic
+        ``start`` and ``duration``: a compile event (jax reports it when
+        it ends) or a set-up phase that closed before the ring was
+        there (``obs/compilewatch.py``).  Its parent is the span open on
+        this thread now if that one was open at ``start``, else none."""
+        if not self.enabled:
+            return
+        stack = self._stack()
+        parent = stack[-1] if stack and stack[-1].start <= start else None
+        self._emit({
+            "name": name, "ph": "X",
+            "wall": time.time() - (time.monotonic() - start),
+            "dur": duration, "cpu": cpu, "trace": self.trace_id,
+            "span": next(_SPAN_IDS),
+            "parent": parent.span_id if parent is not None else 0,
+            "tid": (threading.get_ident() if tid is None else tid)
+            & 0x7FFFFFFF,
+            "attrs": attrs,
+        })
+
     # -- cross-process context ------------------------------------------
     def adopt_trace(self, trace_id: int) -> None:
         """Take a remote originator's trace id as ours (worker side of

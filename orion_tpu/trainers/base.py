@@ -14,7 +14,9 @@ minibatching, the jitted update step, and logging.  Do NOT override
 
 from __future__ import annotations
 
+import json
 import resource
+import sys
 from typing import Any, Callable, Dict, Iterator, Optional
 
 import flax.struct
@@ -165,11 +167,13 @@ def _device_free_bytes(tree) -> Optional[int]:
     return min(free) if free else None
 
 
-def _stamp(span, gc_totals: tuple) -> tuple:
-    """(wall, this thread's CPU clock, its collector's seconds) at the
-    START of ``span``, ``gc_totals`` being ``obs.gc_totals()`` read
-    there: the three clocks the trainer loop stamps an iteration on."""
-    return span.start, span.cpu_start, gc_totals[1]
+def _stamp(span, gc_totals: tuple, compile_totals: tuple) -> tuple:
+    """(wall, this thread's CPU clock, its collector's seconds, its
+    compile clock) at the START of ``span``, ``gc_totals`` and
+    ``compile_totals`` being ``obs.gc_totals()`` and
+    ``obs.compile_totals()`` read there: the four clocks the trainer
+    loop stamps an iteration on."""
+    return span.start, span.cpu_start, gc_totals[1], compile_totals
 
 
 def hold_fixed(updates, cfg_model):
@@ -273,6 +277,10 @@ class BaseTrainer:
     #: trace (:meth:`_choose_remat_keep`).
     _remat_keep: tuple = ()
     _remat_info: Optional[dict] = None
+    #: The stamps of the first iterations (at most eight), until the
+    #: first of them that compiled nothing has its ``setup`` row (None
+    #: from then on).
+    _setup_begins: Optional[list] = None
 
     def __init__(self, cfg: TrainConfig, model: Transformer, params: Any,
                  reward_fn: Optional[Callable] = None,
@@ -395,6 +403,11 @@ class BaseTrainer:
         # The collector's hook is there whether or not obs.trace is on:
         # every metrics row carries host_gc_s (obs/gcwatch.py).
         self._gc_watch = _obs.install_gc_watch()
+        # and the compile watch's: every row carries compile_s /
+        # compiles / cache_misses, and the first steady iteration writes
+        # the one ``setup`` row (obs/compilewatch.py)
+        self._compile_watch = _obs.install_compile_watch()
+        self._setup_begins = []
         # Opt-in runtime guards (orion_tpu.analysis.runtime_guards):
         # recompile sentinel installs here; the transfer guard wraps
         # the train() loop body.
@@ -403,9 +416,9 @@ class BaseTrainer:
         self._recompile_sentinel = install_from_config(cfg)
 
     def close(self) -> None:
-        """Release process-global hooks (the recompile sentinel's log
-        handler + jax_log_compiles flag, the obs tracer/flight
-        recorder, the collector's hook) and close the metrics writer —
+        """Release process-global hooks (the recompile sentinel, the
+        obs tracer/flight recorder, the collector's hook, the hold on
+        the compile watch) and close the metrics writer —
         THE trainer/orchestrator exit path for every sink.  Idempotent;
         also runs
         from __del__ so sweep scripts constructing many trainers don't
@@ -415,9 +428,9 @@ class BaseTrainer:
         if sentinel is not None:
             sentinel.uninstall()
             self._recompile_sentinel = None
-        # the hook first: a collection it reports wants the tracer that
-        # was there while it ran
-        for attr in ("_gc_watch", "_obs"):
+        # the hooks first: a collection or a compile they report wants
+        # the tracer that was there while it ran
+        for attr in ("_gc_watch", "_compile_watch", "_obs"):
             held = getattr(self, attr, None)
             if held is not None:
                 held.uninstall()
@@ -841,10 +854,20 @@ class BaseTrainer:
         else it is traced once more with the names to keep, and there
         is one update program from then on.  A device that reports
         nothing (the CPU) gives no budget, and nothing is kept."""
+        from orion_tpu import obs
+
         self._remat_info = info = {
             "remat_kept": "", "remat_kept_bytes": 0, "remat_budget_bytes": 0}
+        if not self.cfg.model.remat:
+            return
+        # a phase of set-up: the compile for the reading, and the
+        # clear_cache() that makes the update trace again
+        with obs.setup_phase("setup.remat_probe"):
+            self._probe_remat_keep(info, experience, idx_mat)
+
+    def _probe_remat_keep(self, info: dict, experience, idx_mat) -> None:
         free = _device_free_bytes(self.state.params)
-        if not self.cfg.model.remat or free is None:
+        if free is None:
             return
         program, states = self._update_program()
         mem = program.lower(
@@ -1107,16 +1130,21 @@ class BaseTrainer:
                 # Every stamp of a metrics row is the start or the end
                 # of a span (obs.timed measures with tracing off), so
                 # all differences are taken on one clock.  An iteration
-                # is stamped on three: the wall, this thread's CPU time
-                # and its collector's seconds (_stamp).  The batch
+                # is stamped on four: the wall, this thread's CPU time,
+                # its collector's seconds and its compile clock
+                # (_stamp).  The batch
                 # fetch is a sibling BEFORE train.iteration, not its
                 # child: whoever starts or stops a profiler from inside
                 # the iterator then cuts this small span, and the
                 # window holds whole train.iteration spans.
                 gc_begin = obs.gc_totals()
+                compiled = obs.compile_totals()
                 with obs.timed("data.next_batch", it=it) as sp_data:
                     batch = next(prompt_iter)
-                begin = _stamp(sp_data, gc_begin)
+                begin = _stamp(sp_data, gc_begin, compiled)
+                if self._setup_begins is not None and \
+                        len(self._setup_begins) < 8:
+                    self._setup_begins.append((begin[0], compiled))
                 with obs.span("train.iteration", it=it) as sp_it:
                     gc_it = obs.gc_totals()
                     if pending is not None:
@@ -1185,8 +1213,11 @@ class BaseTrainer:
                     was, usage = usage, resource.getrusage(
                         resource.RUSAGE_THREAD)
                     gc_n, gc_s = obs.gc_totals()
+                    compiled = obs.compile_totals().since(compiled)
                     sp_it.set(gc_us=round((gc_s - gc_it[1]) * 1e6),
                               gc_n=gc_n - gc_it[0],
+                              compile_us=round(compiled.seconds * 1e6),
+                              compiles=compiled.programs,
                               nivcsw=usage.ru_nivcsw - was.ru_nivcsw,
                               majflt=usage.ru_majflt - was.ru_majflt)
             if pending is not None:  # flush the last iteration's stats
@@ -1254,10 +1285,14 @@ class BaseTrainer:
         ``fetch_copy_s`` it took the results to the host with the
         device idle, ``host_cpu_s`` is this thread's CPU time over the
         iteration (the host's WORK: a dispatch that blocks is wall and
-        not this) of which ``host_gc_s`` went to the collector.  What
-        is left of a long iteration the thread neither worked nor
+        not this) of which ``host_gc_s`` went to the collector, and
+        ``compile_s`` it traced, lowered, compiled and loaded
+        ``compiles`` programs, ``cache_misses`` of them past the
+        persistent cache (obs/compilewatch.py; 0 in a steady iteration).
+        What is left of a long iteration the thread neither worked nor
         waited on a named thing.  A phase's device time is read from a
-        profiler trace."""
+        profiler trace.  The first iteration that compiled nothing ends
+        set-up: its row is followed by the one ``setup`` row."""
         from orion_tpu import obs
 
         def scal(v):
@@ -1265,12 +1300,13 @@ class BaseTrainer:
 
         with obs.timed("stats.finalize", it=pending["it"]) as sp:
             if end is None:
-                end = _stamp(sp, obs.gc_totals())
+                end = _stamp(sp, obs.gc_totals(), obs.compile_totals())
             stats = {k: scal(v) for k, v in fetched["upd"].items()}
             stats.update({k: scal(v) for k, v in fetched["exp"].items()})
             self._on_host_stats(stats, pending["n"])
             iter_s, host_cpu_s, host_gc_s = (
-                b - a for a, b in zip(pending["begin"], end))
+                b - a for a, b in zip(pending["begin"][:3], end))
+            compiled = end[3].since(pending["begin"][3])
             iter_s = max(iter_s, 1e-9)
             stats.update({
                 "iteration": pending["it"],
@@ -1279,6 +1315,9 @@ class BaseTrainer:
                 "fetch_copy_s": pending["fetch_s"][1],
                 "host_cpu_s": host_cpu_s,
                 "host_gc_s": host_gc_s,
+                "compile_s": compiled.seconds,
+                "compiles": compiled.programs,
+                "cache_misses": compiled.misses,
                 "samples_per_sec": pending["n"] / iter_s,
                 **(self._remat_info or {}), **pending["model"],
             })
@@ -1293,6 +1332,25 @@ class BaseTrainer:
             if self.cfg.log_every and \
                     pending["it"] % self.cfg.log_every == 0:
                 self.log(stats)
+            if self._setup_begins is not None and not compiled.seconds:
+                self._write_setup_row(pending)
+
+    def _write_setup_row(self, pending: dict) -> None:
+        """Set-up is over: ``pending`` is the first iteration in which
+        this thread compiled nothing.  Where it went, once: a row of
+        ``metrics.jsonl`` (``"setup": 1``, under the iteration's global
+        step) and a JSON line on stderr; not an entry of
+        ``metrics_history``, which holds iterations
+        (obs/compilewatch.py::SetupAccount.row has the keys)."""
+        from orion_tpu import obs
+
+        begins, self._setup_begins = self._setup_begins, None
+        row = obs.setup_row(
+            begins, (pending["begin"][0], pending["begin"][3]))
+        row["iteration"] = pending["it"]
+        if self.writer is not None:
+            self.writer.write(pending["giter"], row, jsonl_only=True)
+        print(json.dumps(row), file=sys.stderr, flush=True)
 
     def log(self, stats: dict) -> None:
         keys = ("iteration", "reward_mean", "loss", "kl", "samples_per_sec")

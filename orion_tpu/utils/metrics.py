@@ -129,7 +129,10 @@ class MetricsWriter:
             self._closed = True
             raise
 
-    def write(self, step: int, scalars: dict) -> None:
+    def write(self, step: int, scalars: dict,
+              jsonl_only: bool = False) -> None:
+        """``jsonl_only``: a row that is no point of the iterations'
+        series (the ``setup`` row shares keys and a step with one)."""
         if self._closed:
             raise ValueError("MetricsWriter is closed")
         numeric: Dict[str, float] = {}
@@ -142,12 +145,13 @@ class MetricsWriter:
                 numeric[k] = float(v.value)
             elif isinstance(v, (int, float)) or _is_scalar_like(v):
                 numeric[k] = float(v)
-            elif isinstance(v, str):
-                annot[k] = v  # jsonl-only (e.g. profile trace dir)
+            elif isinstance(v, (str, list, dict)):
+                # jsonl-only (a profile trace dir, the setup row's tables)
+                annot[k] = v
         rec = {"step": int(step), "time": time.time(), **numeric, **annot}
         self._jsonl.write(json.dumps(rec) + "\n")
         self._jsonl.flush()
-        if self._tb is not None and numeric:
+        if self._tb is not None and numeric and not jsonl_only:
             self._tb.write_scalars(int(step), numeric)
 
     def close(self) -> None:

@@ -1340,27 +1340,40 @@ def test_recompile_sentinel_counts_and_warns():
     assert not jax.config.jax_log_compiles
 
 
-def test_stacked_sentinels_restore_log_compiles():
-    """Two live sentinels: the LAST uninstall restores the ORIGINAL
-    jax_log_compiles (a per-sentinel snapshot would capture the first
-    install's True and leak it forever)."""
+def test_stacked_sentinels_leave_jax_as_found():
+    """Two live sentinels share the compile watch's ONE pair of
+    listeners: the first uninstall leaves the second counting, the
+    last leaves ``jax.monitoring`` as found; ``jax_log_compiles`` and
+    the ``jax`` logger are never touched (ISSUE 51)."""
     import jax
+    import jax.numpy as jnp
+    from jax._src import monitoring
 
     from orion_tpu.analysis.runtime_guards import RecompileSentinel
 
+    def listeners():
+        return (list(monitoring.get_event_duration_listeners()),
+                list(monitoring.get_event_listeners()))
+
     orig = bool(jax.config.jax_log_compiles)
+    handlers = list(logging.getLogger("jax").handlers)
+    found = listeners()
     a = RecompileSentinel(budget=3).install()
+    grown = listeners()
     b = RecompileSentinel(budget=3).install()
+    assert listeners() == grown     # one pair, however many holds
     a.uninstall()
-    assert jax.config.jax_log_compiles  # b still live
+    assert listeners() == grown and b.installed  # b still live
+    jax.jit(lambda x: x - 3, inline=False)(jnp.ones((7,)))
+    assert b.total_compiles >= 1 and not a.installed
     b.uninstall()
+    b.uninstall()   # idempotent
+    assert listeners() == found
     assert bool(jax.config.jax_log_compiles) == orig
-    handlers = logging.getLogger("jax").handlers
-    assert a not in handlers and b not in handlers
+    assert logging.getLogger("jax").handlers == handlers
 
 
 def test_trainer_close_uninstalls_sentinel():
-    from orion_tpu.analysis.runtime_guards import _active_sentinels
     from orion_tpu.config import TrainConfig
     from orion_tpu.trainers.base import BaseTrainer
 
@@ -1371,8 +1384,10 @@ def test_trainer_close_uninstalls_sentinel():
     from orion_tpu.analysis.runtime_guards import install_from_config
     shell._recompile_sentinel = install_from_config(
         TrainConfig(recompile_budget=2))
-    assert shell._recompile_sentinel in _active_sentinels
+    sentinel = shell._recompile_sentinel
+    assert sentinel.installed
     shell.close()
+    assert not sentinel.installed
     assert shell._recompile_sentinel is None
     shell.close()  # idempotent
 
