@@ -405,12 +405,28 @@ class Attention(nn.Module, Kind):
     per_head_kv = True
     steps_over_prefix = True
 
-    @staticmethod
-    def cache_entry(cfg, batch, slots, dtype, pre=(), quantized=False):
-        """{"k", "v"} [B, slots, Hkv, D] of the key-value heads held;
-        ``quantized``: int8 values beside per-token-per-head float32
-        scales (RolloutConfig.quantize_kv, ops/quant.py)."""
+    @classmethod
+    def kv_step_form(cls, cfg, slots, quantized=False) -> str:
+        """``kernel`` where this kind's one-token step against a cache
+        of ``slots`` slots is ``ops/pallas/dense_step.py``'s (its
+        ``step_form``, of the heads held), else ``""``: asked by
+        :meth:`cache_entry`, which lays the cache out for the step, and
+        by :func:`decode_attrs`; the step follows the cache's rank."""
+        held = cfg.heads_held()
+        return dense_step.step_form(cfg.block_length or 1, held["q"],
+                                    held["kv"], slots, quantized)
+
+    @classmethod
+    def cache_entry(cls, cfg, batch, slots, dtype, pre=(), quantized=False):
+        """{"k", "v"} of the key-value heads held: [B, slots, Hkv * D]
+        where the one-token step is the kernel (:meth:`kv_step_form`:
+        the key heads of a slot side by side, no lane padded under
+        heads of 64), [B, slots, Hkv, D] elsewhere; ``quantized``: int8
+        values beside per-token-per-head float32 scales
+        (RolloutConfig.quantize_kv, ops/quant.py)."""
         shape = pre + (batch, slots, cfg.heads_held()["kv"], cfg.head_dim)
+        if cls.kv_step_form(cfg, slots, quantized):
+            shape = shape[:-2] + (shape[-2] * shape[-1],)
         entry = {n: jnp.zeros(shape, jnp.int8 if quantized else dtype)
                  for n in "kv"}
         if quantized:
@@ -459,14 +475,17 @@ class Attention(nn.Module, Kind):
     def __call__(self, x, positions, layer_cache=None, visible=None):
         """x: [B, L, E]; positions: [B, L] absolute positions.
 
-        layer_cache: {"k","v"} [B, Lmax, Hkv, D] or None.  When a cache
+        layer_cache: {"k","v"} [B, Lmax, Hkv, D], [B, Lmax, Hkv * D]
+        where the one-token step is the kernel (:meth:`cache_entry`; the
+        rank tells them apart), or None.  When a cache
         is given, the L new keys/values are written at per-sequence
         slots starting at ``positions[:, 0]`` — one formula covers
         prefill (positions 0..L-1), chunked prefill (P..P+L-1) and
         decode (positions = current lengths).  The cache is dense
         ([B, Lmax] slots a layer, int8 with scales under
         ``quantize_kv``); a one-token step reads its filled prefix in
-        blocks (:func:`prefix_step`), and so does the step of a
+        blocks (the kernel ``dense_step`` a row's, :func:`prefix_step`
+        the batch's), and so does the step of a
         block-diffusion model: ``block_length`` tokens a row, or twice
         that, the block before riding in front to be committed (the L
         keys and values are written before any query reads, and the
@@ -541,9 +560,13 @@ class Attention(nn.Module, Kind):
                                         new_cache["v_scale"],
                                         _dt(cfg.dtype))
             else:
-                new_cache = {"k": write(layer_cache["k"], k),
-                             "v": write(layer_cache["v"], v)}
-                keys, values = new_cache["k"], new_cache["v"]
+                # a cache laid [B, Lmax, Hkv * D] takes its rows so, and
+                # gives the prefill's attention one re-laid copy
+                lay = (B, L) + layer_cache["k"].shape[2:]
+                new_cache = {"k": write(layer_cache["k"], k.reshape(lay)),
+                             "v": write(layer_cache["v"], v.reshape(lay))}
+                keys, values = (new_cache[n].reshape(B, -1, *k.shape[2:])
+                                for n in "kv")
         else:
             new_cache = None
             keys, values = k, v
@@ -563,10 +586,10 @@ class Attention(nn.Module, Kind):
             # one new token (one block's, two's) against the dense slot
             # cache, int8 or not
             Lmax = new_cache["k"].shape[1]
-            if dense_step.step_form(L, H, k.shape[2], Lmax,
-                                    "k_scale" in new_cache):
-                # a group of query heads on each of several key heads:
-                # the kernel, over each row's filled blocks
+            if new_cache["k"].ndim == 3:
+                # laid out for the kernel (cache_entry: one token, a
+                # group of query heads on each of several key heads):
+                # over each row's filled blocks
                 out = dense_step.dense_step(
                     q, new_cache["k"], new_cache["v"], see[:, 0], scale)
             else:
@@ -630,11 +653,16 @@ class SparseAttention(Attention):
     steps_over_prefix = False
     rl_fixed = ("index_",)
 
-    @staticmethod
-    def cache_entry(cfg, batch, slots, dtype, pre=()):
-        """:class:`Attention`'s beside {"ki": [B, slots,
-        sa_index_head_dim]}, the indexer's one key head."""
-        return {**Attention.cache_entry(cfg, batch, slots, dtype, pre),
+    @classmethod
+    def kv_step_form(cls, cfg, slots, quantized=False) -> str:
+        """Never ``dense_step``: the selected step (:meth:`step_read`)."""
+        return ""
+
+    @classmethod
+    def cache_entry(cls, cfg, batch, slots, dtype, pre=()):
+        """:class:`Attention`'s [B, slots, Hkv, D] beside {"ki": [B,
+        slots, sa_index_head_dim]}, the indexer's one key head."""
+        return {**super().cache_entry(cfg, batch, slots, dtype, pre),
                 "ki": jnp.zeros(
                     pre + (batch, slots, cfg.sa_index_head_dim), dtype)}
 
@@ -1625,18 +1653,23 @@ def decode_attrs(cfg: ModelConfig, lens=None, slots: int = 0,
     ``kernel`` (``ops/pallas/dense_step.py``: each row's filled blocks),
     under :func:`prefix_step` ``prefix`` (the batch's filled blocks) /
     ``whole`` (a cache of one block), and ``kv_step_slots``, the slots
-    one row's step reads a layer (mean)."""
+    one row's step reads a layer (mean); with :class:`Attention` layers
+    ``kv_cache_lane_fill``, their cache's minor dimension over that
+    dimension rounded up to the TPU's 128 lanes: what the HBM holds of
+    K and V is the data over this (1.0 under the kernel, whose cache is
+    ``Hkv * D`` minor; 0.5 at heads of 64 laid ``[.., Hkv, 64]``)."""
     attrs = {"kda_step": "", "attn_heads_a_step": cfg.attn_heads_a_step()}
     if lens is None:
         return attrs
     of = kinds(cfg)
-    held = cfg.heads_held()
-    if Attention in of and dense_step.step_form(
-            cfg.block_length or 1, held["q"], held["kv"], slots, quantized):
+    kernel = Attention in of and Attention.kv_step_form(cfg, slots, quantized)
+    if Attention in of:
+        width = cfg.head_dim * (cfg.heads_held()["kv"] if kernel else 1)
+        attrs["kv_cache_lane_fill"] = width / (-(-width // 128) * 128)
+    if kernel:
         attrs.update(
             kv_step_form="kernel",
-            kv_step_slots=dense_step.step_slots(lens, slots, held["kv"],
-                                                new_tokens - 1))
+            kv_step_slots=dense_step.step_slots(lens, slots, new_tokens - 1))
     elif any(kind.steps_over_prefix for kind in of):
         attrs.update(
             kv_step_form="prefix" if len(prefix_lengths(slots)) > 1

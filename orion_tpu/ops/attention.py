@@ -95,7 +95,9 @@ def step_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     runs; over SEVERAL the TPU compiler first copies the prefix of K and
     of V, slots minor-most, at every step, so on one TPU device one
     token's grouped step over a bf16 cache does not come here but goes
-    to the kernel ``ops/pallas/dense_step.py`` (its ``step_form``: the
+    to the kernel ``ops/pallas/dense_step.py``, whose cache is laid
+    ``[B, m, Hkv * D]`` (its ``step_form``, which ``Attention.
+    cache_entry`` asks too: the
     rule is ``Hkv > 1 and g > 1``, not ``g > 1`` alone: at Nemotron-H's
     shapes, 32 rows of 1280 slots, 8 query heads on one key head of 128,
     this einsum's step took 23 / 34 / 44 us at 256 / 768 / 1280 filled

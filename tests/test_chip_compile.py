@@ -1388,10 +1388,15 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
     the head tied; remat, each stretch scanned) at the timed shapes, 64
     prompts padded to 256 and 1024 new tokens.  ``generate``: prefill
     (``short_conv.chunk``, flash at heads of 64) and the decode loop
-    (``short_conv.step`` on six layers, the kernel ``dense_step`` over
-    the per-head cache on two, four query heads a key head: no
-    ``conditional`` over prefixes and no copy of the cache or of a
-    prefix of it, ISSUE 50).  ``experience``: one forward of all 64
+    (``short_conv.step`` on six layers, the kernel ``dense_step`` on
+    two, four query heads a key head, over a cache laid ``bf16[64,
+    1280, 512]``, the eight key heads of 64 side by side along the
+    lanes and no lane padded: no ``conditional`` over prefixes and no
+    copy of the cache or of a prefix of it, ISSUES 50 and 52; the
+    program's temporaries are 3.089 GB and its peak 5.967 GB where the
+    per-head cache ``bf16[64, 1280, 8, 64]``, each row of 64 in 128
+    lanes, made them 3.992 and 6.307: 340 MB less at the peak, four
+    arrays of 84 MB in place of 168).  ``experience``: one forward of all 64
     rows.  ``update``: the forward, remat's and the backward in
     minibatches of 16.  No kernel of the convolution's own: XLA fuses
     the taps between the two products.  Each fits beside what else the
@@ -1461,13 +1466,25 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
         # the step does not switch over prefixes of the cache, and
         # nothing the loop runs copies or re-lays the cache or a prefix
         # of it (PR 49's 32 ``copy bf16[64,<prefix>,8,64]{1,3,2,0}``)
+        cache = r"bf16\[64,\d+,(?:8,64|512)\]"
         for name in _called(comps, decode):
             assert " conditional(" not in comps[name]
-            assert not re.search(
-                r"= bf16\[64,\d+,8,64\]\S* copy(-start)?\(", comps[name]), name
-        # the kernel takes k and v as the loop carries them: a bitcast
+            assert not re.search(r"= %s\S* copy(-start)?\(" % cache,
+                                 comps[name]), name
+        # the prefill's attention reads the cache by head: the re-laid
+        # copies of the two layers' K and V, once a rollout, outside the
+        # loop
+        relaid = [ln for name in set(comps) - _called(comps, decode)
+                  for ln in comps[name].splitlines()
+                  if re.search(r"= %s\S* copy\(" % cache, ln)]
+        assert len(relaid) >= 4 and all("/prefill/" in ln for ln in relaid)
+        # the kernel takes k and v as the loop carries and writes them:
+        # [64, 1280, 512] in tiles of 16 x 128 bf16, nothing padded
         steps = re.findall(r"%dense_step[.\d]* = [^\n]*", comps[decode])
         assert len(steps) == 2
+        laid = "bf16[64,1280,512]{2,1,0:T(8,128)(2,1)}"
+        assert comps[decode].count(                          # the carry
+            "= %s get-tuple-element(" % laid) == 4
         for call in steps:
             operands = re.search(r"custom-call\(([^)]*)\)", call).group(1)
             for name in [o.strip().lstrip("%")
@@ -1475,8 +1492,12 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
                 made = re.search(
                     r"%%%s = (\S+) (\w[\w\-]*)\(" % re.escape(name),
                     comps[decode])
-                assert made and made.group(2) == "bitcast", (name, made)
-                assert made.group(1).startswith("bf16[64,10240,64]")
+                # (the row's scatter, in place)
+                assert made and made.group(2) == "fusion", (name, made)
+                assert made.group(1) == laid
+        assert "bf16[64,10240,64]" not in text
+        assert mem.temp_size_in_bytes == pytest.approx(3.089e9, rel=1e-2)
+        assert mem.peak_memory_in_bytes == pytest.approx(5.967e9, rel=1e-2)
     elif program == "experience":
         assert "short_conv.step" not in scopes
         assert names.count("flash_fwd") == 2     # one a stretch of attention

@@ -250,26 +250,51 @@ def _kernel_form(monkeypatch, block=None):
 
 
 def _grouped(dtype, B=4, Lmax=320, H=32, Hkv=8, D=64, seed=0):
+    """q [B, 1, H, D] and the cache as the kernel takes it, k, v [B,
+    Lmax, Hkv * D]; :func:`_heads` gives the reference its view."""
     rs = np.random.RandomState(seed)
     return [jnp.asarray(rs.standard_normal(shape), dtype) for shape in (
-        (B, 1, H, D), (B, Lmax, Hkv, D), (B, Lmax, Hkv, D))]
+        (B, 1, H, D), (B, Lmax, Hkv * D), (B, Lmax, Hkv * D))]
+
+
+def _heads(a, D):
+    return a.reshape(a.shape[0], a.shape[1], -1, D)
+
+
+# (H, Hkv, D, slots, the most slots a grid step holds, those it holds)
+GROUPED = {
+    # LFM2's heads over a cache of no whole blocks of 256: two of 160
+    "lfm2_heads": (32, 8, 64, 320, 256, 160),
+    # heads of whole lanes
+    "heads_of_128": (8, 2, 128, 320, 256, 160),
+    # the cell's cache at the block sizes measured, the one kept last
+    "1280_slots_by_256": (32, 8, 64, 1280, 256, 256),
+    "1280_slots_by_512": (32, 8, 64, 1280, 512, 320),
+    "1280_slots": (32, 8, 64, 1280, None, 640),
+}
 
 
 @pytest.mark.parametrize("dtype, tol", [("float32", 2e-6),
                                         ("bfloat16", 2e-2)])
-def test_dense_step_has_the_reference_numbers(dtype, tol):
-    """``dense_step`` interpreted at LFM2's heads (32 on 8 of 64) over a
-    cache of 320 slots (no whole blocks of 512: two of 160), rows filled
-    to unequal lengths, against ``reference_attention_gqa``."""
+@pytest.mark.parametrize("shape", sorted(GROUPED))
+def test_dense_step_has_the_reference_numbers(shape, dtype, tol,
+                                              monkeypatch):
+    """``dense_step`` interpreted over a cache laid ``[B, slots, Hkv *
+    D]``, rows filled to unequal lengths, against
+    ``reference_attention_gqa`` over the same numbers by head."""
     from orion_tpu.ops.attention import reference_attention_gqa
     from orion_tpu.ops.pallas import dense_step
 
-    q, k, v = _grouped(dtype)
-    assert dense_step.block_slots(320, 8) == 160
-    pos = jnp.asarray([3, 159, 160, 319])
-    mask = jnp.arange(320)[None, None, :] <= pos[:, None, None]
-    got = dense_step.dense_step(q, k, v, pos, 0.125)
-    want = reference_attention_gqa(q, k, v, mask, 0.125)
+    H, Hkv, D, Lmax, most, tk = GROUPED[shape]
+    if most:
+        monkeypatch.setattr(dense_step, "BLOCK_SLOTS", most)
+    q, k, v = _grouped(dtype, Lmax=Lmax, H=H, Hkv=Hkv, D=D)
+    assert dense_step.block_slots(Lmax) == tk
+    pos = jnp.asarray([3, tk - 1, tk, Lmax - 1])
+    mask = jnp.arange(Lmax)[None, None, :] <= pos[:, None, None]
+    got = dense_step.dense_step(q, k, v, pos, D ** -0.5)
+    want = reference_attention_gqa(q, _heads(k, D), _heads(v, D), mask,
+                                   D ** -0.5)
     assert got.dtype == q.dtype and got.shape == q.shape
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -278,7 +303,7 @@ def test_dense_step_has_the_reference_numbers(dtype, tol):
 
 @pytest.mark.parametrize("positions", [[3, 159, 100], [160, 5, 319]],
                          ids=["first_block", "mixed_rows"])
-def test_blocks_past_a_rows_position_are_never_read(positions):
+def test_blocks_past_a_rows_position_are_never_read(positions, monkeypatch):
     """NaN in every slot past the block that holds a ROW's position
     (finer than the batch's furthest) leaves the kernel's output finite
     and equal to the clean cache's; the einsum over the whole cache
@@ -286,42 +311,54 @@ def test_blocks_past_a_rows_position_are_never_read(positions):
     from orion_tpu.ops.attention import reference_attention_gqa
     from orion_tpu.ops.pallas import dense_step
 
+    monkeypatch.setattr(dense_step, "BLOCK_SLOTS", 256)    # two of 160
     q, k, v = _grouped("float32", B=3)
     pos = jnp.asarray(positions)
     past = jnp.arange(320)[None, :] >= ((pos // 160 + 1) * 160)[:, None]
-    kp, vp = (jnp.where(past[:, :, None, None], jnp.nan, a) for a in (k, v))
+    kp, vp = (jnp.where(past[:, :, None], jnp.nan, a) for a in (k, v))
     mask = jnp.arange(320)[None, None, :] <= pos[:, None, None]
+
+    def reference(k, v):
+        return reference_attention_gqa(q, _heads(k, 64), _heads(v, 64),
+                                       mask, 0.125)
+
     got = dense_step.dense_step(q, kp, vp, pos, 0.125)
     assert np.isfinite(np.asarray(got)).all()
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(reference_attention_gqa(q, k, v, mask,
-                                                            0.125)),
-        atol=2e-6, rtol=2e-6)
-    read = ~np.isfinite(np.asarray(reference_attention_gqa(
-        q, kp, vp, mask, 0.125))).all(axis=(1, 2, 3))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(reference(k, v)),
+                               atol=2e-6, rtol=2e-6)
+    read = ~np.isfinite(np.asarray(reference(kp, vp))).all(axis=(1, 2, 3))
     np.testing.assert_array_equal(read, np.asarray(past.any(axis=1)))
 
 
 @pytest.mark.parametrize("where", sorted(POSITIONS))
 def test_the_grouped_layer_steps_through_the_kernel(where, monkeypatch):
     """The ``gqa`` layer (4 query heads on 2 key heads) under the kernel
-    form (blocks of 64 slots of its 384) gives the whole-cache step's
-    output and writes the same cache."""
+    form (blocks of 64 slots of its 384): its cache entry is laid ``[B,
+    slots, Hkv * D]``, and over the same numbers so laid the step gives
+    the whole-cache step's output and writes the same row."""
     mod, cache, params, x = _layer("gqa")
+    cfg = mod.cfg
     want, new_whole = _step(mod, params, x, POSITIONS[where], cache,
                             monkeypatch)
     dense_step = _kernel_form(monkeypatch, block=64)
     assert dense_step.step_form(1, 4, 2, LMAX) == "kernel"
+    entry = mod.cache_entry(cfg, 3, LMAX, jnp.float32)
+    packed = (3, LMAX, cfg.num_kv_heads * cfg.head_dim)
+    assert {n: a.shape for n, a in entry.items()} == {"k": packed,
+                                                      "v": packed}
     calls = []
     kernel = dense_step.dense_step
     monkeypatch.setattr(dense_step, "dense_step",
                         lambda *a: calls.append(1) or kernel(*a))
-    got, new = _step(mod, params, x, POSITIONS[where], cache)
+    got, new = _step(mod, params, x, POSITIONS[where],
+                     {n: a.reshape(packed) for n, a in cache.items()})
     assert calls == [1]
     np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
     for name in new:
-        np.testing.assert_array_equal(np.asarray(new[name]),
-                                      np.asarray(new_whole[name]))
+        assert new[name].shape == packed
+        np.testing.assert_array_equal(
+            np.asarray(new[name]).reshape(new_whole[name].shape),
+            np.asarray(new_whole[name]))
 
 
 # what the one-token step of each cell's model sees: (preset, overrides,
@@ -359,7 +396,9 @@ def test_the_step_form_from_what_the_step_sees(cell, device, monkeypatch):
     """The form follows the queries a row, the heads held, the cache's
     dtype and length, and the trace's target: the kernel for LFM2's
     shapes on one TPU device alone; everything as it was on the CPU (and
-    under a mesh: ``select_form``)."""
+    under a mesh: ``select_form``).  The cache's layout follows the
+    form: rank 3 for ``ppo-lfm2-ep4-sync`` on one TPU device, 4 for
+    every other cell and everywhere else."""
     import dataclasses
 
     from orion_tpu.ops import indexer
@@ -372,6 +411,22 @@ def test_the_step_form_from_what_the_step_sees(cell, device, monkeypatch):
         want = "prefix"
     assert got.get("kv_step_form") == want
     assert ("kv_step_slots" in got) == (want is not None)
+    # the cache is laid out for the step: K and V [B, slots, Hkv * D]
+    # under the kernel, whose rows fill their lanes, and per head
+    # elsewhere (the latent cache has no K and V)
+    mixers = dict.fromkeys(m for m, _ in cfg.layer_kinds()
+                           if m and tr.MIXERS[m].per_head_kv)
+    ranks = {a.ndim for m in mixers for n, a in jax.eval_shape(
+        lambda: tr.cache_entry(cfg, m, 2, slots, jnp.bfloat16,
+                               quantized=int8)).items() if n in "kv"}
+    assert ranks <= {3 if want == "kernel" else 4}
+    if tr.Attention in tr.kinds(cfg):
+        assert ranks and got["kv_cache_lane_fill"] == (
+            1.0 if want == "kernel" else min(cfg.head_dim, 128) / 128)
+        assert (want == "kernel") == (
+            tr.Attention.kv_step_form(cfg, slots, int8) == "kernel")
+    else:
+        assert "kv_cache_lane_fill" not in got
 
 
 def _generate(arch, monkeypatch, whole, **rollout):
@@ -463,7 +518,7 @@ def test_the_kernels_read_from_lengths(monkeypatch):
     to each ROW's filled slot, the mean over rows and steps: row 0 at 24
     .. 534, row 1 at 300 .. 810 of 1024 slots; an int8 cache steps as it
     did."""
-    _kernel_form(monkeypatch)
+    _kernel_form(monkeypatch, block=256)
     got = _engine(max_prompt_len=512, max_new_tokens=512) \
         .dispatch_attrs((2, 512), [24, 300])
     assert got["kv_step_form"] == "kernel"
