@@ -23,11 +23,11 @@ LATENT_ARCHS = ("deepseek_v3", "kimi_linear")
 #: The archs whose model is a per-layer pattern of (mixer, FFN) kinds
 #: over RMSNorm blocks (``ModelConfig.layer_kinds``).
 PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa", "nemotron_h",
-                                "sdar_moe", "lfm2_moe")
+                                "sdar_moe", "lfm2_moe", "mellum")
 #: The archs whose layers end in the dropless expert layer
 #: (``ops.moe.TopKMoE``) and so share its fields and their checks.
 EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa", "nemotron_h", "sdar_moe",
-                               "lfm2_moe")
+                               "lfm2_moe", "mellum")
 #: nemotron_h's ``hybrid_override_pattern`` characters -> (mixer, ffn)
 #: halves of a block.
 PATTERN_HALVES = {"M": ("mamba2", None), "*": ("attention", None),
@@ -35,13 +35,14 @@ PATTERN_HALVES = {"M": ("mamba2", None), "*": ("attention", None),
 #: The published ``layer_types`` entries -> mixers, by arch.
 LAYER_TYPE_MIXERS = {
     "olmo_hybrid": {"linear_attention": "gdn", "full_attention": "attention"},
-    "lfm2_moe": {"conv": "conv", "full_attention": "attention"}}
+    "lfm2_moe": {"conv": "conv", "full_attention": "attention"},
+    "mellum": {"sliding_attention": "window", "full_attention": "attention"}}
 
 
 @dataclass
 class ModelConfig:
     """Architecture hyperparameters for the decoder-only transformer:
-    flat fields under the published key names of nine model families,
+    flat fields under the published key names of ten model families,
     a ``_check_<arch>`` each, and the model's description derived from
     them, :meth:`layer_kinds`: one (mixer, feed-forward) pair per block.
     What follows from a kind is ``models/transformer.py``'s
@@ -50,7 +51,7 @@ class ModelConfig:
 
     # the family whose published keys and checks apply: "llama" | "neox"
     # | "deepseek_v3" | "kimi_linear" | "olmo_hybrid" | "keye_dsa"
-    # | "nemotron_h" | "sdar_moe" | "lfm2_moe"
+    # | "nemotron_h" | "sdar_moe" | "lfm2_moe" | "mellum"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -231,6 +232,23 @@ class ModelConfig:
     # sigmoid scores and the selection bias (the published
     # use_expert_bias); the head is the embedding (tie_word_embeddings).
     conv_L_cache: int = 0
+    # arch="mellum" (JetBrains' Mellum 2): the pre-norm RMSNorm block of
+    # grouped-query attention (keye_dsa's per-head q/k norm, full rotary)
+    # over the dropless expert layer with softmax scores, its attention
+    # per layer by the published layer_types, whole and read up to
+    # num_layers: "sliding_attention" (models/transformer.py
+    # WindowAttention: a query at position t sees the keys s <= t with
+    # t - s < sliding_window, itself and the sliding_window - 1 before
+    # it; its cache is a ring of sliding_window slots written at position
+    # mod sliding_window) or "full_attention" (every key s <= t, a cache
+    # of max_seq_len slots).  rope_parameters, as published, one entry a
+    # layer type: {"rope_type": "default" | "yarn", "rope_theta", and for
+    # yarn "factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "attention_factor"} (ops/rotary.py inv_frequencies;
+    # the factor multiplies cos and sin); a layer type without an entry
+    # rotates by rope_theta.
+    sliding_window: int = 0
+    rope_parameters: dict = field(default_factory=dict)
 
     def __post_init__(self) -> None:
         if self.arch in EXPERT_ARCHS:
@@ -251,6 +269,13 @@ class ModelConfig:
             self._check_olmo_hybrid()
         if self.arch == "lfm2_moe":
             self._check_lfm2_moe()
+        if self.arch == "mellum":
+            self._check_mellum()
+        elif self.sliding_window or self.rope_parameters:
+            raise ValueError(
+                f"model.sliding_window={self.sliding_window} / "
+                "model.rope_parameters: only arch='mellum' has windowed "
+                "layers and rotary parameters by layer type")
         self.head_share = tuple(self.head_share)
         if self.arch == "nemotron_h":
             self._check_nemotron_h()
@@ -440,6 +465,32 @@ class ModelConfig:
                 "under the expert bias (moe_scoring), and a convolution "
                 "takes whole sequences (seq_shard_activations)")
 
+    def _check_mellum(self) -> None:
+        self._check_layer_types()
+        if self.sliding_window < 1:
+            raise ValueError("arch='mellum' needs model.sliding_window >= 1 "
+                             "(the keys a sliding layer's query sees)")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("arch='mellum': num_kv_heads divides "
+                             "num_heads (grouped-query attention)")
+        unknown = set(self.rope_parameters) - set(LAYER_TYPE_MIXERS["mellum"])
+        kinds = {p.get("rope_type", "default")
+                 for p in self.rope_parameters.values()}
+        if unknown or kinds - {"default", "yarn"}:
+            raise ValueError(
+                "model.rope_parameters has one entry a layer type "
+                f"({sorted(LAYER_TYPE_MIXERS['mellum'])}) of rope_type "
+                f"'default' or 'yarn' (got {sorted(self.rope_parameters)}, "
+                f"{sorted(kinds)})")
+        if (self.moe_scoring != "softmax" or self.seq_shard_activations
+                or self.first_k_dense_replace or self.n_shared_experts):
+            raise ValueError(
+                "arch='mellum': every layer is an expert layer "
+                "(mlp_layer_types all sparse: first_k_dense_replace = 0) "
+                "whose router scores by a softmax over all experts "
+                "(moe_scoring), without a shared expert, and a window is "
+                "not cut across sequence shards (seq_shard_activations)")
+
     @property
     def latent_attention(self) -> bool:
         """The attention is latent (deepseek_v3, kimi_linear)."""
@@ -516,8 +567,8 @@ class ModelConfig:
     def layer_kinds(self) -> tuple:
         """((mixer, ffn), ...) per block: the model's description.
         mixer: a key of ``models.transformer.MIXERS`` ("attention",
-        "sparse", "latent", "kda", "gdn", "mamba2", "conv": what each is
-        and caches is stated there) or None (no mixer half); ffn: "dense" (a
+        "window", "sparse", "latent", "kda", "gdn", "mamba2", "conv": what
+        each is and caches is stated there) or None (no mixer half); ffn: "dense" (a
         SwiGLU or GELU MLP), "gshard" (num_experts), "experts" (the
         dropless layer) or None (no feed-forward half).
         Every model but nemotron_h has both halves in every block, one
@@ -546,6 +597,9 @@ class ModelConfig:
                 (LAYER_TYPE_MIXERS[self.arch][t],
                  "dense" if i < self.first_k_dense_replace else "experts")
                 for i, t in enumerate(self.layer_types[:self.num_layers]))
+        if self.arch == "mellum":
+            return tuple((LAYER_TYPE_MIXERS[self.arch][t], "experts")
+                         for t in self.layer_types[:self.num_layers])
         if self.arch == "keye_dsa":
             return (("sparse", "experts"),) * self.num_layers
         if self.arch == "sdar_moe":
@@ -717,6 +771,42 @@ class ModelConfig:
         )
 
     @staticmethod
+    def mellum2_12b_a2_5b() -> "ModelConfig":
+        """JetBrains/Mellum2-12B-A2.5B-Instruct as published
+        (config.json, model_type mellum): every expert held.
+        ``num_experts`` is ``n_routed_experts`` here (``num_experts`` is
+        the GShard layer's switch); ``intermediate_size`` 7168 is
+        published and belongs to no layer (``mlp_layer_types`` is
+        ``sparse`` 28 times); the multi-token-prediction head is not
+        here (config.json has no key for it)."""
+        return ModelConfig(
+            arch="mellum", vocab_size=98304, hidden_size=2304,
+            intermediate_size=7168, num_layers=28, num_heads=32,
+            num_kv_heads=4, head_dim=128, max_seq_len=131072,
+            rope_theta=500000.0, rms_norm_eps=1e-6,
+            layer_types=(("sliding_attention",) * 3
+                         + ("full_attention",)) * 7,
+            sliding_window=1024,
+            rope_parameters={
+                "full_attention": {
+                    "rope_type": "yarn", "rope_theta": 500000.0,
+                    "factor": 16.0,
+                    "original_max_position_embeddings": 8192,
+                    "beta_fast": 32.0, "beta_slow": 1.0,
+                    "attention_factor": 1.2772588722239782},
+                "sliding_attention": {"rope_type": "default",
+                                      "rope_theta": 500000.0}},
+            n_routed_experts=64, num_experts_per_tok=8,
+            moe_intermediate_size=896, moe_scoring="softmax",
+        )
+
+    @staticmethod
+    def tiny_mellum() -> "ModelConfig":
+        """``model_preset=tiny_mellum``: the small sibling of
+        mellum2_12b_a2_5b (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("mellum")
+
+    @staticmethod
     def tiny_lfm2_moe() -> "ModelConfig":
         """``model_preset=tiny_lfm2_moe``: the small sibling of
         lfm2_8b_a1b (tests, CPU rehearsals)."""
@@ -816,6 +906,32 @@ class ModelConfig:
                 conv_L_cache=3, n_routed_experts=8, num_experts_per_tok=2,
                 moe_intermediate_size=32, first_k_dense_replace=2,
                 routed_scaling_factor=1.0, moe_scoring="sigmoid",
+            )
+            base.update(kw)
+            return ModelConfig(**base)
+        if arch == "mellum":
+            # two whole periods S S S F; a window of 8 keys; YaRN over 16
+            # original positions, so that sequences of 40 pass its ramp;
+            # 2 query heads a key/value head
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=96, num_layers=8, num_heads=4,
+                num_kv_heads=2, head_dim=16, max_seq_len=128,
+                rope_theta=1e4, rms_norm_eps=1e-6,
+                layer_types=(("sliding_attention",) * 3
+                             + ("full_attention",)) * 2,
+                sliding_window=8,
+                rope_parameters={
+                    "full_attention": {
+                        "rope_type": "yarn", "rope_theta": 1e4,
+                        "factor": 4.0,
+                        "original_max_position_embeddings": 16,
+                        "beta_fast": 4.0, "beta_slow": 1.0,
+                        "attention_factor": 1.138629436111989},
+                    "sliding_attention": {"rope_type": "default",
+                                          "rope_theta": 1e4}},
+                n_routed_experts=8, num_experts_per_tok=2,
+                moe_intermediate_size=32, moe_scoring="softmax",
             )
             base.update(kw)
             return ModelConfig(**base)

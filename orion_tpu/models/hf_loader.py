@@ -91,6 +91,14 @@ _NO_LFM2_LOADER = (
     "[taps, channels] layout; arch='lfm2_moe' runs from random weights "
     "only")
 
+_NO_MELLUM_LOADER = (
+    "there is no mellum checkpoint loader yet: the checkpoint's files are "
+    "not in this repository (no network), so its tensor names (per-expert "
+    "gate / up / down, the per-head q / k norms, the multi-token-prediction "
+    "head this model leaves out) have no mapping onto models.transformer."
+    "Attention's and ops.moe.TopKMoE's that was checked against them; "
+    "arch='mellum' runs from random weights only")
+
 
 def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
                           include_lm_head: bool = True) -> dict:
@@ -112,6 +120,8 @@ def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
         raise ValueError(_NO_NEMOTRON_H_LOADER)
     elif cfg.arch == "lfm2_moe":
         raise ValueError(_NO_LFM2_LOADER)
+    elif cfg.arch == "mellum":
+        raise ValueError(_NO_MELLUM_LOADER)
     else:
         raise ValueError(cfg.arch)
     if not include_lm_head:
@@ -278,6 +288,8 @@ def config_from_hf(hf_cfg: Any) -> ModelConfig:
         raise ValueError(_NO_NEMOTRON_H_LOADER)
     if mt == "lfm2_moe":
         raise ValueError(_NO_LFM2_LOADER)
+    if mt == "mellum":
+        raise ValueError(_NO_MELLUM_LOADER)
     if mt == "llama":
         return ModelConfig(
             arch="llama",

@@ -9,7 +9,7 @@ share of :func:`remat_tag_bytes`, whether it takes ``token_mask``, what
 it reports on the trainer's spans (:func:`decode_attrs`,
 :func:`update_attrs`), which parameters RL holds fixed and the forms it
 cannot run (:func:`cannot_run`).  The mixers: :class:`Attention`,
-:class:`SparseAttention`, :class:`LatentAttention`,
+:class:`WindowAttention`, :class:`SparseAttention`, :class:`LatentAttention`,
 :class:`KimiDeltaAttention`, :class:`GatedDeltaNet`, :class:`Mamba2`,
 :class:`ShortConv`;
 the feed-forward halves: :class:`MLP`, ``ops.moe.MoEMLP`` (GShard,
@@ -52,8 +52,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from orion_tpu.config import ModelConfig
-from orion_tpu.ops.attention import (_NEG_INF, attention, step_attention,
-                                     streams_attention)
+from orion_tpu.ops.attention import (_NEG_INF, attention, positional_mask,
+                                     step_attention, streams_attention)
 from orion_tpu.ops.paged_kv import is_paged, write_paged_tokens
 from orion_tpu.ops.pallas import dense_step
 from orion_tpu.ops.rotary import apply_rotary
@@ -396,7 +396,10 @@ class Attention(nn.Module, Kind):
     (olmo_hybrid's, whose recurrent layers carry position).  The heads
     are those ``cfg.heads_held()`` leaves here (all, but under
     ``head_share``: that share's query heads against the key-value heads
-    they read, and their part of the output projection's sum)."""
+    they read, and their part of the output projection's sum).  The
+    rotation's table is that of the layer type's entry in
+    ``cfg.rope_parameters`` (``layer_type``; none: the default table at
+    ``rope_theta``)."""
 
     cfg: ModelConfig
     qk_norm: Any = False
@@ -404,6 +407,19 @@ class Attention(nn.Module, Kind):
 
     per_head_kv = True
     steps_over_prefix = True
+    layer_type = "full_attention"     # its key in cfg.rope_parameters
+
+    @staticmethod
+    def window(cfg) -> Optional[int]:
+        """The keys a query sees, itself among them (None: every key up
+        to itself)."""
+        return None
+
+    @classmethod
+    def trace_scope(cls, cfg) -> Optional[str]:
+        """The named scope a device trace groups this kind's operations
+        under, where a model mixes it with windowed layers."""
+        return "attn.full" if cfg.sliding_window else None
 
     @classmethod
     def kv_step_form(cls, cfg, slots, quantized=False) -> str:
@@ -468,11 +484,25 @@ class Attention(nn.Module, Kind):
 
         if self.rotary:
             rotary_dim = int(D * cfg.rotary_pct)
-            q, k = apply_rotary(q, k, positions, rotary_dim, cfg.rope_theta)
+            q, k = apply_rotary(q, k, positions, rotary_dim, cfg.rope_theta,
+                                cfg.rope_parameters.get(self.layer_type))
         return q, k, v
 
     @nn.compact
     def __call__(self, x, positions, layer_cache=None, visible=None):
+        return self.attend(x, positions, layer_cache, visible)
+
+    def attend(self, *args, **kw):
+        """:meth:`_attend` under the kind's named scope, where it has
+        one.  (Called inside a compact ``__call__``, as :meth:`qkv`.)"""
+        scope = self.trace_scope(self.cfg)
+        if not scope:
+            return self._attend(*args, **kw)
+        with jax.named_scope(scope):
+            return self._attend(*args, **kw)
+
+    def _attend(self, x, positions, layer_cache=None, visible=None,
+                token_mask=None):
         """x: [B, L, E]; positions: [B, L] absolute positions.
 
         layer_cache: {"k","v"} [B, Lmax, Hkv, D], [B, Lmax, Hkv * D]
@@ -493,11 +523,19 @@ class Attention(nn.Module, Kind):
         ``visible`` (:class:`Visible`): what to mask by where that is
         not ``positions``; a block-diffusion model given none masks by
         the clean rule (:func:`clean_rule`).
+        Under a window (:meth:`window`, :class:`WindowAttention`) the
+        cache is a ring, written at ``position mod its slots``: prefill
+        attends over its own fresh keys and values under the window rule
+        and hands the ring each row's last real tokens
+        (``token_mask`` [B, L] says which are real; none: all), and a
+        one-token step reads the ring's filled slots, all of which the
+        write has left inside the window.
         Returns (out [B, L, E], new_layer_cache).
         """
         cfg = self.cfg
         B, L, _ = x.shape
         H, D = cfg.heads_held()["q"], cfg.head_dim
+        window = self.window(cfg)
         q, k, v = self.qkv(x, positions)
         if visible is None and cfg.block_length:
             visible = clean_rule(positions, cfg.block_length)
@@ -534,7 +572,11 @@ class Attention(nn.Module, Kind):
                 from orion_tpu.ops.paged_kv import gather_paged_kv
                 keys, values = gather_paged_kv(new_cache, _dt(cfg.dtype))
         elif layer_cache is not None:
-            write = _cache_writer(positions, B, L, step)
+            if window is None:
+                write = _cache_writer(positions, B, L, step)
+            else:
+                write = _ring_writer(positions, token_mask, B, L,
+                                     layer_cache["k"].shape[1])
 
             if "k_scale" in layer_cache:
                 # int8 KV cache (RolloutConfig.quantize_kv): quantize
@@ -565,27 +607,34 @@ class Attention(nn.Module, Kind):
                 lay = (B, L) + layer_cache["k"].shape[2:]
                 new_cache = {"k": write(layer_cache["k"], k.reshape(lay)),
                              "v": write(layer_cache["v"], v.reshape(lay))}
-                keys, values = (new_cache[n].reshape(B, -1, *k.shape[2:])
-                                for n in "kv")
+                if window is None:
+                    keys, values = (new_cache[n].reshape(B, -1, *k.shape[2:])
+                                    for n in "kv")
+                else:
+                    # prefill sees its own rows; a ring is no place to
+                    # look positions up in
+                    keys, values = k, v
         else:
             new_cache = None
             keys, values = k, v
 
-        # Mask: query at absolute position p attends to cache slots
-        # j <= p.  Slots map 1:1 to absolute positions in the train,
-        # prefill, decode and paged-gather paths (decode overwrites the
-        # right-padded prompt tail slot by slot), so one formula covers
-        # all of them.
-        def mask(slots):
-            key_slots = jnp.arange(slots, dtype=positions.dtype)
-            return key_slots[None, None, :] <= see[:, :, None]
-
+        # Mask (positional_mask): query at absolute position p attends
+        # to cache slots j <= p.  Slots map 1:1 to absolute positions in
+        # the train, prefill, decode and paged-gather paths (decode
+        # overwrites the right-padded prompt tail slot by slot), so one
+        # formula covers all of them.
         if paged_decode_out is not None:
             out = paged_decode_out[:, None, :, :]
         elif step and not is_paged(layer_cache):
             # one new token (one block's, two's) against the dense slot
             # cache, int8 or not
             Lmax = new_cache["k"].shape[1]
+            if window is not None:
+                # the ring's filled slots: after the write every one of
+                # them holds a key inside the window (the new token took
+                # the place of the one that left it), and a softmax does
+                # not care in which order its slots lie
+                see = jnp.minimum(see, Lmax - 1)
             if new_cache["k"].ndim == 3:
                 # laid out for the kernel (cache_entry: one token, a
                 # group of query heads on each of several key heads):
@@ -594,7 +643,7 @@ class Attention(nn.Module, Kind):
                     q, new_cache["k"], new_cache["v"], see[:, 0], scale)
             else:
                 # over the filled prefix of its slots
-                whole = mask(Lmax)
+                whole = positional_mask(see, Lmax)
 
                 def attend(m):
                     c = {n: a[:, :m] for n, a in new_cache.items()}
@@ -610,13 +659,173 @@ class Attention(nn.Module, Kind):
                                     visible.block, scale,
                                     impl=cfg.attention_impl)
         else:
-            out = attention(q, keys, values, mask(keys.shape[1]),
+            out = attention(q, keys, values,
+                            positional_mask(see, keys.shape[1], window),
                             scale=scale, impl=cfg.attention_impl,
-                            q_positions=see)
+                            q_positions=see, window=window)
         out = out.reshape(B, L, H * D)
         out = _dense(cfg.hidden_size, ("heads", "embed"),
                      cfg.attn_bias, cfg, "o_proj")(out)
         return out, new_cache
+
+
+def _ring_writer(positions, token_mask, B: int, L: int, ring: int):
+    """``write(cache, new)`` into a ring of ``ring`` slots, position p
+    at slot ``p mod ring``.  One token a row: its slot.  A prefill of L
+    rows from position 0: each ROW's last ``min(len, ring)`` real tokens
+    (``token_mask`` [B, L], a row's prefix: rows are right-padded to
+    different real lengths; none: all L), slot j taking position ``j +
+    ring floor((len - 1 - j) / ring)`` in ONE gather along the sequence;
+    a slot no real token falls on keeps what it held."""
+    if L == 1:
+        return _cache_writer(positions % ring, B, 1)
+    lens = (jnp.full((B,), L, jnp.int32) if token_mask is None
+            else jnp.sum(token_mask, axis=1, dtype=jnp.int32))
+    slot = jnp.arange(ring, dtype=jnp.int32)[None, :]
+    held = slot < lens[:, None]                                  # [B, ring]
+    at = jnp.where(held, slot + (lens[:, None] - 1 - slot) // ring * ring, 0)
+
+    def write(cache, new):
+        wide = (...,) + (None,) * (new.ndim - 2)
+        return jnp.where(held[wide],
+                         jnp.take_along_axis(new, at[wide], axis=1), cache)
+    return write
+
+
+class WindowAttention(Attention):
+    """Sliding-window attention (mellum's ``sliding_attention`` layers):
+    :class:`Attention` whose query at position t sees the keys s with
+    ``t - sliding_window < s <= t``, itself and the ``sliding_window -
+    1`` before it, rotated by its own layer type's table.
+
+    What follows from the window, stated here and nowhere else: the
+    cache entry is a RING of ``min(sliding_window, slots)`` slots
+    (:meth:`cache_entry`; laid ``[B, ring, Hkv * D]`` where the step is
+    the kernel, as :class:`Attention`'s), position p written at slot ``p
+    mod ring`` (:func:`_ring_writer`).  Whole sequences and prefill run
+    the attention over their own keys under the window rule (the flash
+    kernels' ``window``: tiles wholly behind it are neither fetched nor
+    multiplied; the einsum over :func:`positional_mask` elsewhere).  The
+    one-token step needs no new rule: after its write every filled slot
+    of the ring is inside the window, so it is :class:`Attention`'s step
+    (``dense_step``, :func:`prefix_step` on the CPU) under ``reach =
+    min(t, ring - 1)``.  ``token_mask`` says which of a prefill's rows
+    are real: the ring takes each row's LAST real tokens."""
+
+    takes_token_mask = True
+    layer_type = "sliding_attention"
+
+    @staticmethod
+    def window(cfg):
+        return cfg.sliding_window
+
+    @classmethod
+    def trace_scope(cls, cfg):
+        return "attn.window"
+
+    @classmethod
+    def ring_slots(cls, cfg, slots: int) -> int:
+        """The ring's slots beside full caches of ``slots``."""
+        return min(cls.window(cfg), slots)
+
+    @classmethod
+    def kv_step_form(cls, cfg, slots, quantized=False) -> str:
+        """:class:`Attention`'s, of the ring beside caches of ``slots``."""
+        return super().kv_step_form(cfg, cls.ring_slots(cfg, slots),
+                                    quantized)
+
+    @classmethod
+    def cache_entry(cls, cfg, batch, slots, dtype, pre=(), quantized=False):
+        """:class:`Attention`'s entry of :meth:`ring_slots` slots."""
+        return super().cache_entry(cfg, batch, cls.ring_slots(cfg, slots),
+                                   dtype, pre, quantized)
+
+    @staticmethod
+    def lacks(cfg):
+        pages = ("a ring entry is not made of pages: the page pool, its "
+                 "block tables and the paged-attention kernel hold every "
+                 "position of a sequence and mask by position alone "
+                 "(ops/paged_kv.py, ops/pallas/paged_attention.py); a "
+                 "window over pages (pages freed as they leave it, a lower "
+                 "bound in the kernel) is not written")
+        return {
+            "paged": pages,
+            "continuous": "the continuous engine's cache is the page pool "
+            "and its prefill comes in chunks at any position: " + pages,
+            "quantize_kv": "there is no int8 ring (ops/quant.py's scales "
+            "would ride the ring beside their values; the ring's write and "
+            "the step over it were run against the reference in bf16 alone)",
+            "sequence_parallel": "the sequence-parallel attentions exchange "
+            "keys and values by position and apply the causal rule alone "
+            "(ops/attention.py::_flash_on_mesh is handed the window, "
+            "parallel/longctx.py's ring and ulysses forms are not: most of "
+            "a ring's rotations would carry keys no query of the shard "
+            "sees)"}
+
+    @staticmethod
+    def keys_seen(lens, window: int) -> dict:
+        """The real tokens of sequences of ``lens`` (``seq_tokens``) and
+        the keys their queries see on ONE layer, under the window and
+        under the causal rule alone: query t (0-based) has ``min(t + 1,
+        window)`` of its ``t + 1``."""
+        n = np.asarray(lens, np.int64)
+        m = np.minimum(n, window)
+        return {"seq_tokens": int(n.sum()),
+                "window_keys_seen": int((m * (m + 1) // 2
+                                         + (n - m) * window).sum()),
+                "causal_keys": int((n * (n + 1) // 2).sum())}
+
+    @staticmethod
+    def layer_counts(cfg) -> dict:
+        mixers = [m for m, _ in cfg.layer_kinds()]
+        return {"window_layers": mixers.count("window"),
+                "full_layers": mixers.count("attention")}
+
+    @classmethod
+    def forward_attrs(cls, cfg, total_lens):
+        """Of ONE whole-sequence forward of the batch: how many layers
+        of either kind, the window, the real tokens (``seq_tokens``) and
+        the keys their queries see on a layer (:meth:`keys_seen`); the
+        experts held beside them (the benchmark's operation count reads
+        all of it off the span: it hard-codes no count)."""
+        return {**cls.layer_counts(cfg), "sliding_window": cfg.sliding_window,
+                "experts_held": cfg.experts_held,
+                **cls.keys_seen(total_lens, cfg.sliding_window)}
+
+    @classmethod
+    def decode_attrs(cls, cfg, lens, slots, new_tokens):
+        """The two kinds of cache side by side: ``window_slots`` (the
+        ring's), ``ring_cache_bytes`` and ``full_cache_bytes`` of the
+        batch over all layers of either kind, and the slots one row's
+        one-token step reads a layer of either kind, the mean over the
+        steps after prompts of ``lens`` real tokens
+        (``kv_slots_read_window`` under ``reach = min(t, ring - 1)``,
+        ``kv_slots_read_full``): the blocks ``dense_step`` visits where
+        the step is the kernel (its ``step_slots``), :func:`prefix_step`'s
+        prefix elsewhere; and what the prefill goes over: the prompts'
+        real tokens (``seq_tokens``) and the keys their queries see a
+        layer (:meth:`keys_seen`)."""
+        ring = cls.ring_slots(cfg, slots)
+        counts = cls.layer_counts(cfg)
+        row = (2 * cfg.heads_held()["kv"] * cfg.head_dim
+               * _dt(cfg.dtype).itemsize * len(lens))
+
+        def read(kind, cache_len):
+            if kind.kv_step_form(cfg, slots):
+                return dense_step.step_slots(lens, cache_len, new_tokens - 1)
+            return prefix_step_slots(lens, cache_len, new_tokens)
+
+        return {**counts, "window_slots": ring,
+                **cls.keys_seen(lens, cfg.sliding_window),
+                "ring_cache_bytes": counts["window_layers"] * ring * row,
+                "full_cache_bytes": counts["full_layers"] * slots * row,
+                "kv_slots_read_window": read(cls, ring),
+                "kv_slots_read_full": read(Attention, slots)}
+
+    @nn.compact
+    def __call__(self, x, positions, layer_cache=None, token_mask=None,
+                 visible=None):
+        return self.attend(x, positions, layer_cache, visible, token_mask)
 
 
 class SparseAttention(Attention):
@@ -1570,7 +1779,8 @@ class MLP(nn.Module, Kind):
 
 #: ``ModelConfig.layer_kinds``' mixers -> the modules that implement
 #: them and state what follows from them (:class:`Kind`).
-MIXERS = {"attention": Attention, "sparse": SparseAttention,
+MIXERS = {"attention": Attention, "window": WindowAttention,
+          "sparse": SparseAttention,
           "latent": LatentAttention, "kda": KimiDeltaAttention,
           "gdn": GatedDeltaNet, "mamba2": Mamba2, "conv": ShortConv}
 
@@ -1582,7 +1792,7 @@ def mixer_spec(cfg: ModelConfig, kind: str):
     if cfg.arch == "olmo_hybrid" and kind == "attention":
         # rope_theta is published null (0 here): no rotation
         kw = {"qk_norm": True, "rotary": cfg.rope_theta > 0}
-    elif cfg.arch in ("keye_dsa", "sdar_moe", "lfm2_moe") \
+    elif cfg.arch in ("keye_dsa", "sdar_moe", "lfm2_moe", "mellum") \
             and issubclass(MIXERS[kind], Attention):
         kw = {"qk_norm": "head"}
     return MIXERS[kind], kw

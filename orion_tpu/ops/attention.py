@@ -137,7 +137,8 @@ def step_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               mask: jnp.ndarray, scale: float,
               impl: str = "reference",
-              q_positions: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+              q_positions: Optional[jnp.ndarray] = None,
+              window: Optional[int] = None) -> jnp.ndarray:
     """Dispatch on attention implementation.
 
     impl: "auto" -> flash on TPU for Lq > 1 (the measured ~2x kernel is
@@ -152,7 +153,11 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     CONTRACT: every non-"reference" path ignores ``mask`` and applies
     the positional rule ``kv_position <= q_position`` — which holds for
-    every mask this function is given in models/transformer.py.  A mask
+    every mask this function is given in models/transformer.py, and
+    under ``window`` (a sliding-window layer, :func:`positional_mask`)
+    ``q_position - kv_position < window`` as well: the flash kernels
+    take it as a static argument, the sequence-parallel impls have no
+    such rule and refuse it.  A mask
     with extra structure (padding-aware, bidirectional, packed-segment)
     requires impl="reference"; a SELECTION of keys a query (learned
     sparse attention) goes through :func:`sparse_attention`, whose
@@ -173,6 +178,8 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         else:
             impl = "reference"
     if impl in ("ring", "ulysses") and q.shape[1] > 1:
+        if window is not None:
+            raise ValueError(f"attention_impl={impl!r} has no window rule")
         if q_positions is None:
             raise ValueError(f"{impl} attention requires q_positions")
         from orion_tpu.parallel.longctx import (ring_attention,
@@ -187,8 +194,21 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if impl == "flash" and q.shape[1] > 1:
         if q_positions is None:
             raise ValueError("flash attention requires q_positions")
-        return _flash_on_mesh(q, k, v, q_positions, scale)
+        return _flash_on_mesh(q, k, v, q_positions, scale, window)
     return reference_attention_gqa(q, k, v, mask, scale)
+
+
+def positional_mask(see: jnp.ndarray, slots: int,
+                    window: Optional[int] = None) -> jnp.ndarray:
+    """[B, Lq, slots] bool, the positional rule over slots that ARE
+    positions: the query that sees up to ``see`` [B, Lq] attends to slot
+    j iff ``j <= see``, and under ``window`` iff also ``see - j <
+    window`` (itself and the ``window - 1`` before it)."""
+    key_slots = jnp.arange(slots, dtype=see.dtype)
+    mask = key_slots[None, None, :] <= see[:, :, None]
+    if window is not None:
+        mask &= see[:, :, None] - key_slots[None, None, :] < window
+    return mask
 
 
 def sparse_attention(q, k, v, mask, sel_t, q_positions, scale: float,
@@ -381,8 +401,9 @@ def _noisy_vjp_bwd(scale, block, residuals, dout):
 noisy_streams_attention.defvjp(_noisy_vjp_fwd, _noisy_vjp_bwd)
 
 
-def _flash_on_mesh(q, k, v, q_positions, scale):
-    """The flash kernel under whatever mesh is ambient.
+def _flash_on_mesh(q, k, v, q_positions, scale, window=None):
+    """The flash kernel under whatever mesh is ambient (``window``: its
+    windowed form).
 
     jax refuses to lower a Mosaic kernel inside an automatically
     partitioned program ("Mosaic kernels cannot be automatically
@@ -394,9 +415,13 @@ def _flash_on_mesh(q, k, v, q_positions, scale):
     replicates.  Already inside someone else's shard_map (ring /
     ulysses / pipeline bodies) the kernel is called as is.
     """
-    from orion_tpu.ops.pallas.flash_attention import flash_attention_gqa
+    from orion_tpu.ops.pallas import flash_attention
     from orion_tpu.parallel.sharding import ambient_mesh
 
+    flash_attention_gqa = flash_attention.flash_attention_gqa
+    if window is not None:
+        flash_attention_gqa = functools.partial(
+            flash_attention_gqa, window=window)
     mesh = ambient_mesh()
     if (mesh.empty or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):

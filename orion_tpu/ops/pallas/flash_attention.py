@@ -62,6 +62,15 @@ kernels.  Design:
   they also cost no HBM bandwidth.  (The clamp assumes position
   monotonicity, so the ring/kv_positions path disables it and relies
   on the compute skip alone.)
+- A WINDOW (``flash_attention_gqa(..., window=w)``, slot == position
+  only): a query at position p also needs ``p - j < w``.  The rule
+  enters the mask, the tile extents (a q tile's live kv tiles of a
+  major block are a run ``[lo, hi]``, no longer a prefix:
+  ``_for_each_run``) and the fetch clamps (from below too), so tiles
+  wholly behind the window are neither fetched nor multiplied.  A
+  Python branch: without a window grid, index maps and kernel bodies
+  are the program they were.  The windowed calls carry names of their
+  own (``flash_fwd_window``, ``flash_dq_window``, ``flash_dkv_window``).
 - Rows with NO valid key (possible per ring chunk) produce out = 0 and
   lse ≈ -inf — exactly the neutral element of the streaming-softmax
   merge in parallel.longctx.ring_attention.
@@ -147,6 +156,25 @@ def _block_extents(q_positions, kv_positions, bq, bkv, nkv=None):
     return qmax, imin, kvmin
 
 
+def _window_extents(q_positions, kvmin, bq, bkv, window):
+    """The two tables a window adds (slot == position: kv tile j ends at
+    ``kvmin[j] + bkv - 1``):
+
+    qmin [B, nq]   — smallest position in q-block i; pair (i, j) lies
+                     wholly behind the window iff
+                     ``qmin[i] - (kvmin[j] + bkv - 1) >= window``.
+    imax [B, nkv]  — number of q-blocks that kv-block j is not wholly
+                     behind (= one past the last relevant q-block when q
+                     positions are monotone).
+    """
+    B, Lq = q_positions.shape
+    qmin = jnp.min(q_positions.reshape(B, Lq // bq, bq),
+                   axis=-1).astype(jnp.int32)
+    imax = jnp.sum(qmin[:, :, None] - (kvmin[:, None, :] + (bkv - 1))
+                   < window, axis=1).astype(jnp.int32)
+    return qmin, imax
+
+
 # Contracting dims of the kernels' two product forms.
 _NT = ((1,), (1,))   # a @ b.T
 _NN = ((1,), (0,))   # a @ b
@@ -208,30 +236,39 @@ class _Plan(NamedTuple):
     major: int
     nq: int          # q major blocks
     nkv: int         # kv major blocks
-    tables: tuple    # (qmax, imin, kvmin)
+    tables: tuple    # (qmax, imin, kvmin); a window adds (qmin, imax)
 
 
-def _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3) -> _Plan:
+def _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3, window=None) -> _Plan:
     bq, nq_sub = _tiles(Lq, blk_q)
     bkv, n_sub = _tiles(Lk, blk_kv)
     qmajor, major = bq * nq_sub, bkv * n_sub
     tables = _block_extents(
         qpos3[:, 0, :], None if kvpos3 is None else kvpos3[:, :, 0],
         bq, bkv, nkv=Lk // bkv)
+    if window is not None:
+        assert kvpos3 is None, "a window needs slot == position"
+        tables += _window_extents(qpos3[:, 0, :], tables[2], bq, bkv, window)
     return _Plan(bq, nq_sub, bkv, n_sub, qmajor, major, Lq // qmajor,
                  Lk // major, tables)
 
 
-def _kv_fetch(p: _Plan, clamp: bool):
+def _kv_fetch(p: _Plan, clamp: bool, window=None):
     """The kv major block that step (i, j) of a (.., nq, nkv) grid
-    fetches.  Under the clamp, steps beyond the q major block's causal
-    frontier (its last tile's max position: positions are monotone)
-    re-fetch the same block, which Pallas elides.  (Contiguous kv
-    positions only.)"""
+    fetches, from the scalar-prefetch tables ``t``.  Under the clamp,
+    steps beyond the q major block's causal frontier (its last tile's
+    max position: positions are monotone) re-fetch the same block, which
+    Pallas elides; under a window so do the steps before the first block
+    that holds a key of the q major block's first tile's window.
+    (Contiguous kv positions only.)"""
     if not clamp:
-        return lambda qmax, b, i, j: j
-    return lambda qmax, b, i, j: jnp.minimum(
-        j, qmax[b, i * p.nq_sub + p.nq_sub - 1] // p.major)
+        return lambda t, b, i, j: j
+    if window is None:
+        return lambda t, b, i, j: jnp.minimum(
+            j, t[0][b, i * p.nq_sub + p.nq_sub - 1] // p.major)
+    return lambda t, b, i, j: jnp.clip(
+        j, jnp.maximum(t[3][b, i * p.nq_sub] - (window - 1), 0) // p.major,
+        t[0][b, i * p.nq_sub + p.nq_sub - 1] // p.major)
 
 
 def _for_each_extent(live, body, leading: bool):
@@ -254,13 +291,59 @@ def _for_each_extent(live, body, leading: bool):
         pl.when(edge == c)(functools.partial(body, c))
 
 
+def _for_each_run(live, body):
+    """Call ``body(lo, hi)`` for the run ``[lo, hi]`` of tiles of the
+    major block from its first live tile to its last — nothing if no
+    tile is live.  Under the causal rule AND a window the live tiles are
+    that run (a q tile's window is an interval of keys, a kv tile is
+    seen by an interval of queries): ``_for_each_extent`` without the
+    edge that a window moves."""
+    n = len(live)
+    first, last = jnp.int32(n), jnp.int32(-1)
+    for c in reversed(range(n)):
+        first = jnp.where(live[c], c, first)
+    for c in range(n):
+        last = jnp.where(live[c], c, last)
+    for lo in range(n):
+        for hi in range(lo, n):
+            pl.when((first == lo) & (last == hi))(
+                functools.partial(body, lo, hi))
+
+
+def _for_each_live_kv(window, live, update):
+    """``update(lo, hi)`` over a q tile's live kv tiles of a major
+    block: the leading extent ``[0, c]`` without a window, the run under
+    one."""
+    if window is None:
+        _for_each_extent(live, functools.partial(update, 0), leading=True)
+    else:
+        _for_each_run(live, update)
+
+
+def _windowed(seen, window, tables, b, q_tile, kv_tile, blk_kv):
+    """``seen`` (a q tile and a kv tile hold a causal pair: each
+    kernel's own comparison, as it was) and, under a window, that the kv
+    tile is not wholly behind the q tile's window.  ``q_tile``,
+    ``kv_tile``: thunks of the tiles' indices, so that nothing is
+    computed without a window."""
+    if window is None:
+        return seen
+    return seen & (tables[3][b, q_tile()] - tables[2][b, kv_tile()]
+                   - (blk_kv - 1) < window)
+
+
 def _seen(qpos_ref, kvpos_ref, kv_start, kv_rows, q_cols, shape,
-          sel_ref=None):
+          sel_ref=None, window=None):
     """[keys, queries] bool (``shape``): key rows ``kv_rows`` of the kv
     block, which starts at slot ``kv_start``, against query columns
     ``q_cols`` of the q block.  ``sel_ref``: the block of a selection
     ([1, keys, queries] int8, ops/indexer.py), which a pair must also
-    hold."""
+    hold.  ``window``: a query sees no key ``window`` or more positions
+    behind it (slot == position)."""
+    if window is not None:
+        kvcol = kv_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        qpos = qpos_ref[0, :, q_cols]
+        return (kvcol <= qpos) & (qpos - kvcol < window)
     if sel_ref is not None:
         return _seen(qpos_ref, kvpos_ref, kv_start, kv_rows, q_cols,
                      shape) & (sel_ref[0, kv_rows, q_cols] != 0)
@@ -335,9 +418,17 @@ def _for_each_head(n_rep: int, body):
 _DEAD = -3e38
 
 
-def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-                scale: float, use_kvpos: bool, nq_sub: int, n_sub: int,
-                use_sel: bool = False):
+def _split_tables(refs, window):
+    """(the prefetched tables, qpos_ref, the other refs) of a kernel's
+    arguments: three tables, five under a window."""
+    n = 3 if window is None else 5
+    return refs[:n], refs[n], refs[n + 1:]
+
+
+def _fwd_kernel(*refs, scale: float, use_kvpos: bool, nq_sub: int,
+                n_sub: int, use_sel: bool = False, window=None):
+    tables, qpos_ref, rest = _split_tables(refs, window)
+    qmax_ref, _, kvmin_ref = tables[:3]
     kvpos_ref = sel_ref = None
     if use_kvpos:
         kvpos_ref, *rest = rest
@@ -358,12 +449,14 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def update(cols, c):
-        width = (c + 1) * blk_kv
+    def update(cols, lo, hi):
+        # kv tiles lo .. hi of the major block (lo is 0 without a window)
+        span = slice(lo * blk_kv, (hi + 1) * blk_kv)
+        width = span.stop - span.start
         mask = _TileMask(
-            functools.partial(_seen, qpos_ref, kvpos_ref, j * major,
-                              slice(0, width), cols, (width, blk_q),
-                              sel_ref),
+            functools.partial(_seen, qpos_ref, kvpos_ref,
+                              j * major + span.start if lo else j * major,
+                              span, cols, (width, blk_q), sel_ref, window),
             bias_sc, NEG_INF, (slice(0, width), slice(None)))
 
         def group_head(h):
@@ -376,9 +469,15 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
             q = q_ref[0, h, cols, :]
             m_prev, l_prev = m_sc[h, :, cols], l_sc[h, :, cols]  # [1, bq]
             m_new = m_prev
-            for t in range(c + 1):
+
+            def tile(t):     # (its rows of the extent, of the major block)
                 rows = slice(t * blk_kv, (t + 1) * blk_kv)
-                st = mask.scores(_dot(k_ref[0, 0, rows, :], q, _NT) * scale,
+                return rows, slice(rows.start + span.start,
+                                   rows.stop + span.start)
+
+            for t in range(hi + 1 - lo):
+                rows, krows = tile(t)
+                st = mask.scores(_dot(k_ref[0, 0, krows, :], q, _NT) * scale,
                                  rows)                           # [bkv, bq]
                 st_sc[rows, :] = st
                 m_new = jnp.maximum(m_new,
@@ -387,17 +486,17 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
             m_sc[h, :, cols] = m_new
             l_new = l_prev * alpha
             acc_sc[h, :, cols] = acc_sc[h, :, cols] * alpha
-            for t in range(c + 1):
-                rows = slice(t * blk_kv, (t + 1) * blk_kv)
+            for t in range(hi + 1 - lo):
+                rows, krows = tile(t)
                 pt = jnp.exp(st_sc[rows, :] - m_new)
                 l_new = l_new + jnp.sum(pt, axis=0, keepdims=True)
                 acc_sc[h, :, cols] = acc_sc[h, :, cols] + _dot(
-                    vt_ref[0, 0, :, rows], pt.astype(vt_ref.dtype), _NN)
+                    vt_ref[0, 0, :, krows], pt.astype(vt_ref.dtype), _NN)
             l_sc[h, :, cols] = l_new
 
         def one_head(h):             # ONE wide tile: the step of one head
-            vt = vt_ref[0, 0, :, :width]                         # [Dv, w]
-            st = mask.scores(_dot(k_ref[0, 0, :width, :],
+            vt = vt_ref[0, 0, :, span]                           # [Dv, w]
+            st = mask.scores(_dot(k_ref[0, 0, span, :],
                                   q_ref[0, h, cols, :], _NT) * scale)
             m_prev, l_prev = m_sc[h, :, cols], l_sc[h, :, cols]  # [1, bq]
             m_new = jnp.maximum(m_prev,
@@ -411,15 +510,17 @@ def _fwd_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
                 vt, pt.astype(vt.dtype), _NN)                    # [Dv, bq]
 
         _for_each_head(n_rep,
-                       group_head if n_rep > 1 and c > 0 else one_head)
+                       group_head if n_rep > 1 and hi > lo else one_head)
 
     for r in range(nq_sub):
         # kv tile c of this major block holds a key some row of q tile r sees
-        _for_each_extent(
-            [kvmin_ref[b, j * n_sub + c] <= qmax_ref[b, i * nq_sub + r]
-             for c in range(n_sub)],
-            functools.partial(update, slice(r * blk_q, (r + 1) * blk_q)),
-            leading=True)
+        _for_each_live_kv(
+            window,
+            [_windowed(
+                kvmin_ref[b, j * n_sub + c] <= qmax_ref[b, i * nq_sub + r],
+                window, tables, b, lambda: i * nq_sub + r,
+                lambda: j * n_sub + c, blk_kv) for c in range(n_sub)],
+            functools.partial(update, slice(r * blk_q, (r + 1) * blk_q)))
 
     @pl.when(j == nj - 1)
     def _():
@@ -457,8 +558,16 @@ def _group_params(n_rep: int, head_bytes: int, tile: tuple, tiles: int = 1):
             [pltpu.VMEM(tile, jnp.float32)] * tiles)
 
 
+def _kernel_name(which: str, sel_t, window) -> str:
+    """The name a call reaches the device trace under."""
+    if window is not None:
+        return {"fwd": "flash_fwd_window", "bwd_dq": "flash_dq_window",
+                "bwd_dkv": "flash_dkv_window"}[which]
+    return ("flash_" if sel_t is None else "sparse_") + which
+
+
 def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
-         clamp: bool, sel_t=None):
+         clamp: bool, sel_t=None, window=None):
     """qt [B,H,Lq,D], kt/vt [B,Hkv,Lk,D], qpos3 [B,1,Lq], kvpos3
     [B,Lk,1] -> out [B,H,Lq,Dv], lse [B,H,1,Lq].  clamp=True enables
     the contiguous-path fetch clamps.  ``sel_t`` [B, Lk, Lq] int8: a
@@ -468,38 +577,39 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
     B, H, Lq, D = qt.shape
     Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
-    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3)
+    assert window is None or sel_t is None
+    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3, window)
     qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
-    fetch = _kv_fetch(p, clamp)
+    fetch = _kv_fetch(p, clamp, window)
     params, bias = _group_params(
         n_rep, qmajor * (2 * qt.dtype.itemsize * (D + Dv) + 4 * (Dv + 32)),
         (major, p.bq), tiles=2)
 
-    def k_map(b, g, i, j, qm, im, km):
-        return (b, g, fetch(qm, b, i, j), 0)
+    def k_map(b, g, i, j, *t):
+        return (b, g, fetch(t, b, i, j), 0)
 
-    def vt_map(b, g, i, j, qm, im, km):
-        return (b, g, 0, fetch(qm, b, i, j))
+    def vt_map(b, g, i, j, *t):
+        return (b, g, 0, fetch(t, b, i, j))
 
-    def q_lanes(b, g, i, j, qm, im, km):
+    def q_lanes(b, g, i, j, *t):
         return (b, g, 0, i)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(p.tables),
         grid=(B, Hkv, p.nq, p.nkv),
         in_specs=(
             [pl.BlockSpec((1, 1, qmajor),
-                          lambda b, g, i, j, qm, im, km: (b, 0, i))]
+                          lambda b, g, i, j, *t: (b, 0, i))]
             + ([pl.BlockSpec((1, major, 1),
-                             lambda b, g, i, j, qm, im, km: (b, j, 0))]
+                             lambda b, g, i, j, *t: (b, j, 0))]
                if use_kvpos else [])
             + ([pl.BlockSpec((1, major, qmajor),
-                             lambda b, g, i, j, qm, im, km:
-                             (b, fetch(qm, b, i, j), i))]
+                             lambda b, g, i, j, *t:
+                             (b, fetch(t, b, i, j), i))]
                if sel_t is not None else [])
             + [pl.BlockSpec((1, n_rep, qmajor, D),
-                            lambda b, g, i, j, qm, im, km: (b, g, i, 0)),
+                            lambda b, g, i, j, *t: (b, g, i, 0)),
                pl.BlockSpec((1, 1, major, D), k_map),
                pl.BlockSpec((1, 1, Dv, major), vt_map)]
         ),
@@ -520,10 +630,11 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
     # callers' own [B, L, H, D] <-> [B, H, L, D] transposes absorb it
     operands += [qt, kt, vt.swapaxes(2, 3)]
     out_t, lse = named_pallas_call(
-        "flash_fwd" if sel_t is None else "sparse_fwd",
+        _kernel_name("fwd", sel_t, window),
         functools.partial(_fwd_kernel, scale=scale, use_kvpos=use_kvpos,
                           nq_sub=p.nq_sub, n_sub=p.n_sub,
-                          use_sel=sel_t is not None),
+                          use_sel=sel_t is not None,
+                          **({} if window is None else {"window": window})),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Dv, Lq), qt.dtype),
@@ -540,9 +651,10 @@ def _fwd(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv,
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-               scale: float, use_kvpos: bool, nq_sub: int, n_sub: int,
-               use_sel: bool = False):
+def _dq_kernel(*refs, scale: float, use_kvpos: bool, nq_sub: int,
+               n_sub: int, use_sel: bool = False, window=None):
+    tables, qpos_ref, rest = _split_tables(refs, window)
+    qmax_ref, _, kvmin_ref = tables[:3]
     kvpos_ref = sel_ref = None
     if use_kvpos:
         kvpos_ref, *rest = rest
@@ -561,21 +673,23 @@ def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
     def _():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    def update(cols, c):
-        width = (c + 1) * blk_kv
+    def update(cols, lo, hi):
+        # kv tiles lo .. hi of the major block (lo is 0 without a window)
+        span = slice(lo * blk_kv, (hi + 1) * blk_kv)
+        width = span.stop - span.start
         mask = _TileMask(
-            functools.partial(_seen, qpos_ref, kvpos_ref, j * major,
-                              slice(0, width), cols, (width, blk_q),
-                              sel_ref),
+            functools.partial(_seen, qpos_ref, kvpos_ref,
+                              j * major + span.start if lo else j * major,
+                              span, cols, (width, blk_q), sel_ref, window),
             bias_sc, _DEAD, (slice(0, width), slice(None)))
 
         def head(h):                 # one of those that share k, v, the mask
-            kt = kt_ref[0, 0, :, :width]                         # [D, w]
+            kt = kt_ref[0, 0, :, span]                           # [D, w]
             do = do_ref[0, h, cols, :]                           # [bq, Dv]
-            st = _dot(k_ref[0, 0, :width, :], q_ref[0, h, cols, :],
+            st = _dot(k_ref[0, 0, span, :], q_ref[0, h, cols, :],
                       _NT) * scale                               # [w, bq]
             pt = mask.probs(st, lse_ref[0, h, :, cols])
-            dpt = _dot(v_ref[0, 0, :width, :], do, _NT)          # [w, bq]
+            dpt = _dot(v_ref[0, 0, span, :], do, _NT)            # [w, bq]
             dst = pt * (dpt - delta_ref[0, h, :, cols])
             dq_sc[h, :, cols] = dq_sc[h, :, cols] + _dot(
                 kt, dst.astype(kt.dtype), _NN)                   # [D, bq]
@@ -583,11 +697,13 @@ def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         _for_each_head(n_rep, head)
 
     for r in range(nq_sub):
-        _for_each_extent(
-            [kvmin_ref[b, j * n_sub + c] <= qmax_ref[b, i * nq_sub + r]
-             for c in range(n_sub)],
-            functools.partial(update, slice(r * blk_q, (r + 1) * blk_q)),
-            leading=True)
+        _for_each_live_kv(
+            window,
+            [_windowed(
+                kvmin_ref[b, j * n_sub + c] <= qmax_ref[b, i * nq_sub + r],
+                window, tables, b, lambda: i * nq_sub + r,
+                lambda: j * n_sub + c, blk_kv) for c in range(n_sub)],
+            functools.partial(update, slice(r * blk_q, (r + 1) * blk_q)))
 
     @pl.when(j == nj - 1)
     def _():
@@ -597,9 +713,10 @@ def _dq_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         _for_each_head(n_rep, head)
 
 
-def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
-                scale: float, use_kvpos: bool, nq_sub: int, n_sub: int,
-                use_sel: bool = False):
+def _dkv_kernel(*refs, scale: float, use_kvpos: bool, nq_sub: int,
+                n_sub: int, use_sel: bool = False, window=None):
+    tables, qpos_ref, rest = _split_tables(refs, window)
+    qmax_ref, _, kvmin_ref = tables[:3]
     kvpos_ref = sel_ref = None
     if use_kvpos:
         kvpos_ref, *rest = rest
@@ -619,13 +736,15 @@ def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
         dk_sc[:, :] = jnp.zeros_like(dk_sc)
         dv_sc[:, :] = jnp.zeros_like(dv_sc)
 
-    def update(rows, c):
-        cols = slice(c * blk_q, qmajor)     # from q tile c to the block's end
-        width = qmajor - cols.start
+    def update(rows, lo, hi):
+        # q tiles lo .. hi of the major block (hi is its last without a
+        # window: from q tile lo to the block's end)
+        cols = slice(lo * blk_q, (hi + 1) * blk_q)
+        width = cols.stop - cols.start
         mask = _TileMask(
             functools.partial(_seen, qpos_ref, kvpos_ref,
                               j * major + rows.start, rows, cols,
-                              (blk_kv, width), sel_ref),
+                              (blk_kv, width), sel_ref, window),
             bias_sc, _DEAD, (slice(None), slice(0, width)))
 
         def head(h):     # the group's heads add into ONE dk / dv, in float32
@@ -645,11 +764,17 @@ def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
     for r in range(n_sub):
         # q tile c of this major block holds a query that sees a key of
         # kv tile r
-        _for_each_extent(
-            [qmax_ref[b, i * nq_sub + c] >= kvmin_ref[b, j * n_sub + r]
-             for c in range(nq_sub)],
-            functools.partial(update, slice(r * blk_kv, (r + 1) * blk_kv)),
-            leading=False)
+        live = [_windowed(
+            qmax_ref[b, i * nq_sub + c] >= kvmin_ref[b, j * n_sub + r],
+            window, tables, b, lambda: i * nq_sub + c, lambda: j * n_sub + r,
+            blk_kv) for c in range(nq_sub)]
+        update_r = functools.partial(update,
+                                     slice(r * blk_kv, (r + 1) * blk_kv))
+        if window is None:
+            _for_each_extent(
+                live, lambda c: update_r(c, nq_sub - 1), leading=False)
+        else:
+            _for_each_run(live, update_r)
 
     @pl.when(i == ni - 1)
     def _():
@@ -658,39 +783,39 @@ def _dkv_kernel(qmax_ref, imin_ref, kvmin_ref, qpos_ref, *rest,
 
 
 def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
-             blk_q, blk_kv, clamp: bool, sel_t=None):
+             blk_q, blk_kv, clamp: bool, sel_t=None, window=None):
     B, H, Lq, D = qt.shape
     Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
-    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3)
+    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3, window)
     qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
-    fetch = _kv_fetch(p, clamp)
+    fetch = _kv_fetch(p, clamp, window)
     params, bias = _group_params(
         n_rep, qmajor * (2 * qt.dtype.itemsize * (2 * D + Dv) + 4 * (D + 32)),
         (major, p.bq))
 
-    def kv_map(b, g, i, j, qm, im, km):
-        return (b, g, fetch(qm, b, i, j), 0)
+    def kv_map(b, g, i, j, *t):
+        return (b, g, fetch(t, b, i, j), 0)
 
-    def kt_map(b, g, i, j, qm, im, km):
-        return (b, g, 0, fetch(qm, b, i, j))
+    def kt_map(b, g, i, j, *t):
+        return (b, g, 0, fetch(t, b, i, j))
 
-    def q_rows(b, g, i, j, qm, im, km):
+    def q_rows(b, g, i, j, *t):
         return (b, g, i, 0)
 
-    def q_lanes(b, g, i, j, qm, im, km):
+    def q_lanes(b, g, i, j, *t):
         return (b, g, 0, i)
 
     in_specs = (
         [pl.BlockSpec((1, 1, qmajor),
-                      lambda b, g, i, j, qm, im, km: (b, 0, i))]
+                      lambda b, g, i, j, *t: (b, 0, i))]
         + ([pl.BlockSpec((1, major, 1),
-                         lambda b, g, i, j, qm, im, km: (b, j, 0))]
+                         lambda b, g, i, j, *t: (b, j, 0))]
            if use_kvpos else [])
         + ([pl.BlockSpec((1, major, qmajor),
-                         lambda b, g, i, j, qm, im, km:
-                         (b, fetch(qm, b, i, j), i))]
+                         lambda b, g, i, j, *t:
+                         (b, fetch(t, b, i, j), i))]
            if sel_t is not None else [])
         + [pl.BlockSpec((1, n_rep, qmajor, D), q_rows),
            pl.BlockSpec((1, 1, major, D), kv_map),
@@ -709,12 +834,13 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
     # dq^T = k^T ds^T; dq leaves transposed like the forward's output
     operands += [qt, kt, kt.swapaxes(2, 3), vt, dout_t, lse, delta]
     dq_t = named_pallas_call(
-        "flash_bwd_dq" if sel_t is None else "sparse_bwd_dq",
+        _kernel_name("bwd_dq", sel_t, window),
         functools.partial(_dq_kernel, scale=scale, use_kvpos=use_kvpos,
                           nq_sub=p.nq_sub, n_sub=p.n_sub,
-                          use_sel=sel_t is not None),
+                          use_sel=sel_t is not None,
+                          **({} if window is None else {"window": window})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(p.tables),
             grid=(B, Hkv, p.nq, p.nkv),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, n_rep, D, qmajor), q_lanes),
@@ -729,45 +855,52 @@ def _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
 
 
 def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
-              blk_q, blk_kv, clamp: bool, sel_t=None):
+              blk_q, blk_kv, clamp: bool, sel_t=None, window=None):
     """dK [B, Hkv, Lk, D] and dV [B, Hkv, Lk, Dv] in the inputs' dtype:
     the query heads of a key head add into one float32 accumulator
     inside the kernel, which is rounded once."""
     B, H, Lq, D = qt.shape
     Hkv, Lk, Dv = kt.shape[1], kt.shape[2], vt.shape[3]
     n_rep = H // Hkv
-    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3)
+    p = _plan(Lq, Lk, blk_q, blk_kv, qpos3, kvpos3, window)
     qmajor, major = p.qmajor, p.major
     use_kvpos = kvpos3 is not None
     params, bias = _group_params(
         n_rep, qmajor * (2 * qt.dtype.itemsize * (D + Dv) + 128),
         (p.bkv, qmajor))
 
-    def first(im, b, j, i):
+    def first(t, b, j, i):
         # q major blocks before this kv block's causal frontier (its
         # first tile's first relevant q tile) re-fetch the first
-        # relevant one: monotone positions only
-        return jnp.maximum(i, im[b, j * p.n_sub] // p.nq_sub) if clamp else i
+        # relevant one: monotone positions only; under a window those
+        # past the last q tile that its last tile is not wholly behind
+        # re-fetch that one
+        if not clamp:
+            return i
+        i = jnp.maximum(i, t[1][b, j * p.n_sub] // p.nq_sub)
+        if window is None:
+            return i
+        return jnp.minimum(i, jnp.maximum(
+            t[4][b, j * p.n_sub + p.n_sub - 1] - 1, 0) // p.nq_sub)
 
-    def q_rows(b, g, j, i, qm, im, km):
-        return (b, g, first(im, b, j, i), 0)
+    def q_rows(b, g, j, i, *t):
+        return (b, g, first(t, b, j, i), 0)
 
-    def q_lanes(b, g, j, i, qm, im, km):
-        return (b, g, 0, first(im, b, j, i))
+    def q_lanes(b, g, j, i, *t):
+        return (b, g, 0, first(t, b, j, i))
 
-    def kv(b, g, j, i, qm, im, km):
+    def kv(b, g, j, i, *t):
         return (b, g, j, 0)
 
     in_specs = (
         [pl.BlockSpec((1, 1, qmajor),
-                      lambda b, g, j, i, qm, im, km:
-                      (b, 0, first(im, b, j, i)))]
+                      lambda b, g, j, i, *t: (b, 0, first(t, b, j, i)))]
         + ([pl.BlockSpec((1, major, 1),
-                         lambda b, g, j, i, qm, im, km: (b, j, 0))]
+                         lambda b, g, j, i, *t: (b, j, 0))]
            if use_kvpos else [])
         + ([pl.BlockSpec((1, major, qmajor),
-                         lambda b, g, j, i, qm, im, km:
-                         (b, j, first(im, b, j, i)))]
+                         lambda b, g, j, i, *t:
+                         (b, j, first(t, b, j, i)))]
            if sel_t is not None else [])
         + [pl.BlockSpec((1, n_rep, qmajor, D), q_rows),
            pl.BlockSpec((1, 1, major, D), kv),
@@ -783,12 +916,13 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
         operands.append(sel_t)
     operands += [qt, kt, vt, dout_t, lse, delta]
     return named_pallas_call(
-        "flash_bwd_dkv" if sel_t is None else "sparse_bwd_dkv",
+        _kernel_name("bwd_dkv", sel_t, window),
         functools.partial(_dkv_kernel, scale=scale, use_kvpos=use_kvpos,
                           nq_sub=p.nq_sub, n_sub=p.n_sub,
-                          use_sel=sel_t is not None),
+                          use_sel=sel_t is not None,
+                          **({} if window is None else {"window": window})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(p.tables),
             grid=(B, Hkv, p.nkv, p.nq),
             in_specs=in_specs,
             out_specs=[pl.BlockSpec((1, 1, major, D), kv),
@@ -808,14 +942,14 @@ def _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
 
 
 def _bwd_impl(qt, kt, vt, qpos3, kvpos3, scale, blk_q, blk_kv, out_t,
-              lse, dout_t, clamp: bool, sel_t=None):
+              lse, dout_t, clamp: bool, sel_t=None, window=None):
     # delta = rowsum(dO * O) — cheap elementwise, plain XLA.
     delta = jnp.sum(dout_t.astype(jnp.float32) * out_t.astype(jnp.float32),
                     axis=-1)[:, :, None, :]                   # [B, H, 1, Lq]
     dq = _dq_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta, scale,
-                  blk_q, blk_kv, clamp, sel_t)
+                  blk_q, blk_kv, clamp, sel_t, window)
     dk, dv = _dkv_call(qt, kt, vt, qpos3, kvpos3, dout_t, lse, delta,
-                       scale, blk_q, blk_kv, clamp, sel_t)
+                       scale, blk_q, blk_kv, clamp, sel_t, window)
     return dq, dk, dv
 
 
@@ -843,9 +977,9 @@ def _check_chunk_alignment(Lq: int, Lk: int, blk_q: int,
                 "of 128")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def flash_attention_gqa(q, k, v, q_positions, scale,
-                        blk_q: int = 512, blk_kv: int = 512):
+                        blk_q: int = 512, blk_kv: int = 512, window=None):
     # Default tiles from on-chip sweeps of 2026-09-28 (PR 29; bf16, one
     # v5e chip; PERF.md section 6) at the shapes the benchmark's cells
     # run — (16 | 48, 384, 8 heads of 256), (16 | 32, 1024, 32 heads, keys
@@ -865,15 +999,18 @@ def flash_attention_gqa(q, k, v, q_positions, scale,
     q_positions: [B, Lq] int32 absolute positions, monotonic per row —
     query at position p attends to KV slots j <= p (identical semantics
     to the reference attention mask built in models/transformer.py).
+    ``window`` (static): the query also needs ``p - j < window`` (a
+    sliding-window layer: itself and the ``window - 1`` keys before it);
+    tiles wholly behind it are neither fetched nor multiplied.
     Returns [B, Lq, H, Dv] in q.dtype.
     """
     out, _ = _fwd(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                   v.transpose(0, 2, 1, 3), q_positions[:, None, :],
-                  None, scale, blk_q, blk_kv, clamp=True)
+                  None, scale, blk_q, blk_kv, clamp=True, window=window)
     return out.transpose(0, 2, 1, 3)
 
 
-def _vjp_fwd(q, k, v, q_positions, scale, blk_q, blk_kv):
+def _vjp_fwd(q, k, v, q_positions, scale, blk_q, blk_kv, window):
     # The residuals carry the names a block's checkpoint may keep
     # (models/transformer.py, REMAT_TAGS): kept, the backward kernels
     # read them as they lie and the forward is not run a second time.
@@ -881,15 +1018,16 @@ def _vjp_fwd(q, k, v, q_positions, scale, blk_q, blk_kv):
                   for t in (q, k, v))
     qpos3 = q_positions[:, None, :]
     out_t, lse = (checkpoint_name(t, "attn_out") for t in _fwd(
-        qt, kt, vt, qpos3, None, scale, blk_q, blk_kv, clamp=True))
+        qt, kt, vt, qpos3, None, scale, blk_q, blk_kv, clamp=True,
+        window=window))
     return out_t.transpose(0, 2, 1, 3), (qt, kt, vt, qpos3, out_t, lse)
 
 
-def _vjp_bwd(scale, blk_q, blk_kv, residuals, dout):
+def _vjp_bwd(scale, blk_q, blk_kv, window, residuals, dout):
     qt, kt, vt, qpos3, out_t, lse = residuals
     dq, dk, dv = _bwd_impl(qt, kt, vt, qpos3, None, scale, blk_q,
                            blk_kv, out_t, lse, dout.transpose(0, 2, 1, 3),
-                           clamp=True)
+                           clamp=True, window=window)
     return (dq.transpose(0, 2, 1, 3),
             dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3),
             None)

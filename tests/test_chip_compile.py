@@ -1507,3 +1507,117 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
                 "moe_gmm_dlhs", "moe_tgmm", "moe_combine"} <= set(names)
         assert mem.argument_size_in_bytes == pytest.approx(6.18e9, rel=1e-2)
     assert mem.peak_memory_in_bytes + resident <= V5E_BYTES_LIMIT
+
+
+# -- the mellum model (sliding-window layers beside full ones) ---------------
+
+def _mellum_cell():
+    """(model configuration, B, P, T, minibatch) of
+    ``ppo-mellum2-ep8-sync``."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+
+    mc = dataclasses.replace(ModelConfig.mellum2_12b_a2_5b(), num_layers=8,
+                             experts_held=8, vocab_size=12288,
+                             max_seq_len=8192)
+    assert mc.layer_runs() == (
+        (0, 3, "window", "experts"), (3, 1, "attention", "experts"),
+        (4, 3, "window", "experts"), (7, 1, "attention", "experts"))
+    return mc, 8, 7168, 1024, 2
+
+
+@pytest.mark.parametrize("program", ["generate", "update"])
+def test_mellum_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
+    """The two largest programs of ``ppo-mellum2-ep8-sync`` (the published
+    layers 0-7 of Mellum2-12B-A2.5B, S S S F S S S F, 8 of 64 experts,
+    12 288 rows of the vocabulary; remat, each stretch scanned) at the
+    timed shapes, 8 prompts padded to 7168 and 1024 new tokens.
+    ``generate``: prefill (``flash_fwd_window`` on six layers over their
+    own keys, ``flash_fwd`` on two over the cache) and the decode loop,
+    the kernel ``dense_step`` on all eight layers, eight query heads a
+    key head of 128 (whole-lane heads: ``Hkv * D`` = 512 lanes), six over
+    rings laid ``bf16[8, 1024, 512]`` and two over caches ``bf16[8, 8192,
+    512]``: no ``conditional`` over prefixes and no copy of a cache or
+    ring inside the loop.  (The experience forward, one forward of all 8
+    rows, is the update's forward at four times the rows: peak 7.06 GB by
+    the same compile, PR 53; not compiled here, for the suite's clock.)
+    ``update``: the forward, remat's and the backward in minibatches of
+    2, the three windowed kernels beside the three full ones.  Each fits
+    beside what else the chip holds: 624 M parameters are 4.99 GB of
+    float32 master and bf16 moments, the bf16 reference 1.25 GB more."""
+    import re
+
+    from orion_tpu.config import RolloutConfig
+    from orion_tpu.rollout.engine import RolloutEngine
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc, B, P, T, rows = _mellum_cell()
+    shell, pshape, mb = _build_8b_shell(mc)
+    shell.cfg.rollout.max_prompt_len = P
+    shell.cfg.rollout.max_new_tokens = T
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), pshape)
+    ids = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+    resident = 1.25e9              # the bf16 reference
+    with jax.default_matmul_precision("default"):
+        if program == "generate":
+            eng = RolloutEngine(shell.model, mc, RolloutConfig(
+                max_prompt_len=P, max_new_tokens=T), eos_token_id=None,
+                pad_token_id=0)
+            rng = jax.eval_shape(lambda: jax.random.key(0))
+            lowered = eng._generate_jit.lower(
+                params, ids(B, P), ids(B), _sds(rng.shape, rng.dtype,
+                                                one_chip),
+                max_new_tokens=T)
+            resident += 2 * 1.25e9          # and the moments
+        else:
+            shapes = {k: (P + T,) if k == "sequences"
+                      else () if k == "prompt_lens" else (T,) for k in mb}
+            experience = {k: _sds((B,) + shapes[k], v.dtype, one_chip)
+                          for k, v in mb.items()}
+            state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                                 _abstract_state(shell, pshape))
+            lowered = jax.jit(
+                lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+                donate_argnums=(0,)).lower(
+                    state, experience,
+                    _sds((B // rows, rows), jnp.int32, one_chip))
+        scopes = lowered.as_text(debug_info=True)
+        compiled = lowered.compile()
+    names = _kernel_names(compiled)
+    mem = compiled.memory_analysis()
+    print(program, {k: getattr(mem, k) for k in _MEMORY},
+          sorted(set(names)))
+    assert "attn.window" in scopes and "attn.full" in scopes
+    if program == "generate":
+        # the prefill: two stretches of each kind, unrolled in the twin
+        assert names.count("flash_fwd_window") == 6
+        assert names.count("flash_fwd") == 2
+        assert not {"flash_bwd_dq", "flash_dq_window", "paged_decode"} \
+            & set(names)
+        text = compiled.as_text()
+        assert names.count("dense_step") == 8
+        (decode,) = [name for name, body in _while_bodies(text).items()
+                     if "%dense_step" in body]
+        comps = _computations(text)
+        cache = r"bf16\[8,(?:1024|8192),(?:4,128|512)\]"
+        for name in _called(comps, decode):
+            assert " conditional(" not in comps[name]
+            assert not re.search(r"= %s\S* copy(-start)?\(" % cache,
+                                 comps[name]), name
+        # the loop carries six rings and two caches, K and V each, laid
+        # for the kernel: nothing padded
+        ring = "bf16[8,1024,512]{2,1,0:T(8,128)(2,1)}"
+        full = "bf16[8,8192,512]{2,1,0:T(8,128)(2,1)}"
+        assert comps[decode].count("= %s get-tuple-element(" % ring) == 12
+        assert comps[decode].count("= %s get-tuple-element(" % full) == 4
+        assert mem.argument_size_in_bytes == pytest.approx(2.50e9, rel=1e-2)
+    else:
+        assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                "flash_fwd_window", "flash_dq_window", "flash_dkv_window",
+                "moe_gmm", "moe_gmm_dlhs", "moe_tgmm", "moe_combine"} \
+            <= set(names)
+        assert mem.argument_size_in_bytes == pytest.approx(4.99e9, rel=2e-2)
+    assert mem.peak_memory_in_bytes + resident <= V5E_BYTES_LIMIT

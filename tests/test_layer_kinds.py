@@ -25,12 +25,12 @@ from orion_tpu.models.transformer import MIXERS, Transformer, mixer_spec
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 ARCHS = ("llama", "neox", "deepseek_v3", "kimi_linear", "olmo_hybrid",
-         "keye_dsa", "nemotron_h", "sdar_moe", "lfm2_moe")
+         "keye_dsa", "nemotron_h", "sdar_moe", "lfm2_moe", "mellum")
 #: a tiny arch whose layers have the mixer
 MIXER_ARCH = {"attention": "llama", "sparse": "keye_dsa",
               "latent": "deepseek_v3", "kda": "kimi_linear",
               "gdn": "olmo_hybrid", "mamba2": "nemotron_h",
-              "conv": "lfm2_moe"}
+              "conv": "lfm2_moe", "window": "mellum"}
 
 
 def _shapes(tree) -> dict:
@@ -75,8 +75,10 @@ def test_a_mixer_returns_the_cache_entry_it_states(mixer):
     assert _shapes(entry) == _shapes(prefill) == _shapes(step)
     assert set(kind.index_leaves) <= set(entry)
     assert kind.cache_kind in ("cache", "state")
-    # a state has no axis of slots, a cache has one in every leaf
-    assert all((slots in x.shape) == (kind.cache_kind == "cache")
+    # a state has no axis of slots, a cache has one in every leaf (a
+    # windowed layer's ring its own fewer)
+    held = getattr(kind, "ring_slots", lambda cfg, n: n)(cfg, slots)
+    assert all((held in x.shape) == (kind.cache_kind == "cache")
                for x in jax.tree.leaves(entry))
 
 
