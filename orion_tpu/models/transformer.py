@@ -2203,6 +2203,14 @@ class Transformer(nn.Module):
 # ---------------------------------------------------------------------------
 
 
+def sown(intermediates, name: str) -> list:
+    """The arrays sown under ``name`` anywhere in an intermediates
+    tree (stacked over layers under scan_layers)."""
+    return [x for path, x in
+            jax.tree_util.tree_flatten_with_path(intermediates)[0]
+            if any(getattr(k, "key", None) == name for k in path)]
+
+
 def cache_slots(max_len: int) -> int:
     """The slots :func:`init_cache` allocates for ``max_len`` positions.
     Round the length up to a multiple of 8: Mosaic tiles the cache

@@ -155,6 +155,50 @@ class MoEMLP(nn.Module, Kind):
 # crossing between the two was not looked for.
 DENSE_MAX_TOKENS = 256
 
+# Such a small step reads only the stacks of the held experts that some
+# row selected (the kernel ops/pallas/experts_step.py) where the share
+# of them it expects to need, 1 - (1 - k / E) ** tokens for top k of E
+# experts, is under this.  Read on a v5e (PERF.md section 6, PR 54): the
+# kernel moves what it reads at the einsum form's rate or better up to
+# 64 rows (16 stacks of 9.4 MB, all hit, 32 rows: 206.8 us against
+# 218.0; 8 of 22 MB, all hit, 64 rows: 240.1 against 259.9), so what it
+# skips is gained: ppo-keye-dsa-ep8-sync (0.40) +5.0% samples/s,
+# ppo-mellum2-ep8-sync (0.66) +3.1%, ppo-kimi-linear-ep32-sync (0.64)
+# +1.6%, and the two cells nearest the bound still win,
+# ppo-nemotron-h-tp4-sync (0.75) +7.5% and ppo-kanana-ep8-sync (0.78)
+# +2.9%.  No crossing was found under 0.78; over 0.9 a step has a tenth
+# of its stacks at most to skip, and the forwards that stand there
+# (LFM2's 64 rows of top-4 of 32, SDAR's 128 and 256 tokens: 0.9998)
+# were not run on the kernel end to end; at 128 rows with every stack
+# hit the einsum form took 145.4 us where the kernel took 208.5.
+STEP_MAX_READ_SHARE = 0.9
+
+
+def step_read_share(n_tokens: int, k: int, n_experts: int) -> float:
+    """The share of a chip's held stacks that a step of ``n_tokens``
+    rows needs if each row selects ``k`` of ``n_experts`` at random: a
+    held expert is selected by none with ``(1 - k / E) ** n_tokens``."""
+    return 1.0 - (1.0 - k / n_experts) ** n_tokens
+
+
+def step_form(n_tokens: int, k: int, n_experts: int, width: int) -> str:
+    """The form a step of ``n_tokens`` tokens takes through the held
+    experts of ``width``, from what the step can see: ``kernel``
+    (``experts_step``: the hit experts' stacks alone) where it is small
+    (:data:`DENSE_MAX_TOKENS`), expects to need less than
+    :data:`STEP_MAX_READ_SHARE` of the stacks, the width is whole lanes
+    (the kernel's tiles are) and the trace is for one TPU device; ``""``
+    elsewhere (the CPU, a mesh of several devices, where GSPMD
+    partitions the einsums, a step whose rows select about every held
+    expert between them, a tiny model): ``experts_dense``, or the
+    grouped form where :func:`block_rows` says so."""
+    from orion_tpu.ops.indexer import select_form
+
+    return "kernel" if (
+        n_tokens <= DENSE_MAX_TOKENS and width % 128 == 0
+        and step_read_share(n_tokens, k, n_experts) < STEP_MAX_READ_SHARE
+        and select_form() == "kernel") else ""
+
 
 def sigmoid_topk_route(z, router_kernel, bias, k: int, scale: float):
     """The published router.  z [T, D], router_kernel [D, E], bias [E]
@@ -217,15 +261,21 @@ def shared_width(cfg: ModelConfig) -> int:
                                    or cfg.moe_intermediate_size)
 
 
+def dense_weight(local, gates, n_held: int):
+    """[T, H] float32: each token's gate for each held expert, 0 where
+    it did not select it (a token that holds an expert more than once
+    gets the sum)."""
+    return jnp.sum(jax.nn.one_hot(local, n_held, dtype=jnp.float32)
+                   * gates[..., None], axis=1)
+
+
 def experts_dense(x, w_up, w_down, local, gates, act: str = "swiglu"):
     """Every held expert on every token, weighted.  x [T, D];
     w_up [H, D, F] (F: ``ACTIVATIONS[act]``); w_down [H, I, D]; local
     [T, k] (expert index among the held ones, anything outside 0..H-1 =
     not held); gates [T, k].  Exact for any routing; its work does not
     follow it."""
-    H = w_up.shape[0]
-    weight = jnp.sum(jax.nn.one_hot(local, H, dtype=jnp.float32)
-                     * gates[..., None], axis=1)               # [T, H]
+    weight = dense_weight(local, gates, w_up.shape[0])
     with jax.named_scope("moe.experts"):
         h = ACTIVATIONS[act][0](jnp.einsum("td,hdf->thf", x, w_up))
         y = jnp.einsum("thf,hfd->thd", h, w_down)
@@ -485,13 +535,17 @@ class TopKMoE(nn.Module, Kind):
     (:func:`combine_work`), for the trainer's counters, and ``moe_selected``
     [B, L, k], the experts each token selected (the reference check
     reads it: a selection is discrete, see
-    benchmarks/reference_check_dsv3.py).
+    benchmarks/reference_check_dsv3.py), and where a small step takes
+    the kernel (:func:`step_form`) ``moe_step_read``, the held experts
+    whose stacks it read (the rollout's counter).
 
     Expert weights are stacked on the ``expert`` logical axis.  On one
     device the large-batch path is the grouped product (Pallas) over
     blocks of the held pairs (:func:`block_rows`: a share that holds an
     eighth of the experts moves a quarter of the pair rows, twice its
-    even share, and more only when the routing sends it more); under a
+    even share, and more only when the routing sends it more), and a
+    small step whose rows leave held experts unselected reads the
+    selected ones' stacks alone (:func:`step_form`); under a
     mesh of several devices a Mosaic kernel cannot be partitioned
     automatically, so the layer takes its dense form there, which GSPMD
     partitions over the ``expert`` axis like the GShard layer's einsums.
@@ -592,8 +646,15 @@ class TopKMoE(nn.Module, Kind):
                      combine_work(local, H, Dl, block))
         operands = (z_in.astype(cdt), w_up.astype(cdt), w_down.astype(cdt),
                     local, gates)
-        routed = experts_grouped(*operands, block, act) if block \
-            else experts_dense(*operands, act)
+        if block:
+            routed = experts_grouped(*operands, block, act)
+        elif step_form(B * L, k, E, I):
+            from orion_tpu.ops.pallas.experts_step import experts_step, hits
+
+            self.sow("intermediates", "moe_step_read", hits(local, H)[1][0])
+            routed = experts_step(*operands, act)
+        else:
+            routed = experts_dense(*operands, act)
         if cfg.moe_latent_size:
             with jax.named_scope("moe_latent"):
                 routed = _dense(Dm, ("latent", "embed"), False, cfg,

@@ -55,12 +55,15 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from orion_tpu.config import ModelConfig, RolloutConfig
 from orion_tpu.models.transformer import (MIXERS, cache_entry, cache_slots,
                                          cannot_run, decode_attrs, init_cache,
-                                         make_decode_twin, prep_decode_params)
+                                         make_decode_twin, prep_decode_params,
+                                         sown)
 from orion_tpu.ops.logprobs import pack_sequences
+from orion_tpu.ops.moe import step_form
 from orion_tpu.ops.sampling import sample_tokens
 from orion_tpu.resilience import fault_point
 
@@ -81,6 +84,12 @@ class GenerationResult:
     # denoising step of its block at which each completion position was
     # revealed (``denoising_steps`` where it never was); None otherwise
     reveal_step: Optional[jnp.ndarray] = None
+    # a model with the dropless expert layer: [2] int, the held experts'
+    # stacks the steps read and held, over expert layers and steps run
+    # (counted where a step reads only the stacks its rows selected,
+    # ops.moe.step_form; equal where every step reads them all); None
+    # for any other model, and from an engine that does not count
+    expert_stacks: Optional[jnp.ndarray] = None
 
     def _fields(self) -> dict:
         return {f.name: getattr(self, f.name)
@@ -125,6 +134,8 @@ class RolloutEngine:
         self._weight_bytes: Optional[int] = None
         self._decode_model, self._decode_cfg = make_decode_twin(
             model, model_cfg)
+        self._expert_layers = sum(
+            ffn == "experts" for _, ffn in model_cfg.layer_kinds())
         for form in ("paged", "quantize_kv", "quantize_weights"):
             why = getattr(cfg, form) and cannot_run(model_cfg, form)
             if why:
@@ -220,8 +231,12 @@ class RolloutEngine:
         if params is None:
             raise ValueError("no weights loaded: call load_weights() first")
         T = int(max_new_tokens or self.cfg.max_new_tokens)
-        return GenerationResult(**self._generate_jit(
-            params, prompt_ids, prompt_lens, rng, max_new_tokens=T))
+        out = self._generate_jit(params, prompt_ids, prompt_lens, rng,
+                                 max_new_tokens=T)
+        if self._expert_layers:
+            # the einsum form counts nothing: every step read every stack
+            out.setdefault("expert_stacks", np.ones((2,), np.int32))
+        return GenerationResult(**out)
 
     def _generate(self, params, prompt_ids, prompt_lens, rng,
                   max_new_tokens: int):
@@ -308,16 +323,27 @@ class RolloutEngine:
         done = is_stop_token(tok0, eos, cfg.stop_token_ids)
         comp_len = jnp.ones((B,), jnp.int32)
 
+        # what a step's expert layers count of themselves rides the
+        # loop; a model that counts nothing runs the step as it was
+        mc = self.model_cfg
+        counted = bool(self._expert_layers and step_form(
+            B, mc.num_experts_per_tok, mc.n_routed_experts,
+            mc.moe_intermediate_size))
+
         def cond(c):
             t, _, _, _, done, _, _, _, _ = c
             return (t < T) & ~jnp.all(done)
 
         def body(c):
             t, cur_tok, cur_pos, rng, done, tokens, logps, plogps, state = c
-            cache, comp_len, seen = state
-            step_logits, cache = self._decode_model.apply(
-                {"params": params}, cur_tok[:, None], cur_pos[:, None],
-                cache)
+            cache, comp_len, seen, *read = state
+            step = partial(self._decode_model.apply, {"params": params},
+                           cur_tok[:, None], cur_pos[:, None], cache)
+            if counted:
+                (step_logits, cache), sowed = step(mutable=["intermediates"])
+                read = [read[0] + sum(sown(sowed, "moe_step_read"))]
+            else:
+                step_logits, cache = step()
             rng, sub = jax.random.split(rng)
             nxt, lp, plp = sample(sub, step_logits[:, 0],
                                   **ctrl_kwargs(seen, t))
@@ -333,18 +359,22 @@ class RolloutEngine:
             comp_len = comp_len + (~done).astype(jnp.int32)
             done = done | is_stop_token(nxt, eos, cfg.stop_token_ids)
             return (t + 1, nxt, cur_pos + 1, rng, done, tokens, logps,
-                    plogps, (cache, comp_len, seen))
+                    plogps, (cache, comp_len, seen, *read))
 
         init = (jnp.int32(1), tok0, prompt_lens, rng, done, tokens, logps,
-                plogps, (cache, comp_len, seen))
+                plogps, (cache, comp_len, seen, *[jnp.int32(0)] * counted))
         with jax.named_scope("decode"):
-            _, _, _, _, done, tokens, logps, plogps, \
-                (cache, comp_len, seen) = \
+            t, _, _, _, done, tokens, logps, plogps, \
+                (cache, comp_len, seen, *read) = \
                 jax.lax.while_loop(cond, body, init)
 
         mask = (jnp.arange(T)[None, :] < comp_len[:, None]).astype(jnp.float32)
         sequences = pack_sequences(prompt_ids, prompt_lens, tokens)
+        stacks = {"expert_stacks": jnp.stack(
+            [read[0], (t - 1) * self._expert_layers * mc.experts_held])} \
+            if counted else {}
         return dict(
+            **stacks,
             sequences=sequences,
             completions=tokens,
             completion_mask=mask,

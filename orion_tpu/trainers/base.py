@@ -27,7 +27,7 @@ import optax
 
 from orion_tpu.config import OptimizerConfig, TrainConfig
 from orion_tpu.models.transformer import (Transformer, kinds, remat_keep,
-                                         remat_tag_bytes, stream_attrs,
+                                         remat_tag_bytes, sown, stream_attrs,
                                          trace_inputs, trace_row_length,
                                          update_attrs)
 from orion_tpu.ops.logprobs import completion_logprobs, entropy_from_logits
@@ -205,14 +205,6 @@ def state_out_shardings(state: "TrainState"):
         else None, state)
 
 
-def _sown(intermediates, name: str) -> list:
-    """The arrays sown under ``name`` anywhere in an intermediates
-    tree (stacked over layers under scan_layers)."""
-    return [x for path, x in
-            jax.tree_util.tree_flatten_with_path(intermediates)[0]
-            if any(getattr(k, "key", None) == name for k in path)]
-
-
 def moe_load_stats(loads: list, pairs_per_layer: int,
                    block_rows: int, combines: list = ()) -> dict:
     """Counters of the dropless expert layer for one forward, from the
@@ -248,6 +240,18 @@ def moe_load_stats(loads: list, pairs_per_layer: int,
         "moe_combine_fill": (jnp.sum(placed) / jnp.maximum(
             jnp.sum(items) * chunk_rows(block_rows), 1)).astype(jnp.float32),
     }
+
+
+def step_read_pct(expert_stacks) -> dict:
+    """``moe_step_read_pct``: of the held experts' stacks, over expert
+    layers and the rollout's one-token steps, the share the steps read
+    (``GenerationResult.expert_stacks``: 100 where the expert layer
+    reads every stack at every step; {} where the rollout counted
+    none)."""
+    if expert_stacks is None:
+        return {}
+    read, held = (float(v) for v in expert_stacks)
+    return {"moe_step_read_pct": 100.0 * read / max(held, 1.0)}
 
 
 def _read_at(extra, read_at):
@@ -474,7 +478,7 @@ class BaseTrainer:
             # Only the router's 'moe_aux_loss' sows feed the loss — any
             # other sown diagnostic (activation stats, attention probes)
             # must NOT silently shift the training objective (ADVICE r2).
-            leaves = _sown(inter, "moe_aux_loss")
+            leaves = sown(inter, "moe_aux_loss")
             if not leaves:
                 raise ValueError(
                     "num_experts > 0 but no 'moe_aux_loss' intermediates "
@@ -488,10 +492,10 @@ class BaseTrainer:
                 mutable=["intermediates"], **apply_kw)
             from orion_tpu.ops.moe import block_rows
 
-            moe = moe_load_stats(_sown(inter, "moe_load"),
+            moe = moe_load_stats(sown(inter, "moe_load"),
                                  sequences.size * mc.num_experts_per_tok,
                                  block_rows(mc, sequences.size),
-                                 _sown(inter, "moe_combine"))
+                                 sown(inter, "moe_combine"))
             aux = jnp.zeros((), jnp.float32)
         else:
             out = self.model.apply({"params": params}, sequences,
@@ -782,7 +786,9 @@ class BaseTrainer:
                 streams["completion_tokens"] = int(
                     np.sum(host.completion_lens))
             sp.set(**streams)
-            return self.build_experience(result, scores, host=host)
+            experience, stats = self.build_experience(result, scores,
+                                                      host=host)
+        return experience, {**stats, **step_read_pct(host.expert_stacks)}
 
     def _fetch(self, tree: dict):
         """``jax.device_get(tree)`` of ``{"r": the rollout's result,
@@ -816,7 +822,8 @@ class BaseTrainer:
                 fetched = jax.device_get(tree)
                 nbytes = sum(int(getattr(x, "nbytes", 0))
                              for x in jax.tree.leaves(fetched))
-            sp.set(bytes=nbytes)
+            sp.set(bytes=nbytes,
+                   **step_read_pct(fetched["r"].get("expert_stacks")))
         self._fetch_s = (sp_wait.duration, sp_copy.duration)
         return fetched
 

@@ -635,6 +635,46 @@ def test_flash_with_a_narrower_value_compiles_for_v5e(one_chip, on_tpu):
                                        "flash_fwd"]
 
 
+# cell -> (rows a decode step, hidden or latent, expert width, held,
+# top-k, activation): the small step's expert layer where the rule takes
+# the kernel (ops/moe.py::step_form; tests/test_experts_step.py has the
+# rule's table)
+STEP_SHAPES = {
+    "keye-16of128-top8-8rows": (8, 2048, 768, 16, 8, "swiglu"),
+    "mellum2-8of64-top8-8rows": (8, 2304, 896, 8, 8, "swiglu"),
+    "kimi-8of256-top8-32rows": (32, 2304, 1024, 8, 8, "swiglu"),
+    "kanana-16of128-top6-32rows": (32, 2048, 768, 16, 6, "swiglu"),
+    "nemotron-8of512-top22-32rows": (32, 1024, 2688, 8, 22, "relu2"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(STEP_SHAPES))
+def test_experts_step_compiles_for_v5e(name, one_chip, on_tpu):
+    """The kernel alone at each cell's decode shapes: one Mosaic call
+    under its name, tiles of whole lanes, inside the VMEM it asks for."""
+    from orion_tpu.ops.moe import ACTIVATIONS
+    from orion_tpu.ops.pallas import experts_step as es
+
+    T, D, I, H, k, act = STEP_SHAPES[name]
+    assert es.width_tile(I) % 128 == 0 and I % es.width_tile(I) == 0
+    compiled = jax.jit(lambda *a: es.experts_step(*a, act)).lower(
+        _sds((T, D), BF16, one_chip),
+        _sds((H, D, ACTIVATIONS[act][1] * I), BF16, one_chip),
+        _sds((H, I, D), BF16, one_chip), _sds((T, k), jnp.int32, one_chip),
+        _sds((T, k), jnp.float32, one_chip)).compile()
+    assert _kernel_names(compiled) == ["experts_step"]
+
+
+def _assert_step_experts(compiled, form: str, layers: int, product: str):
+    """The decode loop's expert layers: under the kernel form one
+    ``experts_step`` a layer and the einsum form's first product
+    (``product``: ``bf16[T, H, F]``) nowhere; under the einsum form that
+    product, and no such kernel."""
+    names, text = _kernel_names(compiled), compiled.as_text()
+    assert names.count("experts_step") == (layers if form == "kernel" else 0)
+    assert (product in text) == (form != "kernel")
+
+
 # (T, hidden, expert width, held, top-k, experts, rows of a block, the
 # same function's ``temp_size_in_bytes`` at PR 34, where every pair had
 # a row): the update's minibatch of the two expert cells
@@ -1193,6 +1233,9 @@ def test_selected_step_reads_the_cache_in_place(new_tokens, form, one_chip,
             max_new_tokens=new_tokens).compile()
     text = compiled.as_text()
     assert "bf16[16384,4,128]" not in text
+    # 8 rows of top-8 of 128 expect to need 0.40 of the 16 held stacks:
+    # the expert layers' step reads the hit ones alone, whatever the cache
+    _assert_step_experts(compiled, "kernel", 2, "bf16[8,16,1536]")
     if form == "masked":
         assert "sparse_step" not in _kernel_names(compiled)
         return
@@ -1350,6 +1393,9 @@ def test_sdar_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
         assert "flash_fwd" in names                      # the prefill's
         assert not {"flash_bwd_dq", "paged_decode"} & set(names)
         assert mem.argument_size_in_bytes == pytest.approx(2.58e9, rel=1e-2)
+        # forwards of 256 and 128 tokens select every held expert: the
+        # einsum form, as before PR 54
+        _assert_step_experts(compiled, "", 6, "bf16[128,16,1536]")
     elif program == "experience":
         assert names.count("flash_fwd") == 2
         assert {"moe_gmm", "moe_combine"} <= set(names)
@@ -1496,6 +1542,9 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
                 assert made and made.group(2) == "fusion", (name, made)
                 assert made.group(1) == laid
         assert "bf16[64,10240,64]" not in text
+        # 64 rows of top-4 of 32 select every held expert (0.9998): the
+        # einsum form, as before PR 54
+        _assert_step_experts(compiled, "", 6, "bf16[64,8,3584]")
         assert mem.temp_size_in_bytes == pytest.approx(3.089e9, rel=1e-2)
         assert mem.peak_memory_in_bytes == pytest.approx(5.967e9, rel=1e-2)
     elif program == "experience":
@@ -1613,6 +1662,9 @@ def test_mellum_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
         full = "bf16[8,8192,512]{2,1,0:T(8,128)(2,1)}"
         assert comps[decode].count("= %s get-tuple-element(" % ring) == 12
         assert comps[decode].count("= %s get-tuple-element(" % full) == 4
+        # 8 rows of top-8 of 64 expect to need 0.66 of the 8 held stacks
+        _assert_step_experts(compiled, "kernel", 8, "bf16[8,8,1792]")
+        assert comps[decode].count("%experts_step") >= 8
         assert mem.argument_size_in_bytes == pytest.approx(2.50e9, rel=1e-2)
     else:
         assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
