@@ -23,7 +23,7 @@ LATENT_ARCHS = ("deepseek_v3", "kimi_linear")
 #: The archs whose model is a per-layer pattern of (mixer, FFN) kinds
 #: over RMSNorm blocks (``ModelConfig.layer_kinds``).
 PATTERN_ARCHS = LATENT_ARCHS + ("olmo_hybrid", "keye_dsa", "nemotron_h",
-                                "sdar_moe", "lfm2_moe", "mellum")
+                                "sdar_moe", "lfm2_moe", "mellum", "ouro")
 #: The archs whose layers end in the dropless expert layer
 #: (``ops.moe.TopKMoE``) and so share its fields and their checks.
 EXPERT_ARCHS = LATENT_ARCHS + ("keye_dsa", "nemotron_h", "sdar_moe",
@@ -36,13 +36,14 @@ PATTERN_HALVES = {"M": ("mamba2", None), "*": ("attention", None),
 LAYER_TYPE_MIXERS = {
     "olmo_hybrid": {"linear_attention": "gdn", "full_attention": "attention"},
     "lfm2_moe": {"conv": "conv", "full_attention": "attention"},
-    "mellum": {"sliding_attention": "window", "full_attention": "attention"}}
+    "mellum": {"sliding_attention": "window", "full_attention": "attention"},
+    "ouro": {"full_attention": "attention"}}
 
 
 @dataclass
 class ModelConfig:
     """Architecture hyperparameters for the decoder-only transformer:
-    flat fields under the published key names of ten model families,
+    flat fields under the published key names of eleven model families,
     a ``_check_<arch>`` each, and the model's description derived from
     them, :meth:`layer_kinds`: one (mixer, feed-forward) pair per block.
     What follows from a kind is ``models/transformer.py``'s
@@ -51,7 +52,7 @@ class ModelConfig:
 
     # the family whose published keys and checks apply: "llama" | "neox"
     # | "deepseek_v3" | "kimi_linear" | "olmo_hybrid" | "keye_dsa"
-    # | "nemotron_h" | "sdar_moe" | "lfm2_moe" | "mellum"
+    # | "nemotron_h" | "sdar_moe" | "lfm2_moe" | "mellum" | "ouro"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 512
@@ -249,6 +250,22 @@ class ModelConfig:
     # rotates by rope_theta.
     sliding_window: int = 0
     rope_parameters: dict = field(default_factory=dict)
+    # arch="ouro" (ByteDance's Ouro, a looped language model): ONE stack
+    # of num_layers blocks run total_ut_steps times over with the same
+    # parameters (models/transformer.py Transformer: pass t's input is
+    # pass t - 1's output under the one final RMSNorm, which is applied
+    # after every pass), each block in the sandwich order, four RMSNorms
+    # around multi-head attention (layer_types all "full_attention", full
+    # rotary at the token's position in every pass, no q/k norm, no bias)
+    # and a dense SwiGLU; a key-value cache entry for every (pass, layer):
+    # pass t of a token reads pass t's keys of the earlier tokens.  After
+    # every pass a gate lambda_t = sigmoid(H_t w_g + b_g) gives the exit
+    # masses p_t = lambda_t prod_{j<t} (1 - lambda_j), the last pass
+    # taking the rest; the hidden state used is that of the first pass at
+    # which the cumulative mass reaches early_exit_threshold: at the
+    # published 1 the last pass's, for every token (no other value runs).
+    total_ut_steps: int = 1
+    early_exit_threshold: float = 1.0  # orion: ignore[config-drift] a published key that _check_ouro holds to 1: every token runs every pass, so no program reads it
 
     def __post_init__(self) -> None:
         if self.arch in EXPERT_ARCHS:
@@ -276,6 +293,13 @@ class ModelConfig:
                 f"model.sliding_window={self.sliding_window} / "
                 "model.rope_parameters: only arch='mellum' has windowed "
                 "layers and rotary parameters by layer type")
+        if self.arch == "ouro":
+            self._check_ouro()
+        elif self.total_ut_steps != 1 or self.early_exit_threshold != 1.0:
+            raise ValueError(
+                f"model.total_ut_steps={self.total_ut_steps} / "
+                f"model.early_exit_threshold={self.early_exit_threshold}: "
+                "only arch='ouro' runs its stack several times over")
         self.head_share = tuple(self.head_share)
         if self.arch == "nemotron_h":
             self._check_nemotron_h()
@@ -491,6 +515,31 @@ class ModelConfig:
                 "(moe_scoring), without a shared expert, and a window is "
                 "not cut across sequence shards (seq_shard_activations)")
 
+    def _check_ouro(self) -> None:
+        self._check_layer_types()
+        if self.total_ut_steps < 1:
+            raise ValueError("arch='ouro' needs model.total_ut_steps >= 1 "
+                             "(the passes over the stack)")
+        if self.early_exit_threshold != 1.0:
+            raise ValueError(
+                f"model.early_exit_threshold={self.early_exit_threshold}: "
+                "only the published 1 runs, at which the cumulative exit "
+                "mass reaches the threshold at the last pass and every "
+                "token runs total_ut_steps passes; a rollout that ends a "
+                "token's passes early (and fills the cache entries of the "
+                "passes it skipped) is another generation rule and is not "
+                "written")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError("arch='ouro': num_kv_heads divides num_heads")
+        if (self.num_experts or self.quantize_dense
+                or self.seq_shard_activations or self.tie_word_embeddings):
+            raise ValueError(
+                "arch='ouro' is dense (num_experts is the GShard layer's) "
+                "with an untied head; its int8 Dense twin "
+                "(rollout.quantize_weights) and sequence-sharded "
+                "activations (seq_shard_activations) were not run against "
+                "the reference over several passes")
+
     @property
     def latent_attention(self) -> bool:
         """The attention is latent (deepseek_v3, kimi_linear)."""
@@ -511,6 +560,19 @@ class ModelConfig:
         """A block norms each half's OUTPUT and nothing before it (the
         OLMo 2 / 3 order)."""
         return self.arch == "olmo_hybrid"
+
+    @property
+    def sandwich_norm(self) -> bool:
+        """A block norms each half's input AND its output (Ouro's order:
+        four norms a block)."""
+        return self.arch == "ouro"
+
+    def layer_visits(self) -> int:
+        """The blocks a token passes: every layer ``total_ut_steps``
+        times.  What counts "over all layers" by the work done (cache
+        entries, kept residuals, a step's reads of the weights) counts
+        these."""
+        return self.total_ut_steps * len(self.layer_kinds())
 
     @property
     def mask_id(self) -> int:
@@ -600,6 +662,8 @@ class ModelConfig:
         if self.arch == "mellum":
             return tuple((LAYER_TYPE_MIXERS[self.arch][t], "experts")
                          for t in self.layer_types[:self.num_layers])
+        if self.arch == "ouro":
+            return (("attention", "dense"),) * self.num_layers
         if self.arch == "keye_dsa":
             return (("sparse", "experts"),) * self.num_layers
         if self.arch == "sdar_moe":
@@ -801,6 +865,25 @@ class ModelConfig:
         )
 
     @staticmethod
+    def ouro_2_6b() -> "ModelConfig":
+        """ByteDance/Ouro-2.6B as published (config.json, model_type
+        ouro): 48 layers run total_ut_steps = 4 times over."""
+        return ModelConfig(
+            arch="ouro", vocab_size=49152, hidden_size=2048,
+            intermediate_size=5632, num_layers=48, num_heads=16,
+            num_kv_heads=16, head_dim=128, max_seq_len=65536,
+            rope_theta=1000000.0, rms_norm_eps=1e-6,
+            layer_types=("full_attention",) * 48,
+            total_ut_steps=4, early_exit_threshold=1.0,
+        )
+
+    @staticmethod
+    def tiny_ouro() -> "ModelConfig":
+        """``model_preset=tiny_ouro``: the small sibling of ouro_2_6b
+        (tests, CPU rehearsals)."""
+        return ModelConfig.tiny("ouro")
+
+    @staticmethod
     def tiny_mellum() -> "ModelConfig":
         """``model_preset=tiny_mellum``: the small sibling of
         mellum2_12b_a2_5b (tests, CPU rehearsals)."""
@@ -906,6 +989,18 @@ class ModelConfig:
                 conv_L_cache=3, n_routed_experts=8, num_experts_per_tok=2,
                 moe_intermediate_size=32, first_k_dense_replace=2,
                 routed_scaling_factor=1.0, moe_scoring="sigmoid",
+            )
+            base.update(kw)
+            return ModelConfig(**base)
+        if arch == "ouro":
+            # three blocks run four times over; one query head a key head
+            base = dict(
+                arch=arch, vocab_size=256, hidden_size=64,
+                intermediate_size=96, num_layers=3, num_heads=4,
+                num_kv_heads=4, head_dim=16, max_seq_len=128,
+                rope_theta=1e6, rms_norm_eps=1e-6,
+                layer_types=("full_attention",) * 3,
+                total_ut_steps=4, early_exit_threshold=1.0,
             )
             base.update(kw)
             return ModelConfig(**base)

@@ -64,6 +64,11 @@ def hf_state_dict(params: dict, cfg: ModelConfig) -> Dict[str, np.ndarray]:
             "layer's convolution [channels, 1, taps], A_log, D, dt_bias and "
             "gated norm; per-expert up / down tensors and the latent "
             "projections; a share of the heads is part of a checkpoint)")
+    if cfg.arch == "ouro":
+        raise ValueError(
+            "HF export of arch='ouro' is not written: there is no ouro "
+            "checkpoint layout on either side yet (four norms a block, the "
+            "exit gate; the checkpoint's files are not in this repository)")
     if cfg.arch == "mellum":
         raise ValueError(
             "HF export of arch='mellum' is not written: there is no mellum "

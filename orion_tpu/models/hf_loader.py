@@ -100,6 +100,16 @@ _NO_MELLUM_LOADER = (
     "arch='mellum' runs from random weights only")
 
 
+_NO_OURO_LOADER = (
+    "there is no ouro checkpoint loader yet: the checkpoint's files are "
+    "not in this repository (no network), so its tensor names (the four "
+    "norms a block, input_layernorm / input_layernorm_2 / "
+    "post_attention_layernorm / post_attention_layernorm_2, and the exit "
+    "gate) have no mapping onto models.transformer.Block's and "
+    "Transformer's that was checked against them; arch='ouro' runs from "
+    "random weights only")
+
+
 def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
                           include_lm_head: bool = True) -> dict:
     if cfg.arch == "llama":
@@ -122,6 +132,8 @@ def convert_hf_state_dict(sd: Mapping[str, Any], cfg: ModelConfig,
         raise ValueError(_NO_LFM2_LOADER)
     elif cfg.arch == "mellum":
         raise ValueError(_NO_MELLUM_LOADER)
+    elif cfg.arch == "ouro":
+        raise ValueError(_NO_OURO_LOADER)
     else:
         raise ValueError(cfg.arch)
     if not include_lm_head:
@@ -290,6 +302,8 @@ def config_from_hf(hf_cfg: Any) -> ModelConfig:
         raise ValueError(_NO_LFM2_LOADER)
     if mt == "mellum":
         raise ValueError(_NO_MELLUM_LOADER)
+    if mt == "ouro":
+        raise ValueError(_NO_OURO_LOADER)
     if mt == "llama":
         return ModelConfig(
             arch="llama",

@@ -64,7 +64,10 @@ from orion_tpu.ops.rotary import apply_rotary
 # where the model is one stack and nothing beside it (likewise for the
 # paged-cache pytrees); {"dense": [per layer], "layers": stacked} where
 # leading dense layers stand beside one stack; {"dense": [...], "runs":
-# [stacked, one per ModelConfig.layer_runs stretch]} for several.
+# [stacked, one per ModelConfig.layer_runs stretch]} for several.  A
+# stack run total_ut_steps times over: an entry for every (pass, layer),
+# a leading pass axis on every leaf, of the list's entries and of the
+# stacked pytrees alike (init_cache).
 KVCache = Any
 
 _dt = lambda s: jnp.dtype(s)  # noqa: E731
@@ -127,17 +130,26 @@ class Kind:
 def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
                     lane: int = 1):
     """((tag, bytes), ...) in :data:`REMAT_TAGS` order, for the tags
-    this model's blocks have: what keeping a tag holds over all layers
-    for one minibatch of ``rows`` sequences of ``seq_len``, from the
-    shapes alone (each kind's ``tag_bytes``).  ``lane``: the multiple a
-    tensor's last dimension is padded to where it is held (128 on a
-    TPU; 1 counts the elements).  The attention tags are counted as the
+    this model's blocks have: what keeping a tag holds over all layer
+    visits for one minibatch of ``rows`` sequences of ``seq_len``, from
+    the shapes alone (each kind's ``tag_bytes``) and the loops that hold
+    them: a stack run ``total_ut_steps`` times over holds a tag once a
+    pass, and under ``scan_layers`` one pass more, because the scan over
+    layers stacks a pass's kept tensors as its own output and the scan
+    over passes then writes that stack into its accumulator of
+    ``passes`` stacks, so the newest pass's stack exists twice
+    (``tests/test_chip_compile.py`` holds the update compiled for the
+    chip to this count).
+    ``lane``: the multiple a tensor's last dimension is padded to where
+    it is held (128 on a TPU; 1 counts the elements).  The attention tags are counted as the
     flash kernel leaves them: an implementation without tags keeps
     nothing under those names and is over-reckoned."""
     def w(d):
         return -(-d // lane) * lane
 
     total = dict.fromkeys(REMAT_TAGS, 0)
+    passes = cfg.total_ut_steps
+    held = passes + (1 if passes > 1 and cfg.scan_layers else 0)
     resid = rows * seq_len * w(cfg.hidden_size) * _dt(cfg.dtype).itemsize
     for mixer, ffn in cfg.layer_kinds():
         parts = [cls.tag_bytes(cfg, rows, seq_len, w) for cls in
@@ -146,7 +158,7 @@ def remat_tag_bytes(cfg: ModelConfig, rows: int, seq_len: int,
             parts.append({"attn_resid": resid})
         for part in parts:
             for tag, size in part.items():
-                total[tag] += size
+                total[tag] += size * held
     return tuple((t, b) for t, b in total.items() if b)
 
 
@@ -331,6 +343,36 @@ def _cache_writer(positions, B: int, L: int, step: bool = False):
                 lambda c, t, i: jax.lax.dynamic_update_slice(
                     c, t, (i,) + zeros))(cache, new, starts)
     return write
+
+
+def _pass_writer(write, at, positions, B: int, L: int):
+    """``write`` for the entries of pass ``at`` (a traced index) of a
+    cache whose leaves lead with a pass axis, [passes, B, slots, ...]
+    (a stack run several times over, its passes a scan that carries the
+    cache): the one-token step ONE scatter into the whole leaf, in
+    place; a prefill takes its pass's entries out and puts them back,
+    once a generate."""
+    if L == 1:
+        bidx, starts = jnp.arange(B), positions[:, 0]
+
+        def step(cache, new):
+            return cache.at[at, bidx, starts].set(
+                new[:, 0], unique_indices=True)
+        return step
+
+    def prefill(cache, new):
+        return jax.lax.dynamic_update_index_in_dim(
+            cache, write(jax.lax.dynamic_index_in_dim(
+                cache, at, 0, keepdims=False), new), at, 0)
+    return prefill
+
+
+def _pass_entry(leaf, at, slots: Optional[int] = None):
+    """Pass ``at``'s [B, slots, ...] of such a leaf (its first ``slots``
+    slots): one dynamic slice, for the consumer to fuse."""
+    sizes = (1, leaf.shape[1], slots or leaf.shape[2]) + leaf.shape[3:]
+    return jax.lax.dynamic_slice(
+        leaf, (at,) + (0,) * (leaf.ndim - 1), sizes)[0]
 
 
 # A one-token step's attention reads a static prefix of its slot cache:
@@ -544,6 +586,13 @@ class Attention(nn.Module, Kind):
         # one step of a decode loop: one token; one block's, or two's
         step = layer_cache is not None and L in (
             1, cfg.block_length, 2 * cfg.block_length)
+        # the pass whose entries these are, of leaves [passes, B, slots,
+        # Hkv, D] (Transformer's scan over the passes of an unrolled
+        # stack run several times over)
+        at = None
+        if layer_cache is not None and "pass" in layer_cache:
+            layer_cache = dict(layer_cache)
+            at = layer_cache.pop("pass")
 
         scale = 1.0 / D ** 0.5
         paged_decode_out = None
@@ -577,6 +626,8 @@ class Attention(nn.Module, Kind):
             else:
                 write = _ring_writer(positions, token_mask, B, L,
                                      layer_cache["k"].shape[1])
+            if at is not None:
+                write = _pass_writer(write, at, positions, B, L)
 
             if "k_scale" in layer_cache:
                 # int8 KV cache (RolloutConfig.quantize_kv): quantize
@@ -604,12 +655,14 @@ class Attention(nn.Module, Kind):
             else:
                 # a cache laid [B, Lmax, Hkv * D] takes its rows so, and
                 # gives the prefill's attention one re-laid copy
-                lay = (B, L) + layer_cache["k"].shape[2:]
+                lay = (B, L) + layer_cache["k"].shape[2 + (at is not None):]
                 new_cache = {"k": write(layer_cache["k"], k.reshape(lay)),
                              "v": write(layer_cache["v"], v.reshape(lay))}
                 if window is None:
-                    keys, values = (new_cache[n].reshape(B, -1, *k.shape[2:])
-                                    for n in "kv")
+                    keys, values = (
+                        (new_cache[n] if at is None
+                         else _pass_entry(new_cache[n], at)
+                         ).reshape(B, -1, *k.shape[2:]) for n in "kv")
                 else:
                     # prefill sees its own rows; a ring is no place to
                     # look positions up in
@@ -628,25 +681,27 @@ class Attention(nn.Module, Kind):
         elif step and not is_paged(layer_cache):
             # one new token (one block's, two's) against the dense slot
             # cache, int8 or not
-            Lmax = new_cache["k"].shape[1]
+            Lmax = new_cache["k"].shape[1 + (at is not None)]
             if window is not None:
                 # the ring's filled slots: after the write every one of
                 # them holds a key inside the window (the new token took
                 # the place of the one that left it), and a softmax does
                 # not care in which order its slots lie
                 see = jnp.minimum(see, Lmax - 1)
-            if new_cache["k"].ndim == 3:
+            if new_cache["k"].ndim == 3 + (at is not None):
                 # laid out for the kernel (cache_entry: one token, a
                 # group of query heads on each of several key heads):
                 # over each row's filled blocks
-                out = dense_step.dense_step(
-                    q, new_cache["k"], new_cache["v"], see[:, 0], scale)
+                k_, v_ = (new_cache[n] if at is None
+                          else _pass_entry(new_cache[n], at) for n in "kv")
+                out = dense_step.dense_step(q, k_, v_, see[:, 0], scale)
             else:
                 # over the filled prefix of its slots
                 whole = positional_mask(see, Lmax)
 
                 def attend(m):
-                    c = {n: a[:, :m] for n, a in new_cache.items()}
+                    c = {n: a[:, :m] if at is None else _pass_entry(a, at, m)
+                         for n, a in new_cache.items()}
                     return step_attention(
                         q, c["k"], c["v"], whole[..., :m], scale,
                         c.get("k_scale"), c.get("v_scale"))
@@ -1841,15 +1896,85 @@ BLOCK_DIFFUSION_LACKS = {
 }
 
 
+#: What a stack run several times over (``ModelConfig.total_ut_steps``
+#: > 1) has none of, whatever the layers' kinds: :func:`cannot_run`'s
+#: forms, and ``pipeline`` (parallel/pipeline.py).
+LOOPED_LACKS = {
+    "continuous": "the continuous engine keeps one cache entry a layer "
+    "and a slot; a stack run several times over keeps one for every "
+    "(pass, layer), and that engine's slots and pages have no pass axis",
+    "speculative": "speculative decoding exists on the continuous engine "
+    "alone, whose cache has no entry for every (pass, layer)",
+    "paged": "the page pool holds one entry a layer (ops/paged_kv.py: "
+    "[num_layers] pages and block tables); pass t of a token reads pass "
+    "t's keys and there are no pages for every (pass, layer)",
+    "quantize_kv": "four passes compound an int8 cache's rounding and the "
+    "int8 entries of every (pass, layer) were not run against the "
+    "reference",
+    "quantize_weights": "the int8 weights would be read once a pass and "
+    "their rounding compounds over the passes; not run against the "
+    "reference",
+    "sequence_parallel": "the sequence-parallel attentions were not run "
+    "against the reference inside the scan over passes",
+    "pipeline": "a looped stack's stages form a ring (the last stage "
+    "hands pass t's output to the first for pass t + 1); "
+    "parallel/pipeline.py walks its stages once",
+}
+
+
 def cannot_run(cfg: ModelConfig, form: str) -> Optional[str]:
     """Why ``cfg``'s model cannot run under ``form`` (the keys of
-    :meth:`Kind.lacks`): every layer kind's reason, each once, and the
-    generation rule's (:data:`BLOCK_DIFFUSION_LACKS`); None where
-    nothing stands in the way."""
+    :meth:`Kind.lacks`): every layer kind's reason, each once, the
+    generation rule's (:data:`BLOCK_DIFFUSION_LACKS`) and the looped
+    stack's (:data:`LOOPED_LACKS`); None where nothing stands in the
+    way."""
     reasons = dict.fromkeys(kind.lacks(cfg).get(form) for kind in kinds(cfg))
     if cfg.block_length:
         reasons[BLOCK_DIFFUSION_LACKS.get(form)] = None
+    if cfg.total_ut_steps > 1:
+        reasons[LOOPED_LACKS.get(form)] = None
     return "; ".join(r for r in reasons if r) or None
+
+
+def ut_attrs(cfg: ModelConfig) -> dict:
+    """What both spans carry of a stack run several times over:
+    ``ut_steps``, the passes, and ``layer_visits``, the blocks a token
+    passes; {} for every other model."""
+    if cfg.total_ut_steps == 1:
+        return {}
+    return {"ut_steps": cfg.total_ut_steps,
+            "layer_visits": cfg.layer_visits()}
+
+
+def rl_fixed(cfg: ModelConfig) -> tuple:
+    """The prefixes of the parameter names RL holds fixed: what the
+    model's kinds name (``Kind.rl_fixed``) and the exit gate of a stack
+    run several times over, which nothing in an RL loss reads."""
+    fixed = tuple(p for kind in kinds(cfg) for p in kind.rl_fixed)
+    return fixed + (("exit_gate",) if cfg.total_ut_steps > 1 else ())
+
+
+def ut_weight_reads(cfg: ModelConfig, tree: dict) -> dict:
+    """What a one-token step of a stack run several times over reads of
+    ``tree`` (a decode copy of the weights, or their shapes; the
+    Transformer's own or under a wrapper's ``backbone`` beside its
+    heads): ``stack_weight_bytes``, the blocks', once a pass, and
+    ``once_weight_bytes``, everything else, once a step (the final norm
+    and the heads), but for what no step reads: the exit gate, and an
+    embedding that is not the head, which is gathered by row.  {} for
+    every other model."""
+    if cfg.total_ut_steps == 1:
+        return {}
+
+    def size(t):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))
+
+    trunk = tree.get("backbone", tree)
+    stack = sum(size(v) for k, v in trunk.items() if k.startswith("layers"))
+    unread = size(trunk["exit_gate"]) + (
+        0 if cfg.tie_word_embeddings else size(trunk["embed"]))
+    return {"stack_weight_bytes": stack,
+            "once_weight_bytes": size(tree) - stack - unread}
 
 
 def decode_attrs(cfg: ModelConfig, lens=None, slots: int = 0,
@@ -1867,8 +1992,11 @@ def decode_attrs(cfg: ModelConfig, lens=None, slots: int = 0,
     ``kv_cache_lane_fill``, their cache's minor dimension over that
     dimension rounded up to the TPU's 128 lanes: what the HBM holds of
     K and V is the data over this (1.0 under the kernel, whose cache is
-    ``Hkv * D`` minor; 0.5 at heads of 64 laid ``[.., Hkv, 64]``)."""
-    attrs = {"kda_step": "", "attn_heads_a_step": cfg.attn_heads_a_step()}
+    ``Hkv * D`` minor; 0.5 at heads of 64 laid ``[.., Hkv, 64]``).  A
+    stack run several times over says so (:func:`ut_attrs`); its
+    ``kv_step_slots`` are one visit's."""
+    attrs = {"kda_step": "", "attn_heads_a_step": cfg.attn_heads_a_step(),
+             **ut_attrs(cfg)}
     if lens is None:
         return attrs
     of = kinds(cfg)
@@ -1975,9 +2103,22 @@ def update_attrs(cfg: ModelConfig, total_lens, prompt_lens=(),
     and, for a model held in part (``head_share``), what of every layer
     this chip holds (the benchmark's operation counts read it): the
     state-space layers' heads and groups, attention's query and
-    key-value heads, the routed experts."""
+    key-value heads, the routed experts; for a stack run several times
+    over :func:`ut_attrs`, ``shared_grad_uses``, the uses whose
+    gradients a block's parameter sums, and ``seq_tokens`` /
+    ``causal_keys``, the batch's real tokens and the keys their queries
+    see on one visit."""
     attrs = {"kda_chunk": "", "attn_heads_a_step": cfg.attn_heads_a_step(),
-             **stream_attrs(cfg, prompt_lens, seq_len, new_tokens)}
+             **stream_attrs(cfg, prompt_lens, seq_len, new_tokens),
+             **ut_attrs(cfg)}
+    if cfg.total_ut_steps > 1:
+        # a shared parameter's gradient is the sum over its uses; the
+        # real tokens and their causal keys on ONE visit (the
+        # benchmark's operation counts read them)
+        n = np.asarray(total_lens, np.int64)
+        attrs.update(shared_grad_uses=cfg.total_ut_steps,
+                     seq_tokens=int(n.sum()),
+                     causal_keys=int((n * (n + 1) // 2).sum()))
     for kind in kinds(cfg):
         attrs.update(kind.forward_attrs(cfg, total_lens))
     if cfg.head_share != (0, 1):
@@ -1988,6 +2129,17 @@ def update_attrs(cfg: ModelConfig, total_lens, prompt_lens=(),
     return attrs
 
 
+def exit_masses(lams):
+    """The exit masses [passes, ...] of a looped stack from its gates
+    ``lams`` [passes, ...] (float32, each in (0, 1)): ``p_t = lambda_t
+    prod_{j<t} (1 - lambda_j)`` and the last pass takes what is left,
+    ``prod_{j<last} (1 - lambda_j)`` (its own gate is not read), so that
+    they sum to 1."""
+    stay = jnp.cumprod(1.0 - lams[:-1], axis=0)
+    before = jnp.concatenate([jnp.ones_like(lams[:1]), stay], axis=0)
+    return jnp.concatenate([lams[:-1] * before[:-1], before[-1:]], axis=0)
+
+
 class Block(nn.Module):
     """One block of ``mixer`` and ``ffn`` (``ModelConfig.layer_kinds``;
     None: that half is absent).  ``a = x + Mixer(N1(x))``, ``y = a +
@@ -1995,8 +2147,11 @@ class Block(nn.Module):
     ``use_parallel_residual`` (GPT-NeoX) ``y = x + Mixer(N1(x)) +
     FFN(N2(x))``; under ``post_norm`` (the OLMo 2 / 3 order) ``a = x +
     N_a(Mixer(x))``, ``y = a + N_f(FFN(a))`` (``post_attn_norm``,
-    ``post_mlp_norm``).  A block of both halves tags ``a``
-    (``attn_resid``) unless they run in parallel."""
+    ``post_mlp_norm``); under ``sandwich_norm`` (Ouro's order) ``a = x
+    + N2(Mixer(N1(x)))``, ``y = a + N4(FFN(N3(a)))`` (``input_norm``,
+    ``attn_out_norm``, ``post_attn_norm``, ``post_mlp_norm``).  A block
+    of both halves tags ``a`` (``attn_resid``) unless they run in
+    parallel."""
 
     cfg: ModelConfig
     mixer: Optional[str]
@@ -2014,7 +2169,13 @@ class Block(nn.Module):
                 return t
 
         def norm(name, t, after: bool = False):
-            return _norm(cfg, name)(t) if after == cfg.post_norm else t
+            here = cfg.sandwich_norm or after == cfg.post_norm
+            return _norm(cfg, name)(t) if here else t
+
+        # the sandwich order norms the mixer's output AND the MLP's input
+        # (post_attn_norm, as in the pre-norm order): a name of its own
+        after_mixer = "attn_out_norm" if cfg.sandwich_norm \
+            else "post_attn_norm"
 
         def run(kind, module, *args, **kw):
             more = (token_mask,) if kind.takes_token_mask else ()
@@ -2028,7 +2189,7 @@ class Block(nn.Module):
                 kind, kind(cfg, name="attn", **kw), norm("input_norm", x),
                 positions, layer_cache,
                 **({} if visible is None else {"visible": visible}))
-            attn_out = norm("post_attn_norm", attn_out, after=True)
+            attn_out = norm(after_mixer, attn_out, after=True)
         if self.ffn is None:
             return sp(x + attn_out), new_cache
         kind = ffn_class(self.ffn)
@@ -2133,51 +2294,144 @@ class Transformer(nn.Module):
         # layouts, stand outside the scanned stacks.
         runs = cfg.layer_runs()
         stacks = _stack_names(cfg)
-        run_caches = _split_cache(cfg, cache)
-        new_caches = []
-        for (first, length, mixer, ffn), rc in zip(runs, run_caches):
-            cls = Block
-            if cfg.remat:
-                cls = nn.remat(
-                    Block, static_argnums=(),
-                    policy=jax.checkpoint_policies.save_only_these_names(
-                        *remat_keep) if remat_keep else None)
-            if cfg.scan_layers and first in stacks:
-                # One Block traced once, lax.scan over a stacked param
-                # tree [length, ...] — compile time is O(1) in depth (the
-                # VERDICT r1 "compile-time win" flag, now real).  The
-                # cache is likewise a stacked pytree (see init_cache /
-                # init_paged_cache with scan_layers=True); positions are
-                # broadcast.  Param metadata gains a leading "layers"
-                # logical axis (replicated by LOGICAL_RULES).
-                scan_block = nn.scan(
-                    cls,
-                    # "intermediates" must be listed or nn.scan silently
-                    # DROPS everything sown inside the scanned block — the
-                    # MoE router aux loss would read as zero under
-                    # scan_layers with no error.
-                    variable_axes={"params": 0, "intermediates": 0,
-                                   "selections": 0},
-                    split_rngs={"params": True},
-                    in_axes=(nn.broadcast, 0, nn.broadcast, nn.broadcast),
-                    out_axes=0,
-                    length=length,
-                    metadata_params={nn.meta.PARTITION_NAME: "layers"},
-                )
-                x, c = scan_block(cfg, mixer, ffn, name=stacks[first])(
-                    x, positions, rc, token_mask, visible)
-                new_caches.append(c)
-            else:
-                out = []
-                for j in range(length):
-                    x, c = cls(cfg, mixer, ffn, name=f"layers_{first + j}")(
-                        x, positions, None if rc is None else rc[j],
-                        token_mask, visible)
-                    out.append(c)
-                new_caches.append(out)
-        new_cache = None if cache is None else _join_cache(cfg, new_caches)
 
-        x = _norm(cfg, "final_norm")(x)
+        def build():
+            """The stack's modules, one entry a stretch: a scanned stack,
+            or its blocks.  (Called where their parameters live: this
+            module's compact call, or the body of the scan over
+            passes.)"""
+            built = []
+            for first, length, mixer, ffn in runs:
+                cls = Block
+                if cfg.remat:
+                    cls = nn.remat(
+                        Block, static_argnums=(),
+                        policy=jax.checkpoint_policies
+                        .save_only_these_names(*remat_keep)
+                        if remat_keep else None)
+                if cfg.scan_layers and first in stacks:
+                    # One Block traced once, lax.scan over a stacked param
+                    # tree [length, ...] — compile time is O(1) in depth
+                    # (the VERDICT r1 "compile-time win" flag, now real).
+                    # The cache is likewise a stacked pytree (see
+                    # init_cache / init_paged_cache with
+                    # scan_layers=True); positions are broadcast.  Param
+                    # metadata gains a leading "layers" logical axis
+                    # (replicated by LOGICAL_RULES).
+                    scan_block = nn.scan(
+                        cls,
+                        # "intermediates" must be listed or nn.scan
+                        # silently DROPS everything sown inside the
+                        # scanned block — the MoE router aux loss would
+                        # read as zero under scan_layers with no error.
+                        variable_axes={"params": 0, "intermediates": 0,
+                                       "selections": 0},
+                        split_rngs={"params": True},
+                        in_axes=(nn.broadcast, 0, nn.broadcast,
+                                 nn.broadcast),
+                        out_axes=0,
+                        length=length,
+                        metadata_params={nn.meta.PARTITION_NAME: "layers"},
+                    )
+                    built.append(scan_block(cfg, mixer, ffn,
+                                            name=stacks[first]))
+                else:
+                    built.append([
+                        cls(cfg, mixer, ffn, name=f"layers_{first + j}")
+                        for j in range(length)])
+            return built
+
+        def walk(built, x, pass_cache, at=None):
+            """One pass over the stack: (x, the pass's new cache).
+            ``at``: the pass, where the unrolled layers' entries lead
+            with a pass axis (each mixer reads and writes its own)."""
+            new_caches = []
+            for (first, length, _, _), rc, mods in zip(
+                    runs, _split_cache(cfg, pass_cache), built):
+                if cfg.scan_layers and first in stacks:
+                    x, c = mods(x, positions, rc, token_mask, visible)
+                    new_caches.append(c)
+                else:
+                    out = []
+                    for j in range(length):
+                        x, c = mods[j](
+                            x, positions,
+                            None if rc is None else rc[j] if at is None
+                            else {**rc[j], "pass": at},
+                            token_mask, visible)
+                        out.append(c)
+                    new_caches.append(out)
+            return x, (None if pass_cache is None
+                       else _join_cache(cfg, new_caches))
+
+        passes = cfg.total_ut_steps
+        if passes == 1:
+            x, new_cache = walk(build(), x, cache)
+            x = _norm(cfg, "final_norm")(x)
+        else:
+            # The stack run ``passes`` times over with ONE set of
+            # parameters, the final norm after every pass (its output the
+            # next pass's input and the pass's hidden state), pass t of a
+            # token against pass t's cache entries.  The exit gate is read
+            # where someone reads what it sows.
+            def finish(mdl, x, fnorm, gate):
+                x = fnorm(x)
+                if not (mdl.is_mutable_collection("intermediates")
+                        or mdl.is_initializing()):
+                    return x, None
+                return x, jax.nn.sigmoid(
+                    gate(x).astype(jnp.float32))[..., 0]
+
+            def exit_gate():
+                return _dense(1, ("embed", None), True, cfg, "exit_gate")
+
+            if cfg.scan_layers:
+                # a scan over passes with the parameters broadcast, around
+                # the scan over layers: one traced block, a shared
+                # weight's gradient summed over its uses inside the
+                # program
+                def one_pass(mdl, x, pass_cache):
+                    x, c = walk(build(), x, pass_cache)
+                    x, lam = finish(mdl, x, _norm(cfg, "final_norm"),
+                                    exit_gate())
+                    return x, (c, lam)
+
+                x, (new_cache, lams) = nn.scan(
+                    one_pass, variable_broadcast="params",
+                    variable_axes={"intermediates": 0, "selections": 0},
+                    split_rngs={"params": False},
+                    length=passes)(self, x, cache)
+            elif cache is not None:
+                # the decode twin: the layers unrolled, the passes a scan
+                # that CARRIES the cache (every leaf [passes, B, slots,
+                # ...], a mixer writing its pass's rows in place), so the
+                # rollout's program holds one pass's blocks
+                def one_pass(mdl, carry, t):
+                    with jax.named_scope("ut.pass"):
+                        x, c = walk(build(), carry[0], carry[1], t)
+                        x, lam = finish(mdl, x, _norm(cfg, "final_norm"),
+                                        exit_gate())
+                    return (x, c), lam
+
+                (x, new_cache), lams = nn.scan(
+                    one_pass, variable_broadcast="params",
+                    variable_axes={"intermediates": 0, "selections": 0},
+                    split_rngs={"params": False})(
+                        self, (x, cache), jnp.arange(passes))
+            else:
+                # unrolled and no cache: every block built once, called
+                # once a pass
+                built, fnorm, gate = build(), _norm(cfg, "final_norm"), \
+                    exit_gate()
+                new_cache, lams = None, []
+                for t in range(passes):
+                    with jax.named_scope("ut.pass"):
+                        x, _ = walk(built, x, None)
+                        x, lam = finish(self, x, fnorm, gate)
+                    lams.append(lam)
+                lams = None if lams[0] is None else jnp.stack(lams)
+            if self.is_mutable_collection("intermediates"):
+                self.sow("intermediates", "ut_exit_mass", exit_masses(lams))
         hidden = x
         if skip_lm_head:
             # Heads-only callers (critic/RM) skip the vocab projection —
@@ -2237,7 +2491,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     """Dense pre-allocated cache: per layer what its mixer states
     (``cache_entry``; {} for a block without a mixer).  ``scan_layers``
     models use a stacked [num_layers, ...] pytree (scanned over axis
-    0); unrolled models a per-layer list.  ``quantized`` stores int8
+    0); unrolled models a per-layer list.  A stack run several times
+    over (``total_ut_steps``) has an entry for every (pass, layer): a
+    leading pass axis on every leaf, of a layer's entry in the list
+    ([passes, B, slots, ...]) and of the stacked pytree ([passes,
+    layers, B, slots, ...]).  ``quantized`` stores int8
     values with per-token-per-head f32 scales (RolloutConfig.quantize_kv
     — see ops/quant.py)."""
     dtype = dtype or _dt(cfg.dtype)
@@ -2247,8 +2505,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         raise ValueError("there is no int8 cache (rollout.quantize_kv) for "
                          f"arch={cfg.arch!r} yet: {why}")
 
+    # the passes of a stack run several times over lead every leaf
+    lead = (cfg.total_ut_steps,) if cfg.total_ut_steps > 1 else ()
+
     def entry(mixer, pre=()):
-        return cache_entry(cfg, mixer, batch, slots, dtype, pre, quantized)
+        return cache_entry(cfg, mixer, batch, slots, dtype, lead + pre,
+                           quantized)
 
     stacks = _stack_names(cfg) if cfg.scan_layers else {}
     return _join_cache(cfg, [

@@ -158,6 +158,11 @@ class PipelinedTransformer:
                              "cfg.scan_layers=True (stacked block params)")
         if axis not in mesh.axis_names:
             raise ValueError(f"mesh has no {axis!r} axis: {mesh.axis_names}")
+        from orion_tpu.models.transformer import LOOPED_LACKS
+
+        if cfg.total_ut_steps > 1:
+            raise ValueError(f"arch={cfg.arch!r} cannot run under pipeline "
+                             f"parallelism: {LOOPED_LACKS['pipeline']}")
         self.cfg = cfg
         self.mesh = mesh
         self.axis = axis

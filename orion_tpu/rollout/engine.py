@@ -61,7 +61,7 @@ from orion_tpu.config import ModelConfig, RolloutConfig
 from orion_tpu.models.transformer import (MIXERS, cache_entry, cache_slots,
                                          cannot_run, decode_attrs, init_cache,
                                          make_decode_twin, prep_decode_params,
-                                         sown)
+                                         sown, ut_weight_reads)
 from orion_tpu.ops.logprobs import pack_sequences
 from orion_tpu.ops.moe import step_form
 from orion_tpu.ops.sampling import sample_tokens
@@ -103,6 +103,15 @@ class GenerationResult:
         return GenerationResult(**jax.device_get(self._fields()))
 
 
+def _weight_reads(tree, model_cfg: ModelConfig) -> dict:
+    """``weight_bytes`` of a decode copy of the weights (shapes), and
+    what the model says a step reads of it how often
+    (``models.transformer.ut_weight_reads``)."""
+    return {"weight_bytes": sum(x.size * x.dtype.itemsize
+                                for x in jax.tree.leaves(tree)),
+            **ut_weight_reads(model_cfg, tree)}
+
+
 class RolloutEngine:
     """Batched autoregressive generation with KV cache + logprob capture."""
 
@@ -131,7 +140,7 @@ class RolloutEngine:
         self.pad_token_id = pad_token_id
         self._params = None
         self._cache_bytes: dict = {}
-        self._weight_bytes: Optional[int] = None
+        self._weight_bytes: Optional[dict] = None
         self._decode_model, self._decode_cfg = make_decode_twin(
             model, model_cfg)
         self._expert_layers = sum(
@@ -165,7 +174,10 @@ class RolloutEngine:
         position (keys and values, or latents); ``state_bytes``, what
         is not (recurrent states and convolution inputs: read and
         written whole at every decode step); with an indexer,
-        ``index_cache_bytes``: its keys' part of ``cache_bytes``."""
+        ``index_cache_bytes``: its keys' part of ``cache_bytes``.  A stack
+        run several times over keeps every pass's entries
+        (``cache_bytes``) and says what one pass's are
+        (``cache_bytes_a_pass``)."""
         key = (batch, slots)
         if key not in self._cache_bytes:
             mc = self._decode_cfg
@@ -181,6 +193,9 @@ class RolloutEngine:
                     if leaf in kind.index_leaves:
                         sizes["index_cache_bytes"] = size + sizes.get(
                             "index_cache_bytes", 0)
+            if mc.total_ut_steps > 1:
+                sizes["cache_bytes_a_pass"] = sizes["cache_bytes"]
+                sizes["cache_bytes"] *= mc.total_ut_steps
             self._cache_bytes[key] = sizes
         return self._cache_bytes[key]
 
@@ -190,17 +205,16 @@ class RolloutEngine:
         shapes and lengths: what a decode step touches of the cache
         (:meth:`_cache_shapes`; 0 under ``paged``, whose pool is sized
         apart), ``weight_bytes`` (the copy of the weights it reads,
-        ``prep_decode_params``) and what the model says of its steps
-        (``models.transformer.decode_attrs``)."""
+        ``prep_decode_params``; :func:`_weight_reads`) and what the model
+        says of its steps (``models.transformer.decode_attrs``)."""
         params = params if params is not None else self._params
         if self._weight_bytes is None and params is not None:
             tree = jax.eval_shape(
                 lambda p: prep_decode_params(p, self.model_cfg,
                                              self.cfg.quantize_weights),
                 params)
-            self._weight_bytes = sum(x.size * x.dtype.itemsize
-                                     for x in jax.tree.leaves(tree))
-        weights = {"weight_bytes": self._weight_bytes or 0}
+            self._weight_bytes = _weight_reads(tree, self.model_cfg)
+        weights = dict(self._weight_bytes or {"weight_bytes": 0})
         if self.cfg.paged:
             return {"cache_bytes": 0, "state_bytes": 0, **weights,
                     **decode_attrs(self.model_cfg)}

@@ -26,10 +26,10 @@ import numpy as np
 import optax
 
 from orion_tpu.config import OptimizerConfig, TrainConfig
-from orion_tpu.models.transformer import (Transformer, kinds, remat_keep,
-                                         remat_tag_bytes, sown, stream_attrs,
-                                         trace_inputs, trace_row_length,
-                                         update_attrs)
+from orion_tpu.models.transformer import (Transformer, remat_keep,
+                                         remat_tag_bytes, rl_fixed, sown,
+                                         stream_attrs, trace_inputs,
+                                         trace_row_length, update_attrs)
 from orion_tpu.ops.logprobs import completion_logprobs, entropy_from_logits
 from orion_tpu.rollout import GenerationResult, RolloutEngine
 
@@ -178,11 +178,11 @@ def _stamp(span, gc_totals: tuple, compile_totals: tuple) -> tuple:
 
 def hold_fixed(updates, cfg_model):
     """The optimizer's updates with those of the parameters that RL
-    holds fixed set to zero: the ones the model's mixers name by prefix
-    (``models.transformer.Kind.rl_fixed``).  No gradient reaches them,
+    holds fixed set to zero: the ones the model names by prefix
+    (``models.transformer.rl_fixed``).  No gradient reaches them,
     so this only keeps weight decay off them; a model that names none
     gets its updates back as they are."""
-    fixed = tuple(p for kind in kinds(cfg_model) for p in kind.rl_fixed)
+    fixed = rl_fixed(cfg_model)
     if not fixed:
         return updates
     return jax.tree_util.tree_map_with_path(
@@ -252,6 +252,26 @@ def step_read_pct(expert_stacks) -> dict:
         return {}
     read, held = (float(v) for v in expert_stacks)
     return {"moe_step_read_pct": 100.0 * read / max(held, 1.0)}
+
+
+def ut_exit_stats(masses, read_at, mask) -> dict:
+    """Counters of a stack run several times over for one forward, from
+    the exit masses it sowed (``ut_exit_mass`` [passes, B, L]) read at
+    ``read_at`` [B, T], means over the tokens ``mask`` [B, T] marks
+    (none: all of them): ``ut_exit_mass_<t>``, the mass on pass t, and
+    ``ut_passes_per_token``, the passes this forward ran (as many as it
+    sowed masses for: at ``early_exit_threshold`` 1, the only one a
+    configuration may state, every token's hidden state is the last
+    pass's, so the counter mirrors ``total_ut_steps`` until a program
+    ends a token's passes early)."""
+    at = jnp.take_along_axis(masses, read_at[None], axis=2)
+    w = jnp.ones(read_at.shape, jnp.float32) if mask is None \
+        else mask.astype(jnp.float32)
+    n = jnp.maximum(jnp.sum(w), 1.0)
+    stats = {f"ut_exit_mass_{t + 1}": jnp.sum(m * w) / n
+             for t, m in enumerate(at)}
+    stats["ut_passes_per_token"] = jnp.float32(len(at))
+    return stats
 
 
 def _read_at(extra, read_at):
@@ -497,6 +517,15 @@ class BaseTrainer:
                                  block_rows(mc, sequences.size),
                                  sown(inter, "moe_combine"))
             aux = jnp.zeros((), jnp.float32)
+        elif mc.total_ut_steps > 1:
+            # A looped stack sows its exit masses [passes, B, L]; the
+            # caller that knows which tokens count reduces them
+            # (_windowed_forward).
+            out, inter = self.model.apply(
+                {"params": params}, sequences, positions,
+                mutable=["intermediates"], **apply_kw)
+            moe = {"ut_exit_mass": sown(inter, "ut_exit_mass")[0]}
+            aux = jnp.zeros((), jnp.float32)
         else:
             out = self.model.apply({"params": params}, sequences,
                                    positions, **apply_kw)
@@ -505,7 +534,7 @@ class BaseTrainer:
 
     def _windowed_forward(self, params, sequences, prompt_lens,
                           max_new: int, with_entropy: bool = True,
-                          reveal_step=None, **apply_kw):
+                          reveal_step=None, mask=None, **apply_kw):
         """Shared completion-window forward: the vocab projection runs
         only at the T completion positions (ops.logprobs.completion_
         window_positions) — the [B, L, V] f32 logits at full length are
@@ -516,8 +545,10 @@ class BaseTrainer:
         ActorCriticModel), each read at the T positions the logits are
         (the value of completion token t: the hidden state its
         log-probability is computed from), and ``aux``, ``moe`` are
-        _policy_apply's.  A block-diffusion model's completion is scored
-        along its sampling trace ``reveal_step`` (:meth:`_trace_forward`)."""
+        _policy_apply's (a looped stack's counters are means over the
+        window's tokens that ``mask`` [B, T] marks: :func:`ut_exit_stats`).
+        A block-diffusion model's completion is scored along its sampling
+        trace ``reveal_step`` (:meth:`_trace_forward`)."""
         from orion_tpu.ops.logprobs import (completion_window_positions,
                                             windowed_completion_logprobs)
 
@@ -538,6 +569,8 @@ class BaseTrainer:
             params, sequences, positions, logits_positions=widx,
             **apply_kw)
         logits_w, extra = out[0], _read_at(out[1:], widx)
+        if "ut_exit_mass" in moe:
+            moe = ut_exit_stats(moe["ut_exit_mass"], widx, mask)
         lp = windowed_completion_logprobs(logits_w, sequences, prompt_lens,
                                           max_new)
         ent = entropy_from_logits(logits_w) if with_entropy else None
@@ -1329,7 +1362,7 @@ class BaseTrainer:
                 **(self._remat_info or {}), **pending["model"],
             })
             sp.set(**{k: v for k, v in stats.items()
-                      if k.startswith("moe_")})
+                      if k.startswith(("moe_", "ut_"))})
             self.metrics_history.append(stats)
             if self.writer is not None:
                 # giter: the global counter at dispatch time — monotone

@@ -114,7 +114,7 @@ class PPOTrainer(BaseTrainer):
         lp, ent, extra, aux, moe = self._windowed_forward(
             params, sequences, prompt_lens, max_new,
             with_entropy=with_entropy, reveal_step=reveal_step,
-            with_values=True)
+            mask=mask, with_values=True)
         # read where the logits were (_windowed_forward)
         return lp, ent, extra[0] * mask, aux, moe
 
@@ -122,12 +122,15 @@ class PPOTrainer(BaseTrainer):
     def build_experience(self, result, scores, host=None):
         T = result.completions.shape[1]
         mask = result.completion_mask
+        ut = {}
         if self.cfg.share_backbone and not self.cfg.async_mode:
             # One fused trunk pass yields old logprobs AND values.
-            old_lp, _, values, _, _ = self._jit_lp_values(
+            old_lp, _, values, _, counters = self._jit_lp_values(
                 self.state.params, result.sequences, result.prompt_lens,
                 mask, max_new=T, with_entropy=False,
                 **self._trace_kw(result))
+            # a looped stack's exit masses over this batch's real tokens
+            ut = {k: v for k, v in counters.items() if k.startswith("ut_")}
         else:
             old_lp = self.behavior_logprobs(result)
             critic_params = (self.state.params if self.cfg.share_backbone
@@ -154,7 +157,7 @@ class PPOTrainer(BaseTrainer):
         dev = {
             "kl": masked_mean(kl, mask),
             "value_mean": masked_mean(values, mask),
-            "return_mean": masked_mean(returns, mask),
+            "return_mean": masked_mean(returns, mask), **ut,
         }
         if self._defer_stats:
             # Sync pipelined loop: leave the scalars on device; the

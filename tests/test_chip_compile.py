@@ -1673,3 +1673,148 @@ def test_mellum_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
             <= set(names)
         assert mem.argument_size_in_bytes == pytest.approx(4.99e9, rel=2e-2)
     assert mem.peak_memory_in_bytes + resident <= V5E_BYTES_LIMIT
+
+
+# -- the ouro model (one stack run several times over) -----------------------
+
+def _ouro_cell():
+    """(model configuration, B, P, T, minibatch) of ``ppo-ouro-d8-sync``."""
+    import dataclasses
+
+    from orion_tpu.config import ModelConfig
+
+    mc = dataclasses.replace(ModelConfig.ouro_2_6b(), num_layers=8,
+                             max_seq_len=768)
+    assert mc.layer_runs() == ((0, 8, "attention", "dense"),)
+    assert mc.layer_visits() == 32
+    return mc, 16, 256, 512, 4
+
+
+@pytest.mark.parametrize("program", ["generate", "experience", "update"])
+def test_ouro_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
+    """The three programs of ``ppo-ouro-d8-sync`` (the published layers
+    0-7 of Ouro-2.6B run four times over with one set of weights; remat,
+    the stack scanned under a scan over passes) at the timed shapes, 16
+    prompts padded to 256 and 512 new tokens.  ``generate``: prefill and
+    the decode loop through the twin, its 8 layers unrolled and its
+    passes a scan that carries the cache: ONE pass's blocks in the text
+    (``flash_fwd`` once a layer in the prefill's pass body), the decode
+    step's loop over passes carrying K and V of the 8 layers as they
+    lie, ``bf16[4, 16, 768, 16, 128]`` (one query head a key head:
+    ``dense_step.step_form`` says ``""``, so ``prefix_step``'s
+    ``conditional`` over 6 prefixes of 128 slots a layer, each branch
+    slicing its pass's prefix out of the leaf inside its fusions), the
+    new rows scattered into the leaves in place: no copy of a leaf nor
+    of a pass's entry anywhere in the step.  ``experience``: the shared-trunk
+    forward of all 16 rows, which sows the exit masses.  ``update``: the
+    forward, remat's and the backward in minibatches of 4 with ONE block
+    body whatever the passes (three flash kernels, each once in the
+    text).  Each fits beside what else the chip holds: 612 M parameters
+    are 4.90 GB of float32 master and bf16 moments, the bf16 reference
+    1.22 GB more."""
+    import re
+
+    from orion_tpu.config import RolloutConfig
+    from orion_tpu.models.transformer import remat_tag_bytes
+    from orion_tpu.rollout.engine import RolloutEngine
+    from orion_tpu.trainers.base import BaseTrainer
+    from orion_tpu.utils.compile_check import (_abstract_state,
+                                               _build_8b_shell)
+
+    mc, B, P, T, rows = _ouro_cell()
+    shell, pshape, mb = _build_8b_shell(mc)
+    shell.cfg.rollout.max_prompt_len = P
+    shell.cfg.rollout.max_new_tokens = T
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), pshape)
+    ids = lambda *shape: _sds(shape, jnp.int32, one_chip)  # noqa: E731
+    resident = 1.22e9              # the bf16 reference
+    with jax.default_matmul_precision("default"):
+        if program == "generate":
+            eng = RolloutEngine(shell.model, mc, RolloutConfig(
+                max_prompt_len=P, max_new_tokens=T), eos_token_id=None,
+                pad_token_id=0)
+            rng = jax.eval_shape(lambda: jax.random.key(0))
+            lowered = eng._generate_jit.lower(
+                params, ids(B, P), ids(B), _sds(rng.shape, rng.dtype,
+                                                one_chip),
+                max_new_tokens=T)
+            resident += 2 * 1.22e9          # and the moments
+        elif program == "experience":
+            lowered = jax.jit(
+                lambda p, s, n, m: shell._lp_values_fwd(
+                    p, s, n, m, max_new=T, with_entropy=False)).lower(
+                params, ids(B, P + T), ids(B),
+                _sds((B, T), jnp.float32, one_chip))
+            resident += 2 * 1.22e9
+        else:
+            shapes = {k: (P + T,) if k == "sequences"
+                      else () if k == "prompt_lens" else (T,) for k in mb}
+            experience = {k: _sds((B,) + shapes[k], v.dtype, one_chip)
+                          for k, v in mb.items()}
+            state = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip),
+                                 _abstract_state(shell, pshape))
+            def update(keep):
+                shell._remat_keep = keep
+                return jax.jit(
+                    lambda s, e, i: BaseTrainer._epochs_fn(shell, s, e, i),
+                    donate_argnums=(0,)).lower(
+                        state, experience,
+                        _sds((B // rows, rows), jnp.int32, one_chip))
+
+            lowered = update(())
+            # what the remat ladder reckons a kept tag holds IS what the
+            # compiled update holds more: four passes' stacks in the scan
+            # over passes' own, and the newest pass's once more in the
+            # scan over layers' (remat_tag_bytes: passes + 1)
+            tags = dict(remat_tag_bytes(mc, rows, P + T, lane=128))
+            kept = update(("mlp_pre",)).compile().memory_analysis()
+        compiled = lowered.compile()
+    names = _kernel_names(compiled)
+    mem = compiled.memory_analysis()
+    print(program, {k: getattr(mem, k) for k in _MEMORY},
+          sorted(set(names)))
+    if program == "update":
+        assert tags["mlp_pre"] == 5 * 8 * 2 * rows * (P + T) * 5632 * 2
+        # by the ladder's own measure (trainers/base.py::_probe_remat_keep)
+        def need(m):
+            return (m.peak_memory_in_bytes - m.argument_size_in_bytes
+                    + m.generated_code_size_in_bytes)
+
+        assert need(kept) - need(mem) == pytest.approx(tags["mlp_pre"],
+                                                       rel=0.03)
+    if program == "generate":
+        # the prefill: one pass's layers in the text
+        assert names.count("flash_fwd") == 8
+        assert not {"flash_bwd_dq", "dense_step", "paged_decode"} \
+            & set(names)
+        text = compiled.as_text()
+        comps = _computations(text)
+        bodies = _while_bodies(text)
+        leaf = "bf16[4,16,768,16,128]"
+        # the decode step's loop over passes: a conditional a layer
+        (passes,) = [name for name, body in bodies.items()
+                     if body.count(" conditional(") == 8]
+        found = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                           comps[passes])
+        assert all(len(f.split(",")) == 6 for f in found)
+        # K and V of the 8 layers ride it as they lie, written in place
+        assert len(re.findall(r"= %s\S* fusion\(" % re.escape(leaf),
+                              comps[passes])) == 16
+        (step,) = [name for name, body in bodies.items()
+                   if "body=%" + passes in body]
+        entry = "bf16[16,768,16,128]"
+        for name in _called(comps, step):
+            for whole in (leaf, entry):
+                assert not re.search(r"= %s\S* (copy(-start)?|dynamic-slice)"
+                                     r"\(" % re.escape(whole),
+                                     comps[name]), (name, whole)
+        assert mem.argument_size_in_bytes == pytest.approx(2.45e9, rel=1e-2)
+    elif program == "experience":
+        assert names.count("flash_fwd") == 1
+        assert mem.argument_size_in_bytes == pytest.approx(2.45e9, rel=1e-2)
+    else:
+        # one block body whatever the passes
+        assert [names.count(k) for k in
+                ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")] == [2, 1, 1]
+        assert mem.argument_size_in_bytes == pytest.approx(4.90e9, rel=2e-2)
+    assert mem.peak_memory_in_bytes + resident <= V5E_BYTES_LIMIT

@@ -25,7 +25,7 @@ from orion_tpu.models.transformer import MIXERS, Transformer, mixer_spec
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 ARCHS = ("llama", "neox", "deepseek_v3", "kimi_linear", "olmo_hybrid",
-         "keye_dsa", "nemotron_h", "sdar_moe", "lfm2_moe", "mellum")
+         "keye_dsa", "nemotron_h", "sdar_moe", "lfm2_moe", "mellum", "ouro")
 #: a tiny arch whose layers have the mixer
 MIXER_ARCH = {"attention": "llama", "sparse": "keye_dsa",
               "latent": "deepseek_v3", "kda": "kimi_linear",
