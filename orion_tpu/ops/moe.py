@@ -155,23 +155,21 @@ class MoEMLP(nn.Module, Kind):
 # crossing between the two was not looked for.
 DENSE_MAX_TOKENS = 256
 
-# Such a small step reads only the stacks of the held experts that some
-# row selected (the kernel ops/pallas/experts_step.py) where the share
-# of them it expects to need, 1 - (1 - k / E) ** tokens for top k of E
-# experts, is under this.  Read on a v5e (PERF.md section 6, PR 54): the
-# kernel moves what it reads at the einsum form's rate or better up to
-# 64 rows (16 stacks of 9.4 MB, all hit, 32 rows: 206.8 us against
-# 218.0; 8 of 22 MB, all hit, 64 rows: 240.1 against 259.9), so what it
-# skips is gained: ppo-keye-dsa-ep8-sync (0.40) +5.0% samples/s,
-# ppo-mellum2-ep8-sync (0.66) +3.1%, ppo-kimi-linear-ep32-sync (0.64)
-# +1.6%, and the two cells nearest the bound still win,
-# ppo-nemotron-h-tp4-sync (0.75) +7.5% and ppo-kanana-ep8-sync (0.78)
-# +2.9%.  No crossing was found under 0.78; over 0.9 a step has a tenth
-# of its stacks at most to skip, and the forwards that stand there
-# (LFM2's 64 rows of top-4 of 32, SDAR's 128 and 256 tokens: 0.9998)
-# were not run on the kernel end to end; at 128 rows with every stack
-# hit the einsum form took 145.4 us where the kernel took 208.5.
+# Such a small step takes the kernel ops/pallas/experts_step.py, which
+# reads only the stacks some row selected, where (1) the share of them
+# it expects to need, 1 - (1 - k / E) ** tokens for top k of E experts,
+# is under STEP_MAX_READ_SHARE: what it skips is gained (a v5e, PERF.md
+# section 6, PR 54: ppo-keye-dsa-ep8-sync at 0.40 +5.0% samples/s, and
+# the cells nearest the bound still win, ppo-nemotron-h-tp4-sync at 0.75
+# +7.5%, ppo-kanana-ep8-sync at 0.78 +2.9%; over 0.9 a tenth at most is
+# left to skip), or (2) it has STEP_KERNEL_MAX_ROWS rows at most: up to
+# there the kernel moves what it reads faster than the einsum form,
+# every stack hit (the loop alone, us a layer, kernel | einsum: 8 stacks
+# of 22 MB at 64 rows 240.1 | 259.9, at 128 rows 208.5 | 145.4), and in
+# ppo-lfm2-ep4-sync (64 rows of top-4 of 32: 0.9998) 235.5 us a layer,
+# rollout 2922 -> 2741 ms, +2.8% samples/s (PERF.md section 6, PR 57).
 STEP_MAX_READ_SHARE = 0.9
+STEP_KERNEL_MAX_ROWS = 64
 
 
 def step_read_share(n_tokens: int, k: int, n_experts: int) -> float:
@@ -185,18 +183,20 @@ def step_form(n_tokens: int, k: int, n_experts: int, width: int) -> str:
     """The form a step of ``n_tokens`` tokens takes through the held
     experts of ``width``, from what the step can see: ``kernel``
     (``experts_step``: the hit experts' stacks alone) where it is small
-    (:data:`DENSE_MAX_TOKENS`), expects to need less than
-    :data:`STEP_MAX_READ_SHARE` of the stacks, the width is whole lanes
-    (the kernel's tiles are) and the trace is for one TPU device; ``""``
+    (:data:`DENSE_MAX_TOKENS`) and either expects to need less than
+    :data:`STEP_MAX_READ_SHARE` of the stacks or has at most
+    :data:`STEP_KERNEL_MAX_ROWS` rows, the width is whole lanes (the
+    kernel's tiles are) and the trace is for one TPU device; ``""``
     elsewhere (the CPU, a mesh of several devices, where GSPMD
-    partitions the einsums, a step whose rows select about every held
-    expert between them, a tiny model): ``experts_dense``, or the
+    partitions the einsums, more rows than that which select about every
+    held expert between them, a tiny model): ``experts_dense``, or the
     grouped form where :func:`block_rows` says so."""
     from orion_tpu.ops.indexer import select_form
 
     return "kernel" if (
         n_tokens <= DENSE_MAX_TOKENS and width % 128 == 0
-        and step_read_share(n_tokens, k, n_experts) < STEP_MAX_READ_SHARE
+        and (n_tokens <= STEP_KERNEL_MAX_ROWS
+             or step_read_share(n_tokens, k, n_experts) < STEP_MAX_READ_SHARE)
         and select_form() == "kernel") else ""
 
 
@@ -544,8 +544,8 @@ class TopKMoE(nn.Module, Kind):
     blocks of the held pairs (:func:`block_rows`: a share that holds an
     eighth of the experts moves a quarter of the pair rows, twice its
     even share, and more only when the routing sends it more), and a
-    small step whose rows leave held experts unselected reads the
-    selected ones' stacks alone (:func:`step_form`); under a
+    small step that leaves held experts unselected, or has 64 rows at
+    most, reads the selected ones' stacks alone (:func:`step_form`); under a
     mesh of several devices a Mosaic kernel cannot be partitioned
     automatically, so the layer takes its dense form there, which GSPMD
     partitions over the ``expert`` axis like the GShard layer's einsums.

@@ -21,12 +21,12 @@ products are in the compute dtype with float32 accumulation and the
 activation's result is rounded to the compute dtype before the second
 product, as the einsum form's is; the gate multiplies in float32.
 
-Why it is here: a chip holds ``H`` of ``E`` experts and a decode step
-carries ``T`` rows; a held expert is selected by none of them with
-probability ``(1 - k / E) ** T``, and the einsum form reads its 9-14 MB
-all the same: at ``ppo-keye-dsa-ep8-sync`` (16 of 128 held, top 8, 8
-rows) 0.91 GB of expert stacks a step of which the rows need ~0.4
-(PERF.md section 6, PR 54).
+Why it is here: a held expert is selected by none of a step's ``T`` rows
+with probability ``(1 - k / E) ** T`` and the einsum form reads its 9-22
+MB all the same (``ppo-keye-dsa-ep8-sync``, 16 of 128 held, top 8, 8
+rows: 0.91 GB of stacks a step, ~0.4 needed; PERF.md section 6, PR 54);
+and up to 64 rows it moves what it reads faster than the einsum form,
+every stack hit (``ppo-lfm2-ep4-sync``; PERF.md section 6, PR 57).
 """
 
 from __future__ import annotations

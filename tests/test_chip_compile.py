@@ -645,6 +645,8 @@ STEP_SHAPES = {
     "kimi-8of256-top8-32rows": (32, 2304, 1024, 8, 8, "swiglu"),
     "kanana-16of128-top6-32rows": (32, 2048, 768, 16, 6, "swiglu"),
     "nemotron-8of512-top22-32rows": (32, 1024, 2688, 8, 22, "relu2"),
+    # every stack hit: the kernel for its rate alone (PR 57)
+    "lfm2-8of32-top4-64rows": (64, 2048, 1792, 8, 4, "swiglu"),
 }
 
 
@@ -1438,7 +1440,9 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
     two, four query heads a key head, over a cache laid ``bf16[64,
     1280, 512]``, the eight key heads of 64 side by side along the
     lanes and no lane padded: no ``conditional`` over prefixes and no
-    copy of the cache or of a prefix of it, ISSUES 50 and 52; the
+    copy of the cache or of a prefix of it, ISSUES 50 and 52; the six
+    expert layers' step the kernel ``experts_step``, 64 rows against
+    every held stack, ISSUE 57; the
     program's temporaries are 3.089 GB and its peak 5.967 GB where the
     per-head cache ``bf16[64, 1280, 8, 64]``, each row of 64 in 128
     lanes, made them 3.992 and 6.307: 340 MB less at the peak, four
@@ -1542,9 +1546,10 @@ def test_lfm2_cell_programs_compile_for_v5e(program, one_chip, on_tpu):
                 assert made and made.group(2) == "fusion", (name, made)
                 assert made.group(1) == laid
         assert "bf16[64,10240,64]" not in text
-        # 64 rows of top-4 of 32 select every held expert (0.9998): the
-        # einsum form, as before PR 54
-        _assert_step_experts(compiled, "", 6, "bf16[64,8,3584]")
+        # 64 rows of top-4 of 32 select every held expert (0.9998) and
+        # are few enough for the kernel all the same (PR 57): one
+        # ``experts_step`` a layer, no einsum form's gate / up product
+        _assert_step_experts(compiled, "kernel", 6, "bf16[64,8,3584]")
         assert mem.temp_size_in_bytes == pytest.approx(3.089e9, rel=1e-2)
         assert mem.peak_memory_in_bytes == pytest.approx(5.967e9, rel=1e-2)
     elif program == "experience":

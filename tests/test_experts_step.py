@@ -63,7 +63,7 @@ ROUTINGS = ["every_expert_hit", "one_hit", "none_hit", "hits_at_the_ends",
 
 
 @pytest.mark.parametrize("routing", ROUTINGS)
-@pytest.mark.parametrize("T", [1, 8, 32])
+@pytest.mark.parametrize("T", [1, 8, 32, 64])
 @pytest.mark.parametrize("H", [8, 16])
 @pytest.mark.parametrize("act", ["swiglu", "relu2"])
 def test_kernel_equals_the_einsum_form_and_reads_no_unhit_stack(
@@ -106,15 +106,21 @@ def test_a_step_past_the_last_hit_names_the_resident_block(n_hit):
     assert set(blocks[n_hit * n_tiles:]) <= {resident}
 
 
+@pytest.mark.parametrize("T,routing", [(8, "outside_and_masked"),
+                                       (64, "every_expert_hit")])
 @pytest.mark.parametrize("act", ["swiglu", "relu2"])
-def test_bfloat16_lies_no_further_from_float32_than_the_einsum_form(act):
+def test_bfloat16_lies_no_further_from_float32_than_the_einsum_form(
+        act, T, routing):
     """Products in bfloat16 with float32 sums, the activation's result
     rounded to bfloat16 before the second product, the gate in float32:
     against the float32 result the kernel is at least as near as the
-    einsum form, which rounds each expert's term before it weights it."""
-    H, T = 8, 8
+    einsum form, which rounds each expert's term before it weights it.
+    Also at 64 rows with every held stack hit, where the kernel stands
+    in for the einsum form on its rate alone (``ppo-lfm2-ep4-sync``)."""
+    H = 8
     x, w_up, w_down, gates = _operands(act, H, T, jnp.bfloat16)
-    local, _ = _routing("outside_and_masked", H, T, np.random.RandomState(3))
+    local, hit = _routing(routing, H, T, np.random.RandomState(3))
+    assert routing != "every_expert_hit" or hit == list(range(H))
     exact = moe.experts_dense(*(a.astype(jnp.float32)
                                 for a in (x, w_up, w_down)),
                               local, gates, act)
@@ -148,19 +154,29 @@ def test_gradients_are_the_einsum_forms(act):
 
 # cell -> (rows a decode step, top k, of E experts, their width, the form
 # on one TPU device): the expected share of held stacks a step needs is
-# 1 - (1 - k / E) ** rows
+# 1 - (1 - k / E) ** rows; a step takes the kernel where that is under
+# STEP_MAX_READ_SHARE or it carries at most STEP_KERNEL_MAX_ROWS rows
 CELLS = {
     "ppo-keye-dsa-ep8-sync": (8, 8, 128, 768, "kernel"),          # 0.40
     "ppo-kimi-linear-ep32-sync": (32, 8, 256, 1024, "kernel"),    # 0.64
     "ppo-mellum2-ep8-sync": (8, 8, 64, 896, "kernel"),            # 0.66
     "ppo-nemotron-h-tp4-sync": (32, 22, 512, 2688, "kernel"),     # 0.75
     "ppo-kanana-ep8-sync": (32, 6, 128, 768, "kernel"),           # 0.78
-    "ppo-lfm2-ep4-sync": (64, 4, 32, 1792, ""),                   # 0.9998
+    # every stack hit, and few enough rows that the kernel reads them
+    # faster than the einsum form (PR 57)
+    "ppo-lfm2-ep4-sync": (64, 4, 32, 1792, "kernel"),             # 0.9998
+    "64 rows that select every expert": (64, 8, 8, 1024, "kernel"),   # 1.0
+    "one row more than that": (65, 4, 32, 1792, ""),              # 0.9998
     "ppo-sdar-ep8-sync, the block's first forward": (256, 8, 128, 768, ""),
     "ppo-sdar-ep8-sync, the other three": (128, 8, 128, 768, ""),
+    # over 64 rows the share alone decides, as before PR 57
+    "128 rows that expect to skip a third": (128, 1, 128, 768, "kernel"),
+    "256 rows just over the bound": (256, 1, 111, 768, ""),       # 0.9014
+    "256 rows just under it": (256, 1, 112, 768, "kernel"),       # 0.8993
     "a training step": (16384, 8, 128, 768, ""),
     # its tiles would not be whole lanes
     "tiny_deepseek_v3, two rows": (2, 3, 8, 48, ""),
+    "64 rows of a width that is not whole lanes": (64, 4, 32, 1800, ""),
 }
 
 
